@@ -7,6 +7,8 @@ with numpy (constants) + jnp (traced), and let XLA do the fusing.
 
 from __future__ import annotations
 
+import contextvars
+import functools
 import math
 from typing import Optional
 
@@ -17,51 +19,63 @@ import jax.numpy as jnp
 CS2 = 1.0 / 3.0  # lattice speed of sound squared
 
 
-@jax.custom_vjp
+# True while a kernel body is being traced for a compiled (Mosaic)
+# ``pallas_call``: Mosaic has no lowering for ``optimization_barrier``,
+# so inside such a body ``pin`` must not emit one.
+_IN_MOSAIC_BODY = contextvars.ContextVar("tclb_in_mosaic_body", default=False)
+
+
 def pin(x):
     """Identity that pins ``x`` to one canonical evaluation: the
     compiler may not fuse ``x``'s producers into its consumers, so the
     multiply-add contraction of the producing graph no longer depends on
     where the value is used.  The engines' bit-parity contract (same
-    model arithmetic on the XLA path and inside a Pallas kernel) needs
-    this at fusion-sensitive seams.  Differentiable in reverse mode (the
-    cotangent is pinned the same way), which the raw
-    ``lax.optimization_barrier`` primitive is not."""
+    model arithmetic on the XLA path and inside an interpret-mode Pallas
+    kernel) needs this at fusion-sensitive seams.  Differentiable in
+    reverse mode (the cotangent is pinned the same way), which the raw
+    ``lax.optimization_barrier`` primitive is not.
+
+    Inside a kernel body traced for the chip (``mosaic_body``) it is the
+    plain identity: there the contract with the XLA step is a tolerance,
+    not bit equality."""
+    if _IN_MOSAIC_BODY.get():
+        return x
+    return _pin(x)
+
+
+@jax.custom_vjp
+def _pin(x):
     return jax.lax.optimization_barrier(x)
 
 
 def _pin_fwd(x):
-    return pin(x), None
+    return _pin(x), None
 
 
 def _pin_bwd(_, g):
     return (jax.lax.optimization_barrier(g),)
 
 
-pin.defvjp(_pin_fwd, _pin_bwd)
+_pin.defvjp(_pin_fwd, _pin_bwd)
 
 
-def _register_pin_batching() -> None:
-    # optimization_barrier ships without a vmap rule in the pinned jax
-    # version, which would make every pinned model un-batchable by the
-    # ensemble engine (serve/ensemble.py).  A barrier is rank-polymorphic:
-    # batching it is binding it on the batched operands with the batch
-    # dims passed through unchanged.
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:      # pragma: no cover - future jax relocations
-        return
-    if optimization_barrier_p in batching.primitive_batchers:
-        return               # pragma: no cover - newer jax grew a rule
+def mosaic_body(kernel, interpret: bool):
+    """The kernel body to hand ``pl.pallas_call``: ``kernel`` itself in
+    interpret mode (barriers kept, bit-parity with the XLA step), and
+    for a compiled call a wrapper under which ``pin`` is the identity
+    while the body is traced."""
+    if interpret:
+        return kernel
 
-    def _barrier_batcher(args, dims):
-        return optimization_barrier_p.bind(*args), dims
+    @functools.wraps(kernel)
+    def body(*refs, **kw):
+        token = _IN_MOSAIC_BODY.set(True)
+        try:
+            return kernel(*refs, **kw)
+        finally:
+            _IN_MOSAIC_BODY.reset(token)
 
-    batching.primitive_batchers[optimization_barrier_p] = _barrier_batcher
-
-
-_register_pin_batching()
+    return body
 
 
 def present_types(model, flags: np.ndarray) -> set:

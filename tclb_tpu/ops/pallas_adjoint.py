@@ -45,8 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, pallas_generic
-from tclb_tpu.ops.pallas_generic import (_CompilerParams, _HALO, KernelCtx,
-                                         action_plan, run_action_plan)
+from tclb_tpu.ops.pallas_generic import (_HALO, KernelCtx, action_plan,
+                                         run_action_plan)
 
 _probe_cache: dict = {}
 
@@ -660,7 +660,7 @@ def _mk_call_bwd_3d(model: Model, shape, cdtype, interpret, present,
             pltpu.VMEM((2, n_aux, Hb, ny, nx), cdtype),
             pltpu.SemaphoreType.DMA((2, 3 * n_sem)),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )
@@ -921,7 +921,7 @@ def make_diff_step(model: Model, shape, dtype=jnp.float32,
             pltpu.VMEM((2, n_aux, by + 2 * _HALO, nx), dtype),
             pltpu.SemaphoreType.DMA((2, 9)),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )

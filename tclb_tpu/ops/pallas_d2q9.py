@@ -50,6 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
+from tclb_tpu.ops import lbm
 from tclb_tpu.ops.lbm import equilibrium, present_types  # noqa: F401
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
@@ -300,7 +301,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     E_ = model.ei[:9, :2]
 
     call = pl.pallas_call(
-        kernel,
+        lbm.mosaic_body(kernel, interpret),
         grid=(1,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -406,7 +407,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     from tclb_tpu.models import d2q9_new as new_mod
     from tclb_tpu.models import family
     from tclb_tpu.ops import cumulant
-    from tclb_tpu.ops import lbm as lbm_mod
 
     if not supports(model, shape, dtype):
         raise ValueError(f"pallas path unsupported for {model.name} {shape}")
@@ -437,8 +437,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         bc_idx = list(model.groups["BC"])
     else:
         E = model.ei[:9, :2]
-        W = lbm_mod.weights(E)
-        OPP = lbm_mod.opposite(E)
+        W = lbm.weights(E)
+        OPP = lbm.opposite(E)
         bc_idx = None
     n_storage = model.n_storage
     f_idx = list(model.groups["f"])
@@ -575,7 +575,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                      if E[k, 1]) / rho
             feq = equilibrium(E, W, rho, (ux, uy))
             if model.name == "d2q9_les":
-                om = lbm_mod.smagorinsky_omega_unrolled(
+                om = lbm.smagorinsky_omega_unrolled(
                     E, f, feq, rho, sett[si["omega"]], sett[si["Smag"]])
             else:
                 om = sett[si["omega"]]
@@ -754,7 +754,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     grid2 = (ny // by2,)
     call2 = pl.pallas_call(
-        kernel2,
+        lbm.mosaic_body(kernel2, interpret),
         grid=grid2,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -773,7 +773,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     )
 
     call = pl.pallas_call(
-        kernel,
+        lbm.mosaic_body(kernel, interpret),
         grid=(ny // by,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

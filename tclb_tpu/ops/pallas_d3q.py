@@ -53,7 +53,6 @@ from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.models import family
 from tclb_tpu.ops import cumulant, fusion, lbm
-from tclb_tpu.ops.pallas_generic import _CompilerParams
 
 _SUPPORTED = ("d3q27_BGK", "d3q27_BGK_galcor", "d3q27_cumulant",
               "d3q19", "d3q19_les")
@@ -397,7 +396,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             # FMA contraction of the relaxation arithmetic, which in the
             # XLA path lowers contraction-free — same 1-ULP class as the
             # streaming-roll barrier above
-            f = jax.lax.optimization_barrier(f)
+            f = lbm.pin(f)
             rho = jnp.sum(f, axis=0)
             u = tuple(lbm.edot(E19[:, a], f) / rho for a in range(3))
             feq = lbm.equilibrium(E19, W19, rho, u)
@@ -420,7 +419,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                     M19, 4, 10, fneq,
                     1.0 - sett[si["omega"]], 1.0 - sett[si["S_high"]])
                 fc = jnp.stack([relax[k] + feq2[k] for k in range(19)])
-            fc = jax.lax.optimization_barrier(fc)
+            fc = lbm.pin(fc)
             return jnp.where(coll[None], fc, f), None
         from tclb_tpu.models.d3q27_bgk import _equilibrium
         rho = jnp.sum(f, axis=0)
@@ -524,7 +523,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         # scalar immediates, a Pallas kernel cannot capture an array
         # constant; no-op at f32/raw storage, so the parity contract is
         # untouched)
-        f = jax.lax.optimization_barrier(
+        f = lbm.pin(
             jnp.stack([ddf.widen_plane(p, cdtype, _shifts[k])
                        for k, p in enumerate(pulled)]))
         flags = flags_ref[:]
@@ -624,7 +623,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         # path (where streaming materializes before the collide fusion);
         # the widen seam restores bf16 storage to the f32 compute dtype
         # (+ the per-plane DDF shift under the shifted representation)
-        f = jax.lax.optimization_barrier(
+        f = lbm.pin(
             jnp.stack([ddf.widen_plane(p, cdtype, _shifts[k])
                        for k, p in enumerate(pulled)]))
         flags = flags_ref[:]
@@ -653,7 +652,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     if ring_mode:
         call = pl.pallas_call(
-            kernel_ring,
+            lbm.mosaic_body(kernel_ring, interpret),
             grid=(nz,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -677,7 +676,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         )
     else:
         call = pl.pallas_call(
-            kernel,
+            lbm.mosaic_body(kernel, interpret),
             grid=(nz // bz,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -814,7 +813,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             # barrier before collision, same reason as the single-step
             # kernels: keep the rolls out of the collide fusion so every
             # fused step's arithmetic is bit-identical to an XLA step
-            f = jax.lax.optimization_barrier(jnp.stack(pulled))
+            f = lbm.pin(jnp.stack(pulled))
             flags = flagbuf[lo:lo + n_j]
             zonal = [zb[lo:lo + n_j] for zb in zonalbuf]
             synth = [sb[lo:lo + n_j] for sb in synthbuf] \
@@ -841,7 +840,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     if K >= 2:
         call_f = pl.pallas_call(
-            kernel_fused,
+            lbm.mosaic_body(kernel_fused, interpret),
             grid=(nz // bzK,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -858,7 +857,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 pltpu.SemaphoreType.DMA((2, 2 + 4 * K)),
             ],
             interpret=interpret,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_FUSED_VMEM_LIMIT),
         )
 

@@ -50,12 +50,8 @@ from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.lattice import (LatticeState, NodeCtx, SimParams,
                                    series_dt_overrides, series_overrides)
 from tclb_tpu.core.registry import Model
-from tclb_tpu.ops import fusion
+from tclb_tpu.ops import fusion, lbm
 from tclb_tpu.ops.lbm import present_types  # noqa: F401  (re-export)
-
-# jax < 0.5 names the Pallas TPU params dataclass TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024
 _HALO = 8   # DMA halo block height: one (8, 128) f32 tile per side
@@ -794,7 +790,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         import os
         vmem_mb = int(os.environ.get("TCLB_VMEM_LIMIT_MB", "0"))
         return pl.pallas_call(
-            _mk_kernel(plan_n, with_dt, with_globals, lean),
+            lbm.mosaic_body(
+                _mk_kernel(plan_n, with_dt, with_globals, lean), interpret),
             grid=grid,
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -811,7 +808,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 pltpu.VMEM((2, n_aux_k, by + 2 * _HALO, nx), cdtype),
                 pltpu.SemaphoreType.DMA((2, 6)),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=vmem_mb * 1024 * 1024)
             if vmem_mb else None,
             interpret=interpret,
@@ -1103,7 +1100,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     @lru_cache(maxsize=None)
     def _call_for(nsteps: int):
         return pl.pallas_call(
-            kernel,
+            lbm.mosaic_body(kernel, interpret),
             grid=(nsteps,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1114,7 +1111,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((ns, ny, nx), dtype),
             scratch_shapes=[pltpu.VMEM((ns, ny, nx), dtype)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=120 * 1024 * 1024),
             interpret=interpret,
         )
@@ -1526,7 +1523,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             out_shape = [out_shape,
                          jax.ShapeDtypeStruct((8, 128), cdtype)]
         return pl.pallas_call(
-            kern,
+            lbm.mosaic_body(kern, interpret),
             grid=(nz // bz,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1543,7 +1540,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                 pltpu.VMEM((2, n_aux_k, bz + 2 * R_k, ny, nx), cdtype),
                 pltpu.SemaphoreType.DMA((2, 2 * (1 + 2 * R_k))),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024)
             if vmem_ceiling else None,
             interpret=interpret,

@@ -1170,9 +1170,9 @@ class Lattice:
                         # kernel is the proven engine for these models:
                         # swap it in and continue this very call.
                         failed = self._fast_name
-                        log.info(f"engine: {self._fast_name} failed to "
-                                 f"compile ({e!r}); fuse=1 "
-                                 "d3q fallback")
+                        log.warning(f"engine: {self._fast_name} failed "
+                                    f"to compile ({e!r}); fuse=1 "
+                                    "d3q fallback")
                         from tclb_tpu.ops import pallas_d3q
                         present = pallas_d3q.present_types(
                             self.model, self._flags_host())
@@ -1201,9 +1201,9 @@ class Lattice:
                         # tuned d2q9 resident to the tuned d2q9 band,
                         # the generic resident to the generic band.
                         failed = self._fast_name
-                        log.info(f"engine: {self._fast_name} failed to "
-                                 f"compile ({e!r}); band "
-                                 "engine fallback")
+                        log.warning(f"engine: {self._fast_name} failed "
+                                    f"to compile ({e!r}); band "
+                                    "engine fallback")
                         if was_generic_res:
                             from tclb_tpu.ops.lbm import present_types
                             present = present_types(self.model,
@@ -1249,9 +1249,9 @@ class Lattice:
                     if self.mesh is not None:
                         ladder = []   # sharded engine: no cap ladder
                     else:
-                        log.debug(f"engine: {self._fast_name} first "
-                                  f"compile failed ({e!r}); "
-                                  "trying smaller bands")
+                        log.warning(f"engine: {self._fast_name} first "
+                                    f"compile failed ({e!r}); "
+                                    "trying smaller bands")
                         from tclb_tpu.ops.lbm import present_types
                         present = present_types(self.model,
                                                 self._flags_host())
@@ -1273,7 +1273,10 @@ class Lattice:
                                 fuse=fz, present=present, by_cap=cap,
                                 shift=self._shift_vec)
                             self.state = attempt(it2)
-                        except Exception:  # noqa: BLE001
+                        except Exception as e2:  # noqa: BLE001
+                            log.warning(f"engine: pallas_generic fuse={fz} "
+                                        f"by<={cap} failed to compile "
+                                        f"({e2!r})")
                             continue
                         self._fast = fast = it2
                         self._fast_cfg = (fz, cap)
@@ -1285,9 +1288,17 @@ class Lattice:
                             model=self.model.name)
                         break
                     else:
-                        log.info(f"engine: {self._fast_name} failed to "
-                                 f"compile ({e!r}); XLA "
-                                 "fallback")
+                        if jax.default_backend() == "tpu":
+                            # on the chip a run that finishes in XLA
+                            # under a Pallas name is ~9x slower and
+                            # reads as a result: fail with the first
+                            # exception instead
+                            raise RuntimeError(
+                                f"engine {failed} and every smaller "
+                                "configuration under it failed to "
+                                f"compile on the TPU backend: {e!r}") from e
+                        log.warning(f"engine: {failed} failed to compile "
+                                    f"({e!r}); XLA fallback")
                         telemetry.engine_fallback(
                             failed, "xla", repr(e),
                             model=self.model.name)

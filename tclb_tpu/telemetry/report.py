@@ -701,7 +701,6 @@ def format_text(summary: dict) -> str:
                      f"{'iters':>9} {'time_s':>10} {'MLUPS':>10} "
                      f"{'vs_roofline':>12}")
         for eng, g in sorted(summary["engines"].items()):
-            star = "" if g.get("roofline_known", True) else "~"
             sdt = g.get("storage_dtype")
             # dtype/repr: the at-rest layout in one cell (repr only
             # matters on a narrowed rung, where it names the encoding)
@@ -712,10 +711,7 @@ def format_text(summary: dict) -> str:
                 f"  {eng:<44} {storage:>17} "
                 f"{g['chunks']:>6} {g['iters']:>9} "
                 f"{_fmt(g['total_s'], 3):>10} {_fmt(g['mlups'], 1):>10} "
-                f"{star + _fmt(g['vs_roofline'], 4):>12}")
-        if any(not g.get("roofline_known", True)
-               for g in summary["engines"].values()):
-            lines.append("  (~ = roofline estimated: unknown device kind)")
+                f"{_fmt(g['vs_roofline'], 4):>12}")
         lines.append("")
     if summary["spans"]:
         lines.append("spans")

@@ -12,7 +12,7 @@ MLUPS line (reference src/main.cpp.Rt:100-126):
 
 * ``mlups``      — ``nodes * iters / dt / 1e6``;
 * ``vs_roofline`` — achieved fraction of this chip's HBM streaming
-  roofline under the classical LBM traffic model (``bytes_per_node`` =
+  roofline (absent on a device kind whose bandwidth is not known) under the classical LBM traffic model (``bytes_per_node`` =
   2 x n_storage x sizeof(real) + flag read per node update) — the same
   math bench.py gates its credibility asserts on (it imports
   :data:`HBM_GBS` from here so the two can never drift).
@@ -29,13 +29,12 @@ from typing import Any, Optional
 
 from tclb_tpu.telemetry import events
 
-# known per-chip HBM bandwidths (GB/s); unknown kinds fall back to an
-# ESTIMATE flagged by roofline_known=False (bench.py additionally skips
-# its credibility asserts for unknown chips)
+# known per-chip HBM bandwidths (GB/s); a kind that is not listed has
+# no roofline (roofline_known=False, no vs_roofline) — never an assumed
+# bandwidth
 HBM_GBS = {"TPU v5 lite": 819.0, "TPU v5e": 819.0,
            "TPU v5p": 2765.0, "TPU v4": 1228.0,
            "TPU v6 lite": 1640.0, "TPU v6e": 1640.0}
-HBM_GBS_FALLBACK = 819.0
 
 _device_kind_cache: Optional[tuple] = None
 
@@ -53,16 +52,16 @@ def device_kind() -> str:
 
 
 def roofline_mlups(bytes_per_node: float,
-                   kind: Optional[str] = None) -> tuple[float, bool]:
-    """``(MLUPS ceiling, bandwidth_known)`` for the 1R+1W streaming
-    traffic model on ``kind`` (default: this process's first device)."""
+                   kind: Optional[str] = None) -> Optional[float]:
+    """MLUPS ceiling of the 1R+1W streaming traffic model on ``kind``
+    (default: this process's first device); None when the kind's HBM
+    bandwidth is not in :data:`HBM_GBS`."""
     if kind is None:
         kind = device_kind()
     hbm = HBM_GBS.get(kind)
-    known = hbm is not None
     if hbm is None:
-        hbm = HBM_GBS_FALLBACK
-    return hbm * 1e9 / float(bytes_per_node) / 1e6, known
+        return None
+    return hbm * 1e9 / float(bytes_per_node) / 1e6
 
 
 def fuse_of(engine: Optional[str]) -> int:
@@ -131,9 +130,11 @@ class Span:
             fields["mlups"] = float(f"{mlups:.6g}")
             bpn = fields.get("bytes_per_node")
             if bpn:
-                ceiling, known = roofline_mlups(bpn)
-                fields["vs_roofline"] = round(fields["mlups"] / ceiling, 4)
-                fields["roofline_known"] = known
+                ceiling = roofline_mlups(bpn)
+                if ceiling is not None:
+                    fields["vs_roofline"] = round(
+                        fields["mlups"] / ceiling, 4)
+                fields["roofline_known"] = ceiling is not None
                 fields["device_kind"] = device_kind()
         events.event("span", name=self.name, dur_s=round(dt, 6), **fields)
         return False

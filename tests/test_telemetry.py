@@ -164,9 +164,10 @@ def test_lattice_iterate_emits_engine_and_span(tmp_path, monkeypatch):
         assert e["mlups"] > 0
         # classical traffic model: 1R+1W of every storage field + flag
         assert e["bytes_per_node"] == 2 * m.n_storage * 4 + 2
-        # CPU device kind is not in the HBM table: estimated roofline
+        # CPU device kind is not in the HBM table: no roofline at all
+        # (never an assumed bandwidth)
         assert e["roofline_known"] is False
-        assert e["vs_roofline"] >= 0
+        assert "vs_roofline" not in e
 
 
 def test_forced_fallback_emits_events(tmp_path, monkeypatch):
@@ -203,6 +204,56 @@ def test_forced_fallback_emits_events(tmp_path, monkeypatch):
     # the iterate span records the engine that actually finished the chunk
     it = [e for e in evts if e["kind"] == "span" and e["name"] == "iterate"]
     assert it and it[-1]["engine"] == "pallas_2d[d2q9,fuse=2]"
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_exhausted_ladder_raises_on_tpu(tmp_path, monkeypatch, backend):
+    """Every rung of the probe ladder fails to compile: on a TPU backend
+    the run must raise with the first exception (an XLA run under a
+    Pallas name would read as a result); off the chip it still lands on
+    XLA, with a breadcrumb."""
+    from tclb_tpu.ops import pallas_generic
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    # process-wide verdict memos: keep this test's refusals out of others
+    monkeypatch.setattr(pallas_generic, "_mosaic_verdict", {})
+    monkeypatch.setattr(pallas_generic, "_cfg_cache", {})
+    monkeypatch.setattr(pallas_generic, "supports_resident",
+                        lambda *a, **k: False)
+    # supports() trace-probes through the builder patched below
+    monkeypatch.setattr(pallas_generic, "supports", lambda *a, **k: True)
+    rungs = []
+
+    def bad_band(model, shape, dtype, **kw):
+        rungs.append((kw.get("fuse"), kw.get("by_cap")))
+
+        def it(state, params, niter):
+            raise RuntimeError(f"synthetic mosaic failure #{len(rungs)}")
+        return it
+
+    monkeypatch.setattr(pallas_generic, "make_pallas_iterate", bad_band)
+    m = get_model("d2q9_kuper")
+    lat = Lattice(m, (32, 128), dtype=jnp.float32)
+    lat.set_flags(np.full((32, 128), m.flag_for("MRT"), dtype=np.uint16))
+    lat.init()
+    it0 = int(lat.state.iteration)     # kuper's Init action counts a step
+    trace = tmp_path / "t.jsonl"
+    telemetry.enable(str(trace))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if backend == "tpu":
+        with pytest.raises(RuntimeError, match="failed to compile on the "
+                           "TPU backend") as ei:
+            lat.iterate(4)
+        # chained from the FIRST failure, not the last rung's
+        assert "synthetic mosaic failure #1" in repr(ei.value.__cause__)
+    else:
+        lat.iterate(4)
+        assert lat._fast_name is None
+        assert int(lat.state.iteration) == it0 + 4
+    telemetry.disable()
+    assert len(rungs) > 1                     # the ladder was walked
+    fb = [e for e in report.load(str(trace))
+          if e["kind"] == "engine_fallback"]
+    assert [e["to"] for e in fb] == ([] if backend == "tpu" else ["xla"])
 
 
 # --------------------------------------------------------------------------- #

@@ -168,6 +168,8 @@ def main(argv=None) -> int:
     d.set_defaults(fn=_cmd_describe)
 
     args = p.parse_args(argv)
+    from tclb_tpu.compile_cache import place_compile_cache
+    place_compile_cache()
     return args.fn(args)
 
 

@@ -21,31 +21,11 @@ from __future__ import annotations
 from functools import lru_cache
 from typing import Callable, Optional
 
-import inspect
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # JAX >= 0.7 exposes shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# the replication-check kwarg was renamed check_rep -> check_vma across
-# jax versions; translate (or drop) so one call site works on both
-_SM_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
-
-
-def _shard_map(f, **kw):
-    if "check_vma" in kw and "check_vma" not in _SM_PARAMS:
-        v = kw.pop("check_vma")
-        if "check_rep" in _SM_PARAMS:
-            kw["check_rep"] = v
-    return _shard_map_impl(f, **kw)
 
 from tclb_tpu import telemetry
 from tclb_tpu.core.lattice import (LatticeState, SimParams, Streaming,
@@ -318,7 +298,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                 iteration=state.iteration + niter,
             )
 
-        f = _shard_map(local_iterate, mesh=mesh,
+        f = jax.shard_map(local_iterate, mesh=mesh,
                        in_specs=(state_specs, P()),
                        out_specs=state_specs, check_vma=False)
         return jax.jit(f, donate_argnums=0)
@@ -387,7 +367,7 @@ def make_sharded_iterate(model: Model, mesh: Mesh,
             return state.replace(
                 globals_=_globals_allreduce(model, state.globals_, names))
 
-        f = _shard_map(local_iterate, mesh=mesh,
+        f = jax.shard_map(local_iterate, mesh=mesh,
                        in_specs=(state_specs, param_specs),
                        out_specs=state_specs, check_vma=False)
         return jax.jit(f, donate_argnums=0)

@@ -10,8 +10,7 @@ many-case engine:
   plans (:class:`GradSpec`) batch N whole unsteady-adjoint sweeps the
   same way;
 * :mod:`tclb_tpu.serve.cache` — LRU cache of AOT-compiled ensemble
-  executables keyed on ``Model.fingerprint`` (+ JAX's persistent
-  compilation cache via ``TCLB_COMPILE_CACHE``);
+  executables keyed on ``Model.fingerprint``;
 * :mod:`tclb_tpu.serve.scheduler` — in-process queue that bins
   compatible jobs into batches, retries failed batched runs and
   degrades to the sequential path rather than failing a whole batch;
@@ -23,8 +22,7 @@ many-case engine:
 CLI: ``python -m tclb_tpu sweep case.xml --param "nu=0.01:0.05:8"``.
 """
 
-from tclb_tpu.serve.cache import (CompiledCache, default_cache,
-                                  wire_persistent_cache)
+from tclb_tpu.serve.cache import CompiledCache, default_cache
 from tclb_tpu.serve.dispatcher import FleetDispatcher, route_job
 from tclb_tpu.serve.ensemble import (Case, EnsemblePlan, EnsembleResult,
                                      GradSpec, run_ensemble)
@@ -48,5 +46,4 @@ __all__ = [
     "make_grad_evaluator",
     "route_job",
     "run_ensemble",
-    "wire_persistent_cache",
 ]

@@ -10,9 +10,8 @@ extras the spec'd key implies: the present-node-type set (the trace
 specializes on painted types), the static ``niter`` and whether Init is
 fused in.
 
-Process-persistent compiles: ``TCLB_COMPILE_CACHE=<dir>`` wires JAX's
-persistent compilation cache so a *new* process warm-starts from disk
-(the serving analogue of a model-server's compiled-artifact store).
+A *new* process warm-starts from JAX's persistent compilation cache,
+which the entry points place (:mod:`tclb_tpu.compile_cache`).
 """
 
 from __future__ import annotations
@@ -24,34 +23,6 @@ from typing import Any, Callable, Optional
 import jax
 
 from tclb_tpu import faults, telemetry
-from tclb_tpu.utils import log
-
-_persistent_wired = False
-
-
-def wire_persistent_cache() -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``TCLB_COMPILE_CACHE``
-    (idempotent; no-op when the env is unset).  Returns the directory
-    when wired."""
-    global _persistent_wired
-    cache_dir = os.environ.get("TCLB_COMPILE_CACHE")
-    if not cache_dir:
-        return None
-    if not _persistent_wired:
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # serving compiles are worth persisting regardless of their
-            # compile time; the default threshold would skip tiny cases
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0)
-        except Exception as e:  # noqa: BLE001 - knob names drift across jax
-            log.warning(f"TCLB_COMPILE_CACHE: could not wire the "
-                        f"persistent compilation cache ({e!r})")
-            return None
-        _persistent_wired = True
-        log.info(f"serve: persistent compilation cache at {cache_dir}")
-    return cache_dir
-
 
 class CompiledCache:
     """LRU cache of AOT-compiled ensemble executables.
@@ -71,7 +42,6 @@ class CompiledCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        wire_persistent_cache()
 
     def key_for(self, plan, batch: int, niter: int, init: bool,
                 device: Any = None) -> tuple:

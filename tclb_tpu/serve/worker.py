@@ -402,8 +402,10 @@ def main(argv=None) -> int:
     sys.stdout = sys.stderr
     inp = os.fdopen(os.dup(0), "rb")
 
+    from tclb_tpu.compile_cache import place_compile_cache
     from tclb_tpu.telemetry import live as tlive
 
+    place_compile_cache()
     # a crashing worker leaves its own flight-<pid>.jsonl post-mortem
     tlive.flight_recorder().attach()
 

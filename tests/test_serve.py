@@ -610,3 +610,34 @@ def test_scheduler_emits_serving_spans(tmp_path):
     assert sv["cache_hit_rate_pct"] == 0.0
     assert cnt.get("serve.jobs.submitted") == 2
     assert cnt.get("serve.jobs.done") == 2
+
+
+# --------------------------------------------------------------------------- #
+# persistent compile cache placement (tclb_tpu/compile_cache.py)
+# --------------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("given", ["/some/dir", None])
+def test_compile_cache_placed_from_outside(monkeypatch, given):
+    """JAX_COMPILATION_CACHE_DIR set: that directory, and no directory
+    set in code; unset: the fixed path inside the checkout."""
+    import jax
+
+    from tclb_tpu import compile_cache
+    if given:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", given)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    updates = []
+    # record instead of applying: this process keeps its own cache state
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    got = compile_cache.place_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if given:
+        assert got == given and updates == []
+    else:
+        assert got == os.path.join(repo, ".jax_cache")
+        assert updates == [("jax_compilation_cache_dir", got)]
+    # the same answer in the next process: nothing of this one in the path
+    assert got == compile_cache.place_compile_cache()

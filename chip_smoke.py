@@ -1,0 +1,449 @@
+#!/usr/bin/env python3
+"""Prove on the chip that the forward solver starts and computes.
+
+    python chip_smoke.py              one TPU chip, every phase below
+    python chip_smoke.py --chips 4    four chips: the sharded path and
+                                      its one-device comparison, only
+    python chip_smoke.py --rehearse   control-flow rehearsal at tiny
+                                      sizes on any backend; can never
+                                      print ``"ok": true``
+
+Everything runs in this one process, through the function the ``tclb``
+console script runs (``tclb_tpu.__main__``): a chip belongs to one
+process at a time, so no child is started.  Each phase fails the script
+on an exception, a non-finite or mis-shaped output field, a ``failcheck``
+or ``engine_fallback`` event, or an engine family other than the one
+expected — a run that finished on the XLA step under a Pallas name is a
+failure here, not a result.  ``TCLB_FASTPATH`` is never set to ``force``:
+the engines are whatever ``Lattice`` selects on this backend.
+
+Without an accelerator the script exits non-zero and prints no result.
+The last line of a passing run is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+The seconds printed on earlier lines are smoke observations (one
+reading, compilation included where it says so), not benchmark numbers.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import re
+import shutil
+import struct
+import sys
+import time
+import traceback
+import xml.etree.ElementTree as ET
+import zlib
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+EXAMPLE = os.path.join(HERE, "example")
+OUT = os.path.join(HERE, "output", "chip_smoke")     # git-ignored
+
+#: Pallas engine vs the XLA step, max |difference| over all populations
+#: after AGREE_STEPS steps from one initial state.  f32 (eps 1.2e-7) with
+#: populations of order 1: the kernels re-associate the same arithmetic
+#: (no barrier inside a compiled kernel), the repo's 10-step interpret
+#: tests allow atol 2e-6, parallel/halo.py reports <= 4e-7 for the
+#: sharded path.  A wrong kernel is off by 1e-3 or more.
+TOL = 2e-5
+AGREE_STEPS = 100
+
+
+# --------------------------------------------------------------------------- #
+# case files and outputs
+# --------------------------------------------------------------------------- #
+
+
+def cut_case(src: str, dst: str, solve: int, handlers: bool = True,
+             geometry: dict | None = None) -> str:
+    """Copy case ``src`` to ``dst`` with ``<Solve>`` cut to ``solve``
+    steps; periodic handlers keep their place but fire no later than the
+    end (``handlers=False`` drops them).  ``geometry`` overrides size
+    attributes (rehearsal only).  Model, parameters and painting stay."""
+    tree = ET.parse(src)
+    root = tree.getroot()
+    for el in list(root):
+        if el.tag == "Solve":
+            el.set("Iterations", str(solve))
+        elif el.tag in ("Log", "VTK", "Failcheck"):
+            if not handlers:
+                root.remove(el)
+            elif int(el.get("Iterations", "0")) > solve:
+                el.set("Iterations", str(solve))
+        elif el.tag == "Geometry" and geometry:
+            for k, v in geometry.items():
+                el.set(k, str(v))
+    os.makedirs(os.path.dirname(dst), exist_ok=True)
+    tree.write(dst)
+    return dst
+
+
+def read_vti(path: str) -> dict:
+    """Arrays of a .vti written by tclb_tpu/utils/vtk.py (appended raw
+    blocks, plain or vtkZLibDataCompressor), as flat numpy arrays with
+    the piece's cell count under ``"__cells__"``."""
+    import numpy as np
+    raw = open(path, "rb").read()
+    head, body = raw.split(b'<AppendedData encoding="raw">\n_', 1)
+    body = body.rsplit(b"\n</AppendedData>", 1)[0]
+    head = head.decode()
+    compressed = "vtkZLibDataCompressor" in head
+    ext = [int(v) for v in
+           re.search(r'<Piece Extent="([^"]+)"', head).group(1).split()]
+    out = {"__cells__": (ext[1] - ext[0]) * (ext[3] - ext[2])
+           * (ext[5] - ext[4])}
+    types = {"Float32": np.float32, "Float64": np.float64,
+             "UInt16": np.uint16, "UInt8": np.uint8, "Int32": np.int32,
+             "UInt32": np.uint32}
+    for m in re.finditer(r'<DataArray type="(\w+)" Name="([^"]+)" '
+                         r'NumberOfComponents="(\d+)" format="appended" '
+                         r'offset="(\d+)"/>', head):
+        typ, name, ncomp, off = m.group(1), m.group(2), int(m.group(3)), \
+            int(m.group(4))
+        if compressed:
+            nblocks = struct.unpack_from("<I", body, off)[0]
+            sizes = struct.unpack_from(f"<{nblocks}I", body, off + 12)
+            pos = off + 12 + 4 * nblocks
+            chunks = []
+            for s in sizes:
+                chunks.append(zlib.decompress(body[pos:pos + s]))
+                pos += s
+            data = b"".join(chunks)
+        else:
+            n = struct.unpack_from("<I", body, off)[0]
+            data = body[off + 4:off + 4 + n]
+        a = np.frombuffer(data, dtype=types[typ])
+        if a.size != out["__cells__"] * ncomp:
+            raise AssertionError(f"{path}: {name} holds {a.size} values, "
+                                 f"expected {out['__cells__'] * ncomp}")
+        out[name] = a
+    return out
+
+
+# --------------------------------------------------------------------------- #
+# the smoke
+# --------------------------------------------------------------------------- #
+
+
+class Smoke:
+    def __init__(self, rehearse: bool):
+        from tclb_tpu import telemetry
+        self.rehearse = rehearse
+        self.events: list[dict] = []
+        self.failed: list[str] = []
+        telemetry.subscribe(self.events.append)
+
+    # -- one `tclb run` ---------------------------------------------------- #
+
+    def argv(self, case: str, outdir: str, mesh: str | None = None) -> list:
+        a = ["run", case, "--output", outdir + "/"]
+        return a + ["--mesh", mesh] if mesh else a
+
+    def check_events(self, ev: list, engine: tuple, steps: int | None
+                     ) -> dict:
+        """The engine this run selected, after checking that it is of an
+        expected family and that nothing fell back or failed a check."""
+        sel = [e for e in ev if e.get("kind") == "engine_selected"]
+        if len(sel) != 1:
+            raise AssertionError(f"{len(sel)} engine_selected events")
+        tag = sel[0]["engine"]
+        fb = [e for e in ev if e.get("kind") == "engine_fallback"]
+        if fb:
+            raise AssertionError(
+                "engine_fallback: " + "; ".join(
+                    f"{e.get('from')} -> {e.get('to')} ({e.get('cause')})"
+                    for e in fb))
+        fc = [e for e in ev if e.get("kind") == "failcheck"]
+        if fc:
+            raise AssertionError(f"failcheck fired: {fc[0]}")
+        # under a forced interpret-mode rehearsal the tags are the chip's;
+        # on a plain CPU rehearsal every engine is "xla": report, not fail
+        forced = os.environ.get("TCLB_FASTPATH") == "force"
+        if (not self.rehearse or forced) and not tag.startswith(engine):
+            raise AssertionError(f"engine {tag!r}, expected one of "
+                                 f"{engine}")
+        spans = [e for e in ev if e.get("kind") == "span"
+                 and e.get("name") == "iterate"]
+        bad = [e for e in spans if e.get("ok") is False]
+        if bad:
+            raise AssertionError(f"iterate failed: {bad[0].get('error')}")
+        done = sum(int(e["iters"]) for e in spans)
+        if steps is not None and done != steps:
+            raise AssertionError(f"{done} steps ran, expected {steps}")
+        first = spans[0]
+        rest = spans[1:]
+        return {"engine": tag, "steps": done,
+                # first call = compile + its steps; the rest are fenced
+                # with block_until_ready by the iterate span
+                "first_call_s": first["dur_s"],
+                "first_call_steps": int(first["iters"]),
+                "later_calls_s": round(sum(e["dur_s"] for e in rest), 6),
+                "later_calls_steps": sum(int(e["iters"]) for e in rest)}
+
+    def run(self, name: str, case: str, engine: tuple, shape: tuple,
+            steps: int) -> None:
+        """`tclb run case` through the console script's main(); checks
+        events and the last VTK file it wrote."""
+        import numpy as np
+        from tclb_tpu.__main__ import main as tclb_main
+        outdir = os.path.join(OUT, name)
+        shutil.rmtree(outdir, ignore_errors=True)
+        mark = len(self.events)
+        t0 = time.perf_counter()
+        rc = tclb_main(self.argv(case, outdir))
+        wall = time.perf_counter() - t0
+        if rc != 0:
+            raise AssertionError(f"tclb run exited {rc}")
+        info = self.check_events(self.events[mark:], engine, steps)
+        vtis = sorted(glob.glob(os.path.join(outdir, "*_VTK_*.vti")))
+        if not vtis:
+            raise AssertionError(f"no VTK output in {outdir}")
+        last = vtis[-1]
+        if not last.endswith(f"_{steps:08d}.vti"):
+            raise AssertionError(f"last VTK is {os.path.basename(last)}, "
+                                 f"expected iteration {steps}")
+        arrays = read_vti(last)
+        cells = arrays.pop("__cells__")
+        if cells != int(np.prod(shape)):
+            raise AssertionError(f"VTK holds {cells} cells, expected "
+                                 f"{int(np.prod(shape))}")
+        for qn, a in arrays.items():
+            if a.dtype.kind == "f" and not np.isfinite(a).all():
+                raise AssertionError(f"{qn} is not finite in {last}")
+        log = glob.glob(os.path.join(outdir, "*_Log.csv"))
+        info.update(phase=name, case=os.path.relpath(case, HERE),
+                    shape=list(shape), total_s=round(wall, 3),
+                    vtk=os.path.basename(last),
+                    vtk_arrays=sorted(arrays), log_csv=bool(log))
+        print(json.dumps(info), flush=True)
+
+    # -- Pallas vs XLA, and mesh vs one device ------------------------------ #
+
+    def fields_after(self, case: str, outdir: str, engine: tuple,
+                     mesh: str | None = None, xla: bool = False):
+        """Run ``case`` through the CLI's run_case() and return
+        (host copy of the final populations, engine tag, lattice)."""
+        import numpy as np
+        from tclb_tpu.__main__ import build_parser, run_case
+        args = build_parser().parse_args(self.argv(case, outdir, mesh))
+        mark = len(self.events)
+        old = os.environ.get("TCLB_FASTPATH")
+        if xla:
+            os.environ["TCLB_FASTPATH"] = "0"    # the plain reference
+        try:
+            solver = run_case(args)
+        finally:
+            if xla:
+                if old is None:
+                    del os.environ["TCLB_FASTPATH"]
+                else:
+                    os.environ["TCLB_FASTPATH"] = old
+        info = self.check_events(self.events[mark:],
+                                 ("xla",) if xla else engine, None)
+        if xla and info["engine"] != "xla":
+            raise AssertionError(f"reference ran on {info['engine']}")
+        lat = solver.lattice
+        f = np.asarray(lat.state.fields)
+        if not np.isfinite(f).all():
+            raise AssertionError("non-finite populations")
+        return f, info, lat
+
+    def agree(self, name: str, case: str, engine: tuple,
+              geometry: dict | None = None) -> None:
+        """AGREE_STEPS steps of ``case`` on the selected Pallas engine
+        and on the XLA step, same initial state; max |diff| <= TOL."""
+        import numpy as np
+        outdir = os.path.join(OUT, name)
+        shutil.rmtree(outdir, ignore_errors=True)
+        cut = cut_case(case, os.path.join(outdir, "case.xml"), AGREE_STEPS,
+                       handlers=False, geometry=geometry)
+        fp, ip, _ = self.fields_after(cut, outdir, engine)
+        fx, _, _ = self.fields_after(cut, outdir, engine, xla=True)
+        diff = float(np.max(np.abs(fp - fx)))
+        print(json.dumps({"phase": name, "engine": ip["engine"],
+                          "reference": "xla", "steps": AGREE_STEPS,
+                          "max_abs_diff": diff, "tolerance": TOL,
+                          "max_abs_field": float(np.max(np.abs(fx)))}),
+              flush=True)
+        if not diff <= TOL:
+            raise AssertionError(f"{ip['engine']} vs XLA: max |diff| "
+                                 f"{diff:.3e} > {TOL:.1e}")
+
+    def sharded(self, name: str, case: str, mesh: str, steps: int,
+                geometry: dict | None = None) -> None:
+        """``case`` on the device mesh and on device 0 alone: sharded
+        Pallas engine, one shard per device, agreement within TOL."""
+        import numpy as np
+        outdir = os.path.join(OUT, name)
+        shutil.rmtree(outdir, ignore_errors=True)
+        cut = cut_case(case, os.path.join(outdir, "case.xml"), steps,
+                       geometry=geometry)
+        n = int(np.prod([int(v) for v in mesh.split("x")]))
+        fm, im, lat = self.fields_after(cut, outdir, ("pallas_sharded[",),
+                                        mesh=mesh)
+        shards = lat.state.fields.addressable_shards
+        devs = {s.device for s in shards}
+        if len(shards) != n or len(devs) != n:
+            raise AssertionError(f"{len(shards)} shards on {len(devs)} "
+                                 f"devices, expected {n} on {n}")
+        rows = sorted((s.index[1].start or 0, s.data.shape[1])
+                      for s in shards)
+        f1, i1, _ = self.fields_after(
+            cut, outdir, ("pallas_2d[", "pallas_resident["))
+        diff = float(np.max(np.abs(fm - f1)))
+        print(json.dumps({
+            "phase": name, "case": os.path.relpath(case, HERE),
+            "mesh": mesh, "engine": im["engine"], "steps": steps,
+            "shards": len(shards),
+            "shard_devices": sorted(str(d) for d in devs),
+            "shard_rows": rows,
+            "first_call_s": im["first_call_s"],
+            "later_calls_s": im["later_calls_s"],
+            "later_calls_steps": im["later_calls_steps"],
+            "one_device_engine": i1["engine"],
+            "one_device_later_calls_s": i1["later_calls_s"],
+            "max_abs_diff": diff, "tolerance": TOL}), flush=True)
+        if not diff <= TOL:
+            raise AssertionError(f"mesh {mesh} vs one device: max |diff| "
+                                 f"{diff:.3e} > {TOL:.1e}")
+
+    # -- phase bookkeeping --------------------------------------------------- #
+
+    def phase(self, name: str, fn, *a, **k) -> None:
+        print(f"--- {name}", flush=True)
+        try:
+            fn(name, *a, **k)
+        except Exception:  # noqa: BLE001 — report, go on, fail at the end
+            traceback.print_exc()
+            sys.stderr.flush()
+            print(f"FAILED {name}", flush=True)
+            self.failed.append(name)
+
+
+def one_chip(s: Smoke) -> None:
+    ex = lambda f: os.path.join(EXAMPLE, f)      # noqa: E731
+    tmp = lambda f: os.path.join(OUT, "cases", f)  # noqa: E731
+    if s.rehearse:
+        # tiny stand-ins cut from the same files: control flow only
+        karman = cut_case(ex("karman.xml"), tmp("karman.xml"), 40,
+                          geometry={"nx": 256})
+        k1024 = cut_case(ex("karman_1024.xml"), tmp("karman_1024.xml"), 20,
+                         geometry={"nx": 256, "ny": 512})
+        ch = cut_case(ex("3d_channel.xml"), tmp("3d_channel.xml"), 8,
+                      geometry={"nx": 128, "ny": 16, "nz": 8})
+        ch512 = cut_case(ex("3d_channel_512.xml"), tmp("3d_channel_512.xml"),
+                         8, geometry={"nx": 128, "ny": 16, "nz": 16})
+        drop = cut_case(ex("drop_512.xml"), tmp("drop_512.xml"), 10,
+                        geometry={"nx": 128, "ny": 64})
+        sizes = dict(karman=((100, 256), 40), k1024=((512, 256), 20),
+                     ch=((8, 16, 128), 8), ch512=((16, 16, 128), 8),
+                     drop=((64, 128), 10))
+        g2, g3, gk = ({"nx": 256, "ny": 512},
+                      {"nx": 128, "ny": 16, "nz": 8},
+                      {"nx": 128, "ny": 64})
+    else:
+        karman, k1024 = ex("karman.xml"), ex("karman_1024.xml")
+        ch = cut_case(ex("3d_channel.xml"), tmp("3d_channel.xml"), 1000)
+        ch512, drop = ex("3d_channel_512.xml"), ex("drop_512.xml")
+        sizes = dict(karman=((100, 1024), 10000), k1024=((1024, 1024), 2000),
+                     ch=((48, 48, 256), 1000), ch512=((512, 48, 256), 1000),
+                     drop=((512, 512), 1000))
+        g2 = g3 = gk = None
+    s.phase("2d_karman_as_shipped", s.run, karman,
+            ("pallas_resident[d2q9,", "pallas_2d[d2q9,"), *sizes["karman"])
+    s.phase("2d_karman_1024", s.run, k1024,
+            ("pallas_2d[d2q9,fuse=2]",), *sizes["k1024"])
+    s.phase("3d_channel", s.run, ch,
+            ("pallas_d3q[d3q27_cumulant,fuse=",), *sizes["ch"])
+    s.phase("3d_channel_512", s.run, ch512,
+            ("pallas_d3q[d3q27_cumulant,fuse=",), *sizes["ch512"])
+    # the registry-driven generic engine, band or VMEM-resident flavour
+    # (at 512x512 the Lattice picks the resident one)
+    generic = ("pallas_generic[d2q9_kuper,fuse=",
+               "pallas_resident_generic[d2q9_kuper,fuse=")
+    s.phase("generic_drop_512", s.run, drop, generic, *sizes["drop"])
+    s.phase("agree_d2q9", s.agree, ex("karman_1024.xml"),
+            ("pallas_2d[d2q9,",), geometry=g2)
+    s.phase("agree_d3q27_cumulant", s.agree, ex("3d_channel.xml"),
+            ("pallas_d3q[d3q27_cumulant,",), geometry=g3)
+    s.phase("agree_d2q9_kuper", s.agree, ex("drop_512.xml"), generic,
+            geometry=gk)
+
+
+def four_chips(s: Smoke) -> None:
+    case = os.path.join(EXAMPLE, "karman_4096.xml")
+    if s.rehearse:
+        s.phase("sharded_4x1", s.sharded, case, "4x1", 12,
+                geometry={"nx": 128, "ny": 256})
+    else:
+        s.phase("sharded_4x1", s.sharded, case, "4x1", 500)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the sharded path and what it is "
+                    "compared with, on four chips")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes on any backend, to find wrong paths "
+                    "and arguments before a chip run; never prints ok")
+    args = ap.parse_args(argv)
+
+    import jax
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if not args.rehearse and device["platform"] != "tpu":
+        print(f"chip_smoke: no accelerator (JAX reports {device}); "
+              "nothing was run", file=sys.stderr)
+        return 2
+    if len(devs) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} needs that many devices, "
+              f"JAX reports {len(devs)}", file=sys.stderr)
+        return 2
+
+    import jaxlib
+    import numpy as np
+
+    from tclb_tpu import native
+    from tclb_tpu.compile_cache import place_compile_cache
+    cache_dir = place_compile_cache()
+    try:
+        from importlib.metadata import version
+        libtpu = version("libtpu")
+    except Exception:  # noqa: BLE001 — not installed as a distribution
+        libtpu = None
+    print(json.dumps({
+        "device": device, "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__, "libtpu": libtpu,
+        "numpy": np.__version__, "python": sys.version.split()[0],
+        "compile_cache_dir": cache_dir,
+        "compile_cache_entries_at_start":
+            len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0,
+        # the compressed VTK of 2d_karman_1024 goes through this library
+        # when it built, else through the zlib fallback in Python
+        "native_library": "built" if native.available()
+        else "absent: Python fallback writes the compressed VTK",
+        "rehearsal": args.rehearse}), flush=True)
+
+    t0 = time.perf_counter()
+    s = Smoke(args.rehearse)
+    (four_chips if args.chips == 4 else one_chip)(s)
+    print(json.dumps({"total_s": round(time.perf_counter() - t0, 1),
+                      "failed": s.failed}), flush=True)
+    if s.failed:
+        return 1
+    if args.rehearse:
+        print(json.dumps({"ok": False, "rehearsal": "passed",
+                          "device": device}))
+        return 0
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

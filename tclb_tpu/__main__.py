@@ -16,7 +16,10 @@ import json
 import sys
 
 
-def _cmd_run(args) -> int:
+def run_case(args):
+    """What ``tclb run`` does with its parsed arguments; returns the
+    finished :class:`~tclb_tpu.control.solver.Solver` (``chip_smoke.py``
+    inspects its lattice).  A usage error exits with code 2."""
     import xml.etree.ElementTree as ET
 
     # honor the config's model attribute when --model is absent
@@ -27,7 +30,7 @@ def _cmd_run(args) -> int:
     if model_name is None:
         print("error: no --model flag and no model= attribute on "
               "<CLBConfig>", file=sys.stderr)
-        return 2
+        raise SystemExit(2)
 
     if args.distributed:
         # multi-host: one process per host over DCN, same config
@@ -52,7 +55,7 @@ def _cmd_run(args) -> int:
         if len(axes) != len(names):
             print(f"error: --mesh needs {len(names)} factors for a "
                   f"{model.ndim}D model", file=sys.stderr)
-            return 2
+            raise SystemExit(2)
         n = int(np.prod(axes))
         mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(axes), names)
     dtype = {"f32": jnp.float32, "f64": jnp.float64}[args.precision]
@@ -78,6 +81,11 @@ def _cmd_run(args) -> int:
             print(f"profile trace written to {args.profile}")
         if monitor is not None:
             monitor.stop()
+    return solver
+
+
+def _cmd_run(args) -> int:
+    solver = run_case(args)
     print(f"done: {solver.iter} iterations")
     return 0
 
@@ -116,7 +124,7 @@ def _cmd_describe(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="tclb", description="TPU-native lattice-Boltzmann framework")
     sub = p.add_subparsers(dest="cmd", required=True)
@@ -167,7 +175,11 @@ def main(argv=None) -> int:
     d.add_argument("model")
     d.set_defaults(fn=_cmd_describe)
 
-    args = p.parse_args(argv)
+    return p
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     from tclb_tpu.compile_cache import place_compile_cache
     place_compile_cache()
     return args.fn(args)

@@ -348,8 +348,11 @@ def _run_root(root: ET.Element, model: Model, mesh, dtype,
     from tclb_tpu.control.handlers import MainContainer
     if root.tag != "CLBConfig":
         raise ValueError(f"config root must be <CLBConfig>, got <{root.tag}>")
-    solver = Solver(model,
-                    output=output or root.get("output", "output/"),
+    if output:
+        # an explicit prefix (the CLI's --output) wins over the config's
+        # own attribute, which <CLBConfig>'s handler would re-apply
+        root.set("output", output)
+    solver = Solver(model, output=root.get("output", "output/"),
                     mesh=mesh, dtype=dtype)
     solver.conf_name = conf_name
     solver.resume_from = resume

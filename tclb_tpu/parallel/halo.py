@@ -299,8 +299,8 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             )
 
         f = jax.shard_map(local_iterate, mesh=mesh,
-                       in_specs=(state_specs, P()),
-                       out_specs=state_specs, check_vma=False)
+                          in_specs=(state_specs, P()),
+                          out_specs=state_specs, check_vma=False)
         return jax.jit(f, donate_argnums=0)
 
     def iterate(state, params, niter):
@@ -368,8 +368,8 @@ def make_sharded_iterate(model: Model, mesh: Mesh,
                 globals_=_globals_allreduce(model, state.globals_, names))
 
         f = jax.shard_map(local_iterate, mesh=mesh,
-                       in_specs=(state_specs, param_specs),
-                       out_specs=state_specs, check_vma=False)
+                          in_specs=(state_specs, param_specs),
+                          out_specs=state_specs, check_vma=False)
         return jax.jit(f, donate_argnums=0)
 
     # how many per-step ppermute exchange rounds the streaming strategy

@@ -1,0 +1,151 @@
+"""The forward kernels of the main path, compiled for a *described* TPU
+v5e by the chip's own compiler (Mosaic + XLA:TPU), with no chip attached.
+
+Interpret mode — every other Pallas test here — cannot see what Mosaic
+refuses: a primitive without a lowering rule (``optimization_barrier``,
+which is why ``lbm.pin`` is the identity inside a compiled kernel body),
+a misaligned slice, a scoped-VMEM overflow.  These compiles can.  Nothing
+runs, so they say nothing about results or times.
+
+This is the only file that describes the chip.  The topology is described
+inside a fixture (loading the TPU library while a module is imported would
+break multi-worker collection), compiles happen in this process, and the
+persistent compilation cache is off around them (a TPU executable written
+to it cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tclb_tpu.core.lattice import Lattice
+from tclb_tpu.models import get_model
+from tclb_tpu.ops import lbm, pallas_d2q9, pallas_d3q, pallas_generic
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is locked
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _as_on_the_chip():
+    """The process configuration of a chip run: 32-bit (conftest turns
+    x64 on for the CPU goldens, and Mosaic refuses the i64 indices that
+    gives), and no persistent cache around the compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was_cache = jax.config.jax_enable_compilation_cache
+    was_x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_x64", was_x64)
+    jax.config.update("jax_enable_compilation_cache", was_cache)
+    cc.reset_cache()
+
+
+def _channel(name, shape, **settings):
+    """A walled channel of ``name`` at ``shape`` (host side only: the
+    state gives the compile its shapes)."""
+    m = get_model(name)
+    lat = Lattice(m, shape, dtype=jnp.float32, settings=settings)
+    flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
+    if "Wall" in m.node_types:
+        flags[..., 0, :] = m.flag_for("Wall")
+        flags[..., -1, :] = m.flag_for("Wall")
+    lat.set_flags(flags)
+    lat.init()
+    return m, lat, lbm.present_types(m, flags)
+
+
+def _compile(iterate, lat, niter, one_chip) -> str:
+    spec = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (lat.state, lat.params))
+    compiled = jax.jit(lambda s, p: iterate(s, p, niter)).lower(
+        *spec).compile()
+    return compiled.as_text()
+
+
+def test_d2q9_band_fused_1024(one_chip):
+    shape = (1024, 1024)
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
+                                         interpret=False, fuse=2,
+                                         present=present)
+    # 5 steps: two fused pairs + the single-step kernel for the odd one
+    assert "tpu_custom_call" in _compile(it, lat, 5, one_chip)
+
+
+@pytest.mark.parametrize("fuse", [None, 1], ids=["fused", "fuse1"])
+def test_d3q27_cumulant_48x48x256(one_chip, fuse):
+    shape = (48, 48, 256)
+    m, lat, present = _channel("d3q27_cumulant", shape, nu=0.01)
+    if fuse is None:
+        assert pallas_d3q.choose_fuse(m, shape, itemsize=4) >= 2
+    it = pallas_d3q.make_pallas_iterate(m, shape, jnp.float32,
+                                        interpret=False, present=present,
+                                        fuse=fuse)
+    assert "tpu_custom_call" in _compile(it, lat, 6, one_chip)
+
+
+@pytest.mark.parametrize("name", ["d2q9_kuper", "d2q9_heat"])
+def test_generic_512(one_chip, name):
+    shape = (512, 512)
+    m, lat, present = _channel(name, shape)
+    it = pallas_generic.make_pallas_iterate(
+        m, shape, jnp.float32, interpret=False,
+        fuse=pallas_generic.choose_fuse(m), present=present)
+    assert "tpu_custom_call" in _compile(it, lat, 4, one_chip)
+
+
+def test_generic_d3q19_heat_builder_defaults(one_chip):
+    """The 3D generic builder at its own defaults (fuse=1, every node
+    type) is refused at 48x48x256 — 18.08M of scoped VMEM against a
+    16.00M limit that the planner's budget does not see — while the fuse
+    the Lattice picks there (choose_fuse_3d -> 3) compiles.  Pinned as an
+    expected failure so the planner fix (ROADMAP Queue 1) has a target;
+    any other error fails."""
+    shape = (48, 48, 256)
+    m, lat, _ = _channel("d3q19_heat", shape)
+    it = pallas_generic.make_pallas_iterate(m, shape, jnp.float32,
+                                            interpret=False)
+    try:
+        text = _compile(it, lat, 4, one_chip)
+    except Exception as e:  # noqa: BLE001 — only the VMEM refusal is known
+        if "exceeded scoped vmem limit" in str(e):
+            pytest.xfail(str(e)[-160:])
+        raise
+    assert "tpu_custom_call" in text
+
+
+def test_pin_is_identity_only_inside_a_compiled_body():
+    """The one mechanism that keeps optimization_barrier away from
+    Mosaic: barrier under XLA and in an interpret-mode body, nothing in
+    a body traced for a compiled pallas_call."""
+    def body(x):
+        return lbm.pin(x) + 1.0
+
+    x = jnp.ones((8, 128), jnp.float32)
+    assert "optimization_barrier" in str(jax.make_jaxpr(body)(x))
+    assert "optimization_barrier" in str(
+        jax.make_jaxpr(lbm.mosaic_body(body, interpret=True))(x))
+    assert "optimization_barrier" not in str(
+        jax.make_jaxpr(lbm.mosaic_body(body, interpret=False))(x))
+    assert "optimization_barrier" in str(jax.make_jaxpr(body)(x))

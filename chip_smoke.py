@@ -261,14 +261,19 @@ class Smoke:
         shutil.rmtree(outdir, ignore_errors=True)
         cut = cut_case(case, os.path.join(outdir, "case.xml"), AGREE_STEPS,
                        handlers=False, geometry=geometry)
-        fp, ip, _ = self.fields_after(cut, outdir, engine)
+        fp, ip, lat = self.fields_after(cut, outdir, engine)
+        # the flow has to have moved, or agreement would be vacuous
+        umax = float(np.max(np.abs(np.asarray(lat.get_quantity("U")))))
         fx, _, _ = self.fields_after(cut, outdir, engine, xla=True)
         diff = float(np.max(np.abs(fp - fx)))
         print(json.dumps({"phase": name, "engine": ip["engine"],
                           "reference": "xla", "steps": AGREE_STEPS,
                           "max_abs_diff": diff, "tolerance": TOL,
-                          "max_abs_field": float(np.max(np.abs(fx)))}),
-              flush=True)
+                          "max_abs_field": float(np.max(np.abs(fx))),
+                          "max_abs_u": umax}), flush=True)
+        if not umax > 0.0:
+            raise AssertionError("velocity is zero everywhere after "
+                                 f"{AGREE_STEPS} steps")
         if not diff <= TOL:
             raise AssertionError(f"{ip['engine']} vs XLA: max |diff| "
                                  f"{diff:.3e} > {TOL:.1e}")
@@ -338,13 +343,13 @@ def one_chip(s: Smoke) -> None:
         ch512 = cut_case(ex("3d_channel_512.xml"), tmp("3d_channel_512.xml"),
                          8, geometry={"nx": 128, "ny": 16, "nz": 16})
         drop = cut_case(ex("drop_512.xml"), tmp("drop_512.xml"), 10,
-                        geometry={"nx": 128, "ny": 64})
+                        geometry={"nx": 384, "ny": 384})
         sizes = dict(karman=((100, 256), 40), k1024=((512, 256), 20),
                      ch=((8, 16, 128), 8), ch512=((16, 16, 128), 8),
-                     drop=((64, 128), 10))
+                     drop=((384, 384), 10))
         g2, g3, gk = ({"nx": 256, "ny": 512},
                       {"nx": 128, "ny": 16, "nz": 8},
-                      {"nx": 128, "ny": 64})
+                      {"nx": 384, "ny": 384})
     else:
         karman, k1024 = ex("karman.xml"), ex("karman_1024.xml")
         ch = cut_case(ex("3d_channel.xml"), tmp("3d_channel.xml"), 1000)

@@ -8,6 +8,8 @@ CPU (interpret mode) and pin the engine entry point — fields AND globals —
 against the pure-XLA path on a boundary-rich case.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -320,3 +322,61 @@ def test_single_step_uses_xla(monkeypatch):
     np.testing.assert_allclose(np.asarray(lat.state.fields),
                                np.asarray(lat_x.state.fields),
                                rtol=1e-6, atol=1e-7)
+
+
+# --------------------------------------------------------------------------- #
+# chip_smoke.py: the parts that need no chip
+# --------------------------------------------------------------------------- #
+
+
+def _chip_smoke():
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_refuses_without_accelerator(capsys):
+    """No accelerator: non-zero exit and no result line (conftest holds
+    this process to the CPU)."""
+    assert _chip_smoke().main([]) == 2
+    out = capsys.readouterr()
+    assert '"ok"' not in out.out
+    assert "no accelerator" in out.err
+
+
+@pytest.mark.parametrize("compress", [False, True])
+def test_chip_smoke_reads_back_vti(tmp_path, compress):
+    """The smoke's output check reads what utils/vtk.py writes."""
+    from tclb_tpu.utils.vtk import write_vti
+    rng = np.random.default_rng(3)
+    rho = rng.standard_normal((6, 40)).astype(np.float32)
+    u = rng.standard_normal((3, 6, 40)).astype(np.float32)
+    p = write_vti(str(tmp_path / "x"), {"Rho": rho, "U": u},
+                  compress=compress)
+    back = _chip_smoke().read_vti(p)
+    assert back["__cells__"] == 6 * 40
+    assert (back["Rho"] == rho.ravel()).all()
+    assert (back["U"].reshape(6, 40, 3) == np.moveaxis(u, 0, -1)).all()
+
+
+def test_cli_output_flag_wins_over_config_attribute(tmp_path):
+    """`tclb run --output DIR/` must not be overridden by the case file's
+    own output= attribute."""
+    from tclb_tpu.control import run_config
+    case = tmp_path / "mini.xml"
+    case.write_text("""<?xml version="1.0"?>
+<CLBConfig version="2.0" model="d2q9" output="{elsewhere}/">
+    <Geometry nx="16" ny="8"><MRT><Box/></MRT></Geometry>
+    <Model><Params Velocity="0.0" nu="0.1"/></Model>
+    <VTK Iterations="2"/>
+    <Solve Iterations="2"/>
+</CLBConfig>
+""".replace("{elsewhere}", str(tmp_path / "elsewhere")))
+    out = tmp_path / "given"
+    run_config(str(case), get_model("d2q9"), output=str(out) + "/")
+    assert list(out.glob("*_VTK_*.vti"))
+    assert not (tmp_path / "elsewhere").exists()

@@ -129,6 +129,34 @@ def read_vti(path: str) -> dict:
 # --------------------------------------------------------------------------- #
 
 
+#: --rehearse only: each case cut to (steps, size) small enough for the
+#: CPU and interpret mode, painted objects still inside the domain
+REHEARSAL = {
+    "karman.xml": (40, {"nx": 256}),
+    "karman_1024.xml": (20, {"nx": 256, "ny": 512}),
+    "3d_channel.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
+    "3d_channel_512.xml": (8, {"nx": 128, "ny": 16, "nz": 16}),
+    "drop_512.xml": (10, {"nx": 384, "ny": 384}),
+    "karman_4096.xml": (12, {"nx": 128, "ny": 256}),
+}
+
+
+def case_size(case: str) -> tuple:
+    """(number of nodes, ``<Solve>`` steps) that ``case`` asks for."""
+    root = ET.parse(case).getroot()
+    g = root.find("Geometry")
+    nodes = 1
+    for k in ("nx", "ny", "nz"):
+        nodes *= int(g.get(k, "1"))
+    return nodes, int(root.find("Solve").get("Iterations"))
+
+
+def run_argv(case: str, outdir: str, mesh: str | None = None) -> list:
+    """The command line of ``tclb run case`` writing under ``outdir``."""
+    a = ["run", case, "--output", outdir + "/"]
+    return a + ["--mesh", mesh] if mesh else a
+
+
 class Smoke:
     def __init__(self, rehearse: bool):
         from tclb_tpu import telemetry
@@ -137,11 +165,24 @@ class Smoke:
         self.failed: list[str] = []
         telemetry.subscribe(self.events.append)
 
-    # -- one `tclb run` ---------------------------------------------------- #
+    def case(self, name: str, solve: int | None = None,
+             handlers: bool = True) -> str:
+        """The case file a phase runs: ``example/<name>`` itself, or a
+        copy under OUT with ``<Solve>`` cut to ``solve`` steps (and, in a
+        rehearsal, everything cut to REHEARSAL's size)."""
+        src = os.path.join(EXAMPLE, name)
+        geometry = None
+        if self.rehearse:
+            steps, geometry = REHEARSAL[name]
+            if handlers:       # a run phase; an agreement keeps its length
+                solve = steps
+        if solve is None:
+            return src
+        tag = "cases" if handlers else "cases_bare"
+        return cut_case(src, os.path.join(OUT, tag, name), solve,
+                        handlers=handlers, geometry=geometry)
 
-    def argv(self, case: str, outdir: str, mesh: str | None = None) -> list:
-        a = ["run", case, "--output", outdir + "/"]
-        return a + ["--mesh", mesh] if mesh else a
+    # -- one `tclb run` ---------------------------------------------------- #
 
     def check_events(self, ev: list, engine: tuple, steps: int | None
                      ) -> dict:
@@ -184,17 +225,17 @@ class Smoke:
                 "later_calls_s": round(sum(e["dur_s"] for e in rest), 6),
                 "later_calls_steps": sum(int(e["iters"]) for e in rest)}
 
-    def run(self, name: str, case: str, engine: tuple, shape: tuple,
-            steps: int) -> None:
+    def run(self, name: str, case: str, engine: tuple) -> None:
         """`tclb run case` through the console script's main(); checks
         events and the last VTK file it wrote."""
         import numpy as np
         from tclb_tpu.__main__ import main as tclb_main
+        nodes, steps = case_size(case)
         outdir = os.path.join(OUT, name)
         shutil.rmtree(outdir, ignore_errors=True)
         mark = len(self.events)
         t0 = time.perf_counter()
-        rc = tclb_main(self.argv(case, outdir))
+        rc = tclb_main(run_argv(case, outdir))
         wall = time.perf_counter() - t0
         if rc != 0:
             raise AssertionError(f"tclb run exited {rc}")
@@ -208,15 +249,15 @@ class Smoke:
                                  f"expected iteration {steps}")
         arrays = read_vti(last)
         cells = arrays.pop("__cells__")
-        if cells != int(np.prod(shape)):
+        if cells != nodes:
             raise AssertionError(f"VTK holds {cells} cells, expected "
-                                 f"{int(np.prod(shape))}")
+                                 f"{nodes}")
         for qn, a in arrays.items():
             if a.dtype.kind == "f" and not np.isfinite(a).all():
                 raise AssertionError(f"{qn} is not finite in {last}")
         log = glob.glob(os.path.join(outdir, "*_Log.csv"))
         info.update(phase=name, case=os.path.relpath(case, HERE),
-                    shape=list(shape), total_s=round(wall, 3),
+                    nodes=nodes, total_s=round(wall, 3),
                     vtk=os.path.basename(last),
                     vtk_arrays=sorted(arrays), log_csv=bool(log))
         print(json.dumps(info), flush=True)
@@ -229,19 +270,18 @@ class Smoke:
         (host copy of the final populations, engine tag, lattice)."""
         import numpy as np
         from tclb_tpu.__main__ import build_parser, run_case
-        args = build_parser().parse_args(self.argv(case, outdir, mesh))
+        args = build_parser().parse_args(run_argv(case, outdir, mesh))
         mark = len(self.events)
-        old = os.environ.get("TCLB_FASTPATH")
+        before = os.environ.get("TCLB_FASTPATH")
         if xla:
             os.environ["TCLB_FASTPATH"] = "0"    # the plain reference
         try:
             solver = run_case(args)
         finally:
-            if xla:
-                if old is None:
-                    del os.environ["TCLB_FASTPATH"]
-                else:
-                    os.environ["TCLB_FASTPATH"] = old
+            if xla and before is None:
+                del os.environ["TCLB_FASTPATH"]
+            elif xla:
+                os.environ["TCLB_FASTPATH"] = before
         info = self.check_events(self.events[mark:],
                                  ("xla",) if xla else engine, None)
         if xla and info["engine"] != "xla":
@@ -252,15 +292,14 @@ class Smoke:
             raise AssertionError("non-finite populations")
         return f, info, lat
 
-    def agree(self, name: str, case: str, engine: tuple,
-              geometry: dict | None = None) -> None:
-        """AGREE_STEPS steps of ``case`` on the selected Pallas engine
-        and on the XLA step, same initial state; max |diff| <= TOL."""
+    def agree(self, name: str, file: str, engine: tuple) -> None:
+        """AGREE_STEPS steps of example ``file`` on the selected Pallas
+        engine and on the XLA step, same initial state; max |diff| <=
+        TOL."""
         import numpy as np
         outdir = os.path.join(OUT, name)
         shutil.rmtree(outdir, ignore_errors=True)
-        cut = cut_case(case, os.path.join(outdir, "case.xml"), AGREE_STEPS,
-                       handlers=False, geometry=geometry)
+        cut = self.case(file, AGREE_STEPS, handlers=False)
         fp, ip, lat = self.fields_after(cut, outdir, engine)
         # the flow has to have moved, or agreement would be vacuous
         umax = float(np.max(np.abs(np.asarray(lat.get_quantity("U")))))
@@ -278,15 +317,15 @@ class Smoke:
             raise AssertionError(f"{ip['engine']} vs XLA: max |diff| "
                                  f"{diff:.3e} > {TOL:.1e}")
 
-    def sharded(self, name: str, case: str, mesh: str, steps: int,
-                geometry: dict | None = None) -> None:
-        """``case`` on the device mesh and on device 0 alone: sharded
-        Pallas engine, one shard per device, agreement within TOL."""
+    def sharded(self, name: str, file: str, mesh: str) -> None:
+        """Example ``file`` on the device mesh and on device 0 alone:
+        sharded Pallas engine, one shard per device, agreement within
+        TOL."""
         import numpy as np
         outdir = os.path.join(OUT, name)
         shutil.rmtree(outdir, ignore_errors=True)
-        cut = cut_case(case, os.path.join(outdir, "case.xml"), steps,
-                       geometry=geometry)
+        cut = self.case(file)
+        steps = case_size(cut)[1]
         n = int(np.prod([int(v) for v in mesh.split("x")]))
         fm, im, lat = self.fields_after(cut, outdir, ("pallas_sharded[",),
                                         mesh=mesh)
@@ -301,7 +340,7 @@ class Smoke:
             cut, outdir, ("pallas_2d[", "pallas_resident["))
         diff = float(np.max(np.abs(fm - f1)))
         print(json.dumps({
-            "phase": name, "case": os.path.relpath(case, HERE),
+            "phase": name, "case": os.path.relpath(cut, HERE),
             "mesh": mesh, "engine": im["engine"], "steps": steps,
             "shards": len(shards),
             "shard_devices": sorted(str(d) for d in devs),
@@ -330,62 +369,25 @@ class Smoke:
 
 
 def one_chip(s: Smoke) -> None:
-    ex = lambda f: os.path.join(EXAMPLE, f)      # noqa: E731
-    tmp = lambda f: os.path.join(OUT, "cases", f)  # noqa: E731
-    if s.rehearse:
-        # tiny stand-ins cut from the same files: control flow only
-        karman = cut_case(ex("karman.xml"), tmp("karman.xml"), 40,
-                          geometry={"nx": 256})
-        k1024 = cut_case(ex("karman_1024.xml"), tmp("karman_1024.xml"), 20,
-                         geometry={"nx": 256, "ny": 512})
-        ch = cut_case(ex("3d_channel.xml"), tmp("3d_channel.xml"), 8,
-                      geometry={"nx": 128, "ny": 16, "nz": 8})
-        ch512 = cut_case(ex("3d_channel_512.xml"), tmp("3d_channel_512.xml"),
-                         8, geometry={"nx": 128, "ny": 16, "nz": 16})
-        drop = cut_case(ex("drop_512.xml"), tmp("drop_512.xml"), 10,
-                        geometry={"nx": 384, "ny": 384})
-        sizes = dict(karman=((100, 256), 40), k1024=((512, 256), 20),
-                     ch=((8, 16, 128), 8), ch512=((16, 16, 128), 8),
-                     drop=((384, 384), 10))
-        g2, g3, gk = ({"nx": 256, "ny": 512},
-                      {"nx": 128, "ny": 16, "nz": 8},
-                      {"nx": 384, "ny": 384})
-    else:
-        karman, k1024 = ex("karman.xml"), ex("karman_1024.xml")
-        ch = cut_case(ex("3d_channel.xml"), tmp("3d_channel.xml"), 1000)
-        ch512, drop = ex("3d_channel_512.xml"), ex("drop_512.xml")
-        sizes = dict(karman=((100, 1024), 10000), k1024=((1024, 1024), 2000),
-                     ch=((48, 48, 256), 1000), ch512=((512, 48, 256), 1000),
-                     drop=((512, 512), 1000))
-        g2 = g3 = gk = None
-    s.phase("2d_karman_as_shipped", s.run, karman,
-            ("pallas_resident[d2q9,", "pallas_2d[d2q9,"), *sizes["karman"])
-    s.phase("2d_karman_1024", s.run, k1024,
-            ("pallas_2d[d2q9,fuse=2]",), *sizes["k1024"])
-    s.phase("3d_channel", s.run, ch,
-            ("pallas_d3q[d3q27_cumulant,fuse=",), *sizes["ch"])
-    s.phase("3d_channel_512", s.run, ch512,
-            ("pallas_d3q[d3q27_cumulant,fuse=",), *sizes["ch512"])
     # the registry-driven generic engine, band or VMEM-resident flavour
     # (at 512x512 the Lattice picks the resident one)
     generic = ("pallas_generic[d2q9_kuper,fuse=",
                "pallas_resident_generic[d2q9_kuper,fuse=")
-    s.phase("generic_drop_512", s.run, drop, generic, *sizes["drop"])
-    s.phase("agree_d2q9", s.agree, ex("karman_1024.xml"),
-            ("pallas_2d[d2q9,",), geometry=g2)
-    s.phase("agree_d3q27_cumulant", s.agree, ex("3d_channel.xml"),
-            ("pallas_d3q[d3q27_cumulant,",), geometry=g3)
-    s.phase("agree_d2q9_kuper", s.agree, ex("drop_512.xml"), generic,
-            geometry=gk)
+    cumulant = ("pallas_d3q[d3q27_cumulant,fuse=",)
+    s.phase("2d_karman_as_shipped", s.run, s.case("karman.xml"),
+            ("pallas_resident[d2q9,", "pallas_2d[d2q9,"))
+    s.phase("2d_karman_1024", s.run, s.case("karman_1024.xml"),
+            ("pallas_2d[d2q9,fuse=2]",))
+    s.phase("3d_channel", s.run, s.case("3d_channel.xml", 1000), cumulant)
+    s.phase("3d_channel_512", s.run, s.case("3d_channel_512.xml"), cumulant)
+    s.phase("generic_drop_512", s.run, s.case("drop_512.xml"), generic)
+    s.phase("agree_d2q9", s.agree, "karman_1024.xml", ("pallas_2d[d2q9,",))
+    s.phase("agree_d3q27_cumulant", s.agree, "3d_channel.xml", cumulant)
+    s.phase("agree_d2q9_kuper", s.agree, "drop_512.xml", generic)
 
 
 def four_chips(s: Smoke) -> None:
-    case = os.path.join(EXAMPLE, "karman_4096.xml")
-    if s.rehearse:
-        s.phase("sharded_4x1", s.sharded, case, "4x1", 12,
-                geometry={"nx": 128, "ny": 256})
-    else:
-        s.phase("sharded_4x1", s.sharded, case, "4x1", 500)
+    s.phase("sharded_4x1", s.sharded, "karman_4096.xml", "4x1")
 
 
 def main(argv=None) -> int:

@@ -1146,7 +1146,8 @@ class Lattice:
                 # execution rather than compile would otherwise leave
                 # the real state's buffers deleted), retry down a
                 # smaller-band/no-fusion ladder, remember the verdict
-                # process-wide, and fall back to XLA if nothing fits.
+                # process-wide, and if nothing fits raise on a TPU
+                # backend (off it: fall back to XLA).
                 from tclb_tpu.ops import pallas_generic
                 from tclb_tpu.utils import log
 

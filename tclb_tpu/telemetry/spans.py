@@ -12,10 +12,11 @@ MLUPS line (reference src/main.cpp.Rt:100-126):
 
 * ``mlups``      — ``nodes * iters / dt / 1e6``;
 * ``vs_roofline`` — achieved fraction of this chip's HBM streaming
-  roofline (absent on a device kind whose bandwidth is not known) under the classical LBM traffic model (``bytes_per_node`` =
+  roofline under the classical LBM traffic model (``bytes_per_node`` =
   2 x n_storage x sizeof(real) + flag read per node update) — the same
   math bench.py gates its credibility asserts on (it imports
-  :data:`HBM_GBS` from here so the two can never drift).
+  :data:`HBM_GBS` from here so the two can never drift); absent on a
+  device kind whose bandwidth is not in that table.
 
 Spans also wrap ``jax.profiler.TraceAnnotation`` when available, so a
 concurrent ``jax.profiler`` capture shows the same region names.

@@ -559,9 +559,11 @@ class cbFailcheck(Handler):
                 continue
             if "all" not in names and q.name not in names:
                 continue
-            arr = np.asarray(s.lattice.get_quantity(q.name))
-            finite = np.isfinite(arr)
-            if not finite.all():
+            arr = s.quantity_host(q.name)
+            with telemetry.span("failcheck.scan", quantity=q.name):
+                finite = np.isfinite(arr)
+                all_finite = finite.all()
+            if not all_finite:
                 n_bad = int(arr.size - finite.sum())
                 log.warning(f"Failcheck: {q.name} has {n_bad} non-finite "
                             f"values at iteration {s.iter}")
@@ -923,7 +925,7 @@ class cbCatalyst(Handler):
         vmax = self.node.get("vmax")
         for q in what:
             q = q.strip()
-            a = np.asarray(s.lattice.get_quantity(q))
+            a = s.quantity_host(q)
             if a.ndim == len(s.shape) + 1:      # vector -> magnitude
                 a = np.sqrt((a ** 2).sum(axis=0))
             if a.ndim == 3:

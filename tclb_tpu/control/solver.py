@@ -220,6 +220,18 @@ class Solver:
 
     # -- output fan-out ------------------------------------------------------ #
 
+    def quantity_host(self, name: str) -> np.ndarray:
+        """One quantity over the lattice as a host array: the eager
+        quantity programs on the device (``quantity.eval``, fenced when
+        traced), then the copy, or on a mesh the gather, to the host
+        (``quantity.d2h``)."""
+        with telemetry.span("quantity.eval", quantity=name) as sp:
+            q = sp.sync(self.lattice.get_quantity(name))
+            sp.add(bytes=q.nbytes)
+        with telemetry.span("quantity.d2h", quantity=name,
+                            bytes=q.nbytes):
+            return np.asarray(q)
+
     def quantity_arrays(self, what: Optional[set[str]] = None
                         ) -> dict[str, np.ndarray]:
         """Evaluate selected quantities -> host arrays (reference
@@ -230,7 +242,7 @@ class Solver:
                 continue
             if what and q.name not in what and "all" not in what:
                 continue
-            out[q.name] = np.asarray(self.lattice.get_quantity(q.name))
+            out[q.name] = self.quantity_host(q.name)
         return out
 
     def write_geometry_vti(self) -> str:

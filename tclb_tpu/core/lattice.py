@@ -966,7 +966,8 @@ class Lattice:
             if it is not None:
                 if getattr(it, "uses_generic", False):
                     self._fast_probing = True
-                return it, f"pallas_sharded[{dict(self.mesh.shape)}]"
+                return it, (f"pallas_sharded[{dict(self.mesh.shape)},"
+                            f"fuse={it.fuse}]")
             return None, None
         if (not has_series
                 and pallas_d2q9.supports_resident(self.model, self.shape,
@@ -1136,200 +1137,211 @@ class Lattice:
         ok_series = (self.params.time_series is None
                      or getattr(fast, "supports_series", False))
         nfast = niter if full else niter - 1
-        if fast is not None and ok_series and nfast >= 1:
-            if self._fast_probing:
-                # the generic engine's trace probe cannot see Mosaic
-                # lowering gaps (e.g. a model using arccos) or
-                # scoped-VMEM overflows — those only surface at first
-                # TPU compile.  Probe on a COPY of the state (the
-                # engines donate their input; a failure that happens at
-                # execution rather than compile would otherwise leave
-                # the real state's buffers deleted), retry down a
-                # smaller-band/no-fusion ladder, remember the verdict
-                # process-wide, and if nothing fits raise on a TPU
-                # backend (off it: fall back to XLA).
-                from tclb_tpu.ops import pallas_generic
-                from tclb_tpu.utils import log
-
-                def attempt(it_fn):
-                    probe = jax.tree.map(jnp.copy, self.state)
-                    return it_fn(probe, self.params, nfast)
-
-                was_resident = (self._fast_name or "").startswith(
-                    "pallas_resident")
-                was_generic_res = (self._fast_name or "").startswith(
-                    "pallas_resident_generic")
-                was_d3q = (self._fast_name or "").startswith(
-                    "pallas_d3q[")
-                try:
-                    self.state = attempt(fast)
-                except Exception as e:  # noqa: BLE001
-                    if was_d3q:
-                        # fused (K>=2) tuned-3D probe failed — its
-                        # raised-ceiling scratch budget cannot see
-                        # Mosaic's compute temporaries.  The K=1 block
-                        # kernel is the proven engine for these models:
-                        # swap it in and continue this very call.
-                        failed = self._fast_name
-                        log.warning(f"engine: {self._fast_name} failed "
-                                    f"to compile ({e!r}); fuse=1 "
-                                    "d3q fallback")
-                        from tclb_tpu.ops import pallas_d3q
-                        present = pallas_d3q.present_types(
-                            self.model, self._flags_host())
-                        self._fast = fast = \
-                            pallas_d3q.make_pallas_iterate(
-                                self.model, self.shape, self.storage_dtype,
-                                present=present, fuse=1,
-                                shift=self._shift_vec)
-                        self._fast_name = (
-                            f"pallas_d3q[{self.model.name},fuse=1]")
-                        telemetry.engine_fallback(
-                            failed, self._fast_name, repr(e),
-                            model=self.model.name)
-                        self._fast_probing = False
-                        self.state = fast(self.state, self.params, nfast)
-                        if not full:
-                            self.state = self._iterate(
-                                self.state, self.params, 1)
-                        return
-                    if was_resident:
-                        # resident probe failed (its budget can't see
-                        # Mosaic temporaries): the band engine is the
-                        # proven fallback for these models — swap it in
-                        # and continue this very call.  Each resident
-                        # flavor falls back to ITS band family: the
-                        # tuned d2q9 resident to the tuned d2q9 band,
-                        # the generic resident to the generic band.
-                        failed = self._fast_name
-                        log.warning(f"engine: {self._fast_name} failed "
-                                    f"to compile ({e!r}); band "
-                                    "engine fallback")
-                        if was_generic_res:
-                            from tclb_tpu.ops.lbm import present_types
-                            present = present_types(self.model,
-                                                    self._flags_host())
-                            fz = (pallas_generic.choose_fuse_3d(
-                                self.model, self.shape,
-                                itemsize=jnp.dtype(
-                                    self.storage_dtype).itemsize)
-                                if self.model.ndim == 3
-                                else pallas_generic.choose_fuse(
-                                    self.model))
-                            self._fast = fast = \
-                                pallas_generic.make_pallas_iterate(
-                                    self.model, self.shape,
-                                    self.storage_dtype,
-                                    fuse=fz, present=present,
-                                    shift=self._shift_vec)
-                            self._fast_cfg = (fz, None)
-                            self._fast_name = (
-                                f"pallas_generic"
-                                f"[{self.model.name},fuse={fz}]")
-                        else:
-                            from tclb_tpu.ops import pallas_d2q9
-                            present = pallas_d2q9.present_types(
-                                self.model, self._flags_host())
-                            self._fast = fast = \
-                                pallas_d2q9.make_pallas_iterate(
-                                    self.model, self.shape, self.dtype,
-                                    fuse=2, present=present)
-                            self._fast_name = (f"pallas_2d"
-                                               f"[{self.model.name},"
-                                               f"fuse=2]")
-                        telemetry.engine_fallback(
-                            failed, self._fast_name, repr(e),
-                            model=self.model.name)
-                        self._fast_probing = False
-                        self.state = fast(self.state, self.params, nfast)
-                        if not full:
-                            self.state = self._iterate(
-                                self.state, self.params, 1)
-                        return
-                    failed = self._fast_name
-                    if self.mesh is not None:
-                        ladder = []   # sharded engine: no cap ladder
-                    else:
-                        log.warning(f"engine: {self._fast_name} first "
-                                    f"compile failed ({e!r}); "
-                                    "trying smaller bands")
-                        from tclb_tpu.ops.lbm import present_types
-                        present = present_types(self.model,
-                                                self._flags_host())
-                        fz0, _ = self._fast_cfg
-                        ladder = [(fz0, 16), (fz0, 8)]
-                        if fz0 >= 2:
-                            ladder += [(1, 16), (1, 8)]
-                        if self.model.ndim == 3:
-                            # last resort: raised scoped-vmem ceiling
-                            # (negative cap encodes it; ~2x slower
-                            # codegen, still ~3x the XLA path)
-                            ladder += [(fz0, -16), (fz0, -8)]
-                        ladder = [c for c in ladder
-                                  if c != self._fast_cfg]
-                    for fz, cap in ladder:
-                        try:
-                            it2 = pallas_generic.make_pallas_iterate(
-                                self.model, self.shape, self.storage_dtype,
-                                fuse=fz, present=present, by_cap=cap,
-                                shift=self._shift_vec)
-                            self.state = attempt(it2)
-                        except Exception as e2:  # noqa: BLE001
-                            log.warning(f"engine: pallas_generic fuse={fz} "
-                                        f"by<={cap} failed to compile "
-                                        f"({e2!r})")
-                            continue
-                        self._fast = fast = it2
-                        self._fast_cfg = (fz, cap)
-                        self._fast_name = (f"pallas_generic"
-                                           f"[{self.model.name},fuse={fz},"
-                                           f"by<={cap}]")
-                        telemetry.engine_fallback(
-                            failed, self._fast_name, repr(e),
-                            model=self.model.name)
-                        break
-                    else:
-                        if jax.default_backend() == "tpu":
-                            # on the chip a run that finishes in XLA
-                            # under a Pallas name is ~9x slower and
-                            # reads as a result: fail with the first
-                            # exception instead
-                            raise RuntimeError(
-                                f"engine {failed} and every smaller "
-                                "configuration under it failed to "
-                                f"compile on the TPU backend: {e!r}") from e
-                        log.warning(f"engine: {failed} failed to compile "
-                                    f"({e!r}); XLA fallback")
-                        telemetry.engine_fallback(
-                            failed, "xla", repr(e),
-                            model=self.model.name)
-                        if self.mesh is None:
-                            # the sharded probe exercised a DIFFERENT
-                            # kernel (local shard shape) — never poison
-                            # the single-device caches from it
-                            pallas_generic.set_mosaic_ok(self.model,
-                                                         self.shape,
-                                                         False)
-                        self._fast = fast = None
-                        self._fast_name = None
-                        self._fast_probing = False
-                        self.state = self._iterate(self.state, self.params,
-                                                   niter)
-                        return
-                if self.mesh is None and not was_resident \
-                        and not was_d3q:
-                    # verdict caches belong to the generic engine only
-                    pallas_generic.set_mosaic_ok(self.model, self.shape,
-                                                 True)
-                    pallas_generic.set_build_cfg(self.model, self.shape,
-                                                 *self._fast_cfg)
-                self._fast_probing = False
+        use_fast = fast is not None and ok_series and nfast >= 1
+        done = nfast if use_fast else niter
+        with telemetry.span("iterate.fused", iters=done) as sp:
+            if not use_fast:
+                self.state = self._iterate(self.state, self.params, niter)
+            elif self._fast_probing:
+                done = self._probe_first_call(fast, niter, nfast)
             else:
                 self.state = fast(self.state, self.params, nfast)
-            if not full:
+            sp.add(iters=done,
+                   engine=(self._fast_name if use_fast else None) or "xla")
+            sp.sync(self.state)
+        if done < niter:
+            # the hybrid engines' trailing XLA step, for the globals
+            with telemetry.span("iterate.globals_step", iters=1) as sp:
                 self.state = self._iterate(self.state, self.params, 1)
-        else:
-            self.state = self._iterate(self.state, self.params, niter)
+                sp.sync(self.state)
+
+    def _probe_first_call(self, fast, niter: int, nfast: int) -> int:
+        """The first call of an engine that has to be probed: run
+        ``nfast`` fused steps, stepping down the fallback chain where the
+        engine does not compile.  Returns the steps done: ``nfast``, or
+        ``niter`` where nothing compiled and XLA ran the whole chunk
+        (its last step has produced the globals)."""
+        # the generic engine's trace probe cannot see Mosaic
+        # lowering gaps (e.g. a model using arccos) or
+        # scoped-VMEM overflows — those only surface at first
+        # TPU compile.  Probe on a COPY of the state (the
+        # engines donate their input; a failure that happens at
+        # execution rather than compile would otherwise leave
+        # the real state's buffers deleted), retry down a
+        # smaller-band/no-fusion ladder, remember the verdict
+        # process-wide, and if nothing fits raise on a TPU
+        # backend (off it: fall back to XLA).
+        from tclb_tpu.ops import pallas_generic
+        from tclb_tpu.utils import log
+
+        def attempt(it_fn):
+            probe = jax.tree.map(jnp.copy, self.state)
+            return it_fn(probe, self.params, nfast)
+
+        was_resident = (self._fast_name or "").startswith(
+            "pallas_resident")
+        was_generic_res = (self._fast_name or "").startswith(
+            "pallas_resident_generic")
+        was_d3q = (self._fast_name or "").startswith(
+            "pallas_d3q[")
+        try:
+            self.state = attempt(fast)
+        except Exception as e:  # noqa: BLE001
+            if was_d3q:
+                # fused (K>=2) tuned-3D probe failed — its
+                # raised-ceiling scratch budget cannot see
+                # Mosaic's compute temporaries.  The K=1 block
+                # kernel is the proven engine for these models:
+                # swap it in and continue this very call.
+                failed = self._fast_name
+                log.warning(f"engine: {self._fast_name} failed "
+                            f"to compile ({e!r}); fuse=1 "
+                            "d3q fallback")
+                from tclb_tpu.ops import pallas_d3q
+                present = pallas_d3q.present_types(
+                    self.model, self._flags_host())
+                self._fast = fast = \
+                    pallas_d3q.make_pallas_iterate(
+                        self.model, self.shape, self.storage_dtype,
+                        present=present, fuse=1,
+                        shift=self._shift_vec)
+                self._fast_name = (
+                    f"pallas_d3q[{self.model.name},fuse=1]")
+                telemetry.engine_fallback(
+                    failed, self._fast_name, repr(e),
+                    model=self.model.name)
+                self._fast_probing = False
+                self.state = fast(self.state, self.params, nfast)
+                return nfast
+            if was_resident:
+                # resident probe failed (its budget can't see
+                # Mosaic temporaries): the band engine is the
+                # proven fallback for these models — swap it in
+                # and continue this very call.  Each resident
+                # flavor falls back to ITS band family: the
+                # tuned d2q9 resident to the tuned d2q9 band,
+                # the generic resident to the generic band.
+                failed = self._fast_name
+                log.warning(f"engine: {self._fast_name} failed "
+                            f"to compile ({e!r}); band "
+                            "engine fallback")
+                if was_generic_res:
+                    from tclb_tpu.ops.lbm import present_types
+                    present = present_types(self.model,
+                                            self._flags_host())
+                    fz = (pallas_generic.choose_fuse_3d(
+                        self.model, self.shape,
+                        itemsize=jnp.dtype(
+                            self.storage_dtype).itemsize)
+                        if self.model.ndim == 3
+                        else pallas_generic.choose_fuse(
+                            self.model))
+                    self._fast = fast = \
+                        pallas_generic.make_pallas_iterate(
+                            self.model, self.shape,
+                            self.storage_dtype,
+                            fuse=fz, present=present,
+                            shift=self._shift_vec)
+                    self._fast_cfg = (fz, None)
+                    self._fast_name = (
+                        f"pallas_generic"
+                        f"[{self.model.name},fuse={fz}]")
+                else:
+                    from tclb_tpu.ops import pallas_d2q9
+                    present = pallas_d2q9.present_types(
+                        self.model, self._flags_host())
+                    self._fast = fast = \
+                        pallas_d2q9.make_pallas_iterate(
+                            self.model, self.shape, self.dtype,
+                            fuse=2, present=present)
+                    self._fast_name = (f"pallas_2d"
+                                       f"[{self.model.name},"
+                                       f"fuse=2]")
+                telemetry.engine_fallback(
+                    failed, self._fast_name, repr(e),
+                    model=self.model.name)
+                self._fast_probing = False
+                self.state = fast(self.state, self.params, nfast)
+                return nfast
+            failed = self._fast_name
+            if self.mesh is not None:
+                ladder = []   # sharded engine: no cap ladder
+            else:
+                log.warning(f"engine: {self._fast_name} first "
+                            f"compile failed ({e!r}); "
+                            "trying smaller bands")
+                from tclb_tpu.ops.lbm import present_types
+                present = present_types(self.model,
+                                        self._flags_host())
+                fz0, _ = self._fast_cfg
+                ladder = [(fz0, 16), (fz0, 8)]
+                if fz0 >= 2:
+                    ladder += [(1, 16), (1, 8)]
+                if self.model.ndim == 3:
+                    # last resort: raised scoped-vmem ceiling
+                    # (negative cap encodes it; ~2x slower
+                    # codegen, still ~3x the XLA path)
+                    ladder += [(fz0, -16), (fz0, -8)]
+                ladder = [c for c in ladder
+                          if c != self._fast_cfg]
+            for fz, cap in ladder:
+                try:
+                    it2 = pallas_generic.make_pallas_iterate(
+                        self.model, self.shape, self.storage_dtype,
+                        fuse=fz, present=present, by_cap=cap,
+                        shift=self._shift_vec)
+                    self.state = attempt(it2)
+                except Exception as e2:  # noqa: BLE001
+                    log.warning(f"engine: pallas_generic fuse={fz} "
+                                f"by<={cap} failed to compile "
+                                f"({e2!r})")
+                    continue
+                self._fast = fast = it2
+                self._fast_cfg = (fz, cap)
+                self._fast_name = (f"pallas_generic"
+                                   f"[{self.model.name},fuse={fz},"
+                                   f"by<={cap}]")
+                telemetry.engine_fallback(
+                    failed, self._fast_name, repr(e),
+                    model=self.model.name)
+                break
+            else:
+                if jax.default_backend() == "tpu":
+                    # on the chip a run that finishes in XLA
+                    # under a Pallas name is ~9x slower and
+                    # reads as a result: fail with the first
+                    # exception instead
+                    raise RuntimeError(
+                        f"engine {failed} and every smaller "
+                        "configuration under it failed to "
+                        f"compile on the TPU backend: {e!r}") from e
+                log.warning(f"engine: {failed} failed to compile "
+                            f"({e!r}); XLA fallback")
+                telemetry.engine_fallback(
+                    failed, "xla", repr(e),
+                    model=self.model.name)
+                if self.mesh is None:
+                    # the sharded probe exercised a DIFFERENT
+                    # kernel (local shard shape) — never poison
+                    # the single-device caches from it
+                    pallas_generic.set_mosaic_ok(self.model,
+                                                 self.shape,
+                                                 False)
+                self._fast = fast = None
+                self._fast_name = None
+                self._fast_probing = False
+                self.state = self._iterate(self.state, self.params,
+                                           niter)
+                return niter
+        if self.mesh is None and not was_resident \
+                and not was_d3q:
+            # verdict caches belong to the generic engine only
+            pallas_generic.set_mosaic_ok(self.model, self.shape,
+                                         True)
+            pallas_generic.set_build_cfg(self.model, self.shape,
+                                         *self._fast_cfg)
+        self._fast_probing = False
+        return nfast
 
     def attach_sampler(self, sampler) -> None:
         """Register a point sampler: every subsequent step also gathers its

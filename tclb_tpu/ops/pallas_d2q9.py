@@ -316,6 +316,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
             pltpu.VMEM((n_storage, ny, nx), dtype),
         ],
         interpret=interpret,
+        name=f"d2q9_resident_fuse{_RESIDENT_FUSE}",
     )
 
     zshift = model.zone_shift
@@ -770,6 +771,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             pltpu.SemaphoreType.DMA((6,)),
         ],
         interpret=interpret,
+        name="d2q9_band_fuse2",
     )
 
     call = pl.pallas_call(
@@ -793,6 +795,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
         interpret=interpret,
+        name="d2q9_band_fuse1",
     )
 
     if ext_halo:
@@ -826,9 +829,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         def refresh(fields):
             if not pad:
                 return fields
-            f = fields.at[:, ny_phys:ny_phys + 2, :].set(fields[:, 0:2, :])
-            return f.at[:, ny - 2:, :].set(
-                fields[:, ny_phys - 2:ny_phys, :])
+            with jax.named_scope("d2q9_ghost_refresh"):
+                f = fields.at[:, ny_phys:ny_phys + 2, :].set(
+                    fields[:, 0:2, :])
+                return f.at[:, ny - 2:, :].set(
+                    fields[:, ny_phys - 2:ny_phys, :])
 
         if fuse == 2:
             aux = jnp.stack([flags_i32.astype(dtype), vel, den])

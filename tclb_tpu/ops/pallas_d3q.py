@@ -673,6 +673,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 pltpu.SemaphoreType.DMA((2,)),
             ],
             interpret=interpret,
+            name="d3q_ring_fuse1",
         )
     else:
         call = pl.pallas_call(
@@ -696,6 +697,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 pltpu.SemaphoreType.DMA((2, 4)),
             ],
             interpret=interpret,
+            name="d3q_slab_fuse1",
         )
 
     if ext_halo:
@@ -859,6 +861,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+            name=f"d3q_slab_fuse{K}",
         )
 
     @partial(jax.jit, static_argnames=("niter",), donate_argnums=0)

@@ -812,6 +812,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 vmem_limit_bytes=vmem_mb * 1024 * 1024)
             if vmem_mb else None,
             interpret=interpret,
+            name=f"generic_band_fuse{fuse if plan_n is plan else 1}",
         )
 
     if ext_halo:
@@ -1114,6 +1115,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=120 * 1024 * 1024),
             interpret=interpret,
+            name=f"generic_resident_fuse{nsteps}",
         )
 
     zshift = model.zone_shift
@@ -1544,6 +1546,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                 vmem_limit_bytes=100 * 1024 * 1024)
             if vmem_ceiling else None,
             interpret=interpret,
+            name=f"generic_slab_fuse{fuse if plan_k is plan else 1}",
         )
 
     call = _mk_call(plan, R, lean=lean_aux)

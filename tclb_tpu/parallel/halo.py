@@ -70,6 +70,28 @@ def _exchange_axis(block: jnp.ndarray, name: str, axis: int, width: int,
     return jnp.concatenate([lo_halo, block, hi_halo], axis=axis)
 
 
+def _exchange_bytes(shape, axis: int, width: int, n: int, planes: int,
+                    itemsize: int) -> int:
+    """Bytes one chip sends in one :func:`_exchange_axis` of a block of
+    ``shape`` (planes first): ``width`` cells of ``planes`` planes to
+    each of its two neighbors, ``2 x width x plane size x planes x
+    itemsize``; nothing on a size-1 mesh axis, where no byte leaves the
+    chip."""
+    if n == 1:
+        return 0
+    plane = int(np.prod(shape[1:])) // int(shape[axis])
+    return 2 * width * plane * planes * itemsize
+
+
+def _count_halo(exchanges: int, nbytes: int) -> None:
+    """The host-side account of one sharded ``iterate`` call: the
+    counters, and ``halo_bytes`` on the enclosing span (the Lattice's
+    ``iterate.fused`` or ``iterate.globals_step``)."""
+    telemetry.counter("halo.exchanges", exchanges)
+    telemetry.counter("halo.bytes", nbytes)
+    telemetry.annotate(halo_bytes=nbytes)
+
+
 def halo_pad(block: jnp.ndarray, mesh: Mesh, width: int,
              start_axis: int = 1) -> jnp.ndarray:
     """Extend a local block with halos on every lattice axis (all planes).
@@ -126,6 +148,35 @@ class HaloStreaming(Streaming):
                 idx.append(slice(start, start + local[k]))
             out.append(padded[(i, *idx)])
         return jnp.stack(out)
+
+    def bytes_per_step(self, local_shape, itemsize: int,
+                       action: str = "Iteration") -> int:
+        """Bytes one chip sends in one step of ``action``, from the
+        shapes: each streaming stage's :meth:`pull` (the planes that
+        cross each split axis) and, where a Field declares a stencil,
+        each stage's :func:`halo_pad` of the raw stack."""
+        n_storage = self.model.n_storage
+
+        def sent(planes_on) -> int:
+            """One pass of exchanges over the mesh axes, each extending
+            the block the next one ships."""
+            shape, total = [n_storage, *local_shape], 0
+            for k, name in enumerate(self.mesh.axis_names):
+                planes = planes_on(name)
+                if planes:
+                    total += _exchange_bytes(
+                        shape, 1 + k, self.width, self.mesh.shape[name],
+                        planes, itemsize)
+                    shape[1 + k] += 2 * self.width
+            return total
+
+        pull = sent(lambda name: 0 if self._send[name] is None
+                    else len(self._send[name]))
+        pad = sent(lambda name: n_storage) if self._needs_loader else 0
+        stages = [self.model.stages[st]
+                  for st in self.model.actions[action]]
+        return (pull * sum(bool(st.load_densities) for st in stages)
+                + pad * len(stages))
 
     def make_loader(self, raw: jnp.ndarray) -> Callable:
         if not self._needs_loader:
@@ -237,12 +288,20 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         zonal_si = [si[nm] for nm in zonal_names]
         width = 1
     zshift = model.zone_shift
+    # bytes one chip sends per exchange of one plane and of the fields;
+    # the planes of the aux stack, which is exchanged once per call
+    plane_bytes = _exchange_bytes((1,) + local, 1, width, n, 1,
+                                  jnp.dtype(dtype).itemsize)
+    field_bytes = model.n_storage * plane_bytes
+    aux_planes = (3 if mode == "tuned2d"
+                  else 1 + len(gz_si) if mode == "generic2d" else 0)
 
     def exch(arr):
         """Prepend/append ``width`` halo rows/slabs from the torus
         neighbors along the sharded axis (identity wrap when n == 1) —
         the shared halo-exchange primitive, axis 1 = the band axis."""
-        return _exchange_axis(arr, axis, 1, width, n)
+        with jax.named_scope("halo_exchange"):
+            return _exchange_axis(arr, axis, 1, width, n)
 
     state_specs = LatticeState(
         fields=field_spec(mesh), flags=flag_spec(mesh),
@@ -307,23 +366,24 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         if params.time_series is not None:
             raise ValueError(
                 "pallas iterate does not support Control time series")
-        if not telemetry.enabled():
-            return _for_niter(int(niter))(state, params)
-        # one ppermute halo exchange per step along the band axis (plus
-        # one aux-stack exchange per chunk) — counted host-side; the
-        # per-step wall time is the enclosing iterate span's business
-        with telemetry.span("halo.sharded_pallas_iterate",
-                            iters=int(niter), mode=mode or "tuned3d",
-                            mesh=dict(mesh.shape)) as sp:
-            out = _for_niter(int(niter))(state, params)
-            sp.sync(out.fields)
-        telemetry.counter("halo.exchanges", int(niter))
+        out = _for_niter(int(niter))(state, params)
+        if telemetry.enabled():
+            # counted host-side from the shapes: one exchange of the
+            # fields per kernel call (a fused pair of steps in the tuned
+            # 2D mode) plus the aux stack once per call; the wall time is
+            # the enclosing span's business
+            calls = (int(niter) // 2 + int(niter) % 2
+                     if mode == "tuned2d" else int(niter))
+            _count_halo(int(niter),
+                        calls * field_bytes + aux_planes * plane_bytes)
         return out
 
     # the generic-kernel building block is capability-probed, not proven:
     # the Lattice dispatch probes its first call and falls back to the
     # sharded XLA engine on a Mosaic lowering failure
     iterate.uses_generic = (mode == "generic2d")
+    # steps per kernel call, for the engine tag
+    iterate.fuse = 2 if mode == "tuned2d" else 1
     return iterate
 
 
@@ -383,13 +443,13 @@ def make_sharded_iterate(model: Model, mesh: Mesh,
             # psum of the already-reduced globals would scale them by the
             # device count)
             return state
-        if not telemetry.enabled():
-            return _for_niter(int(niter))(state, params)
-        with telemetry.span("halo.sharded_iterate", iters=int(niter),
-                            mesh=dict(mesh.shape)) as sp:
-            out = _for_niter(int(niter))(state, params)
-            sp.sync(out.fields)
-        telemetry.counter("halo.exchanges", int(niter) * n_exch)
+        out = _for_niter(int(niter))(state, params)
+        if telemetry.enabled():
+            local = [d // mesh.shape[a]
+                     for d, a in zip(out.fields.shape[1:], names)]
+            _count_halo(int(niter) * n_exch,
+                        int(niter) * streaming.bytes_per_step(
+                            local, out.fields.dtype.itemsize, action))
         return out
 
     return iterate

@@ -620,6 +620,13 @@ class WorkerPool:
     def _reap(self, w: _Worker, reason: str) -> str:
         w.state = "respawning"
         proc = w.proc
+        if proc is not None and reason == "exit" and proc.poll() is None:
+            # the pipe's end arrives before the exit status does: wait
+            # for the exit it announces before calling it a kill
+            try:
+                proc.wait(timeout=self.term_grace_s)
+            except subprocess.TimeoutExpired:
+                pass
         if proc is not None and proc.poll() is None:
             self._kill_proc(w, reason)
         else:

@@ -10,6 +10,8 @@ Usage (a trace costs nothing unless asked for):
   sp.sync(out)`` — honest wall-time (``block_until_ready`` fencing),
   MLUPS / vs-roofline derived metrics, ``jax.profiler.TraceAnnotation``
   passthrough;
+* ``telemetry.annotate(**fields)`` — add fields to the innermost open
+  span from a callee that has none of its own;
 * ``telemetry.counter(name)`` — monotonic counters, snapshotted
   periodically and flushed on close;
 * ``telemetry.subscribe(fn)`` — fan the event stream out to extra sinks
@@ -26,5 +28,5 @@ from tclb_tpu.telemetry.events import (  # noqa: F401
     engine_fallback, engine_selected, event, failcheck, job_context,
     path, set_job, subscribe, unsubscribe)
 from tclb_tpu.telemetry.spans import (  # noqa: F401
-    HBM_GBS, NOOP_SPAN, Span, device_kind, fuse_of, roofline_mlups,
-    span)
+    HBM_GBS, NOOP_SPAN, Span, annotate, device_kind, fuse_of,
+    roofline_mlups, span)

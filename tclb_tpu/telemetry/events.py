@@ -57,6 +57,7 @@ _counters: dict[str, float] = {}
 _counters_last_emit = 0.0           # monotonic ts of the last snapshot
 _atexit_registered = False
 _job_local = threading.local()      # per-thread active job id (correlation)
+_span_local = threading.local()     # per-thread stack of open spans
 
 
 def enabled() -> bool:
@@ -102,6 +103,7 @@ def subscribe(fn: Callable[[dict], None]) -> None:
     with _lock:
         if fn not in _subscribers:
             _subscribers.append(fn)
+        _listen_for_compiles()
         _enabled = True
 
 
@@ -180,12 +182,28 @@ def disable() -> None:
         _counters.clear()
 
 
+def span_stack() -> list:
+    """The spans open on this thread, outermost first (``spans.Span``
+    pushes and pops; only touched while telemetry is enabled)."""
+    try:
+        return _span_local.stack
+    except AttributeError:
+        _span_local.stack = []
+        return _span_local.stack
+
+
 def event(kind: str, **fields: Any) -> None:
-    """Emit one structured event; silently a no-op when disabled."""
+    """Emit one structured event; silently a no-op when disabled.  An
+    event other than a span's own, emitted while a span is open on this
+    thread, carries that span's ``id`` as ``parent``."""
     if not _enabled:
         return
     doc = {"kind": kind, "ts": round(time.time(), 6)}
     doc.update(fields)
+    if kind != "span":
+        stack = span_stack()
+        if stack:
+            doc.setdefault("parent", stack[-1].id)
     with _lock:
         _maybe_snapshot_counters_locked()
         _fanout_locked(doc)
@@ -224,6 +242,54 @@ def _maybe_snapshot_counters_locked() -> None:
     _counters_last_emit = now
     _fanout_locked({"kind": "counters", "ts": round(time.time(), 6),
                     "counters": dict(_counters)})
+
+
+# -- compilations ------------------------------------------------------------- #
+# jax.monitoring hands out every trace, lowering, backend compile and
+# persistent-cache load of the process with the function's name.  The
+# listeners are registered once, when the first subscriber arrives, and
+# gate on the same boolean as everything else here.  On jax 0.9.0 the
+# backend_compile duration wraps the persistent-cache lookup, so a load
+# shows as a short backend_compile with a cache_load beside it.
+
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_CACHE_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+_compile_listening = False
+
+
+def _on_compile_duration(name: str, dur_s: float, **kw: Any) -> None:
+    if not _enabled:
+        return
+    stage = _COMPILE_STAGES.get(name)
+    if stage is not None:
+        event("compile", stage=stage, fun_name=kw.get("fun_name"),
+              dur_s=round(dur_s, 6))
+
+
+def _on_compile_event(name: str, **kw: Any) -> None:
+    if not _enabled:
+        return
+    key = _CACHE_COUNTERS.get(name)
+    if key is not None:
+        counter(key)
+
+
+def _listen_for_compiles() -> None:
+    global _compile_listening
+    if _compile_listening:
+        return
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    monitoring.register_event_listener(_on_compile_event)
+    _compile_listening = True
 
 
 # -- job correlation ---------------------------------------------------------- #

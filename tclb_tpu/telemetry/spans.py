@@ -20,10 +20,20 @@ MLUPS line (reference src/main.cpp.Rt:100-126):
 
 Spans also wrap ``jax.profiler.TraceAnnotation`` when available, so a
 concurrent ``jax.profiler`` capture shows the same region names.
+
+Spans nest.  Each carries ``id`` (a process-wide counter), ``parent``
+(the ``id`` of the span open on this thread when it was entered, None at
+the top) and ``t0`` (its start, on the wall clock of ``ts``), and a child
+inherits ``iteration`` and ``job_id`` from its parent unless it is given
+its own, so every span of one segment shares that identifier.  Non-span
+events emitted inside a span are stamped with its ``id`` as ``parent``
+(:func:`events.event`).  A span's *self time* is ``dur_s`` minus the
+union of its children's ``[t0, t0 + dur_s]`` intervals.
 """
 
 from __future__ import annotations
 
+import itertools
 import re
 import time
 from typing import Any, Optional
@@ -38,6 +48,10 @@ HBM_GBS = {"TPU v5 lite": 819.0, "TPU v5e": 819.0,
            "TPU v6 lite": 1640.0, "TPU v6e": 1640.0}
 
 _device_kind_cache: Optional[tuple] = None
+_ids = itertools.count(1)           # span ids, process-wide
+
+#: identifiers a child span takes from its parent unless given its own
+INHERITED = ("iteration", "job_id")
 
 
 def device_kind() -> str:
@@ -84,12 +98,14 @@ class Span:
     Only constructed when telemetry is enabled (use :func:`span`, which
     returns the shared no-op otherwise), so it may import jax freely."""
 
-    __slots__ = ("name", "fields", "_t0", "_annotation")
+    __slots__ = ("name", "fields", "id", "parent", "t0", "_t0",
+                 "_annotation")
 
     def __init__(self, name: str, fields: dict):
         self.name = name
         self.fields = fields
-        self._t0 = 0.0
+        self.id = self.parent = None
+        self.t0 = self._t0 = 0.0
         self._annotation = None
 
     def add(self, **fields: Any) -> None:
@@ -103,12 +119,22 @@ class Span:
         return jax.block_until_ready(x)
 
     def __enter__(self) -> "Span":
+        stack = events.span_stack()
+        self.id = next(_ids)
+        if stack:
+            outer = stack[-1]
+            self.parent = outer.id
+            for key in INHERITED:
+                if key not in self.fields and key in outer.fields:
+                    self.fields[key] = outer.fields[key]
+        stack.append(self)
         try:
             from jax.profiler import TraceAnnotation
             self._annotation = TraceAnnotation(self.name)
             self._annotation.__enter__()
         except Exception:  # noqa: BLE001 — profiler is optional garnish
             self._annotation = None
+        self.t0 = time.time()
         self._t0 = time.perf_counter()
         return self
 
@@ -119,6 +145,9 @@ class Span:
                 self._annotation.__exit__(exc_type, exc, tb)
             except Exception:  # noqa: BLE001
                 pass
+        stack = events.span_stack()
+        if self in stack:           # drop it and anything left open inside
+            del stack[stack.index(self):]
         fields = self.fields
         if exc is not None:
             fields["ok"] = False
@@ -137,7 +166,8 @@ class Span:
                         fields["mlups"] / ceiling, 4)
                 fields["roofline_known"] = ceiling is not None
                 fields["device_kind"] = device_kind()
-        events.event("span", name=self.name, dur_s=round(dt, 6), **fields)
+        events.event("span", name=self.name, id=self.id, parent=self.parent,
+                     t0=round(self.t0, 6), dur_s=round(dt, 6), **fields)
         return False
 
 
@@ -169,3 +199,14 @@ def span(name: str, **fields: Any):
     if not events.enabled():
         return NOOP_SPAN
     return Span(name, fields)
+
+
+def annotate(**fields: Any) -> None:
+    """Add fields to the innermost span open on this thread, so that a
+    callee can say what it did without a span of its own; nothing when
+    telemetry is disabled or no span is open."""
+    if not events.enabled():
+        return
+    stack = events.span_stack()
+    if stack:
+        stack[-1].add(**fields)

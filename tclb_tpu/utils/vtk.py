@@ -21,6 +21,8 @@ from typing import Iterable
 
 import numpy as np
 
+from tclb_tpu import telemetry
+
 
 def _vtk_type(a: np.ndarray) -> str:
     return {
@@ -71,36 +73,41 @@ def write_vti(path: str, arrays: dict[str, np.ndarray],
         f'<Piece Extent="{extent}">',
         "<CellData>",
     ]
-    offset = 0
+    offset = bytes_in = 0
     blocks: list[bytes] = []
-    for name, a in norm.items():
-        ncomp = a.shape[0] if a.ndim == 4 else 1
-        if a.ndim == 4:
-            flat = np.ascontiguousarray(np.moveaxis(a, 0, -1))
-        else:
-            flat = np.ascontiguousarray(a)
-        raw = flat.tobytes()
-        head.append(
-            f'<DataArray type="{_vtk_type(a)}" Name="{name}" '
-            f'NumberOfComponents="{ncomp}" format="appended" '
-            f'offset="{offset}"/>')
-        if compress:
-            from tclb_tpu.native import zlib_blocks
-            blocks.append(zlib_blocks(raw))
-        else:
-            blocks.append(struct.pack("<I", len(raw)) + raw)
-        offset += len(blocks[-1])
+    with telemetry.span("output.vtk.encode", compress=compress) as sp:
+        for name, a in norm.items():
+            ncomp = a.shape[0] if a.ndim == 4 else 1
+            if a.ndim == 4:
+                flat = np.ascontiguousarray(np.moveaxis(a, 0, -1))
+            else:
+                flat = np.ascontiguousarray(a)
+            raw = flat.tobytes()
+            head.append(
+                f'<DataArray type="{_vtk_type(a)}" Name="{name}" '
+                f'NumberOfComponents="{ncomp}" format="appended" '
+                f'offset="{offset}"/>')
+            if compress:
+                from tclb_tpu.native import zlib_blocks
+                blocks.append(zlib_blocks(raw))
+            else:
+                blocks.append(struct.pack("<I", len(raw)) + raw)
+            offset += len(blocks[-1])
+            bytes_in += len(raw)
+        sp.add(bytes_in=bytes_in, bytes_out=offset)
     head += ["</CellData>", "</Piece>", "</ImageData>",
              '<AppendedData encoding="raw">']
     if not path.endswith(".vti"):
         path += ".vti"
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    with open(path, "wb") as f:
-        f.write("\n".join(head).encode())
-        f.write(b"\n_")
-        for b in blocks:
-            f.write(b)
-        f.write(b"\n</AppendedData>\n</VTKFile>\n")
+    with telemetry.span("output.vtk.file") as sp:
+        with open(path, "wb") as f:
+            f.write("\n".join(head).encode())
+            f.write(b"\n_")
+            for b in blocks:
+                f.write(b)
+            f.write(b"\n</AppendedData>\n</VTKFile>\n")
+            sp.add(bytes=f.tell())
     return path
 
 

@@ -90,8 +90,12 @@ def test_d2q9_band_fused_1024(one_chip):
     it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
                                          interpret=False, fuse=2,
                                          present=present)
-    # 5 steps: two fused pairs + the single-step kernel for the odd one
-    assert "tpu_custom_call" in _compile(it, lat, 5, one_chip)
+    # 5 steps: two fused pairs + the single-step kernel for the odd one,
+    # each under the name of its family and depth (what a trace shows)
+    text = _compile(it, lat, 5, one_chip)
+    assert "tpu_custom_call" in text
+    assert "d2q9_band_fuse2/pallas_call" in text
+    assert "d2q9_band_fuse1/pallas_call" in text
 
 
 @pytest.mark.parametrize("fuse", [None, 1], ids=["fused", "fuse1"])
@@ -103,7 +107,10 @@ def test_d3q27_cumulant_48x48x256(one_chip, fuse):
     it = pallas_d3q.make_pallas_iterate(m, shape, jnp.float32,
                                         interpret=False, present=present,
                                         fuse=fuse)
-    assert "tpu_custom_call" in _compile(it, lat, 6, one_chip)
+    text = _compile(it, lat, 6, one_chip)
+    assert "tpu_custom_call" in text
+    assert re.search(r"d3q_(slab|ring)_fuse%s/pallas_call"
+                     % (fuse or r"[2-9]"), text)
 
 
 @pytest.mark.parametrize("name", ["d2q9_kuper", "d2q9_heat"])
@@ -113,7 +120,10 @@ def test_generic_512(one_chip, name):
     it = pallas_generic.make_pallas_iterate(
         m, shape, jnp.float32, interpret=False,
         fuse=pallas_generic.choose_fuse(m), present=present)
-    assert "tpu_custom_call" in _compile(it, lat, 4, one_chip)
+    text = _compile(it, lat, 4, one_chip)
+    assert "tpu_custom_call" in text
+    # 4 steps are fewer than the fused depth: the remainder kernel
+    assert "generic_band_fuse1/pallas_call" in text
 
 
 def test_sharded_d2q9_4096_on_4x1_mesh(topo):
@@ -145,6 +155,8 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo):
         state, params).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+    assert "d2q9_band_fuse2/pallas_call" in text
+    assert "halo_exchange/" in text
 
 
 def test_generic_d3q19_heat_builder_defaults(one_chip):

@@ -426,3 +426,216 @@ def test_set_level_rejects_unknown():
         assert log._threshold == log.LEVELS["warning"]
     finally:
         log._threshold = old
+
+
+# --------------------------------------------------------------------------- #
+# The span tree: id / parent / t0, phase spans, compile events, halo bytes
+# --------------------------------------------------------------------------- #
+
+
+@pytest.fixture
+def seen():
+    """The event documents of a test, through a subscriber of its own."""
+    docs = []
+    telemetry.subscribe(docs.append)
+    yield docs
+    telemetry.unsubscribe(docs.append)
+
+
+def _spans(docs, name=None):
+    return [e for e in docs if e["kind"] == "span"
+            and name in (None, e["name"])]
+
+
+def test_span_tree_ids_parents_and_inherited_iteration(seen):
+    import threading
+
+    def segment(iteration, job):
+        with telemetry.job_context(job):
+            with telemetry.span("outer", iteration=iteration, job_id=job):
+                with telemetry.span("middle"):
+                    telemetry.event("note", x=1)
+                    with telemetry.span("inner", iteration=iteration + 1):
+                        telemetry.annotate(halo_bytes=7)
+                telemetry.annotate(tagged="outer")
+
+    threads = [threading.Thread(target=segment, args=(10 * k, f"j{k}"))
+               for k in (1, 2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+
+    spans = _spans(seen)
+    assert len({e["id"] for e in spans}) == 6          # process-wide ids
+    for job, it in (("j1", 10), ("j2", 20)):
+        mine = {e["name"]: e for e in spans if e["job_id"] == job}
+        outer, middle, inner = mine["outer"], mine["middle"], mine["inner"]
+        assert outer["parent"] is None
+        assert middle["parent"] == outer["id"]
+        assert inner["parent"] == middle["id"]         # never the other thread's
+        # a child takes its parent's identifiers unless it is given one
+        assert (outer["iteration"], middle["iteration"],
+                inner["iteration"]) == (it, it, it + 1)
+        assert inner["halo_bytes"] == 7 and outer["tagged"] == "outer"
+        assert "halo_bytes" not in middle
+        for e in (outer, middle, inner):
+            assert e["t0"] <= e["ts"]
+            assert e["t0"] + e["dur_s"] == pytest.approx(e["ts"], abs=0.05)
+        assert outer["t0"] <= middle["t0"] <= inner["t0"]
+        note = [e for e in seen if e["kind"] == "note"
+                and e["parent"] == middle["id"]]
+        assert len(note) == 1
+    # outside any span: no parent stamped, annotate goes nowhere
+    telemetry.event("note", x=2)
+    telemetry.annotate(lost=True)
+    assert "parent" not in seen[-1]
+
+
+_SOLVE_XML = """<CLBConfig output="{out}/">
+<Geometry nx="128" ny="64"><MRT><Box/></MRT>
+<WVelocity name="Inlet"><Inlet/></WVelocity>
+<EPressure name="Outlet"><Outlet/></EPressure>
+<Inlet nx="1" dx="2"><Box/></Inlet><Outlet nx="1" dx="-2"><Box/></Outlet>
+<Wall mask="ALL"><Channel/></Wall></Geometry>
+<Model><Params Velocity="0.01"/><Params nu="0.05"/></Model>
+<Failcheck Iterations="3"/><VTK Iterations="6" compress="true"/>
+<Solve Iterations="6"/></CLBConfig>"""
+
+
+def test_solve_emits_phase_spans_with_the_right_parents(
+        seen, tmp_path, monkeypatch):
+    """A tiny <Solve> with <Failcheck> and a compressed <VTK>, on a
+    Pallas engine in interpret mode: every phase span of the segment,
+    each under the span that issued it, all sharing its iteration."""
+    from tclb_tpu.control import run_config_string
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    run_config_string(_SOLVE_XML.format(out=tmp_path), get_model("d2q9"))
+    spans = _spans(seen)
+    by_id = {e["id"]: e for e in spans}
+
+    def parent_of(e):
+        return by_id[e["parent"]] if e["parent"] else None
+
+    for it in _spans(seen, "iterate"):
+        kids = [e for e in spans if e["parent"] == it["id"]]
+        assert [e["name"] for e in kids] == ["iterate.fused",
+                                             "iterate.globals_step"]
+        fused, step = kids
+        assert fused["iters"] == 2 and step["iters"] == 1
+        # a domain this small gets the resident engine, probed on its
+        # first call: that call as a whole is one iterate.fused
+        assert fused["engine"] == it["engine"] \
+            == "pallas_resident[d2q9,fuse=8]"
+        assert fused["iteration"] == step["iteration"] == it["iteration"]
+        assert it["t0"] <= fused["t0"] <= step["t0"] <= it["ts"]
+    assert len(_spans(seen, "iterate")) == 2
+
+    handlers = _spans(seen, "handler")
+    assert [(h["handler"], h["iteration"]) for h in handlers] == [
+        ("cbFailcheck", 3), ("cbFailcheck", 6), ("cbVTK", 6)]
+    quantities = [q.name for q in get_model("d2q9").quantities
+                  if not q.adjoint]
+    for h in handlers[:2]:
+        kids = [e for e in spans if e["parent"] == h["id"]]
+        assert [e["name"] for e in kids] == [
+            "quantity.eval", "quantity.d2h", "failcheck.scan"] \
+            * len(quantities)
+        assert [e["quantity"] for e in kids[::3]] == quantities
+        assert all(e["iteration"] == h["iteration"] for e in kids)
+        assert all(e["bytes"] > 0 for e in kids
+                   if e["name"] != "failcheck.scan")
+    vtk, = _spans(seen, "output.vtk")
+    assert parent_of(vtk) is handlers[2]
+    kids = [e for e in spans if e["parent"] == vtk["id"]]
+    assert [e["name"] for e in kids] == (
+        ["quantity.eval", "quantity.d2h"] * len(quantities)
+        + ["output.vtk.encode", "output.vtk.file"])
+    encode, written = kids[-2:]
+    assert encode["iteration"] == written["iteration"] == 6
+    assert 0 < encode["bytes_out"] < encode["bytes_in"]
+    assert encode["bytes_in"] >= sum(
+        e["bytes"] for e in kids if e["name"] == "quantity.d2h")
+    assert written["bytes"] == os.path.getsize(
+        [str(p) for p in tmp_path.iterdir() if p.suffix == ".vti"][0])
+    # self time: what a span's children do not cover is never negative
+    for e in (handlers[2], vtk):
+        own = e["dur_s"] - sum(k["dur_s"] for k in spans
+                               if k["parent"] == e["id"])
+        assert own >= -1e-3
+
+
+def test_compile_events_name_the_function_and_the_span(seen):
+    @jax.jit
+    def fresh_function_of_this_test(x):
+        return jnp.tanh(x) * 3.0
+
+    x = jnp.ones((4, 4), jnp.float32)
+    with telemetry.span("first_call") as sp:
+        fresh_function_of_this_test(x).block_until_ready()
+    mine = [e for e in seen if e["kind"] == "compile"
+            and "fresh_function_of_this_test" in (e["fun_name"] or "")]
+    assert {"trace", "lower", "backend_compile"} <= {e["stage"]
+                                                     for e in mine}
+    assert all(e["parent"] == sp.id and e["dur_s"] >= 0 for e in mine)
+    n = len([e for e in seen if e["kind"] == "compile"])
+    fresh_function_of_this_test(x).block_until_ready()
+    assert len([e for e in seen if e["kind"] == "compile"]) == n
+
+
+def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch):
+    """The tuned 2D sharded engine on a 4x1 mesh of the forced CPU
+    devices: 2 x width x plane x planes x itemsize per exchange, one
+    exchange per fused pair (and one for an odd step), the three-plane
+    aux stack once per call."""
+    from tclb_tpu.parallel.mesh import make_mesh
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    ny, nx, niter = 64, 128, 8
+    m = get_model("d2q9")
+    mesh = make_mesh((ny, nx), devices=jax.devices()[:4],
+                     decomposition={"y": 4, "x": 1})
+    lat = Lattice(m, (ny, nx), dtype=jnp.float32,
+                  settings={"nu": 0.05, "Velocity": 0.03}, mesh=mesh)
+    flags = np.full((ny, nx), m.flag_for("MRT"), dtype=np.uint16)
+    flags[0, :] = flags[-1, :] = m.flag_for("Wall")
+    lat.set_flags(flags)
+    lat.init()
+    lat.iterate(niter)
+    assert lat._fast_name == "pallas_sharded[{'y': 4, 'x': 1},fuse=2]"
+    assert telemetry.fuse_of(lat._fast_name) == 2
+
+    plane = 2 * 8 * nx * 4                  # 8 rows each way, f32
+    nfast = niter - 1
+    want = (nfast // 2 + nfast % 2) * m.n_storage * plane + 3 * plane
+    fused, = _spans(seen, "iterate.fused")
+    assert fused["iters"] == nfast and fused["halo_bytes"] == want
+    # the trailing step is the sharded XLA step: the planes that cross y
+    # (3 of d2q9's 9 each way), one row wide
+    step, = _spans(seen, "iterate.globals_step")
+    crossing = int(np.count_nonzero(m.ei[:, 1]))
+    assert step["halo_bytes"] == 2 * 1 * nx * crossing * 4
+    assert telemetry.counters()["halo.bytes"] == want + step["halo_bytes"]
+    assert not [e for e in _spans(seen) if e["name"].startswith("halo.")]
+
+
+def test_disabled_solve_never_syncs_and_listener_is_silent(
+        tmp_path, monkeypatch):
+    from tclb_tpu.control import run_config_string
+    from tclb_tpu.telemetry import events
+    docs = []
+    telemetry.subscribe(docs.append)        # registers the listener ...
+    telemetry.unsubscribe(docs.append)      # ... which stays, gated
+    assert events._compile_listening and not telemetry.enabled()
+    assert telemetry.span("iterate.fused", iters=1) is NOOP_SPAN
+
+    def boom(*a, **k):
+        raise AssertionError("telemetry is off")
+
+    monkeypatch.setattr(telemetry.Span, "sync", boom)
+    monkeypatch.setattr(events, "_fanout_locked", boom)  # nothing emits
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    s = run_config_string(_SOLVE_XML.format(out=tmp_path),
+                          get_model("d2q9"))
+    assert s.iter == 6 and docs == []
+    assert telemetry.counters() == {}

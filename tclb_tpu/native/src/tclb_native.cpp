@@ -9,12 +9,14 @@
 // (tclb_tpu/native/__init__.py) with the pure-Python implementations kept
 // as a fallback and as the oracle in tests/test_native.py.
 //
-// Build: g++ -O3 -std=c++17 -fPIC -shared tclb_native.cpp -o ... -lz
+// Build: g++ -O3 -std=c++17 -fPIC -shared -pthread tclb_native.cpp -o ... -lz
 
 #include <algorithm>
+#include <atomic>
 #include <cmath>
 #include <cstdint>
 #include <cstring>
+#include <thread>
 #include <vector>
 
 #include <zlib.h>
@@ -112,11 +114,17 @@ int tclb_voxelize(const double *tri, int64_t ntri,
 // writes raw appended data (src/vtkOutput.cpp); compression is an added
 // capability — every VTK reader understands it and large fields shrink ~3x.
 //
+// The blocks are independent zlib streams, so they are compressed on
+// `threads` threads (the calling one among them), each taking the next
+// block that nobody has taken yet: blocks cost unequally (the wake against
+// the far field), and fixed ranges left the fastest threads waiting.  The
+// bytes written do not depend on the thread count.
+//
 // out must have room for 4*(3+nblocks) + nblocks*compressBound(block).
 // Returns total bytes written, or -1 on error.
 int64_t tclb_zlib_blocks(const uint8_t *data, int64_t n,
                          int64_t block, int level,
-                         uint8_t *out, int64_t outcap) {
+                         uint8_t *out, int64_t outcap, int threads) {
     if (n < 0 || block <= 0) return -1;
     const int64_t nblocks = n == 0 ? 1 : (n + block - 1) / block;
     const int64_t last = n == 0 ? 0 : (n - (nblocks - 1) * block);
@@ -126,15 +134,53 @@ int64_t tclb_zlib_blocks(const uint8_t *data, int64_t n,
     h[0] = (uint32_t)nblocks;
     h[1] = (uint32_t)block;
     h[2] = (uint32_t)(last == block ? 0 : last);
-    int64_t off = header;
-    for (int64_t b = 0; b < nblocks; b++) {
-        const int64_t sz = b == nblocks - 1 ? last : block;
-        uLongf dest = (uLongf)(outcap - off);
-        if (compress2(out + off, &dest, data + b * block, (uLong)sz,
-                      level) != Z_OK)
-            return -1;
-        h[3 + b] = (uint32_t)dest;
-        off += (int64_t)dest;
+    if (threads > nblocks) threads = (int)nblocks;
+    if (threads <= 1) {
+        int64_t off = header;
+        for (int64_t b = 0; b < nblocks; b++) {
+            const int64_t sz = b == nblocks - 1 ? last : block;
+            uLongf dest = (uLongf)(outcap - off);
+            if (compress2(out + off, &dest, data + b * block, (uLong)sz,
+                          level) != Z_OK)
+                return -1;
+            h[3 + b] = (uint32_t)dest;
+            off += (int64_t)dest;
+        }
+        return off;
+    }
+    // each block goes into its own slot of the worst-case size; one pass
+    // afterwards moves the streams together
+    const int64_t slot = (int64_t)compressBound((uLong)block);
+    if (outcap < header + nblocks * slot) return -1;
+    uint8_t *slots = out + header;
+    std::atomic<bool> failed{false};
+    std::atomic<int64_t> next{0};
+    auto work = [&]() {
+        for (int64_t b; !failed && (b = next++) < nblocks;) {
+            const int64_t sz = b == nblocks - 1 ? last : block;
+            uLongf dest = (uLongf)slot;
+            if (compress2(slots + b * slot, &dest, data + b * block,
+                          (uLong)sz, level) != Z_OK) {
+                failed = true;
+                return;
+            }
+            h[3 + b] = (uint32_t)dest;
+        }
+    };
+    std::vector<std::thread> pool;
+    try {
+        pool.reserve(threads - 1);
+        for (int t = 1; t < threads; t++) pool.emplace_back(work);
+    } catch (...) {
+        // no more threads to be had: those there are take all the blocks
+    }
+    work();
+    for (auto &t : pool) t.join();
+    if (failed) return -1;
+    int64_t off = header + h[3];
+    for (int64_t b = 1; b < nblocks; b++) {
+        std::memmove(out + off, slots + b * slot, h[3 + b]);
+        off += h[3 + b];
     }
     return off;
 }

@@ -555,6 +555,9 @@ def test_solve_emits_phase_spans_with_the_right_parents(
     encode, written = kids[-2:]
     assert encode["iteration"] == written["iteration"] == 6
     assert 0 < encode["bytes_out"] < encode["bytes_in"]
+    # one block of 32 KB each for Rho and Flag, three for U: too few for
+    # a second thread
+    assert encode["threads"] == 1 and encode["blocks"] == 5
     assert encode["bytes_in"] >= sum(
         e["bytes"] for e in kids if e["name"] == "quantity.d2h")
     assert written["bytes"] == os.path.getsize(
@@ -564,6 +567,36 @@ def test_solve_emits_phase_spans_with_the_right_parents(
         own = e["dur_s"] - sum(k["dur_s"] for k in spans
                                if k["parent"] == e["id"])
         assert own >= -1e-3
+
+
+@pytest.mark.parametrize("native_on", [True, False])
+def test_vtk_encode_span_says_how_it_was_encoded(
+        seen, tmp_path, monkeypatch, native_on):
+    """`threads`, `blocks` and `native` on `output.vtk.encode`: the native
+    encoder on as many threads as the cores and the blocks allow; with
+    the library off (what TCLB_NATIVE=0 does in `get_lib`) Python on
+    one."""
+    from tclb_tpu import native
+    from tclb_tpu.utils.vtk import write_vti
+    if native_on and not native.available():
+        pytest.skip("native lib not built (no g++?)")
+    monkeypatch.setattr(native, "_usable_cores", lambda: 3)
+    if not native_on:
+        monkeypatch.setenv("TCLB_NATIVE", "0")
+        monkeypatch.setattr(native, "_tried", False)
+        monkeypatch.setattr(native, "_lib", None)
+    rho = np.linspace(0, 1, 512 * 512, dtype=np.float32).reshape(512, 512)
+    write_vti(str(tmp_path / "x"), {"Rho": rho, "U": np.stack([rho] * 3)},
+              compress=True)
+    encode, = _spans(seen, "output.vtk.encode")
+    assert encode["blocks"] == 32 + 96
+    assert encode["native"] is native_on
+    assert encode["threads"] == (3 if native_on else 1)
+    assert 0 < encode["bytes_out"] < encode["bytes_in"] == 4 * 512 * 512 * 4
+    # the raw branch has no encoder to speak of
+    write_vti(str(tmp_path / "y"), {"Rho": rho})
+    raw = _spans(seen, "output.vtk.encode")[-1]
+    assert raw["compress"] is False and "threads" not in raw
 
 
 def test_compile_events_name_the_function_and_the_span(seen):

@@ -1143,7 +1143,15 @@ class Lattice:
             if not use_fast:
                 self.state = self._iterate(self.state, self.params, niter)
             elif self._fast_probing:
-                done = self._probe_first_call(fast, niter, nfast)
+                with telemetry.span("engine.probe",
+                                    engine=self._fast_name) as probe:
+                    tried: list = []
+                    done = self._probe_first_call(fast, niter, nfast, tried)
+                    telemetry.counter("engine.probe_attempts", len(tried))
+                    probe.add(attempts=len(tried),
+                              rungs=[cap for cap in tried if cap],
+                              result=self._fast_name or "xla")
+                    probe.sync(self.state)
             else:
                 self.state = fast(self.state, self.params, nfast)
             sp.add(iters=done,
@@ -1155,12 +1163,15 @@ class Lattice:
                 self.state = self._iterate(self.state, self.params, 1)
                 sp.sync(self.state)
 
-    def _probe_first_call(self, fast, niter: int, nfast: int) -> int:
+    def _probe_first_call(self, fast, niter: int, nfast: int,
+                          tried: list) -> int:
         """The first call of an engine that has to be probed: run
         ``nfast`` fused steps, stepping down the fallback chain where the
         engine does not compile.  Returns the steps done: ``nfast``, or
         ``niter`` where nothing compiled and XLA ran the whole chunk
-        (its last step has produced the globals)."""
+        (its last step has produced the globals).  ``tried`` gains one
+        entry per engine that was run: the generic band engine's band
+        cap (its rung of the ladder), 0 for every other engine."""
         # the generic engine's trace probe cannot see Mosaic
         # lowering gaps (e.g. a model using arccos) or
         # scoped-VMEM overflows — those only surface at first
@@ -1174,7 +1185,8 @@ class Lattice:
         from tclb_tpu.ops import pallas_generic
         from tclb_tpu.utils import log
 
-        def attempt(it_fn):
+        def attempt(it_fn, cap=0):
+            tried.append(cap)
             probe = jax.tree.map(jnp.copy, self.state)
             return it_fn(probe, self.params, nfast)
 
@@ -1184,8 +1196,14 @@ class Lattice:
             "pallas_resident_generic")
         was_d3q = (self._fast_name or "").startswith(
             "pallas_d3q[")
+        first_cap = 0
+        if (self._fast_name or "").startswith("pallas_generic["):
+            # the planner's own choice reads as the 2D band's default cap
+            first_cap = self._fast_cfg[1] or (
+                pallas_generic._DEFAULT_BY_CAP if self.model.ndim == 2
+                else 0)
         try:
-            self.state = attempt(fast)
+            self.state = attempt(fast, first_cap)
         except Exception as e:  # noqa: BLE001
             if was_d3q:
                 # fused (K>=2) tuned-3D probe failed — its
@@ -1211,6 +1229,7 @@ class Lattice:
                     failed, self._fast_name, repr(e),
                     model=self.model.name)
                 self._fast_probing = False
+                tried.append(0)
                 self.state = fast(self.state, self.params, nfast)
                 return nfast
             if was_resident:
@@ -1261,6 +1280,7 @@ class Lattice:
                     failed, self._fast_name, repr(e),
                     model=self.model.name)
                 self._fast_probing = False
+                tried.append(0)
                 self.state = fast(self.state, self.params, nfast)
                 return nfast
             failed = self._fast_name
@@ -1290,7 +1310,7 @@ class Lattice:
                         self.model, self.shape, self.storage_dtype,
                         fuse=fz, present=present, by_cap=cap,
                         shift=self._shift_vec)
-                    self.state = attempt(it2)
+                    self.state = attempt(it2, cap)
                 except Exception as e2:  # noqa: BLE001
                     log.warning(f"engine: pallas_generic fuse={fz} "
                                 f"by<={cap} failed to compile "

@@ -46,6 +46,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tclb_tpu import telemetry
 from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.lattice import (LatticeState, NodeCtx, SimParams,
                                    series_dt_overrides, series_overrides)
@@ -946,9 +947,32 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             iteration=it,
         )
 
+    def account(niter: int, has_series: bool) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side from
+        the shapes (the mirror of ``_iterate_jit``'s schedule)."""
+        final = int(niter > 0 and (call_sg if has_series else call_g)
+                    is not None)
+        main = max(niter, 0) - final
+        fused = 0 if has_series else main // fuse
+        rest = main - fused * fuse
+        return dict(
+            stages_per_step=len(model.actions["Iteration"]),
+            band_rows=by, halo_rows=_HALO, pad_rows=pad, bands=ny // by,
+            kernel_calls=fused + rest + final, remainder_steps=rest + final,
+            aux_planes=(1 + 2 * len(zonal_names) if has_series
+                        else 1 if lean_aux else 1 + len(zonal_names)))
+
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
-        return _iterate_jit(state, params, niter)
+        out = _iterate_jit(state, params, niter)
+        # a call under a trace (supports()'s abstract probe, a caller's
+        # own jit) issues nothing
+        if telemetry.enabled() and not isinstance(out.fields,
+                                                  jax.core.Tracer):
+            did = account(int(niter), params.time_series is not None)
+            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            telemetry.annotate(**did)
+        return out
 
     # contract flags the Lattice dispatch keys on: the engine handles
     # Control time series itself, and (when the globals flavor exists)

@@ -221,10 +221,11 @@ class Solver:
     # -- output fan-out ------------------------------------------------------ #
 
     def quantity_host(self, name: str) -> np.ndarray:
-        """One quantity over the lattice as a host array: the eager
-        quantity programs on the device (``quantity.eval``, fenced when
-        traced), then the copy, or on a mesh the gather, to the host
-        (``quantity.d2h``)."""
+        """One quantity over the lattice as a host array: its compiled
+        program on the device (``quantity.eval``, fenced when traced;
+        ``Lattice.get_quantity`` adds ``program``: ``"built"`` on the
+        call that compiled it, ``"reused"`` after), then the copy, or on
+        a mesh the gather, to the host (``quantity.d2h``)."""
         with telemetry.span("quantity.eval", quantity=name) as sp:
             q = sp.sync(self.lattice.get_quantity(name))
             sp.add(bytes=q.nbytes)

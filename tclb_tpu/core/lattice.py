@@ -25,7 +25,7 @@ and shardable (parallel/halo.py wraps it in ``shard_map``).
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -722,6 +722,34 @@ def make_sampled_iterate(model: Model, points: np.ndarray,
 # --------------------------------------------------------------------------- #
 
 
+@lru_cache(maxsize=None)
+def quantity_program(model: Model, name: str, cdtype: Any,
+                     storage_repr: str) -> tuple[Callable, list[int]]:
+    """The compiled program of one Quantity, ``program(fields, flags,
+    params, iteration, avg_start) -> plane(s)``, and the count of its
+    traces (a one-element list the traced body bumps: a call that leaves
+    it alone reused an executable).  Keyed by what the trace depends on,
+    not by the Lattice, so a second lattice of the model reuses the trace
+    and ``jax.jit``'s own cache handles shapes and shardings; kept, with
+    its model, for the life of the process, as the registry keeps models."""
+    fn = model.quantity_fns[name]
+    shift_block = ddf.stack_shift(model, storage_repr)
+    traces = [0]
+
+    def evaluate(fields, flags, params, iteration, avg_start):
+        traces[0] += 1
+        # quantities evaluate in the compute dtype over RAW distributions
+        # (no-op cast at f32; the shifted rung restores f_i = dev + w_i
+        # at this widen seam, so extraction never sees the deviation)
+        fields = ddf.widen_stack(fields, cdtype, shift_block)
+        ctx = NodeCtx(model, fields, fields, flags, params,
+                      iteration=iteration, avg_start=avg_start)
+        with jax.default_matmul_precision("highest"):
+            return fn(ctx)
+
+    return jax.jit(evaluate), traces
+
+
 class Lattice:
     """Host-side convenience wrapper, mirroring the reference ``Lattice``
     class surface (src/Lattice.h.Rt:36-168): allocate, Init, Iterate,
@@ -1377,19 +1405,23 @@ class Lattice:
 
     def get_quantity(self, name: str) -> jnp.ndarray:
         """Evaluate a registered Quantity over the lattice (reference
-        Lattice::GetQuantity, src/Lattice.cu.Rt:1012-1036)."""
-        fn = self.model.quantity_fns[name]
-        # quantities evaluate in the compute dtype over RAW distributions
-        # (no-op cast at f32; the shifted rung restores f_i = dev + w_i
-        # at this widen seam, so extraction never sees the deviation)
-        fields = ddf.widen_stack(self.state.fields, self.dtype,
-                                 self._shift_block)
-        ctx = NodeCtx(self.model, fields, fields,
-                      self.state.flags, self.params,
-                      iteration=self.state.iteration,
-                      avg_start=self.avg_start)
-        with jax.default_matmul_precision("highest"):
-            return fn(ctx)
+        Lattice::GetQuantity, src/Lattice.cu.Rt:1012-1036): one compiled
+        program a quantity (:func:`quantity_program`), shared by every
+        lattice of the model.  The state is read, not donated; iteration
+        and ``avg_start`` are array arguments, so only a new shape, dtype
+        or sharding compiles again.  The innermost open span
+        (``quantity.eval`` under the Solver) learns whether this call
+        ``"built"`` the program or ``"reused"`` it."""
+        program, traces = quantity_program(
+            self.model, name, jnp.dtype(self.dtype), self.storage_repr)
+        before = traces[0]
+        out = program(self.state.fields, self.state.flags, self.params,
+                      self.state.iteration, np.int32(self.avg_start))
+        built = traces[0] != before
+        if built:
+            telemetry.counter("quantity.programs_built")
+        telemetry.annotate(program="built" if built else "reused")
+        return out
 
     def reset_average(self) -> None:
         """Zero the ``average=True`` storage planes and restart the sample

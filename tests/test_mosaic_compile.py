@@ -159,6 +159,39 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo):
     assert "halo_exchange/" in text
 
 
+@pytest.mark.parametrize("name,quantity", [("d2q9", "U"),
+                                           ("d2q9_kuper", "F")])
+def test_quantity_program_on_4x1_mesh(topo, name, quantity):
+    """`Lattice.get_quantity`'s compiled program at the mesh cell's size:
+    partitioned by the compiler, its result sharded by rows like the
+    state (the gather to the host stays `quantity.d2h`'s), and no plane
+    gathered between chips: `F`'s rolls of `phi` exchange edge rows only."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tclb_tpu.core.lattice import SimParams, quantity_program
+    from tclb_tpu.parallel import halo
+    shape = (4096, 1024)
+    m = get_model(name)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("y", "x"))
+
+    def on(spec, *dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    n = len(m.settings)
+    program, _ = quantity_program(m, quantity, jnp.dtype(jnp.float32), "raw")
+    compiled = program.lower(
+        on(halo.field_spec(mesh), m.n_storage, *shape),
+        on(halo.flag_spec(mesh), *shape, dtype=jnp.uint16),
+        SimParams(settings=on(P(), n), zone_table=on(P(), n, m.zone_max)),
+        on(P(), dtype=jnp.int32), on(P(), dtype=jnp.int32)).compile()
+    assert compiled.output_shardings.spec == P(None, "y", "x")
+    text = compiled.as_text()
+    assert "all-gather" not in text
+    edges = re.search(r"collective-permute|all-to-all", text)
+    assert bool(edges) == (quantity == "F")
+
+
 def test_generic_d3q19_heat_builder_defaults(one_chip):
     """The 3D generic builder at its own defaults (fuse=1, every node
     type) is refused at 48x48x256 — 18.08M of scoped VMEM against a

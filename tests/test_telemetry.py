@@ -546,12 +546,22 @@ def test_solve_emits_phase_spans_with_the_right_parents(
         assert all(e["iteration"] == h["iteration"] for e in kids)
         assert all(e["bytes"] > 0 for e in kids
                    if e["name"] != "failcheck.scan")
+        # the compiled quantity program says whether this call built it:
+        # on quantity.eval alone, and only the first use in a process may
+        assert all(e["program"] in ("built", "reused") for e in kids[::3])
+        assert not any("program" in e for e in kids
+                       if e["name"] != "quantity.eval")
+    assert all(e["program"] == "reused" for e in spans
+               if e["parent"] == handlers[1]["id"]
+               and e["name"] == "quantity.eval")
     vtk, = _spans(seen, "output.vtk")
     assert parent_of(vtk) is handlers[2]
     kids = [e for e in spans if e["parent"] == vtk["id"]]
     assert [e["name"] for e in kids] == (
         ["quantity.eval", "quantity.d2h"] * len(quantities)
         + ["output.vtk.encode", "output.vtk.file"])
+    assert all(e["program"] == "reused" and e["bytes"] > 0
+               for e in kids[:-2:2])
     encode, written = kids[-2:]
     assert encode["iteration"] == written["iteration"] == 6
     assert 0 < encode["bytes_out"] < encode["bytes_in"]

@@ -44,10 +44,6 @@ def _default_sources() -> list:
     tests = os.path.join(_REPO_ROOT, "tests")
     if os.path.isdir(tests):
         srcs += _py_files(tests)
-    for extra in ("bench.py",):
-        p = os.path.join(_REPO_ROOT, extra)
-        if os.path.isfile(p):
-            srcs.append(p)
     return srcs
 
 
@@ -352,7 +348,7 @@ def scan_dispatch_telemetry(lattice_path=None) -> list:
     ``engine_selected`` and every except handler that reassigns
     ``self._fast_name`` (i.e. demotes the engine) emits
     ``engine_fallback``.  Without these, a production trace cannot say
-    which engine ran — the exact blind spot that made the BENCH_r05
+    which engine ran — the exact blind spot that once made a
     heat_adj regression untriageable."""
     path = lattice_path or os.path.join(_PKG_ROOT, "core", "lattice.py")
     rel = os.path.relpath(path, _REPO_ROOT)

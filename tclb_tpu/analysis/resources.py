@@ -25,7 +25,8 @@ _ADJ_SCRATCH_LIMIT = 64 * 1024 * 1024
 
 
 def default_shape(model: Model) -> tuple:
-    """Representative production shape (the bench cases' scale)."""
+    """Representative production shape (the scale of the example
+    cases the kernels' budgets were set at)."""
     return (512, 1024) if model.ndim == 2 else (48, 48, 256)
 
 

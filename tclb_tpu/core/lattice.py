@@ -750,6 +750,22 @@ def quantity_program(model: Model, name: str, cdtype: Any,
     return jax.jit(evaluate), traces
 
 
+@dataclasses.dataclass(frozen=True)
+class EngineCandidate:
+    """One link of the chain :meth:`Lattice._build_fast` returns: a fused
+    engine that can take the lattice, and what dispatch has to know to
+    try it.  ``probe``: its first call runs on a copy of the state and a
+    failure steps down the chain; otherwise it is a proven engine, run on
+    the real state, whose exception goes through."""
+
+    tag: str                        # its name in events, spans, _fast_name
+    build: Callable[[], Callable]   # () -> iterate(state, params, niter)
+    probe: bool = False
+    cap: int = 0   # the band cap it stands for, a rung of ``engine.probe``
+    verdict: Optional[tuple] = None   # generic band engine only: the
+    #                          (fuse, by_cap) to remember once it has run
+
+
 class Lattice:
     """Host-side convenience wrapper, mirroring the reference ``Lattice``
     class surface (src/Lattice.h.Rt:36-168): allocate, Init, Iterate,
@@ -860,7 +876,7 @@ class Lattice:
         self._fast_name = None
         self._fast_tried = False
         self._fast_probing = False
-        self._fast_cfg = (1, None)
+        self._fast_chain: list = []
 
     # -- setup -------------------------------------------------------------- #
 
@@ -958,22 +974,28 @@ class Lattice:
                     static_argnames=("niter",), donate_argnums=0)
         return self._iterate_cached
 
-    def _build_fast(self):
-        """Try to build the fused Pallas fast path for this configuration
-        (the reference's tuned kernel IS its engine — Lattice::Iteration
-        launches it every step, src/Lattice.cu.Rt:414-457; this makes the
-        Pallas kernel play the same role).  Auto-selected on TPU only: in
-        interpret mode (CPU) the kernels are an emulation, far slower than
-        XLA.  ``TCLB_FASTPATH=0`` disables; ``TCLB_FASTPATH=force`` enables
+    def _build_fast(self) -> list:
+        """The fused Pallas engines that can take this configuration, as
+        a chain of :class:`EngineCandidate`: the preferred engine first,
+        then what it steps down to where it does not compile; empty where
+        the XLA engine runs (the reference's tuned kernel IS its engine —
+        Lattice::Iteration launches it every step,
+        src/Lattice.cu.Rt:414-457; this makes the Pallas kernel play the
+        same role).  Auto-selected on TPU only: in interpret mode (CPU)
+        the kernels are an emulation, far slower than XLA.
+        ``TCLB_FASTPATH=0`` disables; ``TCLB_FASTPATH=force`` enables
         off-TPU (tests use this to exercise the dispatch in interpret
         mode)."""
         import os
         mode = os.environ.get("TCLB_FASTPATH", "auto")
-        if mode == "0":
-            return None, None
-        if jax.default_backend() != "tpu" and mode != "force":
-            return None, None
-        from tclb_tpu.ops import pallas_d2q9, pallas_d3q
+        if mode == "0" or (mode != "force"
+                           and jax.default_backend() != "tpu"):
+            return []
+        from tclb_tpu.ops import pallas_d2q9, pallas_d3q, pallas_generic
+        from tclb_tpu.ops.lbm import present_types
+        model, shape, name = self.model, self.shape, self.model.name
+        present = present_types(model, self._flags_host())
+        shift = self._shift_vec
         # a Control time series needs per-iteration zonal planes, which
         # only the generic engine implements — skip the tuned kernels
         # (set_setting_series invalidates the engine so this re-runs)
@@ -985,125 +1007,129 @@ class Lattice:
         # dispatch falls through to the d3q/generic families
         sdt = self.storage_dtype
         s_itemsize = jnp.dtype(sdt).itemsize
+
+        def cand(tag, make, probe=False, cap=0, verdict=None, **kw):
+            # the one place a builder is called: all share this signature
+            return EngineCandidate(
+                tag, lambda: make(model, shape, sdt, present=present, **kw),
+                probe, cap, verdict)
         if self.mesh is not None:
-            from tclb_tpu.ops.lbm import present_types
             from tclb_tpu.parallel.halo import make_sharded_pallas_iterate
-            it = make_sharded_pallas_iterate(
-                self.model, self.mesh, self.shape, self.dtype,
-                present=present_types(self.model, self._flags_host()))
-            if it is not None:
-                if getattr(it, "uses_generic", False):
-                    self._fast_probing = True
-                return it, (f"pallas_sharded[{dict(self.mesh.shape)},"
-                            f"fuse={it.fuse}]")
-            return None, None
-        if (not has_series
-                and pallas_d2q9.supports_resident(self.model, self.shape,
-                                                  sdt)):
-            # small domains: whole lattice VMEM-resident, 8 steps per
-            # kernel call — (1R+1W)/8 HBM traffic per step.  First call
-            # is probed (the budget cannot see Mosaic's temporaries);
-            # on failure the probe falls back — for the resident engine
-            # the ladder is empty, so straight to the band/XLA path
-            present = pallas_d2q9.present_types(
-                self.model, self._flags_host())
-            self._fast_probing = True
-            return (pallas_d2q9.make_resident_iterate(
-                self.model, self.shape, sdt, present=present),
-                f"pallas_resident[{self.model.name},fuse=8]")
-        if (not has_series
-                and pallas_d2q9.supports(self.model, self.shape, sdt)):
-            present = pallas_d2q9.present_types(
-                self.model, self._flags_host())
-            return (pallas_d2q9.make_pallas_iterate(
-                self.model, self.shape, sdt, fuse=2,
-                present=present),
-                f"pallas_2d[{self.model.name},fuse=2]")
-        if not has_series and pallas_d3q.supports(
-                self.model, self.shape, sdt):
-            present = pallas_d3q.present_types(
-                self.model, self._flags_host())
-            # K>=2 multi-step fusion (one HBM round trip per K steps)
-            # compiles against the raised scoped-vmem ceiling: first TPU
-            # compile may still hit Mosaic temporaries the planner can't
-            # see, so the fused build is probed (fallback: fuse=1)
-            k3 = pallas_d3q.choose_fuse(self.model, self.shape,
-                                        itemsize=s_itemsize)
+            # building this engine IS asking whether it takes the case,
+            # so it alone is built here.  Its generic flavour is probed,
+            # with nothing under it
+            it = make_sharded_pallas_iterate(model, self.mesh, shape,
+                                             self.dtype, present=present)
+            if it is None:
+                return []
+            return [EngineCandidate(
+                f"pallas_sharded[{dict(self.mesh.shape)},fuse={it.fuse}]",
+                lambda: it, probe=getattr(it, "uses_generic", False))]
+        if not has_series and pallas_d2q9.supports(model, shape, sdt):
+            chain = [cand(f"pallas_2d[{name},fuse=2]",
+                          pallas_d2q9.make_pallas_iterate, fuse=2)]
+            if pallas_d2q9.supports_resident(model, shape, sdt):
+                # small domains: whole lattice VMEM-resident, 8 steps per
+                # kernel call — (1R+1W)/8 HBM traffic per step.  First
+                # call is probed (the budget cannot see Mosaic's
+                # temporaries); the band engine is the proven one under it
+                chain.insert(0, cand(f"pallas_resident[{name},fuse=8]",
+                                     pallas_d2q9.make_resident_iterate,
+                                     probe=True))
+            return chain
+        if not has_series and pallas_d3q.supports(model, shape, sdt):
+            make = pallas_d3q.make_pallas_iterate
+            k3 = pallas_d3q.choose_fuse(model, shape, itemsize=s_itemsize)
+            # no fuse given: the builder's own planner picks its (bz, K)
+            chain = [cand(f"pallas_d3q[{name},fuse={k3}]", make,
+                          probe=k3 >= 2, shift=shift)]
             if k3 >= 2:
-                self._fast_probing = True
+                # K>=2 multi-step fusion (one HBM round trip per K steps)
+                # compiles against the raised scoped-vmem ceiling: first
+                # TPU compile may still hit Mosaic temporaries the planner
+                # can't see, so the fused build is probed; the K=1 block
+                # kernel is the proven engine for these models
+                chain.append(cand(f"pallas_d3q[{name},fuse=1]", make,
+                                  fuse=1, shift=shift))
             else:
                 # single-step demotion must never be silent: record WHY
                 # the fused planner rejected every (bz, K) so a floor
                 # regression can be triaged from telemetry alone
                 _, why = pallas_d3q.fused_cfg_explain(
-                    self.model, self.shape, itemsize=s_itemsize)
+                    model, shape, itemsize=s_itemsize)
                 telemetry.event(
-                    "fused_rejected", engine="pallas_d3q",
-                    model=self.model.name, shape=list(self.shape),
-                    reason=why or "unknown")
-            return (pallas_d3q.make_pallas_iterate(
-                self.model, self.shape, sdt, present=present,
-                shift=self._shift_vec),
-                f"pallas_d3q[{self.model.name},fuse={k3}]")
-        from tclb_tpu.ops import pallas_generic
+                    "fused_rejected", engine="pallas_d3q", model=name,
+                    shape=list(shape), reason=why or "unknown")
+            return chain
         # the static analyzer's kernel-safety verdict gates EVERY
         # registry-driven kernel: a stage reading beyond its declared
         # stencil would make the band windows silently wrong (the XLA
-        # path wraps exactly, so it stays the safe fallback)
+        # path wraps exactly, so it stays the safe fallback); so does an
+        # earlier probe of this model/shape that found nothing to compile
         from tclb_tpu import analysis
-        if not analysis.kernel_safety_ok(self.model):
-            return None, None
-        if (not has_series
-                and pallas_generic.supports_resident(self.model, self.shape,
-                                                     sdt)
-                and pallas_generic.mosaic_ok(self.model, self.shape)):
+        if not (analysis.kernel_safety_ok(model)
+                and pallas_generic.mosaic_ok(model, shape)):
+            return []
+        fits_resident = not has_series and pallas_generic.supports_resident(
+            model, shape, sdt)
+        if not (fits_resident or pallas_generic.supports(model, shape, sdt)):
+            return []
+
+        def band(fz, by_cap, tag=None, **how):
+            return cand(tag or f"pallas_generic[{name},fuse={fz}]",
+                        pallas_generic.make_pallas_iterate, fuse=fz,
+                        by_cap=by_cap, shift=shift, **how)
+        cfg = (None if fits_resident
+               else pallas_generic.get_build_cfg(model, shape))
+        if cfg is not None:
+            # this model/shape already proved it compiles: skip the
+            # first-call probe (and its full-state copy)
+            return [band(*cfg)]
+        # temporal fusion amortizes one HBM round trip over K steps; the
+        # shared planner caps K by the stencil reach fitting the halo (2D:
+        # fixed 8-row block; deep-stencil models like lee at reach 6/step
+        # stay fuse=1) or by the traffic model vs the K=1 engine (3D: slab
+        # halos grow with K, so the win must be priced)
+        fz0 = (pallas_generic.choose_fuse_3d(model, shape,
+                                             itemsize=s_itemsize)
+               if model.ndim == 3 else pallas_generic.choose_fuse(model))
+        if fits_resident:
             # generic counterpart of the tuned d2q9 resident engine
             # (checked above): whole lattice VMEM-resident, 8 steps per
             # kernel call, for ANY registry model that fits the budget.
-            # First call is probed; on failure the generic BAND engine
-            # is the fallback (see iterate()'s was_resident branch)
-            from tclb_tpu.ops.lbm import present_types
-            present = present_types(self.model, self._flags_host())
-            self._fast_probing = True
-            return (pallas_generic.make_resident_iterate(
-                self.model, self.shape, sdt, present=present,
-                shift=self._shift_vec),
-                f"pallas_resident_generic[{self.model.name},fuse=8]")
-        if (pallas_generic.supports(self.model, self.shape, sdt)
-                and pallas_generic.mosaic_ok(self.model, self.shape)):
-            from tclb_tpu.ops.lbm import present_types
-            present = present_types(self.model, self._flags_host())
-            cfg = pallas_generic.get_build_cfg(self.model, self.shape)
-            if cfg is not None:
-                # this model/shape already proved it compiles: skip the
-                # first-call probe (and its full-state copy)
-                fz, cap = cfg
-            else:
-                self._fast_probing = True   # first call may hit a Mosaic
-                # temporal fusion amortizes one HBM round trip over K
-                # steps; the shared planner caps K by the stencil reach
-                # fitting the halo (2D: fixed 8-row block; deep-stencil
-                # models like lee at reach 6/step stay fuse=1) or by the
-                # traffic model vs the K=1 engine (3D: slab halos grow
-                # with K, so the win must be priced)
-                fz = (pallas_generic.choose_fuse_3d(self.model,
-                                                    self.shape,
-                                                    itemsize=s_itemsize)
-                      if self.model.ndim == 3
-                      else pallas_generic.choose_fuse(self.model))
-                cap = None
-            self._fast_cfg = (fz, cap)
-            return (pallas_generic.make_pallas_iterate(  # lowering gap
-                self.model, self.shape, sdt, fuse=fz,
-                present=present, by_cap=cap,
-                shift=self._shift_vec),
-                f"pallas_generic[{self.model.name},fuse={fz}]")
-        return None, None
+            # First call is probed; each resident flavour steps down to
+            # ITS band family: here the generic band as planned
+            return [cand(f"pallas_resident_generic[{name},fuse=8]",
+                         pallas_generic.make_resident_iterate, probe=True,
+                         shift=shift), band(fz0, None)]
+        # the trace probe of supports() cannot see Mosaic lowering gaps
+        # (e.g. a model using arccos) or scoped-VMEM overflows — those
+        # only surface at first TPU compile: probe the planner's choice,
+        # then a ladder of smaller bands, then no fusion
+        rungs = [(fz0, 16), (fz0, 8)]
+        if fz0 >= 2:
+            rungs += [(1, 16), (1, 8)]
+        if model.ndim == 3:
+            # last resort: raised scoped-vmem ceiling (negative cap
+            # encodes it; ~2x slower codegen, still ~3x the XLA path)
+            rungs += [(fz0, -16), (fz0, -8)]
+        # the planner's own choice reads as the 2D band's default cap
+        cap0 = pallas_generic._DEFAULT_BY_CAP if model.ndim == 2 else 0
+        return [band(fz0, None, probe=True, cap=cap0,
+                     verdict=(fz0, None))] + [
+            band(fz, cap, f"pallas_generic[{name},fuse={fz},by<={cap}]",
+                 probe=True, cap=cap, verdict=(fz, cap))
+            for fz, cap in rungs]
 
     def _fast_path(self):
         if not self._fast_tried:
             self._fast_tried = True
-            self._fast, self._fast_name = self._build_fast()
+            # the preferred engine is built now (iterate() reads what it
+            # advertises), those under it when _probe_first_call gets there
+            self._fast_chain = chain = self._build_fast()
+            self._fast = chain[0].build() if chain else None
+            self._fast_name = chain[0].tag if chain else None
+            self._fast_probing = bool(chain) and chain[0].probe
             from tclb_tpu.utils import log
             if self._fast is not None:
                 suffix = "(in-kernel globals)" if getattr(
@@ -1194,201 +1220,68 @@ class Lattice:
     def _probe_first_call(self, fast, niter: int, nfast: int,
                           tried: list) -> int:
         """The first call of an engine that has to be probed: run
-        ``nfast`` fused steps, stepping down the fallback chain where the
-        engine does not compile.  Returns the steps done: ``nfast``, or
-        ``niter`` where nothing compiled and XLA ran the whole chunk
-        (its last step has produced the globals).  ``tried`` gains one
-        entry per engine that was run: the generic band engine's band
-        cap (its rung of the ladder), 0 for every other engine."""
-        # the generic engine's trace probe cannot see Mosaic
-        # lowering gaps (e.g. a model using arccos) or
-        # scoped-VMEM overflows — those only surface at first
-        # TPU compile.  Probe on a COPY of the state (the
-        # engines donate their input; a failure that happens at
-        # execution rather than compile would otherwise leave
-        # the real state's buffers deleted), retry down a
-        # smaller-band/no-fusion ladder, remember the verdict
-        # process-wide, and if nothing fits raise on a TPU
-        # backend (off it: fall back to XLA).
+        ``nfast`` fused steps, walking down the chain of
+        :meth:`_build_fast` where an engine does not compile.  Returns
+        the steps done: ``nfast``, or ``niter`` where nothing compiled and
+        XLA ran the whole chunk (its last step has produced the globals).
+        ``tried`` gains one entry per engine that was run: the band cap
+        the candidate stands for (its rung of the ladder), 0 for an
+        engine without one."""
         from tclb_tpu.ops import pallas_generic
         from tclb_tpu.utils import log
-
-        def attempt(it_fn, cap=0):
-            tried.append(cap)
-            probe = jax.tree.map(jnp.copy, self.state)
-            return it_fn(probe, self.params, nfast)
-
-        was_resident = (self._fast_name or "").startswith(
-            "pallas_resident")
-        was_generic_res = (self._fast_name or "").startswith(
-            "pallas_resident_generic")
-        was_d3q = (self._fast_name or "").startswith(
-            "pallas_d3q[")
-        first_cap = 0
-        if (self._fast_name or "").startswith("pallas_generic["):
-            # the planner's own choice reads as the 2D band's default cap
-            first_cap = self._fast_cfg[1] or (
-                pallas_generic._DEFAULT_BY_CAP if self.model.ndim == 2
-                else 0)
-        try:
-            self.state = attempt(fast, first_cap)
-        except Exception as e:  # noqa: BLE001
-            if was_d3q:
-                # fused (K>=2) tuned-3D probe failed — its
-                # raised-ceiling scratch budget cannot see
-                # Mosaic's compute temporaries.  The K=1 block
-                # kernel is the proven engine for these models:
-                # swap it in and continue this very call.
-                failed = self._fast_name
-                log.warning(f"engine: {self._fast_name} failed "
-                            f"to compile ({e!r}); fuse=1 "
-                            "d3q fallback")
-                from tclb_tpu.ops import pallas_d3q
-                present = pallas_d3q.present_types(
-                    self.model, self._flags_host())
-                self._fast = fast = \
-                    pallas_d3q.make_pallas_iterate(
-                        self.model, self.shape, self.storage_dtype,
-                        present=present, fuse=1,
-                        shift=self._shift_vec)
-                self._fast_name = (
-                    f"pallas_d3q[{self.model.name},fuse=1]")
-                telemetry.engine_fallback(
-                    failed, self._fast_name, repr(e),
-                    model=self.model.name)
-                self._fast_probing = False
-                tried.append(0)
-                self.state = fast(self.state, self.params, nfast)
-                return nfast
-            if was_resident:
-                # resident probe failed (its budget can't see
-                # Mosaic temporaries): the band engine is the
-                # proven fallback for these models — swap it in
-                # and continue this very call.  Each resident
-                # flavor falls back to ITS band family: the
-                # tuned d2q9 resident to the tuned d2q9 band,
-                # the generic resident to the generic band.
-                failed = self._fast_name
-                log.warning(f"engine: {self._fast_name} failed "
-                            f"to compile ({e!r}); band "
-                            "engine fallback")
-                if was_generic_res:
-                    from tclb_tpu.ops.lbm import present_types
-                    present = present_types(self.model,
-                                            self._flags_host())
-                    fz = (pallas_generic.choose_fuse_3d(
-                        self.model, self.shape,
-                        itemsize=jnp.dtype(
-                            self.storage_dtype).itemsize)
-                        if self.model.ndim == 3
-                        else pallas_generic.choose_fuse(
-                            self.model))
-                    self._fast = fast = \
-                        pallas_generic.make_pallas_iterate(
-                            self.model, self.shape,
-                            self.storage_dtype,
-                            fuse=fz, present=present,
-                            shift=self._shift_vec)
-                    self._fast_cfg = (fz, None)
-                    self._fast_name = (
-                        f"pallas_generic"
-                        f"[{self.model.name},fuse={fz}]")
-                else:
-                    from tclb_tpu.ops import pallas_d2q9
-                    present = pallas_d2q9.present_types(
-                        self.model, self._flags_host())
-                    self._fast = fast = \
-                        pallas_d2q9.make_pallas_iterate(
-                            self.model, self.shape, self.dtype,
-                            fuse=2, present=present)
-                    self._fast_name = (f"pallas_2d"
-                                       f"[{self.model.name},"
-                                       f"fuse=2]")
-                telemetry.engine_fallback(
-                    failed, self._fast_name, repr(e),
-                    model=self.model.name)
-                self._fast_probing = False
-                tried.append(0)
-                self.state = fast(self.state, self.params, nfast)
-                return nfast
-            failed = self._fast_name
-            if self.mesh is not None:
-                ladder = []   # sharded engine: no cap ladder
-            else:
-                log.warning(f"engine: {self._fast_name} first "
-                            f"compile failed ({e!r}); "
-                            "trying smaller bands")
-                from tclb_tpu.ops.lbm import present_types
-                present = present_types(self.model,
-                                        self._flags_host())
-                fz0, _ = self._fast_cfg
-                ladder = [(fz0, 16), (fz0, 8)]
-                if fz0 >= 2:
-                    ladder += [(1, 16), (1, 8)]
-                if self.model.ndim == 3:
-                    # last resort: raised scoped-vmem ceiling
-                    # (negative cap encodes it; ~2x slower
-                    # codegen, still ~3x the XLA path)
-                    ladder += [(fz0, -16), (fz0, -8)]
-                ladder = [c for c in ladder
-                          if c != self._fast_cfg]
-            for fz, cap in ladder:
-                try:
-                    it2 = pallas_generic.make_pallas_iterate(
-                        self.model, self.shape, self.storage_dtype,
-                        fuse=fz, present=present, by_cap=cap,
-                        shift=self._shift_vec)
-                    self.state = attempt(it2, cap)
-                except Exception as e2:  # noqa: BLE001
-                    log.warning(f"engine: pallas_generic fuse={fz} "
-                                f"by<={cap} failed to compile "
-                                f"({e2!r})")
-                    continue
-                self._fast = fast = it2
-                self._fast_cfg = (fz, cap)
-                self._fast_name = (f"pallas_generic"
-                                   f"[{self.model.name},fuse={fz},"
-                                   f"by<={cap}]")
-                telemetry.engine_fallback(
-                    failed, self._fast_name, repr(e),
-                    model=self.model.name)
-                break
-            else:
-                if jax.default_backend() == "tpu":
-                    # on the chip a run that finishes in XLA
-                    # under a Pallas name is ~9x slower and
-                    # reads as a result: fail with the first
-                    # exception instead
-                    raise RuntimeError(
-                        f"engine {failed} and every smaller "
-                        "configuration under it failed to "
-                        f"compile on the TPU backend: {e!r}") from e
-                log.warning(f"engine: {failed} failed to compile "
-                            f"({e!r}); XLA fallback")
-                telemetry.engine_fallback(
-                    failed, "xla", repr(e),
-                    model=self.model.name)
-                if self.mesh is None:
-                    # the sharded probe exercised a DIFFERENT
-                    # kernel (local shard shape) — never poison
-                    # the single-device caches from it
-                    pallas_generic.set_mosaic_ok(self.model,
-                                                 self.shape,
-                                                 False)
-                self._fast = fast = None
-                self._fast_name = None
-                self._fast_probing = False
-                self.state = self._iterate(self.state, self.params,
-                                           niter)
-                return niter
-        if self.mesh is None and not was_resident \
-                and not was_d3q:
-            # verdict caches belong to the generic engine only
-            pallas_generic.set_mosaic_ok(self.model, self.shape,
-                                         True)
+        chain, selected, cause = self._fast_chain, self._fast_name, None
+        for n, cand in enumerate(chain):
+            tried.append(cand.cap)
+            try:
+                it = fast if n == 0 else cand.build()
+                # probe on a COPY of the state: the engines donate their
+                # input, and a failure that happens at execution rather
+                # than compile would otherwise leave the real state's
+                # buffers deleted
+                state = (jax.tree.map(jnp.copy, self.state) if cand.probe
+                         else self.state)
+                self.state = it(state, self.params, nfast)
+            except Exception as e:  # noqa: BLE001
+                if not cand.probe:
+                    # a proven engine on the real state is the end of
+                    # its chain: its exception is the run's
+                    raise
+                if cause is None:
+                    cause = e
+                log.warning(f"engine: {cand.tag} failed to compile "
+                            f"({e!r}); stepping down its chain")
+                continue
+            break
+        else:
+            if jax.default_backend() == "tpu":
+                # on the chip a run that finishes in XLA under a Pallas
+                # name is ~9x slower and reads as a result: fail with
+                # the first exception instead
+                raise RuntimeError(
+                    f"engine {selected} and every smaller configuration "
+                    "under it failed to compile on the TPU backend: "
+                    f"{cause!r}") from cause
+            log.warning(f"engine: {selected} failed to compile "
+                        f"({cause!r}); XLA fallback")
+            if self.mesh is None:
+                # the sharded probe exercised a DIFFERENT kernel (local
+                # shard shape) — never poison the single-device caches
+                # from it
+                pallas_generic.set_mosaic_ok(self.model, self.shape, False)
+            cand = it = None
+        ran = cand.tag if cand else None
+        if ran != selected:
+            telemetry.engine_fallback(selected, ran or "xla", repr(cause),
+                                      model=self.model.name)
+        self._fast, self._fast_name, self._fast_probing = it, ran, False
+        if cand is None:
+            self.state = self._iterate(self.state, self.params, niter)
+            return niter
+        if cand.verdict is not None:
+            # the generic band engine's verdict, remembered process-wide
+            pallas_generic.set_mosaic_ok(self.model, self.shape, True)
             pallas_generic.set_build_cfg(self.model, self.shape,
-                                         *self._fast_cfg)
-        self._fast_probing = False
+                                         *cand.verdict)
         return nfast
 
     def attach_sampler(self, sampler) -> None:

@@ -262,9 +262,9 @@ def _make_diff_step_3d(model: Model, shape, dtype=jnp.float32,
     window mask keeps) and takes ``jax.vjp`` of it in-band; the settings
     tape accumulates per-slab so band overlaps never double-count.
     ``bwd="xla"`` keeps the PR 9 hybrid (Pallas forward / XLA-chain
-    backward) — the measured baseline ``bench.py``'s
-    ``adjoint3d_speedup`` compares against; ``"auto"`` takes the fused
-    kernel whenever :func:`adjoint_slab_plan` finds a feasible config."""
+    backward), the baseline the fused backward is compared against;
+    ``"auto"`` takes the fused kernel whenever :func:`adjoint_slab_plan`
+    finds a feasible config."""
     nz, ny, nx = (int(s) for s in shape)
     if k is None:
         k = max_chunk(model)

@@ -59,16 +59,6 @@ _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
 def _band_rows(model: Model, ny: int, nx: int) -> Optional[int]:
     """Largest band height BY that divides ny, is a multiple of 8 (f32
     sublane tile) and keeps the (n_storage, BY+2, nx) scratch in budget."""
-    import os
-    override = os.environ.get("TCLB_PALLAS_BY")
-    if override:
-        by = int(override)
-        # the override must satisfy the same alignment/budget contract the
-        # kernel's DMA offsets are built on, or Mosaic miscompiles
-        if (by % 8 == 0 and ny % by == 0
-                and model.n_storage * (by + 2) * nx * 4
-                <= _VMEM_SCRATCH_BUDGET * 2):
-            return by
     best = None
     for by in range(8, ny + 1, 8):
         if ny % by:

@@ -193,7 +193,7 @@ def fused_cfg_explain(model: Model, shape, itemsize: int = 4
     term — either no (bz, K) fits ``_FUSED_BUDGET`` (VMEM) or the best
     feasible fused traffic does not beat the single-step engine (cost).
     The Lattice dispatch forwards the reason as a ``fused_rejected``
-    telemetry event so a silent single-step demotion (the PR-5 bench's
+    telemetry event so a silent single-step demotion (once seen as an
     untagged d3q27 engine) can never recur unnoticed."""
     if model.name not in _SUPPORTED or len(shape) != 3:
         return None, "unsupported: model/shape outside the tuned 3D family"

@@ -554,7 +554,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         present: Optional[set] = None,
                         ext_halo: bool = False,
                         by_cap: Optional[int] = None,
-                        full_band: Optional[bool] = None,
+                        full_band: bool = False,
                         shift: Optional[np.ndarray] = None):
     """Build ``iterate(state, params, niter) -> state`` running the model's
     full Iteration action as one fused Pallas band kernel per step.
@@ -614,9 +614,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     nt_present = set(model.node_types) if present is None else set(present)
     if pad > 2 * mirror:
         nt_present = nt_present | {"Wall"}   # middle ghost rows are walls
-    if full_band is None:
-        import os
-        full_band = os.environ.get("TCLB_FULLBAND", "0") == "1"
 
     def _mk_kernel(plan, with_dt=False, with_globals=False, lean=False):
         """Kernel flavor factory: ``with_dt`` adds per-iteration _DT
@@ -788,8 +785,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                       else (lambda i: (0, 0)),
                                       memory_space=pltpu.VMEM)]
             out_shape = [out_shape, jax.ShapeDtypeStruct(gshape, cdtype)]
-        import os
-        vmem_mb = int(os.environ.get("TCLB_VMEM_LIMIT_MB", "0"))
         return pl.pallas_call(
             lbm.mosaic_body(
                 _mk_kernel(plan_n, with_dt, with_globals, lean), interpret),
@@ -809,9 +804,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 pltpu.VMEM((2, n_aux_k, by + 2 * _HALO, nx), cdtype),
                 pltpu.SemaphoreType.DMA((2, 6)),
             ],
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=vmem_mb * 1024 * 1024)
-            if vmem_mb else None,
             interpret=interpret,
             name=f"generic_band_fuse{fuse if plan_n is plan else 1}",
         )

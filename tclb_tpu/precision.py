@@ -68,8 +68,8 @@ def build_case(name: str, n: int = 64):
     Returns ``(model, shape, settings, flags, zonal)`` — the caller
     constructs the Lattice so it can thread ``storage_dtype``.
 
-    * ``cavity`` — the d2q9 driven cavity/channel family the bench's
-      karman case uses: walls top/bottom, WVelocity inflow, EPressure
+    * ``cavity`` — the d2q9 driven cavity/channel family of
+      ``example/karman.xml``: walls top/bottom, WVelocity inflow, EPressure
       outflow, a square obstacle (boundary dispatch + MRT bulk).
     * ``kuper_drop`` — the d2q9_kuper drop.xml physics: a liquid drop
       (zone-1 Density) equilibrating in vapor; exercises the

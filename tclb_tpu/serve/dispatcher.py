@@ -16,7 +16,7 @@ queue.  This layer turns ``jax.devices()`` into N concurrent lanes:
   ``device_put`` onto the lane's device; results start their D2H copy
   asynchronously right after dispatch.  ``serve.lane_batch`` spans
   carry ``stage_s``/``stall_s`` so ``telemetry report`` can prove the
-  staging is hidden under execution (the bench gate wants >90%);
+  staging is hidden under execution (``fleet_bench`` wants >90%);
 * **size-aware routing** — a cost model compares lane time (~cells x
   niter) against the sharded engine's (~work x (1+overhead)/n, with
   ``decomposition_overhead`` from the mesh divisor search): swarms of

@@ -1,7 +1,5 @@
-"""The fleet-throughput workload: bench case + CI smoke in one place.
-
-``bench.py:fleet_throughput`` and the CI fast job's dispatcher smoke
-both drive this module so the measured thing is identical everywhere:
+"""The fleet-throughput workload, driven by the CI fast and chaos jobs'
+dispatcher smoke (``python -m tclb_tpu.serve.fleet_bench --smoke``):
 
 * **throughput** — the 16-small-cavity-job workload through the
   single-worker :class:`Scheduler` vs the :class:`FleetDispatcher`

@@ -15,8 +15,8 @@ BENCH/ROADMAP triage loop needs:
 
 ``--compare other.jsonl`` diffs two traces engine-by-engine and
 span-by-span, flagging slowdowns beyond ``--threshold`` (default 5%) —
-the intended first tool for localizing regressions like the tracked
-BENCH_r05 ``heat_adj_vs_roofline`` 0.91 -> 0.79 drop.
+the intended first tool for localizing a regression to an engine or a
+span.
 """
 
 from __future__ import annotations
@@ -132,8 +132,8 @@ def _fleet_summary(evts: list[dict]) -> dict:
     Staging overlap is the fraction of host-staging time hidden under
     device execution, ``1 - sum(stall_s)/sum(stage_s)`` over
     ``serve.lane_batch`` spans — a lane's first fill has nothing to
-    overlap with and is excluded (``first=True`` rows).  The bench gate
-    wants >90% on the fleet workload."""
+    overlap with and is excluded (``first=True`` rows).
+    ``serve.fleet_bench`` wants >90% on its workload."""
     lanes = [e for e in evts if e.get("kind") == "span"
              and e.get("name") == "serve.lane_batch"]
     fleet = [e for e in evts if e.get("kind") == "span"

@@ -3,8 +3,7 @@
 A span measures host wall-time around a region.  JAX dispatch is
 asynchronous, so a naive ``perf_counter`` pair times the *enqueue*, not
 the work — callers fence with :meth:`Span.sync` (``jax.block_until_ready``
-on the region's output) before the span closes, the same discipline
-bench.py's ``timed`` enforces with its in-region checksum.
+on the region's output) before the span closes.
 
 When the region carries enough context (``nodes``/``iters`` fields), the
 span exit stamps derived metrics the way the reference prints its own
@@ -13,10 +12,9 @@ MLUPS line (reference src/main.cpp.Rt:100-126):
 * ``mlups``      — ``nodes * iters / dt / 1e6``;
 * ``vs_roofline`` — achieved fraction of this chip's HBM streaming
   roofline under the classical LBM traffic model (``bytes_per_node`` =
-  2 x n_storage x sizeof(real) + flag read per node update) — the same
-  math bench.py gates its credibility asserts on (it imports
-  :data:`HBM_GBS` from here so the two can never drift); absent on a
-  device kind whose bandwidth is not in that table.
+  2 x n_storage x sizeof(real) + flag read per node update), with the
+  bandwidth from :data:`HBM_GBS`; absent on a device kind whose
+  bandwidth is not in that table.
 
 Spans also wrap ``jax.profiler.TraceAnnotation`` when available, so a
 concurrent ``jax.profiler`` capture shows the same region names.
@@ -83,9 +81,9 @@ def fuse_of(engine: Optional[str]) -> int:
     """Temporal-fusion depth encoded in an engine name (the
     ``,fuse=K`` tag every fused engine carries, e.g.
     ``pallas_d3q[d3q19,fuse=3]``); 1 when absent (XLA, unfused
-    engines).  bench.py and the report CLI key their per-engine
-    credibility caps off this, so the tag format lives next to the
-    roofline table it feeds."""
+    engines).  The ``iterate`` span records the depth it reads from the
+    tag through this, so the tag format lives next to the roofline
+    table."""
     if not engine:
         return 1
     m = re.search(r"[\[,]fuse=(-?\d+)", engine)

@@ -170,40 +170,140 @@ def test_lattice_iterate_emits_engine_and_span(tmp_path, monkeypatch):
         assert "vs_roofline" not in e
 
 
-def test_forced_fallback_emits_events(tmp_path, monkeypatch):
-    """Break the resident engine's probe: the dispatch must land on the
-    band engine AND leave an engine_fallback breadcrumb with the cause."""
+def _failing_engine(*args, **kw):
+    def it(state, params, niter):
+        raise RuntimeError("synthetic mosaic failure")
+    return it
+
+
+def _chain_resident(monkeypatch):
+    """tuned resident -> tuned 2D band"""
+    monkeypatch.setattr(pallas_d2q9, "make_resident_iterate",
+                        _failing_engine)
+    return (_karman_lattice()[1], "pallas_resident[d2q9,fuse=8]",
+            "pallas_2d[d2q9,fuse=2]")
+
+
+def _chain_d3q(monkeypatch):
+    """pallas_d3q fuse K >= 2 -> fuse 1"""
+    from tclb_tpu.ops import pallas_d3q
+    real = pallas_d3q.make_pallas_iterate
+    monkeypatch.setattr(
+        pallas_d3q, "make_pallas_iterate",
+        lambda *a, **kw: (real if kw.get("fuse") == 1
+                          else _failing_engine)(*a, **kw))
+    m = get_model("d3q27_BGK")
+    shape = (8, 16, 64)
+    lat = Lattice(m, shape, dtype=jnp.float32,
+                  settings={"omega": 1.0, "GravitationX": 1e-5})
+    flags = np.full(shape, m.flag_for("BGK"), dtype=np.uint16)
+    flags[:, 0, :] = flags[:, -1, :] = m.flag_for("Wall")
+    lat.set_flags(flags)
+    lat.init()
+    k3 = pallas_d3q.choose_fuse(m, shape)
+    assert k3 >= 2
+    return (lat, f"pallas_d3q[d3q27_BGK,fuse={k3}]",
+            "pallas_d3q[d3q27_BGK,fuse=1]")
+
+
+def _chain_generic_resident(monkeypatch):
+    """generic resident -> generic band, as the planner fuses it"""
+    from tclb_tpu.ops import pallas_generic
+    monkeypatch.setattr(pallas_generic, "make_resident_iterate",
+                        _failing_engine)
+    m = get_model("d2q9_heat")
+    lat = Lattice(m, (16, 128), dtype=jnp.float32,
+                  settings={"nu": 0.05, "FluidAlfa": 0.05,
+                            "InletVelocity": 0.02})
+    flags = np.full((16, 128), m.flag_for("BGK"), dtype=np.uint16)
+    flags[0, :] = flags[-1, :] = m.flag_for("Wall")
+    lat.set_flags(flags)
+    lat.init()
+    return (lat, "pallas_resident_generic[d2q9_heat,fuse=8]",
+            f"pallas_generic[d2q9_heat,fuse={pallas_generic.choose_fuse(m)}]")
+
+
+@pytest.mark.parametrize("chain", [_chain_resident, _chain_d3q,
+                                   _chain_generic_resident])
+def test_forced_fallback_emits_events(tmp_path, monkeypatch, chain):
+    """Break the probe of a chain's first engine: the dispatch must land
+    on the engine under it AND leave one engine_fallback breadcrumb with
+    the cause."""
     monkeypatch.setenv("TCLB_FASTPATH", "force")
-
-    def bad_resident(model, shape, dtype, present=None):
-        def it(state, params, niter):
-            raise RuntimeError("synthetic mosaic failure")
-        return it
-
-    monkeypatch.setattr(pallas_d2q9, "make_resident_iterate", bad_resident)
-
+    lat, selected, under = chain(monkeypatch)
+    it0 = int(lat.state.iteration)
     trace = tmp_path / "t.jsonl"
     telemetry.enable(str(trace))
-    _, lat = _karman_lattice()
     niter = 5
     lat.iterate(niter)
     telemetry.disable()
 
-    assert lat._fast_name == "pallas_2d[d2q9,fuse=2]"
-    assert int(lat.state.iteration) == niter
+    assert lat._fast_name == under and not lat._fast_probing
+    assert int(lat.state.iteration) == it0 + niter
 
     evts = report.load(str(trace))
     sel = [e for e in evts if e["kind"] == "engine_selected"]
-    assert sel and sel[0]["engine"] == "pallas_resident[d2q9,fuse=8]"
+    assert sel and sel[0]["engine"] == selected
     assert sel[0]["probed"] is True
     fb = [e for e in evts if e["kind"] == "engine_fallback"]
     assert len(fb) == 1
-    assert fb[0]["from"] == "pallas_resident[d2q9,fuse=8]"
-    assert fb[0]["to"] == "pallas_2d[d2q9,fuse=2]"
+    assert (fb[0]["from"], fb[0]["to"]) == (selected, under)
     assert "synthetic mosaic failure" in fb[0]["cause"]
+    probe = [e for e in evts
+             if e["kind"] == "span" and e["name"] == "engine.probe"]
+    assert len(probe) == 1
+    assert (probe[0]["engine"], probe[0]["result"]) == (selected, under)
+    assert (probe[0]["attempts"], probe[0]["rungs"]) == (2, [])
     # the iterate span records the engine that actually finished the chunk
     it = [e for e in evts if e["kind"] == "span" and e["name"] == "iterate"]
-    assert it and it[-1]["engine"] == "pallas_2d[d2q9,fuse=2]"
+    assert it and it[-1]["engine"] == under
+    # and the engine under it was handed the real state: a second call
+    # goes straight to it
+    lat.iterate(niter)
+    assert int(lat.state.iteration) == it0 + 2 * niter
+
+
+@pytest.mark.parametrize("name,shape,cached", [
+    ("d2q9_kuper", (32, 128), None),
+    ("d3q19_heat", (16, 16, 128), None),
+    ("d2q9_kuper", (32, 128), (1, 16))])
+def test_generic_band_ladder_is_data(monkeypatch, name, shape, cached):
+    """The generic band engine's chain as ``_build_fast`` lists it, tags
+    and caps, with no kernel built: the planner's choice first, then
+    smaller bands, then no fusion, then (3D) the raised ceiling; one
+    unprobed link where an earlier probe left its verdict."""
+    from tclb_tpu.ops import pallas_generic
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    monkeypatch.setattr(pallas_generic, "_mosaic_verdict", {})
+    monkeypatch.setattr(pallas_generic, "_cfg_cache", {})
+    monkeypatch.setattr(pallas_generic, "supports_resident",
+                        lambda *a, **k: False)
+    monkeypatch.setattr(pallas_generic, "supports", lambda *a, **k: True)
+    monkeypatch.setattr(
+        pallas_generic, "make_pallas_iterate",
+        lambda *a, **k: pytest.fail("a chain is listed, not built"))
+    m = get_model(name)
+    if cached:
+        pallas_generic.set_build_cfg(m, shape, *cached)
+    lat = Lattice(m, shape, dtype=jnp.float32)
+    chain = lat._build_fast()
+    if cached:
+        assert [(c.tag, c.probe, c.cap, c.verdict) for c in chain] == [
+            (f"pallas_generic[{name},fuse={cached[0]}]", False, 0, None)]
+        return
+    fz = (pallas_generic.choose_fuse(m) if len(shape) == 2
+          else pallas_generic.choose_fuse_3d(m, shape))
+    assert fz >= 2
+    rungs = [(fz, 16), (fz, 8), (1, 16), (1, 8)]
+    if len(shape) == 3:
+        rungs += [(fz, -16), (fz, -8)]
+    assert [c.tag for c in chain] == [f"pallas_generic[{name},fuse={fz}]"] + [
+        f"pallas_generic[{name},fuse={f},by<={cap}]" for f, cap in rungs]
+    # the planner's own band reads as the 2D default cap; 3D has none
+    first_cap = pallas_generic._DEFAULT_BY_CAP if len(shape) == 2 else 0
+    assert [c.cap for c in chain] == [first_cap] + [cap for _, cap in rungs]
+    assert [c.verdict for c in chain] == [(fz, None)] + rungs
+    assert all(c.probe for c in chain)
 
 
 @pytest.mark.parametrize("backend", ["tpu", "cpu"])

@@ -35,6 +35,7 @@ from flax import struct
 
 from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.registry import Model
+from tclb_tpu.ops.fusion import zone_plane
 from tclb_tpu import telemetry
 
 FLAG_DTYPE = jnp.uint16
@@ -149,10 +150,12 @@ def series_overrides(params: SimParams, i: int, iteration) -> list:
 
     Returned as per-zone SCALARS to be applied with
     ``jnp.where(zones == z, value, plane)`` against a loop-invariant
-    base plane: modifying the zone TABLE and re-gathering per step keeps
-    a (zone_max,)->(ny,nx) gather inside the iteration scan, which XLA
-    cannot hoist and lowers catastrophically (~25 ms/step at 1024^2 on
-    v5e); masked selects against the hoisted base plane are free."""
+    base plane: modifying the zone TABLE and indexing it with the zone
+    ids per step kept a (zone_max,)->(ny,nx) gather inside the iteration
+    scan, which XLA could not hoist and lowered catastrophically
+    (~25 ms/step at 1024^2 on v5e); masked selects against the hoisted
+    base plane are free.  No such gather is left: the base plane itself
+    is a chain of selects (``fusion.zone_plane``)."""
     rows = [(z, r) for (si, z, r) in params.series_map if si == i]
     if not rows or params.time_series is None:
         return []
@@ -252,8 +255,9 @@ class NodeCtx:
 
     def setting(self, name: str) -> jnp.ndarray:
         """Scalar for plain settings; per-node plane for zonal settings
-        (gathered through the flag's zone bits — reference ``ZoneSetting()``
-        device accessor, src/LatticeContainer.h.Rt:89-108).  Zones with a
+        (selected through the flag's zone bits, ``fusion.zone_plane`` —
+        reference ``ZoneSetting()`` device accessor,
+        src/LatticeContainer.h.Rt:89-108).  Zones with a
         registered time series (``<Control>``) read the current iteration's
         entry instead of the constant table."""
         m = self.model
@@ -261,7 +265,7 @@ class NodeCtx:
         spec = m.settings[i]
         if not spec.zonal:
             return self.params.settings[i]
-        plane = self.params.zone_table[i][self._zones()]
+        plane = zone_plane(self.params.zone_table[i], self._zones())
         for z, v in series_overrides(self.params, i, self.iteration):
             plane = jnp.where(self._zones() == z,
                               v.astype(plane.dtype), plane)

@@ -13,10 +13,10 @@ This module holds the *planning* logic — picking the fusion depth ``K``
 (and slab depth ``bz`` in 3D) from the VMEM budget and the traffic
 model — so the 2D band engine, the 3D generic slab engine and the tuned
 d3q slab engine all make the same decision the same way.  It also holds
-the in-kernel zonal-plane reconstruction used by the lean aux flavors
-(flags are DMA'd; zonal settings are a pure function of the zone bits
-and the SMEM zone table, so shipping them as planes is wasted HBM
-traffic).
+the zonal-plane reconstruction (:func:`zone_plane`): in the lean aux
+kernels (flags are DMA'd; zonal settings are a pure function of the zone
+bits and the SMEM zone table, so shipping them as planes is wasted HBM
+traffic), and wherever an XLA program needs a zonal setting per node.
 """
 
 from __future__ import annotations
@@ -198,18 +198,27 @@ def snapshot_mem_slots(n_storage: int, shape: Tuple[int, ...],
     return max(1, int(budget_bytes) // per_snap)
 
 
-def zone_plane(ztab, col: int, zone_max: int, zones,
+def zone_plane(ztab, zones, zone_max: Optional[int] = None, col: int = 0,
                zones_present: Optional[Iterable[int]] = None):
-    """Reconstruct one zonal-setting plane inside a kernel.
+    """One zonal setting's per-node plane, ``row[zones]`` bit for bit,
+    built from selects — in a kernel and in the XLA programs round it.
 
-    ``ztab`` is the flattened SMEM zone table (row ``col`` holds that
-    setting's per-zone values, ``ztab[col * zone_max + z]``); ``zones``
-    the flag-derived zone ids (``flags >> zone_shift``, always in
-    ``[0, zone_max)`` by bit width).  A where-chain over the present
-    zones reproduces the host-side ``zone_table[si][zones]`` gather
-    bit-exactly; ``zones_present=None`` means all zones (exact parity
-    with no host knowledge).
+    ``ztab`` holds the setting's per-zone values in the compute dtype at
+    ``ztab[col * zone_max + z]``: a row of ``SimParams.zone_table``
+    (``col`` 0, ``zone_max`` its length) or, in a kernel, the flattened
+    SMEM zone table of all zonal settings; ``zones`` the flag-derived
+    zone ids
+    (``flags >> zone_shift``, always in ``[0, zone_max)`` by bit width).
+    A where-chain over the present zones returns the table's own values;
+    ``zones_present=None`` means all zones (exact with no host
+    knowledge).  Nothing indexes ``ztab`` by ``zones``: above 64 zones
+    XLA lowers that on the TPU to a true gather at 6 to 10 ns a node,
+    where the chain fuses into one elementwise pass.  The gradient with
+    respect to ``ztab`` is a masked sum per zone (the gather's
+    scatter-add up to summation order).
     """
+    if zone_max is None:
+        zone_max = ztab.shape[0]
     zs = list(zones_present) if zones_present is not None \
         else list(range(zone_max))
     v0 = ztab[col * zone_max + zs[0]]

@@ -317,8 +317,8 @@ def _make_diff_step_3d(model: Model, shape, dtype=jnp.float32,
                 zones = flags_i32 >> zshift
                 aux = jnp.stack(
                     [flags_i32.astype(cdtype)]
-                    + [p.zone_table[j].astype(cdtype)[zones]
-                       for j in zonal_si])
+                    + [fusion.zone_plane(p.zone_table[j].astype(cdtype),
+                                         zones) for j in zonal_si])
 
                 def call(f, it):
                     return call_g(sett, it[None], f, aux)
@@ -375,8 +375,8 @@ def _make_diff_step_3d(model: Model, shape, dtype=jnp.float32,
                 zones = flags_i32 >> zshift
                 aux = jnp.stack(
                     [flags_i32.astype(cdtype)]
-                    + [p.zone_table[j].astype(cdtype)[zones]
-                       for j in zonal_si])
+                    + [fusion.zone_plane(p.zone_table[j].astype(cdtype),
+                                         zones) for j in zonal_si])
                 lam_f, sett_acc = call_bwd(sett, lg, it_arr,
                                            fields.astype(cdtype),
                                            lam_f_ct, aux)
@@ -526,8 +526,8 @@ def _mk_call_bwd_3d(model: Model, shape, cdtype, interpret, present,
         flags_full = bufa[slot, 0].astype(jnp.int32)
         if ztab is not None:
             zones_full = flags_full >> zshift
-            zonal_full = {nm: fusion.zone_plane(ztab, j, zone_max,
-                                                zones_full)
+            zonal_full = {nm: fusion.zone_plane(ztab, zones_full,
+                                                zone_max, col=j)
                           for j, nm in enumerate(zonal_names)}
         else:
             zonal_full = {nm: bufa[slot, 1 + j]
@@ -958,7 +958,8 @@ def make_diff_step(model: Model, shape, dtype=jnp.float32,
     def _aux_base(params: SimParams, flags):
         flags_i32 = flags.astype(jnp.int32)
         zones = flags_i32 >> zshift
-        base = [params.zone_table[j].astype(dtype)[zones] for j in zonal_si]
+        base = [fusion.zone_plane(params.zone_table[j].astype(dtype), zones)
+                for j in zonal_si]
         return flags_i32.astype(dtype), zones, base
 
     def _aux_series(params: SimParams, flags_f, zones, base, it):
@@ -1010,7 +1011,7 @@ def make_diff_step(model: Model, shape, dtype=jnp.float32,
 
     def prepare(state: LatticeState, params: SimParams):
         """Bind the loop-invariant inputs ONCE per (jitted) gradient
-        call: the zonal gather, settings cast and aux assembly must
+        call: the zonal planes, settings cast and aux assembly must
         happen OUTSIDE the step scan — as scan-carry derived values they
         would re-run every step (flags ride the carry, so XLA cannot
         hoist them).  Called INSIDE the differentiated trace, so

@@ -23,7 +23,8 @@ Design (TPU-first, not a CUDA translation):
   (the matrices are ±small-integer constants; an MXU matmul would waste a
   128x128 systolic pass on a 9-vector);
 * scalar Settings ride in SMEM; zonal Settings (Velocity/Density) are
-  pre-gathered into per-node planes outside the kernel (they are constant
+  built into per-node planes outside the kernel, once a call, by selects
+  over the zones (``fusion.zone_plane``; they are constant
   across an ``Iterate`` call — the reference reads them per node from const
   memory through the zone bits, src/LatticeContainer.h.Rt:89-108).
 
@@ -50,7 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
-from tclb_tpu.ops import lbm
+from tclb_tpu.ops import fusion, lbm
 from tclb_tpu.ops.lbm import equilibrium, present_types  # noqa: F401
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
@@ -168,18 +169,20 @@ def _sparse_matvec(mat: np.ndarray, planes: list) -> list:
     return out
 
 
-def gather_zonal_planes(model: Model, params, zones, dtype):
+def zonal_planes(model: Model, params, zones, dtype):
     """Per-node (velocity, density) planes from the zonal tables — the
-    kernels' static per-call inputs.  Models without a Density setting
-    (d2q9_new) parameterize the boundary density via zonal Pressure,
-    rho = 1 + 3 p."""
+    kernels' static per-call inputs, built from selects
+    (:func:`fusion.zone_plane`), never by indexing the table with the
+    zone ids.  Models without a Density setting (d2q9_new) parameterize
+    the boundary density via zonal Pressure, rho = 1 + 3 p."""
     si = model.setting_index
-    vel = params.zone_table[si["Velocity"]].astype(dtype)[zones]
-    if "Density" in si:
-        den = params.zone_table[si["Density"]].astype(dtype)[zones]
-    else:
-        den = 1.0 + 3.0 * \
-            params.zone_table[si["Pressure"]].astype(dtype)[zones]
+
+    def plane(name):
+        return fusion.zone_plane(params.zone_table[si[name]].astype(dtype),
+                                 zones)
+    vel = plane("Velocity")
+    den = plane("Density") if "Density" in si \
+        else 1.0 + 3.0 * plane("Pressure")
     return vel, den
 
 
@@ -316,7 +319,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
                      ) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
         zones = flags_i32 >> zshift
-        vel, den = gather_zonal_planes(model, params, zones, dtype)
+        vel, den = zonal_planes(model, params, zones, dtype)
         sett = params.settings.astype(dtype)
 
         def body(fields, _):
@@ -813,7 +816,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             fields = jnp.concatenate([fields, fields[:, init_src, :]],
                                      axis=1)
         zones = flags_i32 >> zshift
-        vel, den = gather_zonal_planes(model, params, zones, dtype)
+        vel, den = zonal_planes(model, params, zones, dtype)
         sett = params.settings.astype(dtype)
 
         def refresh(fields):

@@ -23,7 +23,7 @@ Design (TPU-first):
   collision reuses ``ops.cumulant.collide_d3q27`` / the BGK equilibrium
   verbatim (those modules are written in Mosaic-safe primitives);
 * scalar Settings ride in SMEM; zonal Velocity/Density (+Turbulence) are
-  pre-gathered into per-node planes outside the kernel;
+  built into per-node planes outside the kernel (``fusion.zone_plane``);
 * like the d2q9 kernel this is the "NoGlobals" specialization
   (src/cuda.cu.Rt Globals-mode template): ``state.globals_`` is zeroed.
   The cumulant model's running averages (avgP/avgU) ARE accumulated, and
@@ -781,7 +781,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
         flagbuf = scrg[slot]
         zones = flagbuf >> zshift
-        zonalbuf = [fusion.zone_plane(ztab, c, zone_max, zones)
+        zonalbuf = [fusion.zone_plane(ztab, zones, zone_max, col=c)
                     for c in range(len(zonal_names))]
         synthbuf = [ddf.widen_plane(scrf[slot, j], cdtype, _shifts[j])
                     for j in synth_idx] if is_cumulant else None
@@ -871,8 +871,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         zones = flags_i32 >> zshift
         # zonal planes, settings and the SMEM zone table ride in the
         # COMPUTE dtype: only the field stack pays the storage narrowing
-        zonal = jnp.stack([params.zone_table[j].astype(cdtype)[zones]
-                           for j in zonal_si])
+        zonal = jnp.stack([fusion.zone_plane(
+            params.zone_table[j].astype(cdtype), zones) for j in zonal_si])
         sett = params.settings.astype(cdtype)
         fields = state.fields.astype(dtype)
 

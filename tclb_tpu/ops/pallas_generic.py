@@ -20,9 +20,9 @@ generator would have emitted:
 * multi-stage actions (e.g. d2q9_kuper's Run + CalcPhi) run back-to-back in
   one band pass on progressively-shrinking row extensions, so multi-stage
   models stream their state from HBM ONCE per iteration;
-* zonal settings are pre-gathered into per-node planes that ride the aux
-  DMA (the reference reads them per node through the flag's zone bits,
-  src/LatticeContainer.h.Rt:89-108);
+* zonal settings are built into per-node planes (``fusion.zone_plane``)
+  that ride the aux DMA (the reference reads them per node through the
+  flag's zone bits, src/LatticeContainer.h.Rt:89-108);
 * the ``present`` node-type set specializes the trace on the painted
   boundary types (reference compile-time kernel zoo specialization).
 
@@ -389,7 +389,7 @@ class KernelCtx(NodeCtx):
 
     The model's stage function cannot tell the difference: ``group`` /
     ``density`` return the streamed band planes, ``load`` reaches into the
-    band's halo rows, zonal ``setting``s are pre-gathered planes, node-type
+    band's halo rows, zonal ``setting``s are prebuilt planes, node-type
     tests run on the band's flag rows.  (The reference's ``Node_Run`` object
     plays this role per thread; here it's per band.)"""
 
@@ -717,8 +717,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         flags_full = bufa[slot, 0].astype(jnp.int32)
         if ztab is not None:
             zones_full = flags_full >> zshift
-            zonal_full = {nm: fusion.zone_plane(ztab, j, zone_max,
-                                                zones_full)
+            zonal_full = {nm: fusion.zone_plane(ztab, zones_full,
+                                                zone_max, col=j)
                           for j, nm in enumerate(zonal_names)}
             dt_full = {}
         else:
@@ -857,11 +857,12 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
         # loop-invariant pieces (XLA hoists them out of the step scan):
         # the base zonal planes and the affected-zone masks.  Per step
-        # only scalar masked selects remain — a zone-table re-gather
-        # inside the scan is ~25 ms/step at 1024^2 (unhoistable gather)
+        # only scalar masked selects remain — indexing a modified zone
+        # table with the zone ids inside the scan was an unhoistable
+        # gather, ~25 ms/step at 1024^2
         flags_f = flags_i32.astype(cdtype)
-        base_planes = [params.zone_table[k].astype(cdtype)[zones]
-                       for k in zonal_si]
+        base_planes = [fusion.zone_plane(
+            params.zone_table[k].astype(cdtype), zones) for k in zonal_si]
 
         def aux_of(it):
             return assemble_aux(params, zones, flags_f, base_planes,
@@ -1150,7 +1151,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         sett = params.settings.astype(cdtype)
         aux = jnp.stack(
             [flags_i32.astype(cdtype)]
-            + [params.zone_table[j].astype(cdtype)[zones]
+            + [fusion.zone_plane(params.zone_table[j].astype(cdtype), zones)
                for j in zonal_si])
         fields = _call_for(niter)(sett, state.iteration[None],
                                   state.fields.astype(dtype), aux)
@@ -1445,8 +1446,8 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             flags_full = bufa[slot, 0].astype(jnp.int32)
             if ztab is not None:
                 zones_full = flags_full >> zshift
-                zonal_full = {nm: fusion.zone_plane(ztab, j, zone_max,
-                                                    zones_full)
+                zonal_full = {nm: fusion.zone_plane(ztab, zones_full,
+                                                    zone_max, col=j)
                               for j, nm in enumerate(zonal_names)}
                 dt_full = {}
             else:
@@ -1586,8 +1587,8 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
         sett = params.settings.astype(cdtype)
         has_series = params.time_series is not None
         flags_f = flags_i32.astype(cdtype)
-        base_planes = [params.zone_table[k].astype(cdtype)[zones]
-                       for k in zonal_si]
+        base_planes = [fusion.zone_plane(
+            params.zone_table[k].astype(cdtype), zones) for k in zonal_si]
 
         def aux_of(it):
             return assemble_aux(params, zones, flags_f, base_planes,

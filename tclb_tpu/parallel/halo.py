@@ -239,7 +239,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
     Like the single-device fast path this is the "NoGlobals"
     specialization: ``globals_`` is zeroed; the Lattice hybrid's trailing
     XLA step (which psums) supplies them."""
-    from tclb_tpu.ops import pallas_d2q9, pallas_d3q
+    from tclb_tpu.ops import fusion, pallas_d2q9, pallas_d3q
     try:
         _validate_mesh(model, mesh)
     except ValueError:
@@ -318,7 +318,8 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             if mode == "generic2d":
                 aux_ext = exch(jnp.stack(
                     [flags_i32.astype(dtype)]
-                    + [params.zone_table[j].astype(dtype)[zones]
+                    + [fusion.zone_plane(
+                        params.zone_table[j].astype(dtype), zones)
                        for j in gz_si]))
 
                 def bodyg(carry, _):
@@ -329,7 +330,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                 (fields, _), _ = lax.scan(
                     bodyg, (fields, state.iteration), None, length=niter)
             elif model.ndim == 2:
-                vel, den = pallas_d2q9.gather_zonal_planes(
+                vel, den = pallas_d2q9.zonal_planes(
                     model, params, zones, dtype)
                 aux_ext = exch(jnp.stack(
                     [flags_i32.astype(dtype), vel, den]))
@@ -343,8 +344,9 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                     fields = call1(sett, exch(fields), flags_i32, vel,
                                    den)
             else:
-                zonal = jnp.stack([params.zone_table[j].astype(dtype)[zones]
-                                   for j in zonal_si])
+                zonal = jnp.stack([fusion.zone_plane(
+                    params.zone_table[j].astype(dtype), zones)
+                    for j in zonal_si])
 
                 def body3(f, _):
                     return call3(sett, exch(f), flags_i32, zonal), None

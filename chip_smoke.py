@@ -136,6 +136,7 @@ REHEARSAL = {
     "karman_1024.xml": (20, {"nx": 256, "ny": 512}),
     "3d_channel.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
     "3d_channel_512.xml": (8, {"nx": 128, "ny": 16, "nz": 16}),
+    "tgv_256.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
     "drop_512.xml": (10, {"nx": 384, "ny": 384}),
     "karman_4096.xml": (12, {"nx": 128, "ny": 256}),
 }
@@ -380,9 +381,13 @@ def one_chip(s: Smoke) -> None:
             ("pallas_2d[d2q9,fuse=2]",))
     s.phase("3d_channel", s.run, s.case("3d_channel.xml", 1000), cumulant)
     s.phase("3d_channel_512", s.run, s.case("3d_channel_512.xml"), cumulant)
+    # a 256 x 256 plane, which the engine tiles in y; its initial field is
+    # set by <CallPython>
+    s.phase("3d_tgv_256", s.run, s.case("tgv_256.xml", 500), cumulant)
     s.phase("generic_drop_512", s.run, s.case("drop_512.xml"), generic)
     s.phase("agree_d2q9", s.agree, "karman_1024.xml", ("pallas_2d[d2q9,",))
     s.phase("agree_d3q27_cumulant", s.agree, "3d_channel.xml", cumulant)
+    s.phase("agree_d3q27_cumulant_tiled", s.agree, "tgv_256.xml", cumulant)
     s.phase("agree_d2q9_kuper", s.agree, "drop_512.xml", generic)
 
 

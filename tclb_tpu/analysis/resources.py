@@ -187,28 +187,38 @@ def check_resources(model: Model, shape=None) -> list:
                     {"fuse": K3, "bz": bzK, "reach": RK,
                      "scratch_bytes": estK}))
         from tclb_tpu.ops import pallas_d3q
-        cfg = pallas_d3q.fused_cfg(model, shape)
-        if cfg is not None:
-            bzD, KD = cfg
-            if not pallas_d3q._fused_fits(model, nz, ny, nx, bzD, KD):
+        # the plan the engine builds: whole planes in bands of bz slabs
+        # (fused_cfg), or a plane no single-step kernel holds tiled in y
+        # too (tile_plan); one account, the planner's own, audits both
+        tile = pallas_d3q.tile_plan(model, shape)
+        whole = pallas_d3q.fused_cfg(model, shape)
+        if tile is not None or whole is not None:
+            bzD, byD, KD = tile or (whole[0], None, whole[1])
+            by = byD if byD is not None and byD < ny else None
+            rows = ny if by is None else by + 2 * pallas_d3q._HALO_Y
+            said = f"(bz={bzD}, K={KD})" if by is None \
+                else f"(bz={bzD}, by={by}, K={KD})"
+            if not pallas_d3q._fused_fits(model, nz, ny, nx, bzD, KD,
+                                          by=by):
                 findings.append(Finding(
                     "resources.fused_vmem", "error", model.name,
-                    f"tuned d3q planner picked (bz={bzD}, K={KD}) but "
+                    f"tuned d3q planner picked {said} but "
                     f"its working set exceeds the "
                     f"{pallas_d3q._FUSED_BUDGET >> 20} MB fused budget "
                     f"at {nz}x{ny}x{nx}: planner/builder drift", where,
-                    {"fuse": KD, "bz": bzD}))
+                    {"fuse": KD, "bz": bzD, "by": by}))
             else:
                 H = bzD + 2 * KD
-                per = ny * nx * 4
-                estD = (2 * (model.n_storage + 1) * H
-                        + 2 * model.n_storage * bzD) * per
+                estD = (2 * (model.n_storage + 1) * H * rows
+                        + 2 * model.n_storage * bzD * (by or ny)) * nx * 4
                 findings.append(Finding(
                     "resources.fused_slab", "info", model.name,
-                    f"tuned d3q fused engine: fuse={KD} bz={bzD} "
-                    f"scratch~{estD >> 20} MiB (+ collision "
+                    f"tuned d3q fused engine: fuse={KD} bz={bzD}"
+                    + (f" by={by}" if by else "")
+                    + f" scratch~{estD >> 20} MiB (+ collision "
                     "temporaries)", where,
-                    {"fuse": KD, "bz": bzD, "scratch_bytes": estD}))
+                    {"fuse": KD, "bz": bzD, "by": by,
+                     "scratch_bytes": estD}))
         # -- fused 3D backward kernel at the production chunk ----------- #
         # mirror the 2D adjoint_layout finding: evaluate the Run_b slab
         # planner at the shape production actually runs, so an infeasible

@@ -818,7 +818,12 @@ class acCallPython(Handler):
         fn = self.node.get("function", "run")
         self._fn = getattr(importlib.import_module(mod), fn)
         if not self.every_iter:
-            return self.do_it()
+            # run once, where the element stands: an initial field.  Its
+            # seconds are set-up, so a trace names them
+            with telemetry.span("callpython", module=mod, function=fn) as sp:
+                ret = self.do_it()
+                sp.sync(self.solver.lattice.state.fields)
+            return ret
         return 0
 
     def do_it(self) -> int:

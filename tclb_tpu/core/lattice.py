@@ -770,6 +770,16 @@ class EngineCandidate:
     #                          (fuse, by_cap) to remember once it has run
 
 
+@partial(jax.jit, static_argnames=("idx",))
+def _write_planes(fields, planes, idx):
+    """``fields`` with ``planes`` written at storage indices ``idx``, as
+    one program: a plane at a time, dispatched eagerly, a state of
+    gigabytes has one whole copy in flight for every plane written."""
+    for i, plane in zip(idx, planes):
+        fields = fields.at[i].set(plane)
+    return fields
+
+
 class Lattice:
     """Host-side convenience wrapper, mirroring the reference ``Lattice``
     class surface (src/Lattice.h.Rt:36-168): allocate, Init, Iterate,
@@ -1044,17 +1054,21 @@ class Lattice:
         if not has_series and pallas_d3q.supports(model, shape, sdt):
             make = pallas_d3q.make_pallas_iterate
             k3 = pallas_d3q.choose_fuse(model, shape, itemsize=s_itemsize)
-            # no fuse given: the builder's own planner picks its (bz, K)
-            chain = [cand(f"pallas_d3q[{name},fuse={k3}]", make,
-                          probe=k3 >= 2, shift=shift)]
+
+            def tag3(fuse, pin=None):
+                # a plane the engine tiles says so: the rows of its bands
+                plan = pallas_d3q.tile_plan(model, shape, s_itemsize, pin)
+                by = f",by={plan[1]}" if plan and plan[1] < shape[1] else ""
+                return f"pallas_d3q[{name},fuse={fuse}{by}]"
+            # no fuse given: the builder's own planner picks its plan
+            chain = [cand(tag3(k3), make, probe=k3 >= 2, shift=shift)]
             if k3 >= 2:
                 # K>=2 multi-step fusion (one HBM round trip per K steps)
                 # compiles against the raised scoped-vmem ceiling: first
                 # TPU compile may still hit Mosaic temporaries the planner
                 # can't see, so the fused build is probed; the K=1 block
                 # kernel is the proven engine for these models
-                chain.append(cand(f"pallas_d3q[{name},fuse=1]", make,
-                                  fuse=1, shift=shift))
+                chain.append(cand(tag3(1, 1), make, fuse=1, shift=shift))
             else:
                 # single-step demotion must never be silent: record WHY
                 # the fused planner rejected every (bz, K) so a floor
@@ -1359,7 +1373,7 @@ class Lattice:
         per-plane set_density would re-shard the whole state each time).
         Values are RAW distributions; the shifted rung removes ``w_i``
         in the compute dtype before narrowing."""
-        fields = self.state.fields
+        idxs, planes = [], []
         for name, value in values.items():
             idx = self.model.storage_index[name]
             w = self._plane_w(idx)
@@ -1369,8 +1383,10 @@ class Lattice:
                 plane = ddf.narrow_plane(
                     jnp.asarray(value, dtype=self.dtype),
                     self.storage_dtype, w)
-            fields = fields.at[idx].set(plane)
-        self.state = dataclasses.replace(self.state, fields=fields)
+            idxs.append(idx)
+            planes.append(plane)
+        self.state = dataclasses.replace(self.state, fields=_write_planes(
+            self.state.fields, tuple(planes), tuple(idxs)))
         if self._place is not None:
             self.state, self.params = self._place()
 

@@ -13,9 +13,13 @@ Design (TPU-first):
 * the lattice (nz, ny, nx) is tiled into **z-slab bands** of ``BZ`` slabs;
   each grid step DMAs its band plus one wrapped halo slab above and below
   into VMEM.  The (ny, nx) plane is the natural (sublane, lane) tile and
-  stays whole — the baseline-scale 3D cases (e.g. the reference's
-  256x48x48 forced channel, example/3d_channel_test_periodic_force_driven
-  .xml) fit whole planes comfortably;
+  stays whole where it fits — the baseline-scale 3D cases (e.g. the
+  reference's 256x48x48 forced channel, example/
+  3d_channel_test_periodic_force_driven.xml) fit whole planes
+  comfortably.  A plane that fits no engine whole (a 256^3 box) is tiled
+  in y too: the fused kernel's windows become bands of ``BY`` rows with
+  one sublane tile (``_HALO_Y`` rows) of wrapped halo rows a side, x
+  stays whole (the lane axis) — :func:`tile_plan`;
 * pull-streaming is slab-select in z (the halo slabs make ``z ± 1``
   local), a static 1-row roll in y (sublane shift) and a lane-roll in x;
 * the boundary dispatch reuses ``family.boundary_cases`` — the IDENTICAL
@@ -48,6 +52,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tclb_tpu import telemetry
 from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
@@ -73,6 +78,24 @@ _VMEM_BUDGET = 15 * 1024 * 1024
 _FUSED_BUDGET = 80 * 1024 * 1024
 _FUSED_VMEM_LIMIT = 100 * 1024 * 1024
 _TEMP_PLANES = 6
+# the same count for a y-tiled window.  Mosaic's own reports for this
+# kernel (compiled for a described v5e, d3q27_cumulant at 256^3, PR 32)
+# come to 2.5 to 2.7 planes a population at K >= 2 and 1.3 at K = 1;
+# whole planes keep the 6 they were planned with until their plans are
+# measured again (the channel cell's (bz, K) is pinned on it)
+_TILE_TEMP_PLANES = 3
+# what recomputed node steps cost, in the planes of _fused_cost: a tiled
+# window computes (1 + (K-1)/bz) x (by + 2 _HALO_Y)/by node steps for a
+# useful one, at 0.12 to 0.16 ns each for the cumulant collision on a v5e
+# (PR 32's sweep of nine plans at 256^3), which is 21 to 28 planes of DMA
+# traffic at the 86 % of the HBM peak the kernel's copies reach.  The
+# cheaper collisions of the family are not measured: for them this errs
+# toward less recomputation
+_RECOMPUTE_PLANES = 23
+# wrapped halo rows a side of a y band: one sublane tile, so every DMA
+# window starts on a tile boundary; it covers any K <= fusion.FUSE_MAX
+# (the in-window y roll spoils one row a side per step)
+_HALO_Y = 8
 
 E = cumulant.velocity_set(3)
 W = lbm.weights(E)
@@ -92,7 +115,7 @@ _RING = 4   # ring capacity: slab j lives in slot j % 4 for its 3-step life
 
 
 def _ring_ok(model: Model, nz: int, ny: int, nx: int,
-             itemsize: int = 4) -> bool:
+             itemsize: int = 4, budget: int = _VMEM_BUDGET) -> bool:
     """Whether the rolling-window (neighbor-slab reuse) kernel applies:
     one z-slab per grid step, ring of 4 resident slabs, each slab DMA'd
     from HBM ONCE per lattice step (vs (bz+2)/bz read amplification of
@@ -105,11 +128,12 @@ def _ring_ok(model: Model, nz: int, ny: int, nx: int,
     naux = ns - q
     per = ny * nx * itemsize
     need = (_RING * q + 2 * naux + 2 * ns + 2 * 4) * per
-    return nz % _RING == 0 and nz >= 2 * _RING and need <= _VMEM_BUDGET
+    return nz % _RING == 0 and nz >= 2 * _RING and need <= budget
 
 
 def _slab_depth(model: Model, nz: int, ny: int, nx: int,
-                itemsize: int = 4) -> Optional[int]:
+                itemsize: int = 4,
+                budget: int = _VMEM_BUDGET) -> Optional[int]:
     """Largest band depth BZ dividing nz whose working set fits VMEM:
     scratch (ns, BZ+2) slabs + output block + flag/zonal blocks + the
     collision's live intermediates (~6 stacked q-plane tensors)."""
@@ -126,7 +150,7 @@ def _slab_depth(model: Model, nz: int, ny: int, nx: int,
         # remains of the ~16 MB VMEM (Mosaic errors loudly if they don't)
         need = (2 * q * (bz + 2) + 2 * naux * bz + 2 * ns * bz
                 + 2 * 4 * bz) * per
-        if need > _VMEM_BUDGET:
+        if need > budget:
             break
         best = bz
     return best
@@ -137,28 +161,44 @@ def _n_zonal(model: Model) -> int:
 
 
 def _fused_fits(model: Model, nz: int, ny: int, nx: int,
-                bz: int, K: int, itemsize: int = 4) -> bool:
+                bz: int, K: int, itemsize: int = 4,
+                by: Optional[int] = None,
+                budget: int = _FUSED_BUDGET) -> bool:
     """VMEM predicate for the fused kernel at (bz, K): 2-slot halo'd
     f+aux buffers + 2-slot flag buffers + pipelined out blocks + the
     widest fused window's collision intermediates.  The DMA scratch
     scales with the storage itemsize; the collision temporaries are
-    always compute-dtype (f32) planes."""
+    always compute-dtype (f32) planes.  ``by`` tiles the plane: the
+    windows hold ``by`` rows and ``_HALO_Y`` wrapped halo rows a side,
+    the out blocks ``by`` rows."""
     ns = model.n_storage
     q = _q_of(model)
-    per = ny * nx
+    rows, band = (ny, ny) if by is None else (by + 2 * _HALO_Y, by)
     H = bz + 2 * K
-    scratch = (2 * ns * H + 2 * ns * bz) * per * itemsize
-    flagbuf = 2 * H * per * 4   # int32 flag buffer, itemsize-invariant
-    temp = _TEMP_PLANES * q * (bz + 2 * (K - 1)) * per * 4
-    return scratch + flagbuf + temp <= _FUSED_BUDGET
+    scratch = (2 * ns * H * rows + 2 * ns * bz * band) * nx * itemsize
+    flagbuf = 2 * H * rows * nx * 4   # int32 flags, itemsize-invariant
+    temp = (_TEMP_PLANES if by is None else _TILE_TEMP_PLANES) \
+        * q * (bz + 2 * (K - 1)) * rows * nx * 4
+    return scratch + flagbuf + temp <= budget
 
 
-def _fused_cost(model: Model, bz: int, K: int) -> float:
+def _fused_cost(model: Model, bz: int, K: int,
+                by: Optional[int] = None) -> float:
     """Modeled HBM planes per lattice step of the fused kernel: the
-    f+aux stack and the flag plane are read with K halo slabs per side,
-    the ns output planes written halo-free, all amortized over K steps."""
+    f+aux stack and the flag plane are read with K halo slabs per side
+    (and, tiled, ``_HALO_Y`` halo rows a side of ``by``), the ns output
+    planes written halo-free, all amortized over K steps."""
     ns = model.n_storage
-    return ((ns + 1) * (bz + 2 * K) + ns * bz) / (K * bz)
+    wide = 1.0 if by is None else (by + 2 * _HALO_Y) / by
+    return ((ns + 1) * (bz + 2 * K) * wide + ns * bz) / (K * bz)
+
+
+def _tile_cost(model: Model, bz: int, K: int, by: int) -> float:
+    """What decides between tiled plans: the traffic of
+    :func:`_fused_cost` or the arithmetic of the node steps a window
+    computes more than once, whichever binds."""
+    again = (1.0 + (K - 1) / bz) * (by + 2 * _HALO_Y) / by
+    return max(_fused_cost(model, bz, K, by), _RECOMPUTE_PLANES * again)
 
 
 def _base_cost(model: Model, nz: int, ny: int, nx: int,
@@ -228,8 +268,63 @@ def fused_cfg_explain(model: Model, shape, itemsize: int = 4
         f"single-step {base:.2f}")
 
 
+def _whole_plane(model: Model, nz: int, ny: int, nx: int,
+                 itemsize: int = 4, budget: int = _VMEM_BUDGET) -> bool:
+    """Whether a single-step engine holds whole (ny, nx) planes in VMEM:
+    then every plan (block, ring, fused) keeps the plane whole."""
+    return (_slab_depth(model, nz, ny, nx, itemsize, budget) is not None
+            or _ring_ok(model, nz, ny, nx, itemsize, budget))
+
+
+def _single_share(budget: int) -> int:
+    """What the single-step kernels may fill when the fused kernel may
+    fill ``budget``: the default scoped VMEM against the raised limit."""
+    return budget * _VMEM_BUDGET // _FUSED_BUDGET
+
+
+def tile_plan(model: Model, shape, itemsize: int = 4,
+              fuse: Optional[int] = None,
+              budget: int = _FUSED_BUDGET) -> Optional[tuple]:
+    """Plan ``(bz, by, K)`` of the fused kernel for a shape whose plane
+    no single-step engine holds whole: bands of ``bz`` slabs x ``by``
+    rows advanced ``K`` steps per HBM round trip (``fuse`` pins K; K = 1
+    is the same kernel at one step).  ``by == ny`` keeps the plane whole
+    (no halo rows) where only the fused budget takes it.  The least of
+    :func:`_tile_cost` wins; ties go to the taller band.
+    ``budget`` is the VMEM the fused kernel may fill; the single-step
+    engines get the share of it that ``_VMEM_BUDGET`` is of
+    ``_FUSED_BUDGET`` (:func:`_single_share`).
+    None where :func:`fused_cfg` plans the shape or nothing fits."""
+    if model.name not in _SUPPORTED or len(shape) != 3:
+        return None
+    nz, ny, nx = (int(s) for s in shape)
+    if _whole_plane(model, nz, ny, nx, itemsize, _single_share(budget)):
+        return None
+    bys = [ny] + [b for b in range(ny - _HALO_Y, 0, -_HALO_Y)
+                  if ny % b == 0 and ny % _HALO_Y == 0]
+    best, best_c = None, float("inf")
+    for K in ([fuse] if fuse else range(1, fusion.FUSE_MAX + 1)):
+        if nz < 2 * K:
+            break
+        for by in bys:
+            tile = None if by == ny else by
+            bz = max((b for b in range(1, nz + 1) if nz % b == 0
+                      and _fused_fits(model, nz, ny, nx, b, K, itemsize,
+                                      tile, budget)), default=None)
+            if bz is None:
+                continue
+            c = _fused_cost(model, bz, K) if tile is None \
+                else _tile_cost(model, bz, K, by)
+            if c < best_c:
+                best, best_c = (bz, by, K), c
+    return best
+
+
 def choose_fuse(model: Model, shape, itemsize: int = 4) -> int:
     """Fusion depth K the engine will run at (1 = single-step)."""
+    plan = tile_plan(model, shape, itemsize)
+    if plan:
+        return plan[2]
     cfg = fused_cfg(model, shape, itemsize)
     return cfg[1] if cfg else 1
 
@@ -239,8 +334,9 @@ def supports(model: Model, shape, dtype, ext_halo: bool = False) -> bool:
 
     ``ext_halo=True`` asks about the sharded building block, which only
     has the block kernel — ring-only shapes (whose block working set
-    exceeds VMEM) must answer False there so parallel/halo.py falls back
-    cleanly instead of building a kernel Mosaic will reject."""
+    exceeds VMEM) and planes only :func:`tile_plan` takes must answer
+    False there so parallel/halo.py falls back cleanly instead of
+    building a kernel Mosaic will reject."""
     if model.name not in _SUPPORTED:
         return False
     if len(shape) != 3 or jnp.dtype(dtype) not in (
@@ -254,7 +350,10 @@ def supports(model: Model, shape, dtype, ext_halo: bool = False) -> bool:
         return False  # (ny, nx) is the (sublane, lane) tile
     if _slab_depth(model, nz, ny, nx, itemsize) is not None:
         return True
-    return (not ext_halo) and _ring_ok(model, nz, ny, nx, itemsize)
+    if ext_halo:
+        return False
+    return (_ring_ok(model, nz, ny, nx, itemsize)
+            or tile_plan(model, shape, itemsize) is not None)
 
 
 present_types = lbm.present_types   # shared helper (re-exported)
@@ -266,7 +365,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         ext_halo: bool = False,
                         fuse: Optional[int] = None,
                         fuse_bz: Optional[int] = None,
-                        shift: Optional[np.ndarray] = None):
+                        shift: Optional[np.ndarray] = None,
+                        vmem_budget: int = _FUSED_BUDGET):
     """Build ``iterate(state, params, niter) -> state`` running the fused
     3D Pallas kernel.  Caller must check :func:`supports` first.
 
@@ -297,7 +397,18 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     bz = _slab_depth(model, nz, ny, nx, itemsize) or 1
     if ext_halo:
         fuse = 1
-    if fuse is None:
+    # a plane no single-step engine holds whole: the fused kernel on
+    # (bz, by) windows does every step, the remainder at its K = 1 plan
+    tiled = tile_plan(model, shape, itemsize, fuse, vmem_budget)
+    if tiled is None and not _whole_plane(
+            model, nz, ny, nx, itemsize, _single_share(vmem_budget)):
+        raise ValueError(f"no plan tiles {shape} at fuse={fuse} within "
+                         f"{vmem_budget} B of VMEM")
+    if tiled is not None:
+        cfg = tiled
+        rem_cfg = tiled if tiled[2] == 1 else tile_plan(
+            model, shape, itemsize, 1, vmem_budget)
+    elif fuse is None:
         cfg = fused_cfg(model, shape, itemsize)
     else:
         cfg = None
@@ -311,8 +422,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             if nz % bzf:
                 raise ValueError(f"fused band depth {bzf} must divide {nz}")
             cfg = (bzf, fuse)
-    K = cfg[1] if cfg else 1
-    bzK = cfg[0] if cfg else bz
+    if cfg is not None and len(cfg) == 2:
+        cfg = (cfg[0], ny, cfg[1])       # a whole plane is one y band
+    K = cfg[2] if cfg else 1
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     is_cumulant = model.name == "d3q27_cumulant"
@@ -705,158 +817,190 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         # exactly the order this kernel's zonal_ref expects
         return call, bz, zonal_names
 
-    H = bzK + 2 * K   # fused buffer depth: band + K wrapped halo slabs/side
+    def fused_call(bzK: int, byK: int, K: int):
+        """The multi-step fused band kernel at one plan: K lattice steps
+        per HBM round trip on windows of ``bzK`` slabs x ``byK`` rows
+        (``byK == ny``: whole planes, one y band)."""
+        hy = _HALO_Y if byK < ny else 0
+        H = bzK + 2 * K       # buffer depth: band + K wrapped halo slabs/side
+        R = byK + 2 * hy      # buffer rows: band + hy wrapped halo rows/side
+        nzb, nyb = nz // bzK, ny // byK
 
-    def kernel_fused(sett, ztab, f_hbm, flags_hbm, out_ref, scrf, scrg,
-                     sems):
-        """Multi-step fused band kernel: K lattice steps per HBM round
-        trip.  The DMA'd buffer carries K wrapped halo slabs per side
-        (f + aux stack AND flags — boundary dispatch in the halo region
-        needs true node types so the recomputed halo sites agree with
-        their home band's values); step j (0-based) computes buffer rows
-        [j+1, H-(j+1)) from rows [j, H-j) of the step-(j-1) state, so
-        after K steps rows [K, K+bz) hold the valid K-step-advanced
-        band.  Zonal settings never ride the DMA: they are a pure
-        function of the flag zone bits and the SMEM zone table, so they
-        are reconstructed in-kernel (fusion.zone_plane) — the same aux
-        diet the generic engine runs.  The 2-slot double-buffered band
-        pipeline is kept: band i+1's (wider) blocks prefetch under band
-        i's K-step compute."""
-        i = pl.program_id(0)
-        n = pl.num_programs(0)
+        def pieces(band: int, halo: int):
+            """(offset from the band's first index, buffer index, length)
+            of a band and its wrapped halos along one axis.  A halo no
+            longer than the band (which divides the axis) never straddles
+            the periodic seam and goes as one block; a longer one index
+            by index."""
+            if not halo:
+                return [(0, 0, band)]
+            if band >= halo:
+                return [(0, halo, band), (-halo, 0, halo),
+                        (band, halo + band, halo)]
+            return [(0, halo, band)] + [
+                p for h in range(1, halo + 1)
+                for p in ((-h, halo - h, 1),
+                          (band - 1 + h, halo + band - 1 + h, 1))]
 
-        def band_dmas(slot, band):
-            base = band * jnp.int32(bzK)
-            copies = [
-                pltpu.make_async_copy(
-                    f_hbm.at[:, pl.ds(base, bzK)],
-                    scrf.at[slot, :, pl.ds(K, bzK)], sems.at[slot, 0]),
-                pltpu.make_async_copy(
-                    flags_hbm.at[pl.ds(base, bzK)],
-                    scrg.at[slot, pl.ds(K, bzK)], sems.at[slot, 1]),
-            ]
-            # halo slabs copied one at a time with individual wrapped
-            # indices (a block copy would straddle the periodic seam)
-            for h in range(1, K + 1):
-                zm = jax.lax.rem(base - jnp.int32(h) + jnp.int32(nz),
-                                 jnp.int32(nz))
-                zp = jax.lax.rem(base + jnp.int32(bzK - 1 + h),
-                                 jnp.int32(nz))
-                s = 2 + 4 * (h - 1)
-                copies += [
-                    pltpu.make_async_copy(
-                        f_hbm.at[:, pl.ds(zm, 1)],
-                        scrf.at[slot, :, pl.ds(K - h, 1)],
-                        sems.at[slot, s]),
-                    pltpu.make_async_copy(
-                        f_hbm.at[:, pl.ds(zp, 1)],
-                        scrf.at[slot, :, pl.ds(K + bzK - 1 + h, 1)],
-                        sems.at[slot, s + 1]),
-                    pltpu.make_async_copy(
-                        flags_hbm.at[pl.ds(zm, 1)],
-                        scrg.at[slot, pl.ds(K - h, 1)],
-                        sems.at[slot, s + 2]),
-                    pltpu.make_async_copy(
-                        flags_hbm.at[pl.ds(zp, 1)],
-                        scrg.at[slot, pl.ds(K + bzK - 1 + h, 1)],
-                        sems.at[slot, s + 3]),
-                ]
-            return copies
+        z_pieces, y_pieces = pieces(bzK, K), pieces(byK, hy)
 
-        slot = jax.lax.rem(i, jnp.int32(2))
-        nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(2))
+        def wrap(base, off: int, n: int):
+            """``base + off`` on a periodic axis of ``n``."""
+            return base if not off else jax.lax.rem(
+                base + jnp.int32(off + n), jnp.int32(n))
 
-        @pl.when(i == 0)
-        def _():
-            for c in band_dmas(jnp.int32(0), i):
-                c.start()
+        def kernel_fused(sett, ztab, f_hbm, flags_hbm, out_ref, scrf, scrg,
+                         sems):
+            """The DMA'd buffer carries K wrapped halo slabs per side
+            (f + aux stack AND flags — boundary dispatch in the halo
+            region needs true node types so the recomputed halo sites
+            agree with their home band's values); step j (0-based)
+            computes buffer slabs [j+1, H-(j+1)) from slabs [j, H-j) of
+            the step-(j-1) state, so after K steps slabs [K, K+bz) hold
+            the valid K-step-advanced band.  A y-tiled window rolls in y
+            inside its own rows: each step spoils one more row at either
+            end, K <= hy of them, never a band row.  Zonal settings
+            never ride the DMA: they are a pure function of the flag zone
+            bits and the SMEM zone table, so they are reconstructed
+            in-kernel (fusion.zone_plane) — the same aux diet the generic
+            engine runs.  The 2-slot double-buffered band pipeline is
+            kept: the next band's (wider) blocks prefetch under this
+            band's K-step compute."""
+            i = pl.program_id(0)
+            j = pl.program_id(1) if nyb > 1 else jnp.int32(0)
+            t = i * jnp.int32(nyb) + j       # bands run z-major, y-minor
 
-        @pl.when(i + 1 < n)
-        def _():
-            for c in band_dmas(nxt, i + jnp.int32(1)):
-                c.start()
+            def band_dmas(slot, bi, bj):
+                z0 = bi * jnp.int32(bzK)
+                y0 = bj * jnp.int32(byK) if nyb > 1 else 0
+                copies = []
+                for oz, dz, lz in z_pieces:
+                    for oy, dy_, ly in y_pieces:
+                        sz, sy = wrap(z0, oz, nz), wrap(y0, oy, ny)
+                        if hy:      # bands and halos are whole sublane tiles
+                            sy = pl.multiple_of(sy, _HALO_Y)
+                        s = len(copies)
+                        copies += [
+                            pltpu.make_async_copy(
+                                f_hbm.at[:, pl.ds(sz, lz), pl.ds(sy, ly)],
+                                scrf.at[slot, :, pl.ds(dz, lz),
+                                        pl.ds(dy_, ly)],
+                                sems.at[slot, s]),
+                            pltpu.make_async_copy(
+                                flags_hbm.at[pl.ds(sz, lz), pl.ds(sy, ly)],
+                                scrg.at[slot, pl.ds(dz, lz), pl.ds(dy_, ly)],
+                                sems.at[slot, s + 1]),
+                        ]
+                return copies
 
-        for c in band_dmas(slot, i):
-            c.wait()
+            slot = jax.lax.rem(t, jnp.int32(2))
+            nxt = jax.lax.rem(t + jnp.int32(1), jnp.int32(2))
+            if nyb > 1:
+                turn = j + jnp.int32(1) == jnp.int32(nyb)
+                ni = jnp.where(turn, i + jnp.int32(1), i)
+                nj = jnp.where(turn, jnp.int32(0), j + jnp.int32(1))
+            else:
+                ni, nj = i + jnp.int32(1), j
 
-        flagbuf = scrg[slot]
-        zones = flagbuf >> zshift
-        zonalbuf = [fusion.zone_plane(ztab, zones, zone_max, col=c)
-                    for c in range(len(zonal_names))]
-        synthbuf = [ddf.widen_plane(scrf[slot, j], cdtype, _shifts[j])
-                    for j in synth_idx] if is_cumulant else None
-        if is_cumulant:
-            # widen ONCE, accumulate all K steps in f32, narrow on the
-            # output write (the precision.unsafe_accum contract)
-            acc_p = ddf.widen_plane(scrf[slot, avgp_idx, K:K + bzK],
-                                    cdtype, _shifts[avgp_idx])
-            acc_u = [ddf.widen_plane(scrf[slot, j, K:K + bzK], cdtype,
-                                     _shifts[j])
-                     for j in avgu_idx]
+            @pl.when(t == 0)
+            def _():
+                for c in band_dmas(jnp.int32(0), i, j):
+                    c.start()
 
-        # rows [0, H); widened to the compute dtype for the step chain
-        # (the DDF shift restores once here and removes once at the
-        # final narrow: all K in-between steps run on raw f in f32)
-        cur = [ddf.widen_plane(scrf[slot, k], cdtype, _shifts[k])
-               for k in range(q)]
-        for j in range(K):
-            lo = j + 1                       # output window in buffer rows
-            n_j = bzK + 2 * (K - 1 - j)
-            pulled = []
-            for k in range(q):
-                dx, dy, dz = int(E_[k, 0]), int(E_[k, 1]), int(E_[k, 2])
-                a = lo - dz - j              # cur[k] covers rows [j, H-j)
-                sl = cur[k][a:a + n_j]
-                if dy:
-                    sl = jnp.roll(sl, dy, axis=1)
-                if dx:
-                    sl = pltpu.roll(sl, dx % nx, axis=2)
-                pulled.append(sl)
-            # barrier before collision, same reason as the single-step
-            # kernels: keep the rolls out of the collide fusion so every
-            # fused step's arithmetic is bit-identical to an XLA step
-            f = lbm.pin(jnp.stack(pulled))
-            flags = flagbuf[lo:lo + n_j]
-            zonal = [zb[lo:lo + n_j] for zb in zonalbuf]
-            synth = [sb[lo:lo + n_j] for sb in synthbuf] \
-                if is_cumulant else None
-            fnew, extras = _step(f, flags, zonal, synth, sett)
-            cur = [fnew[k] for k in range(q)]   # now rows [lo, lo + n_j)
+            @pl.when(t + 1 < nzb * nyb)
+            def _():
+                for c in band_dmas(nxt, ni, nj):
+                    c.start()
+
+            for c in band_dmas(slot, i, j):
+                c.wait()
+
+            rows = slice(hy, hy + byK) if hy else slice(None)   # the band's
+
+            flagbuf = scrg[slot]
+            zones = flagbuf >> zshift
+            zonalbuf = [fusion.zone_plane(ztab, zones, zone_max, col=c)
+                        for c in range(len(zonal_names))]
+            synthbuf = [ddf.widen_plane(scrf[slot, j_], cdtype, _shifts[j_])
+                        for j_ in synth_idx] if is_cumulant else None
             if is_cumulant:
-                # running averages accumulate on the central band only,
-                # in the same left-fold order as K single XLA steps
-                c0 = K - lo
-                p_inc, us = extras
-                acc_p = acc_p + p_inc[c0:c0 + bzK]
-                acc_u = [au + u[c0:c0 + bzK] for au, u in zip(acc_u, us)]
+                # widen ONCE, accumulate all K steps in f32, narrow on the
+                # output write (the precision.unsafe_accum contract)
+                acc_p = ddf.widen_plane(
+                    scrf[slot, avgp_idx, K:K + bzK, rows], cdtype,
+                    _shifts[avgp_idx])
+                acc_u = [ddf.widen_plane(scrf[slot, j_, K:K + bzK, rows],
+                                         cdtype, _shifts[j_])
+                         for j_ in avgu_idx]
 
-        for k in range(q):
-            out_ref[k] = ddf.narrow_plane(cur[k], dtype, _shifts[k])
-        if is_cumulant:
-            for j in synth_idx:
-                out_ref[j] = scrf[slot, j, K:K + bzK]
-            out_ref[avgp_idx] = ddf.narrow_plane(acc_p, dtype,
-                                                 _shifts[avgp_idx])
-            for j, au in zip(avgu_idx, acc_u):
-                out_ref[j] = ddf.narrow_plane(au, dtype, _shifts[j])
+            # slabs [0, H); widened to the compute dtype for the step chain
+            # (the DDF shift restores once here and removes once at the
+            # final narrow: all K in-between steps run on raw f in f32)
+            cur = [ddf.widen_plane(scrf[slot, k], cdtype, _shifts[k])
+                   for k in range(q)]
+            for st in range(K):
+                lo = st + 1                  # output window in buffer slabs
+                n_j = bzK + 2 * (K - 1 - st)
+                pulled = []
+                for k in range(q):
+                    dx, dy, dz = int(E_[k, 0]), int(E_[k, 1]), int(E_[k, 2])
+                    a = lo - dz - st         # cur[k] covers slabs [st, H-st)
+                    sl = cur[k][a:a + n_j]
+                    if dy:
+                        sl = jnp.roll(sl, dy, axis=1)
+                    if dx:
+                        sl = pltpu.roll(sl, dx % nx, axis=2)
+                    pulled.append(sl)
+                # barrier before collision, same reason as the single-step
+                # kernels: keep the rolls out of the collide fusion so every
+                # fused step's arithmetic is bit-identical to an XLA step
+                f = lbm.pin(jnp.stack(pulled))
+                flags = flagbuf[lo:lo + n_j]
+                zonal = [zb[lo:lo + n_j] for zb in zonalbuf]
+                synth = [sb[lo:lo + n_j] for sb in synthbuf] \
+                    if is_cumulant else None
+                fnew, extras = _step(f, flags, zonal, synth, sett)
+                cur = [fnew[k] for k in range(q)]   # slabs [lo, lo + n_j)
+                if is_cumulant:
+                    # running averages accumulate on the central band only,
+                    # in the same left-fold order as K single XLA steps
+                    c0 = K - lo
+                    p_inc, us = extras
+                    acc_p = acc_p + p_inc[c0:c0 + bzK, rows]
+                    acc_u = [au + u[c0:c0 + bzK, rows]
+                             for au, u in zip(acc_u, us)]
 
-    if K >= 2:
-        call_f = pl.pallas_call(
+            for k in range(q):
+                out_ref[k] = ddf.narrow_plane(cur[k][:, rows], dtype,
+                                              _shifts[k])
+            if is_cumulant:
+                for j_ in synth_idx:
+                    out_ref[j_] = scrf[slot, j_, K:K + bzK, rows]
+                out_ref[avgp_idx] = ddf.narrow_plane(acc_p, dtype,
+                                                     _shifts[avgp_idx])
+                for j_, au in zip(avgu_idx, acc_u):
+                    out_ref[j_] = ddf.narrow_plane(au, dtype, _shifts[j_])
+
+        return pl.pallas_call(
             lbm.mosaic_body(kernel_fused, interpret),
-            grid=(nz // bzK,),
+            grid=(nzb,) if nyb == 1 else (nzb, nyb),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((ns, bzK, ny, nx), lambda i: (0, i, 0, 0),
-                                   memory_space=pltpu.VMEM),
+            out_specs=pl.BlockSpec(
+                (ns, bzK, byK, nx),
+                (lambda i: (0, i, 0, 0)) if nyb == 1
+                else (lambda i, j: (0, i, j, 0)),
+                memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((ns, nz, ny, nx), dtype),
             scratch_shapes=[
-                pltpu.VMEM((2, ns, H, ny, nx), dtype),
-                pltpu.VMEM((2, H, ny, nx), jnp.int32),
-                pltpu.SemaphoreType.DMA((2, 2 + 4 * K)),
+                pltpu.VMEM((2, ns, H, R, nx), dtype),
+                pltpu.VMEM((2, H, R, nx), jnp.int32),
+                pltpu.SemaphoreType.DMA(
+                    (2, 2 * len(z_pieces) * len(y_pieces))),
             ],
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
@@ -864,32 +1008,43 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             name=f"d3q_slab_fuse{K}",
         )
 
+    call_f = fused_call(*cfg) if cfg else None
+    # the steps a fused call leaves over: the single-step block/ring
+    # kernel where a plane fits it whole, else the fused kernel at K = 1
+    call_r = (None if tiled is None else call_f if K == 1
+              else fused_call(*rem_cfg))
+
     @partial(jax.jit, static_argnames=("niter",), donate_argnums=0)
     def _iterate_jit(state: LatticeState, params: SimParams,
                      niter: int) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
-        zones = flags_i32 >> zshift
         # zonal planes, settings and the SMEM zone table ride in the
         # COMPUTE dtype: only the field stack pays the storage narrowing
-        zonal = jnp.stack([fusion.zone_plane(
-            params.zone_table[j].astype(cdtype), zones) for j in zonal_si])
         sett = params.settings.astype(cdtype)
         fields = state.fields.astype(dtype)
+        ztab = jnp.concatenate(
+            [params.zone_table[j].astype(cdtype) for j in zonal_si])
 
+        def body_f(call_k):
+            return lambda fields, _: (
+                call_k(sett, ztab, fields, flags_i32), None)
+
+        rem = niter
         if K >= 2:
-            ztab = jnp.concatenate(
-                [params.zone_table[j].astype(cdtype) for j in zonal_si])
-
-            def body_f(fields, _):
-                return call_f(sett, ztab, fields, flags_i32), None
-
-            fields, _ = jax.lax.scan(body_f, fields, None,
+            fields, _ = jax.lax.scan(body_f(call_f), fields, None,
                                      length=niter // K)
+            rem = niter % K
+        if tiled is not None:
+            body = body_f(call_r)
+        else:
+            zones = flags_i32 >> zshift
+            zonal = jnp.stack([fusion.zone_plane(
+                params.zone_table[j].astype(cdtype), zones)
+                for j in zonal_si])
 
-        def body(fields, _):
-            return call(sett, fields, flags_i32, zonal), None
+            def body(fields, _):
+                return call(sett, fields, flags_i32, zonal), None
 
-        rem = niter % K if K >= 2 else niter
         fields, _ = jax.lax.scan(body, fields, None, length=rem)
         return LatticeState(
             fields=fields,
@@ -898,12 +1053,35 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             iteration=state.iteration + niter,
         )
 
+    def account(niter: int) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side from
+        the plan (the mirror of ``_iterate_jit``'s schedule): the fused
+        calls' windows, and the steps left to the single-step kernel."""
+        fused = niter // K if cfg else 0
+        rest = niter - fused * K
+        did = dict(kernel_calls=fused + rest, remainder_steps=rest)
+        if fused:
+            bzp, byp, _ = cfg
+            did.update(
+                z_bands=nz // bzp, band_slabs=bzp, halo_slabs=K,
+                y_bands=ny // byp, band_rows=byp,
+                halo_rows=_HALO_Y if byp < ny else 0,
+                aux_planes=1)      # the int32 flag plane rides each window
+        return did
+
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
         if params.time_series is not None:
             raise ValueError(
                 "pallas iterate does not support Control time series; "
                 "use the XLA path for time-dependent zonal settings")
-        return _iterate_jit(state, params, niter)
+        out = _iterate_jit(state, params, niter)
+        # a call under a trace (a caller's own jit) issues nothing
+        if telemetry.enabled() and not isinstance(out.fields,
+                                                  jax.core.Tracer):
+            did = account(int(niter))
+            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            telemetry.annotate(**did)
+        return out
 
     return iterate

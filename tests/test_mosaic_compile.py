@@ -113,6 +113,38 @@ def test_d3q27_cumulant_48x48x256(one_chip, fuse):
                      % (fuse or r"[2-9]"), text)
 
 
+def test_d3q27_cumulant_256_tiled(one_chip):
+    """The plan of ``example/tgv_256.xml``: no kernel holds a 256 x 256
+    plane whole, so the fused kernel runs on y-tiled windows, for the
+    fused calls and for the step they leave over.  Only shapes are
+    described: a 256^3 state is 2.3 GB."""
+    from tclb_tpu.core.lattice import LatticeState
+    shape = (256, 256, 256)
+    m = get_model("d3q27_cumulant")
+    bz, by, K = pallas_d3q.tile_plan(m, shape)
+    assert K >= 2 and by < 256
+    assert pallas_d3q.tile_plan(m, shape, fuse=1)[1] < 256
+    small = Lattice(m, (8, 8, 128), dtype=jnp.float32,
+                    settings={"nu": 0.001273})
+
+    def on_chip(x, dims=None):
+        return jax.ShapeDtypeStruct(dims or x.shape, x.dtype,
+                                    sharding=one_chip)
+    st = small.state
+    state = LatticeState(
+        fields=on_chip(st.fields, (m.n_storage,) + shape),
+        flags=on_chip(st.flags, shape), globals_=on_chip(st.globals_),
+        iteration=on_chip(st.iteration))
+    # every node collides, as in the Taylor-Green box
+    it = pallas_d3q.make_pallas_iterate(m, shape, jnp.float32,
+                                        interpret=False, present={"MRT"})
+    text = jax.jit(lambda s, p: it(s, p, K + 1)).lower(
+        state, jax.tree.map(on_chip, small.params)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"d3q_slab_fuse{K}/pallas_call" in text
+    assert "d3q_slab_fuse1/pallas_call" in text
+
+
 @pytest.mark.parametrize("name", ["d2q9_kuper", "d2q9_heat"])
 def test_generic_512(one_chip, name):
     shape = (512, 512)

@@ -336,3 +336,131 @@ def test_fused_rejected_event(monkeypatch, tmp_path):
     assert rej[0]["engine"] == "pallas_d3q"
     assert rej[0]["model"] == "d3q19"
     assert rej[0]["reason"].startswith("vmem")
+
+
+# --------------------------------------------------------------------- #
+# the (y, x) plane tiled in y (tier-1: interpret mode on CPU)
+# --------------------------------------------------------------------- #
+
+# a plane that no kernel may hold whole once the planner is given this
+# little VMEM to count on; nz = 12 and ny = 64 leave room for every plan
+# the tests pin.  The planner takes bands that divide nz and ny (rows in
+# whole sublane tiles) and nothing else, so there is no uneven band to
+# test: the uneven case is the remainder (niter % K != 0)
+TILED_SHAPE = (12, 64, 64)
+TILED_VMEM = {"d3q19": 6_000_000, "d3q27_BGK": 8_000_000,
+              "d3q27_cumulant": 12_000_000}
+
+
+def _tiled_lat(name, wall):
+    m = get_model(name)
+    sett = {"nu": 0.05, "GravitationX": 1e-5}
+    if name == "d3q27_cumulant":
+        sett = {"nu": 0.05, "ForceX": 1e-5}
+    lat = Lattice(m, TILED_SHAPE, dtype=jnp.float32, settings=sett)
+    flags = np.full(TILED_SHAPE, m.flag_for("MRT"), dtype=np.uint16)
+    if wall:
+        # a wall on the row the first band starts with, which is also a
+        # wrapped halo row of the last band, and walls on the z faces
+        flags[:, 0, :] = m.flag_for("Wall")
+        flags[0] = flags[-1] = m.flag_for("Wall")
+    lat.set_flags(flags)
+    lat.init()
+    # a field that differs from node to node: a halo row or slab taken
+    # from the wrong place then shows
+    f = np.array(lat.state.fields)
+    f[:len(m.groups["f"])] *= (1.0 + 0.01 * np.random.default_rng(3)
+                               .standard_normal(f[:len(m.groups["f"])].shape)
+                               ).astype(np.float32)
+    lat.state = lat.state.replace(fields=jnp.asarray(f))
+    return m, lat, flags
+
+
+@pytest.mark.parametrize("name,K,wall", [
+    ("d3q27_cumulant", 1, False), ("d3q27_cumulant", 1, True),
+    ("d3q27_cumulant", 2, False), ("d3q27_cumulant", 2, True),
+    ("d3q27_cumulant", 3, False), ("d3q27_cumulant", 3, True),
+    ("d3q19", 2, True), ("d3q27_BGK", 3, True)])
+def test_y_tiled_bit_exact_vs_xla(name, K, wall):
+    """The fused kernel on windows of ``bz`` slabs x ``by`` rows with
+    wrapped halo rows is BIT-IDENTICAL to the XLA step, periodic in y
+    and with a wall on the seam: the tiling comes from the planner, given
+    less VMEM than a whole plane takes, not from a knob."""
+    m, lat, flags = _tiled_lat(name, wall)
+    budget = TILED_VMEM[name]
+    bz, by, k = pallas_d3q.tile_plan(m, TILED_SHAPE, 4, K, budget)
+    assert k == K and by < TILED_SHAPE[1] and by % 8 == 0
+    assert pallas_d3q._fused_fits(m, *TILED_SHAPE, bz, K, by=by,
+                                  budget=budget)
+    it = pallas_d3q.make_pallas_iterate(
+        m, TILED_SHAPE, present=pallas_d3q.present_types(m, flags),
+        fuse=K, vmem_budget=budget)
+    niter = 2 * K + 1      # two fused calls and, for K > 1, one step over
+    s_p = it(jax.tree.map(jnp.copy, lat.state), lat.params, niter)
+    s_x = lat._iterate(lat.state, lat.params, niter)
+    np.testing.assert_array_equal(np.asarray(s_p.fields),
+                                  np.asarray(s_x.fields))
+    assert int(s_p.iteration) == int(s_x.iteration) == niter
+
+
+def test_y_tiled_needs_a_plan():
+    """A budget that no window fits is refused, not run whole-plane."""
+    m, lat, flags = _tiled_lat("d3q19", False)
+    with pytest.raises(ValueError, match="no plan tiles"):
+        pallas_d3q.make_pallas_iterate(m, TILED_SHAPE, fuse=2,
+                                       vmem_budget=200_000)
+
+
+def test_channel_plan_slab_by_slab_halo():
+    """The channel cell's plan in small, (bz, K) = (2, 3): a halo deeper
+    than the band is copied slab by slab, each index wrapped."""
+    m, lat, flags = _fused_lat("d3q27_cumulant")
+    it = pallas_d3q.make_pallas_iterate(
+        m, FUSED_SHAPE, present=pallas_d3q.present_types(m, flags),
+        fuse=3, fuse_bz=2)
+    s_p = it(jax.tree.map(jnp.copy, lat.state), lat.params, 7)
+    s_x = lat._iterate(lat.state, lat.params, 7)
+    np.testing.assert_array_equal(np.asarray(s_p.fields),
+                                  np.asarray(s_x.fields))
+
+
+@pytest.mark.parametrize("shape,whole,ext", [
+    ((512, 48, 256), (2, 3), True),      # channel3d512: the parent's plan
+    ((48, 48, 256), (3, 2), True),       # 3d_channel.xml: the parent's
+    ((256, 128, 128), (2, 2), False),    # a ring-only plane, still whole
+    ((256, 256, 256), None, False),      # tgv256: tiled
+    ((128, 128, 256), None, False)])
+def test_planner_keeps_whole_planes_and_tiles_the_rest(shape, whole, ext):
+    """Where a whole plane fits a single-step kernel the plan is what it
+    was, to the tuple; where none does the tiled plan passes the
+    planner's own VMEM account; the sharded building block stays
+    whole-plane."""
+    m = get_model("d3q27_cumulant")
+    nz, ny, nx = shape
+    assert pallas_d3q.supports(m, shape, jnp.float32)
+    assert pallas_d3q.supports(m, shape, jnp.float32, ext_halo=True) == ext
+    plan = pallas_d3q.tile_plan(m, shape)
+    if whole is not None:
+        assert plan is None
+        assert pallas_d3q.fused_cfg(m, shape) == whole
+        assert pallas_d3q._fused_fits(m, nz, ny, nx, *whole)
+        assert pallas_d3q.choose_fuse(m, shape) == whole[1]
+        return
+    bz, by, K = plan
+    assert K >= 2 and nz % bz == 0 and ny % by == 0 and by % 8 == 0
+    assert by < ny
+    assert pallas_d3q._fused_fits(m, nz, ny, nx, bz, K, by=by)
+    assert pallas_d3q.choose_fuse(m, shape) == K
+    # the steps a fused call leaves over have a plan of their own
+    bz1, by1, K1 = pallas_d3q.tile_plan(m, shape, fuse=1)
+    assert K1 == 1 and pallas_d3q._fused_fits(m, nz, ny, nx, bz1, 1,
+                                              by=by1)
+    # and what the account admits moves less than the single-step plan
+    assert pallas_d3q._fused_cost(m, bz, K, by) \
+        < pallas_d3q._fused_cost(m, bz1, 1, by1)
+    # the static audit follows the planner
+    from tclb_tpu.analysis import resources
+    said = [f for f in resources.check_resources(m, shape)
+            if f.check == "resources.fused_slab" and "tuned" in f.message]
+    assert [(f.details["bz"], f.details["by"], f.details["fuse"])
+            for f in said] == [(bz, by, K)]

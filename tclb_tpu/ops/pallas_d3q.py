@@ -96,6 +96,13 @@ _RECOMPUTE_PLANES = 23
 # window starts on a tile boundary; it covers any K <= fusion.FUSE_MAX
 # (the in-window y roll spoils one row a side per step)
 _HALO_Y = 8
+# kernel calls a loop body of _iterate_jit.  A loop's carry is one buffer
+# and a custom call cannot write the buffer it reads: with one call a
+# body XLA copies the whole state before every call (a third of the
+# device's time at 512 x 48 x 256 and at 256^3); with two, state
+# A -> B -> A, the call that writes the carry is not the one that reads
+# it.  Right for every plan, so a constant
+_PAIR = 2
 
 E = cumulant.velocity_set(3)
 W = lbm.weights(E)
@@ -1029,10 +1036,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             return lambda fields, _: (
                 call_k(sett, ztab, fields, flags_i32), None)
 
+        # both loops: _PAIR calls a body, an odd call after the loop
         rem = niter
         if K >= 2:
             fields, _ = jax.lax.scan(body_f(call_f), fields, None,
-                                     length=niter // K)
+                                     length=niter // K, unroll=_PAIR)
             rem = niter % K
         if tiled is not None:
             body = body_f(call_r)
@@ -1045,7 +1053,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             def body(fields, _):
                 return call(sett, fields, flags_i32, zonal), None
 
-        fields, _ = jax.lax.scan(body, fields, None, length=rem)
+        fields, _ = jax.lax.scan(body, fields, None, length=rem,
+                                 unroll=_PAIR)
         return LatticeState(
             fields=fields,
             flags=state.flags,
@@ -1059,7 +1068,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         calls' windows, and the steps left to the single-step kernel."""
         fused = niter // K if cfg else 0
         rest = niter - fused * K
-        did = dict(kernel_calls=fused + rest, remainder_steps=rest)
+        # calls issued from a two-call loop body: a loop of one trip or
+        # none is no loop (lax.scan unrolls it whole)
+        paired = sum(n - n % _PAIR for n in (fused, rest) if n >= 2 * _PAIR)
+        did = dict(kernel_calls=fused + rest, remainder_steps=rest,
+                   paired_calls=paired)
         if fused:
             bzp, byp, _ = cfg
             did.update(
@@ -1081,7 +1094,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                                   jax.core.Tracer):
             did = account(int(niter))
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            telemetry.counter("engine.paired_calls", did["paired_calls"])
             telemetry.annotate(**did)
         return out
 
+    iterate.account = account
     return iterate

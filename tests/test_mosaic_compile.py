@@ -113,17 +113,12 @@ def test_d3q27_cumulant_48x48x256(one_chip, fuse):
                      % (fuse or r"[2-9]"), text)
 
 
-def test_d3q27_cumulant_256_tiled(one_chip):
-    """The plan of ``example/tgv_256.xml``: no kernel holds a 256 x 256
-    plane whole, so the fused kernel runs on y-tiled windows, for the
-    fused calls and for the step they leave over.  Only shapes are
-    described: a 256^3 state is 2.3 GB."""
+def _tgv_256(one_chip):
+    """The Taylor-Green box by shapes only (a 256^3 state is 2.3 GB):
+    every node collides."""
     from tclb_tpu.core.lattice import LatticeState
     shape = (256, 256, 256)
     m = get_model("d3q27_cumulant")
-    bz, by, K = pallas_d3q.tile_plan(m, shape)
-    assert K >= 2 and by < 256
-    assert pallas_d3q.tile_plan(m, shape, fuse=1)[1] < 256
     small = Lattice(m, (8, 8, 128), dtype=jnp.float32,
                     settings={"nu": 0.001273})
 
@@ -135,14 +130,80 @@ def test_d3q27_cumulant_256_tiled(one_chip):
         fields=on_chip(st.fields, (m.n_storage,) + shape),
         flags=on_chip(st.flags, shape), globals_=on_chip(st.globals_),
         iteration=on_chip(st.iteration))
-    # every node collides, as in the Taylor-Green box
+    return m, shape, state, jax.tree.map(on_chip, small.params), {"MRT"}
+
+
+def test_d3q27_cumulant_256_tiled(one_chip):
+    """The plan of ``example/tgv_256.xml``: no kernel holds a 256 x 256
+    plane whole, so the fused kernel runs on y-tiled windows, for the
+    fused calls and for the step they leave over.  Only shapes are
+    described: a 256^3 state is 2.3 GB."""
+    m, shape, state, params, present = _tgv_256(one_chip)
+    bz, by, K = pallas_d3q.tile_plan(m, shape)
+    assert K >= 2 and by < 256
+    assert pallas_d3q.tile_plan(m, shape, fuse=1)[1] < 256
     it = pallas_d3q.make_pallas_iterate(m, shape, jnp.float32,
-                                        interpret=False, present={"MRT"})
+                                        interpret=False, present=present)
     text = jax.jit(lambda s, p: it(s, p, K + 1)).lower(
-        state, jax.tree.map(on_chip, small.params)).compile().as_text()
+        state, params).compile().as_text()
     assert "tpu_custom_call" in text
     assert f"d3q_slab_fuse{K}/pallas_call" in text
     assert "d3q_slab_fuse1/pallas_call" in text
+
+
+def _computations(text: str) -> dict:
+    """The computations of a compiled module's text, name -> lines."""
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if name is None and head:
+            name = head.group(1)
+            found[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            found[name].append(line)
+    return found
+
+
+@pytest.mark.parametrize("case,fuse", [
+    ("channel", None), ("channel", 1), ("tgv256", None)],
+    ids=["channel-fused", "channel-fuse1", "tgv256-tiled"])
+def test_d3q_loop_body_pairs_the_calls_and_copies_no_state(one_chip, case,
+                                                           fuse):
+    """The loop that carries the state through the kernel holds two
+    kernel calls a body, so the call that writes the carry is not the one
+    that reads it, and XLA puts no copy of the whole state before the
+    kernel.  Five calls of the looped kernel: two trips and an odd call
+    after the loop; one step over where the depth allows one."""
+    if case == "channel":
+        shape = (48, 48, 256)
+        m, lat, present = _channel("d3q27_cumulant", shape, nu=0.01)
+    else:
+        m, shape, state, params, present = _tgv_256(one_chip)
+    it = pallas_d3q.make_pallas_iterate(m, shape, jnp.float32,
+                                        interpret=False, present=present,
+                                        fuse=fuse)
+    K = fuse or pallas_d3q.choose_fuse(m, shape)
+    assert (K >= 2) == (fuse is None)
+    niter = 5 * K + (K >= 2)
+    did = it.account(niter)
+    assert did["kernel_calls"] == 5 + (K >= 2) and did["paired_calls"] == 4
+    if case == "channel":
+        text = _compile(it, lat, niter, one_chip)
+    else:
+        text = jax.jit(lambda s, p: it(s, p, niter)).lower(
+            state, params).compile().as_text()
+    kernel = re.compile(r"= \S+ custom-call\(.*d3q_(slab|ring)_fuse%d/" % K)
+    bodies = [lines for name, lines in _computations(text).items()
+              if any(kernel.search(line) for line in lines)
+              and re.search(r"\bbody=%%?%s\b" % re.escape(name), text)]
+    assert len(bodies) == 1
+    assert sum(bool(kernel.search(line)) for line in bodies[0]) == 2
+    whole = "f32[%s]" % ",".join(str(n) for n in (m.n_storage,) + shape)
+    copy = re.compile(r"= %s\S* copy(-start)?\(" % re.escape(whole))
+    copies = [line.strip() for line in bodies[0] if copy.search(line)]
+    assert not copies, copies
 
 
 @pytest.mark.parametrize("name", ["d2q9_kuper", "d2q9_heat"])

@@ -203,20 +203,32 @@ def _fused_lat(name):
     return m, lat, flags
 
 
-@pytest.mark.parametrize("name", ["d3q19", "d3q27_cumulant"])
-@pytest.mark.parametrize("K", [1, 2, 4])
-def test_fused_bit_exact_vs_xla(name, K):
+def _pairing_edges(K, rests):
+    """Step counts at the edges of the two-call loop body: 0 to 4 fused
+    calls (no loop, one call, one pair unrolled whole, a one-trip loop
+    and an odd call, a loop of two trips), each with ``rests`` steps
+    left over.  A case is a compile of its own in interpret mode, so the
+    whole-plane test takes the remainders 0 and K - 1 and the y-tiled
+    one the remainder 1: the loops are the same code in both."""
+    return [K * fused + rest for fused in range(5) for rest in rests]
+
+
+# niter=5: for K=2 -> 2 fused calls + 1 remainder step; for K=4 ->
+# 1 fused call + 1 remainder
+@pytest.mark.parametrize("name,K,niter", [
+    (name, K, 5) for K in (1, 2, 4) for name in ("d3q19", "d3q27_cumulant")
+] + [("d3q19", 3, niter) for niter in _pairing_edges(3, (0, 2))])
+def test_fused_bit_exact_vs_xla(name, K, niter):
     """fuse=K output is BIT-IDENTICAL to the XLA path (not allclose):
     the kernel spells rho/u/collision exactly as the model does, and the
     progressive-extension windows must reproduce each step's values
-    exactly — any reassociation or halo slip fails at == level."""
+    exactly — any reassociation or halo slip fails at == level.  Two
+    calls a loop body and the odd call after the loop are the same calls
+    in the same order, whatever the count."""
     m, lat, flags = _fused_lat(name)
     it = pallas_d3q.make_pallas_iterate(
         m, FUSED_SHAPE, present=pallas_d3q.present_types(m, flags),
         fuse=K)
-    # niter=5: for K=2 -> 2 fused calls + 1 remainder step; for K=4 ->
-    # 1 fused call + 1 remainder
-    niter = 5
     s_p = it(jax.tree.map(jnp.copy, lat.state), lat.params, niter)
     s_x = lat._iterate(lat.state, lat.params, niter)
     np.testing.assert_array_equal(np.asarray(s_p.fields),
@@ -252,8 +264,11 @@ def test_choose_fuse_planner():
         < pallas_d3q._base_cost(m, 48, 48, 256)
 
 
-@pytest.mark.parametrize("name", ["d3q19", "d3q27_cumulant"])
-def test_fused_bit_exact_K8(name):
+# niter=9: one fused chunk + one remainder step; 15: seven steps over,
+# the only depth whose remainder loop has whole pairs (three and an odd)
+@pytest.mark.parametrize("name,niter", [
+    ("d3q19", 9), ("d3q27_cumulant", 9), ("d3q19", 15)])
+def test_fused_bit_exact_K8(name, niter):
     """fuse=8 (the raised FUSE_MAX) stays bit-identical to the XLA step.
     Needs nz >= 2*K halo slabs, so this runs on a taller domain than
     FUSED_SHAPE."""
@@ -270,7 +285,6 @@ def test_fused_bit_exact_K8(name):
     lat.init()
     it = pallas_d3q.make_pallas_iterate(
         m, shape, present=pallas_d3q.present_types(m, flags), fuse=8)
-    niter = 9   # one fused chunk + one remainder step
     s_p = it(jax.tree.map(jnp.copy, lat.state), lat.params, niter)
     s_x = lat._iterate(lat.state, lat.params, niter)
     np.testing.assert_array_equal(np.asarray(s_p.fields),
@@ -376,12 +390,17 @@ def _tiled_lat(name, wall):
     return m, lat, flags
 
 
-@pytest.mark.parametrize("name,K,wall", [
-    ("d3q27_cumulant", 1, False), ("d3q27_cumulant", 1, True),
-    ("d3q27_cumulant", 2, False), ("d3q27_cumulant", 2, True),
-    ("d3q27_cumulant", 3, False), ("d3q27_cumulant", 3, True),
-    ("d3q19", 2, True), ("d3q27_BGK", 3, True)])
-def test_y_tiled_bit_exact_vs_xla(name, K, wall):
+# 2 K + 1 steps: two fused calls and, for K > 1, one step over; then the
+# pairing's edges at K = 2 with one step over (5 steps is among the first)
+@pytest.mark.parametrize("name,K,wall,niter", [
+    (name, K, wall, 2 * K + 1) for name, K, wall in [
+        ("d3q27_cumulant", 1, False), ("d3q27_cumulant", 1, True),
+        ("d3q27_cumulant", 2, False), ("d3q27_cumulant", 2, True),
+        ("d3q27_cumulant", 3, False), ("d3q27_cumulant", 3, True),
+        ("d3q19", 2, True), ("d3q27_BGK", 3, True)]
+] + [("d3q19", 2, True, niter) for niter in _pairing_edges(2, (1,))
+     if niter != 5])
+def test_y_tiled_bit_exact_vs_xla(name, K, wall, niter):
     """The fused kernel on windows of ``bz`` slabs x ``by`` rows with
     wrapped halo rows is BIT-IDENTICAL to the XLA step, periodic in y
     and with a wall on the seam: the tiling comes from the planner, given
@@ -395,12 +414,38 @@ def test_y_tiled_bit_exact_vs_xla(name, K, wall):
     it = pallas_d3q.make_pallas_iterate(
         m, TILED_SHAPE, present=pallas_d3q.present_types(m, flags),
         fuse=K, vmem_budget=budget)
-    niter = 2 * K + 1      # two fused calls and, for K > 1, one step over
     s_p = it(jax.tree.map(jnp.copy, lat.state), lat.params, niter)
     s_x = lat._iterate(lat.state, lat.params, niter)
     np.testing.assert_array_equal(np.asarray(s_p.fields),
                                   np.asarray(s_x.fields))
     assert int(s_p.iteration) == int(s_x.iteration) == niter
+
+
+@pytest.mark.parametrize("fuse,budget,niter,fused,rest,paired", [
+    (3, None, 3 * fused + rest, fused, rest, 4 if fused == 4 else 0)
+    for fused in range(5) for rest in (0, 1, 2)
+] + [
+    (3, None, 499, 166, 1, 166),    # the channel cell's call
+    (3, None, 250, 83, 1, 82),      # the vortex cell's: the 83rd is odd
+    (8, None, 15, 1, 7, 6),         # a remainder loop of three pairs
+    (8, None, 47, 5, 7, 4 + 6),
+    (1, None, 5, 0, 5, 4),          # fuse=1: the ring/block kernel's loop
+    (1, 12_000_000, 5, 5, 0, 4),    # y-tiled at K = 1: the fused kernel's
+    (3, 12_000_000, 14, 4, 2, 4)])
+def test_account_counts_the_paired_calls(fuse, budget, niter, fused, rest,
+                                         paired):
+    """``account`` mirrors ``_iterate_jit``'s schedule: ``paired_calls``
+    are the kernel calls issued from a two-call loop body (a loop of one
+    trip or none is unrolled whole and counts nothing);
+    ``kernel_calls`` and ``remainder_steps`` are what they were."""
+    m = get_model("d3q27_cumulant")
+    shape, kw = (((16, 8, 64), {}) if budget is None
+                 else (TILED_SHAPE, {"vmem_budget": budget}))
+    it = pallas_d3q.make_pallas_iterate(m, shape, fuse=fuse, **kw)
+    did = it.account(niter)
+    assert (did["kernel_calls"], did["remainder_steps"],
+            did["paired_calls"]) == (fused + rest, rest, paired)
+    assert ("z_bands" in did) == bool(fused)
 
 
 def test_y_tiled_needs_a_plan():

@@ -115,8 +115,9 @@ def test_tuned_engine_float32_and_the_span(tmp_path, monkeypatch):
             and "kernel_calls" in e]
     assert [e["name"] for e in said] == ["engine.probe"]
     assert {k: said[0][k] for k in (
-        "kernel_calls", "remainder_steps", "z_bands", "band_slabs",
-        "halo_slabs", "y_bands", "band_rows", "halo_rows", "aux_planes")} \
-        == dict(kernel_calls=5, remainder_steps=3, z_bands=1, band_slabs=16,
-                halo_slabs=8, y_bands=1, band_rows=16, halo_rows=0,
-                aux_planes=1)
+        "kernel_calls", "remainder_steps", "paired_calls", "z_bands",
+        "band_slabs", "halo_slabs", "y_bands", "band_rows", "halo_rows",
+        "aux_planes")} \
+        == dict(kernel_calls=5, remainder_steps=3, paired_calls=0, z_bands=1,
+                band_slabs=16, halo_slabs=8, y_bands=1, band_rows=16,
+                halo_rows=0, aux_planes=1)

@@ -140,12 +140,21 @@ def check_resources(model: Model, shape=None) -> list:
         nz, ny, nx = shape
         bz = pallas_generic._slab_depth_gen(model, nz, ny, nx,
                                             max(reach, 1))
-        if bz is None:
+        tiled = pallas_generic.tile_plan_3d(model, shape) \
+            if bz is None else None
+        if tiled is not None:
+            findings.append(Finding(
+                "resources.slab_tiled", "info", model.name,
+                f"3D slab engine tiles the plane: windows of {tiled[0]} "
+                f"slabs x {tiled[1]} rows at fuse={tiled[2]}", where,
+                {"bz": tiled[0], "by": tiled[1], "fuse": tiled[2]}))
+        elif bz is None:
             findings.append(Finding(
                 "resources.slab_vmem", "warning", model.name,
-                f"no z-slab depth fits the 12 MB scratch budget at "
-                f"{nz}x{ny}x{nx} ({model.n_storage} storage planes): "
-                "generic 3D engine ineligible, XLA fallback", where,
+                f"no whole-plane z-slab depth fits the 12 MB scratch "
+                f"budget at {nz}x{ny}x{nx} ({model.n_storage} storage "
+                "planes) and no tiled window the raised ceiling: generic "
+                "3D engine ineligible, XLA fallback", where,
                 {"n_storage": model.n_storage, "shape": list(shape)}))
         else:
             n_aux = 1 + 2 * len(model.zonal_settings)
@@ -160,7 +169,8 @@ def check_resources(model: Model, shape=None) -> list:
         # accepts, so a config exceeding its engine's budget here means
         # planner and builder have drifted apart — an error, because the
         # first TPU compile would die where the probe ladder can't see it
-        K3 = pallas_generic.choose_fuse_3d(model, shape)
+        K3 = pallas_generic.choose_fuse_3d(model, shape) \
+            if bz is not None else 1     # a tiled plan is its own account
         if K3 >= 2:
             _, rK = pallas_generic.action_plan(model, "Iteration",
                                                fuse=K3)

@@ -1093,9 +1093,19 @@ class Lattice:
         if not (fits_resident or pallas_generic.supports(model, shape, sdt)):
             return []
 
+        def tiled(fz, by_cap=None):
+            # the (bz, by, K) of a 3D plane no whole-plane plan holds
+            return (pallas_generic.tile_plan_3d(model, shape, s_itemsize,
+                                                fz, by_cap)
+                    if model.ndim == 3 else None)
+
         def band(fz, by_cap, tag=None, **how):
-            return cand(tag or f"pallas_generic[{name},fuse={fz}]",
-                        pallas_generic.make_pallas_iterate, fuse=fz,
+            if tag is None:
+                # a plane the engine tiles says so: the rows of its bands
+                plan = tiled(fz, by_cap)
+                by = f",by={plan[1]}" if plan and plan[1] < shape[1] else ""
+                tag = f"pallas_generic[{name},fuse={fz}{by}]"
+            return cand(tag, pallas_generic.make_pallas_iterate, fuse=fz,
                         by_cap=by_cap, shift=shift, **how)
         cfg = (None if fits_resident
                else pallas_generic.get_build_cfg(model, shape))
@@ -1127,12 +1137,17 @@ class Lattice:
         rungs = [(fz0, 16), (fz0, 8)]
         if fz0 >= 2:
             rungs += [(1, 16), (1, 8)]
-        if model.ndim == 3:
+        plan0 = tiled(fz0)
+        if model.ndim == 3 and plan0 is None:
             # last resort: raised scoped-vmem ceiling (negative cap
-            # encodes it; ~2x slower codegen, still ~3x the XLA path)
+            # encodes it; ~2x slower codegen, still ~3x the XLA path).
+            # A tiled window compiles under it from the start: its rungs
+            # cap the rows and slabs of the window
             rungs += [(fz0, -16), (fz0, -8)]
-        # the planner's own choice reads as the 2D band's default cap
-        cap0 = pallas_generic._DEFAULT_BY_CAP if model.ndim == 2 else 0
+        # the planner's own choice reads as the 2D band's default cap,
+        # a tiled 3D window's as the rows of its bands
+        cap0 = (pallas_generic._DEFAULT_BY_CAP if model.ndim == 2
+                else plan0[1] if plan0 else 0)
         return [band(fz0, None, probe=True, cap=cap0,
                      verdict=(fz0, None))] + [
             band(fz, cap, f"pallas_generic[{name},fuse={fz},by<={cap}]",

@@ -80,24 +80,23 @@ def _storage_ok(dtype) -> bool:
 # --------------------------------------------------------------------------- #
 
 
-def _stage_reach(model: Model, stage_name: str) -> int:
-    """Band-axis reach of one stage's reads: pull distance of streamed
-    densities (when the stage streams) and the declared Field stencil
-    extents, along the banded axis (y rows in 2D, z slabs in 3D).
-    x-reach is free (lane rolls wrap the whole row), and in 3D the whole
-    (ny, nx) plane rides the band so y is free too."""
+def _stage_reach(model: Model, stage_name: str,
+                 axis: Optional[str] = None) -> int:
+    """Reach of one stage's reads along ``axis``: pull distance of
+    streamed densities (when the stage streams) and the declared Field
+    stencil extents.  The default is the banded axis (y rows in 2D, z
+    slabs in 3D): x-reach is free (lane rolls wrap the whole row), and in
+    3D the whole (ny, nx) plane rides the band so y is free too, unless
+    the plane is tiled (``axis="y"``, :func:`_reach_y`)."""
+    axis = axis or ("y" if model.ndim == 2 else "z")
     stage = model.stages[stage_name]
     r = 0
-    if model.ndim == 2:
-        if stage.load_densities:
-            r = max((abs(int(d.dy)) for d in model.densities), default=0)
-        for f in model.fields:
-            r = max(r, abs(f.dy_range[0]), abs(f.dy_range[1]))
-    else:
-        if stage.load_densities:
-            r = max((abs(int(d.dz)) for d in model.densities), default=0)
-        for f in model.fields:
-            r = max(r, abs(f.dz_range[0]), abs(f.dz_range[1]))
+    if stage.load_densities:
+        r = max((abs(int(getattr(d, "d" + axis))) for d in model.densities),
+                default=0)
+    for f in model.fields:
+        lo, hi = getattr(f, f"d{axis}_range")
+        r = max(r, abs(lo), abs(hi))
     return r
 
 
@@ -1191,6 +1190,29 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 # 100 MB scoped-vmem ceiling they always compile with — the wider K*R
 # halo is what buys the K-fold traffic amortization
 _FUSED3D_BUDGET = 28 * 1024 * 1024
+_VMEM3D_LIMIT = 100 * 1024 * 1024
+# a plane no whole-plane plan holds is cut into y bands: windows of bz
+# slabs x by rows with _HALO wrapped halo rows a side (one sublane tile,
+# so every DMA window starts on a tile boundary), always compiled under
+# the raised ceiling.  _TILED3D_BUDGET is what _window_fits lets a window
+# fill of it: DMA scratch, the pipelined out blocks and the traced
+# physics' temporaries, counted as _TILE3D_TEMP_PLANES f32 planes a
+# storage plane over the widest stage window (from Mosaic's own reports
+# for d3q19_kuper and d3q19_heat compiled for a described v5e, PR 34)
+_TILED3D_BUDGET = 80 * 1024 * 1024
+_TILE3D_TEMP_PLANES = 3
+# what a node step computed again costs, in the f32 planes of
+# _tile_cost_3d's traffic: a window computes every stage on its halo
+# rows and on the slabs later stages read.  Measured for d3q19_kuper on
+# a v5e only (PR 34's sweep of ten plans at 256^3): the five one-step
+# plans run at 0.0056 to 0.0059 ns a plane and node moved (83 % of the
+# HBM peak, in the order of their traffic), the five deeper ones at 0.21
+# to 0.27 ns a computed node step, which is 41 planes
+_RECOMPUTE3D_PLANES = 41
+# kernel calls a loop body of _iterate_jit's non-series scans: with one
+# call a body XLA copies the whole carry before every call (a custom
+# call cannot write the buffer it reads; ops/pallas_d3q._PAIR, PR 33)
+_PAIR = 2
 
 
 def _slab_depth_gen(model: Model, nz: int, ny: int, nx: int,
@@ -1220,6 +1242,97 @@ def _slab_depth_gen(model: Model, nz: int, ny: int, nx: int,
     return best
 
 
+def _whole_plane_3d(model: Model, nz: int, ny: int, nx: int,
+                    itemsize: int = 4) -> bool:
+    """Whether the single-step kernel holds whole (ny, nx) planes: then
+    every plan of the shape keeps the plane whole, as before y tiling."""
+    r1 = max(action_plan(model, "Iteration", fuse=1)[1], 1)
+    return _slab_depth_gen(model, nz, ny, nx, r1,
+                           itemsize=itemsize) is not None
+
+
+def _reach_y(model: Model, fuse: int) -> int:
+    """Rows a side that ``fuse`` repetitions of the action spoil in a y
+    window: every pull and every Field load rolls the window in y inside
+    its own rows, so a stage's y reach is lost at either end."""
+    return fuse * sum(_stage_reach(model, s, "y")
+                      for s in model.actions["Iteration"])
+
+
+def _window_fits(model: Model, nx: int, bz: int, rows: int, by: int,
+                 plan: list, reach: int, itemsize: int = 4,
+                 budget: int = _TILED3D_BUDGET) -> bool:
+    """VMEM account of a tiled window of ``bz`` slabs x ``by`` rows
+    (``rows`` with its halo rows) under an action ``plan`` of ``reach``
+    halo slabs: the double-slotted state + aux scratch (the series
+    flavor's aux stack: every flavor shares the window), the pipelined
+    out blocks, and the temporaries of the widest stage window."""
+    ns = model.n_storage
+    n_aux = 1 + 2 * len(model.zonal_settings)
+    scratch = 2 * (ns * itemsize + n_aux * 4) * (bz + 2 * reach) * rows * nx
+    out = 2 * ns * itemsize * bz * by * nx
+    widest = bz + 2 * max(ext for _, ext in plan)
+    temp = _TILE3D_TEMP_PLANES * ns * widest * rows * nx * 4
+    return scratch + out + temp <= budget
+
+
+def _tile_cost_3d(model: Model, bz: int, rows: int, by: int,
+                  plan: list, reach: int, K: int) -> tuple:
+    """What decides between tiled plans, in f32 planes a node step: the
+    planes a window moves (state + flag plane read with its halos, state
+    written) or the node steps it computes (every stage on its extended
+    slabs and all its rows), whichever binds; the traffic breaks ties."""
+    ns = model.n_storage
+    wide = rows / by
+    traffic = ((ns + 1) * (bz + 2 * reach) * wide + ns * bz) / (K * bz)
+    again = wide * sum(bz + 2 * ext for _, ext in plan) / (len(plan) * bz)
+    return max(traffic, _RECOMPUTE3D_PLANES * again), traffic
+
+
+def tile_plan_3d(model: Model, shape, itemsize: int = 4,
+                 fuse: Optional[int] = None, cap: Optional[int] = None,
+                 budget: int = _TILED3D_BUDGET) -> Optional[tuple]:
+    """Plan ``(bz, by, K)`` of the slab kernel for a shape whose plane no
+    whole-plane plan holds: windows of ``bz`` slabs x ``by`` rows (a
+    multiple of 8 dividing ny, x whole) with ``_HALO`` wrapped halo rows a
+    side, ``K`` action repetitions a round trip (``fuse`` pins K).  The
+    in-window y roll spoils :func:`_reach_y` rows a side, which the halo
+    bounds; ``by == ny`` keeps the plane whole (no halo rows) where only
+    the raised ceiling takes it.  The least :func:`_tile_cost_3d` wins,
+    ties go to the taller band.  ``cap`` is a rung of the Lattice's probe
+    ladder: bands of at most ``|cap|`` rows and ``|cap| // 8`` slabs.
+    None where a whole-plane plan exists or nothing fits."""
+    nz, ny, nx = (int(s) for s in shape)
+    if _whole_plane_3d(model, nz, ny, nx, itemsize):
+        return None
+    by_max = ny if cap is None else min(ny, abs(cap))
+    bz_max = nz if cap is None else max(1, abs(cap) // 8)
+    bys = [b for b in range(ny, 0, -_HALO)
+           if ny % b == 0 and b % _HALO == 0 and b <= by_max]
+    best, best_c = None, None
+    for K in ([fuse] if fuse else range(1, fusion.FUSE_MAX + 1)):
+        plan, reach = action_plan(model, "Iteration", fuse=K)
+        reach = max(reach, 1)
+        if nz < 2 * reach:
+            break
+        for by in bys:
+            hy = 0 if by == ny else _HALO
+            if hy and _reach_y(model, K) > hy:
+                continue
+            rows = by + 2 * hy
+            bz = max((b for b in range(1, min(nz, bz_max) + 1)
+                      if nz % b == 0 and _window_fits(
+                          model, nx, b, rows, by, plan, reach, itemsize,
+                          budget)),
+                     default=None)
+            if bz is None:
+                continue
+            c = _tile_cost_3d(model, bz, rows, by, plan, reach, K)
+            if best_c is None or c < best_c:
+                best, best_c = (bz, by, K), c
+    return best
+
+
 def choose_fuse_3d(model: Model, shape,
                    fmax: int = fusion.FUSE_MAX,
                    itemsize: int = 4) -> int:
@@ -1227,14 +1340,16 @@ def choose_fuse_3d(model: Model, shape,
     fused plan both fits the (raised-ceiling) VMEM budget at some slab
     depth AND beats the single-step engine's modeled traffic.  3D halos
     are real slabs (not fixed-height row blocks), so unlike 2D the halo
-    cost grows with K and the planner must weigh it."""
+    cost grows with K and the planner must weigh it.  A plane that is
+    tiled gets :func:`tile_plan_3d`'s K."""
     nz, ny, nx = (int(s) for s in shape)
     _, r1 = action_plan(model, "Iteration", fuse=1)
     R1 = max(r1, 1)
     ns = model.n_storage
+    if not _whole_plane_3d(model, nz, ny, nx, itemsize):
+        tiled = tile_plan_3d(model, shape, itemsize)
+        return tiled[2] if tiled else 1
     bz1 = _slab_depth_gen(model, nz, ny, nx, R1, itemsize=itemsize)
-    if bz1 is None:
-        return 1
     # lean aux: the non-series kernels move ns + 1 planes per slab
     best, best_c = 1, ((ns + 1) * (bz1 + 2 * R1) + ns * bz1) / bz1
     for K in range(2, fmax + 1):
@@ -1253,7 +1368,8 @@ def choose_fuse_3d(model: Model, shape,
 
 
 def supports_3d(model: Model, shape, dtype, probe: bool = True) -> bool:
-    """3D eligibility: same registry checks as 2D, z-banded."""
+    """3D eligibility: same registry checks as 2D, z-banded; a plane no
+    whole-plane plan holds needs a tiled one (:func:`tile_plan_3d`)."""
     if model.ndim != 3 or len(shape) != 3 or not _storage_ok(dtype):
         return False
     if "Iteration" not in model.actions:
@@ -1270,8 +1386,8 @@ def supports_3d(model: Model, shape, dtype, probe: bool = True) -> bool:
         return False
     if jax.default_backend() == "tpu" and (nx % 128 or ny % 8):
         return False  # (ny, nx) is the (sublane, lane) tile
-    if _slab_depth_gen(model, nz, ny, nx, max(reach, 1),
-                       itemsize=itemsize) is None:
+    if not _whole_plane_3d(model, nz, ny, nx, itemsize) \
+            and tile_plan_3d(model, shape, itemsize) is None:
         return False
     if not probe:
         return True
@@ -1300,12 +1416,31 @@ def supports_3d(model: Model, shape, dtype, probe: bool = True) -> bool:
     return _probe_cache[key]
 
 
+def _pieces(band: int, halo: int) -> list:
+    """(offset from the band's first index, buffer index, length) of a
+    band and its wrapped halos along one axis.  A halo no longer than the
+    band (which divides the axis) never straddles the periodic seam and
+    goes as one block; a longer one index by index (a block copy of R
+    slabs starting at (base - R) mod nz would read out of bounds, e.g.
+    bz=1, R=2, band 1)."""
+    if not halo:
+        return [(0, 0, band)]
+    if band >= halo:
+        return [(0, halo, band), (-halo, 0, halo),
+                (band, halo + band, halo)]
+    return [(0, halo, band)] + [
+        p for h in range(1, halo + 1)
+        for p in ((-h, halo - h, 1),
+                  (band - 1 + h, halo + band - 1 + h, 1))]
+
+
 def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                            interpret: Optional[bool] = None,
                            present: Optional[set] = None,
                            fuse: int = 1,
                            by_cap: Optional[int] = None,
-                           shift: Optional[np.ndarray] = None):
+                           shift: Optional[np.ndarray] = None,
+                           window: Optional[tuple] = None):
     """3D generic engine: the model's full Iteration action per z-slab
     band pass, with the same registry-driven machinery as the 2D builder
     (multi-stage extension plan, zonal aux planes, in-kernel SUM globals
@@ -1314,7 +1449,19 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     encode the shrinking interiors, so the kernel machinery is identical
     — only the halo widens to the fused plan's reach and the non-series
     scan advances K iterations per call (remainder steps use a fuse=1
-    flavor)."""
+    flavor).
+
+    The plan is ``(bz, by, K)``.  A shape whose single-step kernel holds
+    whole planes keeps them (``by == ny``, a 1-D grid, ``bz`` from
+    :func:`_slab_depth_gen` as before y tiling).  Any other plane is cut
+    into bands of ``by`` rows with ``_HALO`` wrapped halo rows a side
+    (:func:`tile_plan_3d` at this ``fuse``; grid z-major, y-minor): the
+    in-window y roll spoils :func:`_reach_y` halo rows a side, never a
+    band row.  Every flavor runs on the same windows: the remainder, the
+    in-kernel globals flavor (it sums a band's own rows only) and the
+    Control-series flavors at ``fuse=1`` inside the fused plan's
+    ``(bz, by)``, whose account holds the series' aux stack.
+    ``window=(bz, by)`` pins the window (tests and sweeps)."""
     if not supports_3d(model, shape, dtype, probe=False):
         raise ValueError(f"pallas_generic 3d unsupported: {model.name} "
                          f"{shape}")
@@ -1336,16 +1483,34 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     # never the default — only what rescues temporaries-heavy models
     # like d3q19_kuper that OOM even at bz=1).  Fused (K>=2) builds
     # always compile with the raised ceiling: their K*R halo scratch is
-    # budgeted against it (_FUSED3D_BUDGET).
-    vmem_ceiling = (by_cap is not None and by_cap < 0) or fuse >= 2
-    cap = None if by_cap is None else max(1, abs(by_cap) // 8)
-    bz = _slab_depth_gen(model, nz, ny, nx, R, cap, n_aux=1,
-                         budget=_FUSED3D_BUDGET, itemsize=itemsize) \
-        if fuse >= 2 \
-        else _slab_depth_gen(model, nz, ny, nx, R, cap, itemsize=itemsize)
+    # budgeted against it (_FUSED3D_BUDGET); so do tiled windows
+    # (_TILED3D_BUDGET), whose rungs cap rows and slabs both.
+    whole = window is None and _whole_plane_3d(model, nz, ny, nx, itemsize)
+    if window is not None:
+        bz, by = (int(v) for v in window)
+    elif whole:
+        cap = None if by_cap is None else max(1, abs(by_cap) // 8)
+        by = ny
+        bz = _slab_depth_gen(model, nz, ny, nx, R, cap, n_aux=1,
+                             budget=_FUSED3D_BUDGET, itemsize=itemsize) \
+            if fuse >= 2 \
+            else _slab_depth_gen(model, nz, ny, nx, R, cap,
+                                 itemsize=itemsize)
+    else:
+        tiled = tile_plan_3d(model, shape, itemsize, fuse, by_cap)
+        bz, by = tiled[:2] if tiled else (None, ny)
     if bz is None:
         raise ValueError(f"no slab depth fits fuse={fuse} for "
                          f"{model.name} {shape}")
+    if nz % bz or ny % by or (by < ny and (by % _HALO
+                                           or _reach_y(model, fuse) > _HALO)):
+        raise ValueError(f"window {(bz, by)} does not tile {shape} at "
+                         f"fuse={fuse}")
+    hy = _HALO if by < ny else 0   # wrapped halo rows a side
+    rows = by + 2 * hy             # rows of a window
+    nzb, nyb = nz // bz, ny // by
+    vmem_ceiling = (by_cap is not None and by_cap < 0) or fuse >= 2 \
+        or not whole
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -1366,10 +1531,19 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     loads_density = {nm: model.stages[nm].load_densities
                      for nm in model.actions["Iteration"]}
     nt_present = set(model.node_types) if present is None else set(present)
+    y_pieces = _pieces(by, hy)
+    own = slice(hy, hy + by) if hy else slice(None)   # a band's own rows
+
+    def _wrap(base, off: int, n: int):
+        """``base + off`` on a periodic axis of ``n``."""
+        return base if not off else jax.lax.rem(
+            base + jnp.int32(off + n), jnp.int32(n))
 
     def _mk_kernel(plan, R, with_dt=False, with_globals=False, lean=False):
         n_aux_k = 1 if lean \
             else 1 + (2 if with_dt else 1) * len(zonal_names)
+        z_pieces = _pieces(bz, R)
+        n_sem = len(z_pieces) * len(y_pieces)
 
         def kern(sett, it_ref, *rest):
             if lean:
@@ -1382,56 +1556,59 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             else:
                 (out_ref, buff, bufa, sems), g_ref = refs, None
             i = pl.program_id(0)
-            n = pl.num_programs(0)
+            j = pl.program_id(1) if nyb > 1 else jnp.int32(0)
+            t = i * jnp.int32(nyb) + j     # windows run z-major, y-minor
 
-            def band_dmas(slot, band):
-                # halo slabs are copied ONE AT A TIME with individual
-                # modular indices: a block copy of R slabs starting at
-                # (base - R) mod nz would straddle the periodic boundary
-                # whenever that start lands within R of the top (e.g.
-                # bz=1, R=2, band 1), reading out of bounds
-                base = band * jnp.int32(bz)
+            def band_dmas(slot, bi, bj):
+                z0 = bi * jnp.int32(bz)
+                y0 = bj * jnp.int32(by)
                 out = []
-                n_sem = 1 + 2 * R
-                for si_, (hbm, buf, nplanes) in enumerate((
-                        (f_hbm, buff, ns), (aux_hbm, bufa, n_aux_k))):
-                    out.append(pltpu.make_async_copy(
-                        hbm.at[pl.ds(0, nplanes), pl.ds(base, bz)],
-                        buf.at[slot, :, pl.ds(R, bz)],
-                        sems.at[slot, n_sem * si_]))
-                    for r in range(R):
-                        zm_r = jax.lax.rem(
-                            base - jnp.int32(R - r) + jnp.int32(nz),
-                            jnp.int32(nz))
-                        zp_r = jax.lax.rem(base + jnp.int32(bz + r),
-                                           jnp.int32(nz))
-                        out.append(pltpu.make_async_copy(
-                            hbm.at[pl.ds(0, nplanes), pl.ds(zm_r, 1)],
-                            buf.at[slot, :, pl.ds(r, 1)],
-                            sems.at[slot, n_sem * si_ + 1 + r]))
-                        out.append(pltpu.make_async_copy(
-                            hbm.at[pl.ds(0, nplanes), pl.ds(zp_r, 1)],
-                            buf.at[slot, :, pl.ds(R + bz + r, 1)],
-                            sems.at[slot, n_sem * si_ + 1 + R + r]))
+                for hbm, buf, nplanes in ((f_hbm, buff, ns),
+                                          (aux_hbm, bufa, n_aux_k)):
+                    for oz, dz, lz in z_pieces:
+                        sz = _wrap(z0, oz, nz)
+                        if not hy:          # whole planes
+                            out.append(pltpu.make_async_copy(
+                                hbm.at[pl.ds(0, nplanes), pl.ds(sz, lz)],
+                                buf.at[slot, :, pl.ds(dz, lz)],
+                                sems.at[slot, len(out)]))
+                            continue
+                        for oy, dy_, ly in y_pieces:
+                            # bands and halos are whole sublane tiles
+                            sy = pl.multiple_of(_wrap(y0, oy, ny), _HALO)
+                            out.append(pltpu.make_async_copy(
+                                hbm.at[pl.ds(0, nplanes), pl.ds(sz, lz),
+                                       pl.ds(sy, ly)],
+                                buf.at[slot, :, pl.ds(dz, lz),
+                                       pl.ds(dy_, ly)],
+                                sems.at[slot, len(out)]))
                 return out
 
-            slot = jax.lax.rem(i, jnp.int32(2))
-            nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(2))
+            slot = jax.lax.rem(t, jnp.int32(2))
+            nxt = jax.lax.rem(t + jnp.int32(1), jnp.int32(2))
+            if nyb > 1:
+                turn = j + jnp.int32(1) == jnp.int32(nyb)
+                ni = jnp.where(turn, i + jnp.int32(1), i)
+                nj = jnp.where(turn, jnp.int32(0), j + jnp.int32(1))
+            else:
+                ni, nj = i + jnp.int32(1), j
 
-            @pl.when(i == 0)
+            @pl.when(t == 0)
             def _():
-                for d in band_dmas(jnp.int32(0), i):
+                for d in band_dmas(jnp.int32(0), i, j):
                     d.start()
 
-            @pl.when(i + 1 < n)
+            @pl.when(t + 1 < nzb * nyb)
             def _():
-                for d in band_dmas(nxt, i + jnp.int32(1)):
+                for d in band_dmas(nxt, ni, nj):
                     d.start()
 
-            for d in band_dmas(slot, i):
+            for d in band_dmas(slot, i, j):
                 d.wait()
 
             def _rollyx(sl, dy, dx):
+                # in a y window the roll wraps inside the window's own
+                # rows: one halo row a side is spoiled per unit of reach
                 if dy:
                     sl = jnp.roll(sl, dy, axis=1)
                 if dx % nx:
@@ -1441,20 +1618,29 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             # widen to the compute dtype at the read (traced no-op at f32
             # storage); the whole fused action accumulates in f32 and the
             # output write narrows back to the storage dtype
-            work = [ddf.widen_plane(buff[slot, k], cdtype, _shifts[k])
+            # each plane with the first buffer slab it covers: a stage
+            # replaces a plane by the slabs it computed, and every later
+            # read lies inside them (the plan's extensions see to that),
+            # so the stale slabs outside are dropped, not carried along
+            work = [(ddf.widen_plane(buff[slot, k], cdtype, _shifts[k]), 0)
                     for k in range(ns)]
+
+            def slabs(k, first, n):
+                arr, z0 = work[k]
+                return arr[first - z0:first - z0 + n]
+
             flags_full = bufa[slot, 0].astype(jnp.int32)
             if ztab is not None:
                 zones_full = flags_full >> zshift
                 zonal_full = {nm: fusion.zone_plane(ztab, zones_full,
-                                                    zone_max, col=j)
-                              for j, nm in enumerate(zonal_names)}
+                                                    zone_max, col=c)
+                              for c, nm in enumerate(zonal_names)}
                 dt_full = {}
             else:
-                zonal_full = {nm: bufa[slot, 1 + j]
-                              for j, nm in enumerate(zonal_names)}
-                dt_full = {nm: bufa[slot, 1 + len(zonal_names) + j]
-                           for j, nm in enumerate(zonal_names)} \
+                zonal_full = {nm: bufa[slot, 1 + c]
+                              for c, nm in enumerate(zonal_names)}
+                dt_full = {nm: bufa[slot, 1 + len(zonal_names) + c]
+                           for c, nm in enumerate(zonal_names)} \
                     if with_dt else {}
             g_acc: dict = {}
 
@@ -1468,14 +1654,13 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                     planes = []
                     for k in range(ns):
                         dxk, dyk, dzk = (int(v) for v in ei[k])
-                        sl = work[k][lo - dzk:lo - dzk + n_i]
-                        planes.append(_rollyx(sl, dyk, dxk))
+                        planes.append(_rollyx(slabs(k, lo - dzk, n_i),
+                                              dyk, dxk))
                 else:
-                    planes = [w[lo:lo + n_i] for w in work]
+                    planes = [slabs(k, lo, n_i) for k in range(ns)]
 
                 def loader(index, dx, dy, dz=0, _lo=lo, _n=n_i):
-                    sl = work[index][_lo + dz:_lo + dz + _n]
-                    return _rollyx(sl, -dy, -dx)
+                    return _rollyx(slabs(index, _lo + dz, _n), -dy, -dx)
 
                 ctx = KernelCtx(
                     model, planes, loader,
@@ -1488,7 +1673,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                 res = stage_fns[stage_name](ctx)
                 if g_ref is not None:
                     for nm, plane in ctx._globals.items():
-                        part = plane[out_ext:out_ext + bz]
+                        part = plane[out_ext:out_ext + bz, own]
                         g_acc[nm] = part if nm not in g_acc \
                             else g_acc[nm] + part
 
@@ -1500,50 +1685,54 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                             if len(idx) == 1 and stack.ndim == 3:
                                 updates[idx[0]] = stack
                             else:
-                                for j, k in enumerate(idx):
-                                    updates[k] = stack[j]
+                                for c, k in enumerate(idx):
+                                    updates[k] = stack[c]
                         else:
                             updates[model.storage_index[name]] = stack
                 else:
                     updates = {k: res[k] for k in range(ns)}
                 for k, new in updates.items():
-                    w = work[k]
-                    work[k] = jnp.concatenate(
-                        [w[:lo], new, w[lo + n_i:]], axis=0)
+                    work[k] = (new, lo)
 
             for k in range(ns):
-                out_ref[k] = ddf.narrow_plane(work[k][R:R + bz], dtype,
+                out_ref[k] = ddf.narrow_plane(slabs(k, R, bz)[:, own], dtype,
                                               _shifts[k])
 
             if g_ref is not None:
-                @pl.when(i == 0)
+                @pl.when(t == 0)
                 def _():
                     g_ref[...] = jnp.zeros((8, 128), cdtype)
                 for gi, g in enumerate(model.globals_):
                     if g.name not in g_acc:
                         continue
                     part = g_acc[g.name].reshape(
-                        (bz * ny * (nx // 128), 128)).sum(axis=0)
+                        (bz * by * (nx // 128), 128)).sum(axis=0)
                     g_ref[gi] = g_ref[gi] + part
 
-        return kern, n_aux_k
+        return kern, n_aux_k, n_sem
 
     def _mk_call(plan_k, R_k, with_dt=False, with_globals=False,
                  lean=False):
-        kern, n_aux_k = _mk_kernel(plan_k, R_k, with_dt, with_globals,
-                                   lean)
-        out_specs = pl.BlockSpec((ns, bz, ny, nx), lambda i: (0, i, 0, 0),
-                                 memory_space=pltpu.VMEM)
+        kern, n_aux_k, n_sem = _mk_kernel(plan_k, R_k, with_dt,
+                                          with_globals, lean)
+        tiled = nyb > 1
+        out_specs = pl.BlockSpec(
+            (ns, bz, by, nx),
+            (lambda i, j: (0, i, j, 0)) if tiled
+            else (lambda i: (0, i, 0, 0)),
+            memory_space=pltpu.VMEM)
         out_shape = jax.ShapeDtypeStruct((ns, nz, ny, nx), dtype)
         if with_globals:
             out_specs = [out_specs,
-                         pl.BlockSpec((8, 128), lambda i: (0, 0),
+                         pl.BlockSpec((8, 128),
+                                      (lambda i, j: (0, 0)) if tiled
+                                      else (lambda i: (0, 0)),
                                       memory_space=pltpu.VMEM)]
             out_shape = [out_shape,
                          jax.ShapeDtypeStruct((8, 128), cdtype)]
         return pl.pallas_call(
             lbm.mosaic_body(kern, interpret),
-            grid=(nz // bz,),
+            grid=(nzb, nyb) if tiled else (nzb,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1555,12 +1744,12 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
-                pltpu.VMEM((2, ns, bz + 2 * R_k, ny, nx), dtype),
-                pltpu.VMEM((2, n_aux_k, bz + 2 * R_k, ny, nx), cdtype),
-                pltpu.SemaphoreType.DMA((2, 2 * (1 + 2 * R_k))),
+                pltpu.VMEM((2, ns, bz + 2 * R_k, rows, nx), dtype),
+                pltpu.VMEM((2, n_aux_k, bz + 2 * R_k, rows, nx), cdtype),
+                pltpu.SemaphoreType.DMA((2, 2 * n_sem)),
             ],
             compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=100 * 1024 * 1024)
+                vmem_limit_bytes=_VMEM3D_LIMIT)
             if vmem_ceiling else None,
             interpret=interpret,
             name=f"generic_slab_fuse{fuse if plan_k is plan else 1}",
@@ -1638,12 +1827,14 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                 out = invoke(call1, it, fields)
                 return (out, it + adv), None
 
+            # both loops: _PAIR calls a body, an odd call after the loop
             (fields, it), _ = jax.lax.scan(
                 body, (fields, state.iteration), None,
-                length=main // fuse)
+                length=main // fuse, unroll=_PAIR)
             if fuse > 1:
                 (fields, it), _ = jax.lax.scan(
-                    body1, (fields, it), None, length=main % fuse)
+                    body1, (fields, it), None, length=main % fuse,
+                    unroll=_PAIR)
 
         globals_ = jnp.zeros_like(state.globals_)
         if final_g is not None:
@@ -1658,12 +1849,46 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
         return LatticeState(fields=fields, flags=state.flags,
                             globals_=globals_, iteration=it)
 
+    def account(niter: int, has_series: bool = False) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side from
+        the plan (the mirror of ``_iterate_jit``'s schedule): the calls,
+        the windows of the looped kernel, the steps left over."""
+        final = int(niter > 0 and (call_sg if has_series else call_g)
+                    is not None)
+        main = max(niter, 0) - final
+        fused = 0 if has_series else main // fuse
+        rest = main - fused * fuse
+        # calls issued from a two-call loop body: a loop of one trip or
+        # none is no loop (lax.scan unrolls it whole)
+        paired = 0 if has_series else sum(
+            n - n % _PAIR for n in (fused, rest) if n >= 2 * _PAIR)
+        return dict(
+            stages_per_step=len(model.actions["Iteration"]),
+            kernel_calls=fused + rest + final, remainder_steps=rest + final,
+            paired_calls=paired,
+            z_bands=nzb, band_slabs=bz, halo_slabs=R if fused else R1,
+            y_bands=nyb, band_rows=by, halo_rows=hy,
+            # the f32 flag plane (and what rides beside it) of each window
+            aux_planes=(1 + 2 * len(zonal_names) if has_series
+                        else 1 if lean_aux else 1 + len(zonal_names)))
+
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
-        return _iterate_jit(state, params, niter)
+        out = _iterate_jit(state, params, niter)
+        # a call under a trace (supports_3d()'s abstract probe, a
+        # caller's own jit) issues nothing
+        if telemetry.enabled() and not isinstance(out.fields,
+                                                  jax.core.Tracer):
+            did = account(int(niter), params.time_series is not None)
+            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            telemetry.counter("engine.paired_calls", did["paired_calls"])
+            telemetry.annotate(**did)
+        return out
 
     iterate.supports_series = True
     iterate.full_globals = bool(model.n_globals == 0 or call_g is not None)
+    iterate.account = account
+    iterate.plan = (bz, by, fuse)
     # internals for the differentiable wrapper (ops/pallas_adjoint's 3D
     # diff step drives call_g directly, outside the scanning iterate)
     iterate._impl = dict(call_g=call_g, call_sg=call_sg, lean_aux=lean_aux,

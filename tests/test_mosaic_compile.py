@@ -206,6 +206,51 @@ def test_d3q_loop_body_pairs_the_calls_and_copies_no_state(one_chip, case,
     assert not copies, copies
 
 
+def test_generic_d3q19_kuper_256_tiled(one_chip):
+    """The plan of ``example/drop3d_256.xml``: no whole-plane plan of the
+    generic 3D slab engine holds a 256 x 256 plane of this model, so the
+    kernel runs on y-tiled windows under the raised ceiling, two calls a
+    loop body: XLA puts no copy of the 1.34 GB state before the kernel.
+    Only shapes are described."""
+    from tclb_tpu.core.lattice import LatticeState
+    shape = (256, 256, 256)
+    m = get_model("d3q19_kuper")
+    small = Lattice(m, (8, 8, 128), dtype=jnp.float32)
+
+    def on_chip(x, dims=None):
+        return jax.ShapeDtypeStruct(dims or x.shape, x.dtype,
+                                    sharding=one_chip)
+    st = small.state
+    state = LatticeState(
+        fields=on_chip(st.fields, (m.n_storage,) + shape),
+        flags=on_chip(st.flags, shape), globals_=on_chip(st.globals_),
+        iteration=on_chip(st.iteration))
+    params = jax.tree.map(on_chip, small.params)
+    assert pallas_generic.supports_3d(m, shape, jnp.float32, probe=False)
+    bz, by, K = pallas_generic.tile_plan_3d(m, shape)
+    assert by < 256
+    it = pallas_generic.make_pallas_iterate_3d(
+        m, shape, jnp.float32, interpret=False, present={"MRT"}, fuse=K)
+    assert it.plan == (bz, by, K)
+    niter = 5 * K + (K >= 2)
+    did = it.account(niter)
+    assert did["kernel_calls"] == 5 + (K >= 2) and did["paired_calls"] == 4
+    text = jax.jit(lambda s, p: it(s, p, niter)).lower(
+        state, params).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"generic_slab_fuse{K}/pallas_call" in text
+    kernel = re.compile(r"= \S+ custom-call\(.*generic_slab_fuse%d/" % K)
+    bodies = [lines for name, lines in _computations(text).items()
+              if any(kernel.search(line) for line in lines)
+              and re.search(r"\bbody=%%?%s\b" % re.escape(name), text)]
+    assert len(bodies) == 1
+    assert sum(bool(kernel.search(line)) for line in bodies[0]) == 2
+    whole = "f32[%s]" % ",".join(str(n) for n in (m.n_storage,) + shape)
+    copy = re.compile(r"= %s\S* copy(-start)?\(" % re.escape(whole))
+    copies = [line.strip() for line in bodies[0] if copy.search(line)]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("name", ["d2q9_kuper", "d2q9_heat"])
 def test_generic_512(one_chip, name):
     shape = (512, 512)
@@ -289,9 +334,10 @@ def test_generic_d3q19_heat_builder_defaults(one_chip):
     """The 3D generic builder at its own defaults (fuse=1, every node
     type) is refused at 48x48x256 — 18.08M of scoped VMEM against a
     16.00M limit that the planner's budget does not see — while the fuse
-    the Lattice picks there (choose_fuse_3d -> 3) compiles.  Pinned as an
-    expected failure so the planner fix (ROADMAP Queue 1) has a target;
-    any other error fails."""
+    the Lattice picks there (choose_fuse_3d -> 3) compiles.  A whole-plane
+    plan keeps the account it had (PR 34 tiles only planes no such plan
+    holds).  Pinned as an expected failure so the planner fix (ROADMAP M1)
+    has a target; any other error fails."""
     shape = (48, 48, 256)
     m, lat, _ = _channel("d3q19_heat", shape)
     it = pallas_generic.make_pallas_iterate(m, shape, jnp.float32,

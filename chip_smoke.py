@@ -7,6 +7,7 @@
     python chip_smoke.py --rehearse   control-flow rehearsal at tiny
                                       sizes on any backend; can never
                                       print ``"ok": true``
+    python chip_smoke.py --only TEXT  the phases whose name holds TEXT
 
 Everything runs in this one process, through the function the ``tclb``
 console script runs (``tclb_tpu.__main__``): a chip belongs to one
@@ -14,8 +15,10 @@ process at a time, so no child is started.  Each phase fails the script
 on an exception, a non-finite or mis-shaped output field, a ``failcheck``
 or ``engine_fallback`` event, or an engine family other than the one
 expected — a run that finished on the XLA step under a Pallas name is a
-failure here, not a result.  ``TCLB_FASTPATH`` is never set to ``force``:
-the engines are whatever ``Lattice`` selects on this backend.
+failure here, not a result.  One phase turns that round: it plants a NaN
+and an infinity and fails unless ``<Failcheck>`` stops the run on them.
+``TCLB_FASTPATH`` is never set to ``force``: the engines are whatever
+``Lattice`` selects on this backend.
 
 Without an accelerator the script exits non-zero and prints no result.
 The last line of a passing run is one JSON object,
@@ -124,6 +127,24 @@ def read_vti(path: str) -> dict:
     return out
 
 
+#: the planted values of the ``failcheck_fires`` phases: (population,
+#: rows from the top edge, column, value); the rows lie in the last shard
+PLANTED = ((3, 5, 40, float("nan")), (3, 9, 60, float("inf")))
+
+
+def spoil(solver) -> int:
+    """``<CallPython>`` of the ``failcheck_fires`` phases: PLANTED written
+    into the populations where they lie, on one chip or a mesh."""
+    import jax
+    lat = solver.lattice
+    fields, ny = lat.state.fields, lat.shape[-2]
+    sharding = fields.sharding
+    for plane, up, x, value in PLANTED:
+        fields = fields.at[plane, ny - up, x].set(value)
+    lat.state = lat.state.replace(fields=jax.device_put(fields, sharding))
+    return 0
+
+
 # --------------------------------------------------------------------------- #
 # the smoke
 # --------------------------------------------------------------------------- #
@@ -159,9 +180,10 @@ def run_argv(case: str, outdir: str, mesh: str | None = None) -> list:
 
 
 class Smoke:
-    def __init__(self, rehearse: bool):
+    def __init__(self, rehearse: bool, only: str = ""):
         from tclb_tpu import telemetry
         self.rehearse = rehearse
+        self.only = only
         self.events: list[dict] = []
         self.failed: list[str] = []
         telemetry.subscribe(self.events.append)
@@ -185,6 +207,15 @@ class Smoke:
 
     # -- one `tclb run` ---------------------------------------------------- #
 
+    def expect_engine(self, tag: str, engine: tuple) -> None:
+        """Under a forced interpret-mode rehearsal the tags are the
+        chip's; on a plain CPU rehearsal every engine is "xla": report,
+        not fail."""
+        forced = os.environ.get("TCLB_FASTPATH") == "force"
+        if (not self.rehearse or forced) and not tag.startswith(engine):
+            raise AssertionError(f"engine {tag!r}, expected one of "
+                                 f"{engine}")
+
     def check_events(self, ev: list, engine: tuple, steps: int | None
                      ) -> dict:
         """The engine this run selected, after checking that it is of an
@@ -202,12 +233,7 @@ class Smoke:
         fc = [e for e in ev if e.get("kind") == "failcheck"]
         if fc:
             raise AssertionError(f"failcheck fired: {fc[0]}")
-        # under a forced interpret-mode rehearsal the tags are the chip's;
-        # on a plain CPU rehearsal every engine is "xla": report, not fail
-        forced = os.environ.get("TCLB_FASTPATH") == "force"
-        if (not self.rehearse or forced) and not tag.startswith(engine):
-            raise AssertionError(f"engine {tag!r}, expected one of "
-                                 f"{engine}")
+        self.expect_engine(tag, engine)
         spans = [e for e in ev if e.get("kind") == "span"
                  and e.get("name") == "iterate"]
         bad = [e for e in spans if e.get("ok") is False]
@@ -356,9 +382,107 @@ class Smoke:
             raise AssertionError(f"mesh {mesh} vs one device: max |diff| "
                                  f"{diff:.3e} > {TOL:.1e}")
 
+    # -- the guard itself ---------------------------------------------------- #
+
+    def failcheck_fires(self, name: str, file: str, engine: tuple,
+                        mesh: str | None = None) -> None:
+        """Example ``file`` with its handlers replaced by ``<Failcheck>``
+        every ``step`` steps with a ``<VTK/>`` rescue child, and PLANTED
+        written at the second firing: the first passes and brings four
+        bytes a quantity to the host, the second names ``Rho``, counts
+        two, writes one file and stops the run; every quantity's count
+        on the device equals a host scan of its plane."""
+        import numpy as np
+        from tclb_tpu.__main__ import build_parser, run_case
+        step = 4 if self.rehearse else 100
+        outdir = os.path.join(OUT, name)
+        shutil.rmtree(outdir, ignore_errors=True)
+        tree = ET.parse(self.case(file, 3 * step, handlers=False))
+        root = tree.getroot()
+        at = list(root).index(root.find("Solve"))
+        root.insert(at, ET.Element("CallPython", {
+            "module": "chip_smoke", "function": "spoil",
+            "Iterations": str(2 * step)}))
+        guard = ET.Element("Failcheck", {"Iterations": str(step)})
+        guard.append(ET.Element("VTK"))
+        root.insert(at + 1, guard)
+        cut = os.path.join(OUT, "cases_guard", file)
+        os.makedirs(os.path.dirname(cut), exist_ok=True)
+        tree.write(cut)
+
+        mark = len(self.events)
+        solver = run_case(build_parser().parse_args(
+            run_argv(cut, outdir, mesh)))
+        ev = self.events[mark:]
+        tag = next(e["engine"] for e in ev
+                   if e.get("kind") == "engine_selected")
+        self.expect_engine(tag, engine)
+        spans = [e for e in ev if e.get("kind") == "span"]
+        done = sum(int(e["iters"]) for e in spans if e["name"] == "iterate")
+        if done != 2 * step:
+            raise AssertionError(f"{done} steps ran, expected the run to "
+                                 f"stop after {2 * step}")
+        lat = solver.lattice
+        names = [q.name for q in lat.model.quantities if not q.adjoint]
+        hits = [(e["iteration"], e["quantity"], e["n_bad"])
+                for e in ev if e.get("kind") == "failcheck"]
+        if hits != [(2 * step, "Rho", len(PLANTED))]:
+            raise AssertionError(f"failcheck events {hits}, expected one "
+                                 f"for Rho at {2 * step} counting 2")
+        guards = [e for e in spans if e["name"] == "handler"
+                  and e.get("handler") == "cbFailcheck"]
+        to_host = []
+        for g in guards:
+            # its own children are count programs and one copy of the
+            # counts; planes come down under the rescue's output.vtk only
+            kids = [e for e in spans if e.get("parent") == g["id"]]
+            evals = [e.get("reduce") for e in kids
+                     if e["name"] == "quantity.eval"]
+            if evals != ["nonfinite"] * len(names):
+                raise AssertionError(f"quantity.eval spans {evals}")
+            to_host.append(sum(e["bytes"] for e in kids
+                               if e["name"] == "quantity.d2h"))
+        if to_host != [4 * len(names)] * 2:
+            raise AssertionError(f"bytes to the host {to_host}, expected "
+                                 f"{4 * len(names)} at each of 2 firings")
+        writes = sum(e["name"] == "output.vtk" for e in spans)
+        if writes != 1:
+            raise AssertionError(f"{writes} output.vtk spans, expected 1")
+        vtis = sorted(glob.glob(os.path.join(outdir, "*_VTK_*.vti")))
+        if [os.path.basename(v)[-12:] for v in vtis] \
+                != [f"{2 * step:08d}.vti"]:
+            raise AssertionError(f"rescue files {vtis}, expected one")
+        rho = read_vti(vtis[0])["Rho"]
+        if int(rho.size - np.isfinite(rho).sum()) != len(PLANTED):
+            raise AssertionError("the rescue file does not hold the "
+                                 "planted values")
+        counts = {}
+        for q in names:
+            c = lat.count_nonfinite(q)
+            plane = np.asarray(lat.get_quantity(q))
+            counts[q] = int(c)
+            if counts[q] != int(plane.size - np.isfinite(plane).sum()):
+                raise AssertionError(f"{q}: the device counts {counts[q]}"
+                                     ", a host scan otherwise")
+            if len(c.sharding.device_set) != len(
+                    lat.state.fields.sharding.device_set):
+                raise AssertionError(f"{q}: count on {c.sharding}")
+        ny = lat.shape[-2]
+        print(json.dumps({
+            "phase": name, "case": os.path.relpath(cut, HERE),
+            "mesh": mesh, "engine": tag, "steps": done,
+            "failcheck": hits[0], "counts": counts,
+            "bytes_to_host": to_host, "rescue": os.path.basename(vtis[0]),
+            "planted_rows": [ny - up for _, up, _, _ in PLANTED],
+            "devices": len(lat.state.fields.sharding.device_set),
+            "failcheck_ms": [round(1e3 * g["dur_s"], 3) for g in guards],
+        }), flush=True)
+
     # -- phase bookkeeping --------------------------------------------------- #
 
     def phase(self, name: str, fn, *a, **k) -> None:
+        if self.only not in name:
+            return
         print(f"--- {name}", flush=True)
         try:
             fn(name, *a, **k)
@@ -389,10 +513,14 @@ def one_chip(s: Smoke) -> None:
     s.phase("agree_d3q27_cumulant", s.agree, "3d_channel.xml", cumulant)
     s.phase("agree_d3q27_cumulant_tiled", s.agree, "tgv_256.xml", cumulant)
     s.phase("agree_d2q9_kuper", s.agree, "drop_512.xml", generic)
+    s.phase("failcheck_fires", s.failcheck_fires, "karman_1024.xml",
+            ("pallas_2d[d2q9,fuse=2]",))
 
 
 def four_chips(s: Smoke) -> None:
     s.phase("sharded_4x1", s.sharded, "karman_4096.xml", "4x1")
+    s.phase("failcheck_fires_4x1", s.failcheck_fires, "karman_4096.xml",
+            ("pallas_sharded[",), mesh="4x1")
 
 
 def main(argv=None) -> int:
@@ -403,6 +531,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on any backend, to find wrong paths "
                     "and arguments before a chip run; never prints ok")
+    ap.add_argument("--only", default="", metavar="TEXT",
+                    help="run only the phases whose name holds TEXT; the "
+                    "last line then says so")
     args = ap.parse_args(argv)
 
     import jax
@@ -443,7 +574,7 @@ def main(argv=None) -> int:
         "rehearsal": args.rehearse}), flush=True)
 
     t0 = time.perf_counter()
-    s = Smoke(args.rehearse)
+    s = Smoke(args.rehearse, args.only)
     (four_chips if args.chips == 4 else one_chip)(s)
     print(json.dumps({"total_s": round(time.perf_counter() - t0, 1),
                       "failed": s.failed}), flush=True)
@@ -453,7 +584,8 @@ def main(argv=None) -> int:
         print(json.dumps({"ok": False, "rehearsal": "passed",
                           "device": device}))
         return 0
-    print(json.dumps({"ok": True, "device": device}))
+    print(json.dumps({"ok": True, "device": device,
+                      **({"only": args.only} if args.only else {})}))
     return 0
 
 

@@ -543,8 +543,13 @@ class cbStop(Handler):
 
 
 class cbFailcheck(Handler):
-    """<Failcheck Iterations="N">: NaN scan of quantities; on failure run
-    child elements (rescue dump) then stop (reference cbFailcheck,
+    """<Failcheck Iterations="N" what="...">: look for NaN and infinity in
+    the quantities (``what``, default all; adjoint ones skipped), on the
+    device: one count a quantity comes to the host
+    (``Solver.nonfinite_counts``), never a plane.  On a hit (the first
+    quantity, in the model's order, whose count is not zero) warn, emit
+    ``telemetry.failcheck``, run the child elements once each (rescue
+    dump; these bring planes down) and stop (reference cbFailcheck,
     src/Handlers.cpp.Rt:1175-1277)."""
 
     kind = "callback"
@@ -552,34 +557,31 @@ class cbFailcheck(Handler):
     def do_it(self) -> int:
         s = self.solver
         what = self.node.get("what")
-        names = set(what.split(",")) if what else {"all"}
-        bad = False
-        for q in s.model.quantities:
-            if q.adjoint:
-                continue
-            if "all" not in names and q.name not in names:
-                continue
-            arr = s.quantity_host(q.name)
-            with telemetry.span("failcheck.scan", quantity=q.name):
-                finite = np.isfinite(arr)
-                all_finite = finite.all()
-            if not all_finite:
-                n_bad = int(arr.size - finite.sum())
-                log.warning(f"Failcheck: {q.name} has {n_bad} non-finite "
-                            f"values at iteration {s.iter}")
-                telemetry.failcheck(
-                    iteration=s.iter, quantity=q.name, n_bad=n_bad,
-                    engine=getattr(s.lattice, "_fast_name", None) or "xla")
-                bad = True
-                break
-        if bad:
-            for child in self.node:
-                h = get_handler(child, self.solver)
-                if h is not None:
-                    h.init()
+        wanted = set(what.split(",")) if what else {"all"}
+        names = [q.name for q in s.model.quantities if not q.adjoint
+                 and ("all" in wanted or q.name in wanted)]
+        counts = s.nonfinite_counts(names)
+        telemetry.counter("failcheck.device_scans")
+        telemetry.annotate(scan="device", quantities=len(names),
+                           bytes_to_host=4 * len(names))
+        hit = next(((q, n) for q, n in zip(names, counts) if n), None)
+        if hit is None:
+            return 0
+        name, n_bad = hit
+        log.warning(f"Failcheck: {name} has {n_bad} non-finite "
+                    f"values at iteration {s.iter}")
+        telemetry.failcheck(
+            iteration=s.iter, quantity=name, n_bad=n_bad,
+            engine=getattr(s.lattice, "_fast_name", None) or "xla")
+        for child in self.node:
+            h = get_handler(child, self.solver)
+            if h is not None:
+                h.init()
+                # a child without Iterations ran in its init, as where
+                # it stands in a case; one with them runs now
+                if h.every_iter:
                     h.do_it()
-            return ITERATION_STOP
-        return 0
+        return ITERATION_STOP
 
 
 class cbSample(Handler):

@@ -17,8 +17,9 @@ from __future__ import annotations
 import os
 import time
 import xml.etree.ElementTree as ET
-from typing import Any, Optional
+from typing import Any, Optional, Sequence
 
+import jax
 import numpy as np
 
 from tclb_tpu import telemetry
@@ -225,13 +226,30 @@ class Solver:
         program on the device (``quantity.eval``, fenced when traced;
         ``Lattice.get_quantity`` adds ``program``: ``"built"`` on the
         call that compiled it, ``"reused"`` after), then the copy, or on
-        a mesh the gather, to the host (``quantity.d2h``)."""
+        a mesh the gather, to the host (``quantity.d2h``).  For output
+        (``<VTK>``, ``<TXT>``, ``<Catalyst>``); ``<Failcheck>`` brings no
+        plane down: :meth:`nonfinite_counts`."""
         with telemetry.span("quantity.eval", quantity=name) as sp:
             q = sp.sync(self.lattice.get_quantity(name))
             sp.add(bytes=q.nbytes)
         with telemetry.span("quantity.d2h", quantity=name,
                             bytes=q.nbytes):
             return np.asarray(q)
+
+    def nonfinite_counts(self, names: Sequence[str]) -> list[int]:
+        """How many values of each named quantity are NaN or infinite,
+        tested on the device (``Lattice.count_nonfinite``): every count
+        program is dispatched before any is waited for (one
+        ``quantity.eval`` each, ``reduce="nonfinite"``, fenced only when
+        traced), then the counts come to the host in one copy of 4 bytes
+        a quantity (``quantity.d2h``)."""
+        counts = []
+        for name in names:
+            with telemetry.span("quantity.eval", quantity=name,
+                                reduce="nonfinite") as sp:
+                counts.append(sp.sync(self.lattice.count_nonfinite(name)))
+        with telemetry.span("quantity.d2h", bytes=4 * len(counts)):
+            return [int(c) for c in jax.device_get(counts)]
 
     def quantity_arrays(self, what: Optional[set[str]] = None
                         ) -> dict[str, np.ndarray]:

@@ -726,16 +726,12 @@ def make_sampled_iterate(model: Model, points: np.ndarray,
 # --------------------------------------------------------------------------- #
 
 
-@lru_cache(maxsize=None)
-def quantity_program(model: Model, name: str, cdtype: Any,
-                     storage_repr: str) -> tuple[Callable, list[int]]:
-    """The compiled program of one Quantity, ``program(fields, flags,
-    params, iteration, avg_start) -> plane(s)``, and the count of its
-    traces (a one-element list the traced body bumps: a call that leaves
-    it alone reused an executable).  Keyed by what the trace depends on,
-    not by the Lattice, so a second lattice of the model reuses the trace
-    and ``jax.jit``'s own cache handles shapes and shardings; kept, with
-    its model, for the life of the process, as the registry keeps models."""
+def _quantity_body(model: Model, name: str, cdtype: Any,
+                   storage_repr: str) -> tuple[Callable, list[int]]:
+    """The traced body both programs of a Quantity share, ``evaluate(
+    fields, flags, params, iteration, avg_start) -> plane(s)``, and the
+    count of its traces (a one-element list the body bumps: a call that
+    leaves it alone reused an executable)."""
     fn = model.quantity_fns[name]
     shift_block = ddf.stack_shift(model, storage_repr)
     traces = [0]
@@ -751,7 +747,44 @@ def quantity_program(model: Model, name: str, cdtype: Any,
         with jax.default_matmul_precision("highest"):
             return fn(ctx)
 
+    return evaluate, traces
+
+
+@lru_cache(maxsize=None)
+def quantity_program(model: Model, name: str, cdtype: Any,
+                     storage_repr: str) -> tuple[Callable, list[int]]:
+    """The compiled program of one Quantity, ``program(fields, flags,
+    params, iteration, avg_start) -> plane(s)``, and the count of its
+    traces (a one-element list the traced body bumps: a call that leaves
+    it alone reused an executable).  Keyed by what the trace depends on,
+    not by the Lattice, so a second lattice of the model reuses the trace
+    and ``jax.jit``'s own cache handles shapes and shardings; kept, with
+    its model, for the life of the process, as the registry keeps models."""
+    evaluate, traces = _quantity_body(model, name, cdtype, storage_repr)
     return jax.jit(evaluate), traces
+
+
+@lru_cache(maxsize=None)
+def nonfinite_program(model: Model, name: str, cdtype: Any,
+                      storage_repr: str) -> tuple[Callable, list[int]]:
+    """What ``<Failcheck>`` runs for one Quantity: ``program(fields, flags,
+    params, iteration, avg_start) -> int32 scalar``, the number of values
+    of the quantity, over every node and component, that are NaN or
+    infinite; beside it the count of its traces.  The same body as
+    :func:`quantity_program` and the test in the same ``jax.jit``: only
+    the count leaves the program, so it has no plane-sized output and
+    nothing but four bytes to bring to the host (a v5e compile still
+    keeps the plane as a temporary between the quantity's last fusion
+    and the reduction; PERF.md), and on a mesh the state's sharding
+    carries through to one replicated scalar.  Keyed and kept as
+    :func:`quantity_program` is; the state is read, not donated."""
+    evaluate, traces = _quantity_body(model, name, cdtype, storage_repr)
+
+    def count(fields, flags, params, iteration, avg_start):
+        plane = evaluate(fields, flags, params, iteration, avg_start)
+        return jnp.sum(~jnp.isfinite(plane), dtype=jnp.int32)
+
+    return jax.jit(count), traces
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1338,7 +1371,19 @@ class Lattice:
         or sharding compiles again.  The innermost open span
         (``quantity.eval`` under the Solver) learns whether this call
         ``"built"`` the program or ``"reused"`` it."""
-        program, traces = quantity_program(
+        return self._run_quantity(quantity_program, name)
+
+    def count_nonfinite(self, name: str) -> jax.Array:
+        """How many values of a registered Quantity are NaN or infinite,
+        as an int32 scalar still on the device (replicated on a mesh):
+        :func:`nonfinite_program`, dispatched and not waited for, so a
+        caller can issue every quantity's count and fetch them together.
+        Says ``"built"`` or ``"reused"`` as :meth:`get_quantity` does."""
+        return self._run_quantity(nonfinite_program, name)
+
+    def _run_quantity(self, programs: Callable, name: str) -> jax.Array:
+        """One of a quantity's two compiled programs on the live state."""
+        program, traces = programs(
             self.model, name, jnp.dtype(self.dtype), self.storage_repr)
         before = traces[0]
         out = program(self.state.fields, self.state.flags, self.params,

@@ -363,6 +363,33 @@ def test_chip_smoke_reads_back_vti(tmp_path, compress):
     assert (back["U"].reshape(6, 40, 3) == np.moveaxis(u, 0, -1)).all()
 
 
+def test_chip_smoke_rehearses_the_guard_phase(tmp_path, monkeypatch,
+                                              capsys):
+    """`failcheck_fires` at its rehearsal size: the planted NaN and
+    infinity stop the run at the second firing, `Rho` counts two, one
+    rescue file; a rehearsal never prints ok."""
+    import json
+    import sys
+
+    from tclb_tpu import telemetry
+    mod = _chip_smoke()
+    monkeypatch.setattr(mod, "OUT", str(tmp_path / "smoke"))
+    monkeypatch.setitem(sys.modules, "chip_smoke", mod)   # <CallPython>
+    try:
+        assert mod.main(["--rehearse", "--only", "failcheck_fires"]) == 0
+    finally:
+        telemetry.disable()
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    phase, = [x for x in lines if x.get("phase")]
+    assert phase["phase"] == "failcheck_fires"
+    assert phase["failcheck"] == [8, "Rho", 2] and phase["steps"] == 8
+    assert phase["bytes_to_host"] == [8, 8]
+    assert phase["rescue"].endswith("_VTK_00000008.vti")
+    assert lines[-2]["failed"] == []
+    assert lines[-1]["ok"] is False and lines[-1]["rehearsal"] == "passed"
+
+
 def test_cli_output_flag_wins_over_config_attribute(tmp_path):
     """`tclb run --output DIR/` must not be overridden by the case file's
     own output= attribute."""

@@ -297,16 +297,12 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo):
     assert "halo_exchange/" in text
 
 
-@pytest.mark.parametrize("name,quantity", [("d2q9", "U"),
-                                           ("d2q9_kuper", "F")])
-def test_quantity_program_on_4x1_mesh(topo, name, quantity):
-    """`Lattice.get_quantity`'s compiled program at the mesh cell's size:
-    partitioned by the compiler, its result sharded by rows like the
-    state (the gather to the host stays `quantity.d2h`'s), and no plane
-    gathered between chips: `F`'s rolls of `phi` exchange edge rows only."""
+def _quantity_on_4x1_mesh(topo, programs, name, quantity):
+    """One of a quantity's two programs compiled at the mesh cell's size,
+    the state split by rows over four of the described chips."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from tclb_tpu.core.lattice import SimParams, quantity_program
+    from tclb_tpu.core.lattice import SimParams
     from tclb_tpu.parallel import halo
     shape = (4096, 1024)
     m = get_model(name)
@@ -317,17 +313,54 @@ def test_quantity_program_on_4x1_mesh(topo, name, quantity):
                                     sharding=NamedSharding(mesh, spec))
 
     n = len(m.settings)
-    program, _ = quantity_program(m, quantity, jnp.dtype(jnp.float32), "raw")
-    compiled = program.lower(
+    program, _ = programs(m, quantity, jnp.dtype(jnp.float32), "raw")
+    return program.lower(
         on(halo.field_spec(mesh), m.n_storage, *shape),
         on(halo.flag_spec(mesh), *shape, dtype=jnp.uint16),
         SimParams(settings=on(P(), n), zone_table=on(P(), n, m.zone_max)),
         on(P(), dtype=jnp.int32), on(P(), dtype=jnp.int32)).compile()
+
+
+MESH_QUANTITIES = [("d2q9", "U"), ("d2q9_kuper", "F")]
+
+
+@pytest.mark.parametrize("name,quantity", MESH_QUANTITIES)
+def test_quantity_program_on_4x1_mesh(topo, name, quantity):
+    """`Lattice.get_quantity`'s compiled program at the mesh cell's size:
+    partitioned by the compiler, its result sharded by rows like the
+    state (the gather to the host stays `quantity.d2h`'s), and no plane
+    gathered between chips: `F`'s rolls of `phi` exchange edge rows only."""
+    from jax.sharding import PartitionSpec as P
+
+    from tclb_tpu.core.lattice import quantity_program
+    compiled = _quantity_on_4x1_mesh(topo, quantity_program, name, quantity)
     assert compiled.output_shardings.spec == P(None, "y", "x")
     text = compiled.as_text()
     assert "all-gather" not in text
     edges = re.search(r"collective-permute|all-to-all", text)
     assert bool(edges) == (quantity == "F")
+
+
+@pytest.mark.parametrize("name,quantity", MESH_QUANTITIES)
+def test_count_program_on_4x1_mesh(topo, name, quantity):
+    """`<Failcheck>`'s program there: every chip counts its own rows and
+    one scalar all-reduce ends the sum on all four; what leaves the
+    program is four bytes, and its temporaries are no more than the
+    plane's program holds."""
+    from tclb_tpu.core.lattice import nonfinite_program, quantity_program
+    compiled = _quantity_on_4x1_mesh(topo, nonfinite_program, name, quantity)
+    assert compiled.output_shardings.is_fully_replicated
+    text = compiled.as_text()
+    assert re.search(r"-> s32\[\] \{", text[text.index("ENTRY"):])
+    assert "all-gather" not in text
+    assert re.search(r"s32\[\]\S* all-reduce", text)
+    edges = re.search(r"collective-permute|all-to-all", text)
+    assert bool(edges) == (quantity == "F")
+    memory = compiled.memory_analysis()
+    plane = _quantity_on_4x1_mesh(topo, quantity_program, name,
+                                  quantity).memory_analysis()
+    assert memory.output_size_in_bytes < 1024 < plane.output_size_in_bytes
+    assert memory.temp_size_in_bytes <= plane.temp_size_in_bytes + 2**20
 
 
 def test_generic_d3q19_heat_builder_defaults(one_chip):

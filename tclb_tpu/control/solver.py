@@ -128,21 +128,25 @@ class Solver:
         dt = now - self._prog_t0
         if dt < 1.0:
             return
-        # force execution so the rate is real (jit dispatch is async);
-        # only the elapsed chunk is billed
-        jax.block_until_ready(self.lattice.state.fields)
-        dt = time.time() - self._prog_t0
-        nodes = float(np.prod(self.shape))
-        mlups = nodes * self._prog_iters / dt / 1e6
-        bytes_per = (2 * self.model.n_storage
-                     * np.dtype(self.lattice.state.fields.dtype).itemsize
-                     + 2)
-        log.info(f"iter {self.iter}: {mlups:8.1f} MLUPS "
-                 f"({mlups * bytes_per / 1e3:6.1f} GB/s eff) "
-                 f"[{self._prog_iters} it in {dt:.2f} s]")
-        telemetry.event("progress", iteration=self.iter,
-                        mlups=round(mlups, 1),
-                        gbps=round(mlups * bytes_per / 1e3, 1))
+        # the call that reports, under its span (the others stay two
+        # float operations)
+        with telemetry.span("progress", iteration=self.iter):
+            # force execution so the rate is real (jit dispatch is
+            # async); only the elapsed chunk is billed.  The run's own
+            # fence, telemetry on or off: it is no Span.sync
+            jax.block_until_ready(self.lattice.state.fields)
+            dt = time.time() - self._prog_t0
+            nodes = float(np.prod(self.shape))
+            mlups = nodes * self._prog_iters / dt / 1e6
+            bytes_per = (2 * self.model.n_storage
+                         * np.dtype(self.lattice.state.fields.dtype).itemsize
+                         + 2)
+            log.info(f"iter {self.iter}: {mlups:8.1f} MLUPS "
+                     f"({mlups * bytes_per / 1e3:6.1f} GB/s eff) "
+                     f"[{self._prog_iters} it in {dt:.2f} s]")
+            telemetry.event("progress", iteration=self.iter,
+                            mlups=round(mlups, 1),
+                            gbps=round(mlups * bytes_per / 1e3, 1))
         self._prog_t0, self._prog_iters = time.time(), 0
 
     # -- config provenance (reference MainContainer dump with version/
@@ -198,16 +202,22 @@ class Solver:
             "Walltime": time.time() - self.start_walltime,
             "OptIteration": float(self.opt_iter),
         }
-        svec = np.asarray(lat.params.settings)
+        # the row's three device-to-host copies, in this order
+        with telemetry.span("output.log.fetch") as sp:
+            svec = np.asarray(lat.params.settings)
+            table = (np.asarray(lat.params.zone_table) if self.geometry
+                     else None)
+            globals_ = lat.get_globals()
+            sp.add(copies=2 + (table is not None),
+                   bytes=svec.nbytes + lat.state.globals_.nbytes
+                   + (0 if table is None else table.nbytes))
         for s in m.settings:
             row[f"{s.name}"] = float(svec[m.setting_index[s.name]])
-        if self.geometry:
-            table = np.asarray(lat.params.zone_table)
+        if table is not None:
             for s in m.zonal_settings:
                 for zname, zid in self.geometry.setting_zones.items():
                     row[f"{s}-{zname}"] = float(table[m.setting_index[s], zid])
-        for name, val in lat.get_globals().items():
-            row[name] = val
+        row.update(globals_)
         return row
 
     def write_log(self) -> None:
@@ -217,7 +227,9 @@ class Solver:
             if self.log is None:
                 self.log = CSVLog(self.out_path("Log", "csv",
                                                 with_iter=False))
-            self.log.write(self.log_row())
+            row = self.log_row()
+            with telemetry.span("output.log.write") as sp:
+                sp.add(bytes=self.log.write(row))
 
     # -- output fan-out ------------------------------------------------------ #
 

@@ -25,6 +25,7 @@ and shardable (parallel/halo.py wraps it in ``shard_map``).
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import lru_cache, partial
 from typing import Any, Callable, Optional, Sequence
 
@@ -1215,23 +1216,24 @@ class Lattice:
     def iterate(self, niter: int) -> None:
         """Advance ``niter`` steps on the auto-selected engine.  With
         telemetry enabled the chunk runs under an ``iterate`` span
-        (block_until_ready-fenced wall time, MLUPS + vs-roofline derived
-        metrics); disabled, the span machinery is a single boolean check."""
+        (block_until_ready-fenced wall time, derived MLUPS); disabled,
+        the span machinery is a single boolean check."""
         if not telemetry.enabled():
             self._iterate_impl(niter)
             return
         # int(iteration) forces a device sync BEFORE the span opens, so
-        # the measured wall time never bills a previous chunk's async tail
+        # the measured wall time never bills a previous chunk's async
+        # tail; what the read itself took is the span's pre_sync_s
+        t = time.perf_counter()
+        iteration = int(self.state.iteration)
+        pre_sync_s = round(time.perf_counter() - t, 6)
         with telemetry.span(
                 "iterate", iters=int(niter),
                 nodes=float(np.prod(self.shape)),
-                bytes_per_node=(2 * self.model.n_storage
-                                * np.dtype(self.state.fields.dtype).itemsize
-                                + 2),
                 storage_dtype=np.dtype(self.state.fields.dtype).name,
                 storage_repr=self.storage_repr,
                 model=self.model.name,
-                iteration=int(self.state.iteration)) as sp:
+                iteration=iteration, pre_sync_s=pre_sync_s) as sp:
             self._iterate_impl(niter)
             engine = ("sampled_xla" if self.sampler is not None
                       else (self._fast_name or "xla"))
@@ -1259,9 +1261,12 @@ class Lattice:
         nfast = niter if full else niter - 1
         use_fast = fast is not None and ok_series and nfast >= 1
         done = nfast if use_fast else niter
+        # dispatch_s: the jitted call has returned, the fence not begun;
+        # a probed first call (compile, fallback ladder) leaves it out
         with telemetry.span("iterate.fused", iters=done) as sp:
             if not use_fast:
                 self.state = self._iterate(self.state, self.params, niter)
+                sp.mark("dispatch_s")
             elif self._fast_probing:
                 with telemetry.span("engine.probe",
                                     engine=self._fast_name) as probe:
@@ -1274,6 +1279,7 @@ class Lattice:
                     probe.sync(self.state)
             else:
                 self.state = fast(self.state, self.params, nfast)
+                sp.mark("dispatch_s")
             sp.add(iters=done,
                    engine=(self._fast_name if use_fast else None) or "xla")
             sp.sync(self.state)
@@ -1281,6 +1287,7 @@ class Lattice:
             # the hybrid engines' trailing XLA step, for the globals
             with telemetry.span("iterate.globals_step", iters=1) as sp:
                 self.state = self._iterate(self.state, self.params, 1)
+                sp.mark("dispatch_s")
                 sp.sync(self.state)
 
     def _probe_first_call(self, fast, niter: int, nfast: int,

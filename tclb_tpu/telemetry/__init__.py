@@ -7,9 +7,9 @@ Usage (a trace costs nothing unless asked for):
   sink on; everything below is a strict no-op otherwise;
 * ``telemetry.event(kind, **fields)`` — one structured event line;
 * ``with telemetry.span("iterate", nodes=n, iters=k) as sp: ...;
-  sp.sync(out)`` — honest wall-time (``block_until_ready`` fencing),
-  MLUPS / vs-roofline derived metrics, ``jax.profiler.TraceAnnotation``
-  passthrough;
+  sp.sync(out)`` — honest wall-time (``block_until_ready`` fencing, the
+  seconds it blocked in ``wait_s``), derived MLUPS,
+  ``jax.profiler.TraceAnnotation`` passthrough;
 * ``telemetry.annotate(**fields)`` — add fields to the innermost open
   span from a callee that has none of its own;
 * ``telemetry.counter(name)`` — monotonic counters, snapshotted
@@ -28,5 +28,4 @@ from tclb_tpu.telemetry.events import (  # noqa: F401
     engine_fallback, engine_selected, event, failcheck, job_context,
     path, set_job, subscribe, unsubscribe)
 from tclb_tpu.telemetry.spans import (  # noqa: F401
-    HBM_GBS, NOOP_SPAN, Span, annotate, device_kind, fuse_of,
-    roofline_mlups, span)
+    NOOP_SPAN, Span, annotate, fuse_of, span)

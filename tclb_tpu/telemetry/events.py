@@ -21,7 +21,12 @@ Design constraints:
   reference's equivalent switch is its compile-time logging level);
 * **append-only JSONL** — one self-describing JSON object per line, so a
   crashed run still yields a readable (truncated) trace and two traces
-  diff line-wise;
+  diff line-wise.  The file is block-buffered: it is flushed by every
+  event emitted while no span is open on its thread (a thread's
+  outermost span closing, a ``<Solve>`` segment, is one), by each
+  ``counters`` snapshot and on :func:`disable`, so the spans inside a
+  segment cost no write each and a killed run keeps whole lines up to
+  its last segment;
 * **counters survive abnormal exits** — cumulative ``counters`` snapshots
   are emitted every ``COUNTER_SNAPSHOT_S`` seconds (piggybacked on event
   traffic), so a SIGKILLed run's trace still carries counter totals; the
@@ -142,7 +147,7 @@ def enable(trace_path: str) -> None:
             _close_locked()
         d = os.path.dirname(os.path.abspath(trace_path))
         os.makedirs(d, exist_ok=True)
-        _sink = open(trace_path, "a", buffering=1)  # line-buffered
+        _sink = open(trace_path, "a")       # flushed by event(), not a line
         _path = trace_path
         # counters are session-scoped: a fresh JSONL session must not
         # inherit bumps recorded while only live sinks were attached
@@ -200,13 +205,14 @@ def event(kind: str, **fields: Any) -> None:
         return
     doc = {"kind": kind, "ts": round(time.time(), 6)}
     doc.update(fields)
-    if kind != "span":
-        stack = span_stack()
-        if stack:
-            doc.setdefault("parent", stack[-1].id)
+    stack = span_stack()        # a span has left it before its own event
+    if stack and kind != "span":
+        doc.setdefault("parent", stack[-1].id)
     with _lock:
-        _maybe_snapshot_counters_locked()
+        snapshot = _maybe_snapshot_counters_locked()
         _fanout_locked(doc)
+        if _sink is not None and (snapshot or not stack):
+            _sink.flush()
 
 
 def counter(name: str, inc: float = 1) -> None:
@@ -228,20 +234,22 @@ def counters() -> dict[str, float]:
         return dict(_counters)
 
 
-def _maybe_snapshot_counters_locked() -> None:
+def _maybe_snapshot_counters_locked() -> bool:
     # Counter loss on abnormal exit: the final flush in _close_locked
     # never happens on SIGKILL, so piggyback a cumulative snapshot on
     # event traffic every COUNTER_SNAPSHOT_S seconds.  Snapshots are
     # cumulative, so the report aggregates them with per-session max.
+    # True where a snapshot went out.
     global _counters_last_emit
     if not _counters:
-        return
+        return False
     now = time.monotonic()
     if now - _counters_last_emit < COUNTER_SNAPSHOT_S:
-        return
+        return False
     _counters_last_emit = now
     _fanout_locked({"kind": "counters", "ts": round(time.time(), 6),
                     "counters": dict(_counters)})
+    return True
 
 
 # -- compilations ------------------------------------------------------------- #

@@ -53,9 +53,6 @@ _META = {
                              "Wall time of iterate spans (fenced)"),
     "tclb_mlups": ("gauge",
                    "MLUPS of the last iterate span, by engine/model"),
-    "tclb_vs_roofline": ("gauge",
-                         "Fraction of the HBM roofline achieved by the "
-                         "last iterate span"),
     "tclb_iterations_total": ("counter", "Lattice iterations completed"),
     "tclb_node_updates_total": ("counter", "Lattice node updates completed"),
     "tclb_batch_seconds": ("histogram",
@@ -341,9 +338,6 @@ def _observe(doc: dict) -> None:
             if doc.get("mlups") is not None:
                 reg.gauge("tclb_mlups", doc["mlups"], engine=engine,
                           model=model, **wlbl)
-            if doc.get("vs_roofline") is not None:
-                reg.gauge("tclb_vs_roofline", doc["vs_roofline"],
-                          engine=engine)
             iters = doc.get("iters")
             if iters:
                 reg.count("tclb_iterations_total", iters)
@@ -354,7 +348,6 @@ def _observe(doc: dict) -> None:
             last = {
                 "engine": engine, "model": model,
                 "mlups": doc.get("mlups"),
-                "vs_roofline": doc.get("vs_roofline"),
                 "iteration": doc.get("iteration"),
                 "dur_s": dur, "ts": doc.get("ts"),
             }

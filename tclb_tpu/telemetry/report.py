@@ -5,9 +5,12 @@ BENCH/ROADMAP triage loop needs:
 
 * **per-engine iterate summary** — for every engine the dispatch ran
   (``iterate`` spans grouped by their ``engine`` field): chunks, total
-  iterations, wall time, aggregate MLUPS (total node-updates / total
-  time) and the traffic-model roofline fraction;
+  iterations, wall time and aggregate MLUPS (total node-updates / total
+  time);
 * **per-span table** — every span name with count/total/mean/max;
+* **segments** — the ``segment`` spans of ``<Solve>``'s loop by the
+  handlers that ran in them: the span, what its fences waited, the
+  host's own time and where it went by span name;
 * **dispatch history** — ``engine_selected`` decisions and the
   ``engine_fallback`` chain with each fallback's exception cause (the
   information the old free-form log strings swallowed);
@@ -23,6 +26,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import statistics
 import sys
 from typing import Optional
 
@@ -283,6 +287,62 @@ def _slo_summary(evts: list[dict]) -> dict:
     return out
 
 
+def _segments_summary(evts: list[dict]) -> dict:
+    """The ``segment`` spans (one pass of ``<Solve>``'s loop each, the
+    root of everything the pass did), grouped by the handlers that ran
+    in them.  Per group the medians, in milliseconds: ``span_ms``;
+    ``wait_ms``, what the fences of the span and all its descendants
+    blocked (their ``wait_s``); ``host_ms``, the span less that: the
+    host's own time, in which a fenced device had nothing to run;
+    ``pre_sync_ms`` and ``dispatch_ms``, those fields summed over the
+    segment (the device read before ``iterate``, the launches); and
+    ``self_ms`` by span name, ``dur_s`` less children less ``wait_s``."""
+    # span ids restart with every process: a file that sessions were
+    # appended to holds each id once a session
+    session, spans = 0, []
+    for e in evts:
+        if e.get("kind") == "trace_start":
+            session += 1
+        elif e.get("kind") == "span" and "id" in e:
+            spans.append((session, e))
+    kids: dict = {}
+    for session, e in spans:
+        kids.setdefault((session, e["parent"]), []).append(e)
+    groups: dict = {}
+    for session, seg in spans:
+        if seg.get("name") != "segment":
+            continue
+        row = {"span_ms": 1e3 * seg["dur_s"], "wait_ms": 0.0,
+               "pre_sync_ms": 0.0, "dispatch_ms": 0.0}
+        own: dict = {}
+        todo = [seg]
+        while todo:
+            e = todo.pop()
+            mine = kids.get((session, e["id"]), [])
+            todo += mine
+            wait = e.get("wait_s", 0.0)
+            row["wait_ms"] += 1e3 * wait
+            row["pre_sync_ms"] += 1e3 * e.get("pre_sync_s", 0.0)
+            row["dispatch_ms"] += 1e3 * e.get("dispatch_s", 0.0)
+            own[e["name"]] = own.get(e["name"], 0.0) + 1e3 * (
+                e["dur_s"] - sum(k["dur_s"] for k in mine) - wait)
+        row["host_ms"] = row["span_ms"] - row["wait_ms"]
+        shape = "+".join(k.get("handler", "?")
+                         for k in kids.get((session, seg["id"]), [])
+                         if k["name"] == "handler") or "none"
+        groups.setdefault(shape, []).append((row, own))
+    out = {}
+    for shape, rows in groups.items():
+        g = {k: round(statistics.median(r[k] for r, _ in rows), 4)
+             for k in rows[0][0]}
+        names = sorted({n for _, own in rows for n in own})
+        g["self_ms"] = {n: round(statistics.median(
+            own.get(n, 0.0) for _, own in rows), 4) for n in names}
+        g["count"] = len(rows)
+        out[shape] = g
+    return out
+
+
 def summarize(evts: list[dict]) -> dict:
     """Aggregate one trace into the report structure (all plain dicts,
     JSON-serializable as-is)."""
@@ -318,8 +378,7 @@ def summarize(evts: list[dict]) -> dict:
                 eng = e.get("engine", "?")
                 g = engines.setdefault(eng, {
                     "chunks": 0, "iters": 0, "node_updates": 0.0,
-                    "total_s": 0.0, "vs_roofline": None,
-                    "roofline_known": e.get("roofline_known"),
+                    "total_s": 0.0,
                     "storage_dtype": e.get("storage_dtype"),
                     "storage_repr": e.get("storage_repr")})
                 if e.get("storage_dtype") is not None:
@@ -356,26 +415,8 @@ def summarize(evts: list[dict]) -> dict:
             g["mlups"] = None
         g["total_s"] = round(g["total_s"], 6)
         del g["node_updates"]
-    # stamp each engine's roofline fraction from its own iterate spans
-    # (weighted by node-updates so short chunks don't skew it)
-    w: dict[str, list] = {}
-    for e in evts:
-        if e.get("kind") == "span" and e.get("name") == "iterate" \
-                and e.get("vs_roofline") is not None:
-            nu = float(e.get("nodes", 0.0)) * float(e.get("iters", 0))
-            w.setdefault(e.get("engine", "?"), []).append(
-                (nu, float(e["vs_roofline"])))
-        if e.get("kind") == "span" and e.get("name") == "iterate" \
-                and e.get("roofline_known") is not None:
-            eng = e.get("engine", "?")
-            if eng in engines:
-                engines[eng]["roofline_known"] = e["roofline_known"]
-    for eng, rows in w.items():
-        tot = sum(nu for nu, _ in rows)
-        if tot > 0 and eng in engines:
-            engines[eng]["vs_roofline"] = round(
-                sum(nu * r for nu, r in rows) / tot, 4)
     return {"engines": engines, "spans": spans,
+            "segments": _segments_summary(evts),
             "serving": _serving_summary(evts),
             "adjoint": _adjoint_summary(evts),
             "fleet": _fleet_summary(evts),
@@ -404,9 +445,7 @@ def compare(base: dict, other: dict, threshold: float = 0.05) -> dict:
         a = base["engines"].get(eng)
         b = other["engines"].get(eng)
         row: dict = {"base_mlups": a and a.get("mlups"),
-                     "other_mlups": b and b.get("mlups"),
-                     "base_vs_roofline": a and a.get("vs_roofline"),
-                     "other_vs_roofline": b and b.get("vs_roofline")}
+                     "other_mlups": b and b.get("mlups")}
         if a and b and (a.get("storage_repr") or "raw") \
                 != (b.get("storage_repr") or "raw"):
             # a storage-representation switch is a different compiled
@@ -698,8 +737,7 @@ def format_text(summary: dict) -> str:
     if summary["engines"]:
         lines.append("per-engine iterate summary")
         lines.append(f"  {'engine':<44} {'storage':>17} {'chunks':>6} "
-                     f"{'iters':>9} {'time_s':>10} {'MLUPS':>10} "
-                     f"{'vs_roofline':>12}")
+                     f"{'iters':>9} {'time_s':>10} {'MLUPS':>10}")
         for eng, g in sorted(summary["engines"].items()):
             sdt = g.get("storage_dtype")
             # dtype/repr: the at-rest layout in one cell (repr only
@@ -710,8 +748,7 @@ def format_text(summary: dict) -> str:
             lines.append(
                 f"  {eng:<44} {storage:>17} "
                 f"{g['chunks']:>6} {g['iters']:>9} "
-                f"{_fmt(g['total_s'], 3):>10} {_fmt(g['mlups'], 1):>10} "
-                f"{_fmt(g['vs_roofline'], 4):>12}")
+                f"{_fmt(g['total_s'], 3):>10} {_fmt(g['mlups'], 1):>10}")
         lines.append("")
     if summary["spans"]:
         lines.append("spans")
@@ -723,6 +760,21 @@ def format_text(summary: dict) -> str:
                          f"{_fmt(s['total_s'], 4):>10} "
                          f"{_fmt(s['mean_s'], 4):>10} "
                          f"{_fmt(s['max_s'], 4):>10}")
+        lines.append("")
+    if summary.get("segments"):
+        cols = ("span_ms", "wait_ms", "host_ms", "pre_sync_ms",
+                "dispatch_ms")
+        lines.append("segments (medians; host = span - wait; then self "
+                     "time by span name, ms)")
+        lines.append(f"  {'handlers':<40} {'count':>6} "
+                     + " ".join(f"{c:>11}" for c in cols))
+        for shape, g in sorted(summary["segments"].items(),
+                               key=lambda kv: -kv[1]["count"]):
+            lines.append(f"  {shape:<40} {g['count']:>6} "
+                         + " ".join(f"{_fmt(g[c], 3):>11}" for c in cols))
+            lines.append("      " + "  ".join(
+                f"{n} {_fmt(v, 3)}" for n, v in sorted(
+                    g["self_ms"].items(), key=lambda kv: -kv[1])))
         lines.append("")
     if summary.get("serving"):
         sv = summary["serving"]

@@ -1,20 +1,18 @@
-"""Timing spans with honest walls and model-derived roofline metrics.
+"""Timing spans with honest walls.
 
 A span measures host wall-time around a region.  JAX dispatch is
 asynchronous, so a naive ``perf_counter`` pair times the *enqueue*, not
 the work — callers fence with :meth:`Span.sync` (``jax.block_until_ready``
-on the region's output) before the span closes.
+on the region's output) before the span closes.  A fence says what it
+waited: the seconds a span's own fences blocked add up in its ``wait_s``
+(absent on a span that never fenced), and :meth:`Span.mark` records the
+seconds from the span's start to a point inside it (``dispatch_s``: a
+jitted call has returned, the fence not yet begun).  A span's *host
+time* is ``dur_s`` less its children less ``wait_s``.
 
 When the region carries enough context (``nodes``/``iters`` fields), the
-span exit stamps derived metrics the way the reference prints its own
-MLUPS line (reference src/main.cpp.Rt:100-126):
-
-* ``mlups``      — ``nodes * iters / dt / 1e6``;
-* ``vs_roofline`` — achieved fraction of this chip's HBM streaming
-  roofline under the classical LBM traffic model (``bytes_per_node`` =
-  2 x n_storage x sizeof(real) + flag read per node update), with the
-  bandwidth from :data:`HBM_GBS`; absent on a device kind whose
-  bandwidth is not in that table.
+span exit stamps ``mlups`` (``nodes * iters / dt / 1e6``) the way the
+reference prints its own MLUPS line (reference src/main.cpp.Rt:100-126).
 
 Spans also wrap ``jax.profiler.TraceAnnotation`` when available, so a
 concurrent ``jax.profiler`` capture shows the same region names.
@@ -38,43 +36,10 @@ from typing import Any, Optional
 
 from tclb_tpu.telemetry import events
 
-# known per-chip HBM bandwidths (GB/s); a kind that is not listed has
-# no roofline (roofline_known=False, no vs_roofline) — never an assumed
-# bandwidth
-HBM_GBS = {"TPU v5 lite": 819.0, "TPU v5e": 819.0,
-           "TPU v5p": 2765.0, "TPU v4": 1228.0,
-           "TPU v6 lite": 1640.0, "TPU v6e": 1640.0}
-
-_device_kind_cache: Optional[tuple] = None
 _ids = itertools.count(1)           # span ids, process-wide
 
 #: identifiers a child span takes from its parent unless given its own
 INHERITED = ("iteration", "job_id")
-
-
-def device_kind() -> str:
-    """The first device's kind (cached; '' if jax has no devices)."""
-    global _device_kind_cache
-    if _device_kind_cache is None:
-        try:
-            import jax
-            _device_kind_cache = (jax.devices()[0].device_kind,)
-        except Exception:  # noqa: BLE001
-            _device_kind_cache = ("",)
-    return _device_kind_cache[0]
-
-
-def roofline_mlups(bytes_per_node: float,
-                   kind: Optional[str] = None) -> Optional[float]:
-    """MLUPS ceiling of the 1R+1W streaming traffic model on ``kind``
-    (default: this process's first device); None when the kind's HBM
-    bandwidth is not in :data:`HBM_GBS`."""
-    if kind is None:
-        kind = device_kind()
-    hbm = HBM_GBS.get(kind)
-    if hbm is None:
-        return None
-    return hbm * 1e9 / float(bytes_per_node) / 1e6
 
 
 def fuse_of(engine: Optional[str]) -> int:
@@ -82,8 +47,7 @@ def fuse_of(engine: Optional[str]) -> int:
     ``,fuse=K`` tag every fused engine carries, e.g.
     ``pallas_d3q[d3q19,fuse=3]``); 1 when absent (XLA, unfused
     engines).  The ``iterate`` span records the depth it reads from the
-    tag through this, so the tag format lives next to the roofline
-    table."""
+    tag through this."""
     if not engine:
         return 1
     m = re.search(r"[\[,]fuse=(-?\d+)", engine)
@@ -97,13 +61,14 @@ class Span:
     returns the shared no-op otherwise), so it may import jax freely."""
 
     __slots__ = ("name", "fields", "id", "parent", "t0", "_t0",
-                 "_annotation")
+                 "_wait", "_annotation")
 
     def __init__(self, name: str, fields: dict):
         self.name = name
         self.fields = fields
         self.id = self.parent = None
         self.t0 = self._t0 = 0.0
+        self._wait: Optional[float] = None      # seconds its fences blocked
         self._annotation = None
 
     def add(self, **fields: Any) -> None:
@@ -112,9 +77,17 @@ class Span:
 
     def sync(self, x: Any) -> Any:
         """Fence: block until ``x`` (any pytree of jax arrays) is computed
-        so the span's wall-time covers the work, not the enqueue."""
+        so the span's wall-time covers the work, not the enqueue.  The
+        seconds it blocked go into the span's ``wait_s``."""
         import jax
-        return jax.block_until_ready(x)
+        t = time.perf_counter()
+        x = jax.block_until_ready(x)
+        self._wait = (self._wait or 0.0) + time.perf_counter() - t
+        return x
+
+    def mark(self, field: str) -> None:
+        """Record the seconds since the span opened under ``field``."""
+        self.fields[field] = round(time.perf_counter() - self._t0, 6)
 
     def __enter__(self) -> "Span":
         stack = events.span_stack()
@@ -156,14 +129,8 @@ class Span:
             # far below 1 MLUPS and must not round to zero
             mlups = float(nodes) * float(iters) / dt / 1e6
             fields["mlups"] = float(f"{mlups:.6g}")
-            bpn = fields.get("bytes_per_node")
-            if bpn:
-                ceiling = roofline_mlups(bpn)
-                if ceiling is not None:
-                    fields["vs_roofline"] = round(
-                        fields["mlups"] / ceiling, 4)
-                fields["roofline_known"] = ceiling is not None
-                fields["device_kind"] = device_kind()
+        if self._wait is not None:
+            fields["wait_s"] = round(self._wait, 6)
         events.event("span", name=self.name, id=self.id, parent=self.parent,
                      t0=round(self.t0, 6), dur_s=round(dt, 6), **fields)
         return False
@@ -179,6 +146,9 @@ class _NoopSpan:
 
     def sync(self, x: Any) -> Any:
         return x
+
+    def mark(self, field: str) -> None:
+        pass
 
     def __enter__(self) -> "_NoopSpan":
         return self

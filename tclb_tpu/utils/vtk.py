@@ -160,14 +160,16 @@ class CSVLog:
         self._header: list[str] | None = None
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
 
-    def write(self, row: dict[str, float]) -> None:
+    def write(self, row: dict[str, float]) -> int:
+        """Append ``row``; the characters written (ASCII: the bytes)."""
+        n = 0
         if self._header is None:
             self._header = list(row.keys())
             with open(self.path, "w") as f:
-                f.write(",".join(f'"{h}"' for h in self._header) + "\n")
+                n = f.write(",".join(f'"{h}"' for h in self._header) + "\n")
         with open(self.path, "a") as f:
-            f.write(",".join(repr(float(row.get(h, 0.0)))
-                             for h in self._header) + "\n")
+            return n + f.write(",".join(repr(float(row.get(h, 0.0)))
+                                        for h in self._header) + "\n")
 
 
 def csvdiff(a: str, b: str, tol: float = 1e-10,

@@ -136,7 +136,7 @@ def test_observe_derives_metrics_from_events():
     reg = live.registry()
     live._observe({"kind": "span", "name": "iterate", "dur_s": 0.25,
                    "engine": "fused", "model": "d2q9", "mlups": 88.0,
-                   "vs_roofline": 0.8, "iters": 10, "nodes": 1000,
+                   "iters": 10, "nodes": 1000,
                    "iteration": 50, "ts": 123.0})
     live._observe({"kind": "span", "name": "serve.lane_batch", "lane": 2,
                    "batch": 3, "dur_s": 0.5, "stage_s": 0.1,

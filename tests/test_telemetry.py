@@ -16,10 +16,12 @@ import numpy as np
 import pytest
 
 from tclb_tpu import telemetry
+from tclb_tpu.core import lattice as lattice_mod
 from tclb_tpu.core.lattice import Lattice
 from tclb_tpu.models import get_model
 from tclb_tpu.ops import pallas_d2q9
 from tclb_tpu.telemetry import report
+from tclb_tpu.telemetry import spans as spans_mod
 from tclb_tpu.telemetry.spans import NOOP_SPAN
 from tclb_tpu.utils import log
 
@@ -30,6 +32,16 @@ def _sink_off():
     telemetry.disable()
     yield
     telemetry.disable()
+
+
+class _Untouchable:
+    """Stands in for a module a disabled site must not use."""
+
+    def __init__(self, what):
+        self._what = what
+
+    def __getattr__(self, name):
+        raise AssertionError(f"telemetry is off: {self._what}.{name}")
 
 
 def _mrt_lattice(ny=8, nx=16):
@@ -76,8 +88,10 @@ def test_disabled_is_strict_noop(monkeypatch):
         raise AssertionError("disabled span must never touch jax")
 
     monkeypatch.setattr(jax, "block_until_ready", boom)
+    monkeypatch.setattr(spans_mod, "time", _Untouchable("the clock"))
     with sp:
         sp.add(engine="xla")
+        sp.mark("dispatch_s")
         assert sp.sync(sentinel) is sentinel
 
 
@@ -90,6 +104,8 @@ def test_disabled_lattice_iterate_never_syncs(monkeypatch):
         raise AssertionError("disabled iterate must not fence")
 
     monkeypatch.setattr(jax, "block_until_ready", boom)
+    # nor read the clock (pre_sync_s), nor the device (its iteration)
+    monkeypatch.setattr(lattice_mod, "time", _Untouchable("the clock"))
     lat.iterate(2)                             # telemetry disabled
     monkeypatch.setattr(jax, "block_until_ready", real)
     assert int(lat.state.iteration) == 2
@@ -162,12 +178,12 @@ def test_lattice_iterate_emits_engine_and_span(tmp_path, monkeypatch):
         assert e["engine"] == "xla"
         assert e["nodes"] == 8 * 16
         assert e["mlups"] > 0
-        # classical traffic model: 1R+1W of every storage field + flag
-        assert e["bytes_per_node"] == 2 * m.n_storage * 4 + 2
-        # CPU device kind is not in the HBM table: no roofline at all
-        # (never an assumed bandwidth)
-        assert e["roofline_known"] is False
-        assert "vs_roofline" not in e
+        # the device read of the iteration before the span opened, and
+        # the span's own fence
+        assert 0 <= e["pre_sync_s"] and 0 <= e["wait_s"] <= e["dur_s"]
+        # no roofline from a host-fenced wall over a fixed byte count
+        assert not {"bytes_per_node", "vs_roofline", "roofline_known",
+                    "device_kind"} & set(e)
 
 
 def _failing_engine(*args, **kw):
@@ -399,8 +415,7 @@ _ENG = "pallas_2d[d2q9,fuse=2]"
 def _iterate_span(dur_s, nodes=8192.0, iters=100, engine=_ENG):
     return {"kind": "span", "ts": 1.0, "name": "iterate", "dur_s": dur_s,
             "iters": iters, "nodes": nodes, "engine": engine,
-            "mlups": round(nodes * iters / dur_s / 1e6, 3),
-            "vs_roofline": 0.5, "roofline_known": True}
+            "mlups": round(nodes * iters / dur_s / 1e6, 3)}
 
 
 def _write_trace(path, events):
@@ -424,7 +439,7 @@ def test_summarize_engine_table(tmp_path):
     g = s["engines"][_ENG]
     assert g["chunks"] == 2 and g["iters"] == 200
     assert g["mlups"] == pytest.approx(8192 * 200 / 0.02 / 1e6, rel=1e-3)
-    assert g["vs_roofline"] == pytest.approx(0.5)
+    assert "vs_roofline" not in g
     assert s["spans"]["output.vtk"]["count"] == 1
     assert s["counters"] == {"halo.exchanges": 12}
     txt = report.format_text(s)
@@ -685,6 +700,203 @@ def test_solve_emits_phase_spans_with_the_right_parents(
         assert own >= -1e-3
 
 
+_SOLVE_LOG_XML = _SOLVE_XML.replace("<Failcheck", '<Log Iterations="3"/>'
+                                    "<Failcheck", 1)
+
+#: the spans of a <Solve> that fence (Span.sync), and so say wait_s
+_FENCED = {"iterate", "iterate.fused", "engine.probe",
+           "iterate.globals_step", "quantity.eval"}
+
+
+class _SteppingClock:
+    """``time`` for ``control/solver.py``: every reading is ``step``
+    seconds after the one before."""
+
+    def __init__(self, step):
+        self.step, self.now = step, 1000.0
+
+    def time(self):
+        self.now += self.step
+        return self.now
+
+
+@pytest.fixture(scope="module")
+def solved(tmp_path_factory):
+    """One tiny <Solve> of two segments with <Log>, <Failcheck> and a
+    <VTK>, on a Pallas engine in interpret mode: its events, its solver
+    and its output directory."""
+    from tclb_tpu.control import run_config_string
+    out = tmp_path_factory.mktemp("solved")
+    docs = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TCLB_FASTPATH", "force")
+        telemetry.subscribe(docs.append)
+        try:
+            solver = run_config_string(_SOLVE_LOG_XML.format(out=out),
+                                       get_model("d2q9"))
+        finally:
+            telemetry.unsubscribe(docs.append)
+    return docs, solver, out
+
+
+def test_segment_is_the_root_of_its_pass(solved):
+    docs = solved[0]
+    spans = _spans(docs)
+    segments = _spans(docs, "segment")
+    assert [(e["iteration"], e["steps"], e["parent"]) for e in segments] \
+        == [(3, 3, None), (6, 3, None)]
+    # iterate says where it started, the segment and its handlers where
+    # the pass ends
+    assert [e["iteration"] for e in _spans(docs, "iterate")] == [0, 3]
+    for seg, start in zip(segments, (0, 3)):
+        kids = [e for e in spans if e["parent"] == seg["id"]]
+        names = [e["name"] for e in kids if e["name"] != "progress"]
+        assert names[0] == "iterate" and set(names[1:]) == {"handler"}
+        assert kids[0]["iteration"] == start
+        assert all(e["iteration"] == seg["iteration"] for e in kids[1:])
+        assert seg["t0"] <= kids[0]["t0"] and kids[-1]["ts"] <= seg["ts"]
+    assert [[e["handler"] for e in spans if e["name"] == "handler"
+             and e["parent"] == seg["id"]] for seg in segments] == [
+        ["cbLog", "cbFailcheck"], ["cbLog", "cbFailcheck", "cbVTK"]]
+    # every span of the solve hangs from a segment: none is a second root
+    assert all(e["parent"] is not None for e in spans
+               if e["name"] != "segment")
+
+
+def test_a_fence_says_what_it_waited_and_a_launch_what_it_cost(solved):
+    spans = _spans(solved[0])
+    assert {e["name"] for e in spans} >= _FENCED | {"segment", "handler"}
+    for e in spans:
+        # wait_s exactly on the spans that fenced
+        assert ("wait_s" in e) == (e["name"] in _FENCED), e["name"]
+        wait = e.get("wait_s", 0.0)
+        assert 0 <= wait <= e["dur_s"] + 2e-6
+        # host time: what neither a child nor a fence covers
+        own = e["dur_s"] - wait - sum(k["dur_s"] for k in spans
+                                      if k["parent"] == e["id"])
+        assert own >= -1e-3, e["name"]
+    assert all(e["pre_sync_s"] >= 0 for e in spans if e["name"] == "iterate")
+    probed, fused = [e for e in spans if e["name"] == "iterate.fused"]
+    # the probed first call (a copy of the state, the compile, the
+    # ladder) is no launch
+    assert "dispatch_s" not in probed
+    assert [e["name"] for e in spans if e["parent"] == probed["id"]] \
+        == ["engine.probe"]
+    launches = [fused] + [e for e in spans
+                          if e["name"] == "iterate.globals_step"]
+    assert len(launches) == 3
+    for e in launches:
+        assert 0 < e["dispatch_s"] <= e["dur_s"] - e["wait_s"] + 2e-6
+
+
+def test_output_log_has_its_fetch_and_its_write(solved):
+    docs, solver, out = solved
+    spans = _spans(docs)
+    logs = _spans(docs, "output.log")
+    assert [e["iteration"] for e in logs] == [3, 6]
+    lat = solver.lattice
+    three = (np.asarray(lat.params.settings).nbytes
+             + np.asarray(lat.params.zone_table).nbytes
+             + np.asarray(lat.state.globals_).nbytes)
+    written = 0
+    for log_span in logs:
+        fetch, write = [e for e in spans if e["parent"] == log_span["id"]]
+        assert (fetch["name"], write["name"]) == ("output.log.fetch",
+                                                  "output.log.write")
+        assert (fetch["copies"], fetch["bytes"]) == (3, three)
+        assert fetch["iteration"] == write["iteration"] \
+            == log_span["iteration"]
+        written += write["bytes"]
+    # the first write holds the header too
+    csv, = [p for p in out.iterdir() if p.name.endswith("_Log.csv")]
+    assert written == csv.stat().st_size
+
+
+def test_report_prints_a_segments_host_time(solved):
+    summary = report.summarize(solved[0])
+    seg = summary["segments"]
+    assert set(seg) == {"cbLog+cbFailcheck", "cbLog+cbFailcheck+cbVTK"}
+    for g in seg.values():
+        assert g["count"] == 1
+        assert g["host_ms"] == pytest.approx(g["span_ms"] - g["wait_ms"],
+                                             abs=1e-3)
+        assert 0 < g["wait_ms"] < g["span_ms"] and g["pre_sync_ms"] >= 0
+        assert {"segment", "iterate", "handler", "output.log.fetch",
+                "output.log.write"} <= set(g["self_ms"])
+        # the self times and the waits add up to the span
+        assert sum(g["self_ms"].values()) + g["wait_ms"] \
+            == pytest.approx(g["span_ms"], abs=0.05)
+    # only the segment without the probe has both launches to count
+    assert seg["cbLog+cbFailcheck+cbVTK"]["dispatch_ms"] > 0
+    text = report.format_text(summary)
+    assert "segments (medians" in text and "cbLog+cbFailcheck+cbVTK" in text
+    # a session appended to the same file uses the ids again: kept apart
+    twice = report.summarize(
+        solved[0] + [{"kind": "trace_start", "ts": 0.0}] + solved[0])
+    assert {k: (g["count"], g["self_ms"])
+            for k, g in twice["segments"].items()} \
+        == {k: (2, g["self_ms"]) for k, g in seg.items()}
+
+
+_LOG_ONLY_XML = """<CLBConfig output="{out}/">
+<Geometry nx="32" ny="16"><MRT><Box/></MRT></Geometry>
+<Model><Params nu="0.05"/></Model>
+<Log Iterations="2"/><Solve Iterations="6"/></CLBConfig>"""
+
+
+@pytest.mark.parametrize("step,reports", [(0.0, []), (10.0, [4, 6])])
+def test_progress_span_only_on_a_reporting_call(
+        seen, tmp_path, monkeypatch, step, reports):
+    """`Solver.progress` reports about once a second: with a clock that
+    stands still never, with one that leaps on every pass after the
+    first, and only then is there a span."""
+    from tclb_tpu.control import run_config_string, solver as solver_mod
+    monkeypatch.delenv("TCLB_FASTPATH", raising=False)
+    monkeypatch.setattr(solver_mod, "time", _SteppingClock(step))
+    run_config_string(_LOG_ONLY_XML.format(out=tmp_path), get_model("d2q9"))
+    segments = {e["id"]: e for e in _spans(seen, "segment")}
+    assert [e["iteration"] for e in segments.values()] == [2, 4, 6]
+    progress = _spans(seen, "progress")
+    assert [e["iteration"] for e in progress] == reports
+    assert all(segments[e["parent"]]["iteration"] == e["iteration"]
+               and "wait_s" not in e for e in progress)
+    notes = [e for e in seen if e["kind"] == "progress"]
+    assert [e["parent"] for e in notes] == [e["id"] for e in progress]
+
+
+def test_jsonl_sink_is_flushed_by_the_outermost_span(tmp_path, monkeypatch):
+    """Block-buffered: the spans inside a segment cost no write each; an
+    event outside any span, the outermost span's own and a counters
+    snapshot reach the file at once, whole lines only."""
+    from tclb_tpu.telemetry import events
+    trace = tmp_path / "t.jsonl"
+    telemetry.enable(str(trace))
+
+    def on_disk():
+        text = trace.read_text()
+        assert text.endswith("\n")
+        return [e.get("name", e["kind"])
+                for e in map(json.loads, text.splitlines())]
+
+    assert on_disk() == ["trace_start"]
+    with telemetry.span("segment"):
+        with telemetry.span("iterate"):
+            telemetry.event("note")
+        with telemetry.span("handler"):
+            pass
+        assert on_disk() == ["trace_start"]
+    whole = ["trace_start", "note", "iterate", "handler", "segment"]
+    assert on_disk() == whole
+    monkeypatch.setattr(events, "COUNTER_SNAPSHOT_S", 0.0)
+    with telemetry.span("segment"):
+        telemetry.counter("halo.exchanges")
+        telemetry.event("note")             # a snapshot rides on it
+        assert on_disk() == whole + ["counters", "note"]
+    telemetry.disable()     # the segment's event took a snapshot along too
+    assert on_disk() == whole + ["counters", "note", "counters", "segment",
+                                 "counters"]
+
+
 @pytest.mark.parametrize("native_on", [True, False])
 def test_vtk_encode_span_says_how_it_was_encoded(
         seen, tmp_path, monkeypatch, native_on):
@@ -770,7 +982,7 @@ def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch):
 
 def test_disabled_solve_never_syncs_and_listener_is_silent(
         tmp_path, monkeypatch):
-    from tclb_tpu.control import run_config_string
+    from tclb_tpu.control import run_config_string, solver as solver_mod
     from tclb_tpu.telemetry import events
     docs = []
     telemetry.subscribe(docs.append)        # registers the listener ...
@@ -781,10 +993,18 @@ def test_disabled_solve_never_syncs_and_listener_is_silent(
     def boom(*a, **k):
         raise AssertionError("telemetry is off")
 
-    monkeypatch.setattr(telemetry.Span, "sync", boom)
+    # no span is made, fences, or marks a launch; nothing reads the
+    # clock for pre_sync_s; the segment, progress (a clock that makes
+    # every call after the first report) and the Log's two children
+    # included
+    for method in ("__init__", "sync", "mark"):
+        monkeypatch.setattr(telemetry.Span, method, boom)
     monkeypatch.setattr(events, "_fanout_locked", boom)  # nothing emits
+    monkeypatch.setattr(lattice_mod, "time", _Untouchable("the clock"))
+    monkeypatch.setattr(solver_mod, "time", _SteppingClock(10.0))
     monkeypatch.setenv("TCLB_FASTPATH", "force")
-    s = run_config_string(_SOLVE_XML.format(out=tmp_path),
+    s = run_config_string(_SOLVE_LOG_XML.format(out=tmp_path),
                           get_model("d2q9"))
     assert s.iter == 6 and docs == []
+    assert len((tmp_path / "run_Log.csv").read_text().splitlines()) == 3
     assert telemetry.counters() == {}

@@ -20,7 +20,7 @@ from typing import Callable, Iterator, Optional
 
 import numpy as np
 
-from tclb_tpu import faults
+from tclb_tpu import faults, telemetry
 
 
 # -- path normalization ------------------------------------------------------- #
@@ -245,16 +245,21 @@ def commit_dir(tmp_dir: str, final_dir: str) -> None:
 
 
 class AsyncWriter:
-    """At most one background save in flight.
+    """At most one background job in flight, on a thread called ``name``.
 
     ``submit`` first drains any previous job (so two saves can never
-    interleave in one checkpoint root), then runs ``fn`` on a daemon
-    thread.  Errors are captured and re-raised on the *next* ``wait()``
-    — a failed background save must not kill the solve loop, but it must
-    not stay silent either.
+    interleave in one checkpoint root, and at most one write's arrays
+    are held on the host), then runs ``fn`` on a daemon thread.  Errors
+    are captured and re-raised on the *next* ``wait()`` — a failed
+    background write must not kill the solve loop, but it must not stay
+    silent either.  The checkpoint manager owns one for its saves, the
+    ``Solver`` one for ``<VTK>`` output (``Solver.write_vtk``).  The
+    thread launches nothing on the device: its spans are kept apart from
+    the launching thread's in a profile (``telemetry.off_launch_thread``).
     """
 
-    def __init__(self) -> None:
+    def __init__(self, name: str = "tclb-checkpoint-writer") -> None:
+        self.name = name
         self._thread: Optional[threading.Thread] = None
         self._error: Optional[BaseException] = None
 
@@ -262,6 +267,7 @@ class AsyncWriter:
         self.wait()
 
         def run() -> None:
+            telemetry.off_launch_thread(self.name)
             try:
                 fn()
             except BaseException as e:  # noqa: BLE001 — surfaced on wait()
@@ -271,7 +277,7 @@ class AsyncWriter:
                 self._error = e
 
         self._thread = threading.Thread(target=run, daemon=True,
-                                        name="tclb-checkpoint-writer")
+                                        name=self.name)
         self._thread.start()
 
     def busy(self) -> bool:

@@ -175,41 +175,45 @@ class acSolve(GenericAction):
         # absolute iteration instead of restarting its count
         s.solve_stack.append(self)
         try:
-            while True:
-                # one pass of the loop is a segment: its span is the root
-                # of everything the pass does (scheduling, iterate,
-                # progress, the due handlers), so what none of the
-                # children covers is the loop's own time
-                with telemetry.span("segment") as seg:
-                    next_it = self.next_it(s.iter)
-                    for h in s.hands:
-                        it = h.next_it(s.iter)
-                        if 0 < it < next_it:
-                            next_it = it
-                    steps = next_it
-                    s.iter += steps
-                    # the iteration the segment ends at, as its handlers
-                    # carry; iterate gives the one it starts from
-                    seg.add(iteration=s.iter, steps=steps)
-                    s.update_synthetic_turbulence(steps)
-                    s.lattice.iterate(steps)
-                    s.progress(steps)
-                    for h in s.hands:
-                        if h.now(s.iter):
-                            # each periodic callback runs under its own
-                            # span, so a trace attributes Solve wall-time
-                            # between lattice iteration and
-                            # VTK/Log/Failcheck/... output work
-                            with telemetry.span("handler",
-                                                handler=type(h).__name__,
-                                                iteration=s.iter):
-                                r = h.do_it()
-                            if r == ITERATION_STOP:
-                                stop = True
-                            elif r not in (0, None):
-                                return r
-                    if stop or self.now(s.iter):
-                        break
+            # however the loop is left (the count reached, a <Stop>, a
+            # Failcheck hit with its rescue children, an exception), what
+            # follows <Solve> in the case finds the files whole
+            with s.output_drained("solve_end"):
+                while True:
+                    # one pass of the loop is a segment: its span is the root
+                    # of everything the pass does (scheduling, iterate,
+                    # progress, the due handlers), so what none of the
+                    # children covers is the loop's own time
+                    with telemetry.span("segment") as seg:
+                        next_it = self.next_it(s.iter)
+                        for h in s.hands:
+                            it = h.next_it(s.iter)
+                            if 0 < it < next_it:
+                                next_it = it
+                        steps = next_it
+                        s.iter += steps
+                        # the iteration the segment ends at, as its handlers
+                        # carry; iterate gives the one it starts from
+                        seg.add(iteration=s.iter, steps=steps)
+                        s.update_synthetic_turbulence(steps)
+                        s.lattice.iterate(steps)
+                        s.progress(steps)
+                        for h in s.hands:
+                            if h.now(s.iter):
+                                # each periodic callback runs under its own
+                                # span, so a trace attributes Solve wall-time
+                                # between lattice iteration and
+                                # VTK/Log/Failcheck/... output work
+                                with telemetry.span("handler",
+                                                    handler=type(h).__name__,
+                                                    iteration=s.iter):
+                                    r = h.do_it()
+                                if r == ITERATION_STOP:
+                                    stop = True
+                                elif r not in (0, None):
+                                    return r
+                        if stop or self.now(s.iter):
+                            break
         finally:
             s.solve_stack.pop()
         self.unstack()

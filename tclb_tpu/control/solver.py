@@ -14,10 +14,11 @@ arrays), so output and geometry code is rank-free by construction.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 import xml.etree.ElementTree as ET
-from typing import Any, Optional, Sequence
+from typing import Any, Iterator, Optional, Sequence
 
 import jax
 import numpy as np
@@ -30,6 +31,17 @@ from tclb_tpu.utils.units import UnitEnv
 from tclb_tpu.utils.vtk import CSVLog
 
 ITERATION_STOP = 1
+
+
+class OutputError(RuntimeError):
+    """A background output write failed; the message names the file."""
+
+
+def _to_host(name: str, q: jax.Array) -> np.ndarray:
+    """A device array on the host, waited for: the copy, or on a mesh
+    the gather (``quantity.d2h``)."""
+    with telemetry.span("quantity.d2h", quantity=name, bytes=q.nbytes):
+        return np.asarray(q)
 
 
 class Solver:
@@ -63,6 +75,10 @@ class Solver:
         self.solve_stack: list = []    # acSolve handlers currently running
         self._pending_restore: dict = {}   # ck_key -> restored handler state
         self._ck_counts: dict = {}     # class name -> instances seen so far
+        # <VTK> output: one background writer, made at the first write,
+        # and the piece it is writing (None: nothing in flight)
+        self._output = None
+        self._output_file: Optional[str] = None
 
     def next_ck_key(self, cls_name: str) -> str:
         """Deterministic per-handler checkpoint key: Nth instance of a
@@ -233,20 +249,25 @@ class Solver:
 
     # -- output fan-out ------------------------------------------------------ #
 
-    def quantity_host(self, name: str) -> np.ndarray:
-        """One quantity over the lattice as a host array: its compiled
-        program on the device (``quantity.eval``, fenced when traced;
-        ``Lattice.get_quantity`` adds ``program``: ``"built"`` on the
-        call that compiled it, ``"reused"`` after), then the copy, or on
-        a mesh the gather, to the host (``quantity.d2h``).  For output
-        (``<VTK>``, ``<TXT>``, ``<Catalyst>``); ``<Failcheck>`` brings no
-        plane down: :meth:`nonfinite_counts`."""
+    def quantity_device(self, name: str) -> jax.Array:
+        """One quantity over the lattice, still on the device: its
+        compiled program dispatched (``quantity.eval``, fenced when
+        traced; ``Lattice.get_quantity`` adds ``program``: ``"built"`` on
+        the call that compiled it, ``"reused"`` after).  The output is a
+        fresh buffer that no ``iterate`` donates."""
         with telemetry.span("quantity.eval", quantity=name) as sp:
             q = sp.sync(self.lattice.get_quantity(name))
             sp.add(bytes=q.nbytes)
-        with telemetry.span("quantity.d2h", quantity=name,
-                            bytes=q.nbytes):
-            return np.asarray(q)
+        return q
+
+    def quantity_host(self, name: str) -> np.ndarray:
+        """One quantity over the lattice as a host array, on the calling
+        thread: :meth:`quantity_device`, then the copy, or on a mesh the
+        gather, to the host (``quantity.d2h``), waited for.  For ``<TXT>``
+        and ``<Catalyst>``; ``<VTK>`` dispatches here and copies on its
+        writer's thread (:meth:`write_vtk`), ``<Failcheck>`` brings no
+        plane down (:meth:`nonfinite_counts`)."""
+        return _to_host(name, self.quantity_device(name))
 
     def nonfinite_counts(self, names: Sequence[str]) -> list[int]:
         """How many values of each named quantity are NaN or infinite,
@@ -263,18 +284,18 @@ class Solver:
         with telemetry.span("quantity.d2h", bytes=4 * len(counts)):
             return [int(c) for c in jax.device_get(counts)]
 
+    def _wanted(self, what: Optional[set[str]]) -> list[str]:
+        """The quantities an output handler's ``what`` selects
+        (reference vtkWriteLattice quantity loop,
+        src/vtkLattice.cpp.Rt:47-66)."""
+        return [q.name for q in self.model.quantities if not q.adjoint
+                and (not what or q.name in what or "all" in what)]
+
     def quantity_arrays(self, what: Optional[set[str]] = None
                         ) -> dict[str, np.ndarray]:
-        """Evaluate selected quantities -> host arrays (reference
-        vtkWriteLattice quantity loop, src/vtkLattice.cpp.Rt:47-66)."""
-        out = {}
-        for q in self.model.quantities:
-            if q.adjoint:
-                continue
-            if what and q.name not in what and "all" not in what:
-                continue
-            out[q.name] = self.quantity_host(q.name)
-        return out
+        """Evaluate selected quantities -> host arrays."""
+        return {name: self.quantity_host(name)
+                for name in self._wanted(what)}
 
     def write_geometry_vti(self) -> str:
         """Write the painted geometry as VTI: raw flags, one 0/1 layer per
@@ -296,20 +317,85 @@ class Solver:
 
     def write_vtk(self, what: Optional[set[str]] = None,
                   compress: bool = False) -> Optional[str]:
+        """Hand one ``.vti`` piece and its ``.pvti`` to the output writer
+        and return the piece's path; the files are whole once
+        :meth:`drain_output` has returned.  On the calling thread
+        (``output.vtk``): the dispatch of every selected quantity's
+        program, the start of its copy to the host, and the flags (the
+        lattice's host copy: ``iterate`` donates the live state).  On the
+        writer's thread (``output.vtk.write``, given this write's
+        ``iteration``): the copies finished (``quantity.d2h``; on a mesh
+        the gather), ``write_vti`` (``output.vtk.encode``,
+        ``output.vtk.file``) and ``write_pvti``.  One write is in flight
+        at most: a second waits for the first (``output.vtk.drain``)."""
         if not self.is_main:
             return None
         from tclb_tpu.utils.vtk import write_pvti, write_vti
-        with telemetry.span("output.vtk", iteration=self.iter):
-            arrays = self.quantity_arrays(what)
-            flags = np.asarray(self.lattice.state.flags)
+        piece = self.out_path("VTK", "vti")
+        master = self.out_path("VTK", "pvti")
+        with telemetry.span("output.vtk", iteration=self.iter) as sp:
+            pending = {name: self.quantity_device(name)
+                       for name in self._wanted(what)}
+            for q in pending.values():
+                q.copy_to_host_async()
             # node-type group layers (reference writes one flag layer per
             # selected group, src/vtkLattice.cpp.Rt:33-46)
-            if what is None or "flag" in (what or set()) or not what:
-                arrays["Flag"] = flags
-            piece = write_vti(self.out_path("VTK", "vti"), arrays,
-                              compress=compress)
-            write_pvti(self.out_path("VTK", "pvti"), piece, arrays)
+            flags = (self.lattice._flags_host()
+                     if not what or "flag" in what else None)
+            ids = sp.inherited()
+            sp.add(queued_bytes=sum(q.nbytes for q in pending.values())
+                   + (0 if flags is None else flags.nbytes))
+
+            def write() -> None:
+                with telemetry.span("output.vtk.write", **ids):
+                    arrays = {name: _to_host(name, q)
+                              for name, q in pending.items()}
+                    pending.clear()         # the device's buffers go
+                    if flags is not None:
+                        arrays["Flag"] = flags
+                    write_vti(piece, arrays, compress=compress)
+                    write_pvti(master, piece, arrays)
+
+            self.drain_output("next_write")
+            if self._output is None:
+                from tclb_tpu.checkpoint.writer import AsyncWriter
+                self._output = AsyncWriter("tclb-output-writer")
+            self._output.submit(write)
+            self._output_file = piece
+            telemetry.counter("output.vtk.async_writes")
         return piece
+
+    def drain_output(self, reason: str = "run_end") -> None:
+        """Wait until the write in flight, if any, has left whole files
+        (``output.vtk.drain``: ``reason``, and ``wait_s``, what it
+        blocked; counter ``output.vtk.drain_waits`` where that was over a
+        millisecond).  A write that failed raises here, naming its file.
+        Nothing in flight: returns at once."""
+        piece, self._output_file = self._output_file, None
+        if piece is None:
+            return
+        with telemetry.span("output.vtk.drain", reason=reason) as sp:
+            try:
+                if sp.blocked(self._output.wait) > 1e-3:
+                    telemetry.counter("output.vtk.drain_waits")
+            except Exception as e:
+                raise OutputError(f"writing {piece} failed: {e!r}") from e
+
+    @contextlib.contextmanager
+    def output_drained(self, reason: str) -> Iterator[None]:
+        """Leave the block with no write in flight, however it is left.
+        A failed write fails the run here; where another exception is
+        already on its way that one wins and the write's is logged."""
+        try:
+            yield
+        except BaseException:
+            try:
+                self.drain_output(reason)
+            except OutputError as e:
+                from tclb_tpu.utils import log
+                log.warning(str(e))
+            raise
+        self.drain_output(reason)
 
     def write_txt(self, what: Optional[set[str]] = None,
                   gzip_out: bool = True) -> list[str]:
@@ -411,7 +497,8 @@ def _run_root(root: ET.Element, model: Model, mesh, dtype,
                  int(round(solver.units.alt(geom.get("ny", "1")))),
                  int(round(solver.units.alt(geom.get("nx", "1")))))
     solver.set_size(shape)
-    MainContainer(root, solver).init()
+    with solver.output_drained("run_end"):
+        MainContainer(root, solver).init()
     if solver.resume_from is not None:
         from tclb_tpu.utils import log
         log.warning("--resume was given but the config has no "

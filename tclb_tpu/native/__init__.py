@@ -6,8 +6,10 @@ here the two genuinely hot host loops — STL voxelization
 (reference src/vtkOutput.cpp) — are native C++ (src/tclb_native.cpp),
 compiled once per checkout into ``_build/`` and loaded via ctypes.  The
 VTI encoder compresses its blocks in parallel, on as many threads as the
-process has usable cores and the array has blocks for; the blocks are
-independent zlib streams, so the bytes do not depend on the thread count.
+process has usable cores (less two, left to the thread that launches the
+device's programs and the runtime's own: the encoder runs beside them)
+and the array has blocks for; the blocks are independent zlib streams,
+so the bytes do not depend on the thread count.
 
 Everything degrades gracefully: no compiler, a failed build, or
 ``TCLB_NATIVE=0`` fall back to the pure-Python implementations
@@ -122,9 +124,19 @@ def _usable_cores() -> int:
         return os.cpu_count() or 1
 
 
+# the encoder runs on the output writer's thread while the main thread
+# launches the next iterate and the runtime's own thread starts it: with
+# all 13 cores of the chip's host encoding, the segment after a write ran
+# 84 ms for 62; with one core left free 73, with two 64.6, with three
+# 63.3 while the encode grows (PERF.md, PR 38)
+_CORES_LEFT_FREE = 2
+
+
 def _zlib_threads(nblocks: int) -> int:
-    """Threads for one array: all usable cores, as far as the blocks go."""
-    return max(1, min(_usable_cores(), nblocks // _MIN_BLOCKS_PER_THREAD))
+    """Threads for one array: the usable cores but ``_CORES_LEFT_FREE``,
+    as far as the blocks go."""
+    return max(1, min(_usable_cores() - _CORES_LEFT_FREE,
+                      nblocks // _MIN_BLOCKS_PER_THREAD))
 
 
 def _native_blocks(lib: ctypes.CDLL, src: np.ndarray, block: int,

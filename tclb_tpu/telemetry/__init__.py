@@ -28,4 +28,4 @@ from tclb_tpu.telemetry.events import (  # noqa: F401
     engine_fallback, engine_selected, event, failcheck, job_context,
     path, set_job, subscribe, unsubscribe)
 from tclb_tpu.telemetry.spans import (  # noqa: F401
-    NOOP_SPAN, Span, annotate, fuse_of, span)
+    NOOP_SPAN, Span, annotate, fuse_of, off_launch_thread, span)

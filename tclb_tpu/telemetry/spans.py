@@ -5,7 +5,8 @@ asynchronous, so a naive ``perf_counter`` pair times the *enqueue*, not
 the work — callers fence with :meth:`Span.sync` (``jax.block_until_ready``
 on the region's output) before the span closes.  A fence says what it
 waited: the seconds a span's own fences blocked add up in its ``wait_s``
-(absent on a span that never fenced), and :meth:`Span.mark` records the
+(absent on a span that never fenced; a wait for another thread counts
+the same way, :meth:`Span.blocked`), and :meth:`Span.mark` records the
 seconds from the span's start to a point inside it (``dispatch_s``: a
 jitted call has returned, the fence not yet begun).  A span's *host
 time* is ``dur_s`` less its children less ``wait_s``.
@@ -15,16 +16,22 @@ span exit stamps ``mlups`` (``nodes * iters / dt / 1e6``) the way the
 reference prints its own MLUPS line (reference src/main.cpp.Rt:100-126).
 
 Spans also wrap ``jax.profiler.TraceAnnotation`` when available, so a
-concurrent ``jax.profiler`` capture shows the same region names.
+concurrent ``jax.profiler`` capture shows the same region names.  A
+thread that launches nothing on the device (a background writer) says so
+once (:func:`off_launch_thread`): its spans are annotated
+``<thread>/<name>``, so whoever explains the device's idle gaps by the
+annotation over them reads only the thread that could have filled them.
 
 Spans nest.  Each carries ``id`` (a process-wide counter), ``parent``
 (the ``id`` of the span open on this thread when it was entered, None at
 the top) and ``t0`` (its start, on the wall clock of ``ts``), and a child
 inherits ``iteration`` and ``job_id`` from its parent unless it is given
-its own, so every span of one segment shares that identifier.  Non-span
-events emitted inside a span are stamped with its ``id`` as ``parent``
-(:func:`events.event`).  A span's *self time* is ``dur_s`` minus the
-union of its children's ``[t0, t0 + dur_s]`` intervals.
+its own, so every span of one segment shares that identifier.  The stack
+is a thread's own: work handed to another thread starts a new tree
+there, whose root is given the identifiers (:meth:`Span.inherited`).
+Non-span events emitted inside a span are stamped with its ``id`` as
+``parent`` (:func:`events.event`).  A span's *self time* is ``dur_s``
+minus the union of its children's ``[t0, t0 + dur_s]`` intervals.
 """
 
 from __future__ import annotations
@@ -32,7 +39,7 @@ from __future__ import annotations
 import itertools
 import re
 import time
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 from tclb_tpu.telemetry import events
 
@@ -85,9 +92,26 @@ class Span:
         self._wait = (self._wait or 0.0) + time.perf_counter() - t
         return x
 
+    def blocked(self, wait: Callable[[], Any]) -> float:
+        """Run ``wait``, which blocks on something other than the device
+        (a thread's ``join``): its seconds go into the span's ``wait_s``
+        as a fence's do, and are returned."""
+        t = time.perf_counter()
+        try:
+            wait()
+        finally:
+            dt = time.perf_counter() - t
+            self._wait = (self._wait or 0.0) + dt
+        return dt
+
     def mark(self, field: str) -> None:
         """Record the seconds since the span opened under ``field``."""
         self.fields[field] = round(time.perf_counter() - self._t0, 6)
+
+    def inherited(self) -> dict:
+        """The identifiers a child would take from this span: what the
+        root of the work it hands to another thread has to be given."""
+        return {k: self.fields[k] for k in INHERITED if k in self.fields}
 
     def __enter__(self) -> "Span":
         stack = events.span_stack()
@@ -101,7 +125,8 @@ class Span:
         stack.append(self)
         try:
             from jax.profiler import TraceAnnotation
-            self._annotation = TraceAnnotation(self.name)
+            self._annotation = TraceAnnotation(
+                getattr(events._span_local, "prefix", "") + self.name)
             self._annotation.__enter__()
         except Exception:  # noqa: BLE001 — profiler is optional garnish
             self._annotation = None
@@ -147,8 +172,15 @@ class _NoopSpan:
     def sync(self, x: Any) -> Any:
         return x
 
+    def blocked(self, wait: Callable[[], Any]) -> float:
+        wait()
+        return 0.0
+
     def mark(self, field: str) -> None:
         pass
+
+    def inherited(self) -> dict:
+        return {}
 
     def __enter__(self) -> "_NoopSpan":
         return self
@@ -167,6 +199,13 @@ def span(name: str, **fields: Any):
     if not events.enabled():
         return NOOP_SPAN
     return Span(name, fields)
+
+
+def off_launch_thread(name: str) -> None:
+    """The calling thread launches nothing on the device: from here on
+    its spans' profiler annotations are called ``<name>/<span>``.  Their
+    events keep the span's name."""
+    events._span_local.prefix = name + "/"
 
 
 def annotate(**fields: Any) -> None:

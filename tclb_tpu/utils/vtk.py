@@ -42,9 +42,10 @@ def write_vti(path: str, arrays: dict[str, np.ndarray],
     appended data blocks, src/vtkOutput.cpp); ``compress=True`` switches the
     blocks to vtkZLibDataCompressor layout (native C++ encoder in
     tclb_tpu/native when available, which compresses the 32 KB blocks in
-    parallel on the usable host cores; the file does not depend on the
+    parallel on the usable host cores but two; the file does not depend on the
     thread count) — every VTK reader understands it and large fields
-    shrink ~3x.
+    shrink ~3x.  The file appears under its name whole (written beside
+    it, then renamed).
     """
     norm: dict[str, np.ndarray] = {}
     shape = None
@@ -102,9 +103,11 @@ def write_vti(path: str, arrays: dict[str, np.ndarray],
              '<AppendedData encoding="raw">']
     if not path.endswith(".vti"):
         path += ".vti"
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    from tclb_tpu.checkpoint.writer import atomic_path
     with telemetry.span("output.vtk.file") as sp:
-        with open(path, "wb") as f:
+        # under a temporary name, then renamed: a reader (or a run that
+        # dies in the write) never sees half a file
+        with atomic_path(path) as tmp, open(tmp, "wb") as f:
             f.write("\n".join(head).encode())
             f.write(b"\n_")
             for b in blocks:
@@ -144,8 +147,8 @@ def write_pvti(path: str, piece: str, arrays: dict[str, np.ndarray],
               "</PImageData>", "</VTKFile>"]
     if not path.endswith(".pvti"):
         path += ".pvti"
-    with open(path, "w") as f:
-        f.write("\n".join(lines))
+    from tclb_tpu.checkpoint.writer import atomic_write_bytes
+    atomic_write_bytes(path, "\n".join(lines).encode())
     return path
 
 

@@ -109,8 +109,8 @@ def test_zlib_blocks_same_bytes_on_any_thread_count(n, threads):
 
 @needs_native
 @pytest.mark.parametrize("cores,nblocks,want", [
-    (13, 0, 1), (13, 1, 1), (13, 15, 1), (13, 64, 8), (13, 128, 13),
-    (13, 384, 13), (1, 384, 1), (224, 384, 48)])
+    (13, 0, 1), (13, 1, 1), (13, 15, 1), (13, 64, 8), (13, 128, 11),
+    (13, 384, 11), (1, 384, 1), (2, 384, 1), (224, 384, 48)])
 def test_zlib_threads_follow_cores_and_blocks(monkeypatch, cores, nblocks,
                                               want):
     monkeypatch.setattr(native, "_usable_cores", lambda: cores)
@@ -161,7 +161,7 @@ def test_write_vti_compressed_roundtrip(tmp_path, shape, monkeypatch):
     (U: 7 MB, 216 blocks) for the encoder to use more than one thread."""
     from tclb_tpu import telemetry
     from tclb_tpu.utils.vtk import write_vti
-    monkeypatch.setattr(native, "_usable_cores", lambda: 4)
+    monkeypatch.setattr(native, "_usable_cores", lambda: 6)  # two stay free
     rng = np.random.default_rng(1)
     a = rng.standard_normal(shape).astype(np.float32)
     u = np.cumsum(rng.standard_normal((3,) + shape), axis=3) \

@@ -404,10 +404,13 @@ def _failcheck(s, xml=RESCUE):
 
 
 def _run(h, iteration=0):
-    """`do_it` under the span `<Solve>`'s loop opens round a handler."""
+    """`do_it` under the span `<Solve>`'s loop opens round a handler,
+    then the drain `<Solve>` ends with: a rescue `<VTK>` is whole."""
     with telemetry.span("handler", handler="cbFailcheck",
                         iteration=iteration):
-        return h.do_it()
+        ret = h.do_it()
+    h.solver.drain_output("solve_end")
+    return ret
 
 
 def _under(docs, parent, name):

@@ -676,23 +676,38 @@ def test_solve_emits_phase_spans_with_the_right_parents(
                and e["name"] == "quantity.eval")
     vtk, = _spans(seen, "output.vtk")
     assert parent_of(vtk) is handlers[2]
+    # the handler's own part: the planes' programs dispatched, then the
+    # hand-off (nothing was in flight: no drain under it)
     kids = [e for e in spans if e["parent"] == vtk["id"]]
-    assert [e["name"] for e in kids] == (
-        ["quantity.eval", "quantity.d2h"] * len(quantities)
-        + ["output.vtk.encode", "output.vtk.file"])
+    assert [e["name"] for e in kids] == ["quantity.eval"] * n
+    assert [e["quantity"] for e in kids] == quantities
     # the planes' programs are not Failcheck's: this write may build them
     assert all(e["program"] in ("built", "reused") and e["bytes"] > 0
-               and "reduce" not in e for e in kids[:-2:2])
+               and "reduce" not in e for e in kids)
+    # the writer's thread has a tree of its own, off the segment: its
+    # root is given the write's iteration, the children take it from it
+    write, = _spans(seen, "output.vtk.write")
+    assert write["parent"] is None and write["iteration"] == 6
+    kids = [e for e in spans if e["parent"] == write["id"]]
+    assert [e["name"] for e in kids] == (
+        ["quantity.d2h"] * n + ["output.vtk.encode", "output.vtk.file"])
+    assert [e["quantity"] for e in kids[:n]] == quantities
+    assert all(e["iteration"] == 6 for e in kids)
     encode, written = kids[-2:]
-    assert encode["iteration"] == written["iteration"] == 6
     assert 0 < encode["bytes_out"] < encode["bytes_in"]
     # one block of 32 KB each for Rho and Flag, three for U: too few for
     # a second thread
     assert encode["threads"] == 1 and encode["blocks"] == 5
-    assert encode["bytes_in"] >= sum(
+    assert encode["bytes_in"] == vtk["queued_bytes"] >= sum(
         e["bytes"] for e in kids if e["name"] == "quantity.d2h")
     assert written["bytes"] == os.path.getsize(
         [str(p) for p in tmp_path.iterdir() if p.suffix == ".vti"][0])
+    # <Solve> waited for it on its way out, under no segment
+    drain, = _spans(seen, "output.vtk.drain")
+    assert drain["parent"] is None and drain["reason"] == "solve_end"
+    assert 0 <= drain["wait_s"] <= drain["dur_s"] + 1e-6
+    assert write["ts"] <= drain["ts"]
+    assert telemetry.counters()["output.vtk.async_writes"] == 1
     # self time: what a span's children do not cover is never negative
     for e in (handlers[2], vtk):
         own = e["dur_s"] - sum(k["dur_s"] for k in spans
@@ -703,9 +718,10 @@ def test_solve_emits_phase_spans_with_the_right_parents(
 _SOLVE_LOG_XML = _SOLVE_XML.replace("<Failcheck", '<Log Iterations="3"/>'
                                     "<Failcheck", 1)
 
-#: the spans of a <Solve> that fence (Span.sync), and so say wait_s
+#: the spans of a <Solve> that fence (Span.sync) or wait for the output
+#: writer (Span.blocked), and so say wait_s
 _FENCED = {"iterate", "iterate.fused", "engine.probe",
-           "iterate.globals_step", "quantity.eval"}
+           "iterate.globals_step", "quantity.eval", "output.vtk.drain"}
 
 
 class _SteppingClock:
@@ -758,9 +774,12 @@ def test_segment_is_the_root_of_its_pass(solved):
     assert [[e["handler"] for e in spans if e["name"] == "handler"
              and e["parent"] == seg["id"]] for seg in segments] == [
         ["cbLog", "cbFailcheck"], ["cbLog", "cbFailcheck", "cbVTK"]]
-    # every span of the solve hangs from a segment: none is a second root
-    assert all(e["parent"] is not None for e in spans
-               if e["name"] != "segment")
+    # every span of the solve's own thread hangs from a segment; the
+    # other roots are the <VTK> write on the writer's thread and <Solve>
+    # waiting for it on its way out
+    assert [e["name"] for e in spans if e["parent"] is None
+            and e["name"] != "segment"] == ["output.vtk.write",
+                                            "output.vtk.drain"]
 
 
 def test_a_fence_says_what_it_waited_and_a_launch_what_it_cost(solved):
@@ -901,14 +920,14 @@ def test_jsonl_sink_is_flushed_by_the_outermost_span(tmp_path, monkeypatch):
 def test_vtk_encode_span_says_how_it_was_encoded(
         seen, tmp_path, monkeypatch, native_on):
     """`threads`, `blocks` and `native` on `output.vtk.encode`: the native
-    encoder on as many threads as the cores and the blocks allow; with
-    the library off (what TCLB_NATIVE=0 does in `get_lib`) Python on
-    one."""
+    encoder on as many threads as the cores, less the two it leaves
+    free, and the blocks allow; with the library off (what TCLB_NATIVE=0
+    does in `get_lib`) Python on one."""
     from tclb_tpu import native
     from tclb_tpu.utils.vtk import write_vti
     if native_on and not native.available():
         pytest.skip("native lib not built (no g++?)")
-    monkeypatch.setattr(native, "_usable_cores", lambda: 3)
+    monkeypatch.setattr(native, "_usable_cores", lambda: 5)  # two stay free
     if not native_on:
         monkeypatch.setenv("TCLB_NATIVE", "0")
         monkeypatch.setattr(native, "_tried", False)
@@ -997,7 +1016,7 @@ def test_disabled_solve_never_syncs_and_listener_is_silent(
     # clock for pre_sync_s; the segment, progress (a clock that makes
     # every call after the first report) and the Log's two children
     # included
-    for method in ("__init__", "sync", "mark"):
+    for method in ("__init__", "sync", "mark", "blocked", "inherited"):
         monkeypatch.setattr(telemetry.Span, method, boom)
     monkeypatch.setattr(events, "_fanout_locked", boom)  # nothing emits
     monkeypatch.setattr(lattice_mod, "time", _Untouchable("the clock"))

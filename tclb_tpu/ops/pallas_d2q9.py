@@ -49,6 +49,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tclb_tpu import telemetry
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, lbm
@@ -186,6 +187,18 @@ def zonal_planes(model: Model, params, zones, dtype):
     return vel, den
 
 
+_AUX_PLANES = 3      # what a kernel call reads beside the state: the
+#                      int32 flags, the f32 Velocity and Density planes
+
+
+def resident_vmem_bytes(model: Model, ny: int, nx: int) -> int:
+    """What a resident call holds on-chip: the input block, the out block
+    (doubles as the second ping-pong buffer) and one scratch stack, and
+    the static planes; per-chunk temporaries live in the scoped budget
+    like the band kernels'."""
+    return (3 * model.n_storage + _AUX_PLANES) * ny * nx * 4
+
+
 def supports_resident(model: Model, shape, dtype) -> bool:
     """Whether the VMEM-resident multi-step kernel can run this
     configuration: the whole lattice (two ping-pong stacks + statics)
@@ -196,13 +209,7 @@ def supports_resident(model: Model, shape, dtype) -> bool:
     if not supports(model, shape, dtype):
         return False
     ny, nx = (int(s) for s in shape)
-    # input block + out block (doubles as the second ping-pong buffer) +
-    # one scratch stack + 3 static planes; per-chunk temporaries live in
-    # the scoped budget like the band kernels'
-    if 3 * model.n_storage * ny * nx * 4 + 3 * ny * nx * 4 \
-            > 15 * 1024 * 1024:
-        return False
-    return True
+    return resident_vmem_bytes(model, ny, nx) <= 15 * 1024 * 1024
 
 
 _RESIDENT_FUSE = 8   # lattice steps per kernel invocation (MUST be even:
@@ -219,6 +226,18 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     halo DMA, any ny.  This is the deep temporal fusion the band kernels
     cannot do (their VMEM only holds a band); the reference has no
     analogue (its GPU has no software-managed on-chip tier).
+
+    A call of ``n`` steps dispatches up to two programs: ``n // 8``
+    resident calls in one ``lax.scan``, and, where 8 does not divide
+    ``n``, the ``n % 8`` steps left over on a second engine, the
+    single-step band kernel of :func:`make_pallas_iterate` with its
+    ghost rows (an XLA pad before its calls and a slice after them).
+    The Lattice hybrid hands this engine ``niter - 1`` steps (the last
+    is the XLA step that produces the globals), so a handler interval
+    that is a multiple of 8 (100, 500, 1000) takes the second engine
+    for 3 or 7 steps in every call: three programs a segment.  With
+    telemetry on, the call says what it issued on the open span
+    (``account``).
 
     Same NoGlobals + no-Control contract as the band kernels."""
     if not supports_resident(model, shape, dtype):
@@ -327,13 +346,11 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 
         fields, _ = jax.lax.scan(body, state.fields, None,
                                  length=niter // _RESIDENT_FUSE)
-        # remainder steps on the band path would need its ghost padding;
-        # run them as additional resident calls is impossible (fuse is
-        # baked in), so delegate the tail to the caller via the band
-        # engine — the Lattice hybrid only ever calls with large niter,
-        # and the fuse divides it after the -1 hybrid split rarely; keep
-        # exactness by running the remainder through the single-step
-        # band kernel of make_pallas_iterate when needed
+        # the steps left over cannot be more resident calls (the fuse is
+        # baked in): ``iterate`` below runs them through the single-step
+        # band kernel of make_pallas_iterate.  The Lattice hybrid hands
+        # over niter - 1 steps, so that happens in every call whose
+        # length is a multiple of 8
         return LatticeState(
             fields=fields,
             flags=state.flags,
@@ -344,6 +361,18 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 
     band = make_pallas_iterate(model, shape, dtype, interpret=interpret,
                                fuse=1, present=present)
+
+    def account(niter: int) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side (the
+        mirror of ``iterate``'s split): the resident calls, and the steps
+        left over with the shape of the band calls that run them."""
+        calls, rest = divmod(int(niter), _RESIDENT_FUSE)
+        return dict(
+            band.band_shape, kernel_calls=calls + rest,
+            resident_calls=calls, resident_steps=_RESIDENT_FUSE,
+            remainder_steps=rest, aux_planes=_AUX_PLANES,
+            remainder_aux_planes=_AUX_PLANES, chunk_rows=chunk,
+            vmem_bytes=resident_vmem_bytes(model, ny, nx))
 
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
@@ -356,8 +385,17 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         rest = niter - main
         if rest:
             state = band(state, params, rest)
+        # a call under a trace (a caller's own jit) issues nothing
+        if telemetry.enabled() and not isinstance(state.fields,
+                                                  jax.core.Tracer):
+            did = account(niter)
+            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            telemetry.counter("engine.resident_calls",
+                              did["resident_calls"])
+            telemetry.annotate(**did)
         return state
 
+    iterate.account = account
     return iterate
 
 
@@ -863,4 +901,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 "use the XLA path for time-dependent zonal settings")
         return _iterate_jit(state, params, niter, fuse=fuse)
 
+    # the single-step kernel's bands, for the resident engine's account
+    # of the steps it leaves to this one
+    iterate.band_shape = dict(bands=ny // by, band_rows=by, halo_rows=8,
+                              pad_rows=pad)
     return iterate

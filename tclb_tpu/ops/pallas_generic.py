@@ -976,6 +976,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     iterate._impl = dict(call1=call1, call_g=call_g, by=by, pad=pad,
                          zonal_si=zonal_si, zshift=zshift,
                          nt_present=nt_present, mk_call=_mk_call)
+    iterate.account = account
     return iterate
 
 
@@ -988,6 +989,15 @@ _RESIDENT_FUSE = 8       # steps per kernel call (EVEN: ping-pong parity)
 _RESIDENT_BUDGET = 72 * 1024 * 1024   # state+aux residency budget (v5e
 #                          VMEM is 128 MiB; the rest holds the chunk
 #                          temporaries Mosaic scopes)
+
+
+def resident_vmem_bytes(model: Model, ny: int, nx: int, dtype) -> int:
+    """What a resident call holds on-chip: the two ping-pong field
+    stacks, which narrow with the storage dtype, and the aux planes,
+    which stay f32 (flags + zonal settings)."""
+    n_aux = 1 + len(model.zonal_settings)
+    return (2 * model.n_storage * jnp.dtype(dtype).itemsize
+            + n_aux * 4) * ny * nx
 
 
 def supports_resident(model: Model, shape, dtype) -> bool:
@@ -1006,12 +1016,7 @@ def supports_resident(model: Model, shape, dtype) -> bool:
     if ny % 8 or nx % 128:
         return False   # residency keeps the exact periodic wrap: no
         #                ghost-row machinery, so the shape must be aligned
-    n_aux = 1 + len(model.zonal_settings)
-    itemsize = jnp.dtype(dtype).itemsize
-    # ping-pong field stacks narrow with the storage dtype; the aux
-    # planes stay f32 (flags + zonal settings)
-    if (2 * model.n_storage * itemsize + n_aux * 4) * ny * nx \
-            > _RESIDENT_BUDGET:
+    if resident_vmem_bytes(model, ny, nx, dtype) > _RESIDENT_BUDGET:
         return False
     plan, reach = action_plan(model, "Iteration", fuse=1)
     if reach > _HALO:
@@ -1158,26 +1163,55 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
                             globals_=jnp.zeros_like(state.globals_),
                             iteration=state.iteration + adv * niter)
 
+    # EVEN resident length (ping-pong parity) leaving >=1 step for the
+    # band engine's globals flavor when the model declares Globals
+    # (full_globals contract)
+    tail_min = 1 if getattr(band, "full_globals", False) \
+        and model.n_globals else 0
+
+    def resident_length(niter: int) -> int:
+        return max(niter - tail_min, 0) // 2 * 2
+
+    def account(niter: int) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side (the
+        mirror of ``iterate``'s split): one resident call of ``main``
+        steps, and the band engine's own account of the steps left to
+        it, whose ``aux_planes`` goes by ``remainder_aux_planes``."""
+        main = resident_length(niter)
+        rest = band.account(niter - main, False)
+        return dict(
+            rest, kernel_calls=int(main > 0) + rest["kernel_calls"],
+            resident_calls=int(main > 0), resident_steps=main,
+            remainder_steps=niter - main, aux_planes=n_aux,
+            remainder_aux_planes=rest["aux_planes"], chunk_rows=chunk,
+            vmem_bytes=resident_vmem_bytes(model, ny, nx, dtype))
+
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
         if params.time_series is not None:
             raise ValueError("generic resident engine does not support "
                              "Control time series")
-        # EVEN resident length (ping-pong parity) leaving >=1 step for
-        # the band engine's globals flavor when the model declares
-        # Globals (full_globals contract)
-        tail_min = 1 if getattr(band, "full_globals", False) \
-            and model.n_globals else 0
-        main = max(niter - tail_min, 0) // 2 * 2
+        main = resident_length(niter)
         if main:
             state = _resident_jit(state, params, main)
         rest = niter - main
         if rest:
             state = band(state, params, rest)
+        # a call under a trace (a caller's own jit) issues nothing; the
+        # band engine has counted and annotated its own calls, and this
+        # account of the whole call lies over its fields
+        if telemetry.enabled() and not isinstance(state.fields,
+                                                  jax.core.Tracer):
+            did = account(int(niter))
+            telemetry.counter("engine.kernel_calls", did["resident_calls"])
+            telemetry.counter("engine.resident_calls",
+                              did["resident_calls"])
+            telemetry.annotate(**did)
         return state
 
     iterate.supports_series = False
     iterate.full_globals = getattr(band, "full_globals", False)
+    iterate.account = account
     return iterate
 
 

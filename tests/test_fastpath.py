@@ -20,7 +20,7 @@ from tclb_tpu.models import get_model
 from tclb_tpu.ops import pallas_d2q9, pallas_d3q, pallas_generic
 
 
-def _karman_lattice(ny=64, nx=128):
+def _karman_lattice(ny=64, nx=128, wedge=False):
     m = get_model("d2q9")
     lat = Lattice(m, (ny, nx), dtype=jnp.float32,
                   settings={"nu": 0.05, "Velocity": 0.03})
@@ -30,6 +30,11 @@ def _karman_lattice(ny=64, nx=128):
     flags[0, :] = m.flag_for("Wall")
     flags[-1, :] = m.flag_for("Wall")
     flags[ny // 3:2 * ny // 3, nx // 8:nx // 4] = m.flag_for("Wall")
+    if wedge:
+        # the box cut to a wedge, its slope facing the inlet
+        rows, cols = np.mgrid[0:ny, 0:nx]
+        flags[(rows - ny // 3 < nx // 4 - cols) & (rows >= ny // 3)
+              & (cols >= nx // 8) & (rows < 2 * ny // 3)] = m.flag_for("MRT")
     # objective columns: globals (fluxes/pressure loss) accumulate here
     flags[1:-1, 2] = m.flag_for("MRT", "Inlet")
     flags[1:-1, -3] = m.flag_for("MRT", "Outlet")
@@ -53,17 +58,21 @@ def test_supports_only_implemented_models():
                                         jnp.float32), name
 
 
-def test_engine_dispatch_matches_xla(monkeypatch):
+@pytest.mark.parametrize("ny,niter,wedge", [
+    (64, 21, False),
+    # the awkward part of the published 1024 x 100: two chunks of 50
+    # rows (no multiple of 8), the periodic pull built by concatenation
+    (100, 17, True)], ids=["64", "100"])
+def test_engine_dispatch_matches_xla(monkeypatch, ny, niter, wedge):
     """Solver-path == pallas-path on the boundary-rich Kármán case:
     the engine entry point (Lattice.iterate) with the fast path forced
     must reproduce the XLA engine's fields AND globals."""
-    niter = 21
     monkeypatch.setenv("TCLB_FASTPATH", "0")   # pin pure XLA (even on TPU)
-    _, lat_x = _karman_lattice()
+    _, lat_x = _karman_lattice(ny, wedge=wedge)
     lat_x.iterate(niter)
 
     monkeypatch.setenv("TCLB_FASTPATH", "force")
-    _, lat_f = _karman_lattice()
+    _, lat_f = _karman_lattice(ny, wedge=wedge)
     lat_f.iterate(niter)
     # small domains select the VMEM-resident deep-fusion engine
     assert lat_f._fast_name == "pallas_resident[d2q9,fuse=8]"

@@ -98,6 +98,54 @@ def test_d2q9_band_fused_1024(one_chip):
     assert "d2q9_band_fuse1/pallas_call" in text
 
 
+def test_d2q9_resident_karman_as_shipped(one_chip, monkeypatch):
+    """``example/karman.xml`` at its own size, 1024 x 100: the whole
+    lattice fits ``supports_resident``'s budget, the dispatch puts the
+    VMEM-resident engine first and probes it, and what the probe would
+    compile compiles: the resident kernel on two chunks of 50 rows (no
+    multiple of the sublane tile: the periodic pull is concatenations)
+    and, for the 7 steps a segment leaves over, the single-step band
+    kernel on 120 padded rows.  A compile that raised here would make
+    the probe step down to the band engine on the chip.  23 steps: two
+    resident calls and 7 left over.  (About 70 s on this host: the
+    resident kernel is 16 unrolled chunk steps.)"""
+    shape = (100, 1024)
+    m = get_model("d2q9")
+    lat = Lattice(m, shape, dtype=jnp.float32,
+                  settings={"nu": 0.02, "Velocity": 0.01})
+    flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
+    flags[:, 0] = m.flag_for("WVelocity", "MRT")
+    flags[:, -1] = m.flag_for("EPressure", "MRT")
+    flags[0, :] = flags[-1, :] = m.flag_for("Wall")
+    rows, cols = np.mgrid[0:100, 0:1024]
+    flags[np.abs(rows - 49.5) + np.abs(cols - 139.5) < 20] = \
+        m.flag_for("Wall")
+    lat.set_flags(flags)
+    lat.init()
+    assert pallas_d2q9.supports_resident(m, shape, jnp.float32)
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    chain = lat._build_fast()
+    assert [(c.tag, c.probe) for c in chain] == [
+        ("pallas_resident[d2q9,fuse=8]", True),
+        ("pallas_2d[d2q9,fuse=2]", False)]
+    it = pallas_d2q9.make_resident_iterate(
+        m, shape, jnp.float32, interpret=False,
+        present=lbm.present_types(m, flags))
+    assert it.account(23) == dict(
+        kernel_calls=9, resident_calls=2, resident_steps=8,
+        remainder_steps=7, aux_planes=3, remainder_aux_planes=3,
+        chunk_rows=50, vmem_bytes=14_745_600, bands=3, band_rows=40,
+        halo_rows=8, pad_rows=20)
+    text = _compile(it, lat, 23, one_chip)
+    assert "tpu_custom_call" in text
+    assert "d2q9_resident_fuse8/pallas_call" in text
+    assert "d2q9_band_fuse1/pallas_call" in text
+    # the names a device trace shows, which the benchmark's reader of
+    # the remainder's share tells apart
+    assert re.search(r"%d2q9_resident_fuse8[\w.]* = \S+ custom-call\(", text)
+    assert re.search(r"%d2q9_band_fuse1[\w.]* = \S+ custom-call\(", text)
+
+
 @pytest.mark.parametrize("fuse", [None, 1], ids=["fused", "fuse1"])
 def test_d3q27_cumulant_48x48x256(one_chip, fuse):
     shape = (48, 48, 256)

@@ -71,26 +71,31 @@ _SUPPORTED = ("d3q27_BGK", "d3q27_BGK_galcor", "d3q27_cumulant",
 STORAGE_DTYPES = (jnp.float32, jnp.bfloat16)
 _COMPUTE_DTYPE = jnp.float32
 _VMEM_BUDGET = 15 * 1024 * 1024
-# the fused (K>=2) kernel budgets against a raised Mosaic ceiling: its
-# scratch is deliberately larger (K halo slabs per side, 2 slots) and the
-# widest fused window's collision intermediates (~_TEMP_PLANES stacked
-# q-plane tensors) must coexist with it
+# the fused kernel budgets against a raised Mosaic ceiling: its scratch is
+# deliberately larger (K halo slabs per side, 2 slots) and the widest fused
+# window's collision intermediates must coexist with it
 _FUSED_BUDGET = 80 * 1024 * 1024
 _FUSED_VMEM_LIMIT = 100 * 1024 * 1024
-_TEMP_PLANES = 6
-# the same count for a y-tiled window.  Mosaic's own reports for this
-# kernel (compiled for a described v5e, d3q27_cumulant at 256^3, PR 32)
-# come to 2.5 to 2.7 planes a population at K >= 2 and 1.3 at K = 1;
-# whole planes keep the 6 they were planned with until their plans are
-# measured again (the channel cell's (bz, K) is pinned on it)
-_TILE_TEMP_PLANES = 3
-# what recomputed node steps cost, in the planes of _fused_cost: a tiled
-# window computes (1 + (K-1)/bz) x (by + 2 _HALO_Y)/by node steps for a
-# useful one, at 0.12 to 0.16 ns each for the cumulant collision on a v5e
-# (PR 32's sweep of nine plans at 256^3), which is 21 to 28 planes of DMA
-# traffic at the 86 % of the HBM peak the kernel's copies reach.  The
-# cheaper collisions of the family are not measured: for them this errs
-# toward less recomputation
+# what Mosaic holds beyond the declared scratch and the pipelined out
+# blocks, in f32 planes a population over the widest fused window (bz +
+# 2 (K - 1) slabs).  Read off the chip's compiler (a described v5e: the
+# scoped allocation it reports against a limit it cannot meet; PR 41,
+# the table in PERF.md section 6): whole planes 2.0 to 2.6 for
+# d3q27_cumulant over seven plans at three shapes ((4, 3) at 512 x 48 x
+# 256: 2.52), 2.2 to 2.6 for d3q19 and d3q19_les, 1.6 and 1.8 for the two
+# d3q27 BGK models; y-tiled windows 2.5 to 2.7 at K >= 2 and 1.3 at K = 1
+# (PR 32).  One number for every window of the kernel: the next whole
+# one above the largest reading (the channel's (4, 3) window of 48 x 256
+# planes is 71 MiB by Mosaic's count, 76 by this one)
+_TEMP_PLANES = 3
+# what recomputed node steps cost, in the planes of _fused_cost: a window
+# computes (1 + (K-1)/bz) node steps for a useful one, a y-tiled one
+# (by + 2 _HALO_Y)/by times that, at 0.12 to 0.16 ns each for the
+# cumulant collision on a v5e (PR 32's sweep of nine plans at 256^3),
+# which is 21 to 28 planes of DMA traffic at the 86 % of the HBM peak
+# the kernel's copies reach.  d3q19's node step reads 0.125 to 0.139 ns
+# (PR 41's three whole-plane plans at 512 x 48 x 256): the same; the BGK
+# models' is not measured
 _RECOMPUTE_PLANES = 23
 # wrapped halo rows a side of a y band: one sublane tile, so every DMA
 # window starts on a tile boundary; it covers any K <= fusion.FUSE_MAX
@@ -167,26 +172,48 @@ def _n_zonal(model: Model) -> int:
     return 3 if model.name == "d3q27_cumulant" else 2
 
 
-def _fused_fits(model: Model, nz: int, ny: int, nx: int,
-                bz: int, K: int, itemsize: int = 4,
-                by: Optional[int] = None,
-                budget: int = _FUSED_BUDGET) -> bool:
-    """VMEM predicate for the fused kernel at (bz, K): 2-slot halo'd
-    f+aux buffers + 2-slot flag buffers + pipelined out blocks + the
-    widest fused window's collision intermediates.  The DMA scratch
-    scales with the storage itemsize; the collision temporaries are
-    always compute-dtype (f32) planes.  ``by`` tiles the plane: the
-    windows hold ``by`` rows and ``_HALO_Y`` wrapped halo rows a side,
-    the out blocks ``by`` rows."""
+def _fused_vmem(model: Model, ny: int, nx: int, bz: int, K: int,
+                itemsize: int = 4, by: Optional[int] = None) -> int:
+    """The VMEM the planner counts for the fused kernel at (bz, K), in
+    bytes: 2-slot halo'd f+aux buffers + 2-slot flag buffers + pipelined
+    out blocks + the widest fused window's collision intermediates.  The
+    DMA scratch scales with the storage itemsize; the collision
+    temporaries are always compute-dtype (f32) planes.  ``by`` tiles the
+    plane: the windows hold ``by`` rows and ``_HALO_Y`` wrapped halo rows
+    a side, the out blocks ``by`` rows."""
     ns = model.n_storage
     q = _q_of(model)
     rows, band = (ny, ny) if by is None else (by + 2 * _HALO_Y, by)
     H = bz + 2 * K
     scratch = (2 * ns * H * rows + 2 * ns * bz * band) * nx * itemsize
     flagbuf = 2 * H * rows * nx * 4   # int32 flags, itemsize-invariant
-    temp = (_TEMP_PLANES if by is None else _TILE_TEMP_PLANES) \
-        * q * (bz + 2 * (K - 1)) * rows * nx * 4
-    return scratch + flagbuf + temp <= budget
+    temp = _TEMP_PLANES * q * (bz + 2 * (K - 1)) * rows * nx * 4
+    return scratch + flagbuf + temp
+
+
+def _fused_fits(model: Model, nz: int, ny: int, nx: int,
+                bz: int, K: int, itemsize: int = 4,
+                by: Optional[int] = None,
+                budget: int = _FUSED_BUDGET) -> bool:
+    """VMEM predicate for the fused kernel at (bz, K): whether
+    :func:`_fused_vmem` fits ``budget``."""
+    return _fused_vmem(model, ny, nx, bz, K, itemsize, by) <= budget
+
+
+def _deepest_band(model: Model, nz: int, ny: int, nx: int, K: int,
+                  itemsize: int = 4, by: Optional[int] = None,
+                  budget: int = _FUSED_BUDGET) -> Optional[int]:
+    """The deepest band ``bz`` dividing nz that :func:`_fused_fits`
+    admits at depth K (traffic falls with bz), None where none fits."""
+    return max((b for b in range(1, nz + 1) if nz % b == 0
+                and _fused_fits(model, nz, ny, nx, b, K, itemsize, by,
+                                budget)), default=None)
+
+
+def _wide(by: Optional[int]) -> float:
+    """Rows a window holds for a row of its band: a y-tiled window reads
+    ``_HALO_Y`` wrapped halo rows a side, a whole plane none."""
+    return 1.0 if by is None else (by + 2 * _HALO_Y) / by
 
 
 def _fused_cost(model: Model, bz: int, K: int,
@@ -196,15 +223,16 @@ def _fused_cost(model: Model, bz: int, K: int,
     (and, tiled, ``_HALO_Y`` halo rows a side of ``by``), the ns output
     planes written halo-free, all amortized over K steps."""
     ns = model.n_storage
-    wide = 1.0 if by is None else (by + 2 * _HALO_Y) / by
-    return ((ns + 1) * (bz + 2 * K) * wide + ns * bz) / (K * bz)
+    return ((ns + 1) * (bz + 2 * K) * _wide(by) + ns * bz) / (K * bz)
 
 
-def _tile_cost(model: Model, bz: int, K: int, by: int) -> float:
-    """What decides between tiled plans: the traffic of
+def _tile_cost(model: Model, bz: int, K: int,
+               by: Optional[int] = None) -> float:
+    """What decides between the plans of the fused kernel, whole planes
+    (``by`` None: no halo rows) and y-tiled windows alike: the traffic of
     :func:`_fused_cost` or the arithmetic of the node steps a window
     computes more than once, whichever binds."""
-    again = (1.0 + (K - 1) / bz) * (by + 2 * _HALO_Y) / by
+    again = (1.0 + (K - 1) / bz) * _wide(by)
     return max(_fused_cost(model, bz, K, by), _RECOMPUTE_PLANES * again)
 
 
@@ -238,7 +266,8 @@ def fused_cfg_explain(model: Model, shape, itemsize: int = 4
     """Planner verdict WITH its reason: ``((bz, K), None)`` when a fused
     config wins, else ``(None, reason)`` naming the failing predicate
     term — either no (bz, K) fits ``_FUSED_BUDGET`` (VMEM) or the best
-    feasible fused traffic does not beat the single-step engine (cost).
+    feasible fused plan does not beat the single-step engine (cost:
+    :func:`_tile_cost`, the rule that also plans the y-tiled windows).
     The Lattice dispatch forwards the reason as a ``fused_rejected``
     telemetry event so a silent single-step demotion (once seen as an
     untagged d3q27 engine) can never recur unnoticed."""
@@ -249,29 +278,27 @@ def fused_cfg_explain(model: Model, shape, itemsize: int = 4
     cfg = fusion.choose_fuse_slab(
         nz,
         lambda bz, K: _fused_fits(model, nz, ny, nx, bz, K, itemsize),
-        lambda bz, K: _fused_cost(model, bz, K),
+        lambda bz, K: _tile_cost(model, bz, K),
         base)
     if cfg is not None:
         return cfg, None
     # no K >= 2 selected: re-walk the search recording WHY
-    feasible = []
-    for K in range(2, fusion.FUSE_MAX + 1):
-        if nz < 2 * K:
-            break
-        bzs = [bz for bz in range(1, nz + 1) if nz % bz == 0
-               and _fused_fits(model, nz, ny, nx, bz, K, itemsize)]
-        if bzs:
-            feasible.append((max(bzs), K))
+    feasible = [(bz, K) for K in range(2, fusion.FUSE_MAX + 1)
+                if nz >= 2 * K
+                for bz in [_deepest_band(model, nz, ny, nx, K, itemsize)]
+                if bz]
     if not feasible:
         return None, (
             f"vmem: no (bz, K) fits _FUSED_BUDGET="
             f"{_FUSED_BUDGET // (1024 * 1024)}MB at shape "
-            f"{(nz, ny, nx)} (scratch + {_TEMP_PLANES} temp planes/q)")
+            f"{(nz, ny, nx)} (scratch + {_TEMP_PLANES} temp planes/q over "
+            f"the widest window, the next whole number above what "
+            f"Mosaic holds)")
     bz_b, K_b = min(feasible,
-                    key=lambda c: _fused_cost(model, c[0], c[1]))
+                    key=lambda c: _tile_cost(model, c[0], c[1]))
     return None, (
         f"cost: best fused (bz={bz_b}, K={K_b}) models "
-        f"{_fused_cost(model, bz_b, K_b):.2f} planes/step >= "
+        f"{_tile_cost(model, bz_b, K_b):.2f} planes/step >= "
         f"single-step {base:.2f}")
 
 
@@ -315,13 +342,10 @@ def tile_plan(model: Model, shape, itemsize: int = 4,
             break
         for by in bys:
             tile = None if by == ny else by
-            bz = max((b for b in range(1, nz + 1) if nz % b == 0
-                      and _fused_fits(model, nz, ny, nx, b, K, itemsize,
-                                      tile, budget)), default=None)
+            bz = _deepest_band(model, nz, ny, nx, K, itemsize, tile, budget)
             if bz is None:
                 continue
-            c = _fused_cost(model, bz, K) if tile is None \
-                else _tile_cost(model, bz, K, by)
+            c = _tile_cost(model, bz, K, tile)
             if c < best_c:
                 best, best_c = (bz, by, K), c
     return best
@@ -422,10 +446,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         if fuse >= 2:
             bzf = fuse_bz
             if bzf is None:
-                bzf = max(b for b in range(1, nz + 1) if nz % b == 0
-                          and (b == 1
-                               or _fused_fits(model, nz, ny, nx, b, fuse,
-                                              itemsize)))
+                bzf = _deepest_band(model, nz, ny, nx, fuse, itemsize) or 1
             if nz % bzf:
                 raise ValueError(f"fused band depth {bzf} must divide {nz}")
             cfg = (bzf, fuse)
@@ -1079,7 +1100,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 z_bands=nz // bzp, band_slabs=bzp, halo_slabs=K,
                 y_bands=ny // byp, band_rows=byp,
                 halo_rows=_HALO_Y if byp < ny else 0,
-                aux_planes=1)      # the int32 flag plane rides each window
+                aux_planes=1,      # the int32 flag plane rides each window
+                # what the planner's account admitted the window at
+                vmem_bytes=_fused_vmem(model, ny, nx, bzp, K, itemsize,
+                                       byp if byp < ny else None))
         return did
 
     def iterate(state: LatticeState, params: SimParams, niter: int

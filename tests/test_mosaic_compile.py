@@ -161,14 +161,12 @@ def test_d3q27_cumulant_48x48x256(one_chip, fuse):
                      % (fuse or r"[2-9]"), text)
 
 
-def _tgv_256(one_chip):
-    """The Taylor-Green box by shapes only (a 256^3 state is 2.3 GB):
-    every node collides."""
+def _by_shapes(one_chip, shape, present, **settings):
+    """A ``d3q27_cumulant`` box by shapes only (a 256^3 state is 2.3 GB,
+    the channel cell's 0.86)."""
     from tclb_tpu.core.lattice import LatticeState
-    shape = (256, 256, 256)
     m = get_model("d3q27_cumulant")
-    small = Lattice(m, (8, 8, 128), dtype=jnp.float32,
-                    settings={"nu": 0.001273})
+    small = Lattice(m, (8, 8, 128), dtype=jnp.float32, settings=settings)
 
     def on_chip(x, dims=None):
         return jax.ShapeDtypeStruct(dims or x.shape, x.dtype,
@@ -178,7 +176,19 @@ def _tgv_256(one_chip):
         fields=on_chip(st.fields, (m.n_storage,) + shape),
         flags=on_chip(st.flags, shape), globals_=on_chip(st.globals_),
         iteration=on_chip(st.iteration))
-    return m, shape, state, jax.tree.map(on_chip, small.params), {"MRT"}
+    return m, shape, state, jax.tree.map(on_chip, small.params), present
+
+
+def _tgv_256(one_chip):
+    """The Taylor-Green box: every node collides."""
+    return _by_shapes(one_chip, (256, 256, 256), {"MRT"}, nu=0.001273)
+
+
+def _channel_512(one_chip):
+    """``example/3d_channel_512.xml``: the walled, force-driven channel
+    of the two ``channel3d512`` cells."""
+    return _by_shapes(one_chip, (512, 48, 256), {"MRT", "Wall"},
+                      nu=0.01, ForceX=1e-5)
 
 
 def test_d3q27_cumulant_256_tiled(one_chip):
@@ -215,20 +225,32 @@ def _computations(text: str) -> dict:
 
 
 @pytest.mark.parametrize("case,fuse", [
-    ("channel", None), ("channel", 1), ("tgv256", None)],
-    ids=["channel-fused", "channel-fuse1", "tgv256-tiled"])
+    ("channel", None), ("channel", 1), ("tgv256", None),
+    ("channel512", None)],
+    ids=["channel-fused", "channel-fuse1", "tgv256-tiled",
+         "channel512-fused"])
 def test_d3q_loop_body_pairs_the_calls_and_copies_no_state(one_chip, case,
                                                            fuse):
     """The loop that carries the state through the kernel holds two
     kernel calls a body, so the call that writes the carry is not the one
     that reads it, and XLA puts no copy of the whole state before the
     kernel.  Five calls of the looped kernel: two trips and an odd call
-    after the loop; one step over where the depth allows one."""
+    after the loop; one step over where the depth allows one.  The two
+    cells' own shapes compile under the planner's own plans, the ones
+    ``PERF.md`` records: whole planes in windows of (bz, K) = (4, 3) at
+    512 x 48 x 256 (the buffer ``tgv256``'s (4, 32, 3) holds, to the
+    byte), which the temporaries Mosaic holds leave room for."""
     if case == "channel":
         shape = (48, 48, 256)
         m, lat, present = _channel("d3q27_cumulant", shape, nu=0.01)
     else:
-        m, shape, state, params, present = _tgv_256(one_chip)
+        m, shape, state, params, present = (
+            _tgv_256 if case == "tgv256" else _channel_512)(one_chip)
+    if case == "tgv256":
+        assert pallas_d3q.tile_plan(m, shape) == (4, 32, 3)
+    if case == "channel512":
+        assert pallas_d3q.tile_plan(m, shape) is None
+        assert pallas_d3q.fused_cfg(m, shape) == (4, 3)
     it = pallas_d3q.make_pallas_iterate(m, shape, jnp.float32,
                                         interpret=False, present=present,
                                         fuse=fuse)
@@ -237,11 +259,16 @@ def test_d3q_loop_body_pairs_the_calls_and_copies_no_state(one_chip, case,
     niter = 5 * K + (K >= 2)
     did = it.account(niter)
     assert did["kernel_calls"] == 5 + (K >= 2) and did["paired_calls"] == 4
+    if case == "channel512":
+        assert (did["band_slabs"], did["halo_slabs"], did["z_bands"],
+                did["y_bands"], did["halo_rows"]) == (4, 3, 128, 1, 0)
+        assert did["vmem_bytes"] <= pallas_d3q._FUSED_BUDGET
     if case == "channel":
         text = _compile(it, lat, niter, one_chip)
     else:
         text = jax.jit(lambda s, p: it(s, p, niter)).lower(
             state, params).compile().as_text()
+    assert re.search(r"d3q_(slab|ring)_fuse%d/pallas_call" % K, text)
     kernel = re.compile(r"= \S+ custom-call\(.*d3q_(slab|ring)_fuse%d/" % K)
     bodies = [lines for name, lines in _computations(text).items()
               if any(kernel.search(line) for line in lines)

@@ -364,6 +364,10 @@ def test_fused_rejected_event(monkeypatch, tmp_path):
 TILED_SHAPE = (12, 64, 64)
 TILED_VMEM = {"d3q19": 6_000_000, "d3q27_BGK": 8_000_000,
               "d3q27_cumulant": 12_000_000}
+# at K = 1 the cumulant's 12 MB hold two whole planes a window (counted
+# with the 3 temporary planes Mosaic holds, not 6), and a whole plane
+# moves less than any tiling: this is what tiles the K = 1 plan too
+TILED_VMEM_K1 = 5_500_000
 
 
 def _tiled_lat(name, wall):
@@ -406,7 +410,7 @@ def test_y_tiled_bit_exact_vs_xla(name, K, wall, niter):
     and with a wall on the seam: the tiling comes from the planner, given
     less VMEM than a whole plane takes, not from a knob."""
     m, lat, flags = _tiled_lat(name, wall)
-    budget = TILED_VMEM[name]
+    budget = TILED_VMEM_K1 if K == 1 else TILED_VMEM[name]
     bz, by, k = pallas_d3q.tile_plan(m, TILED_SHAPE, 4, K, budget)
     assert k == K and by < TILED_SHAPE[1] and by % 8 == 0
     assert pallas_d3q._fused_fits(m, *TILED_SHAPE, bz, K, by=by,
@@ -430,7 +434,9 @@ def test_y_tiled_bit_exact_vs_xla(name, K, wall, niter):
     (8, None, 15, 1, 7, 6),         # a remainder loop of three pairs
     (8, None, 47, 5, 7, 4 + 6),
     (1, None, 5, 0, 5, 4),          # fuse=1: the ring/block kernel's loop
-    (1, 12_000_000, 5, 5, 0, 4),    # y-tiled at K = 1: the fused kernel's
+    (1, 12_000_000, 5, 5, 0, 4),    # a plane only the fused kernel's K = 1
+                                    # plan takes: windows of 2 whole planes
+    (1, 5_500_000, 5, 5, 0, 4),     # y-tiled at K = 1
     (3, 12_000_000, 14, 4, 2, 4)])
 def test_account_counts_the_paired_calls(fuse, budget, niter, fused, rest,
                                          paired):
@@ -456,41 +462,53 @@ def test_y_tiled_needs_a_plan():
                                        vmem_budget=200_000)
 
 
-def test_channel_plan_slab_by_slab_halo():
-    """The channel cell's plan in small, (bz, K) = (2, 3): a halo deeper
-    than the band is copied slab by slab, each index wrapped."""
-    m, lat, flags = _fused_lat("d3q27_cumulant")
+@pytest.mark.parametrize("name,bz", [
+    ("d3q27_cumulant", 2), ("d3q27_cumulant", 4), ("d3q19", 4)])
+def test_channel_plan_slab_by_slab_halo(name, bz):
+    """The channel cell's plans in small at ``fuse=3``: (bz, K) = (2, 3),
+    the plan until PR 41, whose halo is deeper than the band and is
+    copied slab by slab, each index wrapped; and (4, 3), the plan since,
+    whose halo goes as one block a side.  Both bit-identical to the XLA
+    step: the plan is a shape, not a result."""
+    m, lat, flags = _fused_lat(name)
     it = pallas_d3q.make_pallas_iterate(
         m, FUSED_SHAPE, present=pallas_d3q.present_types(m, flags),
-        fuse=3, fuse_bz=2)
+        fuse=3, fuse_bz=bz)
+    did = it.account(7)
+    assert (did["band_slabs"], did["halo_slabs"], did["z_bands"]) \
+        == (bz, 3, FUSED_SHAPE[0] // bz)
+    assert did["vmem_bytes"] == pallas_d3q._fused_vmem(
+        m, *FUSED_SHAPE[1:], bz, 3)
     s_p = it(jax.tree.map(jnp.copy, lat.state), lat.params, 7)
     s_x = lat._iterate(lat.state, lat.params, 7)
     np.testing.assert_array_equal(np.asarray(s_p.fields),
                                   np.asarray(s_x.fields))
 
 
-@pytest.mark.parametrize("shape,whole,ext", [
-    ((512, 48, 256), (2, 3), True),      # channel3d512: the parent's plan
-    ((48, 48, 256), (3, 2), True),       # 3d_channel.xml: the parent's
-    ((256, 128, 128), (2, 2), False),    # a ring-only plane, still whole
-    ((256, 256, 256), None, False),      # tgv256: tiled
-    ((128, 128, 256), None, False)])
-def test_planner_keeps_whole_planes_and_tiles_the_rest(shape, whole, ext):
-    """Where a whole plane fits a single-step kernel the plan is what it
-    was, to the tuple; where none does the tiled plan passes the
-    planner's own VMEM account; the sharded building block stays
-    whole-plane."""
+@pytest.mark.parametrize("shape,want,ext", [
+    ((512, 48, 256), (4, 3), True),      # channel3d512: (2, 3) until PR 41
+    ((48, 48, 256), (4, 3), True),       # 3d_channel.xml: (3, 2) until then
+    ((256, 128, 128), (2, 3), False),    # a ring-only plane, whole: (2, 2)
+    ((256, 256, 256), (4, 32, 3), False),     # tgv256: tiled, as it was
+    ((128, 128, 256), (4, 32, 3), False)])
+def test_planner_keeps_whole_planes_and_tiles_the_rest(shape, want, ext):
+    """Where a whole plane fits a single-step kernel the plan is the one
+    ``PERF.md`` records (section 6, PR 41: planned with the temporaries
+    Mosaic holds), to the tuple; where none does the tiled plan is what
+    it was and passes the planner's own VMEM account; the sharded
+    building block stays whole-plane."""
     m = get_model("d3q27_cumulant")
     nz, ny, nx = shape
     assert pallas_d3q.supports(m, shape, jnp.float32)
     assert pallas_d3q.supports(m, shape, jnp.float32, ext_halo=True) == ext
     plan = pallas_d3q.tile_plan(m, shape)
-    if whole is not None:
+    if len(want) == 2:          # (bz, K): whole planes
         assert plan is None
-        assert pallas_d3q.fused_cfg(m, shape) == whole
-        assert pallas_d3q._fused_fits(m, nz, ny, nx, *whole)
-        assert pallas_d3q.choose_fuse(m, shape) == whole[1]
+        assert pallas_d3q.fused_cfg(m, shape) == want
+        assert pallas_d3q._fused_fits(m, nz, ny, nx, *want)
+        assert pallas_d3q.choose_fuse(m, shape) == want[1]
         return
+    assert plan == want
     bz, by, K = plan
     assert K >= 2 and nz % bz == 0 and ny % by == 0 and by % 8 == 0
     assert by < ny
@@ -509,3 +527,37 @@ def test_planner_keeps_whole_planes_and_tiles_the_rest(shape, whole, ext):
             if f.check == "resources.fused_slab" and "tuned" in f.message]
     assert [(f.details["bz"], f.details["by"], f.details["fuse"])
             for f in said] == [(bz, by, K)]
+
+
+@pytest.mark.parametrize("shape", [
+    (512, 48, 256), (48, 48, 256), (256, 128, 128), (64, 64, 128)])
+@pytest.mark.parametrize("name", pallas_d3q._SUPPORTED)
+def test_whole_planes_are_planned_by_the_tiled_cost(name, shape):
+    """One rule plans every window of the fused kernel: a whole-plane
+    plan is the least of ``_tile_cost`` (no halo rows) over the deepest
+    band of every depth, so a cheaper VMEM account never buys depth
+    whose recomputed node steps cost more than the bytes it saves: no
+    shallower feasible plan is cheaper than the one chosen, and a deeper
+    one only where it is no dearer by bytes and by arithmetic both."""
+    m = get_model(name)
+    nz = shape[0]
+    bz, K = pallas_d3q.fused_cfg(m, shape)
+    # what the planner chooses among: the deepest band of every depth
+    plans = {k: pallas_d3q._deepest_band(m, *shape, k)
+             for k in range(2, pallas_d3q.fusion.FUSE_MAX + 1)
+             if nz >= 2 * k}
+    plans = {k: b for k, b in plans.items() if b}
+    assert plans[K] == bz
+    cost = {k: pallas_d3q._tile_cost(m, b, k) for k, b in plans.items()}
+    assert cost[K] == min(cost.values())
+    assert all(cost[k] > cost[K] for k in plans if k < K)
+    # the rule is the tiled windows' own, with no halo rows
+    assert pallas_d3q._tile_cost(m, bz, K) == max(
+        pallas_d3q._fused_cost(m, bz, K),
+        pallas_d3q._RECOMPUTE_PLANES * (1.0 + (K - 1) / bz))
+    # bytes alone would go deeper at the channel's shape for the 19
+    # populations: (4, 6), 2.25 node steps a useful one
+    if name == "d3q19" and shape == (512, 48, 256):
+        by_bytes = min(plans, key=lambda k: pallas_d3q._fused_cost(
+            m, plans[k], k))
+        assert (plans[by_bytes], by_bytes) == (4, 6) and K < by_bytes

@@ -15,6 +15,7 @@ from benchmark.reference import d3q27_cumulant_tgv as reference
 from tclb_tpu import telemetry
 from tclb_tpu.control.solver import run_config_string
 from tclb_tpu.models import get_model
+from tclb_tpu.ops import pallas_d3q
 
 SEED = 2**31 + 32
 STEPS = 50
@@ -109,8 +110,10 @@ def test_tuned_engine_float32_and_the_span(tmp_path, monkeypatch):
         == [("tclb_tpu.control.initial", "taylor_green")]
     assert spans[0]["dur_s"] > 0
     # the engine says what its calls were, on the innermost span open
-    # round them (the first call's probe): 19 steps at fuse 8 are two
-    # fused calls on 2 bands of 8 whole planes and three steps over
+    # round them (the first call's probe): 19 steps at fuse 4 (the depth
+    # whose recomputed node steps and bytes balance: 8 computes 1.44 node
+    # steps a useful one) are four fused calls on one band of 16 whole
+    # planes and three steps over
     said = [e for e in seen if e.get("kind") == "span"
             and "kernel_calls" in e]
     assert [e["name"] for e in said] == ["engine.probe"]
@@ -118,6 +121,8 @@ def test_tuned_engine_float32_and_the_span(tmp_path, monkeypatch):
         "kernel_calls", "remainder_steps", "paired_calls", "z_bands",
         "band_slabs", "halo_slabs", "y_bands", "band_rows", "halo_rows",
         "aux_planes")} \
-        == dict(kernel_calls=5, remainder_steps=3, paired_calls=0, z_bands=1,
-                band_slabs=16, halo_slabs=8, y_bands=1, band_rows=16,
+        == dict(kernel_calls=7, remainder_steps=3, paired_calls=4, z_bands=1,
+                band_slabs=16, halo_slabs=4, y_bands=1, band_rows=16,
                 halo_rows=0, aux_planes=1)
+    assert said[0]["vmem_bytes"] == pallas_d3q._fused_vmem(
+        get_model("d3q27_cumulant"), 16, 16, 16, 4)

@@ -497,7 +497,7 @@ def one_chip(s: Smoke) -> None:
     # the registry-driven generic engine, band or VMEM-resident flavour
     # (at 512x512 the Lattice picks the resident one)
     generic = ("pallas_generic[d2q9_kuper,fuse=",
-               "pallas_resident_generic[d2q9_kuper,fuse=")
+               "pallas_resident_generic[d2q9_kuper]")
     cumulant = ("pallas_d3q[d3q27_cumulant,fuse=",)
     s.phase("2d_karman_as_shipped", s.run, s.case("karman.xml"),
             ("pallas_resident[d2q9,", "pallas_2d[d2q9,"))

@@ -1157,11 +1157,15 @@ class Lattice:
                if model.ndim == 3 else pallas_generic.choose_fuse(model))
         if fits_resident:
             # generic counterpart of the tuned d2q9 resident engine
-            # (checked above): whole lattice VMEM-resident, 8 steps per
-            # kernel call, for ANY registry model that fits the budget.
+            # (checked above): whole lattice VMEM-resident for ANY
+            # registry model that fits the budget.  ONE kernel call
+            # advances a whole iterate(n) (all but the one or two steps
+            # left to the band engine's globals flavour), so the tag
+            # states no fuse depth and every distinct n is a program of
+            # its own (counter engine.resident_programs).
             # First call is probed; each resident flavour steps down to
             # ITS band family: here the generic band as planned
-            return [cand(f"pallas_resident_generic[{name},fuse=8]",
+            return [cand(f"pallas_resident_generic[{name}]",
                          pallas_generic.make_resident_iterate, probe=True,
                          shift=shift), band(fz0, None)]
         # the trace probe of supports() cannot see Mosaic lowering gaps

@@ -981,11 +981,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
 
 # --------------------------------------------------------------------------- #
-# Generic VMEM-resident engine (2D): whole lattice on-chip, FUSE_R steps
-# per kernel launch
+# Generic VMEM-resident engine (2D): whole lattice on-chip, one kernel
+# launch per iterate(n)
 # --------------------------------------------------------------------------- #
 
-_RESIDENT_FUSE = 8       # steps per kernel call (EVEN: ping-pong parity)
 _RESIDENT_BUDGET = 72 * 1024 * 1024   # state+aux residency budget (v5e
 #                          VMEM is 128 MiB; the rest holds the chunk
 #                          temporaries Mosaic scopes)
@@ -1029,11 +1028,16 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
                           present: Optional[set] = None,
                           chunk_cap: int = 64,
                           shift: Optional[np.ndarray] = None):
-    """Generic VMEM-resident engine: ``_RESIDENT_FUSE`` full lattice
-    steps per kernel launch with the state ping-ponging between two
-    on-chip stacks — HBM traffic (1R+1W)/FUSE per step and ONE kernel
-    launch per FUSE steps (the band engines pay a launch per 1-2 steps,
-    measured ~40 us of gap each on v5e).
+    """Generic VMEM-resident engine: ONE kernel launch advances a whole
+    ``iterate(n)``: ``resident_length(n)`` steps (an even length that
+    leaves the band engine's globals flavour a step where the model
+    declares Globals) ride the kernel's grid with the state ping-ponging
+    between two on-chip stacks, and the one or two steps left over run on
+    the fuse-1 band kernel.  HBM traffic is one read and one write of
+    the state a call, whatever its length (the band engines pay a launch
+    per 1-2 steps, measured ~40 us of gap each on v5e).  The grid is
+    static, so every distinct length is a program of its own, compiled
+    at its first call: counter ``engine.resident_programs``.
 
     Physics is the SAME ``run_action_plan`` trace as the band kernels,
     applied to row chunks of the resident stack; chunk halos are sliced
@@ -1121,6 +1125,9 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 
     @lru_cache(maxsize=None)
     def _call_for(nsteps: int):
+        # runs once a length (while _resident_jit traces it): a mix of
+        # segment lengths shows its compiles under the engine's name
+        telemetry.counter("engine.resident_programs")
         return pl.pallas_call(
             lbm.mosaic_body(kernel, interpret),
             grid=(nsteps,),

@@ -51,10 +51,11 @@ INHERITED = ("iteration", "job_id")
 
 def fuse_of(engine: Optional[str]) -> int:
     """Temporal-fusion depth encoded in an engine name (the
-    ``,fuse=K`` tag every fused engine carries, e.g.
-    ``pallas_d3q[d3q19,fuse=3]``); 1 when absent (XLA, unfused
-    engines).  The ``iterate`` span records the depth it reads from the
-    tag through this."""
+    ``,fuse=K`` tag of an engine that runs K steps a kernel call, e.g.
+    ``pallas_d3q[d3q19,fuse=3]``); 1 when absent (XLA, unfused engines,
+    and ``pallas_resident_generic[...]``, whose one call is a whole
+    ``iterate(n)``: its account says the length).  The ``iterate`` span
+    records the depth it reads from the tag through this."""
     if not engine:
         return 1
     m = re.search(r"[\[,]fuse=(-?\d+)", engine)

@@ -146,7 +146,7 @@ def test_generic_resident_dispatch_matches_xla(monkeypatch):
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     lat_f = build()
     lat_f.iterate(niter)
-    assert lat_f._fast_name == "pallas_resident_generic[d2q9_heat,fuse=8]"
+    assert lat_f._fast_name == "pallas_resident_generic[d2q9_heat]"
 
     monkeypatch.setenv("TCLB_FASTPATH", "0")
     lat_x = build()

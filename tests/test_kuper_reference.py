@@ -37,6 +37,15 @@ STEPS32 = 23
 TOL32 = 1e-5
 
 
+MODEL_XML = """    <Model>
+        <Params omega="1"/>
+        <Params Density="3.2600529440452366"
+                Density-zdrop="0.014500641645077492"
+                Temperature="0.56" FAcc="1" Magic="0.01"
+                MagicA="-0.152" MagicF="-0.6666666666666"/>
+    </Model>"""
+
+
 def case_xml(tail: str = "") -> str:
     ox, oy, d = np.random.default_rng(SEED).integers(-4, 5, 3)
     return f"""<CLBConfig version="2.0" model="d2q9_kuper" output="output/">
@@ -46,13 +55,7 @@ def case_xml(tail: str = "") -> str:
             <Sphere dx="{44 + ox}" nx="{36 + d}" dy="{14 + oy}" ny="{36 + d}"/>
         </None>
     </Geometry>
-    <Model>
-        <Params omega="1"/>
-        <Params Density="3.2600529440452366"
-                Density-zdrop="0.014500641645077492"
-                Temperature="0.56" FAcc="1" Magic="0.01"
-                MagicA="-0.152" MagicF="-0.6666666666666"/>
-    </Model>{tail}
+{MODEL_XML}{tail}
 </CLBConfig>"""
 
 
@@ -64,8 +67,8 @@ def solver_of(dtype, tmp_path, steps=None):
                              dtype=dtype, output=str(tmp_path) + "/")
 
 
-def worst(program, ref) -> float:
-    assert program.shape == ref.shape == (10,) + SHAPE
+def worst(program, ref, shape=SHAPE) -> float:
+    assert program.shape == ref.shape == (10,) + shape
     return float(np.abs(program.astype(np.float64) - ref).max())
 
 
@@ -133,7 +136,7 @@ def test_pallas_float32_against_the_reference(engine, reference32, tmp_path,
     assert worst(np.asarray(lat.state.fields), start) < 1e-6
     if engine == "resident":
         lat.iterate(STEPS32)
-        assert lat._fast_name == "pallas_resident_generic[d2q9_kuper,fuse=8]"
+        assert lat._fast_name == "pallas_resident_generic[d2q9_kuper]"
         state = lat.state
     else:
         fuse = int(engine[-1])
@@ -150,9 +153,110 @@ def test_pallas_float32_against_the_reference(engine, reference32, tmp_path,
     assert abs(mass(program) - mass(start)) < 1e-5 * mass(start)
 
 
+# the resident engine cuts its rows into 64-row chunks of its own, each
+# with halo rows pulled from its neighbours on-chip: at 128 x 128 two of
+# them, which the 64-row case above (one chunk) never compares
+SHAPE2 = (128, 128)
+
+
+def two_chunk_xml() -> str:
+    """One drop across the seam of the two chunks (rows 63 | 64), seeded,
+    and four small ones on the box's corners: vapour faces vapour across
+    the periodic wrap in y (rows 127 | 0, the first chunk's upper halo
+    and the last one's lower) and in x."""
+    ox, oy, d = np.random.default_rng(SEED + 14).integers(-4, 5, 3)
+    return f"""<CLBConfig version="2.0" model="d2q9_kuper" output="output/">
+    <Geometry nx="{SHAPE2[1]}" ny="{SHAPE2[0]}">
+        <MRT><Box/></MRT>
+        <None name="zdrop">
+            <Sphere dx="{44 + ox}" nx="{40 + d}" dy="{44 + oy}" ny="{40 + d}"/>
+            <Sphere dx="0" nx="24" dy="0" ny="20"/>
+            <Sphere dx="0" nx="24" dy="108" ny="20"/>
+            <Sphere dx="104" nx="24" dy="0" ny="20"/>
+            <Sphere dx="104" nx="24" dy="108" ny="20"/>
+        </None>
+    </Geometry>
+{MODEL_XML}
+</CLBConfig>"""
+
+
+@pytest.mark.parametrize("steps,main", [(23, 22), (24, 22)])
+def test_resident_two_chunks_against_the_reference(steps, main, tmp_path,
+                                                   monkeypatch):
+    """The generic resident engine as ``_build_fast`` gives it, on two
+    chunks: an odd call is one resident call of ``steps - 1`` and one
+    band step, an even one ``steps - 2`` and two (both branches of
+    ``resident_length``; the band remainder runs either way).  Both read
+    1.07e-6 against the float32 reference (the single-chunk case 9.5e-7):
+    ``TOL32``, for the reason given beside it."""
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    xml = two_chunk_xml()
+    root = ET.fromstring(xml)
+    inside = zones.paint(root.find("Geometry"))["zone"] == 1
+    assert inside[63].any() and inside[64].any()        # the seam
+    assert inside[0].any() and inside[127].any()        # the wrap in y
+    assert inside[:, 0].any() and inside[:, 127].any()  # and in x
+    lat = run_config_string(xml, get_model("d2q9_kuper"), dtype=jnp.float32,
+                            output=str(tmp_path) + "/").lattice
+    start = reference.run(root, 0, jnp.float32)
+    assert worst(np.asarray(lat.state.fields), start, SHAPE2) < 1e-6
+    lat.iterate(steps)
+    assert lat._fast_name == "pallas_resident_generic[d2q9_kuper]"
+    did = lat._fast.account(steps)
+    assert (did["resident_calls"], did["resident_steps"],
+            did["remainder_steps"], did["chunk_rows"]) \
+        == (1, main, steps - main, 64)
+    program = np.asarray(lat.state.fields)
+    assert np.isfinite(program).all()
+    ref = reference.run(root, steps, jnp.float32)
+    assert worst(start, ref, SHAPE2) > 0.1              # it has moved
+    assert worst(program, ref, SHAPE2) < TOL32
+    assert abs(mass(program) - mass(start)) < 1e-5 * mass(start)
+
+
+def test_the_drop512_draws_stay_in_the_box():
+    """``benchmark/cases/drop512.xml`` under ``traffic/relax.json``: the
+    template is ``example/drop_512.xml`` without its handlers, and for
+    every draw the drop (diameter 160 to 224 nodes, low corner 128 to
+    192) lies between 128 and 416 of the 512 nodes of each axis, which
+    the reference's painter would refuse otherwise."""
+    from benchmark import casegen
+    template = os.path.join(ROOT, "benchmark", "cases", "drop512.xml")
+    shipped = ET.parse(os.path.join(ROOT, "example", "drop_512.xml")
+                       ).getroot()
+    mine = ET.parse(template).getroot()
+
+    def plain(el):
+        return (el.tag, dict(el.attrib), [plain(k) for k in el])
+    for tag in ("Geometry", "Model"):
+        assert plain(mine.find(tag)) == plain(shipped.find(tag))
+    assert [el.tag for el in mine] == ["Geometry", "Model"]
+    traffic = casegen.load_json("traffic", "relax")
+    lo = {r["attr"]: r["int"][0] for r in traffic["seeded"]}
+    hi = {r["attr"]: r["int"][1] for r in traffic["seeded"]}
+    sphere = mine.find("Geometry/None/Sphere")
+    for axis in ("x", "y"):
+        d, n = int(sphere.get("d" + axis)), int(sphere.get("n" + axis))
+        assert (d, n) == (160, 192)
+        assert d + lo["d" + axis] == 128
+        assert d + hi["d" + axis] + n + hi["n" + axis] == 416
+        assert (n + lo["n" + axis], n + hi["n" + axis]) == (160, 224)
+    seen = set()
+    for seed in list(range(24)) + [2**31 + 99, 4200000101]:
+        root, drawn = casegen.generate(template, traffic, seed)
+        seen.add(tuple(sorted(drawn.items())))
+        zone = zones.paint(root.find("Geometry"))["zone"]
+        rows, cols = zone.nonzero()
+        assert 128 <= rows.min() and rows.max() < 416
+        assert 128 <= cols.min() and cols.max() < 416
+        assert rows.max() - rows.min() + 1 == 192 + drawn["d"]
+        assert cols.max() - cols.min() + 1 == 192 + drawn["d"]
+    assert len(seen) > 20
+
+
 @pytest.mark.parametrize("example,tag", [
     ("drop_1024.xml", "pallas_generic[d2q9_kuper,fuse=4]"),
-    ("drop_512.xml", "pallas_resident_generic[d2q9_kuper,fuse=8]")])
+    ("drop_512.xml", "pallas_resident_generic[d2q9_kuper]")])
 def test_build_fast_picks_the_engine(example, tag, monkeypatch):
     """What ``tclb run example/<example>`` gets on the chip, from
     ``_build_fast`` alone: nothing is built but the engine."""

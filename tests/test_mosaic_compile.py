@@ -339,6 +339,56 @@ def test_generic_512(one_chip, name):
     assert "generic_band_fuse1/pallas_call" in text
 
 
+def test_generic_resident_drop_512(one_chip, monkeypatch):
+    """``example/drop_512.xml``, upstream's drop at its own 512 x 512
+    (the configuration ``drop512``): the state fits the generic
+    VMEM-resident engine's budget, the dispatch puts that engine first
+    and probes it, with the generic band engine under it, and what the
+    probe would compile compiles for the chip: one kernel whose grid is
+    the call's steps, eight unrolled 64-row chunks a grid step, and the
+    fuse-1 band kernel for the two steps an even call leaves over.  The
+    grid is short here (6 steps: 4 resident, 2 left over); the body is
+    the one ``iterate(500)`` runs 498 times.  (106 s on this host with
+    nothing beside it, nearly all of it the resident kernel: three
+    parity branches of eight two-stage chunk bodies.  Under the two
+    minutes the issue allows, so the real shape is compiled and not a
+    smaller one of two chunks.)"""
+    shape = (512, 512)
+    m = get_model("d2q9_kuper")
+    lat = Lattice(m, shape, dtype=jnp.float32)
+    flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
+    rows, cols = np.mgrid[0:512, 0:512]
+    drop = (rows - 255.5) ** 2 + (cols - 255.5) ** 2 < 96 ** 2
+    flags[drop] |= 1 << m.zone_shift        # the zone of Density-zdrop
+    lat.set_flags(flags)
+    lat.init()
+    assert pallas_generic.supports_resident(m, shape, jnp.float32)
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    chain = lat._build_fast()
+    assert [(c.tag, c.probe) for c in chain] == [
+        ("pallas_resident_generic[d2q9_kuper]", True),
+        ("pallas_generic[d2q9_kuper,fuse=4]", False)]
+    it = pallas_generic.make_resident_iterate(
+        m, shape, jnp.float32, interpret=False,
+        present=lbm.present_types(m, flags))
+    # a <Log Iterations="500"> segment, as the cell drop512.relax runs it
+    assert it.account(500) == dict(
+        kernel_calls=3, resident_calls=1, resident_steps=498,
+        remainder_steps=2, aux_planes=2, remainder_aux_planes=1,
+        chunk_rows=64, vmem_bytes=23_068_672, stages_per_step=2,
+        bands=16, band_rows=32, halo_rows=8, pad_rows=0)
+    assert it.account(6)["resident_steps"] == 4
+    text = _compile(it, lat, 6, one_chip)
+    assert "tpu_custom_call" in text
+    assert "generic_resident_fuse4/pallas_call" in text
+    assert "generic_band_fuse1/pallas_call" in text
+    # the names a device trace shows, which the benchmark's reader of
+    # the remainder's share tells apart
+    assert re.search(r"%generic_resident_fuse4[\w.]* = \S+ custom-call\(",
+                     text)
+    assert re.search(r"%generic_band_fuse1[\w.]* = \S+ custom-call\(", text)
+
+
 def test_sharded_d2q9_4096_on_4x1_mesh(topo):
     """The four-chip path of chip_smoke.py: the sharded Pallas step over
     a y-split mesh of the described topology's devices — kernel and halo

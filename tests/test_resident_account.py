@@ -66,7 +66,7 @@ def _expected(name, m, n):
 @pytest.mark.parametrize("n", [17, 32, 33])
 @pytest.mark.parametrize("name,tag", [
     ("d2q9", "pallas_resident[d2q9,fuse=8]"),
-    ("d2q9_heat", "pallas_resident_generic[d2q9_heat,fuse=8]")])
+    ("d2q9_heat", "pallas_resident_generic[d2q9_heat]")])
 def test_account_on_the_fused_span(monkeypatch, name, tag, n):
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     m, lat = _lattice(name)
@@ -101,6 +101,32 @@ def test_account_on_the_fused_span(monkeypatch, name, tag, n):
     # the tuned family's hybrid step is there, the generic engine's not
     steps = [e for e in spans if e["name"] == "iterate.globals_step"]
     assert len(steps) == (2 if name == "d2q9" else 0)
+
+
+def test_a_resident_program_is_counted_once_a_length(monkeypatch):
+    """The generic engine's one call is a program of its own for every
+    resident length (a static grid): ``engine.resident_programs`` rises
+    when a length is built, not when it is run again, and two calls
+    whose lengths differ only in the steps left to the band kernel
+    share the program."""
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    _, lat = _lattice("d2q9_heat")
+    events = []
+    before = telemetry.counters().get("engine.resident_programs", 0)
+    telemetry.subscribe(events.append)
+    try:
+        seen = []
+        for n in (17, 17, 18, 33, 17):      # resident lengths 16, 16, 16, 32
+            lat.iterate(n)
+            seen.append(telemetry.counters()["engine.resident_programs"]
+                        - before)
+    finally:
+        telemetry.unsubscribe(events.append)
+    assert lat._fast_name == "pallas_resident_generic[d2q9_heat]"
+    assert seen == [1, 1, 1, 2, 2]
+    fused = [e for e in events if e.get("kind") == "span"
+             and e["name"] == "iterate.fused"]
+    assert [e["resident_steps"] for e in fused[1:]] == [16, 16, 32, 16]
 
 
 def test_nothing_is_recorded_with_telemetry_off(monkeypatch):

@@ -235,7 +235,7 @@ def _chain_generic_resident(monkeypatch):
     flags[0, :] = flags[-1, :] = m.flag_for("Wall")
     lat.set_flags(flags)
     lat.init()
-    return (lat, "pallas_resident_generic[d2q9_heat,fuse=8]",
+    return (lat, "pallas_resident_generic[d2q9_heat]",
             f"pallas_generic[d2q9_heat,fuse={pallas_generic.choose_fuse(m)}]")
 
 

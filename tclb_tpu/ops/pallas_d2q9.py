@@ -54,6 +54,7 @@ from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, lbm
 from tclb_tpu.ops.lbm import equilibrium, present_types  # noqa: F401
+from tclb_tpu.ops.pallas_d3q import _PAIR
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
 
@@ -228,7 +229,8 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     analogue (its GPU has no software-managed on-chip tier).
 
     A call of ``n`` steps dispatches up to two programs: ``n // 8``
-    resident calls in one ``lax.scan``, and, where 8 does not divide
+    resident calls in one ``lax.scan`` (two calls a loop body,
+    ``_PAIR``: no copy of the carry), and, where 8 does not divide
     ``n``, the ``n % 8`` steps left over on a second engine, the
     single-step band kernel of :func:`make_pallas_iterate` with its
     ghost rows (an XLA pad before its calls and a slice after them).
@@ -344,8 +346,11 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         def body(fields, _):
             return call(sett, fields, flags_i32, vel, den), None
 
+        # _PAIR calls a body, an odd call after the loop: no copy of the
+        # carry before a call (ops/pallas_d3q._PAIR)
         fields, _ = jax.lax.scan(body, state.fields, None,
-                                 length=niter // _RESIDENT_FUSE)
+                                 length=niter // _RESIDENT_FUSE,
+                                 unroll=_PAIR)
         # the steps left over cannot be more resident calls (the fuse is
         # baked in): ``iterate`` below runs them through the single-step
         # band kernel of make_pallas_iterate.  The Lattice hybrid hands
@@ -369,6 +374,10 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         calls, rest = divmod(int(niter), _RESIDENT_FUSE)
         return dict(
             band.band_shape, kernel_calls=calls + rest,
+            # resident calls issued from a two-call loop body: a loop of
+            # one trip or none is no loop (lax.scan unrolls it whole); the
+            # band engine's loop of the steps left over is single
+            paired_calls=calls - calls % _PAIR if calls >= 2 * _PAIR else 0,
             resident_calls=calls, resident_steps=_RESIDENT_FUSE,
             remainder_steps=rest, aux_planes=_AUX_PLANES,
             remainder_aux_planes=_AUX_PLANES, chunk_rows=chunk,
@@ -392,6 +401,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
             telemetry.counter("engine.resident_calls",
                               did["resident_calls"])
+            telemetry.counter("engine.paired_calls", did["paired_calls"])
             telemetry.annotate(**did)
         return state
 
@@ -866,6 +876,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 return f.at[:, ny - 2:, :].set(
                     fields[:, ny_phys - 2:ny_phys, :])
 
+        # both loops: one call a body, not _PAIR.  Compiled paired at
+        # 1024 x 1024, one of the two state buffers leaves the compiler's
+        # fast memory and kernel2 waits for its input copies; a micro-run
+        # read the paired loop faster all the same: PERF.md section 7
         if fuse == 2:
             aux = jnp.stack([flags_i32.astype(dtype), vel, den])
 

@@ -57,6 +57,21 @@ from tclb_tpu.ops.lbm import present_types  # noqa: F401  (re-export)
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024
 _HALO = 8   # DMA halo block height: one (8, 128) f32 tile per side
 HALO = _HALO  # public: max per-action reach a caller can plan against
+# kernel calls a loop body of the scans that carry the state through a
+# kernel: the 2D band engine's three, the 3D slab engine's non-series
+# two.  A loop's carry is one buffer and a custom call cannot write the
+# buffer it reads: with one call a body XLA copies the whole carry before
+# every call; with two, state A -> B -> A, the call that writes the carry
+# is not the one that reads it (ops/pallas_d3q's _PAIR, PR 33).  Right
+# for every plan, so a constant
+_PAIR = 2
+
+
+def _paired_calls(*trips: int) -> int:
+    """Of loops of ``trips`` kernel calls each, the calls a two-call loop
+    body issues: a loop's calls less its odd one; a loop of one trip or
+    none is no loop (``lax.scan`` unrolls it whole)."""
+    return sum(n - n % _PAIR for n in trips if n >= 2 * _PAIR)
 
 # storage dtypes the generic engines can keep in HBM.  Compute is ALWAYS
 # f32: field planes are widened right after the VMEM read and narrowed
@@ -887,7 +902,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 return (out, it + adv), None
 
             (fields, it), _ = jax.lax.scan(
-                body_s, (fields, state.iteration), None, length=main)
+                body_s, (fields, state.iteration), None, length=main,
+                unroll=_PAIR)
         else:
             if lean_aux:
                 # aux diet: the DMA'd aux stack is the flag plane alone;
@@ -909,15 +925,17 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 fields, it = carry
                 return (invoke(call, it, fields), it + adv * fuse), None
 
-            (fields, it), _ = jax.lax.scan(
-                body, (fields, state.iteration), None, length=main // fuse)
-
             def body1(carry, _):
                 fields, it = carry
                 return (invoke(call1, it, fields), it + adv), None
 
+            # both loops: _PAIR calls a body, an odd call after the loop
             (fields, it), _ = jax.lax.scan(
-                body1, (fields, it), None, length=main % fuse)
+                body, (fields, state.iteration), None, length=main // fuse,
+                unroll=_PAIR)
+            (fields, it), _ = jax.lax.scan(
+                body1, (fields, it), None, length=main % fuse,
+                unroll=_PAIR)
 
         globals_ = jnp.zeros_like(state.globals_)
         if final_g is not None:
@@ -951,6 +969,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             stages_per_step=len(model.actions["Iteration"]),
             band_rows=by, halo_rows=_HALO, pad_rows=pad, bands=ny // by,
             kernel_calls=fused + rest + final, remainder_steps=rest + final,
+            paired_calls=_paired_calls(fused, rest),
             aux_planes=(1 + 2 * len(zonal_names) if has_series
                         else 1 if lean_aux else 1 + len(zonal_names)))
 
@@ -963,6 +982,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                                   jax.core.Tracer):
             did = account(int(niter), params.time_series is not None)
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            telemetry.counter("engine.paired_calls", did["paired_calls"])
             telemetry.annotate(**did)
         return out
 
@@ -1250,10 +1270,6 @@ _TILE3D_TEMP_PLANES = 3
 # HBM peak, in the order of their traffic), the five deeper ones at 0.21
 # to 0.27 ns a computed node step, which is 41 planes
 _RECOMPUTE3D_PLANES = 41
-# kernel calls a loop body of _iterate_jit's non-series scans: with one
-# call a body XLA copies the whole carry before every call (a custom
-# call cannot write the buffer it reads; ops/pallas_d3q._PAIR, PR 33)
-_PAIR = 2
 
 
 def _slab_depth_gen(model: Model, nz: int, ny: int, nx: int,
@@ -1899,14 +1915,11 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
         main = max(niter, 0) - final
         fused = 0 if has_series else main // fuse
         rest = main - fused * fuse
-        # calls issued from a two-call loop body: a loop of one trip or
-        # none is no loop (lax.scan unrolls it whole)
-        paired = 0 if has_series else sum(
-            n - n % _PAIR for n in (fused, rest) if n >= 2 * _PAIR)
         return dict(
             stages_per_step=len(model.actions["Iteration"]),
             kernel_calls=fused + rest + final, remainder_steps=rest + final,
-            paired_calls=paired,
+            # the series loop runs one call a body
+            paired_calls=0 if has_series else _paired_calls(fused, rest),
             z_bands=nzb, band_slabs=bz, halo_slabs=R if fused else R1,
             y_bands=nyb, band_rows=by, halo_rows=hy,
             # the f32 flag plane (and what rides beside it) of each window

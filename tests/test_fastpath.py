@@ -17,7 +17,7 @@ import pytest
 
 from tclb_tpu.core.lattice import Lattice
 from tclb_tpu.models import get_model
-from tclb_tpu.ops import pallas_d2q9, pallas_d3q, pallas_generic
+from tclb_tpu.ops import lbm, pallas_d2q9, pallas_d3q, pallas_generic
 
 
 def _karman_lattice(ny=64, nx=128, wedge=False):
@@ -88,6 +88,50 @@ def test_engine_dispatch_matches_xla(monkeypatch, ny, niter, wedge):
     # the hybrid's trailing XLA step produced REAL (nonzero) globals
     assert any(abs(v) > 0 for v in gf.values())
     assert int(lat_f.state.iteration) == niter
+
+
+@pytest.fixture(scope="module")
+def resident_16():
+    """The Kármán case at 16 rows on the resident engine, and its state
+    after 0 to 5 resident calls issued one at a time (``iterate(8)``: a
+    loop of one trip, which is no loop), each then advanced 7 steps by
+    the band kernel (``iterate(7)``)."""
+    m, lat = _karman_lattice(16)
+    present = lbm.present_types(m, np.asarray(lat.state.flags))
+    it = pallas_d2q9.make_resident_iterate(m, (16, 128), jnp.float32,
+                                           interpret=True, present=present)
+    one_by_one, state = {}, jax.tree.map(jnp.copy, lat.state)
+    for calls in range(6):
+        if calls:
+            state = it(state, lat.params, 8)
+        one_by_one[calls, 0] = np.asarray(state.fields)
+        one_by_one[calls, 7] = np.asarray(
+            it(jax.tree.map(jnp.copy, state), lat.params, 7).fields)
+    return lat, it, one_by_one
+
+
+@pytest.mark.parametrize("over", [0, 7], ids=["even", "over7"])
+@pytest.mark.parametrize("calls", range(6))
+def test_resident_loop_pairs_its_calls_bit_for_bit(calls, over, resident_16):
+    """The resident engine's loop runs two kernel calls a body and an odd
+    call after the loop (``_PAIR``): the same calls in the same order as
+    one at a time, so the state is equal to the last bit, with and
+    without the band kernel's steps after them; ``account`` says which
+    calls a two-call body issued (a loop of four trips or more, less its
+    odd one; the band kernel's loop is single)."""
+    lat, it, one_by_one = resident_16
+    niter = 8 * calls + over
+    did = it.account(niter)
+    assert (did["kernel_calls"], did["resident_calls"],
+            did["remainder_steps"], did["paired_calls"]) \
+        == (calls + over, calls, over, {4: 4, 5: 4}.get(calls, 0))
+    state = it(jax.tree.map(jnp.copy, lat.state), lat.params, niter)
+    assert int(state.iteration) == int(lat.state.iteration) + niter
+    assert np.abs(np.asarray(state.fields)
+                  - one_by_one[calls, over]).max() == 0.0
+    if niter:
+        assert np.abs(one_by_one[calls, over]
+                      - one_by_one[0, 0]).max() > 1e-4      # it has moved
 
 
 def test_engine_dispatch_3d(monkeypatch):
@@ -384,9 +428,16 @@ def test_chip_smoke_rehearses_the_guard_phase(tmp_path, monkeypatch,
     mod = _chip_smoke()
     monkeypatch.setattr(mod, "OUT", str(tmp_path / "smoke"))
     monkeypatch.setitem(sys.modules, "chip_smoke", mod)   # <CallPython>
+    # the smoke subscribes for its process's life: here it must not
+    # leave telemetry on for the tests that run after it
+    added, subscribe = [], telemetry.subscribe
+    monkeypatch.setattr(telemetry, "subscribe",
+                        lambda fn: (added.append(fn), subscribe(fn))[1])
     try:
         assert mod.main(["--rehearse", "--only", "failcheck_fires"]) == 0
     finally:
+        for fn in added:
+            telemetry.unsubscribe(fn)
         telemetry.disable()
     lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
              if x.startswith("{")]

@@ -18,7 +18,7 @@ from benchmark.reference import d2q9_kuper as reference
 from benchmark.reference import zones
 from tclb_tpu import telemetry
 from tclb_tpu.control.solver import run_config_string
-from tclb_tpu.core.lattice import Lattice
+from tclb_tpu.core.lattice import Lattice, make_iterate
 from tclb_tpu.models import get_model
 from tclb_tpu.ops import pallas_generic
 from tclb_tpu.ops.lbm import present_types
@@ -151,6 +151,67 @@ def test_pallas_float32_against_the_reference(engine, reference32, tmp_path,
     assert np.isfinite(program).all()
     assert worst(program, ref) < TOL32
     assert abs(mass(program) - mass(start)) < 1e-5 * mass(start)
+
+
+@pytest.fixture(scope="module")
+def xla_steps(tmp_path_factory):
+    """The case's state after 0 to 24 XLA steps, float32, and the
+    lattice they started from."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("TCLB_FASTPATH", "0")
+        lat = solver_of(jnp.float32, tmp_path_factory.mktemp("xla")).lattice
+    m = lat.model
+    present = present_types(m, np.asarray(lat.state.flags))
+    step = jax.jit(make_iterate(m, present=present),
+                   static_argnames=("niter",))
+    states = [jax.tree.map(jnp.copy, lat.state)]
+    for _ in range(24):
+        states.append(step(jax.tree.map(jnp.copy, states[-1]), lat.params,
+                           1))
+    return lat, present, [np.asarray(s.fields) for s in states]
+
+
+@pytest.mark.parametrize("over", [0, 3], ids=["even", "over3"])
+@pytest.mark.parametrize("calls", range(6))
+def test_band_loops_pair_their_calls_bit_for_bit(calls, over, xla_steps):
+    """The band engine's loops run two kernel calls a body and an odd
+    call after the loop (``_PAIR``): ``calls`` calls at fuse 4, ``over``
+    single steps and the globals flavor's one are the same calls in the
+    same order as one a body, so the state equals the XLA step's to the
+    last bit (as it did), and ``account`` says which calls a two-call
+    body issued: those of a loop of four trips or more, less its odd
+    one (``lax.scan`` unrolls a shorter loop whole)."""
+    lat, present, after = xla_steps
+    m = lat.model
+    it = pallas_generic.make_pallas_iterate(m, SHAPE, jnp.float32, fuse=4,
+                                            present=present)
+    niter = 4 * calls + over + 1
+    did = it.account(niter, False)
+    assert (did["kernel_calls"], did["remainder_steps"],
+            did["paired_calls"]) == (calls + over + 1, over + 1,
+                                     {4: 4, 5: 4}.get(calls, 0))
+    state = it(jax.tree.map(jnp.copy, lat.state), lat.params, niter)
+    assert int(state.iteration) == int(lat.state.iteration) + niter
+    assert np.abs(np.asarray(state.fields) - after[niter]).max() == 0.0
+    assert np.abs(after[niter] - after[0]).max() > 1e-3     # it has moved
+
+
+def test_band_account_of_the_cells_segment(xla_steps):
+    """``drop1024.relax``'s ``iterate(500)``: 499 steps at fuse 4 are 124
+    looped calls (62 trips of the two-call body) and 3 steps over (a loop
+    ``lax.scan`` unrolls whole: no loop, nothing paired), then the
+    globals flavor's step.  A ``<Control>`` series runs every step but
+    the last in one loop of single steps, paired too."""
+    lat, present, _ = xla_steps
+    it = pallas_generic.make_pallas_iterate(lat.model, SHAPE, jnp.float32,
+                                            fuse=4, present=present)
+    did = it.account(500, False)
+    assert (did["kernel_calls"], did["remainder_steps"],
+            did["paired_calls"]) == (128, 4, 124)
+    did = it.account(500, True)
+    assert (did["kernel_calls"], did["remainder_steps"],
+            did["paired_calls"]) == (500, 500, 498)
+    assert it.account(0, False)["paired_calls"] == 0
 
 
 # the resident engine cuts its rows into 64-row chunks of its own, each
@@ -318,12 +379,14 @@ def test_spans_and_annotations(band_lattice, reference32):
     assert (probe["attempts"], probe["rungs"]) == (1, [32])
     assert 0 < probe["dur_s"] <= fused[0]["dur_s"]
     did = dict(stages_per_step=2, band_rows=32, halo_rows=8, pad_rows=0,
-               bands=2, kernel_calls=8, remainder_steps=3, aux_planes=1)
+               bands=2, kernel_calls=8, remainder_steps=3, paired_calls=4,
+               aux_planes=1)
     # the first call's account lies on the probe that made the calls
     for span in (probe, fused[1]):
         assert {k: span[k] for k in did} == did
     assert fused[1]["iters"] == STEPS32 and fused[1]["engine"] == tag
     assert counters["engine.kernel_calls"] == 16
+    assert counters["engine.paired_calls"] == 8
     assert counters["engine.probe_attempts"] == 1
     assert not [e for e in spans if e["name"] == "iterate.globals_step"]
     # supports()'s abstract trace of the engine issues no call

@@ -106,9 +106,11 @@ def test_d2q9_resident_karman_as_shipped(one_chip, monkeypatch):
     multiple of the sublane tile: the periodic pull is concatenations)
     and, for the 7 steps a segment leaves over, the single-step band
     kernel on 120 padded rows.  A compile that raised here would make
-    the probe step down to the band engine on the chip.  23 steps: two
-    resident calls and 7 left over.  (About 70 s on this host: the
-    resident kernel is 16 unrolled chunk steps.)"""
+    the probe step down to the band engine on the chip.  47 steps: five
+    resident calls (two trips of a two-call loop body and an odd call
+    after the loop, so XLA puts no copy of the state before the kernel)
+    and 7 left over.  (About 70 s on this host: the resident kernel is
+    16 unrolled chunk steps.)"""
     shape = (100, 1024)
     m = get_model("d2q9")
     lat = Lattice(m, shape, dtype=jnp.float32,
@@ -131,12 +133,12 @@ def test_d2q9_resident_karman_as_shipped(one_chip, monkeypatch):
     it = pallas_d2q9.make_resident_iterate(
         m, shape, jnp.float32, interpret=False,
         present=lbm.present_types(m, flags))
-    assert it.account(23) == dict(
-        kernel_calls=9, resident_calls=2, resident_steps=8,
+    assert it.account(47) == dict(
+        kernel_calls=12, resident_calls=5, paired_calls=4, resident_steps=8,
         remainder_steps=7, aux_planes=3, remainder_aux_planes=3,
         chunk_rows=50, vmem_bytes=14_745_600, bands=3, band_rows=40,
         halo_rows=8, pad_rows=20)
-    text = _compile(it, lat, 23, one_chip)
+    text = _compile(it, lat, 47, one_chip)
     assert "tpu_custom_call" in text
     assert "d2q9_resident_fuse8/pallas_call" in text
     assert "d2q9_band_fuse1/pallas_call" in text
@@ -144,6 +146,9 @@ def test_d2q9_resident_karman_as_shipped(one_chip, monkeypatch):
     # the remainder's share tells apart
     assert re.search(r"%d2q9_resident_fuse8[\w.]* = \S+ custom-call\(", text)
     assert re.search(r"%d2q9_band_fuse1[\w.]* = \S+ custom-call\(", text)
+    body, calls = _kernel_loop_body(text, "d2q9_resident_fuse8")
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
 
 
 @pytest.mark.parametrize("fuse", [None, 1], ids=["fused", "fuse1"])
@@ -224,6 +229,26 @@ def _computations(text: str) -> dict:
     return found
 
 
+def _kernel_loop_body(text: str, kernel: str):
+    """The lines of the one ``while`` body of a compiled module that
+    holds custom calls of the kernel ``kernel`` (a regex for its name),
+    and how many of those calls it holds."""
+    call = re.compile(r"= \S+ custom-call\(.*%s/" % kernel)
+    bodies = [lines for name, lines in _computations(text).items()
+              if any(call.search(line) for line in lines)
+              and re.search(r"\bbody=%%?%s\b" % re.escape(name), text)]
+    assert len(bodies) == 1
+    return bodies[0], sum(bool(call.search(line)) for line in bodies[0])
+
+
+def _state_copies(lines, m, shape) -> list:
+    """The copies of the whole state among a computation's lines."""
+    whole = "f32[%s]" % ",".join(str(n) for n in (m.n_storage,)
+                                 + tuple(shape))
+    copy = re.compile(r"= %s\S* copy(-start)?\(" % re.escape(whole))
+    return [line.strip() for line in lines if copy.search(line)]
+
+
 @pytest.mark.parametrize("case,fuse", [
     ("channel", None), ("channel", 1), ("tgv256", None),
     ("channel512", None)],
@@ -269,16 +294,9 @@ def test_d3q_loop_body_pairs_the_calls_and_copies_no_state(one_chip, case,
         text = jax.jit(lambda s, p: it(s, p, niter)).lower(
             state, params).compile().as_text()
     assert re.search(r"d3q_(slab|ring)_fuse%d/pallas_call" % K, text)
-    kernel = re.compile(r"= \S+ custom-call\(.*d3q_(slab|ring)_fuse%d/" % K)
-    bodies = [lines for name, lines in _computations(text).items()
-              if any(kernel.search(line) for line in lines)
-              and re.search(r"\bbody=%%?%s\b" % re.escape(name), text)]
-    assert len(bodies) == 1
-    assert sum(bool(kernel.search(line)) for line in bodies[0]) == 2
-    whole = "f32[%s]" % ",".join(str(n) for n in (m.n_storage,) + shape)
-    copy = re.compile(r"= %s\S* copy(-start)?\(" % re.escape(whole))
-    copies = [line.strip() for line in bodies[0] if copy.search(line)]
-    assert not copies, copies
+    body, calls = _kernel_loop_body(text, "d3q_(slab|ring)_fuse%d" % K)
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
 
 
 def test_generic_d3q19_kuper_256_tiled(one_chip):
@@ -314,16 +332,9 @@ def test_generic_d3q19_kuper_256_tiled(one_chip):
         state, params).compile().as_text()
     assert "tpu_custom_call" in text
     assert f"generic_slab_fuse{K}/pallas_call" in text
-    kernel = re.compile(r"= \S+ custom-call\(.*generic_slab_fuse%d/" % K)
-    bodies = [lines for name, lines in _computations(text).items()
-              if any(kernel.search(line) for line in lines)
-              and re.search(r"\bbody=%%?%s\b" % re.escape(name), text)]
-    assert len(bodies) == 1
-    assert sum(bool(kernel.search(line)) for line in bodies[0]) == 2
-    whole = "f32[%s]" % ",".join(str(n) for n in (m.n_storage,) + shape)
-    copy = re.compile(r"= %s\S* copy(-start)?\(" % re.escape(whole))
-    copies = [line.strip() for line in bodies[0] if copy.search(line)]
-    assert not copies, copies
+    body, calls = _kernel_loop_body(text, "generic_slab_fuse%d" % K)
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
 
 
 @pytest.mark.parametrize("name", ["d2q9_kuper", "d2q9_heat"])
@@ -337,6 +348,68 @@ def test_generic_512(one_chip, name):
     assert "tpu_custom_call" in text
     # 4 steps are fewer than the fused depth: the remainder kernel
     assert "generic_band_fuse1/pallas_call" in text
+
+
+def _drop(n: int):
+    """The drop of ``example/drop.xml`` in a periodic box of ``n`` x
+    ``n`` nodes: every node collides, the drop is a settings zone."""
+    shape = (n, n)
+    m = get_model("d2q9_kuper")
+    lat = Lattice(m, shape, dtype=jnp.float32)
+    flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
+    rows, cols = np.mgrid[0:n, 0:n]
+    mid = (n - 1) / 2
+    drop = (rows - mid) ** 2 + (cols - mid) ** 2 < (3 * n // 16) ** 2
+    flags[drop] |= 1 << m.zone_shift        # the zone of Density-zdrop
+    lat.set_flags(flags)
+    lat.init()
+    return m, lat, flags
+
+
+def test_generic_band_drop_1024_pairs_the_calls(one_chip):
+    """``example/drop_1024.xml`` (the cell ``drop1024.relax``): the band
+    engine at the planner's fuse 4 with its lean aux stack (the flag
+    plane; the zone table in SMEM).  The loop that carries the state
+    through the kernel holds two calls a body, so XLA puts no copy of the
+    42 MB state before a call (the parent's body: one call reading
+    ``copy(carry)``).  22 steps: five looped calls (two trips and an odd
+    call), a step over and the globals flavor's."""
+    m, lat, flags = _drop(1024)
+    shape = (1024, 1024)
+    fuse = pallas_generic.choose_fuse(m)
+    assert fuse == 4
+    it = pallas_generic.make_pallas_iterate(
+        m, shape, jnp.float32, interpret=False, fuse=fuse,
+        present=lbm.present_types(m, flags))
+    did = it.account(22, False)
+    assert (did["kernel_calls"], did["remainder_steps"],
+            did["paired_calls"], did["aux_planes"], did["bands"]) \
+        == (7, 2, 4, 1, 32)
+    text = _compile(it, lat, 22, one_chip)
+    assert "generic_band_fuse1/pallas_call" in text
+    body, calls = _kernel_loop_body(text, "generic_band_fuse4")
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
+
+
+def test_d2q9_band_1024_stays_one_call_a_body(one_chip):
+    """The tuned band engine's loop is NOT paired (``PERF.md`` section
+    7): compiled with two calls a body at 1024 x 1024, one of the two 46
+    MB state buffers leaves the compiler's fast memory (``S(1)``), and
+    ``kernel2`` waits for its input copies before it computes.  So its
+    body holds one call and the copy of the carry before it; a change
+    that pairs it has that finding to answer, and PR 43's micro-run on
+    the chip, which read the paired loop 4.7 % faster, to cite.  11
+    steps: five looped calls and a step over."""
+    shape = (1024, 1024)
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
+                                         interpret=False, fuse=2,
+                                         present=present)
+    text = _compile(it, lat, 11, one_chip)
+    body, calls = _kernel_loop_body(text, "d2q9_band_fuse2")
+    assert calls == 1
+    assert len(_state_copies(body, m, shape)) == 1
 
 
 def test_generic_resident_drop_512(one_chip, monkeypatch):
@@ -354,14 +427,7 @@ def test_generic_resident_drop_512(one_chip, monkeypatch):
     minutes the issue allows, so the real shape is compiled and not a
     smaller one of two chunks.)"""
     shape = (512, 512)
-    m = get_model("d2q9_kuper")
-    lat = Lattice(m, shape, dtype=jnp.float32)
-    flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
-    rows, cols = np.mgrid[0:512, 0:512]
-    drop = (rows - 255.5) ** 2 + (cols - 255.5) ** 2 < 96 ** 2
-    flags[drop] |= 1 << m.zone_shift        # the zone of Density-zdrop
-    lat.set_flags(flags)
-    lat.init()
+    m, lat, flags = _drop(512)
     assert pallas_generic.supports_resident(m, shape, jnp.float32)
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     chain = lat._build_fast()
@@ -373,8 +439,9 @@ def test_generic_resident_drop_512(one_chip, monkeypatch):
         present=lbm.present_types(m, flags))
     # a <Log Iterations="500"> segment, as the cell drop512.relax runs it
     assert it.account(500) == dict(
-        kernel_calls=3, resident_calls=1, resident_steps=498,
-        remainder_steps=2, aux_planes=2, remainder_aux_planes=1,
+        kernel_calls=3, resident_calls=1, paired_calls=0,
+        resident_steps=498, remainder_steps=2, aux_planes=2,
+        remainder_aux_planes=1,
         chunk_rows=64, vmem_bytes=23_068_672, stages_per_step=2,
         bands=16, band_rows=32, halo_rows=8, pad_rows=0)
     assert it.account(6)["resident_steps"] == 4

@@ -18,7 +18,7 @@ from tclb_tpu.models import get_model
 from tclb_tpu.ops import pallas_d2q9, pallas_generic
 
 SHAPE = (16, 128)
-NAMES = ("kernel_calls", "resident_calls", "resident_steps",
+NAMES = ("kernel_calls", "resident_calls", "paired_calls", "resident_steps",
          "remainder_steps", "aux_planes", "remainder_aux_planes",
          "chunk_rows", "vmem_bytes", "bands", "band_rows", "halo_rows",
          "pad_rows")
@@ -44,6 +44,9 @@ def _expected(name, m, n):
         calls, rest = divmod(n - 1, 8)
         return dict(
             kernel_calls=calls + rest, resident_calls=calls,
+            # a loop of four trips runs two calls a body, a shorter one
+            # is unrolled whole
+            paired_calls=4 if calls == 4 else 0,
             resident_steps=8, remainder_steps=rest, aux_planes=3,
             remainder_aux_planes=3, chunk_rows=16,
             vmem_bytes=(3 * m.n_storage + 3) * 16 * 128 * 4,
@@ -54,6 +57,8 @@ def _expected(name, m, n):
     n_aux = 1 + len(m.zonal_settings)
     return dict(
         kernel_calls=1 + n - main, resident_calls=1, resident_steps=main,
+        # the band engine's one or two steps are no loop
+        paired_calls=0,
         remainder_steps=n - main, aux_planes=n_aux,
         # the band kernel builds its zonal planes from the zone table
         # and reads the flag plane alone
@@ -98,6 +103,7 @@ def test_account_on_the_fused_span(monkeypatch, name, tag, n):
         == did["resident_calls"] + did["remainder_steps"]
     assert counters["engine.kernel_calls"] == 2 * did["kernel_calls"]
     assert counters["engine.resident_calls"] == 2 * did["resident_calls"]
+    assert counters.get("engine.paired_calls", 0) == 2 * did["paired_calls"]
     # the tuned family's hybrid step is there, the generic engine's not
     steps = [e for e in spans if e["name"] == "iterate.globals_step"]
     assert len(steps) == (2 if name == "d2q9" else 0)
@@ -150,10 +156,10 @@ def test_the_account_of_the_published_karman_shape():
     it = pallas_d2q9.make_resident_iterate(m, (100, 1024), jnp.float32,
                                            interpret=True)
     assert it.account(999) == dict(
-        kernel_calls=131, resident_calls=124, resident_steps=8,
-        remainder_steps=7, aux_planes=3, remainder_aux_planes=3,
-        chunk_rows=50, vmem_bytes=14_745_600, bands=3, band_rows=40,
-        halo_rows=8, pad_rows=20)
+        kernel_calls=131, resident_calls=124, paired_calls=124,
+        resident_steps=8, remainder_steps=7, aux_planes=3,
+        remainder_aux_planes=3, chunk_rows=50, vmem_bytes=14_745_600,
+        bands=3, band_rows=40, halo_rows=8, pad_rows=20)
     # the generic engine's count of its own budget, by the same name
     k = get_model("d2q9_kuper")
     assert pallas_generic.resident_vmem_bytes(k, 512, 512, jnp.float32) \

@@ -331,12 +331,14 @@ def _calls_named(node, name: str) -> bool:
 
 
 def _assigns_fast_name(node) -> bool:
-    """True if any statement under ``node`` assigns ``self._fast_name``."""
+    """True if any statement under ``node`` assigns ``self._fast_name``
+    or ``self._tail_name`` (the engine of a hybrid's trailing step)."""
     for n in ast.walk(node):
         if isinstance(n, (ast.Assign, ast.AugAssign, ast.AnnAssign)):
             targets = n.targets if isinstance(n, ast.Assign) else [n.target]
             for t in targets:
-                if isinstance(t, ast.Attribute) and t.attr == "_fast_name" \
+                if isinstance(t, ast.Attribute) \
+                        and t.attr in ("_fast_name", "_tail_name") \
                         and isinstance(t.value, ast.Name) \
                         and t.value.id == "self":
                     return True
@@ -346,8 +348,8 @@ def _assigns_fast_name(node) -> bool:
 def scan_dispatch_telemetry(lattice_path=None) -> list:
     """Engine dispatch must be observable: ``_fast_path`` emits
     ``engine_selected`` and every except handler that reassigns
-    ``self._fast_name`` (i.e. demotes the engine) emits
-    ``engine_fallback``.  Without these, a production trace cannot say
+    ``self._fast_name`` or ``self._tail_name`` (i.e. demotes an engine)
+    emits ``engine_fallback``.  Without these, a production trace cannot say
     which engine ran — the exact blind spot that once made a
     heat_adj regression untriageable."""
     path = lattice_path or os.path.join(_PKG_ROOT, "core", "lattice.py")

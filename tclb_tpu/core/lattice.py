@@ -902,6 +902,7 @@ class Lattice:
         # flags span non-addressable devices and cannot be fetched back
         self._iterate_cached = None
         self._host_flags: Optional[np.ndarray] = None
+        self._present: Optional[tuple] = None   # see _present_types()
         step_init = make_action_step(model, "Init")
         if narrowed:
             def _init_narrow(state, params, _step=step_init,
@@ -925,6 +926,11 @@ class Lattice:
         self._fast_tried = False
         self._fast_probing = False
         self._fast_chain: list = []
+        # the engine of the one step a hybrid engine leaves for the
+        # Globals, and its tag (None: the XLA step); see _build_tail()
+        self._tail = None
+        self._tail_name = None
+        self._tail_probing = False
 
     # -- setup -------------------------------------------------------------- #
 
@@ -1000,14 +1006,24 @@ class Lattice:
             return self._host_flags
         return np.asarray(self.state.flags)
 
+    def _present_types(self) -> set:
+        """The node types painted on the lattice (a pass over the flags
+        for each type of the model), remembered for the host flags it
+        was read from: the XLA engine, the fused chain and the tail
+        engine all specialize on it."""
+        from tclb_tpu.ops.lbm import present_types
+        flags = self._flags_host()
+        if self._present is None or self._present[0] is not flags:
+            self._present = (flags, present_types(self.model, flags))
+        return self._present[1]
+
     @property
     def _iterate(self):
         """The XLA engine, built on demand and specialized on the painted
         node types (absent boundary cases are skipped; globals reduce on
         the final step only — iterate()'s contract)."""
         if self._iterate_cached is None:
-            from tclb_tpu.ops.lbm import present_types
-            present = present_types(self.model, self._flags_host())
+            present = self._present_types()
             if self.mesh is not None:
                 from tclb_tpu.parallel.halo import make_sharded_iterate
                 self._iterate_cached = make_sharded_iterate(
@@ -1021,6 +1037,33 @@ class Lattice:
                                  storage_shift=self._shift_block),
                     static_argnames=("niter",), donate_argnums=0)
         return self._iterate_cached
+
+    def _generic_tiles(self, fz: int, by_cap: Optional[int] = None):
+        """The ``(bz, by, K)`` the generic slab engine cuts a 3D plane
+        into where no whole-plane plan holds it, else None."""
+        from tclb_tpu.ops import pallas_generic
+        if self.model.ndim != 3:
+            return None
+        return pallas_generic.tile_plan_3d(
+            self.model, self.shape, self.storage_dtype.itemsize, fz, by_cap)
+
+    def _generic_cand(self, present: set, fz: int,
+                      by_cap: Optional[int] = None,
+                      tag: Optional[str] = None, **how) -> EngineCandidate:
+        """The generic band (2D) or slab (3D) engine at ``fz`` steps a
+        kernel call as a candidate; ``how`` is what dispatch has to know
+        to try it (``probe``, ``cap``, ``verdict``)."""
+        from tclb_tpu.ops import pallas_generic
+        model, shape, sdt = self.model, self.shape, self.storage_dtype
+        if tag is None:
+            # a plane the engine tiles says so: the rows of its bands
+            plan = self._generic_tiles(fz, by_cap)
+            by = f",by={plan[1]}" if plan and plan[1] < shape[1] else ""
+            tag = f"pallas_generic[{model.name},fuse={fz}{by}]"
+        return EngineCandidate(
+            tag, lambda: pallas_generic.make_pallas_iterate(
+                model, shape, sdt, present=present, fuse=fz, by_cap=by_cap,
+                shift=self._shift_vec), **how)
 
     def _build_fast(self) -> list:
         """The fused Pallas engines that can take this configuration, as
@@ -1040,9 +1083,8 @@ class Lattice:
                            and jax.default_backend() != "tpu"):
             return []
         from tclb_tpu.ops import pallas_d2q9, pallas_d3q, pallas_generic
-        from tclb_tpu.ops.lbm import present_types
         model, shape, name = self.model, self.shape, self.model.name
-        present = present_types(model, self._flags_host())
+        present = self._present_types()
         shift = self._shift_vec
         # a Control time series needs per-iteration zonal planes, which
         # only the generic engine implements — skip the tuned kernels
@@ -1127,20 +1169,7 @@ class Lattice:
         if not (fits_resident or pallas_generic.supports(model, shape, sdt)):
             return []
 
-        def tiled(fz, by_cap=None):
-            # the (bz, by, K) of a 3D plane no whole-plane plan holds
-            return (pallas_generic.tile_plan_3d(model, shape, s_itemsize,
-                                                fz, by_cap)
-                    if model.ndim == 3 else None)
-
-        def band(fz, by_cap, tag=None, **how):
-            if tag is None:
-                # a plane the engine tiles says so: the rows of its bands
-                plan = tiled(fz, by_cap)
-                by = f",by={plan[1]}" if plan and plan[1] < shape[1] else ""
-                tag = f"pallas_generic[{name},fuse={fz}{by}]"
-            return cand(tag, pallas_generic.make_pallas_iterate, fuse=fz,
-                        by_cap=by_cap, shift=shift, **how)
+        band = partial(self._generic_cand, present)
         cfg = (None if fits_resident
                else pallas_generic.get_build_cfg(model, shape))
         if cfg is not None:
@@ -1175,10 +1204,14 @@ class Lattice:
         rungs = [(fz0, 16), (fz0, 8)]
         if fz0 >= 2:
             rungs += [(1, 16), (1, 8)]
-        plan0 = tiled(fz0)
+        plan0 = self._generic_tiles(fz0)
         if model.ndim == 3 and plan0 is None:
-            # last resort: raised scoped-vmem ceiling (negative cap
-            # encodes it; ~2x slower codegen, still ~3x the XLA path).
+            # last resort: smaller caps under the raised scoped-vmem
+            # ceiling (a negative cap encodes it; the ceiling itself
+            # costs nothing: the same (2, 32, 1) window of d3q19,
+            # d3q19_kuper and d3q27_BGK read 0.830, 1.547 and 0.971 ns
+            # an update under it and 0.851, 1.550 and 0.988 under
+            # Mosaic's own 16 MiB, bit-equal; chip, PR 44).
             # A tiled window compiles under it from the start: its rungs
             # cap the rows and slabs of the window
             rungs += [(fz0, -16), (fz0, -8)]
@@ -1192,6 +1225,60 @@ class Lattice:
                  probe=True, cap=cap, verdict=(fz, cap))
             for fz, cap in rungs]
 
+    def _build_tail(self) -> tuple:
+        """The engine of the one step a hybrid engine (one that does not
+        say ``full_globals``) leaves for the Globals, and its tag: the
+        generic Pallas engine's one-step flavour, which reduces them in
+        the kernel, wherever it takes the case; ``(None, None)``, the XLA
+        step, on a mesh (the sharded engines keep their own step), where
+        ``pallas_generic`` refuses the model, the shape or the storage
+        dtype, where its kernel reduces no Globals, and where its band
+        stands on ghost rows (no multiple of its rows, as the 100 rows
+        of ``karman.xml``): the call then lies between an XLA pad and a
+        slice of the whole state, and beside a resident engine it is one
+        more band call among the ``n % 8`` steps that engine accounts
+        for.  The same for every model and dimension: it is one
+        algorithm, one step that reduces Globals.  A ``<Control>`` series
+        never gets here (it keeps the tuned engines out of the chain).
+        Its first call is probed (:meth:`_probe_tail`): nothing has shown
+        yet that it compiles."""
+        from tclb_tpu import analysis
+        from tclb_tpu.ops import pallas_generic
+        model, shape = self.model, self.shape
+        # supports() without its abstract trace of the kernel (seconds
+        # of every run's set-up): the probed first call is that trace,
+        # and a model whose kernel does not trace steps down there
+        if self.mesh is not None or not (
+                analysis.kernel_safety_ok(model)
+                and pallas_generic.mosaic_ok(model, shape)
+                and pallas_generic.supports(model, shape,
+                                            self.storage_dtype,
+                                            probe=False)):
+            return None, None
+        cand = self._generic_cand(self._present_types(), 1)
+        try:
+            it = cand.build()
+        except Exception as e:  # noqa: BLE001
+            self._tail_failed(cand.tag, e)
+            return None, None
+        if getattr(it, "full_globals", False) \
+                and not it.account(1, False).get("pad_rows"):
+            return it, cand.tag
+        return None, None
+
+    def _tail_failed(self, tag: str, e: Exception) -> None:
+        """The tail engine cannot be built, compiled or run: the XLA
+        step takes the trailing steps of this lattice from here on.  No
+        verdict is remembered: ``mosaic_ok`` is the fused chain's, which
+        may still need the generic engine's looped kernels, and a later
+        lattice's probe costs one call."""
+        from tclb_tpu.utils import log
+        log.warning(f"engine: {tag} failed to compile ({e!r}); the XLA "
+                    "step takes the globals")
+        telemetry.engine_fallback(tag, "xla", repr(e),
+                                  model=self.model.name)
+        self._tail = self._tail_name = None
+
     def _fast_path(self):
         if not self._fast_tried:
             self._fast_tried = True
@@ -1201,11 +1288,16 @@ class Lattice:
             self._fast = chain[0].build() if chain else None
             self._fast_name = chain[0].tag if chain else None
             self._fast_probing = bool(chain) and chain[0].probe
+            full = getattr(self._fast, "full_globals", False)
+            self._tail, self._tail_name = (
+                self._build_tail() if self._fast is not None and not full
+                else (None, None))
+            self._tail_probing = self._tail is not None
             from tclb_tpu.utils import log
             if self._fast is not None:
-                suffix = "(in-kernel globals)" if getattr(
-                    self._fast, "full_globals", False) \
-                    else "(+1 XLA step per call for globals)"
+                suffix = "(in-kernel globals)" if full \
+                    else (f"(+1 step per call on {self._tail_name or 'xla'} "
+                          "for globals)")
                 log.info(f"engine: {self._fast_name} fused fast path "
                          f"{suffix}")
             else:
@@ -1255,8 +1347,10 @@ class Lattice:
         fast = self._fast_path()
         # an engine advertising full_globals returns the LAST step's
         # Globals itself (in-kernel accumulation, ≡ the reference's
-        # src/cuda.cu.Rt:176-202) — no trailing XLA step; the hybrid
-        # engines run niter-1 fused steps + one XLA step instead.
+        # src/cuda.cu.Rt:176-202) — no trailing step; the hybrid
+        # engines run niter-1 fused steps + one step on the tail engine
+        # (_build_tail: the generic Pallas kernel's in-kernel-globals
+        # flavour where it takes the case, else the XLA step) instead.
         # Engines advertising supports_series gather Control time series
         # per iteration themselves; others fall back to XLA for those.
         full = bool(getattr(fast, "full_globals", False))
@@ -1288,11 +1382,41 @@ class Lattice:
                    engine=(self._fast_name if use_fast else None) or "xla")
             sp.sync(self.state)
         if done < niter:
-            # the hybrid engines' trailing XLA step, for the globals
+            # the hybrid engines' trailing step, for the globals
             with telemetry.span("iterate.globals_step", iters=1) as sp:
-                self.state = self._iterate(self.state, self.params, 1)
-                sp.mark("dispatch_s")
+                if self._tail_probing:
+                    self._probe_tail()
+                else:
+                    self.state = (self._tail or self._iterate)(
+                        self.state, self.params, 1)
+                    sp.mark("dispatch_s")
+                if self._tail is not None:
+                    telemetry.counter("engine.tail_calls")
+                sp.add(engine=self._tail_name or "xla")
                 sp.sync(self.state)
+
+    def _probe_tail(self) -> None:
+        """The first step of the tail engine (:meth:`_build_tail`), on
+        the state itself: its program of one kernel call does not donate
+        (``pallas_generic._donating_unless_one_call``), so a failure
+        leaves the state whole.  Where it does not compile, or fails as
+        it runs, the XLA step takes over with one ``engine_fallback``
+        event and the run goes on: unlike a fused engine's steps, this
+        step in XLA is what every run paid before."""
+        tag = self._tail_name
+        self._tail_probing = False
+        with telemetry.span("engine.probe", engine=tag) as probe:
+            try:
+                # fenced inside the try: a failure at execution shows here
+                self.state = jax.block_until_ready(
+                    self._tail(self.state, self.params, 1))
+            except Exception as e:  # noqa: BLE001
+                self._tail_failed(tag, e)
+                self.state = self._iterate(self.state, self.params, 1)
+            telemetry.counter("engine.probe_attempts")
+            probe.add(attempts=1, rungs=[],
+                      result=self._tail_name or "xla")
+            probe.sync(self.state)
 
     def _probe_first_call(self, fast, niter: int, nfast: int,
                           tried: list) -> int:

@@ -234,8 +234,9 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     ``n``, the ``n % 8`` steps left over on a second engine, the
     single-step band kernel of :func:`make_pallas_iterate` with its
     ghost rows (an XLA pad before its calls and a slice after them).
-    The Lattice hybrid hands this engine ``niter - 1`` steps (the last
-    is the XLA step that produces the globals), so a handler interval
+    The Lattice hybrid hands this engine ``niter - 1`` steps (the last,
+    which produces the globals, runs on the Lattice's tail engine: the
+    generic band kernel's one-step flavour, or XLA), so a handler interval
     that is a multiple of 8 (100, 500, 1000) takes the second engine
     for 3 or 7 steps in every call: three programs a segment.  With
     telemetry on, the call says what it issued on the open span

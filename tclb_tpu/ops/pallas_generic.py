@@ -86,6 +86,22 @@ STORAGE_DTYPES = (jnp.float32, jnp.bfloat16)
 _COMPUTE_DTYPE = jnp.float32
 
 
+def _donating_unless_one_call(schedule: Callable) -> Callable:
+    """``schedule(state, params, niter)`` compiled twice, and
+    ``program(did)``, which picks the one to run from the engine's
+    account of a call: donating the state for every schedule of two
+    kernel calls and more (a call reads what the call before it wrote),
+    not donating it for a schedule of one call.  That call reads halos
+    of the state while it writes, so where its output has to be the
+    donated input's buffer XLA copies the whole state first (0.86 GB at
+    34 x 512 x 48 x 256: 2 ms on a v5e); not donated, it writes a
+    buffer of its own and the caller drops the input: the same peak, no
+    copy."""
+    jit = partial(jax.jit, schedule, static_argnames=("niter",))
+    donating, once = jit(donate_argnums=0), jit()
+    return lambda did: once if did["kernel_calls"] == 1 else donating
+
+
 def _storage_ok(dtype) -> bool:
     return jnp.dtype(dtype) in {jnp.dtype(d) for d in STORAGE_DTYPES}
 
@@ -847,8 +863,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     adv = int(any(model.stages[s].load_densities
                   for s in model.actions["Iteration"]))
 
-    @partial(jax.jit, static_argnames=("niter",), donate_argnums=0)
-    def _iterate_jit(state: LatticeState, params: SimParams, niter: int
+    def _schedule(state: LatticeState, params: SimParams, niter: int
                      ) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
         fields = state.fields.astype(dtype)
@@ -959,7 +974,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     def account(niter: int, has_series: bool) -> dict:
         """What one ``iterate(niter)`` issues, reckoned host-side from
-        the shapes (the mirror of ``_iterate_jit``'s schedule)."""
+        the shapes (the mirror of ``_schedule``)."""
         final = int(niter > 0 and (call_sg if has_series else call_g)
                     is not None)
         main = max(niter, 0) - final
@@ -973,14 +988,16 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             aux_planes=(1 + 2 * len(zonal_names) if has_series
                         else 1 if lean_aux else 1 + len(zonal_names)))
 
+    program = _donating_unless_one_call(_schedule)
+
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
-        out = _iterate_jit(state, params, niter)
+        did = account(int(niter), params.time_series is not None)
+        out = program(did)(state, params, niter)
         # a call under a trace (supports()'s abstract probe, a caller's
         # own jit) issues nothing
         if telemetry.enabled() and not isinstance(out.fields,
                                                   jax.core.Tracer):
-            did = account(int(niter), params.time_series is not None)
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
             telemetry.counter("engine.paired_calls", did["paired_calls"])
             telemetry.annotate(**did)
@@ -988,7 +1005,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     # contract flags the Lattice dispatch keys on: the engine handles
     # Control time series itself, and (when the globals flavor exists)
-    # returns the LAST step's Globals — no trailing XLA step needed
+    # returns the LAST step's Globals — no trailing step needed (and a
+    # hybrid engine's trailing step can be this engine's iterate(.., 1))
     iterate.supports_series = True
     iterate.full_globals = bool(model.n_globals == 0 or call_g is not None)
     # internals for make_diff_step (the differentiable single-step path
@@ -1252,6 +1270,9 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 # halo is what buys the K-fold traffic amortization
 _FUSED3D_BUDGET = 28 * 1024 * 1024
 _VMEM3D_LIMIT = 100 * 1024 * 1024
+# Mosaic's own scoped-vmem limit on a v5e, which a kernel built without
+# the raised ceiling compiles under
+_VMEM3D_DEFAULT = 16 * 1024 * 1024
 # a plane no whole-plane plan holds is cut into y bands: windows of bz
 # slabs x by rows with _HALO wrapped halo rows a side (one sublane tile,
 # so every DMA window starts on a tile boundary), always compiled under
@@ -1535,13 +1556,14 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     # the Lattice probe ladder passes row-oriented caps (16, 8); for
     # z-slabs interpret them as a slab-depth cap (8 rows ~ 1 slab) so the
     # retry actually shrinks the scoped-VMEM working set.  NEGATIVE caps
-    # are the last-resort rungs: |cap| plus a raised scoped-vmem ceiling
-    # (the big ceiling costs ~2x in Mosaic codegen quality, so it is
-    # never the default — only what rescues temporaries-heavy models
-    # like d3q19_kuper that OOM even at bz=1).  Fused (K>=2) builds
-    # always compile with the raised ceiling: their K*R halo scratch is
-    # budgeted against it (_FUSED3D_BUDGET); so do tiled windows
-    # (_TILED3D_BUDGET), whose rungs cap rows and slabs both.
+    # are the last-resort rungs: |cap| plus a raised scoped-vmem ceiling.
+    # Fused (K>=2) builds always compile with the raised ceiling: their
+    # K*R halo scratch is budgeted against it (_FUSED3D_BUDGET); so do
+    # tiled windows (_TILED3D_BUDGET), whose rungs cap rows and slabs
+    # both; and so does a whole-plane window whose own account
+    # (_window_fits: _slab_depth_gen budgets the DMA scratch alone)
+    # passes Mosaic's default limit: d3q27_cumulant's one slab of 48 x
+    # 256 needs 16.14 MiB of 16 (v5e compile, PR 44).
     whole = window is None and _whole_plane_3d(model, nz, ny, nx, itemsize)
     if window is not None:
         bz, by = (int(v) for v in window)
@@ -1567,7 +1589,8 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     rows = by + 2 * hy             # rows of a window
     nzb, nyb = nz // bz, ny // by
     vmem_ceiling = (by_cap is not None and by_cap < 0) or fuse >= 2 \
-        or not whole
+        or not whole or not _window_fits(model, nx, bz, rows, by, plan, R,
+                                         itemsize, _VMEM3D_DEFAULT)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -1824,8 +1847,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     adv = int(any(model.stages[s].load_densities
                   for s in model.actions["Iteration"]))
 
-    @partial(jax.jit, static_argnames=("niter",), donate_argnums=0)
-    def _iterate_jit(state: LatticeState, params: SimParams, niter: int
+    def _schedule(state: LatticeState, params: SimParams, niter: int
                      ) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
         fields = state.fields.astype(dtype)
@@ -1908,7 +1930,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
 
     def account(niter: int, has_series: bool = False) -> dict:
         """What one ``iterate(niter)`` issues, reckoned host-side from
-        the plan (the mirror of ``_iterate_jit``'s schedule): the calls,
+        the plan (the mirror of ``_schedule``): the calls,
         the windows of the looped kernel, the steps left over."""
         final = int(niter > 0 and (call_sg if has_series else call_g)
                     is not None)
@@ -1926,14 +1948,16 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             aux_planes=(1 + 2 * len(zonal_names) if has_series
                         else 1 if lean_aux else 1 + len(zonal_names)))
 
+    program = _donating_unless_one_call(_schedule)
+
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
-        out = _iterate_jit(state, params, niter)
+        did = account(int(niter), params.time_series is not None)
+        out = program(did)(state, params, niter)
         # a call under a trace (supports_3d()'s abstract probe, a
         # caller's own jit) issues nothing
         if telemetry.enabled() and not isinstance(out.fields,
                                                   jax.core.Tracer):
-            did = account(int(niter), params.time_series is not None)
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
             telemetry.counter("engine.paired_calls", did["paired_calls"])
             telemetry.annotate(**did)

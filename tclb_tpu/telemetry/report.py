@@ -6,7 +6,9 @@ BENCH/ROADMAP triage loop needs:
 * **per-engine iterate summary** — for every engine the dispatch ran
   (``iterate`` spans grouped by their ``engine`` field): chunks, total
   iterations, wall time and aggregate MLUPS (total node-updates / total
-  time);
+  time); and the engine of the step a hybrid engine leaves for the
+  Globals (``iterate.globals_step`` spans by their ``engine``: the
+  generic Pallas tail, or ``xla``);
 * **per-span table** — every span name with count/total/mean/max;
 * **segments** — the ``segment`` spans of ``<Solve>``'s loop by the
   handlers that ran in them: the span, what its fences waited, the
@@ -348,6 +350,7 @@ def summarize(evts: list[dict]) -> dict:
     JSON-serializable as-is)."""
     spans: dict[str, dict] = {}
     engines: dict[str, dict] = {}
+    tails: dict[str, dict] = {}
     selected: list[dict] = []
     fallbacks: list[dict] = []
     failchecks: list[dict] = []
@@ -390,6 +393,14 @@ def summarize(evts: list[dict]) -> dict:
                 g["node_updates"] += (float(e.get("nodes", 0.0))
                                       * float(e.get("iters", 0)))
                 g["total_s"] += dt
+            elif name == "iterate.globals_step":
+                # the step a hybrid engine leaves for the Globals, by
+                # the engine that ran it (a trace from before the span
+                # said so: "?")
+                g = tails.setdefault(e.get("engine", "?"),
+                                     {"steps": 0, "total_s": 0.0})
+                g["steps"] += int(e.get("iters", 1))
+                g["total_s"] += dt
         elif kind == "engine_selected":
             selected.append(e)
         elif kind == "engine_fallback":
@@ -406,6 +417,8 @@ def summarize(evts: list[dict]) -> dict:
         s["total_s"] = round(s["total_s"], 6)
         s["mean_s"] = round(s["total_s"] / max(s["count"], 1), 6)
         s["max_s"] = round(s["max_s"], 6)
+    for g in tails.values():
+        g["total_s"] = round(g["total_s"], 6)
     for g in engines.values():
         if g["total_s"] > 0 and g["node_updates"] > 0:
             # significant digits, not decimals: tiny smoke domains sit
@@ -415,7 +428,7 @@ def summarize(evts: list[dict]) -> dict:
             g["mlups"] = None
         g["total_s"] = round(g["total_s"], 6)
         del g["node_updates"]
-    return {"engines": engines, "spans": spans,
+    return {"engines": engines, "globals_steps": tails, "spans": spans,
             "segments": _segments_summary(evts),
             "serving": _serving_summary(evts),
             "adjoint": _adjoint_summary(evts),
@@ -749,6 +762,13 @@ def format_text(summary: dict) -> str:
                 f"  {eng:<44} {storage:>17} "
                 f"{g['chunks']:>6} {g['iters']:>9} "
                 f"{_fmt(g['total_s'], 3):>10} {_fmt(g['mlups'], 1):>10}")
+        lines.append("")
+    if summary.get("globals_steps"):
+        lines.append("trailing globals steps of the hybrid engines")
+        lines.append(f"  {'engine':<44} {'steps':>6} {'time_s':>10}")
+        for eng, g in sorted(summary["globals_steps"].items()):
+            lines.append(f"  {eng:<44} {g['steps']:>6} "
+                         f"{_fmt(g['total_s'], 4):>10}")
         lines.append("")
     if summary["spans"]:
         lines.append("spans")

@@ -311,7 +311,8 @@ def test_hygiene_fires_on_dead_entry_point(tmp_path):
                     "ops.fake_engine.make_foo_iterate"}
 
 
-def test_hygiene_fires_on_untraced_dispatch(tmp_path):
+@pytest.mark.parametrize("attr", ["_fast_name", "_tail_name"])
+def test_hygiene_fires_on_untraced_dispatch(tmp_path, attr):
     p = tmp_path / "lattice.py"
     p.write_text(
         "from tclb_tpu import telemetry\n"
@@ -322,7 +323,7 @@ def test_hygiene_fires_on_untraced_dispatch(tmp_path):
         "        try:\n"
         "            self._fast_iter(n)\n"
         "        except Exception:\n"
-        "            self._fast_name = None   # silent demotion\n")
+        f"            self.{attr} = None   # silent demotion\n")
     fs = hygiene.scan_dispatch_telemetry(lattice_path=str(p))
     checks = [f.check for f in fs]
     assert checks == ["hygiene.untraced_dispatch"] * 2
@@ -341,7 +342,7 @@ def test_hygiene_fires_on_untraced_dispatch(tmp_path):
         "        try:\n"
         "            self._fast_iter(n)\n"
         "        except Exception as e:\n"
-        "            self._fast_name = None\n"
+        f"            self.{attr} = None\n"
         "            telemetry.engine_fallback('pallas', 'xla', repr(e))\n")
     assert hygiene.scan_dispatch_telemetry(lattice_path=str(p)) == []
 

@@ -1,7 +1,8 @@
 """The fused Pallas kernel as the ENGINE, not a bench artifact.
 
 ``Lattice.iterate`` auto-selects the fused fast path (hybrid: Pallas for
-niter-1 steps + one XLA step refreshing globals) the way the reference's
+niter-1 steps + one step refreshing globals, on the generic Pallas
+engine's in-kernel-globals flavour or on XLA) the way the reference's
 tuned kernel IS its engine (reference src/Lattice.cu.Rt:414-457 →
 src/LatticeContainer.inc.cpp.Rt:247-266).  These tests force the dispatch on
 CPU (interpret mode) and pin the engine entry point — fields AND globals —
@@ -18,6 +19,36 @@ import pytest
 from tclb_tpu.core.lattice import Lattice
 from tclb_tpu.models import get_model
 from tclb_tpu.ops import lbm, pallas_d2q9, pallas_d3q, pallas_generic
+
+
+@pytest.fixture
+def seen():
+    """The event documents of a test, through a subscriber of its own."""
+    from tclb_tpu import telemetry
+    docs = []
+    telemetry.subscribe(docs.append)
+    yield docs
+    telemetry.unsubscribe(docs.append)
+
+
+def _spans(docs, name):
+    return [e for e in docs if e["kind"] == "span" and e["name"] == name]
+
+
+def _says_tail(seen, lat, fused, tail, calls):
+    """What a run on a hybrid engine with the Pallas tail says of
+    itself after ``calls`` calls of ``iterate``."""
+    assert (lat._fast_name, lat._tail_name) == (fused, tail)
+    steps = _spans(seen, "iterate.globals_step")
+    assert [e["engine"] for e in steps] == [tail] * calls
+    # the tail's account lands on its own span (the first call's on the
+    # probe's), never on the fused one: the rooflines read that
+    assert all("stages_per_step" in e for e in steps[1:])
+    assert not any("stages_per_step" in e
+                   for e in _spans(seen, "iterate.fused"))
+    assert [e["engine"] for e in _spans(seen, "engine.probe")] \
+        == [fused, tail]
+    assert not [e for e in seen if e["kind"] == "engine_fallback"]
 
 
 def _karman_lattice(ny=64, nx=128, wedge=False):
@@ -63,10 +94,13 @@ def test_supports_only_implemented_models():
     # the awkward part of the published 1024 x 100: two chunks of 50
     # rows (no multiple of 8), the periodic pull built by concatenation
     (100, 17, True)], ids=["64", "100"])
-def test_engine_dispatch_matches_xla(monkeypatch, ny, niter, wedge):
+def test_engine_dispatch_matches_xla(monkeypatch, seen, ny, niter, wedge):
     """Solver-path == pallas-path on the boundary-rich Kármán case:
     the engine entry point (Lattice.iterate) with the fast path forced
-    must reproduce the XLA engine's fields AND globals."""
+    must reproduce the XLA engine's fields AND globals.  The hybrid's
+    last step, which reduces them, runs on the generic Pallas engine's
+    one-step flavour; at 100 rows, where that engine's band would stand
+    on 28 ghost rows, it stays the XLA step."""
     monkeypatch.setenv("TCLB_FASTPATH", "0")   # pin pure XLA (even on TPU)
     _, lat_x = _karman_lattice(ny, wedge=wedge)
     lat_x.iterate(niter)
@@ -85,9 +119,21 @@ def test_engine_dispatch_matches_xla(monkeypatch, ny, niter, wedge):
     for k in gx:
         np.testing.assert_allclose(gf[k], gx[k], rtol=1e-4, atol=1e-6,
                                    err_msg=f"global {k}")
-    # the hybrid's trailing XLA step produced REAL (nonzero) globals
+    # the hybrid's trailing step produced REAL (nonzero) globals
     assert any(abs(v) > 0 for v in gf.values())
     assert int(lat_f.state.iteration) == niter
+    if ny == 64:
+        _says_tail(seen, lat_f, "pallas_resident[d2q9,fuse=8]",
+                   "pallas_generic[d2q9,fuse=1]", 1)
+        return
+    assert pallas_generic.make_pallas_iterate(
+        lat_f.model, (ny, 128), fuse=1).account(1, False)["pad_rows"] == 28
+    assert lat_f._tail is None and lat_f._tail_name is None
+    assert [e["engine"] for e in _spans(seen, "iterate.globals_step")] \
+        == ["xla"]
+    assert [e["engine"] for e in _spans(seen, "engine.probe")] \
+        == ["pallas_resident[d2q9,fuse=8]"]
+    assert not [e for e in seen if e["kind"] == "engine_fallback"]
 
 
 @pytest.fixture(scope="module")

@@ -593,3 +593,63 @@ def test_pin_is_identity_only_inside_a_compiled_body():
     assert "optimization_barrier" not in str(
         jax.make_jaxpr(lbm.mosaic_body(body, interpret=False))(x))
     assert "optimization_barrier" in str(jax.make_jaxpr(body)(x))
+
+
+@pytest.mark.parametrize("case", ["channel512", "tgv256", "karman1024"])
+def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
+                                                     case):
+    """The one step the hybrid engines leave for the Globals, as the
+    lattices of ``channel3d512``, ``tgv256`` and ``karman1024`` build it
+    (``Lattice._build_tail``; in 3D by shapes only): the generic engine's
+    one-step flavour that reduces the Globals in the kernel, one
+    ``generic_slab_fuse1`` / ``generic_band_fuse1`` call.  The channel's
+    one slab of 48 x 256 needs 16.14 MiB of scoped VMEM and compiles
+    under the raised ceiling, by the window's own account.  The program
+    of one call does not donate the state, so XLA puts no copy of it
+    (0.86 and 2.28 GB in 3D) between the fused program's output and the
+    kernel: donated, the call's output would have to be the buffer it
+    reads halos from."""
+    if case == "karman1024":
+        shape = (1024, 1024)
+        m, lat, _ = _channel("d2q9", shape, nu=0.02)
+        state, params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip),
+            (lat.state, lat.params))
+        kernel = "generic_band_fuse1"
+    else:
+        m, shape, state, params, present = (
+            _tgv_256 if case == "tgv256" else _channel_512)(one_chip)
+        # a small lattice that says the cell's shape and node types
+        lat = Lattice(m, (8, 8, 128), dtype=jnp.float32)
+        flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
+        if "Wall" in present:
+            flags[:, 0, :] = flags[:, -1, :] = m.flag_for("Wall")
+        lat.shape, lat._host_flags = shape, flags
+        kernel = "generic_slab_fuse1"
+    # the kernels are built to be compiled, as on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tail, tag = lat._build_tail()
+    by = ",by=32" if case == "tgv256" else ""
+    assert tag == f"pallas_generic[{m.name},fuse=1{by}]"
+    assert tail.full_globals
+    did = tail.account(1, False)
+    assert (did["kernel_calls"], did["aux_planes"]) == (1, 1)
+    if case != "karman1024":
+        assert tail.plan == {"channel512": (1, 48, 1),
+                             "tgv256": (4, 32, 1)}[case]
+    one = lambda s, p: tail(s, p, 1)    # noqa: E731
+    # the program the engine picks for its one call does not donate
+    inner, = [e for e in jax.make_jaxpr(one)(state, params).eqns
+              if e.primitive.name in ("pjit", "jit")]
+    assert not any(inner.params["donated_invars"])
+    text = jax.jit(one).lower(state, params).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"{kernel}/pallas_call" in text
+    assert not _state_copies(text.splitlines(), m, shape)
+    if case != "karman1024":
+        # donated, as every schedule of two calls and more is, it would
+        donated = jax.jit(one, donate_argnums=0)
+        assert len(_state_copies(donated.lower(
+            state, params).compile().as_text().splitlines(),
+            m, shape)) == 1

@@ -265,10 +265,13 @@ def test_forced_fallback_emits_events(tmp_path, monkeypatch, chain):
     assert len(fb) == 1
     assert (fb[0]["from"], fb[0]["to"]) == (selected, under)
     assert "synthetic mosaic failure" in fb[0]["cause"]
+    # the chain's own probe (the engine of a hybrid's trailing step has
+    # one of its own)
     probe = [e for e in evts
-             if e["kind"] == "span" and e["name"] == "engine.probe"]
+             if e["kind"] == "span" and e["name"] == "engine.probe"
+             and e["engine"] == selected]
     assert len(probe) == 1
-    assert (probe[0]["engine"], probe[0]["result"]) == (selected, under)
+    assert probe[0]["result"] == under
     assert (probe[0]["attempts"], probe[0]["rungs"]) == (2, [])
     # the iterate span records the engine that actually finished the chunk
     it = [e for e in evts if e["kind"] == "span" and e["name"] == "iterate"]
@@ -337,13 +340,16 @@ def test_exhausted_ladder_raises_on_tpu(tmp_path, monkeypatch, backend):
                         lambda *a, **k: False)
     # supports() trace-probes through the builder patched below
     monkeypatch.setattr(pallas_generic, "supports", lambda *a, **k: True)
-    rungs = []
+    rungs, failed = [], []
 
     def bad_band(model, shape, dtype, **kw):
+        # (the engine of a hybrid's trailing step is built here too,
+        # before the first call, and never run: nothing reached it)
         rungs.append((kw.get("fuse"), kw.get("by_cap")))
 
         def it(state, params, niter):
-            raise RuntimeError(f"synthetic mosaic failure #{len(rungs)}")
+            failed.append(rungs[-1])
+            raise RuntimeError(f"synthetic mosaic failure #{len(failed)}")
         return it
 
     monkeypatch.setattr(pallas_generic, "make_pallas_iterate", bad_band)
@@ -366,7 +372,7 @@ def test_exhausted_ladder_raises_on_tpu(tmp_path, monkeypatch, backend):
         assert lat._fast_name is None
         assert int(lat.state.iteration) == it0 + 4
     telemetry.disable()
-    assert len(rungs) > 1                     # the ladder was walked
+    assert len(failed) > 1                    # the ladder was walked
     fb = [e for e in report.load(str(trace))
           if e["kind"] == "engine_fallback"]
     assert [e["to"] for e in fb] == ([] if backend == "tpu" else ["xla"])
@@ -444,6 +450,30 @@ def test_summarize_engine_table(tmp_path):
     assert s["counters"] == {"halo.exchanges": 12}
     txt = report.format_text(s)
     assert "per-engine iterate summary" in txt and _ENG in txt
+
+
+@pytest.mark.parametrize("engines", [
+    ["pallas_generic[d3q27_cumulant,fuse=1]"] * 3,
+    ["xla", "pallas_generic[d2q9,fuse=1]", "pallas_generic[d2q9,fuse=1]"],
+    [None, None]], ids=["tail", "fell_back_once", "older_trace"])
+def test_report_lists_the_trailing_steps_by_engine(engines):
+    """``iterate.globals_step`` spans by the ``engine`` they say (a trace
+    from before they said one reads ``?``), with the counter beside."""
+    evts = [dict({"kind": "span", "ts": 1.0, "name": "iterate.globals_step",
+                  "dur_s": 0.005, "iters": 1},
+                 **({"engine": eng} if eng else {})) for eng in engines]
+    tail_calls = sum(bool(e) and e != "xla" for e in engines)
+    evts.append({"kind": "counters", "ts": 2.0,
+                 "counters": {"engine.tail_calls": tail_calls}})
+    s = report.summarize(evts)
+    assert s["globals_steps"] == {
+        eng or "?": {"steps": engines.count(eng),
+                     "total_s": round(0.005 * engines.count(eng), 6)}
+        for eng in set(engines)}
+    txt = report.format_text(s)
+    assert "trailing globals steps of the hybrid engines" in txt
+    assert all((eng or "?") in txt for eng in engines)
+    assert f"engine.tail_calls{'':<23} {tail_calls}" in txt
 
 
 def test_compare_detects_injected_slowdown(tmp_path):
@@ -801,10 +831,13 @@ def test_a_fence_says_what_it_waited_and_a_launch_what_it_cost(solved):
     assert "dispatch_s" not in probed
     assert [e["name"] for e in spans if e["parent"] == probed["id"]] \
         == ["engine.probe"]
-    launches = [fused] + [e for e in spans
-                          if e["name"] == "iterate.globals_step"]
-    assert len(launches) == 3
-    for e in launches:
+    # so is the first call of the engine of the hybrid's trailing step
+    tail_probed, step = [e for e in spans
+                         if e["name"] == "iterate.globals_step"]
+    assert "dispatch_s" not in tail_probed
+    assert [e["name"] for e in spans if e["parent"] == tail_probed["id"]] \
+        == ["engine.probe"]
+    for e in (fused, step):
         assert 0 < e["dispatch_s"] <= e["dur_s"] - e["wait_s"] + 2e-6
 
 

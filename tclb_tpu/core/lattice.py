@@ -797,7 +797,7 @@ class EngineCandidate:
     the real state, whose exception goes through."""
 
     tag: str                        # its name in events, spans, _fast_name
-    build: Callable[[], Callable]   # () -> iterate(state, params, niter)
+    build: Callable[[], Callable]   # () -> ops.engine.Engine
     probe: bool = False
     cap: int = 0   # the band cap it stands for, a rung of ``engine.probe``
     verdict: Optional[tuple] = None   # generic band engine only: the
@@ -1114,7 +1114,7 @@ class Lattice:
                 return []
             return [EngineCandidate(
                 f"pallas_sharded[{dict(self.mesh.shape)},fuse={it.fuse}]",
-                lambda: it, probe=getattr(it, "uses_generic", False))]
+                lambda: it, probe=it.unproven)]
         if not has_series and pallas_d2q9.supports(model, shape, sdt):
             chain = [cand(f"pallas_2d[{name},fuse=2]",
                           pallas_d2q9.make_pallas_iterate, fuse=2)]
@@ -1261,8 +1261,7 @@ class Lattice:
         except Exception as e:  # noqa: BLE001
             self._tail_failed(cand.tag, e)
             return None, None
-        if getattr(it, "full_globals", False) \
-                and not it.account(1, False).get("pad_rows"):
+        if it.full_globals and not it.pad_rows:
             return it, cand.tag
         return None, None
 
@@ -1288,7 +1287,7 @@ class Lattice:
             self._fast = chain[0].build() if chain else None
             self._fast_name = chain[0].tag if chain else None
             self._fast_probing = bool(chain) and chain[0].probe
-            full = getattr(self._fast, "full_globals", False)
+            full = self._fast is not None and self._fast.full_globals
             self._tail, self._tail_name = (
                 self._build_tail() if self._fast is not None and not full
                 else (None, None))
@@ -1353,11 +1352,11 @@ class Lattice:
         # flavour where it takes the case, else the XLA step) instead.
         # Engines advertising supports_series gather Control time series
         # per iteration themselves; others fall back to XLA for those.
-        full = bool(getattr(fast, "full_globals", False))
-        ok_series = (self.params.time_series is None
-                     or getattr(fast, "supports_series", False))
+        full = fast is not None and fast.full_globals
         nfast = niter if full else niter - 1
-        use_fast = fast is not None and ok_series and nfast >= 1
+        use_fast = (fast is not None and nfast >= 1
+                    and (self.params.time_series is None
+                         or fast.supports_series))
         done = nfast if use_fast else niter
         # dispatch_s: the jitted call has returned, the fence not begun;
         # a probed first call (compile, fallback ladder) leaves it out
@@ -1376,7 +1375,7 @@ class Lattice:
                               result=self._fast_name or "xla")
                     probe.sync(self.state)
             else:
-                self.state = fast(self.state, self.params, nfast)
+                self.state = self._run_engine(fast, self.state, nfast)
                 sp.mark("dispatch_s")
             sp.add(iters=done,
                    engine=(self._fast_name if use_fast else None) or "xla")
@@ -1387,18 +1386,42 @@ class Lattice:
                 if self._tail_probing:
                     self._probe_tail()
                 else:
-                    self.state = (self._tail or self._iterate)(
-                        self.state, self.params, 1)
+                    self.state = (
+                        self._run_engine(self._tail, self.state, 1)
+                        if self._tail is not None
+                        else self._iterate(self.state, self.params, 1))
                     sp.mark("dispatch_s")
                 if self._tail is not None:
                     telemetry.counter("engine.tail_calls")
                 sp.add(engine=self._tail_name or "xla")
                 sp.sync(self.state)
 
+    def _run_engine(self, engine, state: LatticeState, niter: int
+                    ) -> LatticeState:
+        """The one place a fused engine is called (the fused call, the
+        tail call and both probes), and the one that reports it: with
+        telemetry on, its account of the call (``Engine.account``) as
+        the counters ``engine.kernel_calls``, ``engine.resident_calls``
+        and ``engine.paired_calls`` and as fields of the innermost open
+        span (``iterate.fused``, ``engine.probe`` or
+        ``iterate.globals_step``), once the call has returned: a
+        candidate that fails its probe reports nothing."""
+        out = engine(state, self.params, niter)
+        if telemetry.enabled() and engine.account is not None:
+            did = engine.account(niter, self.params.time_series is not None)
+            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
+            if "resident_calls" in did:
+                telemetry.counter("engine.resident_calls",
+                                  did["resident_calls"])
+            telemetry.counter("engine.paired_calls", did["paired_calls"])
+            telemetry.annotate(**did)
+        return out
+
     def _probe_tail(self) -> None:
         """The first step of the tail engine (:meth:`_build_tail`), on
         the state itself: its program of one kernel call does not donate
-        (``pallas_generic._donating_unless_one_call``), so a failure
+        (``pallas_generic._donating_unless_one_call``, beside the generic
+        engines' one schedule, ``_scheduled_engine``), so a failure
         leaves the state whole.  Where it does not compile, or fails as
         it runs, the XLA step takes over with one ``engine_fallback``
         event and the run goes on: unlike a fused engine's steps, this
@@ -1409,7 +1432,7 @@ class Lattice:
             try:
                 # fenced inside the try: a failure at execution shows here
                 self.state = jax.block_until_ready(
-                    self._tail(self.state, self.params, 1))
+                    self._run_engine(self._tail, self.state, 1))
             except Exception as e:  # noqa: BLE001
                 self._tail_failed(tag, e)
                 self.state = self._iterate(self.state, self.params, 1)
@@ -1441,7 +1464,7 @@ class Lattice:
                 # buffers deleted
                 state = (jax.tree.map(jnp.copy, self.state) if cand.probe
                          else self.state)
-                self.state = it(state, self.params, nfast)
+                self.state = self._run_engine(it, state, nfast)
             except Exception as e:  # noqa: BLE001
                 if not cand.probe:
                     # a proven engine on the real state is the end of

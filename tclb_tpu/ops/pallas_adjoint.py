@@ -280,7 +280,7 @@ def _make_diff_step_3d(model: Model, shape, dtype=jnp.float32,
         k = plan3[0]
     base = pallas_generic.make_pallas_iterate_3d(
         model, shape, dtype, interpret=interpret, fuse=1, present=present)
-    impl = base._impl
+    impl = base.impl
     call_g = impl["call_g"]
     if call_g is None:
         raise ValueError(f"{model.name}: 3D diff step needs the "
@@ -720,7 +720,7 @@ def make_diff_step(model: Model, shape, dtype=jnp.float32,
     base = pallas_generic.make_pallas_iterate(
         model, shape, dtype, interpret=interpret, fuse=1, present=present,
         full_band=True)
-    impl = base._impl
+    impl = base.impl
     if impl["pad"] != 0:
         raise ValueError("diff step requires an unpadded band layout")
     mk_call = impl["mk_call"]

@@ -49,12 +49,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tclb_tpu import telemetry
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, lbm
+from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
 from tclb_tpu.ops.lbm import equilibrium, present_types  # noqa: F401
-from tclb_tpu.ops.pallas_d3q import _PAIR
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
 
@@ -230,7 +229,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 
     A call of ``n`` steps dispatches up to two programs: ``n // 8``
     resident calls in one ``lax.scan`` (two calls a loop body,
-    ``_PAIR``: no copy of the carry), and, where 8 does not divide
+    ``engine.PAIR``: no copy of the carry), and, where 8 does not divide
     ``n``, the ``n % 8`` steps left over on a second engine, the
     single-step band kernel of :func:`make_pallas_iterate` with its
     ghost rows (an XLA pad before its calls and a slice after them).
@@ -239,7 +238,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     generic band kernel's one-step flavour, or XLA), so a handler interval
     that is a multiple of 8 (100, 500, 1000) takes the second engine
     for 3 or 7 steps in every call: three programs a segment.  With
-    telemetry on, the call says what it issued on the open span
+    telemetry on, dispatch says what the call issued on the open span
     (``account``).
 
     Same NoGlobals + no-Control contract as the band kernels."""
@@ -336,6 +335,15 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
 
     zshift = model.zone_shift
 
+    def split(niter: int) -> tuple:
+        """``niter`` steps as the resident calls and the steps left
+        over, which cannot be more resident calls (the fuse is baked
+        in): ``iterate`` runs them through the single-step band kernel
+        of make_pallas_iterate.  The Lattice hybrid hands over niter - 1
+        steps, so that happens in every call whose length is a multiple
+        of 8."""
+        return divmod(niter, _RESIDENT_FUSE)
+
     @partial(jax.jit, static_argnames=("niter",), donate_argnums=0)
     def _iterate_jit(state: LatticeState, params: SimParams, niter: int
                      ) -> LatticeState:
@@ -347,38 +355,26 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         def body(fields, _):
             return call(sett, fields, flags_i32, vel, den), None
 
-        # _PAIR calls a body, an odd call after the loop: no copy of the
-        # carry before a call (ops/pallas_d3q._PAIR)
-        fields, _ = jax.lax.scan(body, state.fields, None,
-                                 length=niter // _RESIDENT_FUSE,
-                                 unroll=_PAIR)
-        # the steps left over cannot be more resident calls (the fuse is
-        # baked in): ``iterate`` below runs them through the single-step
-        # band kernel of make_pallas_iterate.  The Lattice hybrid hands
-        # over niter - 1 steps, so that happens in every call whose
-        # length is a multiple of 8
+        calls, _ = split(niter)
         return LatticeState(
-            fields=fields,
+            fields=scan_calls(body, state.fields, calls, True),
             flags=state.flags,
             globals_=jnp.zeros_like(state.globals_),
-            iteration=state.iteration + (niter // _RESIDENT_FUSE)
-            * _RESIDENT_FUSE,
+            iteration=state.iteration + calls * _RESIDENT_FUSE,
         )
 
     band = make_pallas_iterate(model, shape, dtype, interpret=interpret,
                                fuse=1, present=present)
 
-    def account(niter: int) -> dict:
-        """What one ``iterate(niter)`` issues, reckoned host-side (the
-        mirror of ``iterate``'s split): the resident calls, and the steps
-        left over with the shape of the band calls that run them."""
-        calls, rest = divmod(int(niter), _RESIDENT_FUSE)
+    def account(niter: int, has_series: bool = False) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side from
+        ``iterate``'s own split: the resident calls, and the steps left
+        over with the shape of the band calls that run them (the band
+        engine's loop of them is single)."""
+        calls, rest = split(int(niter))
         return dict(
-            band.band_shape, kernel_calls=calls + rest,
-            # resident calls issued from a two-call loop body: a loop of
-            # one trip or none is no loop (lax.scan unrolls it whole); the
-            # band engine's loop of the steps left over is single
-            paired_calls=calls - calls % _PAIR if calls >= 2 * _PAIR else 0,
+            band.impl["band_shape"], kernel_calls=calls + rest,
+            paired_calls=paired_calls(calls),
             resident_calls=calls, resident_steps=_RESIDENT_FUSE,
             remainder_steps=rest, aux_planes=_AUX_PLANES,
             remainder_aux_planes=_AUX_PLANES, chunk_rows=chunk,
@@ -390,24 +386,13 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
             raise ValueError(
                 "pallas iterate does not support Control time series; "
                 "use the XLA path for time-dependent zonal settings")
-        main = (niter // _RESIDENT_FUSE) * _RESIDENT_FUSE
-        state = _iterate_jit(state, params, main)
-        rest = niter - main
+        rest = split(niter)[1]
+        state = _iterate_jit(state, params, niter - rest)
         if rest:
             state = band(state, params, rest)
-        # a call under a trace (a caller's own jit) issues nothing
-        if telemetry.enabled() and not isinstance(state.fields,
-                                                  jax.core.Tracer):
-            did = account(niter)
-            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
-            telemetry.counter("engine.resident_calls",
-                              did["resident_calls"])
-            telemetry.counter("engine.paired_calls", did["paired_calls"])
-            telemetry.annotate(**did)
         return state
 
-    iterate.account = account
-    return iterate
+    return Engine(iterate, account)
 
 
 def _make_step_ctx(model: Model, present=None):
@@ -877,24 +862,24 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 return f.at[:, ny - 2:, :].set(
                     fields[:, ny_phys - 2:ny_phys, :])
 
-        # both loops: one call a body, not _PAIR.  Compiled paired at
-        # 1024 x 1024, one of the two state buffers leaves the compiler's
-        # fast memory and kernel2 waits for its input copies; a micro-run
-        # read the paired loop faster all the same: PERF.md section 7
+        # both loops single, paired=False: compiled paired at 1024 x
+        # 1024, one of the two state buffers leaves the compiler's fast
+        # memory and kernel2 waits for its input copies; a micro-run
+        # read the paired loop faster all the same (PERF.md section 7,
+        # PR 43's compile; ROADMAP S1)
         if fuse == 2:
             aux = jnp.stack([flags_i32.astype(dtype), vel, den])
 
             def body2(fields, _):
                 return call2(sett, refresh(fields), aux), None
 
-            fields, _ = jax.lax.scan(body2, fields, None,
-                                     length=niter // 2)
+            fields = scan_calls(body2, fields, niter // 2, False)
         rest = niter % 2 if fuse == 2 else niter
 
         def body(fields, _):
             return call(sett, refresh(fields), flags_i32, vel, den), None
 
-        fields, _ = jax.lax.scan(body, fields, None, length=rest)
+        fields = scan_calls(body, fields, rest, False)
         if pad:
             fields = fields[:, :ny_phys, :]
         return LatticeState(
@@ -916,8 +901,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 "use the XLA path for time-dependent zonal settings")
         return _iterate_jit(state, params, niter, fuse=fuse)
 
-    # the single-step kernel's bands, for the resident engine's account
-    # of the steps it leaves to this one
-    iterate.band_shape = dict(bands=ny // by, band_rows=by, halo_rows=8,
-                              pad_rows=pad)
-    return iterate
+    # reports nothing (no account); band_shape: the single-step kernel's
+    # bands, for the resident engine's account of the steps it leaves to
+    # this one
+    return Engine(iterate, pad_rows=pad, impl=dict(
+        band_shape=dict(bands=ny // by, band_rows=by, halo_rows=8,
+                        pad_rows=pad)))

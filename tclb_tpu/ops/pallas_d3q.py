@@ -52,12 +52,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tclb_tpu import telemetry
 from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.models import family
 from tclb_tpu.ops import cumulant, fusion, lbm
+from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
 
 _SUPPORTED = ("d3q27_BGK", "d3q27_BGK_galcor", "d3q27_cumulant",
               "d3q19", "d3q19_les")
@@ -101,13 +101,6 @@ _RECOMPUTE_PLANES = 23
 # window starts on a tile boundary; it covers any K <= fusion.FUSE_MAX
 # (the in-window y roll spoils one row a side per step)
 _HALO_Y = 8
-# kernel calls a loop body of _iterate_jit.  A loop's carry is one buffer
-# and a custom call cannot write the buffer it reads: with one call a
-# body XLA copies the whole state before every call (a third of the
-# device's time at 512 x 48 x 256 and at 256^3); with two, state
-# A -> B -> A, the call that writes the carry is not the one that reads
-# it.  Right for every plan, so a constant
-_PAIR = 2
 
 E = cumulant.velocity_set(3)
 W = lbm.weights(E)
@@ -1042,6 +1035,12 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     call_r = (None if tiled is None else call_f if K == 1
               else fused_call(*rem_cfg))
 
+    def split(niter: int) -> tuple:
+        """``niter`` steps as the trips of the two loops: ``fused``
+        calls of K steps and ``rest`` calls of one."""
+        fused = niter // K if cfg else 0
+        return fused, niter - fused * K
+
     @partial(jax.jit, static_argnames=("niter",), donate_argnums=0)
     def _iterate_jit(state: LatticeState, params: SimParams,
                      niter: int) -> LatticeState:
@@ -1057,12 +1056,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             return lambda fields, _: (
                 call_k(sett, ztab, fields, flags_i32), None)
 
-        # both loops: _PAIR calls a body, an odd call after the loop
-        rem = niter
-        if K >= 2:
-            fields, _ = jax.lax.scan(body_f(call_f), fields, None,
-                                     length=niter // K, unroll=_PAIR)
-            rem = niter % K
+        fused, rest = split(niter)
+        if cfg:
+            fields = scan_calls(body_f(call_f), fields, fused, True)
         if tiled is not None:
             body = body_f(call_r)
         else:
@@ -1074,8 +1070,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             def body(fields, _):
                 return call(sett, fields, flags_i32, zonal), None
 
-        fields, _ = jax.lax.scan(body, fields, None, length=rem,
-                                 unroll=_PAIR)
+        fields = scan_calls(body, fields, rest, True)
         return LatticeState(
             fields=fields,
             flags=state.flags,
@@ -1083,17 +1078,13 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             iteration=state.iteration + niter,
         )
 
-    def account(niter: int) -> dict:
+    def account(niter: int, has_series: bool = False) -> dict:
         """What one ``iterate(niter)`` issues, reckoned host-side from
-        the plan (the mirror of ``_iterate_jit``'s schedule): the fused
-        calls' windows, and the steps left to the single-step kernel."""
-        fused = niter // K if cfg else 0
-        rest = niter - fused * K
-        # calls issued from a two-call loop body: a loop of one trip or
-        # none is no loop (lax.scan unrolls it whole)
-        paired = sum(n - n % _PAIR for n in (fused, rest) if n >= 2 * _PAIR)
+        the plan and ``_iterate_jit``'s own split: the fused calls'
+        windows, and the steps left to the single-step kernel."""
+        fused, rest = split(int(niter))
         did = dict(kernel_calls=fused + rest, remainder_steps=rest,
-                   paired_calls=paired)
+                   paired_calls=paired_calls(fused, rest))
         if fused:
             bzp, byp, _ = cfg
             did.update(
@@ -1112,15 +1103,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             raise ValueError(
                 "pallas iterate does not support Control time series; "
                 "use the XLA path for time-dependent zonal settings")
-        out = _iterate_jit(state, params, niter)
-        # a call under a trace (a caller's own jit) issues nothing
-        if telemetry.enabled() and not isinstance(out.fields,
-                                                  jax.core.Tracer):
-            did = account(int(niter))
-            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
-            telemetry.counter("engine.paired_calls", did["paired_calls"])
-            telemetry.annotate(**did)
-        return out
+        return _iterate_jit(state, params, niter)
 
-    iterate.account = account
-    return iterate
+    return Engine(iterate, account)

@@ -52,27 +52,12 @@ from tclb_tpu.core.lattice import (LatticeState, NodeCtx, SimParams,
                                    series_dt_overrides, series_overrides)
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, lbm
+from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
 from tclb_tpu.ops.lbm import present_types  # noqa: F401  (re-export)
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024
 _HALO = 8   # DMA halo block height: one (8, 128) f32 tile per side
 HALO = _HALO  # public: max per-action reach a caller can plan against
-# kernel calls a loop body of the scans that carry the state through a
-# kernel: the 2D band engine's three, the 3D slab engine's non-series
-# two.  A loop's carry is one buffer and a custom call cannot write the
-# buffer it reads: with one call a body XLA copies the whole carry before
-# every call; with two, state A -> B -> A, the call that writes the carry
-# is not the one that reads it (ops/pallas_d3q's _PAIR, PR 33).  Right
-# for every plan, so a constant
-_PAIR = 2
-
-
-def _paired_calls(*trips: int) -> int:
-    """Of loops of ``trips`` kernel calls each, the calls a two-call loop
-    body issues: a loop's calls less its odd one; a loop of one trip or
-    none is no loop (``lax.scan`` unrolls it whole)."""
-    return sum(n - n % _PAIR for n in trips if n >= 2 * _PAIR)
-
 # storage dtypes the generic engines can keep in HBM.  Compute is ALWAYS
 # f32: field planes are widened right after the VMEM read and narrowed
 # on the output write, and the aux stack (flags + zonal planes) stays
@@ -88,8 +73,8 @@ _COMPUTE_DTYPE = jnp.float32
 
 def _donating_unless_one_call(schedule: Callable) -> Callable:
     """``schedule(state, params, niter)`` compiled twice, and
-    ``program(did)``, which picks the one to run from the engine's
-    account of a call: donating the state for every schedule of two
+    ``program(calls)``, which picks the one to run from the kernel calls
+    of the schedule: donating the state for every schedule of two
     kernel calls and more (a call reads what the call before it wrote),
     not donating it for a schedule of one call.  That call reads halos
     of the state while it writes, so where its output has to be the
@@ -99,7 +84,180 @@ def _donating_unless_one_call(schedule: Callable) -> Callable:
     copy."""
     jit = partial(jax.jit, schedule, static_argnames=("niter",))
     donating, once = jit(donate_argnums=0), jit()
-    return lambda did: once if did["kernel_calls"] == 1 else donating
+    return lambda calls: once if calls == 1 else donating
+
+
+def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
+                      mk_call: Callable, mk_call1: Callable,
+                      window: Callable, impl: dict,
+                      ghost: Optional[tuple] = None,
+                      series_paired: bool = True, **fields) -> Engine:
+    """The :class:`Engine` of the generic band (2D) and slab (3D)
+    builders, from their kernel flavours: one split of ``niter``, the
+    schedule that loops the calls by it, the account of what it issues
+    and the program that runs it.  ``mk_call(lean=)`` builds the call of
+    ``fuse`` steps, ``mk_call1(with_dt=, with_globals=, lean=)`` those
+    of one.  ``window(fused)``: the shape fields of the account of
+    ``fused`` looped calls.  ``impl``: the builder's internals for the
+    differentiable wrappers of ``ops/pallas_adjoint``, which drive the
+    forward globals kernel (``call_g``, added here) outside the
+    scanning iterate.  ``ghost``: ``(enter, refresh, leave)`` of a band
+    that stands on ghost rows: ``enter(flags, fields)`` appends them,
+    ``refresh(fields)`` renews them before a call, ``leave(fields)``
+    drops them; None where there are none.  ``series_paired``: whether
+    the series loop runs two calls a body.  ``fields``: what else the
+    engine declares."""
+    cdtype = _COMPUTE_DTYPE
+    zonal_names = list(model.zonal_settings)
+    zonal_si = [model.setting_index[nm] for nm in zonal_names]
+    zshift = model.zone_shift
+    # aux diet: the non-series flavors DMA ONLY the flag plane — zonal
+    # settings are iteration-invariant there, a pure function of the
+    # flag zone bits, so they are reconstructed in-kernel from the SMEM
+    # zone table (fusion.zone_plane) instead of riding every HBM round
+    # trip as full planes.  Series flavors keep the full aux stack (the
+    # per-iteration _DT overrides genuinely change per step).
+    lean_aux = len(zonal_names) > 0
+    call = mk_call(lean=lean_aux)
+    call1 = call if fuse == 1 else mk_call1(lean=lean_aux)
+    # in-kernel globals flavor (final step of an iterate call): SUM only —
+    # MAX would need max-combining across bands/stages (no model uses MAX)
+    can_globals = (0 < model.n_globals <= 8   # the (8, 128) partials block
+                   and nx % 128 == 0
+                   and all(g.op == "SUM" for g in model.globals_))
+    call_g = mk_call1(with_globals=True, lean=lean_aux) \
+        if can_globals else None
+    # Control-series flavors: per-iteration zonal + _DT planes, fuse=1
+    # (fused steps would reuse iteration t's settings at t+1)
+    call_s = mk_call1(with_dt=True)
+    call_sg = mk_call1(with_dt=True, with_globals=True) \
+        if can_globals else None
+    # one action rep advances the iteration counter iff any stage streams
+    adv = int(any(model.stages[s].load_densities
+                  for s in model.actions["Iteration"]))
+    enter, refresh, leave = ghost or (
+        lambda flags, fields: (flags, fields), lambda f: f, lambda f: f)
+
+    def split(niter: int, has_series: bool = False) -> tuple:
+        """``niter`` steps as the trips of the two loops and the final
+        Globals call: ``fused`` calls of ``fuse`` steps (none under a
+        series: fused steps would reuse iteration t's settings at t+1),
+        ``rest`` calls of one step, ``final`` 0 or 1."""
+        final = int(niter > 0
+                    and (call_sg if has_series else call_g) is not None)
+        main = max(niter, 0) - final
+        fused = 0 if has_series else main // fuse
+        return fused, main - fused * fuse, final
+
+    def _schedule(state: LatticeState, params: SimParams, niter: int
+                  ) -> LatticeState:
+        flags_i32, fields = enter(state.flags.astype(jnp.int32),
+                                  state.fields.astype(dtype))
+        zones = flags_i32 >> zshift
+        sett = params.settings.astype(cdtype)
+        has_series = params.time_series is not None
+
+        # loop-invariant pieces (XLA hoists them out of the step scan):
+        # the base zonal planes and the affected-zone masks.  Per step
+        # only scalar masked selects remain — indexing a modified zone
+        # table with the zone ids inside the scan was an unhoistable
+        # gather, ~25 ms/step at 1024^2
+        flags_f = flags_i32.astype(cdtype)
+        base_planes = [fusion.zone_plane(
+            params.zone_table[k].astype(cdtype), zones) for k in zonal_si]
+
+        def aux_of(it):
+            return assemble_aux(params, zones, flags_f, base_planes,
+                                zonal_si, it, cdtype, with_dt=has_series)
+
+        if niter <= 0:
+            return state
+        fused, rest, final = split(niter, has_series)
+        carry = (fields, state.iteration)
+
+        if has_series:
+            # series flavors keep the full host-assembled aux stack: the
+            # dt planes depend on the Control series, not just zone bits
+            def invoke(c, it, fields):
+                return c(sett, it[None], refresh(fields), aux_of(it))
+
+            def body_s(carry, _):
+                fields, it = carry
+                return (invoke(call_s, it, fields), it + adv), None
+
+            fields, it = scan_calls(body_s, carry, rest, series_paired)
+        else:
+            if lean_aux:
+                # the DMA'd aux stack is the flag plane alone, every
+                # step, however many zonal settings the model declares;
+                # the zone table rides in SMEM and the kernel rebuilds
+                # the (iteration-invariant) zonal planes itself
+                ztab = jnp.concatenate(
+                    [params.zone_table[k].astype(cdtype) for k in zonal_si])
+                aux = flags_f[None]
+
+                def invoke(c, it, fields):
+                    return c(sett, it[None], ztab, refresh(fields), aux)
+            else:
+                aux = aux_of(state.iteration)
+
+                def invoke(c, it, fields):
+                    return c(sett, it[None], refresh(fields), aux)
+
+            def body(carry, _):
+                fields, it = carry
+                return (invoke(call, it, fields), it + adv * fuse), None
+
+            def body1(carry, _):
+                fields, it = carry
+                return (invoke(call1, it, fields), it + adv), None
+
+            fields, it = scan_calls(body, carry, fused, True)
+            if fuse > 1:
+                fields, it = scan_calls(body1, (fields, it), rest, True)
+
+        globals_ = jnp.zeros_like(state.globals_)
+        if final:
+            fields, gpart = invoke(call_sg if has_series else call_g,
+                                   it, fields)
+            it = it + adv
+            globals_ = gpart[:model.n_globals].sum(axis=1).astype(
+                state.globals_.dtype)
+        return LatticeState(fields=leave(fields), flags=state.flags,
+                            globals_=globals_, iteration=it)
+
+    def account(niter: int, has_series: bool = False) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side from
+        the plan and ``_schedule``'s own split: the calls, the windows
+        of the looped kernel, the steps left over."""
+        fused, rest, final = split(int(niter), has_series)
+        return dict(
+            stages_per_step=len(model.actions["Iteration"]),
+            kernel_calls=fused + rest + final, remainder_steps=rest + final,
+            paired_calls=paired_calls(fused, rest)
+            if series_paired or not has_series else 0,
+            **window(fused),
+            # the f32 flag plane (and what rides beside it) of each window
+            aux_planes=(1 + 2 * len(zonal_names) if has_series
+                        else 1 if lean_aux else 1 + len(zonal_names)))
+
+    program = _donating_unless_one_call(_schedule)
+
+    def iterate(state: LatticeState, params: SimParams, niter: int
+                ) -> LatticeState:
+        calls = sum(split(int(niter), params.time_series is not None))
+        return program(calls)(state, params, niter)
+
+    # the engine handles Control time series itself, and (when the
+    # globals flavor exists) returns the LAST step's Globals — no trailing
+    # step needed (and a hybrid engine's trailing step can be this
+    # engine's iterate(.., 1))
+    return Engine(iterate, account, supports_series=True,
+                  full_globals=bool(model.n_globals == 0
+                                    or call_g is not None),
+                  impl=dict(impl, call_g=call_g, lean_aux=lean_aux,
+                            zonal_si=zonal_si, zshift=zshift, adv=adv,
+                            cdtype=cdtype), **fields)
 
 
 def _storage_ok(dtype) -> bool:
@@ -632,15 +790,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     zonal_names = list(model.zonal_settings)
     zshift = model.zone_shift
     zone_max = model.zone_max
-    si = model.setting_index
-    zonal_si = [si[nm] for nm in zonal_names]
-    # aux diet: the non-series flavors DMA ONLY the flag plane — zonal
-    # settings are iteration-invariant there, a pure function of the
-    # flag zone bits, so they are reconstructed in-kernel from the SMEM
-    # zone table (fusion.zone_plane) instead of riding every HBM round
-    # trip as full planes.  Series flavors keep the full aux stack (the
-    # per-iteration _DT overrides genuinely change per step).
-    lean_aux = len(zonal_names) > 0
     nt_present = set(model.node_types) if present is None else set(present)
     if pad > 2 * mirror:
         nt_present = nt_present | {"Wall"}   # middle ghost rows are walls
@@ -843,179 +992,37 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         # halo composer assembles + exchanges aux planes host-side
         return _mk_call(plan), by, zonal_names
 
-    call = _mk_call(plan, lean=lean_aux)
     plan1 = plan if fuse == 1 \
         else action_plan(model, "Iteration", fuse=1)[0]
-    call1 = call if fuse == 1 else _mk_call(plan1, lean=lean_aux)
-    # in-kernel globals flavor (final step of an iterate call): SUM only —
-    # MAX would need max-combining across bands/stages (no model uses MAX)
-    can_globals = (nx % 128 == 0
-                   and model.n_globals <= 8   # the (8, 128) partials block
-                   and all(g.op == "SUM" for g in model.globals_))
-    call_g = _mk_call(plan1, with_globals=True, lean=lean_aux) \
-        if can_globals and model.n_globals else None
-    # Control-series flavors: per-iteration zonal + _DT planes, fuse=1
-    # (fused steps would reuse iteration t's settings at t+1)
-    call_s = _mk_call(plan1, with_dt=True)
-    call_sg = _mk_call(plan1, with_dt=True, with_globals=True) \
-        if can_globals and model.n_globals else None
-    # one action rep advances the iteration counter iff any stage streams
-    adv = int(any(model.stages[s].load_densities
-                  for s in model.actions["Iteration"]))
 
-    def _schedule(state: LatticeState, params: SimParams, niter: int
-                     ) -> LatticeState:
-        flags_i32 = state.flags.astype(jnp.int32)
-        fields = state.fields.astype(dtype)
-        if pad:
-            # ghost layout: [mirror rows 0..m-1, walls, mirror ny-m..ny-1]
-            mid = pad - 2 * mirror
-            init_src = jnp.asarray(np.array(
-                list(range(mirror)) + [0] * mid
-                + list(range(ny_phys - mirror, ny_phys))))
-            gflags = flags_i32[init_src]
-            if mid:
-                wall = jnp.int32(model.flag_for("Wall"))
-                gflags = gflags.at[mirror:mirror + mid].set(wall)
-            flags_i32 = jnp.concatenate([flags_i32, gflags], axis=0)
-            fields = jnp.concatenate([fields, fields[:, init_src, :]],
-                                     axis=1)
-        zones = flags_i32 >> zshift
-        sett = params.settings.astype(cdtype)
-        has_series = params.time_series is not None
+    # the ghost rows of a band that is no multiple of its rows
+    def enter(flags_i32, fields):
+        # ghost layout: [mirror rows 0..m-1, walls, mirror ny-m..ny-1]
+        mid = pad - 2 * mirror
+        init_src = jnp.asarray(np.array(
+            list(range(mirror)) + [0] * mid
+            + list(range(ny_phys - mirror, ny_phys))))
+        gflags = flags_i32[init_src]
+        if mid:
+            wall = jnp.int32(model.flag_for("Wall"))
+            gflags = gflags.at[mirror:mirror + mid].set(wall)
+        return (jnp.concatenate([flags_i32, gflags], axis=0),
+                jnp.concatenate([fields, fields[:, init_src, :]], axis=1))
 
-        # loop-invariant pieces (XLA hoists them out of the step scan):
-        # the base zonal planes and the affected-zone masks.  Per step
-        # only scalar masked selects remain — indexing a modified zone
-        # table with the zone ids inside the scan was an unhoistable
-        # gather, ~25 ms/step at 1024^2
-        flags_f = flags_i32.astype(cdtype)
-        base_planes = [fusion.zone_plane(
-            params.zone_table[k].astype(cdtype), zones) for k in zonal_si]
+    def refresh(fields):
+        f = fields.at[:, ny_phys:ny_phys + mirror, :].set(
+            fields[:, 0:mirror, :])
+        return f.at[:, ny - mirror:, :].set(
+            fields[:, ny_phys - mirror:ny_phys, :])
 
-        def aux_of(it):
-            return assemble_aux(params, zones, flags_f, base_planes,
-                                zonal_si, it, cdtype, with_dt=has_series)
-
-        def refresh(fields):
-            if not pad:
-                return fields
-            f = fields.at[:, ny_phys:ny_phys + mirror, :].set(
-                fields[:, 0:mirror, :])
-            return f.at[:, ny - mirror:, :].set(
-                fields[:, ny_phys - mirror:ny_phys, :])
-
-        final_g = call_sg if has_series else call_g
-        if niter <= 0:
-            return state
-        main = niter - (1 if final_g is not None else 0)
-
-        if has_series:
-            def body_s(carry, _):
-                fields, it = carry
-                out = call_s(sett, it[None], refresh(fields), aux_of(it))
-                return (out, it + adv), None
-
-            (fields, it), _ = jax.lax.scan(
-                body_s, (fields, state.iteration), None, length=main,
-                unroll=_PAIR)
-        else:
-            if lean_aux:
-                # aux diet: the DMA'd aux stack is the flag plane alone;
-                # the zone table rides in SMEM and the kernel rebuilds
-                # the (iteration-invariant) zonal planes itself
-                ztab = jnp.concatenate(
-                    [params.zone_table[k].astype(cdtype) for k in zonal_si])
-                aux = flags_f[None]
-
-                def invoke(c, it, fields):
-                    return c(sett, it[None], ztab, refresh(fields), aux)
-            else:
-                aux = aux_of(state.iteration)
-
-                def invoke(c, it, fields):
-                    return c(sett, it[None], refresh(fields), aux)
-
-            def body(carry, _):
-                fields, it = carry
-                return (invoke(call, it, fields), it + adv * fuse), None
-
-            def body1(carry, _):
-                fields, it = carry
-                return (invoke(call1, it, fields), it + adv), None
-
-            # both loops: _PAIR calls a body, an odd call after the loop
-            (fields, it), _ = jax.lax.scan(
-                body, (fields, state.iteration), None, length=main // fuse,
-                unroll=_PAIR)
-            (fields, it), _ = jax.lax.scan(
-                body1, (fields, it), None, length=main % fuse,
-                unroll=_PAIR)
-
-        globals_ = jnp.zeros_like(state.globals_)
-        if final_g is not None:
-            if has_series:
-                fields, gpart = final_g(sett, it[None], refresh(fields),
-                                        aux_of(it))
-            else:
-                fields, gpart = invoke(final_g, it, fields)
-            it = it + adv
-            globals_ = gpart[:model.n_globals].sum(axis=1).astype(
-                state.globals_.dtype)
-
-        if pad:
-            fields = fields[:, :ny_phys, :]
-        return LatticeState(
-            fields=fields,
-            flags=state.flags,
-            globals_=globals_,
-            iteration=it,
-        )
-
-    def account(niter: int, has_series: bool) -> dict:
-        """What one ``iterate(niter)`` issues, reckoned host-side from
-        the shapes (the mirror of ``_schedule``)."""
-        final = int(niter > 0 and (call_sg if has_series else call_g)
-                    is not None)
-        main = max(niter, 0) - final
-        fused = 0 if has_series else main // fuse
-        rest = main - fused * fuse
-        return dict(
-            stages_per_step=len(model.actions["Iteration"]),
-            band_rows=by, halo_rows=_HALO, pad_rows=pad, bands=ny // by,
-            kernel_calls=fused + rest + final, remainder_steps=rest + final,
-            paired_calls=_paired_calls(fused, rest),
-            aux_planes=(1 + 2 * len(zonal_names) if has_series
-                        else 1 if lean_aux else 1 + len(zonal_names)))
-
-    program = _donating_unless_one_call(_schedule)
-
-    def iterate(state: LatticeState, params: SimParams, niter: int
-                ) -> LatticeState:
-        did = account(int(niter), params.time_series is not None)
-        out = program(did)(state, params, niter)
-        # a call under a trace (supports()'s abstract probe, a caller's
-        # own jit) issues nothing
-        if telemetry.enabled() and not isinstance(out.fields,
-                                                  jax.core.Tracer):
-            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
-            telemetry.counter("engine.paired_calls", did["paired_calls"])
-            telemetry.annotate(**did)
-        return out
-
-    # contract flags the Lattice dispatch keys on: the engine handles
-    # Control time series itself, and (when the globals flavor exists)
-    # returns the LAST step's Globals — no trailing step needed (and a
-    # hybrid engine's trailing step can be this engine's iterate(.., 1))
-    iterate.supports_series = True
-    iterate.full_globals = bool(model.n_globals == 0 or call_g is not None)
-    # internals for make_diff_step (the differentiable single-step path
-    # reuses the forward globals kernel verbatim)
-    iterate._impl = dict(call1=call1, call_g=call_g, by=by, pad=pad,
-                         zonal_si=zonal_si, zshift=zshift,
-                         nt_present=nt_present, mk_call=_mk_call)
-    iterate.account = account
-    return iterate
+    return _scheduled_engine(
+        model, dtype, nx, fuse, partial(_mk_call, plan),
+        partial(_mk_call, plan1),
+        window=lambda fused: dict(band_rows=by, halo_rows=_HALO,
+                                  pad_rows=pad, bands=ny // by),
+        impl=dict(by=by, pad=pad, nt_present=nt_present, mk_call=_mk_call),
+        ghost=(enter, refresh, lambda f: f[:, :ny_phys, :]) if pad else None,
+        pad_rows=pad)
 
 
 # --------------------------------------------------------------------------- #
@@ -1067,7 +1074,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
                           chunk_cap: int = 64,
                           shift: Optional[np.ndarray] = None):
     """Generic VMEM-resident engine: ONE kernel launch advances a whole
-    ``iterate(n)``: ``resident_length(n)`` steps (an even length that
+    ``iterate(n)``: the first part of ``split(n)`` (an even length that
     leaves the band engine's globals flavour a step where the model
     declares Globals) ride the kernel's grid with the state ping-ponging
     between two on-chip stacks, and the one or two steps left over run on
@@ -1211,23 +1218,25 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     # EVEN resident length (ping-pong parity) leaving >=1 step for the
     # band engine's globals flavor when the model declares Globals
     # (full_globals contract)
-    tail_min = 1 if getattr(band, "full_globals", False) \
-        and model.n_globals else 0
+    tail_min = 1 if band.full_globals and model.n_globals else 0
 
-    def resident_length(niter: int) -> int:
-        return max(niter - tail_min, 0) // 2 * 2
+    def split(niter: int) -> tuple:
+        """``niter`` steps as the steps of the one resident call and
+        the steps left to the band engine."""
+        main = max(niter - tail_min, 0) // 2 * 2
+        return main, niter - main
 
-    def account(niter: int) -> dict:
-        """What one ``iterate(niter)`` issues, reckoned host-side (the
-        mirror of ``iterate``'s split): one resident call of ``main``
-        steps, and the band engine's own account of the steps left to
-        it, whose ``aux_planes`` goes by ``remainder_aux_planes``."""
-        main = resident_length(niter)
-        rest = band.account(niter - main, False)
+    def account(niter: int, has_series: bool = False) -> dict:
+        """What one ``iterate(niter)`` issues, reckoned host-side from
+        ``iterate``'s own split: one resident call of ``main`` steps,
+        and the band engine's own account of the steps left to it,
+        whose ``aux_planes`` goes by ``remainder_aux_planes``."""
+        main, left = split(int(niter))
+        rest = band.account(left)
         return dict(
             rest, kernel_calls=int(main > 0) + rest["kernel_calls"],
             resident_calls=int(main > 0), resident_steps=main,
-            remainder_steps=niter - main, aux_planes=n_aux,
+            remainder_steps=left, aux_planes=n_aux,
             remainder_aux_planes=rest["aux_planes"], chunk_rows=chunk,
             vmem_bytes=resident_vmem_bytes(model, ny, nx, dtype))
 
@@ -1236,28 +1245,14 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         if params.time_series is not None:
             raise ValueError("generic resident engine does not support "
                              "Control time series")
-        main = resident_length(niter)
+        main, left = split(niter)
         if main:
             state = _resident_jit(state, params, main)
-        rest = niter - main
-        if rest:
-            state = band(state, params, rest)
-        # a call under a trace (a caller's own jit) issues nothing; the
-        # band engine has counted and annotated its own calls, and this
-        # account of the whole call lies over its fields
-        if telemetry.enabled() and not isinstance(state.fields,
-                                                  jax.core.Tracer):
-            did = account(int(niter))
-            telemetry.counter("engine.kernel_calls", did["resident_calls"])
-            telemetry.counter("engine.resident_calls",
-                              did["resident_calls"])
-            telemetry.annotate(**did)
+        if left:
+            state = band(state, params, left)
         return state
 
-    iterate.supports_series = False
-    iterate.full_globals = getattr(band, "full_globals", False)
-    iterate.account = account
-    return iterate
+    return Engine(iterate, account, full_globals=band.full_globals)
 
 
 # --------------------------------------------------------------------------- #
@@ -1600,11 +1595,6 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     zonal_names = list(model.zonal_settings)
     zshift = model.zone_shift
     zone_max = model.zone_max
-    si = model.setting_index
-    zonal_si = [si[nm] for nm in zonal_names]
-    # same aux diet as 2D: non-series flavors DMA flags only and rebuild
-    # zonal planes in-kernel from the SMEM zone table
-    lean_aux = len(zonal_names) > 0
     ei = model.ei
     stage_fns = {nm: model.stage_fns[model.stages[nm].main]
                  for nm in model.actions["Iteration"]}
@@ -1835,141 +1825,13 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             name=f"generic_slab_fuse{fuse if plan_k is plan else 1}",
         )
 
-    call = _mk_call(plan, R, lean=lean_aux)
-    call1 = call if fuse == 1 else _mk_call(plan1, R1, lean=lean_aux)
-    can_globals = (nx % 128 == 0 and model.n_globals <= 8
-                   and all(g.op == "SUM" for g in model.globals_))
-    call_g = _mk_call(plan1, R1, with_globals=True, lean=lean_aux) \
-        if can_globals and model.n_globals else None
-    call_s = _mk_call(plan1, R1, with_dt=True)
-    call_sg = _mk_call(plan1, R1, with_dt=True, with_globals=True) \
-        if can_globals and model.n_globals else None
-    adv = int(any(model.stages[s].load_densities
-                  for s in model.actions["Iteration"]))
-
-    def _schedule(state: LatticeState, params: SimParams, niter: int
-                     ) -> LatticeState:
-        flags_i32 = state.flags.astype(jnp.int32)
-        fields = state.fields.astype(dtype)
-        zones = flags_i32 >> zshift
-        sett = params.settings.astype(cdtype)
-        has_series = params.time_series is not None
-        flags_f = flags_i32.astype(cdtype)
-        base_planes = [fusion.zone_plane(
-            params.zone_table[k].astype(cdtype), zones) for k in zonal_si]
-
-        def aux_of(it):
-            return assemble_aux(params, zones, flags_f, base_planes,
-                                zonal_si, it, cdtype, with_dt=has_series)
-
-        final_g = call_sg if has_series else call_g
-        if niter <= 0:
-            return state
-        main = niter - (1 if final_g is not None else 0)
-
-        if has_series:
-            # series flavors keep the full host-assembled aux stack: the
-            # dt planes depend on the Control series, not just zone bits
-            def body_s(carry, _):
-                fields, it = carry
-                out = call_s(sett, it[None], fields, aux_of(it))
-                return (out, it + adv), None
-
-            (fields, it), _ = jax.lax.scan(
-                body_s, (fields, state.iteration), None, length=main)
-        else:
-            # lean aux: iteration-invariant zonal planes are rebuilt
-            # in-kernel from the SMEM zone table — the aux DMA leg
-            # carries exactly one flags plane, every step, regardless of
-            # how many zonal settings the model declares
-            if lean_aux:
-                ztab = jnp.concatenate(
-                    [params.zone_table[k].astype(cdtype)
-                     for k in zonal_si])
-                aux = flags_f[None]
-
-                def invoke(c, it, fields):
-                    return c(sett, it[None], ztab, fields, aux)
-            else:
-                aux = aux_of(state.iteration)
-
-                def invoke(c, it, fields):
-                    return c(sett, it[None], fields, aux)
-
-            def body(carry, _):
-                fields, it = carry
-                out = invoke(call, it, fields)
-                return (out, it + adv * fuse), None
-
-            def body1(carry, _):
-                fields, it = carry
-                out = invoke(call1, it, fields)
-                return (out, it + adv), None
-
-            # both loops: _PAIR calls a body, an odd call after the loop
-            (fields, it), _ = jax.lax.scan(
-                body, (fields, state.iteration), None,
-                length=main // fuse, unroll=_PAIR)
-            if fuse > 1:
-                (fields, it), _ = jax.lax.scan(
-                    body1, (fields, it), None, length=main % fuse,
-                    unroll=_PAIR)
-
-        globals_ = jnp.zeros_like(state.globals_)
-        if final_g is not None:
-            if has_series:
-                fields, gpart = final_g(sett, it[None], fields,
-                                        aux_of(it))
-            else:
-                fields, gpart = invoke(final_g, it, fields)
-            it = it + adv
-            globals_ = gpart[:model.n_globals].sum(axis=1).astype(
-                state.globals_.dtype)
-        return LatticeState(fields=fields, flags=state.flags,
-                            globals_=globals_, iteration=it)
-
-    def account(niter: int, has_series: bool = False) -> dict:
-        """What one ``iterate(niter)`` issues, reckoned host-side from
-        the plan (the mirror of ``_schedule``): the calls,
-        the windows of the looped kernel, the steps left over."""
-        final = int(niter > 0 and (call_sg if has_series else call_g)
-                    is not None)
-        main = max(niter, 0) - final
-        fused = 0 if has_series else main // fuse
-        rest = main - fused * fuse
-        return dict(
-            stages_per_step=len(model.actions["Iteration"]),
-            kernel_calls=fused + rest + final, remainder_steps=rest + final,
-            # the series loop runs one call a body
-            paired_calls=0 if has_series else _paired_calls(fused, rest),
+    return _scheduled_engine(
+        model, dtype, nx, fuse, partial(_mk_call, plan, R),
+        partial(_mk_call, plan1, R1),
+        window=lambda fused: dict(
             z_bands=nzb, band_slabs=bz, halo_slabs=R if fused else R1,
-            y_bands=nyb, band_rows=by, halo_rows=hy,
-            # the f32 flag plane (and what rides beside it) of each window
-            aux_planes=(1 + 2 * len(zonal_names) if has_series
-                        else 1 if lean_aux else 1 + len(zonal_names)))
-
-    program = _donating_unless_one_call(_schedule)
-
-    def iterate(state: LatticeState, params: SimParams, niter: int
-                ) -> LatticeState:
-        did = account(int(niter), params.time_series is not None)
-        out = program(did)(state, params, niter)
-        # a call under a trace (supports_3d()'s abstract probe, a
-        # caller's own jit) issues nothing
-        if telemetry.enabled() and not isinstance(out.fields,
-                                                  jax.core.Tracer):
-            telemetry.counter("engine.kernel_calls", did["kernel_calls"])
-            telemetry.counter("engine.paired_calls", did["paired_calls"])
-            telemetry.annotate(**did)
-        return out
-
-    iterate.supports_series = True
-    iterate.full_globals = bool(model.n_globals == 0 or call_g is not None)
-    iterate.account = account
-    iterate.plan = (bz, by, fuse)
-    # internals for the differentiable wrapper (ops/pallas_adjoint's 3D
-    # diff step drives call_g directly, outside the scanning iterate)
-    iterate._impl = dict(call_g=call_g, call_sg=call_sg, lean_aux=lean_aux,
-                         zonal_si=zonal_si, zshift=zshift, adv=adv,
-                         cdtype=cdtype, bz=bz, R=R)
-    return iterate
+            y_bands=nyb, band_rows=by, halo_rows=hy),
+        impl=dict(bz=bz),
+        # the series loop runs one call a body: pairing it is not
+        # measured (ROADMAP M2)
+        series_paired=False, plan=(bz, by, fuse))

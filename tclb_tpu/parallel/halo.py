@@ -240,6 +240,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
     specialization: ``globals_`` is zeroed; the Lattice hybrid's trailing
     XLA step (which psums) supplies them."""
     from tclb_tpu.ops import fusion, pallas_d2q9, pallas_d3q
+    from tclb_tpu.ops.engine import Engine, scan_calls
     try:
         _validate_mesh(model, mesh)
     except ValueError:
@@ -307,6 +308,12 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         fields=field_spec(mesh), flags=flag_spec(mesh),
         globals_=P(), iteration=P())
 
+    def split(niter: int) -> tuple:
+        """``niter`` steps as the trips of the loop (calls of two fused
+        steps in the tuned 2D mode, of one elsewhere) and the odd call
+        after it."""
+        return divmod(niter, 2) if mode == "tuned2d" else (niter, 0)
+
     @lru_cache(maxsize=None)
     def _for_niter(niter: int):
         def local_iterate(state: LatticeState, params: SimParams
@@ -315,6 +322,9 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             zones = flags_i32 >> zshift
             sett = params.settings.astype(dtype)
             fields = state.fields
+            trips, odd = split(niter)
+            # the three loops are single, paired=False: pairing a loop
+            # whose body exchanges halos is not measured (ROADMAP M2)
             if mode == "generic2d":
                 aux_ext = exch(jnp.stack(
                     [flags_i32.astype(dtype)]
@@ -327,8 +337,8 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                     out = callg(sett, it[None], exch(f), aux_ext)
                     return (out, it + g_adv), None
 
-                (fields, _), _ = lax.scan(
-                    bodyg, (fields, state.iteration), None, length=niter)
+                fields, _ = scan_calls(bodyg, (fields, state.iteration),
+                                       trips, False)
             elif model.ndim == 2:
                 vel, den = pallas_d2q9.zonal_planes(
                     model, params, zones, dtype)
@@ -338,9 +348,8 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                 def body2(f, _):
                     return call2(sett, exch(f), aux_ext), None
 
-                fields, _ = lax.scan(body2, fields, None,
-                                     length=niter // 2)
-                if niter % 2:
+                fields = scan_calls(body2, fields, trips, False)
+                if odd:
                     fields = call1(sett, exch(fields), flags_i32, vel,
                                    den)
             else:
@@ -351,7 +360,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                 def body3(f, _):
                     return call3(sett, exch(f), flags_i32, zonal), None
 
-                fields, _ = lax.scan(body3, fields, None, length=niter)
+                fields = scan_calls(body3, fields, trips, False)
             return LatticeState(
                 fields=fields,
                 flags=state.flags,
@@ -374,19 +383,16 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             # fields per kernel call (a fused pair of steps in the tuned
             # 2D mode) plus the aux stack once per call; the wall time is
             # the enclosing span's business
-            calls = (int(niter) // 2 + int(niter) % 2
-                     if mode == "tuned2d" else int(niter))
-            _count_halo(int(niter),
-                        calls * field_bytes + aux_planes * plane_bytes)
+            _count_halo(int(niter), sum(split(int(niter))) * field_bytes
+                        + aux_planes * plane_bytes)
         return out
 
-    # the generic-kernel building block is capability-probed, not proven:
-    # the Lattice dispatch probes its first call and falls back to the
-    # sharded XLA engine on a Mosaic lowering failure
-    iterate.uses_generic = (mode == "generic2d")
-    # steps per kernel call, for the engine tag
-    iterate.fuse = 2 if mode == "tuned2d" else 1
-    return iterate
+    # no account: the kernel calls are not reported.  The generic-kernel
+    # building block is capability-probed, not proven: dispatch probes
+    # its first call and falls back to the sharded XLA engine on a Mosaic
+    # lowering failure.  fuse: steps per kernel call, for the engine tag
+    return Engine(iterate, unproven=(mode == "generic2d"),
+                  fuse=2 if mode == "tuned2d" else 1)
 
 
 def make_sharded_iterate(model: Model, mesh: Mesh,

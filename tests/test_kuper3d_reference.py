@@ -172,7 +172,7 @@ def test_whole_plane_plans_are_the_parents(name, shape, plan):
     it = pallas_generic.make_pallas_iterate_3d(m, shape, jnp.float32,
                                                interpret=True, fuse=K)
     assert it.plan == plan
-    assert it._impl["bz"] == plan[0]
+    assert it.impl["bz"] == plan[0]
     did = it.account(10)
     assert (did["y_bands"], did["band_rows"], did["halo_rows"]) \
         == (1, shape[1], 0)

@@ -144,8 +144,8 @@ def test_pallas_float32_against_the_reference(engine, reference32, tmp_path,
         it = pallas_generic.make_pallas_iterate(
             m, SHAPE, jnp.float32, fuse=fuse,
             present=present_types(m, np.asarray(lat.state.flags)))
-        assert it.full_globals and it._impl["by"] == 32
-        assert it._impl["pad"] == 0
+        assert it.full_globals and it.impl["by"] == 32
+        assert it.impl["pad"] == 0
         state = it(jax.tree.map(jnp.copy, lat.state), lat.params, STEPS32)
     program = np.asarray(state.fields)
     assert np.isfinite(program).all()
@@ -335,7 +335,7 @@ def test_build_fast_picks_the_engine(example, tag, monkeypatch):
     if "resident" not in tag:
         plan, reach = pallas_generic.action_plan(m, "Iteration", fuse=4)
         assert (len(plan), reach) == (8, pallas_generic.HALO)
-        assert lat._fast._impl["by"] == 32 and lat._fast._impl["pad"] == 0
+        assert lat._fast.impl["by"] == 32 and lat._fast.impl["pad"] == 0
         assert lat._fast.full_globals
 
 

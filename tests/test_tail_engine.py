@@ -196,9 +196,7 @@ def test_tail_engine_falls_back_to_the_xla_step(monkeypatch, seen, fails):
             def iterate(state, params, niter):
                 jax.block_until_ready(state)    # not donated: alive
                 raise RuntimeError("scoped vmem exceeded")
-            iterate.full_globals = it.full_globals
-            iterate.account = it.account
-            return iterate
+            return dataclasses.replace(it, run=iterate)
         return dataclasses.replace(cand, build=build)
     monkeypatch.setattr(Lattice, "_generic_cand", broken)
     _, lat = _bgk_lattice()

@@ -20,6 +20,7 @@ from tclb_tpu.core import lattice as lattice_mod
 from tclb_tpu.core.lattice import Lattice
 from tclb_tpu.models import get_model
 from tclb_tpu.ops import pallas_d2q9
+from tclb_tpu.ops.engine import Engine
 from tclb_tpu.telemetry import report
 from tclb_tpu.telemetry import spans as spans_mod
 from tclb_tpu.telemetry.spans import NOOP_SPAN
@@ -189,7 +190,7 @@ def test_lattice_iterate_emits_engine_and_span(tmp_path, monkeypatch):
 def _failing_engine(*args, **kw):
     def it(state, params, niter):
         raise RuntimeError("synthetic mosaic failure")
-    return it
+    return Engine(it)
 
 
 def _chain_resident(monkeypatch):
@@ -350,7 +351,7 @@ def test_exhausted_ladder_raises_on_tpu(tmp_path, monkeypatch, backend):
         def it(state, params, niter):
             failed.append(rungs[-1])
             raise RuntimeError(f"synthetic mosaic failure #{len(failed)}")
-        return it
+        return Engine(it)
 
     monkeypatch.setattr(pallas_generic, "make_pallas_iterate", bad_band)
     m = get_model("d2q9_kuper")

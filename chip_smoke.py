@@ -155,6 +155,8 @@ def spoil(solver) -> int:
 REHEARSAL = {
     "karman.xml": (40, {"nx": 256}),
     "karman_1024.xml": (20, {"nx": 256, "ny": 512}),
+    # the probes reach x 920 and y 612: only the rows above them go
+    "karman_1024_probes.xml": (12, {"nx": 1024, "ny": 640}),
     "3d_channel.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
     "3d_channel_512.xml": (8, {"nx": 128, "ny": 16, "nz": 16}),
     "tgv_256.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
@@ -503,6 +505,9 @@ def one_chip(s: Smoke) -> None:
             ("pallas_resident[d2q9,", "pallas_2d[d2q9,"))
     s.phase("2d_karman_1024", s.run, s.case("karman_1024.xml"),
             ("pallas_2d[d2q9,fuse=2]",))
+    # <Sample>: the sampled run keeps the tuned band, one step a call
+    s.phase("2d_karman_1024_probes", s.run, s.case("karman_1024_probes.xml"),
+            ("pallas_2d[d2q9,fuse=1]",))
     s.phase("3d_channel", s.run, s.case("3d_channel.xml", 1000), cumulant)
     s.phase("3d_channel_512", s.run, s.case("3d_channel_512.xml"), cumulant)
     # a 256 x 256 plane, which the engine tiles in y; its initial field is

@@ -620,6 +620,9 @@ class cbSample(Handler):
             y = int(round(s.units.alt(p.get("dy", "0"))))
             z = int(round(s.units.alt(p.get("dz", "0"))))
             pts.append((z, y, x)[-s.model.ndim:])
+            if any(not 0 <= i < n for i, n in zip(pts[-1], s.shape)):
+                raise ValueError(f"Sampler <Point> {pts[-1]} lies outside "
+                                 f"the lattice {s.shape}")
         from tclb_tpu.utils.sampler import Sampler
         self.sampler = Sampler(s.model, quants, np.asarray(pts),
                                s.out_path("Sample", "csv", with_iter=False),
@@ -633,7 +636,8 @@ class cbSample(Handler):
 
     def finish(self) -> int:
         self.sampler.flush()
-        self.solver.lattice.sampler = None
+        # through the lattice, so that the engine is selected again
+        self.solver.lattice.detach_sampler()
         return 0
 
     def restorable_state(self) -> dict:

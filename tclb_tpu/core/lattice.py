@@ -25,6 +25,7 @@ and shardable (parallel/halo.py wraps it in ``shard_map``).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from functools import lru_cache, partial
 from typing import Any, Callable, Optional, Sequence
@@ -689,9 +690,12 @@ def make_sampled_iterate(model: Model, points: np.ndarray,
     ring buffer (reference updateAllSamples, src/Lattice.cu.Rt:1212-1225).
 
     ``points`` is (npoints, ndim) in array index order (z, y, x / y, x).
-    Returns ``iterate(state, params, niter) -> (state, samples)`` with
-    samples shaped (niter, npoints, ncols); vector quantities contribute
-    their components as consecutive columns.
+    Returns ``iterate(state, params, niter) -> (state, (samples, its))``
+    with samples shaped (niter, npoints, ncols) and ``its`` the state's
+    iteration after each step; vector quantities contribute their
+    components as consecutive columns.  The scan of what no fused engine
+    takes (:meth:`Lattice._samples_on_engine`): every quantity is
+    evaluated on the whole plane every step.
     """
     step = make_action_step(model, action, streaming)
     idx = tuple(jnp.asarray(points[:, k].astype(np.int32))
@@ -716,7 +720,7 @@ def make_sampled_iterate(model: Model, points: np.ndarray,
                 avg_start=0):
         def body(s, _):
             s2 = step(s, params)
-            return s2, sample(s2, params, avg_start)
+            return s2, (sample(s2, params, avg_start), s2.iteration)
         return jax.lax.scan(body, state, None, length=niter)
 
     return iterate
@@ -786,6 +790,59 @@ def nonfinite_program(model: Model, name: str, cdtype: Any,
         return jnp.sum(~jnp.isfinite(plane), dtype=jnp.int32)
 
     return jax.jit(count), traces
+
+
+class _NeighbourRead(Exception):
+    """A quantity asked :func:`taps_program`'s context for another node."""
+
+
+@lru_cache(maxsize=None)
+def taps_program(model: Model, quantities: tuple, cdtype: Any
+                 ) -> tuple[Callable, list[int]]:
+    """What turns a sampled engine's taps into ``<Sample>``'s columns:
+    ``program(taps, flags, params, iteration, behind, avg_start) ->
+    (samples, its)``, shaped (rows, P, ncols) and (rows,) as
+    :func:`make_sampled_iterate`'s, and the count of its traces, as
+    :func:`quantity_program` keeps it.  ``taps`` is a tuple of stacks
+    ``(n_k, planes, P)``, the stored planes at the P sample points after
+    each of an ``iterate``'s steps in turn (the fused call's, then the
+    tail step's); ``flags`` the (P,) flags of the points; ``iteration``
+    the state's now, ``behind`` steps after the last of the taps.  The
+    model's own quantity functions run on a :class:`NodeCtx` whose
+    lattice is the (rows, P) gathered nodes, so a sample is the
+    arithmetic of :meth:`Lattice.get_quantity` at that node and no plane
+    is evaluated; a vector quantity's components are consecutive
+    columns.  A quantity that reads a neighbour
+    (``ctx.load`` with an offset) raises :class:`_NeighbourRead` as it is
+    traced: :meth:`Lattice._samples_on_engine` asks before dispatch."""
+    fns = [model.quantity_fns[q] for q in quantities]
+    traces = [0]
+
+    def evaluate(taps, flags, params, iteration, behind, avg_start):
+        traces[0] += 1
+        fields = jnp.moveaxis(jnp.concatenate(taps), 1, 0).astype(cdtype)
+        rows = fields.shape[1]
+        its = (jnp.asarray(iteration, jnp.int32) - behind - rows + 1
+               + jnp.arange(rows, dtype=jnp.int32))
+
+        def own_node(index, dx, dy, dz):
+            if dx or dy or dz:
+                raise _NeighbourRead(index, dx, dy, dz)
+            return fields[index]
+
+        ctx = NodeCtx(model, fields, fields,
+                      jnp.broadcast_to(flags, (rows,) + flags.shape),
+                      params, loader=own_node, iteration=its[:, None],
+                      avg_start=avg_start)
+        cols = []
+        for fn in fns:
+            with jax.default_matmul_precision("highest"):
+                v = fn(ctx)
+            cols.append(v[..., None] if v.ndim == 2
+                        else jnp.moveaxis(v, 0, -1))
+        return jnp.concatenate(cols, axis=-1), its
+
+    return jax.jit(evaluate), traces
 
 
 @dataclasses.dataclass(frozen=True)
@@ -915,8 +972,15 @@ class Lattice:
                     fields=ddf.narrow_stack(out.fields, _sdt, _sb))
             step_init = _init_narrow
         self._init = jax.jit(step_init, donate_argnums=0)
+        # <Sample>'s sampler (attach_sampler), the XLA scan of the runs no
+        # fused engine takes, the flags of the sample points on the
+        # device, and what the calls of the iterate under way have left
+        # for the sampler: ("taps", stack) of a sampled engine, ("rows",
+        # (samples, its)) of the XLA scan
         self.sampler = None
         self._iterate_sampled = None
+        self._sample_flags = None
+        self._sampled: list = []
         self.avg_start = 0    # iteration of the last <Average> reset
         # fused Pallas fast path: built lazily at the first iterate() so the
         # painted flags are known (the 3D kernel specializes on present node
@@ -1063,7 +1127,49 @@ class Lattice:
         return EngineCandidate(
             tag, lambda: pallas_generic.make_pallas_iterate(
                 model, shape, sdt, present=present, fuse=fz, by_cap=by_cap,
-                shift=self._shift_vec), **how)
+                shift=self._shift_vec, points=self._engine_points()),
+            **how)
+
+    def _engine_points(self) -> Optional[np.ndarray]:
+        """The sample points a fused engine is built with: those of the
+        attached sampler, None without one."""
+        return None if self.sampler is None else self.sampler.points
+
+    def _samples_on_engine(self) -> bool:
+        """Whether a fused engine may take the run of the attached
+        sampler.  Not on a mesh (the taps would be a gather across
+        shards), not with storage narrower than the compute dtype (the
+        taps could not be widened as the quantity's own seam does), not
+        under a ``<Control>`` series, and not where a quantity reads a
+        neighbour (:func:`taps_program` has the node alone): those keep
+        :func:`make_sampled_iterate`."""
+        if (self.mesh is not None or self.params.time_series is not None
+                or self.storage_dtype != jnp.dtype(self.dtype)):
+            return False
+        npts, i32 = len(self.sampler.points), jnp.int32
+        try:
+            self._with_taps_program(lambda program: jax.eval_shape(
+                program,
+                (jax.ShapeDtypeStruct((1, self.model.n_storage, npts),
+                                      self.storage_dtype),),
+                jax.ShapeDtypeStruct((npts,), FLAG_DTYPE), self.params,
+                *[jax.ShapeDtypeStruct((), i32)] * 3))
+        except _NeighbourRead:
+            return False
+        return True
+
+    def _with_taps_program(self, use: Callable):
+        """``use(program)`` of the attached sampler's
+        :func:`taps_program`, a trace of it counted as
+        :meth:`_run_quantity` counts a quantity program's."""
+        program, traces = taps_program(
+            self.model, tuple(self.sampler.quantities), jnp.dtype(self.dtype))
+        before = traces[0]
+        try:
+            return use(program)
+        finally:
+            if traces[0] != before:
+                telemetry.counter("quantity.programs_built")
 
     def _build_fast(self) -> list:
         """The fused Pallas engines that can take this configuration, as
@@ -1090,6 +1196,16 @@ class Lattice:
         # only the generic engine implements — skip the tuned kernels
         # (set_setting_series invalidates the engine so this re-runs)
         has_series = self.params.time_series is not None
+        # a sampler (<Sample>) needs what every step left at its points:
+        # the engines that can hand that out run one step a kernel call
+        # and say so in their tag; those whose call is many steps
+        # on-chip (the two VMEM-resident engines) are left out, as is the
+        # tuned 3D family, which has no such flavour (attach_sampler and
+        # detach_sampler invalidate the engine so this re-runs)
+        sampled = self.sampler is not None
+        if sampled and not self._samples_on_engine():
+            return []
+        points = self._engine_points()
         # engines receive the STORAGE dtype: their HBM stacks and DMA
         # scratch narrow with it while their compute stays f32 (each
         # kernel family widens on read / narrows on write); f32-only
@@ -1116,6 +1232,10 @@ class Lattice:
                 f"pallas_sharded[{dict(self.mesh.shape)},fuse={it.fuse}]",
                 lambda: it, probe=it.unproven)]
         if not has_series and pallas_d2q9.supports(model, shape, sdt):
+            if sampled:
+                return [cand(f"pallas_2d[{name},fuse=1]",
+                             pallas_d2q9.make_pallas_iterate, fuse=1,
+                             points=points)]
             chain = [cand(f"pallas_2d[{name},fuse=2]",
                           pallas_d2q9.make_pallas_iterate, fuse=2)]
             if pallas_d2q9.supports_resident(model, shape, sdt):
@@ -1128,6 +1248,8 @@ class Lattice:
                                      probe=True))
             return chain
         if not has_series and pallas_d3q.supports(model, shape, sdt):
+            if sampled:
+                return []
             make = pallas_d3q.make_pallas_iterate
             k3 = pallas_d3q.choose_fuse(model, shape, itemsize=s_itemsize)
 
@@ -1164,8 +1286,9 @@ class Lattice:
         if not (analysis.kernel_safety_ok(model)
                 and pallas_generic.mosaic_ok(model, shape)):
             return []
-        fits_resident = not has_series and pallas_generic.supports_resident(
-            model, shape, sdt)
+        fits_resident = (not has_series and not sampled
+                         and pallas_generic.supports_resident(
+                             model, shape, sdt))
         if not (fits_resident or pallas_generic.supports(model, shape, sdt)):
             return []
 
@@ -1175,14 +1298,15 @@ class Lattice:
         if cfg is not None:
             # this model/shape already proved it compiles: skip the
             # first-call probe (and its full-state copy)
-            return [band(*cfg)]
+            return [band(1 if sampled else cfg[0], cfg[1])]
         # temporal fusion amortizes one HBM round trip over K steps; the
         # shared planner caps K by the stencil reach fitting the halo (2D:
         # fixed 8-row block; deep-stencil models like lee at reach 6/step
         # stay fuse=1) or by the traffic model vs the K=1 engine (3D: slab
         # halos grow with K, so the win must be priced)
-        fz0 = (pallas_generic.choose_fuse_3d(model, shape,
-                                             itemsize=s_itemsize)
+        fz0 = (1 if sampled
+               else pallas_generic.choose_fuse_3d(model, shape,
+                                                  itemsize=s_itemsize)
                if model.ndim == 3 else pallas_generic.choose_fuse(model))
         if fits_resident:
             # generic counterpart of the tuned d2q9 resident engine
@@ -1219,10 +1343,14 @@ class Lattice:
         # a tiled 3D window's as the rows of its bands
         cap0 = (pallas_generic._DEFAULT_BY_CAP if model.ndim == 2
                 else plan0[1] if plan0 else 0)
+        # a sampled run's verdict is not remembered: its one step a call
+        # is not what a later lattice of the shape should run
+        def verdict(fz, cap):
+            return None if sampled else (fz, cap)
         return [band(fz0, None, probe=True, cap=cap0,
-                     verdict=(fz0, None))] + [
+                     verdict=verdict(fz0, None))] + [
             band(fz, cap, f"pallas_generic[{name},fuse={fz},by<={cap}]",
-                 probe=True, cap=cap, verdict=(fz, cap))
+                 probe=True, cap=cap, verdict=verdict(fz, cap))
             for fz, cap in rungs]
 
     def _build_tail(self) -> tuple:
@@ -1292,6 +1420,13 @@ class Lattice:
                 self._build_tail() if self._fast is not None and not full
                 else (None, None))
             self._tail_probing = self._tail is not None
+            self._sample_flags = None
+            if self._fast is not None and self.sampler is not None:
+                pts = self.sampler.points
+                self._sample_flags = jax.device_put(
+                    self._flags_host()[tuple(pts[:, k] for k in
+                                             range(pts.shape[1]))]
+                    .astype(FLAG_DTYPE), self.device)
             from tclb_tpu.utils import log
             if self._fast is not None:
                 suffix = "(in-kernel globals)" if full \
@@ -1330,19 +1465,11 @@ class Lattice:
                 model=self.model.name,
                 iteration=iteration, pre_sync_s=pre_sync_s) as sp:
             self._iterate_impl(niter)
-            engine = ("sampled_xla" if self.sampler is not None
-                      else (self._fast_name or "xla"))
+            engine = self._fast_name or "xla"
             sp.add(engine=engine, fuse=telemetry.fuse_of(engine))
             sp.sync(self.state.fields)
 
     def _iterate_impl(self, niter: int) -> None:
-        if self.sampler is not None:
-            it0 = int(self.state.iteration)
-            self.state, samples = self._iterate_sampled(
-                self.state, self.params, niter,
-                jnp.asarray(self.avg_start, jnp.int32))
-            self.sampler.append(it0, np.asarray(samples))
-            return
         fast = self._fast_path()
         # an engine advertising full_globals returns the LAST step's
         # Globals itself (in-kernel accumulation, ≡ the reference's
@@ -1362,7 +1489,7 @@ class Lattice:
         # a probed first call (compile, fallback ladder) leaves it out
         with telemetry.span("iterate.fused", iters=done) as sp:
             if not use_fast:
-                self.state = self._iterate(self.state, self.params, niter)
+                self.state = self._xla_steps(niter)
                 sp.mark("dispatch_s")
             elif self._fast_probing:
                 with telemetry.span("engine.probe",
@@ -1389,12 +1516,63 @@ class Lattice:
                     self.state = (
                         self._run_engine(self._tail, self.state, 1)
                         if self._tail is not None
-                        else self._iterate(self.state, self.params, 1))
+                        else self._xla_steps(1))
                     sp.mark("dispatch_s")
                 if self._tail is not None:
                     telemetry.counter("engine.tail_calls")
                 sp.add(engine=self._tail_name or "xla")
                 sp.sync(self.state)
+        if self.sampler is not None:
+            self._hand_samples()
+
+    def _xla_steps(self, niter: int) -> LatticeState:
+        """The state after ``niter`` steps on the XLA engine; under a
+        sampler on its sampled scan (:func:`make_sampled_iterate`), whose
+        rows go where a sampled engine's taps go."""
+        if self.sampler is None:
+            return self._iterate(self.state, self.params, niter)
+        if self._iterate_sampled is None:
+            self._iterate_sampled = jax.jit(
+                make_sampled_iterate(self.model, self.sampler.points,
+                                     self.sampler.quantities),
+                static_argnames=("niter",))
+        state, rows = self._iterate_sampled(
+            self.state, self.params, niter, np.int32(self.avg_start))
+        self._left_samples("rows", rows, rows[0])
+        return state
+
+    def _left_samples(self, kind: str, what, stack) -> None:
+        """A call has left ``stack.shape[0]`` steps' samples on the
+        device, ``stack`` (a sampled engine's taps, or the XLA scan's
+        rows): keep ``what`` for :meth:`_hand_samples`, and say so on the
+        innermost open span (``iterate.fused``, ``engine.probe`` or
+        ``iterate.globals_step``)."""
+        self._sampled.append((kind, what, stack.shape[0]))
+        telemetry.counter("sampler.rows", stack.shape[0])
+        telemetry.annotate(sample_points=len(self.sampler.points),
+                           sample_rows=stack.shape[0],
+                           sample_bytes=stack.nbytes)
+
+    def _hand_samples(self) -> None:
+        """Give the sampler what the calls of this ``iterate`` left, in
+        the order of the steps, as device arrays: the XLA scan's rows as
+        they are, consecutive taps of sampled engines through one call
+        of :func:`taps_program`.  Nothing here waits for the device; the
+        sampler's flush does."""
+        left, self._sampled = self._sampled, []
+        behind = sum(steps for _, _, steps in left)
+        for kind, calls in itertools.groupby(left, key=lambda c: c[0]):
+            calls = list(calls)
+            behind -= sum(steps for _, _, steps in calls)
+            if kind == "rows":
+                chunks = [what for _, what, _ in calls]
+            else:
+                chunks = [self._with_taps_program(lambda program: program(
+                    tuple(taps for _, taps, _ in calls), self._sample_flags,
+                    self.params, self.state.iteration, np.int32(behind),
+                    np.int32(self.avg_start)))]
+            for samples, its in chunks:
+                self.sampler.append(its, samples)
 
     def _run_engine(self, engine, state: LatticeState, niter: int
                     ) -> LatticeState:
@@ -1405,8 +1583,13 @@ class Lattice:
         and ``engine.paired_calls`` and as fields of the innermost open
         span (``iterate.fused``, ``engine.probe`` or
         ``iterate.globals_step``), once the call has returned: a
-        candidate that fails its probe reports nothing."""
+        candidate that fails its probe reports nothing.  A sampled
+        engine's call returns its taps beside the state; they are kept
+        for the sampler (:meth:`_left_samples`)."""
         out = engine(state, self.params, niter)
+        if engine.samples:
+            out, taps = out
+            self._left_samples("taps", taps, taps)
         if telemetry.enabled() and engine.account is not None:
             did = engine.account(niter, self.params.time_series is not None)
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
@@ -1435,7 +1618,7 @@ class Lattice:
                     self._run_engine(self._tail, self.state, 1))
             except Exception as e:  # noqa: BLE001
                 self._tail_failed(tag, e)
-                self.state = self._iterate(self.state, self.params, 1)
+                self.state = self._xla_steps(1)
             telemetry.counter("engine.probe_attempts")
             probe.add(attempts=1, rungs=[],
                       result=self._tail_name or "xla")
@@ -1499,7 +1682,7 @@ class Lattice:
                                       model=self.model.name)
         self._fast, self._fast_name, self._fast_probing = it, ran, False
         if cand is None:
-            self.state = self._iterate(self.state, self.params, niter)
+            self.state = self._xla_steps(niter)
             return niter
         if cand.verdict is not None:
             # the generic band engine's verdict, remembered process-wide
@@ -1509,14 +1692,23 @@ class Lattice:
         return nfast
 
     def attach_sampler(self, sampler) -> None:
-        """Register a point sampler: every subsequent step also gathers its
-        quantities at the sample points (reference Sampler, C16).  Sampled
-        iteration runs the global-view step (XLA partitions it over the mesh
-        automatically when state is sharded)."""
+        """Register a point sampler: every subsequent step also leaves
+        its quantities at the sample points (reference Sampler, C16), a
+        row a step, with the sampler (``sampler.append``).  The engine is
+        selected again, with the sampler in view (:meth:`_build_fast`):
+        a fused engine's one-step flavour that returns the points' planes
+        after every step, or the XLA scan :func:`make_sampled_iterate`
+        (the global-view step, which XLA partitions over a mesh)."""
         self.sampler = sampler
-        f = make_sampled_iterate(self.model, sampler.points,
-                                 sampler.quantities)
-        self._iterate_sampled = jax.jit(f, static_argnames=("niter",))
+        self._iterate_sampled = None
+        self._fast_tried = False
+
+    def detach_sampler(self) -> None:
+        """The run goes on without its sampler: the engine is selected
+        again, as for a lattice that never had one."""
+        self.sampler = None
+        self._iterate_sampled = None
+        self._fast_tried = False
 
     # -- inspection --------------------------------------------------------- #
 

@@ -14,6 +14,8 @@ from dataclasses import dataclass
 from typing import Callable, Optional
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 # kernel calls a loop body of the scans that carry the state through a
 # kernel.  A loop's carry is one buffer and a custom call cannot write
@@ -24,13 +26,30 @@ import jax
 PAIR = 2
 
 
-def scan_calls(body: Callable, carry, trips: int, paired: bool):
+def scan_calls(body: Callable, carry, trips: int, paired: bool,
+               taps: bool = False):
     """``carry`` after ``trips`` kernel calls, ``body(carry, None) ->
     (carry, None)`` each: one ``lax.scan``, ``PAIR`` calls a loop body
     and an odd call after the loop where ``paired``, one call a body
-    otherwise."""
-    return jax.lax.scan(body, carry, None, length=trips,
-                        unroll=PAIR if paired else 1)[0]
+    otherwise.  With ``taps`` the body's second value is what the call
+    left at the sample points and the scan's ``ys`` come back beside the
+    carry, ``(carry, ys)``, a row a trip."""
+    out = jax.lax.scan(body, carry, None, length=trips,
+                       unroll=PAIR if paired else 1)
+    return out if taps else out[0]
+
+
+def tap(fields, points):
+    """The stored planes at the sample points, ``(planes, P)``: what a
+    sampled loop's body returns beside the state after every step.
+    ``points`` is (P, ndim) in array index order, static; ghost rows a
+    band stands on lie behind the physical rows, which keep their
+    indices.  A static slice a point, never a gather: XLA's gather wants
+    the planes' axis inside the rows' and copies the whole state into
+    that layout before every call of it (compiled for a described v5e at
+    11 x 1024 x 1024, PR 46)."""
+    return jnp.stack([fields[(slice(None),) + tuple(int(i) for i in p)]
+                      for p in np.asarray(points)], axis=-1)
 
 
 def paired_calls(*trips: int) -> int:
@@ -46,7 +65,7 @@ class Engine:
     dispatch (``core/lattice.py``) decides on.  An engine is its own
     identity (``eq=False``): it hashes like the closure it wraps."""
 
-    run: Callable       # (state, params, niter) -> state
+    run: Callable       # (state, params, niter) -> state (or: samples)
     # account(niter, has_series=False) -> dict: what one call issues,
     # reckoned host-side from the same split of ``niter`` its schedule
     # runs; dispatch counts and annotates it.  None: nothing is reported
@@ -55,6 +74,10 @@ class Engine:
     full_globals: bool = False
     # the engine gathers a <Control> time series per iteration itself
     supports_series: bool = False
+    # built with sample points: its call is one step a kernel call and
+    # returns ``(state, taps)``, the stored planes at the points after
+    # every step, (niter, planes, P)
+    samples: bool = False
     # nothing has shown that its kernel compiles: the first call is probed
     unproven: bool = False
     fuse: Optional[int] = None      # steps a kernel call, where the tag says

@@ -52,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, lbm
-from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
+from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls, tap
 from tclb_tpu.ops.lbm import equilibrium, present_types  # noqa: F401
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
@@ -409,9 +409,15 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         fuse: int = 1,
                         present: Optional[set] = None,
                         ext_halo: bool = False,
-                        _want_step_ctx: bool = False):
+                        _want_step_ctx: bool = False,
+                        points: Optional[np.ndarray] = None):
     """Build ``iterate(state, params, niter) -> state`` running the fused
     Pallas collide-stream kernel.  Caller must check :func:`supports` first.
+
+    ``points`` ((P, 2) in array index order; ``fuse`` 1) builds the
+    sampled flavour for a ``<Sample>`` run: every step is one call of
+    the single-step kernel and the engine returns ``(state, taps)``, the
+    stored planes at the points after every step, (niter, planes, P).
 
     ``fuse=2`` runs TWO lattice steps per kernel band pass (halving the
     HBM traffic per step); an odd trailing step falls back to the single-
@@ -441,6 +447,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     if fuse not in (1, 2):
         raise ValueError(f"fuse={fuse}: only 1 (single-step) and 2 "
                          "(temporally-fused pair) kernels exist")
+    if points is not None and fuse != 1:
+        raise ValueError("the sampled flavour reads the state after "
+                         "every step: fuse=1 only")
     ny_phys, nx = (int(s) for s in shape)
     if ext_halo:
         if ny_phys % 8:
@@ -830,7 +839,14 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     zshift = model.zone_shift
 
-    @partial(jax.jit, static_argnames=("niter", "fuse"), donate_argnums=0)
+    # the sampled flavour does not donate its state: donated, the loop's
+    # carry is the caller's HBM buffer and every trip of two calls ends
+    # in a copy of the whole state out of the compiler's fast memory
+    # (copy-done, 70 us a trip at 11 x 1024 x 1024: a quarter of the
+    # device's time, chip, PR 46); not donated, the state is copied in
+    # once before the loop and out once after it
+    @partial(jax.jit, static_argnames=("niter", "fuse"),
+             donate_argnums=() if points is not None else 0)
     def _iterate_jit(state: LatticeState, params: SimParams, niter: int,
                      fuse: int = 1) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
@@ -862,32 +878,44 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 return f.at[:, ny - 2:, :].set(
                     fields[:, ny_phys - 2:ny_phys, :])
 
-        # both loops single, paired=False: compiled paired at 1024 x
-        # 1024, one of the two state buffers leaves the compiler's fast
-        # memory and kernel2 waits for its input copies; a micro-run
-        # read the paired loop faster all the same (PERF.md section 7,
-        # PR 43's compile; ROADMAP S1)
-        if fuse == 2:
-            aux = jnp.stack([flags_i32.astype(dtype), vel, den])
-
-            def body2(fields, _):
-                return call2(sett, refresh(fields), aux), None
-
-            fields = scan_calls(body2, fields, niter // 2, False)
-        rest = niter % 2 if fuse == 2 else niter
-
         def body(fields, _):
             return call(sett, refresh(fields), flags_i32, vel, den), None
 
-        fields = scan_calls(body, fields, rest, False)
+        def body_t(fields, _):
+            out = body(fields, None)[0]
+            return out, tap(out, points)
+
+        taps = None
+        if points is not None:
+            # every step its own call, and what it left at the points the
+            # scan's ys; two calls a loop body, so the carry is not
+            # copied before a call (ops/engine.py)
+            fields, taps = scan_calls(body_t, fields, niter, True,
+                                      taps=True)
+        else:
+            # both loops single, paired=False: compiled paired at 1024 x
+            # 1024, one of the two state buffers leaves the compiler's
+            # fast memory and kernel2 waits for its input copies; a
+            # micro-run read the paired loop faster all the same
+            # (PERF.md section 7, PR 43's compile; ROADMAP S1)
+            if fuse == 2:
+                aux = jnp.stack([flags_i32.astype(dtype), vel, den])
+
+                def body2(fields, _):
+                    return call2(sett, refresh(fields), aux), None
+
+                fields = scan_calls(body2, fields, niter // 2, False)
+            rest = niter % 2 if fuse == 2 else niter
+            fields = scan_calls(body, fields, rest, False)
         if pad:
             fields = fields[:, :ny_phys, :]
-        return LatticeState(
+        out = LatticeState(
             fields=fields,
             flags=state.flags,
             globals_=jnp.zeros_like(state.globals_),
             iteration=state.iteration + niter,
         )
+        return out if taps is None else (out, taps)
 
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
@@ -901,9 +929,18 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 "use the XLA path for time-dependent zonal settings")
         return _iterate_jit(state, params, niter, fuse=fuse)
 
-    # reports nothing (no account); band_shape: the single-step kernel's
-    # bands, for the resident engine's account of the steps it leaves to
-    # this one
-    return Engine(iterate, pad_rows=pad, impl=dict(
-        band_shape=dict(bands=ny // by, band_rows=by, halo_rows=8,
-                        pad_rows=pad)))
+    # band_shape: the single-step kernel's bands, for the resident
+    # engine's account of the steps it leaves to this one.  The engine
+    # itself reports nothing (no account) but in its sampled flavour,
+    # whose every step is one call on those bands
+    band_shape = dict(bands=ny // by, band_rows=by, halo_rows=8,
+                      pad_rows=pad)
+
+    def account(niter: int, has_series: bool = False) -> dict:
+        return dict(kernel_calls=niter, remainder_steps=0,
+                    paired_calls=paired_calls(niter),
+                    aux_planes=_AUX_PLANES, **band_shape)
+
+    return Engine(iterate, account if points is not None else None,
+                  samples=points is not None, pad_rows=pad,
+                  impl=dict(band_shape=band_shape))

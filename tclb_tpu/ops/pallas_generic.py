@@ -52,7 +52,7 @@ from tclb_tpu.core.lattice import (LatticeState, NodeCtx, SimParams,
                                    series_dt_overrides, series_overrides)
 from tclb_tpu.core.registry import Model
 from tclb_tpu.ops import fusion, lbm
-from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
+from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls, tap
 from tclb_tpu.ops.lbm import present_types  # noqa: F401  (re-export)
 
 _VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024
@@ -73,10 +73,11 @@ _COMPUTE_DTYPE = jnp.float32
 
 def _donating_unless_one_call(schedule: Callable) -> Callable:
     """``schedule(state, params, niter)`` compiled twice, and
-    ``program(calls)``, which picks the one to run from the kernel calls
-    of the schedule: donating the state for every schedule of two
-    kernel calls and more (a call reads what the call before it wrote),
-    not donating it for a schedule of one call.  That call reads halos
+    ``program(calls, donate=True)``, which picks the one to run from the
+    kernel calls of the schedule: donating the state for every schedule
+    of two kernel calls and more (a call reads what the call before it
+    wrote), not donating it for a schedule of one call, nor where the
+    caller says ``donate=False``.  That call reads halos
     of the state while it writes, so where its output has to be the
     donated input's buffer XLA copies the whole state first (0.86 GB at
     34 x 512 x 48 x 256: 2 ms on a v5e); not donated, it writes a
@@ -84,14 +85,17 @@ def _donating_unless_one_call(schedule: Callable) -> Callable:
     copy."""
     jit = partial(jax.jit, schedule, static_argnames=("niter",))
     donating, once = jit(donate_argnums=0), jit()
-    return lambda calls: once if calls == 1 else donating
+    return lambda calls, donate=True: (
+        donating if donate and calls != 1 else once)
 
 
 def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
                       mk_call: Callable, mk_call1: Callable,
                       window: Callable, impl: dict,
                       ghost: Optional[tuple] = None,
-                      series_paired: bool = True, **fields) -> Engine:
+                      series_paired: bool = True,
+                      points: Optional[np.ndarray] = None,
+                      **fields) -> Engine:
     """The :class:`Engine` of the generic band (2D) and slab (3D)
     builders, from their kernel flavours: one split of ``niter``, the
     schedule that loops the calls by it, the account of what it issues
@@ -105,8 +109,12 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
     that stands on ghost rows: ``enter(flags, fields)`` appends them,
     ``refresh(fields)`` renews them before a call, ``leave(fields)``
     drops them; None where there are none.  ``series_paired``: whether
-    the series loop runs two calls a body.  ``fields``: what else the
-    engine declares."""
+    the series loop runs two calls a body.  ``points`` ((P, ndim) in
+    array index order; ``fuse`` 1): the sampled flavour of a
+    ``<Sample>`` run, whose every step is one call and which returns
+    ``(state, taps)``, the stored planes at the points after every
+    step, the final Globals call's too, (niter, planes, P).
+    ``fields``: what else the engine declares."""
     cdtype = _COMPUTE_DTYPE
     zonal_names = list(model.zonal_settings)
     zonal_si = [model.setting_index[nm] for nm in zonal_names]
@@ -137,6 +145,10 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
                   for s in model.actions["Iteration"]))
     enter, refresh, leave = ghost or (
         lambda flags, fields: (flags, fields), lambda f: f, lambda f: f)
+    sampled = points is not None
+    if sampled and fuse != 1:
+        raise ValueError("the sampled flavour reads the state after "
+                         "every step: fuse=1 only")
 
     def split(niter: int, has_series: bool = False) -> tuple:
         """``niter`` steps as the trips of the two loops and the final
@@ -156,6 +168,12 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
         zones = flags_i32 >> zshift
         sett = params.settings.astype(cdtype)
         has_series = params.time_series is not None
+        if sampled and has_series:
+            # dispatch keeps such a run on the XLA scan
+            # (Lattice._samples_on_engine): taps on the series loop are
+            # neither dispatched nor tested
+            raise NotImplementedError(
+                "the sampled flavour does not take a <Control> series")
 
         # loop-invariant pieces (XLA hoists them out of the step scan):
         # the base zonal planes and the affected-zone masks.  Per step
@@ -171,9 +189,27 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
                                 zonal_si, it, cdtype, with_dt=has_series)
 
         if niter <= 0:
-            return state
+            return (state, jnp.zeros((0, fields.shape[0], len(points)),
+                                     fields.dtype)) if sampled else state
         fused, rest, final = split(niter, has_series)
         carry = (fields, state.iteration)
+        rows = []   # the sampled flavour's taps, a stack a loop
+
+        def loop(c, steps, carry, trips, paired):
+            """``carry`` after ``trips`` calls of ``c``, ``steps`` steps
+            each; sampled, what each left at the points goes to
+            ``rows``."""
+            def body(carry, _):
+                fields, it = carry
+                out = invoke(c, it, fields)
+                return (out, it + adv * steps), \
+                    (tap(out, points) if sampled else None)
+
+            out = scan_calls(body, carry, trips, paired, taps=sampled)
+            if sampled:
+                rows.append(out[1])
+                return out[0]
+            return out
 
         if has_series:
             # series flavors keep the full host-assembled aux stack: the
@@ -181,11 +217,7 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
             def invoke(c, it, fields):
                 return c(sett, it[None], refresh(fields), aux_of(it))
 
-            def body_s(carry, _):
-                fields, it = carry
-                return (invoke(call_s, it, fields), it + adv), None
-
-            fields, it = scan_calls(body_s, carry, rest, series_paired)
+            fields, it = loop(call_s, 1, carry, rest, series_paired)
         else:
             if lean_aux:
                 # the DMA'd aux stack is the flag plane alone, every
@@ -204,17 +236,9 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
                 def invoke(c, it, fields):
                     return c(sett, it[None], refresh(fields), aux)
 
-            def body(carry, _):
-                fields, it = carry
-                return (invoke(call, it, fields), it + adv * fuse), None
-
-            def body1(carry, _):
-                fields, it = carry
-                return (invoke(call1, it, fields), it + adv), None
-
-            fields, it = scan_calls(body, carry, fused, True)
+            fields, it = loop(call, fuse, carry, fused, True)
             if fuse > 1:
-                fields, it = scan_calls(body1, (fields, it), rest, True)
+                fields, it = loop(call1, 1, (fields, it), rest, True)
 
         globals_ = jnp.zeros_like(state.globals_)
         if final:
@@ -223,8 +247,11 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
             it = it + adv
             globals_ = gpart[:model.n_globals].sum(axis=1).astype(
                 state.globals_.dtype)
-        return LatticeState(fields=leave(fields), flags=state.flags,
-                            globals_=globals_, iteration=it)
+            if sampled:
+                rows.append(tap(fields, points)[None])
+        out = LatticeState(fields=leave(fields), flags=state.flags,
+                           globals_=globals_, iteration=it)
+        return (out, jnp.concatenate(rows)) if sampled else out
 
     def account(niter: int, has_series: bool = False) -> dict:
         """What one ``iterate(niter)`` issues, reckoned host-side from
@@ -246,13 +273,16 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
         calls = sum(split(int(niter), params.time_series is not None))
-        return program(calls)(state, params, niter)
+        # the sampled flavour never donates: donated, every trip of its
+        # loop copies the state out of the compiler's fast memory into
+        # the caller's buffer (ops/pallas_d2q9.py, the same finding)
+        return program(calls, donate=not sampled)(state, params, niter)
 
     # the engine handles Control time series itself, and (when the
     # globals flavor exists) returns the LAST step's Globals — no trailing
     # step needed (and a hybrid engine's trailing step can be this
     # engine's iterate(.., 1))
-    return Engine(iterate, account, supports_series=True,
+    return Engine(iterate, account, supports_series=True, samples=sampled,
                   full_globals=bool(model.n_globals == 0
                                     or call_g is not None),
                   impl=dict(impl, call_g=call_g, lean_aux=lean_aux,
@@ -743,9 +773,12 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         ext_halo: bool = False,
                         by_cap: Optional[int] = None,
                         full_band: bool = False,
-                        shift: Optional[np.ndarray] = None):
+                        shift: Optional[np.ndarray] = None,
+                        points: Optional[np.ndarray] = None):
     """Build ``iterate(state, params, niter) -> state`` running the model's
     full Iteration action as one fused Pallas band kernel per step.
+
+    ``points`` builds the sampled flavour (:func:`_scheduled_engine`).
 
     ``ext_halo=True`` builds the sharded building block instead (the
     domain is one device's y-block carrying 8 exchanged halo rows at each
@@ -757,7 +790,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         return make_pallas_iterate_3d(model, shape, dtype,
                                       interpret=interpret, present=present,
                                       fuse=fuse, by_cap=by_cap,
-                                      shift=shift)
+                                      shift=shift, points=points)
     if not supports(model, shape, dtype, probe=False):
         raise ValueError(f"pallas_generic unsupported: {model.name} {shape}")
     cdtype = _COMPUTE_DTYPE
@@ -1022,7 +1055,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                   pad_rows=pad, bands=ny // by),
         impl=dict(by=by, pad=pad, nt_present=nt_present, mk_call=_mk_call),
         ghost=(enter, refresh, lambda f: f[:, :ny_phys, :]) if pad else None,
-        pad_rows=pad)
+        points=points, pad_rows=pad)
 
 
 # --------------------------------------------------------------------------- #
@@ -1513,7 +1546,8 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                            fuse: int = 1,
                            by_cap: Optional[int] = None,
                            shift: Optional[np.ndarray] = None,
-                           window: Optional[tuple] = None):
+                           window: Optional[tuple] = None,
+                           points: Optional[np.ndarray] = None):
     """3D generic engine: the model's full Iteration action per z-slab
     band pass, with the same registry-driven machinery as the 2D builder
     (multi-stage extension plan, zonal aux planes, in-kernel SUM globals
@@ -1534,7 +1568,8 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     in-kernel globals flavor (it sums a band's own rows only) and the
     Control-series flavors at ``fuse=1`` inside the fused plan's
     ``(bz, by)``, whose account holds the series' aux stack.
-    ``window=(bz, by)`` pins the window (tests and sweeps)."""
+    ``window=(bz, by)`` pins the window (tests and sweeps); ``points``
+    builds the sampled flavour (:func:`_scheduled_engine`)."""
     if not supports_3d(model, shape, dtype, probe=False):
         raise ValueError(f"pallas_generic 3d unsupported: {model.name} "
                          f"{shape}")
@@ -1834,4 +1869,4 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
         impl=dict(bz=bz),
         # the series loop runs one call a body: pairing it is not
         # measured (ROADMAP M2)
-        series_paired=False, plan=(bz, by, fuse))
+        series_paired=False, points=points, plan=(bz, by, fuse))

@@ -412,6 +412,104 @@ def test_d2q9_band_1024_stays_one_call_a_body(one_chip):
     assert len(_state_copies(body, m, shape)) == 1
 
 
+# the eight probes of the cell karman1024probes.sampled, (row, column)
+_PROBES = np.array([[512, 112], [512, 328], [512, 420], [512, 520],
+                    [412, 520], [612, 520], [512, 720], [512, 920]])
+
+
+def _gathers(text: str) -> list:
+    return [line.strip() for line in text.splitlines()
+            if re.search(r"= \S+ gather\(", line)]
+
+
+def _state_moves(lines, m, shape) -> list:
+    """The copies of the whole state among a computation's lines, those
+    between the compiler's fast memory and HBM too (``copy-done``)."""
+    whole = "f32[%s]" % ",".join(str(n) for n in (m.n_storage,)
+                                 + tuple(shape))
+    move = re.compile(r"= %s\S* copy(-done)?\(" % re.escape(whole))
+    return [line.strip() for line in lines if move.search(line)]
+
+
+def _compile_donating(iterate, lat, niter, one_chip) -> str:
+    """As :func:`_compile`, the state donated: what a sampled flavour
+    would compile to if its program donated like the unsampled one."""
+    spec = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (lat.state, lat.params))
+    return jax.jit(lambda s, p: iterate(s, p, niter),
+                   donate_argnums=0).lower(*spec).compile().as_text()
+
+
+def test_d2q9_band_1024_sampled_pairs_the_calls(one_chip):
+    """The sampled flavour of the tuned band at the size and with the
+    probes of the cell ``karman1024probes.sampled``: every step one call
+    of ``d2q9_band_fuse1``, what it left at the eight points the scan's
+    ys.  The loop body holds two kernel calls and **no copy of the whole
+    state** (both state buffers stay in the compiler's fast memory), and
+    the taps are static slices fused into one small fusion a call: read
+    as an XLA gather they cost a copy of the 46 MB state into the
+    gather's layout before every call (the first wiring of PR 46,
+    compiled here).  **The flavour does not donate its state**: donated,
+    the loop's carry is the caller's HBM buffer and every trip ends in a
+    ``copy-done`` of the whole state out of the fast memory (70 us a
+    trip, a quarter of the device's time on the chip: PR 46's first
+    call); not donated, the state goes in once before the loop and out
+    once after it.  499 steps, the cell's call: 249 trips of two calls
+    and an odd call."""
+    shape = (1024, 1024)
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
+                                         interpret=False, fuse=1,
+                                         present=present, points=_PROBES)
+    assert it.samples
+    assert it.account(499) == dict(
+        kernel_calls=499, remainder_steps=0, paired_calls=498, aux_planes=3,
+        bands=16, band_rows=64, halo_rows=8, pad_rows=0)
+    text = _compile(it, lat, 499, one_chip)
+    body, calls = _kernel_loop_body(text, "d2q9_band_fuse1")
+    assert calls == 2
+    assert not _state_moves(body, m, shape)
+    assert not _gathers(text)
+    assert "f32[249,2,11,8]" in text      # the ys of the paired trips
+    # the engine's own program does not donate ...
+    inner, = [e for e in jax.make_jaxpr(lambda s, p: it(s, p, 499))(
+        lat.state, lat.params).eqns if e.primitive.name in ("pjit", "jit")]
+    assert not any(inner.params["donated_invars"])
+    # ... because donated it would move the state every trip
+    body, _ = _kernel_loop_body(_compile_donating(it, lat, 499, one_chip),
+                                "d2q9_band_fuse1")
+    assert len(_state_moves(body, m, shape)) == 1
+
+
+def test_generic_band_drop_1024_sampled_pairs_the_calls(one_chip):
+    """The generic schedule's sampled flavour at ``drop1024``'s shape:
+    one step a call of ``generic_band_fuse1``, two calls a loop body, no
+    copy of the 42 MB state (not donated, as the tuned band's and for
+    the same reason), no gather; the final Globals call is sampled from
+    the state it returns.  500 steps: 499 looped calls and the globals
+    flavour's."""
+    m, lat, flags = _drop(1024)
+    shape = (1024, 1024)
+    it = pallas_generic.make_pallas_iterate(
+        m, shape, jnp.float32, interpret=False, fuse=1,
+        present=lbm.present_types(m, flags), points=_PROBES)
+    did = it.account(500, False)
+    assert it.samples and (did["kernel_calls"], did["paired_calls"],
+                           did["remainder_steps"]) == (500, 498, 1)
+    text = _compile(it, lat, 500, one_chip)
+    body, calls = _kernel_loop_body(text, "generic_band_fuse1")
+    assert calls == 2
+    assert not _state_moves(body, m, shape)
+    assert not _gathers(text)
+    inner, = [e for e in jax.make_jaxpr(lambda s, p: it(s, p, 500))(
+        lat.state, lat.params).eqns if e.primitive.name in ("pjit", "jit")]
+    assert not any(inner.params["donated_invars"])
+    body, _ = _kernel_loop_body(_compile_donating(it, lat, 500, one_chip),
+                                "generic_band_fuse1")
+    assert len(_state_moves(body, m, shape)) == 1
+
+
 def test_generic_resident_drop_512(one_chip, monkeypatch):
     """``example/drop_512.xml``, upstream's drop at its own 512 x 512
     (the configuration ``drop512``): the state fits the generic
@@ -595,7 +693,8 @@ def test_pin_is_identity_only_inside_a_compiled_body():
     assert "optimization_barrier" in str(jax.make_jaxpr(body)(x))
 
 
-@pytest.mark.parametrize("case", ["channel512", "tgv256", "karman1024"])
+@pytest.mark.parametrize("case", ["channel512", "tgv256", "karman1024",
+                                  "karman1024_sampled"])
 def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
                                                      case):
     """The one step the hybrid engines leave for the Globals, as the
@@ -608,10 +707,16 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
     of one call does not donate the state, so XLA puts no copy of it
     (0.86 and 2.28 GB in 3D) between the fused program's output and the
     kernel: donated, the call's output would have to be the buffer it
-    reads halos from."""
-    if case == "karman1024":
+    reads halos from.  Under a sampler (``karman1024probes``) the
+    tail is built with the probes and its one call returns their planes
+    beside the state: the same one call, still no copy."""
+    sampled = case == "karman1024_sampled"
+    if case.startswith("karman1024"):
         shape = (1024, 1024)
         m, lat, _ = _channel("d2q9", shape, nu=0.02)
+        if sampled:
+            from tclb_tpu.utils.sampler import Sampler
+            lat.attach_sampler(Sampler(m, ["U", "Rho"], _PROBES, "unused"))
         state, params = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=one_chip),
@@ -632,10 +737,10 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
     tail, tag = lat._build_tail()
     by = ",by=32" if case == "tgv256" else ""
     assert tag == f"pallas_generic[{m.name},fuse=1{by}]"
-    assert tail.full_globals
+    assert tail.full_globals and tail.samples == sampled
     did = tail.account(1, False)
     assert (did["kernel_calls"], did["aux_planes"]) == (1, 1)
-    if case != "karman1024":
+    if not case.startswith("karman1024"):
         assert tail.plan == {"channel512": (1, 48, 1),
                              "tgv256": (4, 32, 1)}[case]
     one = lambda s, p: tail(s, p, 1)    # noqa: E731
@@ -647,7 +752,7 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert f"{kernel}/pallas_call" in text
     assert not _state_copies(text.splitlines(), m, shape)
-    if case != "karman1024":
+    if not case.startswith("karman1024"):
         # donated, as every schedule of two calls and more is, it would
         donated = jax.jit(one, donate_argnums=0)
         assert len(_state_copies(donated.lower(

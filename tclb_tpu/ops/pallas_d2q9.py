@@ -395,6 +395,32 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     return Engine(iterate, account)
 
 
+def _shard_halo_rows(base, rows: int, ny: int) -> tuple:
+    """First rows of the two 8-row halo blocks of the band of ``rows``
+    rows at ``base`` in a shard of ``ny`` rows taken as it is: the 8 rows
+    above and below the band, held inside the shard where the band is its
+    first or its last (there the neighbour's block is read instead,
+    :func:`_start_sharded`)."""
+    return (pl.multiple_of(
+                jnp.maximum(base - jnp.int32(8), jnp.int32(0)), 8),
+            pl.multiple_of(
+                jnp.minimum(base + jnp.int32(rows), jnp.int32(ny - 8)), 8))
+
+
+def _start_sharded(own, theirs, first, last) -> None:
+    """Start a band's three field DMAs on one shard of a y-split lattice:
+    ``own`` (band, top block, bottom block) from the shard's own rows,
+    but the top block of the shard's ``first`` band and the bottom block
+    of its ``last`` from the neighbours' exchanged blocks, ``theirs``
+    (top, bottom): to the same destination under the same semaphore, so
+    the wait of ``own`` serves either source."""
+    own[0].start()
+    for mine, other, at_edge in ((own[1], theirs[0], first),
+                                 (own[2], theirs[1], last)):
+        pl.when(at_edge)(other.start)
+        pl.when(jnp.logical_not(at_edge))(mine.start)
+
+
 def _make_step_ctx(model: Model, present=None):
     """Per-model physics closures (the band builder's _lbm_step + BC
     plane indices), extracted for the resident kernel to share — one
@@ -429,13 +455,18 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     types actually painted — :func:`present_types` computes that set.
 
     ``ext_halo=True`` builds the SHARDED building block instead: the
-    domain is one device's block of a y-sharded lattice, the input field
-    stack carries 8 exchanged halo rows at each end ((ns, ny+16, nx)),
-    and the kernels read halos from those rows instead of wrapping
-    periodically.  Returns ``(call1, call2, by, by2)`` raw band calls for
-    :mod:`tclb_tpu.parallel.halo` to compose with ``ppermute`` (the
-    reference's equivalent composition is RunBorder/MPIStream/RunInterior,
-    src/Lattice.cu.Rt:424-456)."""
+    domain is one device's block of a y-sharded lattice, and the kernels
+    read their halos from the neighbours' exchanged rows instead of
+    wrapping periodically.  Returns ``(call1, call2, by, by2)`` raw band
+    calls for :mod:`tclb_tpu.parallel.halo` to compose with ``ppermute``
+    (the reference's equivalent composition is
+    RunBorder/MPIStream/RunInterior, src/Lattice.cu.Rt:424-456).
+    Both take the shard's field stack as it is, (ns, ny, nx), and the
+    lower and the upper neighbour's 8 rows as operands of their own,
+    (ns, 8, nx) each (:func:`_start_sharded`: nothing of the shard's size
+    is built round them): ``call2(sett, f, lo, hi, aux)``, whose aux
+    stack, exchanged once an ``iterate``, carries the 8 rows at each end,
+    (3, ny+16, nx), and ``call1(sett, f, lo, hi, flags, vel, den)``."""
     from tclb_tpu.models import d2q9 as mod
     from tclb_tpu.models import d2q9_inc as inc_mod
     from tclb_tpu.models import d2q9_new as new_mod
@@ -626,7 +657,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         return {"step": _lbm_step, "bc_idx": bc_idx}
 
     def kernel(sett, f_hbm, flags_ref, vel_ref, den_ref, out_ref,
-               buf2, sems):
+               buf2, sems, halos=None):
         # One CONTIGUOUS scratch buffer of by+16 rows per slot: the band
         # lands at rows [8, 8+by), its 8-row halo blocks at [0, 8) and
         # [8+by, 16+by) — all three DMA destinations are (8, 128)-tile
@@ -637,28 +668,23 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         # i+1's DMA is issued before band i's compute, overlapping HBM
         # fetch with VPU work across grid steps (the reference gets the
         # same overlap from its border/interior kernel split + async
-        # memcpy streams, src/Lattice.cu.Rt:424-456).
+        # memcpy streams, src/Lattice.cu.Rt:424-456).  ``halos`` (the
+        # sharded flavour, ``sharded``): the neighbours' 8-row blocks.
         i = pl.program_id(0)
         n = pl.num_programs(0)
 
         def band_dmas(slot, band):
             base = pl.multiple_of(band * jnp.int32(by), 8)
-            if ext_halo:
-                # input rows are [halo(8) | local ny | halo(8)]: the band
-                # lives at base+8, its halos at base and base+8+by —
-                # no wrap, the exchanged rows ARE the neighbors
-                mid8 = pl.multiple_of(base + jnp.int32(8), 8)
-                top8 = base
-                bot8 = pl.multiple_of(base + jnp.int32(8 + by), 8)
+            if halos is not None:
+                top8, bot8 = _shard_halo_rows(base, by, ny)
             else:
-                mid8 = base
                 top8 = pl.multiple_of(
                     jax.lax.rem(base - jnp.int32(8) + jnp.int32(ny),
                                 jnp.int32(ny)), 8)
                 bot8 = pl.multiple_of(
                     jax.lax.rem(base + jnp.int32(by), jnp.int32(ny)), 8)
             return (
-                pltpu.make_async_copy(f_hbm.at[:, pl.ds(mid8, by), :],
+                pltpu.make_async_copy(f_hbm.at[:, pl.ds(base, by), :],
                                       buf2.at[slot, :, pl.ds(8, by), :],
                                       sems.at[slot, 0]),
                 pltpu.make_async_copy(f_hbm.at[:, pl.ds(top8, 8), :],
@@ -669,18 +695,35 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                       sems.at[slot, 2]),
             )
 
+        def halo_dmas(slot):
+            return (
+                pltpu.make_async_copy(halos[0],
+                                      buf2.at[slot, :, pl.ds(0, 8), :],
+                                      sems.at[slot, 1]),
+                pltpu.make_async_copy(halos[1],
+                                      buf2.at[slot, :, pl.ds(8 + by, 8), :],
+                                      sems.at[slot, 2]),
+            )
+
+        def start_band(slot, band):
+            dmas = band_dmas(slot, band)
+            if halos is None:
+                for d in dmas:
+                    d.start()
+            else:
+                _start_sharded(dmas, halo_dmas(slot), band == 0,
+                               band == n - 1)
+
         slot = jax.lax.rem(i, jnp.int32(2))
         nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(2))
 
         @pl.when(i == 0)
         def _():
-            for d in band_dmas(jnp.int32(0), i):
-                d.start()
+            start_band(jnp.int32(0), i)
 
         @pl.when(i + 1 < n)
         def _():
-            for d in band_dmas(nxt, i + jnp.int32(1)):
-                d.start()
+            start_band(nxt, i + jnp.int32(1))
 
         for d in band_dmas(slot, i):
             d.wait()
@@ -707,7 +750,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             out_ref[bc_idx[0]] = bc0
             out_ref[bc_idx[1]] = bc1
 
-    def kernel2(sett, f_hbm, aux_hbm, out_ref, buff, bufa, sems):
+    def kernel2(sett, f_hbm, aux_hbm, out_ref, buff, bufa, sems,
+                halos=None):
         """Temporally-fused kernel: TWO collide-stream steps per band pass
         (the esoteric-twist-style traffic saving flagged in SURVEY §7's
         hard parts — each density is read/written once per TWO steps).
@@ -717,13 +761,20 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         so the statics ride the same contiguous-buffer DMA scheme (flag
         values < 2^16 are exact in f32).  Like kernel, the band+halos land
         in ONE contiguous (by2+16)-row buffer so extended-row access is a
-        single slice, not a concatenate."""
+        single slice, not a concatenate.  ``halos`` (the sharded flavour,
+        :func:`kernel2_sharded`): the neighbours' two 8-row blocks."""
         i = pl.program_id(0)
         base = pl.multiple_of(i * jnp.int32(by2), 8)
-        if ext_halo:
-            mid8 = pl.multiple_of(base + jnp.int32(8), 8)
-            top8 = base
-            bot8 = pl.multiple_of(base + jnp.int32(8 + by2), 8)
+        if halos is not None:
+            # the aux stack's rows are [halo(8) | local ny | halo(8)]: a
+            # band lives at base+8, its halos at base and base+8+by2 — no
+            # wrap, the exchanged rows ARE the neighbors.  The field
+            # stack is the shard as it is
+            a_mid8 = pl.multiple_of(base + jnp.int32(8), 8)
+            a_top8 = base
+            a_bot8 = pl.multiple_of(base + jnp.int32(8 + by2), 8)
+            mid8 = base
+            top8, bot8 = _shard_halo_rows(base, by2, ny)
         else:
             mid8 = base
             top8 = pl.multiple_of(
@@ -731,6 +782,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                             jnp.int32(ny)), 8)
             bot8 = pl.multiple_of(
                 jax.lax.rem(base + jnp.int32(by2), jnp.int32(ny)), 8)
+            a_mid8, a_top8, a_bot8 = mid8, top8, bot8
         dmas = (
             pltpu.make_async_copy(f_hbm.at[:, pl.ds(mid8, by2), :],
                                   buff.at[:, pl.ds(8, by2), :], sems.at[0]),
@@ -739,16 +791,27 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             pltpu.make_async_copy(f_hbm.at[:, pl.ds(bot8, 8), :],
                                   buff.at[:, pl.ds(8 + by2, 8), :],
                                   sems.at[2]),
-            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(mid8, by2), :],
+            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(a_mid8, by2), :],
                                   bufa.at[:, pl.ds(8, by2), :], sems.at[3]),
-            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(top8, 8), :],
+            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(a_top8, 8), :],
                                   bufa.at[:, pl.ds(0, 8), :], sems.at[4]),
-            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(bot8, 8), :],
+            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(a_bot8, 8), :],
                                   bufa.at[:, pl.ds(8 + by2, 8), :],
                                   sems.at[5]),
         )
-        for d in dmas:
-            d.start()
+        if halos is None:
+            for d in dmas:
+                d.start()
+        else:
+            theirs = (
+                pltpu.make_async_copy(
+                    halos[0], buff.at[:, pl.ds(0, 8), :], sems.at[1]),
+                pltpu.make_async_copy(
+                    halos[1], buff.at[:, pl.ds(8 + by2, 8), :], sems.at[2]))
+            _start_sharded(dmas[:3], theirs, i == 0,
+                           i == pl.num_programs(0) - 1)
+            for d in dmas[3:]:
+                d.start()
         for d in dmas:
             d.wait()
 
@@ -789,15 +852,31 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             out_ref[bc_idx[0]] = ext(buff, bc_idx[0], 0, by2)
             out_ref[bc_idx[1]] = ext(buff, bc_idx[1], 0, by2)
 
+    def kernel2_sharded(sett, f_hbm, lo_hbm, hi_hbm, aux_hbm, out_ref,
+                        buff, bufa, sems):
+        """``kernel2`` on one shard of a y-split lattice (``ext_halo``):
+        ``f_hbm`` is the shard's field stack as it is, (ns, ny, nx), and
+        ``lo_hbm`` / ``hi_hbm`` the exchanged last / first 8 rows of the
+        lower / upper neighbour, (ns, 8, nx) each."""
+        kernel2(sett, f_hbm, aux_hbm, out_ref, buff, bufa, sems,
+                halos=(lo_hbm, hi_hbm))
+
+    def kernel_sharded(sett, f_hbm, lo_hbm, hi_hbm, flags_ref, vel_ref,
+                       den_ref, out_ref, buf2, sems):
+        """``kernel`` on one shard, as :func:`kernel2_sharded`."""
+        kernel(sett, f_hbm, flags_ref, vel_ref, den_ref, out_ref, buf2,
+               sems, halos=(lo_hbm, hi_hbm))
+
+    # the field stack and, in the sharded flavour, the neighbours' blocks
+    f_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (3 if ext_halo else 1)
+
     grid2 = (ny // by2,)
     call2 = pl.pallas_call(
-        lbm.mosaic_body(kernel2, interpret),
+        lbm.mosaic_body(kernel2_sharded if ext_halo else kernel2,
+                        interpret),
         grid=grid2,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + f_specs
+        + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((n_storage, by2, nx), lambda i: (0, i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
@@ -811,11 +890,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     )
 
     call = pl.pallas_call(
-        lbm.mosaic_body(kernel, interpret),
+        lbm.mosaic_body(kernel_sharded if ext_halo else kernel, interpret),
         grid=(ny // by,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + f_specs + [
             pl.BlockSpec((by, nx), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((by, nx), lambda i: (i, 0),

@@ -70,6 +70,19 @@ def _exchange_axis(block: jnp.ndarray, name: str, axis: int, width: int,
     return jnp.concatenate([lo_halo, block, hi_halo], axis=axis)
 
 
+def _halo_blocks(block: jnp.ndarray, name: str, axis: int, width: int,
+                 n: int) -> tuple:
+    """The two halo blocks :func:`_exchange_axis` would put round
+    ``block``, ``(lo_halo, hi_halo)``, and no extended copy of it: the
+    upper ``width`` cells of the lower torus neighbor on mesh axis
+    ``name`` and the lower ``width`` cells of the upper one."""
+    size = block.shape[axis]
+    hi_edge = lax.slice_in_dim(block, size - width, size, axis=axis)
+    lo_edge = lax.slice_in_dim(block, 0, width, axis=axis)
+    return (lax.ppermute(hi_edge, name, [(i, (i + 1) % n) for i in range(n)]),
+            lax.ppermute(lo_edge, name, [(i, (i - 1) % n) for i in range(n)]))
+
+
 def _exchange_bytes(shape, axis: int, width: int, n: int, planes: int,
                     itemsize: int) -> int:
     """Bytes one chip sends in one :func:`_exchange_axis` of a block of
@@ -229,18 +242,24 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
     configuration can't run it.
 
     The band axis of the kernels (y in 2D, z in 3D) is the sharded axis;
-    x (and y in 3D) must be unsplit.  Each step exchanges an 8-row (2D,
-    Mosaic tile granularity) or 1-slab (3D) halo via ``ppermute`` and
-    runs the per-shard band kernel on the extended block — the TPU
-    composition of the reference's RunBorder / MPIStream_A / RunInterior
-    / MPIStream_B overlap pipeline (src/Lattice.cu.Rt:424-456), with
-    XLA's latency-hiding scheduler providing the overlap.
+    x (and y in 3D) must be unsplit.  Each kernel call exchanges an 8-row
+    (2D, Mosaic tile granularity) or 1-slab (3D) halo via ``ppermute``
+    and runs the per-shard band kernel — the TPU composition of the
+    reference's RunBorder / MPIStream_A / RunInterior / MPIStream_B
+    overlap pipeline (src/Lattice.cu.Rt:424-456), with XLA's
+    latency-hiding scheduler providing the overlap.  The tuned 2D mode
+    (``pallas_d2q9``, two steps a call) hands the kernel the shard as it
+    is and the neighbours' two 8-row blocks as operands of their own
+    (:func:`_halo_blocks`), two calls a loop body: nothing of the
+    shard's size is written between two calls.  The generic 2D and the
+    3D mode still run their kernel on the extended block
+    (:func:`_exchange_axis`'s padded copy), one call a body.
 
     Like the single-device fast path this is the "NoGlobals"
     specialization: ``globals_`` is zeroed; the Lattice hybrid's trailing
     XLA step (which psums) supplies them."""
     from tclb_tpu.ops import fusion, pallas_d2q9, pallas_d3q
-    from tclb_tpu.ops.engine import Engine, scan_calls
+    from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
     try:
         _validate_mesh(model, mesh)
     except ValueError:
@@ -289,6 +308,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         zonal_si = [si[nm] for nm in zonal_names]
         width = 1
     zshift = model.zone_shift
+    steps = 2 if mode == "tuned2d" else 1     # a kernel call of the loop
     # bytes one chip sends per exchange of one plane and of the fields;
     # the planes of the aux stack, which is exchanged once per call
     plane_bytes = _exchange_bytes((1,) + local, 1, width, n, 1,
@@ -315,16 +335,24 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         return divmod(niter, 2) if mode == "tuned2d" else (niter, 0)
 
     @lru_cache(maxsize=None)
-    def _for_niter(niter: int):
+    def _program(trips: int, odd: int):
+        """The jitted program of a loop of ``trips`` kernel calls or of
+        the ``odd`` call after it.  Two programs (:func:`iterate`), not
+        one: with the one-step kernel in the loop's program the compiler
+        keeps one of the loop's two state buffers, or both, out of its
+        fast memory at 11 x 1024 x 1024, and ``kernel2``, which waits for
+        its input copies, takes 308 to 380 us a call on a state it reads
+        from HBM for 216 (compiled for a described 4 x 1 v5e,
+        ``tests/test_mosaic_compile.py``; chip, PR 47)."""
         def local_iterate(state: LatticeState, params: SimParams
                           ) -> LatticeState:
             flags_i32 = state.flags.astype(jnp.int32)
             zones = flags_i32 >> zshift
             sett = params.settings.astype(dtype)
             fields = state.fields
-            trips, odd = split(niter)
-            # the three loops are single, paired=False: pairing a loop
-            # whose body exchanges halos is not measured (ROADMAP M2)
+            # the generic 2D and the 3D loop are single, paired=False:
+            # their body builds the padded operand anew, which is the
+            # copy of the carry a single call a body needs (ROADMAP S9)
             if mode == "generic2d":
                 aux_ext = exch(jnp.stack(
                     [flags_i32.astype(dtype)]
@@ -342,16 +370,28 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             elif model.ndim == 2:
                 vel, den = pallas_d2q9.zonal_planes(
                     model, params, zones, dtype)
-                aux_ext = exch(jnp.stack(
-                    [flags_i32.astype(dtype), vel, den]))
 
-                def body2(f, _):
-                    return call2(sett, exch(f), aux_ext), None
+                def halos(f):
+                    """The neighbours' 8 rows, the kernels' operands
+                    beside the shard as it is: no padded copy of it."""
+                    with jax.named_scope("halo_exchange"):
+                        return _halo_blocks(f, axis, 1, width, n)
 
-                fields = scan_calls(body2, fields, trips, False)
+                if trips:
+                    aux_ext = exch(jnp.stack(
+                        [flags_i32.astype(dtype), vel, den]))
+
+                    def body2(f, _):
+                        return call2(sett, f, *halos(f), aux_ext), None
+
+                    # paired: the carry is the kernel's input and its
+                    # output at once, and with one call a body XLA copies
+                    # it before the call (the copy the padded operand
+                    # used to be)
+                    fields = scan_calls(body2, fields, trips, True)
                 if odd:
-                    fields = call1(sett, exch(fields), flags_i32, vel,
-                                   den)
+                    fields = call1(sett, fields, *halos(fields), flags_i32,
+                                   vel, den)
             else:
                 zonal = jnp.stack([fusion.zone_plane(
                     params.zone_table[j].astype(dtype), zones)
@@ -365,7 +405,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                 fields=fields,
                 flags=state.flags,
                 globals_=jnp.zeros_like(state.globals_),
-                iteration=state.iteration + niter,
+                iteration=state.iteration + steps * trips + odd,
             )
 
         f = jax.shard_map(local_iterate, mesh=mesh,
@@ -377,7 +417,12 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         if params.time_series is not None:
             raise ValueError(
                 "pallas iterate does not support Control time series")
-        out = _for_niter(int(niter))(state, params)
+        trips, odd = split(int(niter))
+        out = state
+        if trips or not odd:
+            out = _program(trips, 0)(out, params)
+        if odd:
+            out = _program(0, odd)(out, params)
         if telemetry.enabled():
             # counted host-side from the shapes: one exchange of the
             # fields per kernel call (a fused pair of steps in the tuned
@@ -387,12 +432,23 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                         + aux_planes * plane_bytes)
         return out
 
-    # no account: the kernel calls are not reported.  The generic-kernel
-    # building block is capability-probed, not proven: dispatch probes
-    # its first call and falls back to the sharded XLA engine on a Mosaic
-    # lowering failure.  fuse: steps per kernel call, for the engine tag
-    return Engine(iterate, unproven=(mode == "generic2d"),
-                  fuse=2 if mode == "tuned2d" else 1)
+    def account(niter: int, has_series: bool = False) -> dict:
+        """One call's kernel calls, those its two-call loop body issues,
+        and the halo rows a side the kernel takes as operands of their
+        own (0 where the mode pads the shard round them)."""
+        trips, odd = split(niter)
+        tuned = mode == "tuned2d"
+        return dict(kernel_calls=trips + odd,
+                    paired_calls=paired_calls(trips) if tuned else 0,
+                    halo_operand_rows=width if tuned else 0)
+
+    # The generic-kernel building block is capability-probed, not proven:
+    # dispatch probes its first call and falls back to the sharded XLA
+    # engine on a Mosaic lowering failure.  fuse: steps per kernel call,
+    # for the engine tag.  impl: the jitted programs, for the compile tests
+    return Engine(iterate, account, unproven=(mode == "generic2d"),
+                  fuse=steps,
+                  impl=dict(program=_program))
 
 
 def make_sharded_iterate(model: Model, mesh: Mesh,

@@ -554,10 +554,29 @@ def test_generic_resident_drop_512(one_chip, monkeypatch):
     assert re.search(r"%generic_band_fuse1[\w.]* = \S+ custom-call\(", text)
 
 
-def test_sharded_d2q9_4096_on_4x1_mesh(topo):
-    """The four-chip path of chip_smoke.py: the sharded Pallas step over
-    a y-split mesh of the described topology's devices — kernel and halo
-    exchange both present in what the chip would run."""
+@pytest.mark.parametrize("niter", [500, 250, 2])
+def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
+    """The four-chip path of chip_smoke.py and of the two ``karman4096``
+    cells: the sharded Pallas step over a y-split mesh of the described
+    topology's devices, kernel and halo exchange both present in what
+    the chip would run.  Compiled are **the engine's own jitted
+    programs, donating their state as the engine donates it** (an outer
+    ``jit`` drops the inner donation and the compile says nothing of the
+    chip's program), for the engine steps of ``Lattice.iterate(niter)``:
+    one less, the last step being the tail's.  499 and 249 steps are a
+    loop of 249 and 124 kernel calls, two a body, and the odd step in a
+    program of its own (``iterate(2)`` runs that one alone).  The
+    loop's body holds two ``d2q9_band_fuse2`` calls and four
+    ``collective-permute`` of the neighbours' (11, 8, 1024) blocks,
+    which the kernel takes as operands of their own: **no ``pad``,
+    ``concatenate`` or copy of an array of the shard's size, and no move
+    of the state in or out of the compiler's fast memory, inside the
+    ``while``; the carry and the first call's result both live in that
+    memory** (``S(1)``; the state goes in once before the loop and comes
+    out once after it): ``kernel2`` waits for its input copies, and on a
+    state in HBM it read 0.180 ns an update for 0.103 (chip, PR 47).
+    With the one-step kernel in the same program the compiler keeps the
+    first call's result in HBM: hence the two programs."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from tclb_tpu.core.lattice import LatticeState
@@ -568,6 +587,11 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo):
     it = halo.make_sharded_pallas_iterate(m, mesh, shape, jnp.float32,
                                           present=present, interpret=False)
     assert it is not None
+    trips, odd = divmod(niter - 1, 2)
+    assert odd == 1
+    assert it.account(niter - 1) == dict(
+        kernel_calls=trips + 1, paired_calls=trips - trips % 2,
+        halo_operand_rows=8)
     specs = LatticeState(fields=halo.field_spec(mesh),
                          flags=halo.flag_spec(mesh),
                          globals_=P(), iteration=P())
@@ -579,12 +603,39 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo):
         lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
         lat.params)
-    text = jax.jit(lambda s, p: it(s, p, 5)).lower(
-        state, params).compile().as_text()
+    lowered = it.impl["program"](trips, int(not trips)).lower(state, params)
+    assert lowered.args_info[0][0].fields.donated
+    text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
-    assert "d2q9_band_fuse2/pallas_call" in text
     assert "halo_exchange/" in text
+    if not trips:
+        # the odd step: one call of the one-step kernel on the shard as
+        # it is and the two blocks, no shard extended by its halo rows
+        assert "d2q9_band_fuse1/pallas_call" in text
+        assert "d2q9_band_fuse2/pallas_call" not in text
+        assert "f32[11,1040,1024]" not in text
+        return
+    assert "d2q9_band_fuse2/pallas_call" in text
+    assert "d2q9_band_fuse1/pallas_call" not in text
+    body, calls = _kernel_loop_body(text, "d2q9_band_fuse2")
+    assert calls == 2
+    permutes = [line for line in body
+                if re.search(r"= \(.*\) collective-permute-start\(", line)]
+    assert len(permutes) == 4
+    assert all(line.split("= (")[1].startswith("f32[11,8,1024]")
+               for line in permutes)
+    # nothing of the shard's size (11 planes of 1024 rows and more) is
+    # made in the body beside the two kernels' results
+    made = [line.strip() for line in body
+            if re.search(r"= f32\[11,1\d{3},1024\]\S* (?!custom-call|"
+                         r"get-tuple-element|parameter)", line)]
+    assert not made, made
+    assert not _state_moves(body, m, (1024, 1024))
+    # both of the loop's state buffers in the compiler's fast memory
+    results = [line.split(" custom-call(")[0] for line in body
+               if " custom-call(" in line]
+    assert len(results) == 2 and all("S(1)}" in r for r in results), results
 
 
 def _quantity_on_4x1_mesh(topo, programs, name, quantity):

@@ -998,14 +998,18 @@ def test_compile_events_name_the_function_and_the_span(seen):
     assert len([e for e in seen if e["kind"] == "compile"]) == n
 
 
-def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch):
+@pytest.mark.parametrize("niter", [8, 12])
+def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch, niter):
     """The tuned 2D sharded engine on a 4x1 mesh of the forced CPU
     devices: 2 x width x plane x planes x itemsize per exchange, one
     exchange per fused pair (and one for an odd step), the three-plane
-    aux stack once per call."""
+    aux stack once per call.  The span carries the engine's account
+    beside the bytes: its kernel calls, those a two-call loop body
+    issues (none of three trips, four of five), and the 8 halo rows a
+    side the kernel takes as operands of their own."""
     from tclb_tpu.parallel.mesh import make_mesh
     monkeypatch.setenv("TCLB_FASTPATH", "force")
-    ny, nx, niter = 64, 128, 8
+    ny, nx = 64, 128
     m = get_model("d2q9")
     mesh = make_mesh((ny, nx), devices=jax.devices()[:4],
                      decomposition={"y": 4, "x": 1})
@@ -1024,6 +1028,12 @@ def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch):
     want = (nfast // 2 + nfast % 2) * m.n_storage * plane + 3 * plane
     fused, = _spans(seen, "iterate.fused")
     assert fused["iters"] == nfast and fused["halo_bytes"] == want
+    calls, paired = {8: (4, 0), 12: (6, 4)}[niter]
+    assert (fused["kernel_calls"], fused["paired_calls"],
+            fused["halo_operand_rows"]) == (calls, paired, 8)
+    assert telemetry.counters()["engine.kernel_calls"] == calls
+    # as it counted: a fused step an exchange, the XLA step one a mesh axis
+    assert telemetry.counters()["halo.exchanges"] == nfast + 2
     # the trailing step is the sharded XLA step: the planes that cross y
     # (3 of d2q9's 9 each way), one row wide
     step, = _spans(seen, "iterate.globals_step")

@@ -364,7 +364,7 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
         )
 
     band = make_pallas_iterate(model, shape, dtype, interpret=interpret,
-                               fuse=1, present=present)
+                               fuse=1, present=present, paired=False)
 
     def account(niter: int, has_series: bool = False) -> dict:
         """What one ``iterate(niter)`` issues, reckoned host-side from
@@ -436,9 +436,27 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         present: Optional[set] = None,
                         ext_halo: bool = False,
                         _want_step_ctx: bool = False,
-                        points: Optional[np.ndarray] = None):
+                        points: Optional[np.ndarray] = None,
+                        paired: bool = True):
     """Build ``iterate(state, params, niter) -> state`` running the fused
     Pallas collide-stream kernel.  Caller must check :func:`supports` first.
+
+    The kernel calls loop two a ``lax.scan`` body (``engine.scan_calls``),
+    so XLA copies no carry before a call, in **one program that donates
+    its state and ends in the one-step kernel**.  At 11 x 1024 x 1024 two
+    state buffers and the aux stack (105 MB) fill the compiler's fast
+    memory (``S(1)``) to its edge, and ``kernel2`` waits for its input
+    copies: 215.7 us a call on a state in ``S(1)``, 309.3 on one in HBM
+    (chip, PR 48).  Both buffers stay in ``S(1)`` only where the one-step
+    kernel follows the loop in the same donated program: it reads the
+    loop's result there and writes the caller's buffer in HBM, which
+    costs it nothing.  The loop alone (``parallel/halo.py``'s cure on a
+    mesh), the same program not donated, or an even length all in
+    fuse-2 calls keeps one buffer in HBM: 66.4 ms an ``iterate(499)``
+    for 55.3, where one call a body with its copy of the carry took
+    58.2 (``tests/test_mosaic_compile.py`` pins the placement).
+    ``paired=False`` keeps one call a body: the resident engine's
+    remainder of at most seven steps, not changed with this loop.
 
     ``points`` ((P, 2) in array index order; ``fuse`` 1) builds the
     sampled flavour for a ``<Sample>`` run: every step is one call of
@@ -446,8 +464,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     stored planes at the points after every step, (niter, planes, P).
 
     ``fuse=2`` runs TWO lattice steps per kernel band pass (halving the
-    HBM traffic per step); an odd trailing step falls back to the single-
-    step kernel.
+    HBM traffic per step); the call ends in the single-step kernel: the
+    odd step, or an even length's last two (``split``).
 
     ``present`` restricts which boundary node types are materialized
     (every case is full-band compute-then-select, so skipping absent
@@ -916,16 +934,28 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     zshift = model.zone_shift
 
+    def split(niter: int) -> tuple:
+        """``niter`` steps as the calls of the two-step kernel and the
+        one-step calls after them.  At ``fuse`` 2 a call ends in the
+        one-step kernel: once for an odd length, twice for an even one
+        (one two-step call less), which is what keeps the loop's state
+        in the compiler's fast memory (the builder's docstring; the same
+        steps to the last bit, 55.5 ms an ``iterate(500)`` at 1024 x
+        1024 where 250 two-step calls took 66.3 paired and 69.7 single:
+        chip, PR 48)."""
+        twos = max(niter - 1, 0) // 2 if fuse == 2 else 0
+        return twos, niter - 2 * twos
+
     # the sampled flavour does not donate its state: donated, the loop's
     # carry is the caller's HBM buffer and every trip of two calls ends
     # in a copy of the whole state out of the compiler's fast memory
     # (copy-done, 70 us a trip at 11 x 1024 x 1024: a quarter of the
     # device's time, chip, PR 46); not donated, the state is copied in
     # once before the loop and out once after it
-    @partial(jax.jit, static_argnames=("niter", "fuse"),
+    @partial(jax.jit, static_argnames=("niter",),
              donate_argnums=() if points is not None else 0)
-    def _iterate_jit(state: LatticeState, params: SimParams, niter: int,
-                     fuse: int = 1) -> LatticeState:
+    def _iterate_jit(state: LatticeState, params: SimParams, niter: int
+                     ) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
         fields = state.fields
         if pad:
@@ -970,20 +1000,18 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             fields, taps = scan_calls(body_t, fields, niter, True,
                                       taps=True)
         else:
-            # both loops single, paired=False: compiled paired at 1024 x
-            # 1024, one of the two state buffers leaves the compiler's
-            # fast memory and kernel2 waits for its input copies; a
-            # micro-run read the paired loop faster all the same
-            # (PERF.md section 7, PR 43's compile; ROADMAP S1)
-            if fuse == 2:
+            # two calls a loop body, so no copy of the carry before a
+            # call (ops/engine.py): 10.6 us a call of 215.7 at 1024 x
+            # 1024, 61.4 where the carry lay in HBM (chip, PR 48)
+            twos, ones = split(niter)
+            if twos:
                 aux = jnp.stack([flags_i32.astype(dtype), vel, den])
 
                 def body2(fields, _):
                     return call2(sett, refresh(fields), aux), None
 
-                fields = scan_calls(body2, fields, niter // 2, False)
-            rest = niter % 2 if fuse == 2 else niter
-            fields = scan_calls(body, fields, rest, False)
+                fields = scan_calls(body2, fields, twos, paired)
+            fields = scan_calls(body, fields, ones, paired)
         if pad:
             fields = fields[:, :ny_phys, :]
         out = LatticeState(
@@ -1004,20 +1032,25 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             raise ValueError(
                 "pallas iterate does not support Control time series; "
                 "use the XLA path for time-dependent zonal settings")
-        return _iterate_jit(state, params, niter, fuse=fuse)
+        return _iterate_jit(state, params, niter)
 
-    # band_shape: the single-step kernel's bands, for the resident
-    # engine's account of the steps it leaves to this one.  The engine
-    # itself reports nothing (no account) but in its sampled flavour,
-    # whose every step is one call on those bands
+    # the bands of the kernel the engine loops; band_shape: the
+    # single-step kernel's, for the resident engine's account of the
+    # steps it leaves to this engine
     band_shape = dict(bands=ny // by, band_rows=by, halo_rows=8,
                       pad_rows=pad)
+    looped = band_shape if fuse == 1 else dict(
+        band_shape, bands=ny // by2, band_rows=by2)
 
     def account(niter: int, has_series: bool = False) -> dict:
-        return dict(kernel_calls=niter, remainder_steps=0,
-                    paired_calls=paired_calls(niter),
-                    aux_planes=_AUX_PLANES, **band_shape)
+        """One call's kernel calls, two-step and one-step, those a
+        two-call loop body issues, and the looped kernel's bands."""
+        twos, ones = split(niter)
+        return dict(kernel_calls=twos + ones, remainder_steps=0,
+                    paired_calls=paired_calls(twos, ones) if paired else 0,
+                    aux_planes=_AUX_PLANES, **looped)
 
-    return Engine(iterate, account if points is not None else None,
-                  samples=points is not None, pad_rows=pad,
-                  impl=dict(band_shape=band_shape))
+    # impl: the jitted program, for the compile tests
+    return Engine(iterate, account, samples=points is not None,
+                  pad_rows=pad,
+                  impl=dict(band_shape=band_shape, program=_iterate_jit))

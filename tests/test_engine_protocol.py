@@ -97,7 +97,7 @@ def _sharded(name):
 # builder, whether it reports (an account), the lengths to trace: empty
 # loops, loops of one trip, odd and even loops of either kernel
 BUILDERS = {
-    "tuned_band": (_tuned_band, False, (1, 2, 11)),
+    "tuned_band": (_tuned_band, True, (1, 2, 8, 11, 12)),
     "tuned_resident": (_tuned_resident, True, (7, 8, 33, 47)),
     "tuned_3d_whole": (_tuned_3d_whole, True, (1, 2, 9, 10)),
     "tuned_3d_tiled": (_tuned_3d_tiled, True, (2, 3, 14, 15)),

@@ -75,12 +75,17 @@ def _channel(name, shape, **settings):
     return m, lat, lbm.present_types(m, flags)
 
 
-def _compile(iterate, lat, niter, one_chip) -> str:
-    spec = jax.tree.map(
+def _spec(lat, one_chip) -> tuple:
+    """The shapes of a lattice's state and parameters on the described
+    chip: what a compile takes in place of arrays."""
+    return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         (lat.state, lat.params))
+
+
+def _compile(iterate, lat, niter, one_chip) -> str:
     compiled = jax.jit(lambda s, p: iterate(s, p, niter)).lower(
-        *spec).compile()
+        *_spec(lat, one_chip)).compile()
     return compiled.as_text()
 
 
@@ -249,6 +254,13 @@ def _state_copies(lines, m, shape) -> list:
     return [line.strip() for line in lines if copy.search(line)]
 
 
+def _in_fast_memory(body) -> list:
+    """Of the kernel calls among a loop body's lines, whether each one's
+    result lives in the compiler's fast memory (``S(1)``)."""
+    return ["S(1)}" in line.split(" custom-call(")[0] for line in body
+            if " custom-call(" in line]
+
+
 @pytest.mark.parametrize("case,fuse", [
     ("channel", None), ("channel", 1), ("tgv256", None),
     ("channel512", None)],
@@ -392,24 +404,55 @@ def test_generic_band_drop_1024_pairs_the_calls(one_chip):
     assert not _state_copies(body, m, shape)
 
 
-def test_d2q9_band_1024_stays_one_call_a_body(one_chip):
-    """The tuned band engine's loop is NOT paired (``PERF.md`` section
-    7): compiled with two calls a body at 1024 x 1024, one of the two 46
-    MB state buffers leaves the compiler's fast memory (``S(1)``), and
-    ``kernel2`` waits for its input copies before it computes.  So its
-    body holds one call and the copy of the carry before it; a change
-    that pairs it has that finding to answer, and PR 43's micro-run on
-    the chip, which read the paired loop 4.7 % faster, to cite.  11
-    steps: five looped calls and a step over."""
+@pytest.mark.parametrize("fuse,niter,twos,ones", [
+    (2, 499, 249, 1), (2, 500, 249, 2), (1, 499, 0, 499)],
+    ids=["fuse2-499", "fuse2-500", "fuse1-499"])
+def test_d2q9_band_1024_pairs_the_calls(one_chip, fuse, niter, twos, ones):
+    """The tuned band engine's unsampled loop at the size of the two
+    ``karman1024`` cells, whose ``iterate(500)`` is 499 engine steps:
+    **the engine's own jitted program, donating its state as the engine
+    donates it** (an outer ``jit`` drops the inner donation and the
+    compile says nothing of the chip's program).  The loop's body holds
+    two kernel calls and no copy or move of the whole state (one call a
+    body copied the 46 MB carry before every call: ``copy.18``, 2.64 ms
+    an ``iterate(500)``).  At ``fuse`` 2 **both calls' results live in
+    the compiler's fast memory** (``S(1)``): ``kernel2`` waits for its
+    input copies, and on a state it reads from HBM it took 309.3 us a
+    call for 215.7 (chip, PR 48: 66.4 ms an ``iterate(499)`` for 55.3).
+    Two states and the aux stack are 105 MB of that memory, and **what
+    tips the placement is the program's end**: donated and ending in the
+    one-step kernel (which reads the loop's result in ``S(1)`` and
+    writes the caller's buffer in HBM) both buffers stay there; the loop
+    alone (``parallel/halo.py``'s cure on a mesh), the same program not
+    donated, or an even length all in two-step calls puts one of them in
+    HBM.  So an even length ends in two one-step calls (``split``): 249
+    two-step calls, 248 of them looped, and two steps.  The one-step
+    loop is held to the pairing alone: its kernel prefetches its band
+    and read 98.3 and 98.1 us a call with one buffer in HBM (chip, PR
+    48; 61.4 us a call of carry copy gone: 81.1 -> 50.3 ms)."""
     shape = (1024, 1024)
     m, lat, present = _channel("d2q9", shape, nu=0.02)
     it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
-                                         interpret=False, fuse=2,
+                                         interpret=False, fuse=fuse,
                                          present=present)
-    text = _compile(it, lat, 11, one_chip)
-    body, calls = _kernel_loop_body(text, "d2q9_band_fuse2")
-    assert calls == 1
-    assert len(_state_copies(body, m, shape)) == 1
+    rows = {2: 32, 1: 64}[fuse]          # the looped kernel's band
+    assert it.account(niter) == dict(
+        kernel_calls=twos + ones, remainder_steps=0,
+        paired_calls=248 if fuse == 2 else 498, aux_planes=3,
+        bands=1024 // rows, band_rows=rows, halo_rows=8, pad_rows=0)
+    lowered = it.impl["program"].lower(*_spec(lat, one_chip), niter=niter)
+    assert lowered.args_info[0][0].fields.donated
+    text = lowered.compile().as_text()
+    body, calls = _kernel_loop_body(text, "d2q9_band_fuse%d" % fuse)
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
+    assert not _state_moves(body, m, shape)
+    if fuse == 1:
+        return
+    # the program ends in the one-step kernel, once or twice
+    assert len(re.findall(r"= \S+ custom-call\(.*d2q9_band_fuse1/", text)) \
+        == ones
+    assert _in_fast_memory(body) == [True, True]
 
 
 # the eight probes of the cell karman1024probes.sampled, (row, column)
@@ -434,11 +477,8 @@ def _state_moves(lines, m, shape) -> list:
 def _compile_donating(iterate, lat, niter, one_chip) -> str:
     """As :func:`_compile`, the state donated: what a sampled flavour
     would compile to if its program donated like the unsampled one."""
-    spec = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        (lat.state, lat.params))
-    return jax.jit(lambda s, p: iterate(s, p, niter),
-                   donate_argnums=0).lower(*spec).compile().as_text()
+    return jax.jit(lambda s, p: iterate(s, p, niter), donate_argnums=0
+                   ).lower(*_spec(lat, one_chip)).compile().as_text()
 
 
 def test_d2q9_band_1024_sampled_pairs_the_calls(one_chip):
@@ -633,9 +673,7 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
     assert not made, made
     assert not _state_moves(body, m, (1024, 1024))
     # both of the loop's state buffers in the compiler's fast memory
-    results = [line.split(" custom-call(")[0] for line in body
-               if " custom-call(" in line]
-    assert len(results) == 2 and all("S(1)}" in r for r in results), results
+    assert _in_fast_memory(body) == [True, True]
 
 
 def _quantity_on_4x1_mesh(topo, programs, name, quantity):

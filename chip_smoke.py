@@ -162,7 +162,14 @@ REHEARSAL = {
     "tgv_256.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
     "drop_512.xml": (10, {"nx": 384, "ny": 384}),
     "karman_4096.xml": (12, {"nx": 128, "ny": 256}),
+    # rows still wide enough for the raised scoped-VMEM limit
+    "karman_8192.xml": (8, {"nx": 2048, "ny": 64}),
 }
+#: example/karman_8192.xml cut to 256 rows of its 8192 nodes (a plain
+#: channel: the obstacle lies below the cut): the bands and the limit
+#: are those of the whole case, the state 92 MB
+WIDE_ROWS = {"ny": 256}
+WIDE_STEPS = 20
 
 
 def case_size(case: str) -> tuple:
@@ -191,12 +198,12 @@ class Smoke:
         telemetry.subscribe(self.events.append)
 
     def case(self, name: str, solve: int | None = None,
-             handlers: bool = True) -> str:
+             handlers: bool = True, geometry: dict | None = None) -> str:
         """The case file a phase runs: ``example/<name>`` itself, or a
-        copy under OUT with ``<Solve>`` cut to ``solve`` steps (and, in a
-        rehearsal, everything cut to REHEARSAL's size)."""
+        copy under OUT with ``<Solve>`` cut to ``solve`` steps and the
+        size attributes of ``geometry`` (and, in a rehearsal, everything
+        cut to REHEARSAL's size)."""
         src = os.path.join(EXAMPLE, name)
-        geometry = None
         if self.rehearse:
             steps, geometry = REHEARSAL[name]
             if handlers:       # a run phase; an agreement keeps its length
@@ -321,27 +328,29 @@ class Smoke:
             raise AssertionError("non-finite populations")
         return f, info, lat
 
-    def agree(self, name: str, file: str, engine: tuple) -> None:
-        """AGREE_STEPS steps of example ``file`` on the selected Pallas
-        engine and on the XLA step, same initial state; max |diff| <=
-        TOL."""
+    def agree(self, name: str, file: str, engine: tuple,
+              steps: int = AGREE_STEPS, geometry: dict | None = None
+              ) -> None:
+        """``steps`` steps of example ``file`` (at the size ``geometry``
+        cuts it to) on the selected Pallas engine and on the XLA step,
+        same initial state; max |diff| <= TOL."""
         import numpy as np
         outdir = os.path.join(OUT, name)
         shutil.rmtree(outdir, ignore_errors=True)
-        cut = self.case(file, AGREE_STEPS, handlers=False)
+        cut = self.case(file, steps, handlers=False, geometry=geometry)
         fp, ip, lat = self.fields_after(cut, outdir, engine)
         # the flow has to have moved, or agreement would be vacuous
         umax = float(np.max(np.abs(np.asarray(lat.get_quantity("U")))))
         fx, _, _ = self.fields_after(cut, outdir, engine, xla=True)
         diff = float(np.max(np.abs(fp - fx)))
         print(json.dumps({"phase": name, "engine": ip["engine"],
-                          "reference": "xla", "steps": AGREE_STEPS,
+                          "reference": "xla", "steps": steps,
                           "max_abs_diff": diff, "tolerance": TOL,
                           "max_abs_field": float(np.max(np.abs(fx))),
                           "max_abs_u": umax}), flush=True)
         if not umax > 0.0:
             raise AssertionError("velocity is zero everywhere after "
-                                 f"{AGREE_STEPS} steps")
+                                 f"{steps} steps")
         if not diff <= TOL:
             raise AssertionError(f"{ip['engine']} vs XLA: max |diff| "
                                  f"{diff:.3e} > {TOL:.1e}")
@@ -515,6 +524,10 @@ def one_chip(s: Smoke) -> None:
     s.phase("3d_tgv_256", s.run, s.case("tgv_256.xml", 500), cumulant)
     s.phase("generic_drop_512", s.run, s.case("drop_512.xml"), generic)
     s.phase("agree_d2q9", s.agree, "karman_1024.xml", ("pallas_2d[d2q9,",))
+    # rows of 8192 nodes: bands planned under the raised scoped-VMEM
+    # limit, the first call probed; a minute on the chip
+    s.phase("agree_d2q9_wide_rows", s.agree, "karman_8192.xml",
+            ("pallas_2d[d2q9,fuse=2]",), WIDE_STEPS, WIDE_ROWS)
     s.phase("agree_d3q27_cumulant", s.agree, "3d_channel.xml", cumulant)
     s.phase("agree_d3q27_cumulant_tiled", s.agree, "tgv_256.xml", cumulant)
     s.phase("agree_d2q9_kuper", s.agree, "drop_512.xml", generic)

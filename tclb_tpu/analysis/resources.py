@@ -74,7 +74,7 @@ def check_resources(model: Model, shape=None) -> list:
             findings.append(Finding(
                 "resources.band_vmem", "warning", model.name,
                 f"no band height fits the "
-                f"{pallas_generic._VMEM_SCRATCH_BUDGET >> 20} MB scratch "
+                f"{pallas_generic._BAND_SCRATCH[1] >> 20} MB scratch "
                 f"budget at {ny}x{nx} ({model.n_storage} storage planes): "
                 "generic band engine ineligible, XLA fallback", where,
                 {"n_storage": model.n_storage, "shape": list(shape)}))

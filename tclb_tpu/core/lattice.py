@@ -1231,22 +1231,10 @@ class Lattice:
             return [EngineCandidate(
                 f"pallas_sharded[{dict(self.mesh.shape)},fuse={it.fuse}]",
                 lambda: it, probe=it.unproven)]
-        if not has_series and pallas_d2q9.supports(model, shape, sdt):
-            if sampled:
-                return [cand(f"pallas_2d[{name},fuse=1]",
-                             pallas_d2q9.make_pallas_iterate, fuse=1,
-                             points=points)]
-            chain = [cand(f"pallas_2d[{name},fuse=2]",
-                          pallas_d2q9.make_pallas_iterate, fuse=2)]
-            if pallas_d2q9.supports_resident(model, shape, sdt):
-                # small domains: whole lattice VMEM-resident, 8 steps per
-                # kernel call — (1R+1W)/8 HBM traffic per step.  First
-                # call is probed (the budget cannot see Mosaic's
-                # temporaries); the band engine is the proven one under it
-                chain.insert(0, cand(f"pallas_resident[{name},fuse=8]",
-                                     pallas_d2q9.make_resident_iterate,
-                                     probe=True))
-            return chain
+        if not has_series and pallas_d2q9.covers(model, shape, sdt):
+            chain = self._band_chain(cand, sampled, points)
+            if chain:
+                return chain
         if not has_series and pallas_d3q.supports(model, shape, sdt):
             if sampled:
                 return []
@@ -1352,6 +1340,52 @@ class Lattice:
             band(fz, cap, f"pallas_generic[{name},fuse={fz},by<={cap}]",
                  probe=True, cap=cap, verdict=verdict(fz, cap))
             for fz, cap in rungs]
+
+    def _band_chain(self, cand: Callable, sampled: bool, points) -> list:
+        """The tuned 2D family's part of :meth:`_build_fast`'s chain for a
+        lattice its kernels cover: the band engine as
+        ``pallas_d2q9.band_plan`` cuts it, at two steps a kernel call or
+        (under a sampler) one, and over it the VMEM-resident engine where
+        the lattice fits.  A plan over Mosaic's own scoped-VMEM limit
+        (rows of 2048 nodes and more) has not been shown to compile: its
+        first call is probed, and under it stands the plan of the next
+        band height down; a plan at the default limit is the proven
+        engine, as it was.  A shape no plan holds must never fail at its
+        first call: empty, with a ``fused_rejected`` event that says why,
+        and what is under this family takes the case."""
+        from tclb_tpu.ops import pallas_d2q9
+        model, shape, name = self.model, self.shape, self.model.name
+        plan = pallas_d2q9.band_plan(model, *shape)
+        if plan is None:
+            telemetry.event(
+                "fused_rejected", engine="pallas_2d", model=name,
+                shape=list(shape),
+                reason=pallas_d2q9.why_no_plan(model, shape))
+            return []
+        fuse = 1 if sampled else 2
+        how = dict(fuse=fuse, points=points)
+        make = pallas_d2q9.make_pallas_iterate
+        rows = plan.band_rows[fuse - 1]
+        unproven = plan.raised(fuse)
+        chain = [cand(f"pallas_2d[{name},fuse={fuse}]", make,
+                      probe=unproven, cap=rows if unproven else 0, **how)]
+        under = unproven and rows > 8 and pallas_d2q9.band_plan(
+            model, *shape, rows_cap=rows - 8)
+        if under:
+            rows = under.band_rows[fuse - 1]
+            chain.append(cand(
+                f"pallas_2d[{name},fuse={fuse},by<={rows}]", make,
+                probe=True, cap=rows, rows_cap=rows, **how))
+        if not sampled and pallas_d2q9.supports_resident(
+                model, shape, self.storage_dtype):
+            # small domains: whole lattice VMEM-resident, 8 steps per
+            # kernel call — (1R+1W)/8 HBM traffic per step.  First call
+            # is probed (the budget cannot see Mosaic's temporaries); the
+            # band engine is the proven one under it
+            chain.insert(0, cand(f"pallas_resident[{name},fuse=8]",
+                                 pallas_d2q9.make_resident_iterate,
+                                 probe=True))
+        return chain
 
     def _build_tail(self) -> tuple:
         """The engine of the one step a hybrid engine (one that does not
@@ -1597,7 +1631,7 @@ class Lattice:
                 telemetry.counter("engine.resident_calls",
                                   did["resident_calls"])
             telemetry.counter("engine.paired_calls", did["paired_calls"])
-            telemetry.annotate(**did)
+            telemetry.annotate(**did, **(engine.vmem or {}))
         return out
 
     def _probe_tail(self) -> None:

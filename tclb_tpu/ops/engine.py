@@ -83,6 +83,10 @@ class Engine:
     fuse: Optional[int] = None      # steps a kernel call, where the tag says
     pad_rows: int = 0               # ghost rows its band stands on
     plan: Optional[tuple] = None    # (bz, by, K) of a 3D slab engine
+    # what its plan counted of scoped VMEM and the limit its looped
+    # kernel compiles under (``vmem_bytes``, ``vmem_limit_bytes``), where
+    # the account does not say: dispatch annotates it beside the account
+    vmem: Optional[dict] = None
     impl: Optional[dict] = None     # internals for sibling builders
 
     def __call__(self, state, params, niter: int):

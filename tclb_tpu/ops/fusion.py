@@ -51,6 +51,45 @@ def choose_fuse_band(reach_of: Callable[[int], int], halo: int,
     return best
 
 
+BAND_AMPLIFICATION_OK = 1.5   # rows a band reads over rows it advances
+#                the level of the 1024-wide chip records (32 rows under
+#                16 halo rows): a band that reads more is worth a
+#                raised scoped-VMEM limit and the probe that comes with it
+
+
+def plan_band(ny: int, vmem_of: Callable[[int], int], cap: int,
+              ceilings: Tuple[int, int], halo: int
+              ) -> Optional[Tuple[int, int]]:
+    """``(rows, ceiling)`` of a 2D band kernel on ``ny`` rows, or None:
+    the one rule of the tuned d2q9 band kernels and the generic band.
+
+    A band of ``rows`` rows reads ``rows + 2 * halo`` and writes
+    ``rows``, so the bytes an update moves fall with the band's height:
+    the plan is the tallest multiple of 8 (the f32 sublane tile) up to
+    ``cap`` that divides ``ny`` and whose VMEM by the caller's account,
+    ``vmem_of(rows)``, is under the ceiling.  ``ceilings``: what the
+    kernel may fill as it is built by default, and under a raised
+    scoped-VMEM limit.  The default's band stands where it reads no
+    more than ``BAND_AMPLIFICATION_OK`` times what it advances, and
+    where the raised ceiling holds no taller one; so a lattice whose
+    plan compiled under the default keeps plan and program."""
+    def tallest(ceiling):
+        best = None
+        for rows in range(8, min(ny, cap) + 1, 8):
+            if ny % rows == 0 and vmem_of(rows) <= ceiling:
+                best = rows
+        return best
+
+    low, high = ceilings
+    rows = tallest(low)
+    if rows is not None and rows + 2 * halo <= BAND_AMPLIFICATION_OK * rows:
+        return rows, low
+    taller = tallest(high)
+    if taller is not None and (rows is None or taller > rows):
+        return taller, high
+    return None if rows is None else (rows, low)
+
+
 def choose_fuse_slab(nz: int, fits: Callable[[int, int], bool],
                      cost: Callable[[int, int], float],
                      base_cost: float, reach: int = 1,

@@ -39,6 +39,7 @@ The physics here intentionally mirrors ``models/d2q9.py`` op for op;
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Callable, Optional
 
@@ -55,69 +56,130 @@ from tclb_tpu.ops import fusion, lbm
 from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls, tap
 from tclb_tpu.ops.lbm import equilibrium, present_types  # noqa: F401
 
-_VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024  # bytes for the band scratch
+_HALO = 8            # halo rows a side of a band: one f32 sublane tile
+_AUX_PLANES = 3      # what a kernel call reads beside the state: the
+#                      int32 flags, the f32 Velocity and Density planes
+
+# Mosaic's own scoped-VMEM limit on a v5e: a kernel built without
+# ``compiler_params`` compiles under it.  A plan that fits it states no
+# limit of its own, and its program is the one the chip records are of
+_VMEM_DEFAULT = 16 * 1024 * 1024
+# what a plan may ask for instead (``vmem_limit_bytes``; the chip has
+# 128 MiB), where the default holds no band or only one that reads its
+# halo rows too often (:func:`_rows`): rows of 2048 nodes and more
+_VMEM_RAISED = 100 * 1024 * 1024
+# the two-step kernel's band stops here: it waits for its input copies
+# (no prefetch), and 56 rows and more showed no gain over 48 at 1024
+# nodes a row (chip, round 3)
+_FUSED_ROWS_MAX = 48
+# Mosaic's temporaries, in f32 planes of the band, read off compiles for
+# a described v5e (the limit raised step by step until the compile
+# passed, nx 512 to 8192, bands of 8 to 128 rows, every boundary type
+# present; tests/test_tuned_band_plan.py keeps the readings): the one-step
+# kernel's by model, and more of them a plane where a plane is small
+# (d2q9: 11.5 at 32 KiB, 10.3 at 128 KiB, 6.0 at 256 KiB); the two-step
+# kernel's 29 to 30.7 planes of ``band + 10`` rows for every model
+_TEMP_PLANES_1 = {"d2q9": (12, 7), "d2q9_SRT": (21, 21),
+                  "d2q9_inc": (21, 21), "d2q9_cumulant": (21, 21),
+                  "d2q9_les": (36, 36), "d2q9_new": (30, 30)}
+_TEMP_SMALL_PLANE = 128 * 1024
+_TEMP_PLANES_2 = 31
 
 
-def _band_rows(model: Model, ny: int, nx: int) -> Optional[int]:
-    """Largest band height BY that divides ny, is a multiple of 8 (f32
-    sublane tile) and keeps the (n_storage, BY+2, nx) scratch in budget."""
-    best = None
-    for by in range(8, ny + 1, 8):
-        if ny % by:
-            continue
-        if model.n_storage * (by + 2) * nx * 4 > _VMEM_SCRATCH_BUDGET:
-            break
-        best = by
-    return best
+def band_vmem(model: Model, rows: int, nx: int, steps: int) -> int:
+    """The scoped VMEM a band kernel of ``rows`` rows needs, by the
+    planner's own account: the DMA scratch, the pipelined blocks (each
+    double-buffered) and Mosaic's temporaries.  ``steps`` 1: the
+    one-step kernel (two scratch slots of band and halos; the three aux
+    blocks and the out block); 2: the two-step kernel (one slot of the
+    state and the aux stack; the out block)."""
+    ns, row, plane = model.n_storage, nx * 4, rows * nx * 4
+    if steps == 1:
+        small, large = _TEMP_PLANES_1[model.name]
+        return (2 * ns * (rows + 2 * _HALO) * row
+                + 2 * (_AUX_PLANES + ns) * plane
+                + max(small * min(plane, _TEMP_SMALL_PLANE), large * plane))
+    return ((ns + _AUX_PLANES) * (rows + 2 * _HALO) * row + 2 * ns * plane
+            + _TEMP_PLANES_2 * (rows + 10) * row)
 
 
-def _fused_band(by: int, ny: int, nx: int) -> int:
-    """Band height of the temporally-fused kernel (its VMEM working set
-    holds two full intermediate stacks, so the band is capped lower than
-    the single-step kernel's).  The cap scales inversely with the row
-    width so the fused working set stays at the level measured safe on
-    v5e: 48 rows at nx=1024 (beats the old 32 by ~14% on the karman
-    1024x100 geometry — fewer bands, less 16-halo-row DMA amplification
-    — and ~2% at 1024^2; 56+ shows no further gain and crowds the
-    scoped-VMEM budget), halving for each doubling of nx."""
-    cap = max(8, min(48, ((64 * 1024 // max(nx, 1) - 16) // 8) * 8))
-    by2 = by
-    while by2 > 8 and (ny % by2 or by2 > cap):
-        by2 -= 8
-    return by2
+def _rows(model: Model, ny: int, nx: int, steps: int,
+          rows_cap: Optional[int] = None) -> Optional[tuple]:
+    """``(rows, limit)`` of the kernel advancing ``steps`` steps a call
+    on ``ny`` (padded) rows: :func:`fusion.plan_band` over the account
+    of :func:`band_vmem`; the limit is Mosaic's default or the raised
+    ceiling."""
+    cap = min(rows_cap or ny, _FUSED_ROWS_MAX if steps == 2 else ny)
+    return fusion.plan_band(
+        ny, lambda rows: band_vmem(model, rows, nx, steps), cap,
+        (_VMEM_DEFAULT, _VMEM_RAISED), _HALO)
 
 
-def _pad_rows(model: Model, ny: int, nx: int) -> Optional[int]:
-    """Ghost-row padding lifting the ny % 8 (sublane tile) restriction.
+@dataclasses.dataclass(frozen=True)
+class BandPlan:
+    """How the band kernels cut a lattice of (ny, nx): ghost rows under
+    it, the rows of the one-step and of the two-step kernel's band, what
+    each needs of VMEM by :func:`band_vmem`, and the scoped-VMEM limit
+    each is compiled under (``_VMEM_DEFAULT``: none is stated)."""
+    pad_rows: int
+    band_rows: tuple      # (one-step, two-step)
+    vmem_bytes: tuple
+    vmem_limit_bytes: tuple
 
-    The kernel's DMA offsets need row counts that are multiples of 8; a
-    lattice like the reference's karman.xml (1024x100) is padded with
-    >= 4 ghost rows.  The first two ghost rows mirror physical rows 0,1
-    and the last two mirror rows ny-2,ny-1 — refreshed before every
-    kernel call — so the kernel's internal wrap over the padded height
-    reproduces the EXACT periodic pull of the physical height (reach
-    <= 2 for the fused two-step kernel).  Middle ghost rows (pad > 4)
-    are never read by any physical row: they get static Wall flags and
-    evolve freely (any garbage is confined — physical rows pull only
-    from the refreshed mirror rows).
+    def raised(self, fuse: int) -> bool:
+        """Whether a kernel an engine of ``fuse`` runs (the one-step
+        kernel, at 2 the two-step kernel too) asks for more than the
+        default limit: nothing has shown yet that it compiles."""
+        return max(self.vmem_limit_bytes[:fuse]) > _VMEM_DEFAULT
 
-    The padded height is CHOSEN for band efficiency, not minimality: an
-    8-row band pays 8+16 halo rows of DMA per 8 computed (3x read
-    amplification), so padding further to reach a richer divisor (100 ->
-    120 with 24-row fused bands) is a net traffic win.  Returns the pad
-    (0 for already-aligned heights), or None if no candidate fits."""
-    if ny % 8 == 0 and _band_rows(model, ny, nx) is not None:
-        return 0
-    lo = ny + 4 if ny % 8 else ny + 8   # aligned heights without a valid
-    best, best_score = None, None       # band still pad (rare: tiny VMEM)
-    for ny_pad in range(((lo + 7) // 8) * 8, 2 * ny + 64, 8):
-        by = _band_rows(model, ny_pad, nx)
-        if by is None:
-            continue
-        by2 = _fused_band(by, ny_pad, nx)
-        score = ny_pad * (1.0 + (by2 + 16.0) / by2)
-        if best_score is None or score < best_score:
-            best, best_score = ny_pad - ny, score
+    def compiler_params(self, steps: int):
+        """What the kernel's ``pallas_call`` is given: nothing where the
+        default limit is enough, so those programs stay as they were."""
+        limit = self.vmem_limit_bytes[steps - 1]
+        return (pltpu.CompilerParams(vmem_limit_bytes=limit)
+                if limit > _VMEM_DEFAULT else None)
+
+
+def band_plan(model: Model, ny: int, nx: int, ext_halo: bool = False,
+              rows_cap: Optional[int] = None) -> Optional[BandPlan]:
+    """The plan of the band kernels for ``ny`` physical rows of ``nx``
+    nodes, or None where no band fits the raised ceiling.
+
+    By cost, the bytes a step moves: a band of ``rows`` rows reads
+    ``rows + 16`` (its two 8-row halo blocks) and writes ``rows``, over
+    the padded height.  So each kernel takes the tallest band that
+    divides the height and fits its ceiling by :func:`band_vmem`, and a
+    height that is no multiple of 8 (the f32 sublane tile the DMA
+    offsets need) is padded with ghost rows to the height that moves
+    the least: the reference's karman.xml (1024 x 100) gets 20, three
+    bands of 40.  The first two ghost rows mirror physical rows 0, 1
+    and the last two rows ny-2, ny-1, refreshed before every kernel
+    call, so the kernel's wrap over the padded height is the EXACT
+    periodic pull of the physical height (reach <= 2 for the two-step
+    kernel); ghost rows between them (pad > 4) are never read by a
+    physical row: static Wall flags, evolving freely.
+
+    ``ext_halo``: one shard of a y-split lattice, taken as it is (no
+    ghost rows).  ``rows_cap``: the rung under a plan that did not
+    compile (``Lattice._build_fast``)."""
+    def at(ny_pad):
+        one = _rows(model, ny_pad, nx, 1, rows_cap)
+        two = _rows(model, ny_pad, nx, 2, rows_cap)
+        return one and two and BandPlan(
+            ny_pad - ny, (one[0], two[0]),
+            (band_vmem(model, one[0], nx, 1), band_vmem(model, two[0], nx, 2)),
+            (one[1], two[1]))
+
+    if ny % 8 == 0 or ext_halo:
+        return at(ny)
+    best, best_score = None, None
+    for ny_pad in range((ny + 4 + 7) // 8 * 8, 2 * ny + 64, 8):
+        plan = at(ny_pad)
+        if plan:
+            rows = plan.band_rows[1]
+            score = ny_pad * (1.0 + (rows + 2.0 * _HALO) / rows)
+            if best_score is None or score < best_score:
+                best, best_score = plan, score
         if ny_pad >= ny + 64 and best is not None:
             break   # diminishing returns; keep the search bounded
     return best
@@ -130,13 +192,13 @@ _FAMILY_2D = ("d2q9_SRT", "d2q9_les", "d2q9_inc", "d2q9_cumulant",
               "d2q9_new")
 
 
-def supports(model: Model, shape, dtype) -> bool:
-    """Whether the fused kernel can run this configuration.
-
-    ``d2q9`` plus the pure-f family models whose collisions the kernel
-    implements as dedicated branches (``_FAMILY_2D`` — including
-    d2q9_new's raw-moment/LES/entropic collision, which shares
-    models.d2q9_new.collision_core with the XLA path)."""
+def covers(model: Model, shape, dtype) -> bool:
+    """Whether the fused kernels implement this model on a lattice of
+    this kind, whatever its size: ``d2q9`` plus the pure-f family models
+    whose collisions the kernel implements as dedicated branches
+    (``_FAMILY_2D`` — including d2q9_new's raw-moment/LES/entropic
+    collision, which shares models.d2q9_new.collision_core with the XLA
+    path), two dimensions, f32."""
     if model.name == "d2q9":
         pass
     elif model.name in _FAMILY_2D and model.n_storage == 9:
@@ -150,7 +212,25 @@ def supports(model: Model, shape, dtype) -> bool:
         return False
     if jax.default_backend() == "tpu" and nx % 128:
         return False  # x is the lane dimension; keep it tile-aligned
-    return _pad_rows(model, ny, nx) is not None
+    return True
+
+
+def supports(model: Model, shape, dtype) -> bool:
+    """Whether the fused kernels can run this configuration: a model and
+    a lattice they cover (:func:`covers`) of a shape that has a plan
+    (:func:`band_plan`)."""
+    return (covers(model, shape, dtype)
+            and band_plan(model, *(int(s) for s in shape)) is not None)
+
+
+def why_no_plan(model: Model, shape) -> str:
+    """What :func:`band_plan` found too large, for the ``fused_rejected``
+    event of a shape it refuses."""
+    nx = int(shape[1])
+    return (f"vmem: a band of 8 rows of {nx} nodes needs "
+            f"{band_vmem(model, 8, nx, 1)} B (one step) and "
+            f"{band_vmem(model, 8, nx, 2)} B (two steps) of "
+            f"_VMEM_RAISED={_VMEM_RAISED}")
 
 
 def _sparse_matvec(mat: np.ndarray, planes: list) -> list:
@@ -185,10 +265,6 @@ def zonal_planes(model: Model, params, zones, dtype):
     den = plane("Density") if "Density" in si \
         else 1.0 + 3.0 * plane("Pressure")
     return vel, den
-
-
-_AUX_PLANES = 3      # what a kernel call reads beside the state: the
-#                      int32 flags, the f32 Velocity and Density planes
 
 
 def resident_vmem_bytes(model: Model, ny: int, nx: int) -> int:
@@ -437,7 +513,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         ext_halo: bool = False,
                         _want_step_ctx: bool = False,
                         points: Optional[np.ndarray] = None,
-                        paired: bool = True):
+                        paired: bool = True,
+                        rows_cap: Optional[int] = None):
     """Build ``iterate(state, params, niter) -> state`` running the fused
     Pallas collide-stream kernel.  Caller must check :func:`supports` first.
 
@@ -472,6 +549,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     types is pure win); parity holds whenever it is a superset of the
     types actually painted — :func:`present_types` computes that set.
 
+    The bands, the ghost rows and the scoped-VMEM limit of each kernel
+    are :func:`band_plan`'s; ``rows_cap`` bounds the bands' rows (the
+    rung dispatch builds under a plan that did not compile).
+
     ``ext_halo=True`` builds the SHARDED building block instead: the
     domain is one device's block of a y-sharded lattice, and the kernels
     read their halos from the neighbours' exchanged rows instead of
@@ -491,7 +572,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     from tclb_tpu.models import family
     from tclb_tpu.ops import cumulant
 
-    if not supports(model, shape, dtype):
+    if not covers(model, shape, dtype):
         raise ValueError(f"pallas path unsupported for {model.name} {shape}")
     if fuse not in (1, 2):
         raise ValueError(f"fuse={fuse}: only 1 (single-step) and 2 "
@@ -500,18 +581,13 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         raise ValueError("the sampled flavour reads the state after "
                          "every step: fuse=1 only")
     ny_phys, nx = (int(s) for s in shape)
-    if ext_halo:
-        if ny_phys % 8:
-            raise ValueError("ext_halo blocks need ny % 8 == 0")
-        pad = 0
-    else:
-        pad = _pad_rows(model, ny_phys, nx)
-        if pad is None:
-            raise ValueError(f"no valid band height for shape {shape}")
+    if ext_halo and ny_phys % 8:
+        raise ValueError("ext_halo blocks need ny % 8 == 0")
+    plan = band_plan(model, ny_phys, nx, ext_halo, rows_cap)
+    if plan is None:
+        raise ValueError(f"no valid band height for shape {shape}")
+    pad, (by, by2) = plan.pad_rows, plan.band_rows
     ny = ny_phys + pad
-    by = _band_rows(model, ny, nx)
-    by2 = _fused_band(by, ny, nx)
-    assert ny % by2 == 0   # _band_rows guarantees multiple-of-8 divisors
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -904,6 +980,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             pltpu.SemaphoreType.DMA((6,)),
         ],
         interpret=interpret,
+        compiler_params=plan.compiler_params(2),
         name="d2q9_band_fuse2",
     )
 
@@ -926,6 +1003,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
         interpret=interpret,
+        compiler_params=plan.compiler_params(1),
         name="d2q9_band_fuse1",
     )
 
@@ -1037,10 +1115,12 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     # the bands of the kernel the engine loops; band_shape: the
     # single-step kernel's, for the resident engine's account of the
     # steps it leaves to this engine
-    band_shape = dict(bands=ny // by, band_rows=by, halo_rows=8,
-                      pad_rows=pad)
-    looped = band_shape if fuse == 1 else dict(
-        band_shape, bands=ny // by2, band_rows=by2)
+    def shape_of(steps):
+        rows = plan.band_rows[steps - 1]
+        return dict(bands=ny // rows, band_rows=rows, halo_rows=_HALO,
+                    pad_rows=pad)
+
+    band_shape, looped = shape_of(1), shape_of(fuse)
 
     def account(niter: int, has_series: bool = False) -> dict:
         """One call's kernel calls, two-step and one-step, those a
@@ -1050,7 +1130,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                     paired_calls=paired_calls(twos, ones) if paired else 0,
                     aux_planes=_AUX_PLANES, **looped)
 
-    # impl: the jitted program, for the compile tests
+    # vmem: the looped kernel's part of the plan, beside the account on
+    # the span; impl: the jitted program, for the compile tests
     return Engine(iterate, account, samples=points is not None,
                   pad_rows=pad,
-                  impl=dict(band_shape=band_shape, program=_iterate_jit))
+                  vmem=dict(vmem_bytes=plan.vmem_bytes[fuse - 1],
+                            vmem_limit_bytes=plan.vmem_limit_bytes[fuse - 1]),
+                  impl=dict(band_shape=band_shape, program=_iterate_jit,
+                            plan=plan))

@@ -55,7 +55,6 @@ from tclb_tpu.ops import fusion, lbm
 from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls, tap
 from tclb_tpu.ops.lbm import present_types  # noqa: F401  (re-export)
 
-_VMEM_SCRATCH_BUDGET = 4 * 1024 * 1024
 _HALO = 8   # DMA halo block height: one (8, 128) f32 tile per side
 HALO = _HALO  # public: max per-action reach a caller can plan against
 # storage dtypes the generic engines can keep in HBM.  Compute is ALWAYS
@@ -357,17 +356,19 @@ def choose_fuse(model: Model, fmax: int = fusion.FUSE_MAX) -> int:
 _DEFAULT_BY_CAP = 32
 
 
-def _band_rows(model: Model, ny: int, nx: int,
-               by_cap: Optional[int] = None,
-               itemsize: int = 4) -> Optional[int]:
-    """Largest multiple-of-8 band height dividing ny whose scratch
-    (state + aux stacks, band + two 8-row halo blocks) fits the budget.
+# what a band's DMA scratch may fill of scoped VMEM as the kernel is built
+# by default (Mosaic's own 16 MiB limit), and under the raised limit a
+# band asks for where that holds none, or only one that reads its halo
+# rows too often (:func:`fusion.plan_band`: rows of 2048 nodes and more).
+# Half of either: the rest holds the pipelined out blocks and the traced
+# physics' temporaries, which the band sizing cannot see
+_BAND_SCRATCH = (8 * 1024 * 1024, 50 * 1024 * 1024)
+_BAND_VMEM_LIMIT = 100 * 1024 * 1024
 
-    ``by_cap`` bounds the band height: the model's traced physics holds
-    its live temporaries in scoped VMEM, which the band sizing cannot see
-    — the default cap keeps typical models inside the budget and the
-    Lattice's first-call probe retries with a halved cap when a complex
-    model still overflows (Mosaic's scoped-vmem limit error)."""
+
+def _band_scratch(model: Model, by: int, nx: int, itemsize: int = 4) -> int:
+    """The two-slot DMA scratch of a band of ``by`` rows: state and aux
+    stacks, band plus two 8-row halo blocks."""
     # Budget against the LARGEST kernel flavor (the Control-series
     # variant carries value + _DT planes per zonal setting): all flavors
     # of one engine share `by` (the padded height and grid must agree),
@@ -379,15 +380,32 @@ def _band_rows(model: Model, ny: int, nx: int,
     # field planes scale with the storage itemsize; the aux stack is
     # always f32 (flags must survive the float round trip exactly)
     per_row = (model.n_storage * itemsize + n_aux * 4) * nx
-    cap = _DEFAULT_BY_CAP if by_cap is None else by_cap
-    best = None
-    for by in range(8, min(ny, cap) + 1, 8):
-        if ny % by:
-            continue
-        if 2 * (by + 2 * _HALO) * per_row > _VMEM_SCRATCH_BUDGET * 2:
-            break
-        best = by
-    return best
+    return 2 * (by + 2 * _HALO) * per_row
+
+
+def _band_plan(model: Model, ny: int, nx: int,
+               by_cap: Optional[int] = None,
+               itemsize: int = 4) -> Optional[tuple]:
+    """``(rows, budget)`` of the band on ``ny`` rows, by the rule the
+    tuned d2q9 band kernels plan by (:func:`fusion.plan_band`), over the
+    band's scratch (:func:`_band_scratch`) and ``_BAND_SCRATCH``.
+
+    ``by_cap`` bounds the band height: the model's traced physics holds
+    its live temporaries in scoped VMEM, which the band sizing cannot see
+    — the default cap keeps typical models inside the budget and the
+    Lattice's first-call probe retries with a halved cap when a complex
+    model still overflows (Mosaic's scoped-vmem limit error)."""
+    return fusion.plan_band(
+        ny, lambda by: _band_scratch(model, by, nx, itemsize),
+        _DEFAULT_BY_CAP if by_cap is None else by_cap, _BAND_SCRATCH, _HALO)
+
+
+def _band_rows(model: Model, ny: int, nx: int,
+               by_cap: Optional[int] = None,
+               itemsize: int = 4) -> Optional[int]:
+    """The rows of :func:`_band_plan`'s band, None where it has none."""
+    plan = _band_plan(model, ny, nx, by_cap, itemsize)
+    return plan and plan[0]
 
 
 def _pad_rows(model: Model, ny: int, nx: int, mirror: int,
@@ -811,7 +829,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         if pad is None:
             raise ValueError(f"no valid band height for {shape}")
     ny = ny_phys + pad
-    by = _band_rows(model, ny, nx, by_cap, itemsize)
+    by, scratch = _band_plan(model, ny, nx, by_cap, itemsize)
+    # a band planned under the raised budget says so to Mosaic; one under
+    # the default states no limit, and its program is what it was
+    raised = pltpu.CompilerParams(vmem_limit_bytes=_BAND_VMEM_LIMIT) \
+        if scratch > _BAND_SCRATCH[0] else None
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -1017,6 +1039,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 pltpu.SemaphoreType.DMA((2, 6)),
             ],
             interpret=interpret,
+            compiler_params=raised,
             name=f"generic_band_fuse{fuse if plan_n is plan else 1}",
         )
 

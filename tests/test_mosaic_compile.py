@@ -455,6 +455,43 @@ def test_d2q9_band_1024_pairs_the_calls(one_chip, fuse, niter, twos, ones):
     assert _in_fast_memory(body) == [True, True]
 
 
+# shapes the parent's band sizing could not compile, and the bands the
+# plan gives them (one-step, two-step)
+_PLANNED = [((800, 1024), (40, 40)), ((1280, 1024), (64, 40)),
+            ((1024, 2048), (128, 32)), ((64, 8192), (32, 32))]
+
+
+@pytest.mark.parametrize("shape,rows", _PLANNED,
+                         ids=["%dx%d" % s for s, _ in _PLANNED])
+def test_d2q9_band_plans_compile(one_chip, shape, rows):
+    """What ``pallas_d2q9.band_plan`` admits compiles, where the parent
+    failed at its first call on the chip: 800 and 1280 rows of 1024
+    nodes (the parent took an 80-row band, 18 MiB of Mosaic's 16: PR
+    48's finding) under the default limit, and rows of 2048 and 8192
+    nodes (the parent's scratch alone passed the limit) under the raised
+    one the plan states.  64 x 8192 is the band of the cell
+    ``karman8192.longrun`` (8192 x 8192: the same 32 rows, which the
+    host holds).  The engine's own donating program of 21 steps: ten
+    two-step calls from a loop body of two calls with no copy of the
+    state, and the one-step kernel at the end, so both kernels of the
+    plan are compiled."""
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    assert pallas_d2q9.supports(m, shape, jnp.float32)
+    it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
+                                         interpret=False, fuse=2,
+                                         present=present)
+    plan = it.impl["plan"]
+    assert plan.band_rows == rows
+    assert plan.raised(2) == (shape[1] >= 2048)
+    assert it.account(21)["paired_calls"] == 10
+    text = it.impl["program"].lower(*_spec(lat, one_chip),
+                                    niter=21).compile().as_text()
+    assert "d2q9_band_fuse1/pallas_call" in text
+    body, calls = _kernel_loop_body(text, "d2q9_band_fuse2")
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
+
+
 # the eight probes of the cell karman1024probes.sampled, (row, column)
 _PROBES = np.array([[512, 112], [512, 328], [512, 420], [512, 520],
                     [412, 520], [612, 520], [512, 720], [512, 920]])
@@ -783,7 +820,7 @@ def test_pin_is_identity_only_inside_a_compiled_body():
 
 
 @pytest.mark.parametrize("case", ["channel512", "tgv256", "karman1024",
-                                  "karman1024_sampled"])
+                                  "karman1024_sampled", "karman8192"])
 def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
                                                      case):
     """The one step the hybrid engines leave for the Globals, as the
@@ -798,10 +835,13 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
     kernel: donated, the call's output would have to be the buffer it
     reads halos from.  Under a sampler (``karman1024probes``) the
     tail is built with the probes and its one call returns their planes
-    beside the state: the same one call, still no copy."""
+    beside the state: the same one call, still no copy.  At rows of
+    8192 nodes (``karman8192``: 64 rows stand for its 8192, the band is
+    the same 32) the generic band had no plan and this step was XLA's;
+    its band is planned under the raised limit now."""
     sampled = case == "karman1024_sampled"
-    if case.startswith("karman1024"):
-        shape = (1024, 1024)
+    if case.startswith("karman"):
+        shape = (64, 8192) if case == "karman8192" else (1024, 1024)
         m, lat, _ = _channel("d2q9", shape, nu=0.02)
         if sampled:
             from tclb_tpu.utils.sampler import Sampler
@@ -829,7 +869,9 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
     assert tail.full_globals and tail.samples == sampled
     did = tail.account(1, False)
     assert (did["kernel_calls"], did["aux_planes"]) == (1, 1)
-    if not case.startswith("karman1024"):
+    if case == "karman8192":
+        assert (did["band_rows"], did["bands"]) == (32, 2)
+    if not case.startswith("karman"):
         assert tail.plan == {"channel512": (1, 48, 1),
                              "tgv256": (4, 32, 1)}[case]
     one = lambda s, p: tail(s, p, 1)    # noqa: E731
@@ -841,7 +883,7 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert f"{kernel}/pallas_call" in text
     assert not _state_copies(text.splitlines(), m, shape)
-    if not case.startswith("karman1024"):
+    if not case.startswith("karman"):
         # donated, as every schedule of two calls and more is, it would
         donated = jax.jit(one, donate_argnums=0)
         assert len(_state_copies(donated.lower(

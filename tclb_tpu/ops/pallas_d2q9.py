@@ -66,19 +66,24 @@ _AUX_PLANES = 3      # what a kernel call reads beside the state: the
 _VMEM_DEFAULT = 16 * 1024 * 1024
 # what a plan may ask for instead (``vmem_limit_bytes``; the chip has
 # 128 MiB), where the default holds no band or only one that reads its
-# halo rows too often (:func:`_rows`): rows of 2048 nodes and more
-_VMEM_RAISED = 100 * 1024 * 1024
-# the two-step kernel's band stops here: it waits for its input copies
-# (no prefetch), and 56 rows and more showed no gain over 48 at 1024
-# nodes a row (chip, round 3)
+# halo rows too often (:func:`_rows`): rows of 1536 nodes and more.  It
+# holds the two-step kernel's 32-row band at 8192 nodes a row with both
+# slots of its input (104.7 MiB by the account, 102.2 by Mosaic's count)
+_VMEM_RAISED = 106 * 1024 * 1024
+# the two-step kernel's band stops here: 56 rows and more showed no gain
+# over 48 at 1024 nodes a row (chip, round 3, on the kernel that waited
+# for its input copies; not read again since it prefetches them)
 _FUSED_ROWS_MAX = 48
+_BAND_SLOTS = 2      # scratch slots of a band kernel's input: the band
+#                      computed from and the next one's copies in flight
 # Mosaic's temporaries, in f32 planes of the band, read off compiles for
 # a described v5e (the limit raised step by step until the compile
 # passed, nx 512 to 8192, bands of 8 to 128 rows, every boundary type
 # present; tests/test_tuned_band_plan.py keeps the readings): the one-step
 # kernel's by model, and more of them a plane where a plane is small
 # (d2q9: 11.5 at 32 KiB, 10.3 at 128 KiB, 6.0 at 256 KiB); the two-step
-# kernel's 29 to 30.7 planes of ``band + 10`` rows for every model
+# kernel's 27.3 to 30.3 planes of ``band + 10`` rows for every model
+# (read again since it holds two slots of its band: 18 readings)
 _TEMP_PLANES_1 = {"d2q9": (12, 7), "d2q9_SRT": (21, 21),
                   "d2q9_inc": (21, 21), "d2q9_cumulant": (21, 21),
                   "d2q9_les": (36, 36), "d2q9_new": (30, 30)}
@@ -91,16 +96,17 @@ def band_vmem(model: Model, rows: int, nx: int, steps: int) -> int:
     planner's own account: the DMA scratch, the pipelined blocks (each
     double-buffered) and Mosaic's temporaries.  ``steps`` 1: the
     one-step kernel (two scratch slots of band and halos; the three aux
-    blocks and the out block); 2: the two-step kernel (one slot of the
-    state and the aux stack; the out block)."""
+    blocks and the out block); 2: the two-step kernel (two slots of the
+    state and of the aux stack, each with its halo blocks; the out
+    block)."""
     ns, row, plane = model.n_storage, nx * 4, rows * nx * 4
     if steps == 1:
         small, large = _TEMP_PLANES_1[model.name]
-        return (2 * ns * (rows + 2 * _HALO) * row
+        return (_BAND_SLOTS * ns * (rows + 2 * _HALO) * row
                 + 2 * (_AUX_PLANES + ns) * plane
                 + max(small * min(plane, _TEMP_SMALL_PLANE), large * plane))
-    return ((ns + _AUX_PLANES) * (rows + 2 * _HALO) * row + 2 * ns * plane
-            + _TEMP_PLANES_2 * (rows + 10) * row)
+    return (_BAND_SLOTS * (ns + _AUX_PLANES) * (rows + 2 * _HALO) * row
+            + 2 * ns * plane + _TEMP_PLANES_2 * (rows + 10) * row)
 
 
 def _rows(model: Model, ny: int, nx: int, steps: int,
@@ -522,9 +528,13 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     so XLA copies no carry before a call, in **one program that donates
     its state and ends in the one-step kernel**.  At 11 x 1024 x 1024 two
     state buffers and the aux stack (105 MB) fill the compiler's fast
-    memory (``S(1)``) to its edge, and ``kernel2`` waits for its input
-    copies: 215.7 us a call on a state in ``S(1)``, 309.3 on one in HBM
-    (chip, PR 48).  Both buffers stay in ``S(1)`` only where the one-step
+    memory (``S(1)``) to its edge, and the ``kernel2`` that waited for
+    its input copies took 215.7 us a call on a state in ``S(1)``, 309.3
+    on one in HBM (chip, PR 48; it prefetches its band since PR 50, at
+    8192 x 8192, both buffers in HBM, 15.4 ms a call for 26.0; on a
+    state in ``S(1)`` the copies had cost little and it reads 1 % more:
+    the placement below is still what these programs are built for).
+    Both buffers stay in ``S(1)`` only where the one-step
     kernel follows the loop in the same donated program: it reads the
     loop's result there and writes the caller's buffer in HBM, which
     costs it nothing.  The loop alone (``parallel/halo.py``'s cure on a
@@ -808,8 +818,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 _start_sharded(dmas, halo_dmas(slot), band == 0,
                                band == n - 1)
 
-        slot = jax.lax.rem(i, jnp.int32(2))
-        nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(2))
+        slot = jax.lax.rem(i, jnp.int32(_BAND_SLOTS))
+        nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(_BAND_SLOTS))
 
         @pl.when(i == 0)
         def _():
@@ -854,65 +864,80 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         2-row reach.  ``aux_hbm`` stacks (flags-as-f32, Velocity, Density)
         so the statics ride the same contiguous-buffer DMA scheme (flag
         values < 2^16 are exact in f32).  Like kernel, the band+halos land
-        in ONE contiguous (by2+16)-row buffer so extended-row access is a
-        single slice, not a concatenate.  ``halos`` (the sharded flavour,
+        in ONE contiguous (by2+16)-row buffer a slot so extended-row
+        access is a single slice, not a concatenate, and like kernel it is
+        double-slotted: band i+1's six copies are issued before band i's
+        arithmetic.  ``halos`` (the sharded flavour,
         :func:`kernel2_sharded`): the neighbours' two 8-row blocks."""
         i = pl.program_id(0)
-        base = pl.multiple_of(i * jnp.int32(by2), 8)
-        if halos is not None:
-            # the aux stack's rows are [halo(8) | local ny | halo(8)]: a
-            # band lives at base+8, its halos at base and base+8+by2 — no
-            # wrap, the exchanged rows ARE the neighbors.  The field
-            # stack is the shard as it is
-            a_mid8 = pl.multiple_of(base + jnp.int32(8), 8)
-            a_top8 = base
-            a_bot8 = pl.multiple_of(base + jnp.int32(8 + by2), 8)
-            mid8 = base
-            top8, bot8 = _shard_halo_rows(base, by2, ny)
-        else:
-            mid8 = base
-            top8 = pl.multiple_of(
-                jax.lax.rem(base - jnp.int32(8) + jnp.int32(ny),
-                            jnp.int32(ny)), 8)
-            bot8 = pl.multiple_of(
-                jax.lax.rem(base + jnp.int32(by2), jnp.int32(ny)), 8)
-            a_mid8, a_top8, a_bot8 = mid8, top8, bot8
-        dmas = (
-            pltpu.make_async_copy(f_hbm.at[:, pl.ds(mid8, by2), :],
-                                  buff.at[:, pl.ds(8, by2), :], sems.at[0]),
-            pltpu.make_async_copy(f_hbm.at[:, pl.ds(top8, 8), :],
-                                  buff.at[:, pl.ds(0, 8), :], sems.at[1]),
-            pltpu.make_async_copy(f_hbm.at[:, pl.ds(bot8, 8), :],
-                                  buff.at[:, pl.ds(8 + by2, 8), :],
-                                  sems.at[2]),
-            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(a_mid8, by2), :],
-                                  bufa.at[:, pl.ds(8, by2), :], sems.at[3]),
-            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(a_top8, 8), :],
-                                  bufa.at[:, pl.ds(0, 8), :], sems.at[4]),
-            pltpu.make_async_copy(aux_hbm.at[:, pl.ds(a_bot8, 8), :],
-                                  bufa.at[:, pl.ds(8 + by2, 8), :],
-                                  sems.at[5]),
-        )
-        if halos is None:
-            for d in dmas:
-                d.start()
-        else:
+        n = pl.num_programs(0)
+
+        def band_dmas(slot, band):
+            base = pl.multiple_of(band * jnp.int32(by2), 8)
+            if halos is not None:
+                # the aux stack's rows are [halo(8) | local ny | halo(8)]:
+                # a band lives at base+8, its halos at base and
+                # base+8+by2 — no wrap, the exchanged rows ARE the
+                # neighbors.  The field stack is the shard as it is
+                a_mid8 = pl.multiple_of(base + jnp.int32(8), 8)
+                a_top8 = base
+                a_bot8 = pl.multiple_of(base + jnp.int32(8 + by2), 8)
+                top8, bot8 = _shard_halo_rows(base, by2, ny)
+            else:
+                top8 = pl.multiple_of(
+                    jax.lax.rem(base - jnp.int32(8) + jnp.int32(ny),
+                                jnp.int32(ny)), 8)
+                bot8 = pl.multiple_of(
+                    jax.lax.rem(base + jnp.int32(by2), jnp.int32(ny)), 8)
+                a_mid8, a_top8, a_bot8 = base, top8, bot8
+            return tuple(
+                pltpu.make_async_copy(src.at[:, pl.ds(at, rows), :],
+                                      buf.at[slot, :, pl.ds(to, rows), :],
+                                      sems.at[slot, j])
+                for j, (src, buf, at, to, rows) in enumerate((
+                    (f_hbm, buff, base, 8, by2),
+                    (f_hbm, buff, top8, 0, 8),
+                    (f_hbm, buff, bot8, 8 + by2, 8),
+                    (aux_hbm, bufa, a_mid8, 8, by2),
+                    (aux_hbm, bufa, a_top8, 0, 8),
+                    (aux_hbm, bufa, a_bot8, 8 + by2, 8))))
+
+        def start_band(slot, band):
+            dmas = band_dmas(slot, band)
+            if halos is None:
+                for d in dmas:
+                    d.start()
+                return
             theirs = (
                 pltpu.make_async_copy(
-                    halos[0], buff.at[:, pl.ds(0, 8), :], sems.at[1]),
+                    halos[0], buff.at[slot, :, pl.ds(0, 8), :],
+                    sems.at[slot, 1]),
                 pltpu.make_async_copy(
-                    halos[1], buff.at[:, pl.ds(8 + by2, 8), :], sems.at[2]))
-            _start_sharded(dmas[:3], theirs, i == 0,
-                           i == pl.num_programs(0) - 1)
+                    halos[1], buff.at[slot, :, pl.ds(8 + by2, 8), :],
+                    sems.at[slot, 2]))
+            _start_sharded(dmas[:3], theirs, band == 0, band == n - 1)
             for d in dmas[3:]:
                 d.start()
-        for d in dmas:
+
+        slot = jax.lax.rem(i, jnp.int32(_BAND_SLOTS))
+        nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(_BAND_SLOTS))
+
+        @pl.when(i == 0)
+        def _():
+            start_band(jnp.int32(0), i)
+
+        @pl.when(i + 1 < n)
+        def _():
+            start_band(nxt, i + jnp.int32(1))
+
+        for d in band_dmas(slot, i):
             d.wait()
 
         def ext(buf, k, lo, hi):
             """Rows [lo, hi) of the band-extended plane k (band row 0 is
-            buffer row 8) — a single slice of the contiguous buffer."""
-            return buf[k, 8 + lo:8 + hi, :]
+            buffer row 8) — a single slice of the slot's contiguous
+            buffer."""
+            return buf[slot, k, 8 + lo:8 + hi, :]
 
         # ---- step 1 on rows [-1, by+1) ---------------------------------- #
         pulled = []
@@ -940,6 +965,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                        bc0_e[1:by2 + 1] if bc_idx else 0.0,
                        bc1_e[1:by2 + 1] if bc_idx else 0.0,
                        sett)
+
         for k in range(9):
             out_ref[k] = f2[k]
         if bc_idx:
@@ -964,6 +990,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     # the field stack and, in the sharded flavour, the neighbours' blocks
     f_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (3 if ext_halo else 1)
 
+    # both kernels start band i + 1's copies at grid step i and wait for
+    # them at step i + 1: the grid is walked in order, on one core (the
+    # default, "arbitrary", of a grid dimension; a v5e has one core)
     grid2 = (ny // by2,)
     call2 = pl.pallas_call(
         lbm.mosaic_body(kernel2_sharded if ext_halo else kernel2,
@@ -975,9 +1004,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
         scratch_shapes=[
-            pltpu.VMEM((n_storage, by2 + 16, nx), dtype),
-            pltpu.VMEM((3, by2 + 16, nx), dtype),
-            pltpu.SemaphoreType.DMA((6,)),
+            pltpu.VMEM((_BAND_SLOTS, n_storage, by2 + 16, nx), dtype),
+            pltpu.VMEM((_BAND_SLOTS, _AUX_PLANES, by2 + 16, nx), dtype),
+            pltpu.SemaphoreType.DMA((_BAND_SLOTS, 6)),
         ],
         interpret=interpret,
         compiler_params=plan.compiler_params(2),
@@ -999,8 +1028,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, n_storage, by + 16, nx), dtype),
-            pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.VMEM((_BAND_SLOTS, n_storage, by + 16, nx), dtype),
+            pltpu.SemaphoreType.DMA((_BAND_SLOTS, 3)),
         ],
         interpret=interpret,
         compiler_params=plan.compiler_params(1),
@@ -1124,11 +1153,12 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     def account(niter: int, has_series: bool = False) -> dict:
         """One call's kernel calls, two-step and one-step, those a
-        two-call loop body issues, and the looped kernel's bands."""
+        two-call loop body issues, and the looped kernel's bands and
+        the scratch slots it holds of one."""
         twos, ones = split(niter)
         return dict(kernel_calls=twos + ones, remainder_steps=0,
                     paired_calls=paired_calls(twos, ones) if paired else 0,
-                    aux_planes=_AUX_PLANES, **looped)
+                    aux_planes=_AUX_PLANES, band_slots=_BAND_SLOTS, **looped)
 
     # vmem: the looped kernel's part of the plan, beside the account on
     # the span; impl: the jitted program, for the compile tests

@@ -340,10 +340,11 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         the ``odd`` call after it.  Two programs (:func:`iterate`), not
         one: with the one-step kernel in the loop's program the compiler
         keeps one of the loop's two state buffers, or both, out of its
-        fast memory at 11 x 1024 x 1024, and ``kernel2``, which waits for
-        its input copies, takes 308 to 380 us a call on a state it reads
+        fast memory at 11 x 1024 x 1024, and the ``kernel2`` that waited
+        for its input copies took 308 to 380 us a call on a state it read
         from HBM for 216 (compiled for a described 4 x 1 v5e,
-        ``tests/test_mosaic_compile.py``; chip, PR 47)."""
+        ``tests/test_mosaic_compile.py``; chip, PR 47; since PR 50 it
+        prefetches its band, and the placement was not measured again)."""
         def local_iterate(state: LatticeState, params: SimParams
                           ) -> LatticeState:
             flags_i32 = state.flags.astype(jnp.int32)

@@ -416,9 +416,11 @@ def test_d2q9_band_1024_pairs_the_calls(one_chip, fuse, niter, twos, ones):
     two kernel calls and no copy or move of the whole state (one call a
     body copied the 46 MB carry before every call: ``copy.18``, 2.64 ms
     an ``iterate(500)``).  At ``fuse`` 2 **both calls' results live in
-    the compiler's fast memory** (``S(1)``): ``kernel2`` waits for its
-    input copies, and on a state it reads from HBM it took 309.3 us a
-    call for 215.7 (chip, PR 48: 66.4 ms an ``iterate(499)`` for 55.3).
+    the compiler's fast memory** (``S(1)``): the ``kernel2`` that waited
+    for its input copies took 309.3 us a call on a state it read from
+    HBM for 215.7 (chip, PR 48: 66.4 ms an ``iterate(499)`` for 55.3;
+    since PR 50 it prefetches its band, 12.8 MiB of scoped VMEM for 10.1
+    under the same 16 MiB limit, and the placement holds).
     Two states and the aux stack are 105 MB of that memory, and **what
     tips the placement is the program's end**: donated and ending in the
     one-step kernel (which reads the loop's result in ``S(1)`` and
@@ -439,7 +441,8 @@ def test_d2q9_band_1024_pairs_the_calls(one_chip, fuse, niter, twos, ones):
     assert it.account(niter) == dict(
         kernel_calls=twos + ones, remainder_steps=0,
         paired_calls=248 if fuse == 2 else 498, aux_planes=3,
-        bands=1024 // rows, band_rows=rows, halo_rows=8, pad_rows=0)
+        bands=1024 // rows, band_rows=rows, halo_rows=8, pad_rows=0,
+        band_slots=2)
     lowered = it.impl["program"].lower(*_spec(lat, one_chip), niter=niter)
     assert lowered.args_info[0][0].fields.donated
     text = lowered.compile().as_text()
@@ -492,6 +495,67 @@ def test_d2q9_band_plans_compile(one_chip, shape, rows):
     assert not _state_copies(body, m, shape)
 
 
+def _mosaic_modules(text: str) -> dict:
+    """The Mosaic modules of a lowered program's kernel calls, as text,
+    by the kernel's name (the calls of one kernel share a module)."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    found = {}
+    for call in re.finditer(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22'
+                            r'.*?kernel_name = "(\w+)"', text):
+        name = call.group(2)
+        with mlir.JaxIrContext() as ctx:
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True   # ``stable_mosaic``
+            module = ir.Module.parse(base64.b64decode(call.group(1)))
+            found[name] = module.operation.get_asm(enable_debug_info=False)
+    return found
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["chip", "shard"])
+def test_d2q9_two_step_kernel_prefetches_its_band(one_chip, sharded):
+    """The two-step kernel's Mosaic module, read off the lowered program
+    of 1024 x 1024 (nothing is compiled): **two slots** of the state's
+    and of the aux stack's scratch (the band of 32 rows under its two
+    8-row halo blocks) beside the pipelined out block, and **every copy
+    of band 0 and of band 1 is started before the first wait**: six and
+    six on one chip; on a shard eight and eight, the halo blocks' two
+    sources each under its own branch; then the six waits of the band
+    computed.  The grid is one dimension walked in order
+    (``arbitrary``), which a copy started one grid step and waited for
+    at the next relies on."""
+    shape = (1024, 1024)
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    built = pallas_d2q9.make_pallas_iterate(
+        m, shape, jnp.float32, interpret=False, fuse=2, present=present,
+        ext_halo=sharded)
+    if sharded:
+        text = jax.jit(built[1]).lower(*(
+            jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+            for dims in ((len(m.settings),), (11, 1024, 1024),
+                         (11, 8, 1024), (11, 8, 1024),
+                         (3, 1040, 1024)))).as_text()
+    else:
+        assert built.account(3)["band_slots"] == 2
+        text = built.impl["program"].lower(*_spec(lat, one_chip),
+                                           niter=3).as_text()
+    module = _mosaic_modules(text)["d2q9_band_fuse2"]
+    vmem = set(re.findall(r"memref<([0-9x]+)xf32, #tpu.memory_space<vmem>>",
+                          module.split("function_type = ")[1]
+                          .split(" -> ")[0]))
+    # the state's and the aux stack's slots, and the out block's window
+    assert vmem == {"2x11x48x1024", "2x3x48x1024", "11x32x1024"}
+    assert "memref<2x6x!tpu.dma_semaphore" in module
+    assert "dimension_semantics = [#tpu.dimension_semantics<arbitrary>]" \
+        in module
+    dmas = re.findall(r"tpu\.(enqueue_dma|wait_dma)", module)
+    started = 16 if sharded else 12
+    assert dmas == ["enqueue_dma"] * started + ["wait_dma"] * 6
+
+
 # the eight probes of the cell karman1024probes.sampled, (row, column)
 _PROBES = np.array([[512, 112], [512, 328], [512, 420], [512, 520],
                     [412, 520], [612, 520], [512, 720], [512, 920]])
@@ -542,7 +606,7 @@ def test_d2q9_band_1024_sampled_pairs_the_calls(one_chip):
     assert it.samples
     assert it.account(499) == dict(
         kernel_calls=499, remainder_steps=0, paired_calls=498, aux_planes=3,
-        bands=16, band_rows=64, halo_rows=8, pad_rows=0)
+        bands=16, band_rows=64, halo_rows=8, pad_rows=0, band_slots=2)
     text = _compile(it, lat, 499, one_chip)
     body, calls = _kernel_loop_body(text, "d2q9_band_fuse1")
     assert calls == 2
@@ -650,8 +714,9 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
     of the state in or out of the compiler's fast memory, inside the
     ``while``; the carry and the first call's result both live in that
     memory** (``S(1)``; the state goes in once before the loop and comes
-    out once after it): ``kernel2`` waits for its input copies, and on a
-    state in HBM it read 0.180 ns an update for 0.103 (chip, PR 47).
+    out once after it): the ``kernel2`` that waited for its input copies
+    read 0.180 ns an update on a state in HBM for 0.103 (chip, PR 47;
+    it prefetches its band since PR 50).
     With the one-step kernel in the same program the compiler keeps the
     first call's result in HBM: hence the two programs."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

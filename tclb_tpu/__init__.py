@@ -12,8 +12,19 @@ kernels with Tapenade (reference tools/makeAD), this framework uses `jax.grad`
 with checkpoint policies.
 """
 
+import time as _time
+
+#: the boot record's second stamp (``telemetry.events.boot``): this line
+T_PACKAGE = _time.time()
+
 __version__ = "0.2.0"
 
-from tclb_tpu.core.registry import ModelDef, Model  # noqa: F401
-from tclb_tpu.core.lattice import Lattice  # noqa: F401
-from tclb_tpu.models import get_model, list_models  # noqa: F401
+from tclb_tpu.telemetry import events as _events  # noqa: E402
+from tclb_tpu.telemetry.spans import import_span as _import_span  # noqa: E402
+
+with _import_span("tclb_tpu", boot=True):
+    from tclb_tpu.core.registry import ModelDef, Model  # noqa: F401
+    from tclb_tpu.core.lattice import Lattice  # noqa: F401
+    from tclb_tpu.models import get_model, list_models  # noqa: F401
+if _events.enabled():
+    _events._listen_for_compiles()     # jax is there now

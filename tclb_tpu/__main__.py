@@ -14,6 +14,19 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import time
+
+from tclb_tpu import telemetry
+
+
+def _backend_started():
+    """Whether the caller had a JAX backend running already (None where
+    this JAX does not say: it has no public name for the question)."""
+    try:
+        from jax._src import xla_bridge
+        return bool(xla_bridge.backends_are_initialized())
+    except (ImportError, AttributeError):
+        return None
 
 
 def run_case(args):
@@ -42,22 +55,31 @@ def run_case(args):
 
     import jax
     import jax.numpy as jnp
-    from tclb_tpu.control.solver import run_config
-    from tclb_tpu.models import get_model
+    with telemetry.import_span("tclb_tpu.control.solver"):
+        from tclb_tpu.control.solver import run_config
+    with telemetry.import_span("tclb_tpu.models." + model_name):
+        from tclb_tpu.models import get_model
+        model = get_model(model_name)
 
-    model = get_model(model_name)
-    mesh = None
-    if args.mesh:
-        import numpy as np
-        from jax.sharding import Mesh
-        axes = tuple(int(v) for v in args.mesh.split("x"))
-        names = ("y", "x") if model.ndim == 2 else ("z", "y", "x")
-        if len(axes) != len(names):
-            print(f"error: --mesh needs {len(names)} factors for a "
-                  f"{model.ndim}D model", file=sys.stderr)
-            raise SystemExit(2)
-        n = int(np.prod(axes))
-        mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(axes), names)
+    # the backend starts here, under its span, and not at the lattice's
+    # first array
+    with telemetry.span("startup.devices") as sp:
+        if telemetry.enabled():
+            sp.add(preloaded=_backend_started())
+        devices = jax.devices()
+        sp.add(count=len(devices), device_kind=devices[0].device_kind)
+        mesh = None
+        if args.mesh:
+            import numpy as np
+            from jax.sharding import Mesh
+            axes = tuple(int(v) for v in args.mesh.split("x"))
+            names = ("y", "x") if model.ndim == 2 else ("z", "y", "x")
+            if len(axes) != len(names):
+                print(f"error: --mesh needs {len(names)} factors for a "
+                      f"{model.ndim}D model", file=sys.stderr)
+                raise SystemExit(2)
+            n = int(np.prod(axes))
+            mesh = Mesh(np.asarray(devices[:n]).reshape(axes), names)
     dtype = {"f32": jnp.float32, "f64": jnp.float64}[args.precision]
     if dtype is jnp.float64:
         jax.config.update("jax_enable_x64", True)
@@ -179,10 +201,17 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    args = build_parser().parse_args(argv)
-    from tclb_tpu.compile_cache import place_compile_cache
-    place_compile_cache()
-    return args.fn(args)
+    telemetry.boot(time.time())
+    try:
+        # the subcommands' own modules, which the parser imports
+        with telemetry.import_span("tclb_tpu.__main__"):
+            parser = build_parser()
+        args = parser.parse_args(argv)
+        from tclb_tpu.compile_cache import place_compile_cache
+        place_compile_cache()
+        return args.fn(args)
+    finally:
+        telemetry.boot_over()
 
 
 if __name__ == "__main__":

@@ -28,6 +28,9 @@ class Handler:
     """Base scheduling unit (reference vHandler, src/Handlers.h:24-78)."""
 
     kind = "action"   # action | callback | container | design
+    # an element whose init holds a loop of segments (<Solve>, <Repeat>)
+    # gets no startup.element span of its own: its passes have theirs
+    holds_loop = False
     # handlers with mutable numeric run-state must either implement
     # restorable_state()/restore_state() or set this marker (enforced by
     # the hygiene.unrestorable_handler static check)
@@ -115,7 +118,9 @@ class GenericAction(Handler):
             h = get_handler(child, self.solver)
             if h is None:
                 continue
-            ret = h.init()
+            with (telemetry.NOOP_SPAN if h.holds_loop else
+                  telemetry.span("startup.element", element=child.tag)):
+                ret = h.init()
             if ret not in (0, None):
                 return ret
             # a pending resume state for this handler (parked by
@@ -160,6 +165,8 @@ class acSolve(GenericAction):
     """<Solve Iterations="N">: the main loop — event-driven batching of
     lattice iterations between due callbacks (reference acSolve,
     src/Handlers.cpp.Rt:1531-1570)."""
+
+    holds_loop = True
 
     def init(self) -> int:
         Handler.init(self)
@@ -224,6 +231,8 @@ class acRepeat(GenericAction):
     """<Repeat Times="N">: run children N times (reference acRepeat,
     src/Handlers.cpp.Rt:2191-2212)."""
 
+    holds_loop = True
+
     def init(self) -> int:
         Handler.init(self)
         times = int(self.node.get("Times", "1"))
@@ -244,6 +253,8 @@ class acGeometry(Handler):
         s = self.solver
         s.geometry.load(self.node)
         s.lattice.set_flags(s.geometry.result())
+        telemetry.annotate(nodes=int(np.prod(s.shape)),
+                           zones=len(s.geometry.setting_zones))
         if self.node.get("export") == "vti":
             s.write_geometry_vti()
         return 0

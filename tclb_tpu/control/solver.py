@@ -481,22 +481,25 @@ def _run_root(root: ET.Element, model: Model, mesh, dtype,
         # an explicit prefix (the CLI's --output) wins over the config's
         # own attribute, which <CLBConfig>'s handler would re-apply
         root.set("output", output)
-    solver = Solver(model, output=root.get("output", "output/"),
-                    mesh=mesh, dtype=dtype)
-    solver.conf_name = conf_name
-    solver.resume_from = resume
-    _read_units(root, solver)
-    geom = root.find("Geometry")
-    if geom is None:
-        raise ValueError("config must contain a <Geometry> element")
-    if model.ndim == 2:
-        shape = (int(round(solver.units.alt(geom.get("ny", "1")))),
-                 int(round(solver.units.alt(geom.get("nx", "1")))))
-    else:
-        shape = (int(round(solver.units.alt(geom.get("nz", "1")))),
-                 int(round(solver.units.alt(geom.get("ny", "1")))),
-                 int(round(solver.units.alt(geom.get("nx", "1")))))
-    solver.set_size(shape)
+    # the case before its elements: units, sizes, the lattice's arrays
+    with telemetry.span("startup.case", model=model.name) as sp:
+        solver = Solver(model, output=root.get("output", "output/"),
+                        mesh=mesh, dtype=dtype)
+        solver.conf_name = conf_name
+        solver.resume_from = resume
+        _read_units(root, solver)
+        geom = root.find("Geometry")
+        if geom is None:
+            raise ValueError("config must contain a <Geometry> element")
+        if model.ndim == 2:
+            shape = (int(round(solver.units.alt(geom.get("ny", "1")))),
+                     int(round(solver.units.alt(geom.get("nx", "1")))))
+        else:
+            shape = (int(round(solver.units.alt(geom.get("nz", "1")))),
+                     int(round(solver.units.alt(geom.get("ny", "1")))),
+                     int(round(solver.units.alt(geom.get("nx", "1")))))
+        solver.set_size(shape)
+        sp.add(shape=list(shape))
     with solver.output_drained("run_end"):
         MainContainer(root, solver).init()
     if solver.resume_from is not None:

@@ -1445,14 +1445,20 @@ class Lattice:
             self._fast_tried = True
             # the preferred engine is built now (iterate() reads what it
             # advertises), those under it when _probe_first_call gets there
-            self._fast_chain = chain = self._build_fast()
-            self._fast = chain[0].build() if chain else None
-            self._fast_name = chain[0].tag if chain else None
-            self._fast_probing = bool(chain) and chain[0].probe
-            full = self._fast is not None and self._fast.full_globals
-            self._tail, self._tail_name = (
-                self._build_tail() if self._fast is not None and not full
-                else (None, None))
+            with telemetry.span("engine.build") as sp:
+                self._fast_chain = chain = self._build_fast()
+                self._fast = chain[0].build() if chain else None
+                self._fast_name = chain[0].tag if chain else None
+                self._fast_probing = bool(chain) and chain[0].probe
+                full = self._fast is not None and self._fast.full_globals
+                self._tail, self._tail_name = (
+                    self._build_tail()
+                    if self._fast is not None and not full
+                    else (None, None))
+                sp.add(candidates=[c.tag for c in chain],
+                       selected=self._fast_name or "xla",
+                       tail=None if self._fast is None or full
+                       else self._tail_name or "xla")
             self._tail_probing = self._tail is not None
             self._sample_flags = None
             if self._fast is not None and self.sampler is not None:
@@ -1529,7 +1535,8 @@ class Lattice:
                 with telemetry.span("engine.probe",
                                     engine=self._fast_name) as probe:
                     tried: list = []
-                    done = self._probe_first_call(fast, niter, nfast, tried)
+                    done = self._probe_first_call(fast, niter, nfast, tried,
+                                                  probe)
                     telemetry.counter("engine.probe_attempts", len(tried))
                     probe.add(attempts=len(tried),
                               rungs=[cap for cap in tried if cap],
@@ -1575,17 +1582,17 @@ class Lattice:
         self._left_samples("rows", rows, rows[0])
         return state
 
-    def _left_samples(self, kind: str, what, stack) -> None:
+    def _left_samples(self, kind: str, what, stack,
+                      say: Callable = telemetry.annotate) -> None:
         """A call has left ``stack.shape[0]`` steps' samples on the
         device, ``stack`` (a sampled engine's taps, or the XLA scan's
-        rows): keep ``what`` for :meth:`_hand_samples`, and say so on the
-        innermost open span (``iterate.fused``, ``engine.probe`` or
-        ``iterate.globals_step``)."""
+        rows): keep ``what`` for :meth:`_hand_samples`, and ``say`` so:
+        on the innermost open span (``iterate.fused`` or
+        ``iterate.globals_step``), or where a probe tells it to."""
         self._sampled.append((kind, what, stack.shape[0]))
         telemetry.counter("sampler.rows", stack.shape[0])
-        telemetry.annotate(sample_points=len(self.sampler.points),
-                           sample_rows=stack.shape[0],
-                           sample_bytes=stack.nbytes)
+        say(sample_points=len(self.sampler.points),
+            sample_rows=stack.shape[0], sample_bytes=stack.nbytes)
 
     def _hand_samples(self) -> None:
         """Give the sampler what the calls of this ``iterate`` left, in
@@ -1608,22 +1615,24 @@ class Lattice:
             for samples, its in chunks:
                 self.sampler.append(its, samples)
 
-    def _run_engine(self, engine, state: LatticeState, niter: int
-                    ) -> LatticeState:
+    def _run_engine(self, engine, state: LatticeState, niter: int,
+                    say: Callable = telemetry.annotate) -> LatticeState:
         """The one place a fused engine is called (the fused call, the
         tail call and both probes), and the one that reports it: with
         telemetry on, its account of the call (``Engine.account``) as
         the counters ``engine.kernel_calls``, ``engine.resident_calls``
-        and ``engine.paired_calls`` and as fields of the innermost open
-        span (``iterate.fused``, ``engine.probe`` or
-        ``iterate.globals_step``), once the call has returned: a
-        candidate that fails its probe reports nothing.  A sampled
-        engine's call returns its taps beside the state; they are kept
-        for the sampler (:meth:`_left_samples`)."""
+        and ``engine.paired_calls`` and as fields ``say`` puts on a
+        span, once the call has returned: the innermost open one
+        (``iterate.fused`` or ``iterate.globals_step``), or the
+        ``engine.probe`` whose candidate this is (its
+        ``engine.probe.candidate`` is the innermost then, and times the
+        run only).  A candidate that fails its probe reports nothing.
+        A sampled engine's call returns its taps beside the state; they
+        are kept for the sampler (:meth:`_left_samples`)."""
         out = engine(state, self.params, niter)
         if engine.samples:
             out, taps = out
-            self._left_samples("taps", taps, taps)
+            self._left_samples("taps", taps, taps, say)
         if telemetry.enabled() and engine.account is not None:
             did = engine.account(niter, self.params.time_series is not None)
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
@@ -1631,7 +1640,7 @@ class Lattice:
                 telemetry.counter("engine.resident_calls",
                                   did["resident_calls"])
             telemetry.counter("engine.paired_calls", did["paired_calls"])
-            telemetry.annotate(**did, **(engine.vmem or {}))
+            say(**did, **(engine.vmem or {}))
         return out
 
     def _probe_tail(self) -> None:
@@ -1646,12 +1655,17 @@ class Lattice:
         tag = self._tail_name
         self._tail_probing = False
         with telemetry.span("engine.probe", engine=tag) as probe:
-            try:
-                # fenced inside the try: a failure at execution shows here
-                self.state = jax.block_until_ready(
-                    self._run_engine(self._tail, self.state, 1))
-            except Exception as e:  # noqa: BLE001
-                self._tail_failed(tag, e)
+            with telemetry.span("engine.probe.candidate", tag=tag,
+                                cap=0, result="ran") as run:
+                try:
+                    # fenced inside the try: a failure at execution
+                    # shows here
+                    self.state = jax.block_until_ready(self._run_engine(
+                        self._tail, self.state, 1, probe.add))
+                except Exception as e:  # noqa: BLE001
+                    run.add(result=type(e).__name__)
+                    self._tail_failed(tag, e)
+            if self._tail is None:
                 self.state = self._xla_steps(1)
             telemetry.counter("engine.probe_attempts")
             probe.add(attempts=1, rungs=[],
@@ -1659,7 +1673,7 @@ class Lattice:
             probe.sync(self.state)
 
     def _probe_first_call(self, fast, niter: int, nfast: int,
-                          tried: list) -> int:
+                          tried: list, probe) -> int:
         """The first call of an engine that has to be probed: run
         ``nfast`` fused steps, walking down the chain of
         :meth:`_build_fast` where an engine does not compile.  Returns
@@ -1667,31 +1681,42 @@ class Lattice:
         XLA ran the whole chunk (its last step has produced the globals).
         ``tried`` gains one entry per engine that was run: the band cap
         the candidate stands for (its rung of the ladder), 0 for an
-        engine without one."""
+        engine without one.  ``probe`` is the ``engine.probe`` span all
+        this runs under: the engine that ran puts its account there."""
         from tclb_tpu.ops import pallas_generic
         from tclb_tpu.utils import log
         chain, selected, cause = self._fast_chain, self._fast_name, None
         for n, cand in enumerate(chain):
             tried.append(cand.cap)
-            try:
-                it = fast if n == 0 else cand.build()
-                # probe on a COPY of the state: the engines donate their
-                # input, and a failure that happens at execution rather
-                # than compile would otherwise leave the real state's
-                # buffers deleted
-                state = (jax.tree.map(jnp.copy, self.state) if cand.probe
-                         else self.state)
-                self.state = self._run_engine(it, state, nfast)
-            except Exception as e:  # noqa: BLE001
-                if not cand.probe:
-                    # a proven engine on the real state is the end of
-                    # its chain: its exception is the run's
-                    raise
-                if cause is None:
-                    cause = e
-                log.warning(f"engine: {cand.tag} failed to compile "
-                            f"({e!r}); stepping down its chain")
-                continue
+            # one span a candidate run: which rung cost what
+            with telemetry.span("engine.probe.candidate", tag=cand.tag,
+                                cap=cand.cap, result="ran") as run:
+                try:
+                    # probe on a COPY of the state: the engines donate
+                    # their input, and a failure that happens at
+                    # execution rather than compile would otherwise
+                    # leave the real state's buffers deleted.  It comes
+                    # first, so that a traced run reads it off the
+                    # span's start (fenced there, as the engine's first
+                    # kernel would fence it anyway)
+                    state = self.state
+                    if cand.probe:
+                        state = run.sync(jax.tree.map(jnp.copy, state))
+                        run.mark("copy_s")
+                    it = fast if n == 0 else cand.build()
+                    self.state = self._run_engine(it, state, nfast,
+                                                  probe.add)
+                except Exception as e:  # noqa: BLE001
+                    run.add(result=type(e).__name__)
+                    if not cand.probe:
+                        # a proven engine on the real state is the end
+                        # of its chain: its exception is the run's
+                        raise
+                    if cause is None:
+                        cause = e
+                    log.warning(f"engine: {cand.tag} failed to compile "
+                                f"({e!r}); stepping down its chain")
+                    continue
             break
         else:
             if jax.default_backend() == "tpu":

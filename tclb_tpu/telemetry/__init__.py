@@ -24,8 +24,9 @@ Usage (a trace costs nothing unless asked for):
 """
 
 from tclb_tpu.telemetry.events import (  # noqa: F401
-    counter, counters, current_job, disable, enable, enabled,
-    engine_fallback, engine_selected, event, failcheck, job_context,
-    path, set_job, subscribe, unsubscribe)
+    boot, boot_over, counter, counters, current_job, disable, enable,
+    enabled, engine_fallback, engine_selected, event, failcheck,
+    job_context, path, set_job, subscribe, unsubscribe)
 from tclb_tpu.telemetry.spans import (  # noqa: F401
-    NOOP_SPAN, Span, annotate, fuse_of, off_launch_thread, span)
+    NOOP_SPAN, Span, annotate, fuse_of, import_span, off_launch_thread,
+    span)

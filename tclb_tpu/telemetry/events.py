@@ -30,7 +30,13 @@ Design constraints:
 * **counters survive abnormal exits** — cumulative ``counters`` snapshots
   are emitted every ``COUNTER_SNAPSHOT_S`` seconds (piggybacked on event
   traffic), so a SIGKILLed run's trace still carries counter totals; the
-  final flush on :func:`disable` remains authoritative.
+  final flush on :func:`disable` remains authoritative;
+* **one process's boot is kept** — what happens before any sink can
+  exist (the package's ``startup.import`` span, the ``boot`` record of a
+  ``main`` entered with telemetry off) waits in a fixed, small backlog
+  and is handed out once: to the subscribers there when ``main`` is
+  entered, else to the first one that arrives while it runs
+  (:func:`boot`).  Nothing else is recorded while no sink is subscribed.
 """
 
 from __future__ import annotations
@@ -38,6 +44,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Iterator, Optional, TextIO
@@ -63,6 +70,12 @@ _counters_last_emit = 0.0           # monotonic ts of the last snapshot
 _atexit_registered = False
 _job_local = threading.local()      # per-thread active job id (correlation)
 _span_local = threading.local()     # per-thread stack of open spans
+_compile_local = threading.local()  # per-thread cache verdict of a compile
+
+#: the most the backlog of the process's boot holds (:func:`boot_event`)
+BACKLOG_MAX = 16
+_backlog: Optional[list] = []       # None once handed out or main is over
+_in_main = False                    # between boot() and boot_over()
 
 
 def enabled() -> bool:
@@ -110,6 +123,8 @@ def subscribe(fn: Callable[[dict], None]) -> None:
             _subscribers.append(fn)
         _listen_for_compiles()
         _enabled = True
+        if _in_main:
+            _hand_out_backlog_locked()
 
 
 def unsubscribe(fn: Callable[[dict], None]) -> None:
@@ -252,13 +267,91 @@ def _maybe_snapshot_counters_locked() -> bool:
     return True
 
 
+# -- the process's boot ------------------------------------------------------- #
+# The span tree starts where the process does.  Three stamps on the clock
+# of ``ts`` and ``t0`` are taken whether telemetry is on or not: the
+# process's start, the first line of ``tclb_tpu/__init__.py`` and the
+# entry of ``__main__.main``.  They go out as one ``boot`` event.  What
+# closes before a sink can exist waits in ``_backlog``.
+
+
+def process_start() -> Optional[float]:
+    """When the kernel started this process, on ``time.time()``'s clock:
+    its start in ticks since the machine's boot (field 22 of
+    ``/proc/self/stat``) against the machine's uptime now.  None where
+    ``/proc`` cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up_s = float(f.read().split()[0])
+        return time.time() - up_s + ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def boot_event(kind: str, **fields: Any) -> None:
+    """An event of the process's boot: emitted like any other where a
+    sink is subscribed, kept for :func:`boot` to hand out otherwise
+    (up to ``BACKLOG_MAX`` of them, while the backlog is open)."""
+    if _enabled:
+        event(kind, **fields)
+    elif _backlog is not None and len(_backlog) < BACKLOG_MAX:
+        _backlog.append({"kind": kind, "ts": round(time.time(), 6),
+                         **fields})
+
+
+def _hand_out_backlog_locked() -> None:
+    global _backlog
+    kept, _backlog = _backlog, None
+    for doc in kept or ():
+        _fanout_locked(doc)
+    if kept and _sink is not None:
+        _sink.flush()
+
+
+def boot(t_main: float) -> None:
+    """``__main__.main`` has been entered, at ``t_main``: the ``boot``
+    event (``t_process``, ``t_package``, ``t_main``; ``process_from``
+    says ``package`` where the process's start could not be read and
+    the package's first line stands in for it), behind whatever the
+    backlog holds.  With no sink yet it joins the backlog, and the
+    first to subscribe before :func:`boot_over` receives both."""
+    global _in_main
+    import tclb_tpu
+    t_package = tclb_tpu.T_PACKAGE
+    t_process = process_start()
+    with _lock:
+        _in_main = True
+        if _enabled:
+            _hand_out_backlog_locked()
+    boot_event("boot", t_main=round(t_main, 6),
+               t_package=round(t_package, 6),
+               t_process=round(t_process or t_package, 6),
+               process_from="proc" if t_process else "package")
+
+
+def boot_over() -> None:
+    """``main`` returns: a boot nobody asked about is forgotten, and a
+    later ``main`` of this process finds no backlog."""
+    global _backlog, _in_main
+    with _lock:
+        _backlog, _in_main = None, False
+
+
 # -- compilations ------------------------------------------------------------- #
 # jax.monitoring hands out every trace, lowering, backend compile and
 # persistent-cache load of the process with the function's name.  The
 # listeners are registered once, when the first subscriber arrives, and
 # gate on the same boolean as everything else here.  On jax 0.9.0 the
 # backend_compile duration wraps the persistent-cache lookup, so a load
-# shows as a short backend_compile with a cache_load beside it.
+# shows as a short backend_compile with a cache_load beside it.  The
+# cache's own events arrive on the compiling thread before that
+# duration: a lookup (``miss`` unless a hit follows) and the hit.  Each
+# ``compile`` event carries the verdict as ``cache``: ``hit`` (loaded),
+# ``miss`` (looked up, not there, compiled; JAX keeps it only if it took
+# a second or more) or ``off`` (no lookup: no cache directory, or a
+# stage no cache serves: ``trace``, ``lower``).
 
 _COMPILE_STAGES = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
@@ -266,9 +359,9 @@ _COMPILE_STAGES = {
     "/jax/core/compile/backend_compile_duration": "backend_compile",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
 }
-_CACHE_COUNTERS = {
-    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
-    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
 }
 _compile_listening = False
 
@@ -277,22 +370,40 @@ def _on_compile_duration(name: str, dur_s: float, **kw: Any) -> None:
     if not _enabled:
         return
     stage = _COMPILE_STAGES.get(name)
-    if stage is not None:
-        event("compile", stage=stage, fun_name=kw.get("fun_name"),
-              dur_s=round(dur_s, 6))
+    if stage is None:
+        return
+    mine = _compile_local
+    if stage == "cache_load":
+        # the load has no name of its own: it goes out with the
+        # backend_compile it lies in, which says whose it is
+        mine.load_s = dur_s
+        return
+    program, cache = kw.get("fun_name"), "off"
+    if stage == "backend_compile":
+        cache = getattr(mine, "cache", "off")
+        load_s = getattr(mine, "load_s", None)
+        mine.cache, mine.load_s = "off", None
+        if load_s is not None:
+            event("compile", stage="cache_load", program=program,
+                  cache=cache, dur_s=round(load_s, 6))
+    event("compile", stage=stage, program=program, cache=cache,
+          dur_s=round(dur_s, 6))
 
 
 def _on_compile_event(name: str, **kw: Any) -> None:
     if not _enabled:
         return
-    key = _CACHE_COUNTERS.get(name)
-    if key is not None:
-        counter(key)
+    verdict = _CACHE_VERDICTS.get(name)
+    if verdict is not None:
+        _compile_local.cache = verdict
 
 
 def _listen_for_compiles() -> None:
+    """Called for every new subscriber, and by the package once its
+    imports are through: telemetry imports no jax of its own, so a sink
+    that ``TCLB_TELEMETRY`` opens finds it not there yet."""
     global _compile_listening
-    if _compile_listening:
+    if _compile_listening or "jax" not in sys.modules:
         return
     from jax import monitoring
     monitoring.register_event_duration_secs_listener(_on_compile_duration)

@@ -13,6 +13,12 @@ BENCH/ROADMAP triage loop needs:
 * **segments** — the ``segment`` spans of ``<Solve>``'s loop by the
   handlers that ran in them: the span, what its fences waited, the
   host's own time and where it went by span name;
+* **set-up** — from the process's start to the first segment: the
+  ``boot`` record, the ``startup.*`` spans (imports, devices, case,
+  elements), ``engine.build``, the ``engine.probe.candidate`` runs, the
+  ``compile`` events by program with their cache verdict and the span
+  they fell under, and the seconds from ``main``'s entry to the first
+  segment that no span owns;
 * **dispatch history** — ``engine_selected`` decisions and the
   ``engine_fallback`` chain with each fallback's exception cause (the
   information the old free-form log strings swallowed);
@@ -345,6 +351,116 @@ def _segments_summary(evts: list[dict]) -> dict:
     return out
 
 
+#: roots of other threads' span trees (a span event carries no thread)
+OTHER_THREAD_ROOTS = ("output.vtk.write",)
+
+
+def _union_s(intervals: list, lo: float, hi: float) -> float:
+    """Seconds of ``[lo, hi]`` that the ``(start, end)`` intervals cover."""
+    total, end = 0.0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, end), min(b, hi)
+        if b > a:
+            total += b - a
+            end = b
+    return total
+
+
+def _setup_summary(evts: list[dict]) -> dict:
+    """The set-up tree of the file's first process, the reader of every
+    span and field that covers the time before ``<Solve>``'s loop:
+
+    * ``boot``: the three stamps as two intervals, ``process_to_package_s``
+      and ``package_to_main_s``, and ``process_from``;
+    * ``imports``: the ``startup.import`` spans (``module``,
+      ``preloaded``); ``devices``: ``startup.devices``; ``case``:
+      ``startup.case``; ``elements``: the ``startup.element`` spans in
+      the order they started, with their ``depth`` under each other;
+    * ``engine_build``: the ``engine.build`` spans; ``candidates``: the
+      ``engine.probe.candidate`` runs (``tag``, ``cap``, ``result``,
+      ``copy_s``);
+    * ``compiles``: the ``compile`` events by ``program``, ``stage``,
+      ``cache`` and the name of the span they fell ``under``, with their
+      count and seconds, the longest first;
+    * ``entry_to_segment_s``, from ``main``'s entry to the start of the
+      first ``segment`` span, and ``unowned_s``, the part of it under no
+      span without ``parent`` (the roots of other threads left out).
+
+    Empty where the trace holds none of these."""
+    # span ids restart with every process: the first session only
+    starts = [i for i, e in enumerate(evts)
+              if e.get("kind") == "trace_start"]
+    first = evts[:starts[1]] if len(starts) > 1 else evts
+    spans = [e for e in first if e.get("kind") == "span" and "id" in e]
+    by_id = {e["id"]: e for e in spans}
+
+    def start(e: dict) -> float:
+        return e.get("t0", e["ts"] - e["dur_s"])
+
+    def named(name: str) -> list:
+        return sorted((e for e in spans if e["name"] == name), key=start)
+
+    def row(e: dict, *keys: str) -> dict:
+        return {"seconds": e["dur_s"],
+                **{k: e[k] for k in keys if k in e}}
+
+    out: dict = {}
+    boot = next((e for e in first if e.get("kind") == "boot"), None)
+    if boot is not None:
+        out["boot"] = {
+            "process_to_package_s": round(
+                boot["t_package"] - boot["t_process"], 6),
+            "package_to_main_s": round(
+                boot["t_main"] - boot["t_package"], 6),
+            "process_from": boot.get("process_from")}
+    for key, name, fields in (
+            ("imports", "startup.import", ("module", "preloaded")),
+            ("devices", "startup.devices",
+             ("count", "device_kind", "preloaded")),
+            ("case", "startup.case", ("model", "shape")),
+            ("engine_build", "engine.build",
+             ("candidates", "selected", "tail")),
+            ("candidates", "engine.probe.candidate",
+             ("tag", "cap", "result", "copy_s"))):
+        rows = [row(e, *fields) for e in named(name)]
+        if rows:
+            out[key] = rows
+    elements = []
+    for e in named("startup.element"):
+        depth, up = 0, by_id.get(e.get("parent"))
+        while up is not None and up["name"] == "startup.element":
+            depth, up = depth + 1, by_id.get(up.get("parent"))
+        elements.append({**row(e, "element", "nodes", "zones"),
+                         "depth": depth})
+    if elements:
+        out["elements"] = elements
+    groups: dict = {}
+    for e in first:
+        if e.get("kind") != "compile":
+            continue
+        under = by_id.get(e.get("parent"))
+        key = (e.get("program", e.get("fun_name")), e.get("stage"),
+               e.get("cache"), under["name"] if under else None)
+        g = groups.setdefault(key, {"count": 0, "seconds": 0.0})
+        g["count"] += 1
+        g["seconds"] += float(e.get("dur_s", 0.0))
+    if groups:
+        out["compiles"] = sorted(
+            ({"program": p, "stage": st, "cache": c, "under": u,
+              "count": g["count"], "seconds": round(g["seconds"], 6)}
+             for (p, st, c, u), g in groups.items()),
+            key=lambda r: -r["seconds"])
+    segments = named("segment")
+    if boot is not None and segments:
+        lo, hi = boot["t_main"], start(segments[0])
+        roots = [(start(e), start(e) + e["dur_s"]) for e in spans
+                 if e.get("parent") is None
+                 and e["name"] not in OTHER_THREAD_ROOTS]
+        out["entry_to_segment_s"] = round(hi - lo, 6)
+        out["unowned_s"] = round(hi - lo - _union_s(roots, lo, hi), 6)
+    return out
+
+
 def summarize(evts: list[dict]) -> dict:
     """Aggregate one trace into the report structure (all plain dicts,
     JSON-serializable as-is)."""
@@ -430,6 +546,7 @@ def summarize(evts: list[dict]) -> dict:
         del g["node_updates"]
     return {"engines": engines, "globals_steps": tails, "spans": spans,
             "segments": _segments_summary(evts),
+            "setup": _setup_summary(evts),
             "serving": _serving_summary(evts),
             "adjoint": _adjoint_summary(evts),
             "fleet": _fleet_summary(evts),
@@ -745,6 +862,63 @@ def _fmt(v, nd=2) -> str:
     return str(v)
 
 
+def _format_setup(su: dict) -> list:
+    lines = ["set-up (seconds; from the process's start to the first "
+             "segment)"]
+    if "boot" in su:
+        b = su["boot"]
+        lines.append(
+            f"  boot: process to package "
+            f"{_fmt(b['process_to_package_s'], 3)}, package to main "
+            f"{_fmt(b['package_to_main_s'], 3)} (process start from "
+            f"{b['process_from']})")
+    for r in su.get("imports", []):
+        lines.append(f"  import   {str(r.get('module')):<44} "
+                     f"{_fmt(r['seconds'], 3):>9}  "
+                     f"jax preloaded: {r.get('preloaded')}")
+    for r in su.get("devices", []):
+        lines.append(f"  devices  {r.get('count')} x "
+                     f"{str(r.get('device_kind')):<39} "
+                     f"{_fmt(r['seconds'], 3):>9}  "
+                     f"backend preloaded: {r.get('preloaded')}")
+    for r in su.get("case", []):
+        lines.append(f"  case     {str(r.get('model')) + ' ' + str(r.get('shape')):<44} "
+                     f"{_fmt(r['seconds'], 3):>9}")
+    for r in su.get("elements", []):
+        name = "  " * r["depth"] + str(r.get("element"))
+        extra = (f"  nodes {r['nodes']} zones {r.get('zones')}"
+                 if "nodes" in r else "")
+        lines.append(f"  element  {name:<44} "
+                     f"{_fmt(r['seconds'], 3):>9}{extra}")
+    for r in su.get("engine_build", []):
+        lines.append(f"  build    {str(r.get('selected')):<44} "
+                     f"{_fmt(r['seconds'], 3):>9}  "
+                     f"of {r.get('candidates')}; tail {r.get('tail')}")
+    for r in su.get("candidates", []):
+        copy = (f"  copy {_fmt(r['copy_s'], 3)}" if "copy_s" in r else "")
+        lines.append(f"  probe    {str(r.get('tag')):<44} "
+                     f"{_fmt(r['seconds'], 3):>9}  cap {r.get('cap')} "
+                     f"{r.get('result')}{copy}")
+    if "compiles" in su:
+        lines.append(f"  {'compiles by program':<34} {'stage':<16} "
+                     f"{'cache':<5} {'count':>5} {'seconds':>9}  under")
+        for r in su["compiles"][:24]:
+            lines.append(f"  {str(r['program'])[:34]:<34} "
+                         f"{str(r['stage']):<16} {str(r['cache']):<5} "
+                         f"{r['count']:>5} {_fmt(r['seconds'], 3):>9}  "
+                         f"{r['under']}")
+        if len(su["compiles"]) > 24:
+            rest = su["compiles"][24:]
+            lines.append(f"  ... and {len(rest)} more rows, "
+                         f"{_fmt(sum(r['seconds'] for r in rest), 3)} s")
+    if "unowned_s" in su:
+        lines.append(
+            f"  main's entry to the first segment "
+            f"{_fmt(su['entry_to_segment_s'], 3)}, under no span "
+            f"{_fmt(su['unowned_s'], 3)}")
+    return lines
+
+
 def format_text(summary: dict) -> str:
     lines = []
     if summary["engines"]:
@@ -795,6 +969,9 @@ def format_text(summary: dict) -> str:
             lines.append("      " + "  ".join(
                 f"{n} {_fmt(v, 3)}" for n, v in sorted(
                     g["self_ms"].items(), key=lambda kv: -kv[1])))
+        lines.append("")
+    if summary.get("setup"):
+        lines += _format_setup(summary["setup"])
         lines.append("")
     if summary.get("serving"):
         sv = summary["serving"]

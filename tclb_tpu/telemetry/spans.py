@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import itertools
 import re
+import sys
 import time
 from typing import Any, Callable, Optional
 
@@ -69,11 +70,12 @@ class Span:
     returns the shared no-op otherwise), so it may import jax freely."""
 
     __slots__ = ("name", "fields", "id", "parent", "t0", "_t0",
-                 "_wait", "_annotation")
+                 "_wait", "_annotation", "_emit")
 
     def __init__(self, name: str, fields: dict):
         self.name = name
         self.fields = fields
+        self._emit = events.event
         self.id = self.parent = None
         self.t0 = self._t0 = 0.0
         self._wait: Optional[float] = None      # seconds its fences blocked
@@ -124,13 +126,16 @@ class Span:
                 if key not in self.fields and key in outer.fields:
                     self.fields[key] = outer.fields[key]
         stack.append(self)
-        try:
-            from jax.profiler import TraceAnnotation
-            self._annotation = TraceAnnotation(
-                getattr(events._span_local, "prefix", "") + self.name)
-            self._annotation.__enter__()
-        except Exception:  # noqa: BLE001 — profiler is optional garnish
-            self._annotation = None
+        # a span imports no jax of its own: where nothing has yet (the
+        # package's import block), no profiler is there to listen
+        if "jax" in sys.modules:
+            try:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation(
+                    getattr(events._span_local, "prefix", "") + self.name)
+                self._annotation.__enter__()
+            except Exception:  # noqa: BLE001 — profiler is optional garnish
+                self._annotation = None
         self.t0 = time.time()
         self._t0 = time.perf_counter()
         return self
@@ -157,8 +162,8 @@ class Span:
             fields["mlups"] = float(f"{mlups:.6g}")
         if self._wait is not None:
             fields["wait_s"] = round(self._wait, 6)
-        events.event("span", name=self.name, id=self.id, parent=self.parent,
-                     t0=round(self.t0, 6), dur_s=round(dt, 6), **fields)
+        self._emit("span", name=self.name, id=self.id, parent=self.parent,
+                   t0=round(self.t0, 6), dur_s=round(dt, 6), **fields)
         return False
 
 
@@ -200,6 +205,22 @@ def span(name: str, **fields: Any):
     if not events.enabled():
         return NOOP_SPAN
     return Span(name, fields)
+
+
+def import_span(module: str, boot: bool = False):
+    """A ``startup.import`` span over one of the program's own import
+    blocks: ``module`` names it, ``preloaded`` says whether ``jax`` was
+    in ``sys.modules`` already (as under a caller that imported it
+    first).  ``boot``: a block that runs before any sink can exist (the
+    package's own): timed whether telemetry is on or not, its event kept
+    for the first sink (:func:`events.boot_event`)."""
+    if not (boot or events.enabled()):
+        return NOOP_SPAN
+    sp = Span("startup.import",
+              {"module": module, "preloaded": "jax" in sys.modules})
+    if boot:
+        sp._emit = events.boot_event
+    return sp
 
 
 def off_launch_thread(name: str) -> None:

@@ -283,6 +283,42 @@ def test_forced_fallback_emits_events(tmp_path, monkeypatch, chain):
     assert int(lat.state.iteration) == it0 + 2 * niter
 
 
+def test_a_probe_has_one_candidate_span_a_run(monkeypatch):
+    """The chain's first engine fails its probe: two candidate runs
+    under the one ``engine.probe``, each with its tag and cap, the first
+    with the exception's class as ``result``; the account of the engine
+    that ran stays on the probe."""
+    monkeypatch.setenv("TCLB_FASTPATH", "force")
+    lat, selected, under = _chain_resident(monkeypatch)
+    docs = []
+    telemetry.subscribe(docs.append)
+    try:
+        lat.iterate(5)
+    finally:
+        telemetry.unsubscribe(docs.append)
+    spans = [e for e in docs if e["kind"] == "span"]
+    probe, = [e for e in spans if e["name"] == "engine.probe"
+              and e["engine"] == selected]
+    runs = [e for e in spans if e["name"] == "engine.probe.candidate"
+            and e["parent"] == probe["id"]]
+    assert [(e["tag"], e["cap"], e["result"]) for e in runs] == [
+        (selected, 0, "RuntimeError"), (under, 0, "ran")]
+    assert probe["attempts"] == len(runs) and probe["result"] == under
+    # the probed one ran on a copy of the state, made and fenced first;
+    # the proven band under it on the state itself
+    assert 0 <= runs[0]["copy_s"] <= runs[0]["dur_s"]
+    assert "wait_s" in runs[0] and "copy_s" not in runs[1]
+    assert "kernel_calls" in probe
+    assert not any("kernel_calls" in e for e in runs)
+    # the tail engine's probe is one candidate too, with no copy
+    tail, = [e for e in spans if e["name"] == "engine.probe"
+             and e["engine"] != selected]
+    mine, = [e for e in spans if e["name"] == "engine.probe.candidate"
+             and e["parent"] == tail["id"]]
+    assert (mine["tag"], mine["result"]) == (tail["engine"], "ran")
+    assert "copy_s" not in mine
+
+
 @pytest.mark.parametrize("name,shape,cached", [
     ("d2q9_kuper", (32, 128), None),
     ("d3q19_heat", (16, 16, 128), None),
@@ -664,10 +700,12 @@ def test_solve_emits_phase_spans_with_the_right_parents(
     def parent_of(e):
         return by_id[e["parent"]] if e["parent"] else None
 
-    for it in _spans(seen, "iterate"):
+    for n, it in enumerate(_spans(seen, "iterate")):
         kids = [e for e in spans if e["parent"] == it["id"]]
-        assert [e["name"] for e in kids] == ["iterate.fused",
-                                             "iterate.globals_step"]
+        # the first call builds the engine, ahead of its programs
+        assert [e["name"] for e in kids] == ["engine.build"] * (n == 0) + [
+            "iterate.fused", "iterate.globals_step"]
+        kids = kids[-2:]
         fused, step = kids
         assert fused["iters"] == 2 and step["iters"] == 1
         # a domain this small gets the resident engine, probed on its
@@ -808,17 +846,21 @@ def test_segment_is_the_root_of_its_pass(solved):
     # every span of the solve's own thread hangs from a segment; the
     # other roots are the <VTK> write on the writer's thread and <Solve>
     # waiting for it on its way out
+    # (and, ahead of the loop, the case and its elements)
     assert [e["name"] for e in spans if e["parent"] is None
-            and e["name"] != "segment"] == ["output.vtk.write",
-                                            "output.vtk.drain"]
+            and e["name"] not in ("segment", "startup.case",
+                                  "startup.element")] == [
+        "output.vtk.write", "output.vtk.drain"]
 
 
 def test_a_fence_says_what_it_waited_and_a_launch_what_it_cost(solved):
     spans = _spans(solved[0])
     assert {e["name"] for e in spans} >= _FENCED | {"segment", "handler"}
     for e in spans:
-        # wait_s exactly on the spans that fenced
-        assert ("wait_s" in e) == (e["name"] in _FENCED), e["name"]
+        # wait_s exactly on the spans that fenced (a probe's candidate
+        # fences the copy of the state it runs on, where it makes one)
+        assert ("wait_s" in e) == (e["name"] in _FENCED
+                                   or "copy_s" in e), e["name"]
         wait = e.get("wait_s", 0.0)
         assert 0 <= wait <= e["dur_s"] + 2e-6
         # host time: what neither a child nor a fence covers
@@ -989,10 +1031,16 @@ def test_compile_events_name_the_function_and_the_span(seen):
     with telemetry.span("first_call") as sp:
         fresh_function_of_this_test(x).block_until_ready()
     mine = [e for e in seen if e["kind"] == "compile"
-            and "fresh_function_of_this_test" in (e["fun_name"] or "")]
+            and "fresh_function_of_this_test" in (e["program"] or "")]
     assert {"trace", "lower", "backend_compile"} <= {e["stage"]
                                                      for e in mine}
     assert all(e["parent"] == sp.id and e["dur_s"] >= 0 for e in mine)
+    # every one says where it came from; no cache serves a trace or a
+    # lowering
+    assert all(e["cache"] in ("hit", "miss", "off")
+               for e in seen if e["kind"] == "compile")
+    assert {e["cache"] for e in mine
+            if e["stage"] in ("trace", "lower")} == {"off"}
     n = len([e for e in seen if e["kind"] == "compile"])
     fresh_function_of_this_test(x).block_until_ready()
     assert len([e for e in seen if e["kind"] == "compile"]) == n

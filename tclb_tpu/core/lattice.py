@@ -1391,41 +1391,72 @@ class Lattice:
         """The engine of the one step a hybrid engine (one that does not
         say ``full_globals``) leaves for the Globals, and its tag: the
         generic Pallas engine's one-step flavour, which reduces them in
-        the kernel, wherever it takes the case; ``(None, None)``, the XLA
-        step, on a mesh (the sharded engines keep their own step), where
-        ``pallas_generic`` refuses the model, the shape or the storage
-        dtype, where its kernel reduces no Globals, and where its band
-        stands on ghost rows (no multiple of its rows, as the 100 rows
-        of ``karman.xml``): the call then lies between an XLA pad and a
-        slice of the whole state, and beside a resident engine it is one
-        more band call among the ``n % 8`` steps that engine accounts
-        for.  The same for every model and dimension: it is one
-        algorithm, one step that reduces Globals.  A ``<Control>`` series
-        never gets here (it keeps the tuned engines out of the chain).
-        Its first call is probed (:meth:`_probe_tail`): nothing has shown
-        yet that it compiles."""
+        the kernel, wherever it takes the case: on the lattice, or on a
+        mesh on each shard with the partial sums reduced across it
+        (:meth:`_sharded_tail_cand`).  ``(None, None)``, the XLA step,
+        where ``pallas_generic`` refuses the model, the shape (on a mesh:
+        a shard's) or the storage dtype, where its kernel reduces no
+        Globals, where its band stands on ghost rows (no multiple of its
+        rows, as the 100 rows of ``karman.xml``): the call then lies
+        between an XLA pad and a slice of the whole state, and beside a
+        resident engine it is one more band call among the ``n % 8``
+        steps that engine accounts for; and on a mesh the sharded tail
+        cannot take: a 3D one, one split in x, shards of no multiple of
+        8 rows.  The same for every model and dimension: it is one
+        algorithm, one step that reduces Globals.  Off a mesh a
+        ``<Control>`` series never gets here (it keeps the tuned engines
+        out of the chain); on one it keeps the XLA engine for every
+        step.  Its first call is probed (:meth:`_probe_tail`): nothing
+        has shown yet that it compiles."""
         from tclb_tpu import analysis
         from tclb_tpu.ops import pallas_generic
-        model, shape = self.model, self.shape
-        # supports() without its abstract trace of the kernel (seconds
-        # of every run's set-up): the probed first call is that trace,
-        # and a model whose kernel does not trace steps down there
-        if self.mesh is not None or not (
-                analysis.kernel_safety_ok(model)
-                and pallas_generic.mosaic_ok(model, shape)
-                and pallas_generic.supports(model, shape,
-                                            self.storage_dtype,
-                                            probe=False)):
+        model = self.model
+        if not analysis.kernel_safety_ok(model):
             return None, None
-        cand = self._generic_cand(self._present_types(), 1)
+        if self.mesh is not None:
+            cand = self._sharded_tail_cand()
+        elif (pallas_generic.mosaic_ok(model, self.shape)
+              # supports() without its abstract trace of the kernel
+              # (seconds of every run's set-up): the probed first call
+              # is that trace, and a model whose kernel does not trace
+              # steps down there
+              and pallas_generic.supports(model, self.shape,
+                                          self.storage_dtype, probe=False)):
+            cand = self._generic_cand(self._present_types(), 1)
+        else:
+            cand = None
+        if cand is None:
+            return None, None
         try:
             it = cand.build()
         except Exception as e:  # noqa: BLE001
             self._tail_failed(cand.tag, e)
             return None, None
-        if it.full_globals and not it.pad_rows:
+        if it is not None and it.full_globals and not it.pad_rows:
             return it, cand.tag
         return None, None
+
+    def _sharded_tail_cand(self) -> Optional[EngineCandidate]:
+        """The tail engine on a mesh as a candidate, whose build is None
+        where the builder refuses the mesh, the model or the shard's
+        shape (``parallel/halo.make_sharded_pallas_tail``: building it IS
+        asking); None under a ``<Control>`` series (the sharded engines
+        take none, and the XLA engine runs every step) and where an
+        earlier probe of the model at the shard's shape found nothing to
+        compile."""
+        from tclb_tpu.ops import pallas_generic
+        from tclb_tpu.parallel.halo import (band_shards,
+                                            make_sharded_pallas_tail)
+        model, mesh, shape = self.model, self.mesh, self.shape
+        shards = band_shards(model, mesh, shape)
+        if (self.params.time_series is not None or shards is None
+                or not pallas_generic.mosaic_ok(model, shards[2])):
+            return None
+        return EngineCandidate(
+            f"pallas_sharded[generic,{dict(mesh.shape)},fuse=1,globals]",
+            lambda: make_sharded_pallas_tail(
+                model, mesh, shape, self.storage_dtype,
+                present=self._present_types()))
 
     def _tail_failed(self, tag: str, e: Exception) -> None:
         """The tail engine cannot be built, compiled or run: the XLA
@@ -1516,7 +1547,8 @@ class Lattice:
         # src/cuda.cu.Rt:176-202) — no trailing step; the hybrid
         # engines run niter-1 fused steps + one step on the tail engine
         # (_build_tail: the generic Pallas kernel's in-kernel-globals
-        # flavour where it takes the case, else the XLA step) instead.
+        # flavour where it takes the case, on a y-split 2D mesh on each
+        # shard under shard_map with a psum, else the XLA step) instead.
         # Engines advertising supports_series gather Control time series
         # per iteration themselves; others fall back to XLA for those.
         full = fast is not None and fast.full_globals
@@ -1647,8 +1679,9 @@ class Lattice:
         """The first step of the tail engine (:meth:`_build_tail`), on
         the state itself: its program of one kernel call does not donate
         (``pallas_generic._donating_unless_one_call``, beside the generic
-        engines' one schedule, ``_scheduled_engine``), so a failure
-        leaves the state whole.  Where it does not compile, or fails as
+        engines' one schedule, ``_scheduled_engine``; on a mesh
+        ``parallel/halo.make_sharded_pallas_tail``'s one program), so a
+        failure leaves the state whole.  Where it does not compile, or fails as
         it runs, the XLA step takes over with one ``engine_fallback``
         event and the run goes on: unlike a fused engine's steps, this
         step in XLA is what every run paid before."""

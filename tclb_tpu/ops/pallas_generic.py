@@ -70,6 +70,12 @@ STORAGE_DTYPES = (jnp.float32, jnp.bfloat16)
 _COMPUTE_DTYPE = jnp.float32
 
 
+def _lane_sum(model: Model, gpart: jnp.ndarray) -> jnp.ndarray:
+    """The model's Globals from a ``with_globals`` call's (8, 128) block
+    of partial sums: a row a Global, summed over its lanes."""
+    return gpart[:model.n_globals].sum(axis=1)
+
+
 def _donating_unless_one_call(schedule: Callable) -> Callable:
     """``schedule(state, params, niter)`` compiled twice, and
     ``program(calls, donate=True)``, which picks the one to run from the
@@ -86,6 +92,15 @@ def _donating_unless_one_call(schedule: Callable) -> Callable:
     donating, once = jit(donate_argnums=0), jit()
     return lambda calls, donate=True: (
         donating if donate and calls != 1 else once)
+
+
+def kernel_reduces_globals(model: Model, nx: int) -> bool:
+    """Whether the kernels have a flavour that reduces the model's
+    Globals (``with_globals``): SUM only — MAX would need max-combining
+    across bands/stages (no model uses MAX) — into the (8, 128) block of
+    partial sums, a row a Global, over rows of whole lane tiles."""
+    return (0 < model.n_globals <= 8 and nx % 128 == 0
+            and all(g.op == "SUM" for g in model.globals_))
 
 
 def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
@@ -127,11 +142,8 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
     lean_aux = len(zonal_names) > 0
     call = mk_call(lean=lean_aux)
     call1 = call if fuse == 1 else mk_call1(lean=lean_aux)
-    # in-kernel globals flavor (final step of an iterate call): SUM only —
-    # MAX would need max-combining across bands/stages (no model uses MAX)
-    can_globals = (0 < model.n_globals <= 8   # the (8, 128) partials block
-                   and nx % 128 == 0
-                   and all(g.op == "SUM" for g in model.globals_))
+    # in-kernel globals flavor (final step of an iterate call)
+    can_globals = kernel_reduces_globals(model, nx)
     call_g = mk_call1(with_globals=True, lean=lean_aux) \
         if can_globals else None
     # Control-series flavors: per-iteration zonal + _DT planes, fuse=1
@@ -244,8 +256,7 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
             fields, gpart = invoke(call_sg if has_series else call_g,
                                    it, fields)
             it = it + adv
-            globals_ = gpart[:model.n_globals].sum(axis=1).astype(
-                state.globals_.dtype)
+            globals_ = _lane_sum(model, gpart).astype(state.globals_.dtype)
             if sampled:
                 rows.append(tap(fields, points)[None])
         out = LatticeState(fields=leave(fields), flags=state.flags,
@@ -800,8 +811,14 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     ``ext_halo=True`` builds the sharded building block instead (the
     domain is one device's y-block carrying 8 exchanged halo rows at each
-    end); returns ``(call, by, zonal_names)`` for
-    :mod:`tclb_tpu.parallel.halo` to compose with ``ppermute``."""
+    end); returns ``(call, call_g, by, zonal_names)`` for
+    :mod:`tclb_tpu.parallel.halo` to compose with ``ppermute``: ``call``
+    is the NoGlobals kernel call of ``fuse`` steps, ``call_g`` (``fuse``
+    1 and :func:`kernel_reduces_globals`, else None) one step of the
+    in-kernel-globals flavour, ``-> (fields, globals)`` with the block's
+    lanes summed.  The block has no ghost rows and the accumulated planes
+    are its own ``by`` rows, never the halo rows: a shard's Globals are
+    the sums over its own nodes, which the composer ``psum``s."""
     if model.ndim == 3:
         if ext_halo:
             raise ValueError("3d generic engine has no ext_halo mode")
@@ -1046,7 +1063,14 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     if ext_halo:
         # the sharded building block keeps the full-aux convention: the
         # halo composer assembles + exchanges aux planes host-side
-        return _mk_call(plan), by, zonal_names
+        call_g = None
+        if fuse == 1 and kernel_reduces_globals(model, nx):
+            kernel_g = _mk_call(plan, with_globals=True)
+
+            def call_g(*operands):
+                fields, gpart = kernel_g(*operands)
+                return fields, _lane_sum(model, gpart)
+        return _mk_call(plan), call_g, by, zonal_names
 
     plan1 = plan if fuse == 1 \
         else action_plan(model, "Iteration", fuse=1)[0]

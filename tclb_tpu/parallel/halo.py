@@ -233,6 +233,51 @@ def _globals_allreduce(model: Model, g: jnp.ndarray, names) -> jnp.ndarray:
     return jnp.where(jnp.asarray(is_sum), g_sum, g_max)
 
 
+def band_shards(model: Model, mesh: Mesh, shape) -> Optional[tuple]:
+    """``(axis, n, local)`` of a lattice the band kernels can take in
+    shards: the mesh axis their band axis is split over (y in 2D, z in
+    3D), its size, and one shard's shape; None where the mesh is not the
+    model's, splits another axis (the kernels keep the lane plane whole)
+    or does not divide the rows."""
+    try:
+        _validate_mesh(model, mesh)
+    except ValueError:
+        return None
+    if mesh.shape["x"] != 1 or (model.ndim == 3 and mesh.shape["y"] != 1):
+        return None
+    axis = "y" if model.ndim == 2 else "z"
+    n = mesh.shape[axis]
+    if shape[0] % n:
+        return None
+    return axis, n, (shape[0] // n,) + tuple(shape[1:])
+
+
+def _state_specs(mesh: Mesh) -> LatticeState:
+    """How a ``shard_map`` program takes and returns the state: fields
+    and flags by the mesh's axes, Globals and iteration replicated."""
+    return LatticeState(fields=field_spec(mesh), flags=flag_spec(mesh),
+                        globals_=P(), iteration=P())
+
+
+def _generic_aux(params: SimParams, flags_i32, zones, gz_si, dtype):
+    """The aux stack of the generic 2D building block on one shard: the
+    flag plane and a plane a zonal setting (those at ``gz_si`` of the
+    zone table, by the nodes' ``zones``), in ``dtype``."""
+    from tclb_tpu.ops import fusion
+    return jnp.stack(
+        [flags_i32.astype(dtype)]
+        + [fusion.zone_plane(params.zone_table[j].astype(dtype), zones)
+           for j in gz_si])
+
+
+def _streams(model: Model) -> int:
+    """What one rep of the Iteration action adds to the iteration
+    counter: 1 iff any stage streams — the rule the single-device generic
+    engine applies."""
+    return int(any(model.stages[st].load_densities
+                   for st in model.actions["Iteration"]))
+
+
 def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                                 dtype=jnp.float32,
                                 present: Optional[set] = None,
@@ -257,20 +302,14 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
 
     Like the single-device fast path this is the "NoGlobals"
     specialization: ``globals_`` is zeroed; the Lattice hybrid's trailing
-    XLA step (which psums) supplies them."""
+    step supplies them: :func:`make_sharded_pallas_tail` where it takes
+    the case, else the sharded XLA step (both psum)."""
     from tclb_tpu.ops import fusion, pallas_d2q9, pallas_d3q
     from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
-    try:
-        _validate_mesh(model, mesh)
-    except ValueError:
+    shards = band_shards(model, mesh, shape)
+    if shards is None:
         return None
-    if mesh.shape["x"] != 1 or (model.ndim == 3 and mesh.shape["y"] != 1):
-        return None   # kernels keep the lane plane whole
-    axis = "y" if model.ndim == 2 else "z"
-    n = mesh.shape[axis]
-    if shape[0] % n:
-        return None
-    local = (shape[0] // n,) + tuple(shape[1:])
+    axis, n, local = shards
 
     mode = None
     if model.ndim == 2:
@@ -287,15 +326,12 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             from tclb_tpu.ops import pallas_generic
             if not pallas_generic.supports(model, local, dtype):
                 return None
-            callg, byg, gz_names = pallas_generic.make_pallas_iterate(
+            callg, _, byg, gz_names = pallas_generic.make_pallas_iterate(
                 model, local, dtype, interpret=interpret, fuse=1,
                 present=present, ext_halo=True)
             si = model.setting_index
             gz_si = [si[nm] for nm in gz_names]
-            # iteration advances per action rep iff any stage streams —
-            # the same rule the single-device generic engine applies
-            g_adv = int(any(model.stages[st].load_densities
-                            for st in model.actions["Iteration"]))
+            g_adv = _streams(model)
             mode = "generic2d"
         width = 8
     else:
@@ -324,9 +360,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
         with jax.named_scope("halo_exchange"):
             return _exchange_axis(arr, axis, 1, width, n)
 
-    state_specs = LatticeState(
-        fields=field_spec(mesh), flags=flag_spec(mesh),
-        globals_=P(), iteration=P())
+    state_specs = _state_specs(mesh)
 
     def split(niter: int) -> tuple:
         """``niter`` steps as the trips of the loop (calls of two fused
@@ -355,11 +389,8 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             # their body builds the padded operand anew, which is the
             # copy of the carry a single call a body needs (ROADMAP S9)
             if mode == "generic2d":
-                aux_ext = exch(jnp.stack(
-                    [flags_i32.astype(dtype)]
-                    + [fusion.zone_plane(
-                        params.zone_table[j].astype(dtype), zones)
-                       for j in gz_si]))
+                aux_ext = exch(_generic_aux(params, flags_i32, zones,
+                                            gz_si, dtype))
 
                 def bodyg(carry, _):
                     f, it = carry
@@ -452,6 +483,95 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                   impl=dict(program=_program))
 
 
+def make_sharded_pallas_tail(model: Model, mesh: Mesh, shape,
+                             dtype=jnp.float32,
+                             present: Optional[set] = None,
+                             interpret: Optional[bool] = None):
+    """The one step a hybrid engine leaves for the Globals, on a y-split
+    2D mesh: an :class:`Engine` of ``iterate(state, params, 1)``, or None
+    where this configuration can't run it (a 3D model, whose generic
+    engine has no ``ext_halo`` mode; a mesh split in x; shards of no
+    multiple of 8 rows; storage other than f32; a model or local shape
+    ``pallas_generic`` refuses; Globals its kernel does not reduce).
+
+    One ``shard_map`` program: the fields' 8 rows a side and the aux
+    stack are exchanged (:func:`_exchange_axis`'s padded operand, made
+    once an ``iterate``, not once a kernel call), each shard runs ONE
+    call of ``pallas_generic``'s one-step band kernel with in-kernel
+    globals on it, which sums over the shard's own rows, and the partial
+    sums are reduced across the mesh (:func:`_globals_allreduce`):
+    ``globals_`` comes back replicated, as the sharded XLA step
+    (:func:`make_sharded_iterate`) returns it.
+
+    The program does not donate the state: it is one kernel call, which
+    reads halos of what it writes (``pallas_generic.
+    _donating_unless_one_call``, the one-chip tail's rule), so a first
+    call that fails leaves the state whole (``Lattice._probe_tail``).
+    Nothing has shown that the kernel compiles: ``unproven``."""
+    from tclb_tpu.ops import pallas_generic
+    from tclb_tpu.ops.engine import Engine
+    shards = band_shards(model, mesh, shape)
+    if shards is None or model.ndim != 2:
+        return None
+    axis, n, local = shards
+    if (local[0] % 8 or jnp.dtype(dtype) != jnp.dtype(jnp.float32)
+            or not pallas_generic.supports(model, local, dtype,
+                                           probe=False)):
+        return None
+    _, call_g, by, gz_names = pallas_generic.make_pallas_iterate(
+        model, local, dtype, interpret=interpret, fuse=1, present=present,
+        ext_halo=True)
+    if call_g is None:
+        return None
+    gz_si = [model.setting_index[nm] for nm in gz_names]
+    width, adv, names = 8, _streams(model), tuple(mesh.axis_names)
+    aux_planes = 1 + len(gz_si)
+    halo_bytes = (model.n_storage + aux_planes) * _exchange_bytes(
+        (1,) + local, 1, width, n, 1, jnp.dtype(dtype).itemsize)
+
+    def local_step(state: LatticeState, params: SimParams) -> LatticeState:
+        flags_i32 = state.flags.astype(jnp.int32)
+        with jax.named_scope("halo_exchange"):
+            fields_ext = _exchange_axis(state.fields, axis, 1, width, n)
+            aux_ext = _exchange_axis(
+                _generic_aux(params, flags_i32,
+                             flags_i32 >> model.zone_shift, gz_si, dtype),
+                axis, 1, width, n)
+        fields, g = call_g(params.settings.astype(dtype),
+                           state.iteration[None], fields_ext, aux_ext)
+        return LatticeState(
+            fields=fields, flags=state.flags,
+            globals_=_globals_allreduce(
+                model, g.astype(state.globals_.dtype), names),
+            iteration=state.iteration + adv)
+
+    state_specs = _state_specs(mesh)
+    program = jax.jit(jax.shard_map(
+        local_step, mesh=mesh, in_specs=(state_specs, P()),
+        out_specs=state_specs, check_vma=False))
+
+    def iterate(state, params, niter):
+        if int(niter) != 1 or params.time_series is not None:
+            raise ValueError("the sharded tail is one step that reduces "
+                             "the Globals, with no Control time series")
+        out = program(state, params)
+        if telemetry.enabled():
+            _count_halo(1, halo_bytes)
+        return out
+
+    def account(niter: int, has_series: bool = False) -> dict:
+        """One kernel call on the shard padded round its halo rows, and
+        the kernel's bands of it, under the generic engine's names."""
+        return dict(kernel_calls=1, paired_calls=0, halo_operand_rows=0,
+                    stages_per_step=len(model.actions["Iteration"]),
+                    bands=local[0] // by, band_rows=by, halo_rows=width,
+                    aux_planes=aux_planes)
+
+    # full_globals: the call returns the last (its one) step's Globals
+    return Engine(iterate, account, full_globals=True, unproven=True,
+                  fuse=1, impl=dict(program=program))
+
+
 def make_sharded_iterate(model: Model, mesh: Mesh,
                          action: str = "Iteration",
                          unroll: int = 1,
@@ -472,9 +592,7 @@ def make_sharded_iterate(model: Model, mesh: Mesh,
                             compute_globals=True)
     names = tuple(mesh.axis_names)
 
-    state_specs = LatticeState(
-        fields=field_spec(mesh), flags=flag_spec(mesh),
-        globals_=P(), iteration=P())
+    state_specs = _state_specs(mesh)
     # params are fully replicated; a single P() is a valid tree prefix for
     # whatever SimParams contains (incl. Control time series)
     param_specs = P()

@@ -94,6 +94,17 @@ def _sharded(name):
     return it, lat
 
 
+def _sharded_tail():
+    shape = (32, 128)
+    mesh = make_mesh(shape, devices=jax.devices()[:2])
+    m, lat, present = _lattice("d2q9", shape, mesh=mesh)
+    it = halo.make_sharded_pallas_tail(m, mesh, shape, jnp.float32,
+                                       present=present, interpret=True)
+    # one step that returns its Globals, on a kernel nothing has compiled
+    assert it.full_globals and it.unproven and it.fuse == 1
+    return it, lat
+
+
 # builder, whether it reports (an account), the lengths to trace: empty
 # loops, loops of one trip, odd and even loops of either kernel
 BUILDERS = {
@@ -113,6 +124,7 @@ BUILDERS = {
                           (1, 2, 5, 6)),
     "sharded_tuned": (lambda: _sharded("d2q9"), True, (1, 5, 8, 11)),
     "sharded_generic": (lambda: _sharded("d2q9_kuper"), True, (3,)),
+    "sharded_tail": (_sharded_tail, True, (1,)),
 }
 
 

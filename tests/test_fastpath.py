@@ -35,9 +35,11 @@ def _spans(docs, name):
     return [e for e in docs if e["kind"] == "span" and e["name"] == name]
 
 
-def _says_tail(seen, lat, fused, tail, calls):
+def _says_tail(seen, lat, fused, tail, calls, fused_probed=True):
     """What a run on a hybrid engine with the Pallas tail says of
-    itself after ``calls`` calls of ``iterate``."""
+    itself after ``calls`` calls of ``iterate``; ``fused_probed``:
+    whether the fused engine's first call is probed too (the sharded
+    tuned engine's is not)."""
     assert (lat._fast_name, lat._tail_name) == (fused, tail)
     steps = _spans(seen, "iterate.globals_step")
     assert [e["engine"] for e in steps] == [tail] * calls
@@ -47,7 +49,7 @@ def _says_tail(seen, lat, fused, tail, calls):
     assert not any("stages_per_step" in e
                    for e in _spans(seen, "iterate.fused"))
     assert [e["engine"] for e in _spans(seen, "engine.probe")] \
-        == [fused, tail]
+        == [fused] * fused_probed + [tail]
     assert not [e for e in seen if e["kind"] == "engine_fallback"]
 
 

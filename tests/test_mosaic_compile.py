@@ -695,6 +695,28 @@ def test_generic_resident_drop_512(one_chip, monkeypatch):
     assert re.search(r"%generic_band_fuse1[\w.]* = \S+ custom-call\(", text)
 
 
+def _karman_4096_on_4x1_mesh(topo) -> tuple:
+    """The lattice of the two ``karman4096`` cells on a y-split mesh of
+    four of the described chips: the model, the mesh, the node types
+    present, and the shapes of its state and parameters with their
+    shardings (what a compile takes in place of arrays)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tclb_tpu.parallel import halo
+    shape = (4096, 1024)
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("y", "x"))
+    state = jax.tree.map(
+        lambda x, sp: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, sp)),
+        lat.state, halo._state_specs(mesh))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
+        lat.params)
+    return m, mesh, present, state, params
+
+
 @pytest.mark.parametrize("niter", [500, 250, 2])
 def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
     """The four-chip path of chip_smoke.py and of the two ``karman4096``
@@ -719,14 +741,9 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
     it prefetches its band since PR 50).
     With the one-step kernel in the same program the compiler keeps the
     first call's result in HBM: hence the two programs."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from tclb_tpu.core.lattice import LatticeState
     from tclb_tpu.parallel import halo
-    shape = (4096, 1024)
-    m, lat, present = _channel("d2q9", shape, nu=0.02)
-    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("y", "x"))
-    it = halo.make_sharded_pallas_iterate(m, mesh, shape, jnp.float32,
+    m, mesh, present, state, params = _karman_4096_on_4x1_mesh(topo)
+    it = halo.make_sharded_pallas_iterate(m, mesh, (4096, 1024), jnp.float32,
                                           present=present, interpret=False)
     assert it is not None
     trips, odd = divmod(niter - 1, 2)
@@ -734,17 +751,6 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
     assert it.account(niter - 1) == dict(
         kernel_calls=trips + 1, paired_calls=trips - trips % 2,
         halo_operand_rows=8)
-    specs = LatticeState(fields=halo.field_spec(mesh),
-                         flags=halo.flag_spec(mesh),
-                         globals_=P(), iteration=P())
-    state = jax.tree.map(
-        lambda x, sp: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=NamedSharding(mesh, sp)),
-        lat.state, specs)
-    params = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
-        lat.params)
     lowered = it.impl["program"](trips, int(not trips)).lower(state, params)
     assert lowered.args_info[0][0].fields.donated
     text = lowered.compile().as_text()
@@ -776,6 +782,54 @@ def test_sharded_d2q9_4096_on_4x1_mesh(topo, niter):
     assert not _state_moves(body, m, (1024, 1024))
     # both of the loop's state buffers in the compiler's fast memory
     assert _in_fast_memory(body) == [True, True]
+
+
+def test_sharded_tail_4096_on_4x1_mesh(topo):
+    """The step under ``iterate.globals_step`` in the two ``karman4096``
+    cells (``parallel/halo.make_sharded_pallas_tail``), compiled for the
+    described 4 x 1 mesh at the cell's shard, 11 x 1024 x 1024: one
+    program of ONE ``generic_band_fuse1`` call with in-kernel globals, the
+    neighbours' 8 rows of the fields and of the aux stack by
+    ``collective-permute``, one ``all-reduce`` of the Globals' partial
+    sums, **not donating** (a failed probe leaves the state whole), and
+    nothing of the state's size made beside the kernel's result but the
+    one padded operand (11 x 1040 x 1024) the kernel reads."""
+    from tclb_tpu.parallel import halo
+    m, mesh, present, state, params = _karman_4096_on_4x1_mesh(topo)
+    tail = halo.make_sharded_pallas_tail(m, mesh, (4096, 1024), jnp.float32,
+                                         present=present, interpret=False)
+    assert tail.full_globals and tail.unproven and tail.fuse == 1
+    assert tail.account(1) == dict(
+        kernel_calls=1, paired_calls=0, halo_operand_rows=0,
+        stages_per_step=1, bands=32, band_rows=32, halo_rows=8, aux_planes=3)
+    lowered = tail.impl["program"].lower(state, params)
+    assert not lowered.args_info[0][0].fields.donated
+    compiled = lowered.compile()
+    assert compiled.output_shardings.globals_.is_fully_replicated
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "generic_band_fuse1/pallas_call" in text
+    assert "halo_exchange/" in text
+    permutes = re.findall(r"= \((f32\[\d+,8,1024\])\S*, .*\) "
+                          r"collective-permute-start\(", text)
+    assert sorted(permutes) == ["f32[11,8,1024]"] * 2 + ["f32[3,8,1024]"] * 2
+    # the Globals: one all-reduce of three sums, no max beside it
+    assert len(re.findall(r"= f32\[3\]\S* all-reduce(-start)?\(",
+                          text)) == 1
+    entry, = [lines for name, lines in _computations(text).items()
+              if name.startswith("main")]
+    assert not _state_copies(entry, m, (1024, 1024))
+    made = [line.strip() for line in entry
+            if re.search(r"= f32\[11,1\d{3},1024\]\S* (?!custom-call|"
+                         r"get-tuple-element|parameter|bitcast)", line)]
+    # the padded operand; what else there is of the state's size is the
+    # input on its way into the compiler's fast memory, where the pad
+    # reads it
+    padded = [line for line in made if "f32[11,1040,1024]" in line]
+    assert len(padded) == 1 and "halo_exchange/concatenate" in padded[0]
+    assert all(re.search(r"S\(1\)\} copy-done\(", line)
+               for line in made if line not in padded), made
+    assert len(made) <= 2, made
 
 
 def _quantity_on_4x1_mesh(topo, programs, name, quantity):

@@ -1,10 +1,12 @@
 """The step a hybrid engine leaves for the Globals: the tail engine.
 
-A hybrid engine (``pallas_d3q``, ``pallas_2d``, ``pallas_resident``)
-advances ``niter - 1`` steps and leaves the last one, which reduces the
-Globals, to another engine: the generic Pallas engine's one-step flavour
-with in-kernel globals wherever it takes the case
-(``Lattice._build_tail``), else the XLA step.  These tests force the
+A hybrid engine (``pallas_d3q``, ``pallas_2d``, ``pallas_resident``,
+``pallas_sharded``) advances ``niter - 1`` steps and leaves the last one,
+which reduces the Globals, to another engine: the generic Pallas engine's
+one-step flavour with in-kernel globals wherever it takes the case
+(``Lattice._build_tail``; on a y-split 2D mesh on each shard, the partial
+sums reduced across it: ``parallel/halo.make_sharded_pallas_tail``), else
+the XLA step.  These tests force the
 dispatch on CPU (interpret mode) and pin ``Lattice.iterate`` against the
 XLA engine, and what the run says of itself.  (The 2D composition,
 resident engine and tail: ``test_fastpath.py::
@@ -59,60 +61,123 @@ _TAIL_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", list(_TAIL_CASES))
+def _karman_on_a_mesh(chips, ny=64, axis="y"):
+    """``_karman_lattice(ny)`` (inlet, outlet, walls, a box, the
+    objective columns the Globals are reduced on) split over ``chips``
+    of the CPU's devices along ``axis``."""
+    from tclb_tpu.parallel.mesh import make_mesh
+    if len(jax.devices()) < chips:
+        pytest.skip(f"needs {chips} devices")
+    m, ref = _karman_lattice(ny)
+    split = {"y": 1, "x": 1, axis: chips}
+    mesh = make_mesh((ny, 128), devices=jax.devices()[:chips],
+                     decomposition=split)
+    lat = Lattice(m, (ny, 128), dtype=jnp.float32,
+                  settings={"nu": 0.05, "Velocity": 0.03}, mesh=mesh)
+    lat.set_flags(np.asarray(ref.state.flags))
+    lat.init()
+    return m, lat
+
+
+def _mesh_tags(chips):
+    split = {"y": chips, "x": 1}
+    return (f"pallas_sharded[{split},fuse=2]",
+            f"pallas_sharded[generic,{split},fuse=1,globals]")
+
+
+_TAIL_LATTICES = {
+    **{case: (lambda shape=shape: _cumulant_lattice(shape), fused, tail,
+              ("Flux",))
+       for case, (shape, fused, tail) in _TAIL_CASES.items()},
+    # a y-split 2D mesh: the tail is the same kernel on each shard under
+    # shard_map, its partial sums reduced across the mesh
+    **{f"mesh{chips}x1": (lambda chips=chips: _karman_on_a_mesh(chips),
+                          *_mesh_tags(chips),
+                          ("PressureLoss", "OutletFlux", "InletFlux"))
+       for chips in (4, 2)},
+}
+
+
+@pytest.mark.parametrize("case", list(_TAIL_LATTICES))
 def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
     """``Lattice.iterate(n)`` on a hybrid engine, whose last step runs on
     the generic Pallas engine's in-kernel-globals flavour, against the
     XLA engine's ``n`` steps: every storage plane (``avg*`` and
-    ``SynthT*`` among them), ``Flux``, the iteration; and what the run
+    ``SynthT*`` among them), the Globals, the iteration; and what the run
     says of itself: the engine on ``iterate.globals_step``, one
-    ``engine.tail_calls`` a call, no fallback.  (The 2D case is
+    ``engine.tail_calls`` a call, no fallback.  On a mesh (4 and 2 of the
+    CPU's devices) both sides are sharded: the XLA side is the sharded
+    XLA step this tail replaces.  (The one-chip 2D case is
     ``test_engine_dispatch_matches_xla``.)"""
-    shape, fused, tail = _TAIL_CASES[case]
+    lattice, fused, tail, reduced = _TAIL_LATTICES[case]
     niter, calls = 5, 2
     monkeypatch.setenv("TCLB_FASTPATH", "0")
-    m, lat_x = _cumulant_lattice(shape)
-    flux = []
+    m, lat_x = lattice()
+    wanted = []
     for _ in range(calls):
         lat_x.iterate(niter)
-        flux.append(lat_x.get_globals()["Flux"])
+        wanted.append(lat_x.get_globals())
     assert lat_x._fast_name is None and lat_x._tail_name is None
     assert not _spans(seen, "iterate.globals_step")
     monkeypatch.setenv("TCLB_FASTPATH", "force")
-    _, lat_f = _cumulant_lattice(shape)
+    _, lat_f = lattice()
     before = telemetry.counters().get("engine.tail_calls", 0)
-    for want in flux:
+    for want in wanted:
         lat_f.iterate(niter)
-        assert want != 0
-        np.testing.assert_allclose(lat_f.get_globals()["Flux"], want,
-                                   rtol=1e-4)
+        got = lat_f.get_globals()
+        for name in reduced:
+            assert want[name] != 0
+            np.testing.assert_allclose(got[name], want[name], rtol=1e-4,
+                                       err_msg=name)
     fx, ff = np.asarray(lat_x.state.fields), np.asarray(lat_f.state.fields)
     np.testing.assert_allclose(ff, fx, rtol=2e-5, atol=2e-6)
     for plane in m.storage_names:
         if plane.startswith(("avg", "SynthT")):
             assert np.abs(fx[m.storage_index[plane]]).max() > 0, plane
     assert int(lat_f.state.iteration) == niter * calls
-    _says_tail(seen, lat_f, fused, tail, calls)
+    _says_tail(seen, lat_f, fused, tail, calls,
+               fused_probed=lat_f.mesh is None)
     assert telemetry.counters()["engine.tail_calls"] - before == calls
+    if lat_f.mesh is not None:
+        # replicated, as the sharded XLA step returns them
+        assert lat_f.state.globals_.sharding.is_fully_replicated
+        build, = [e for e in _spans(seen, "engine.build")
+                  if e["selected"] == fused]
+        assert build["tail"] == tail
 
 
-def _tail_on_a_mesh():
+def _tail_on_a_3d_mesh():
+    # a z-split 3D mesh: the generic 3D engine has no ext_halo mode
     from tclb_tpu.parallel.mesh import make_mesh
-    m, ref = _karman_lattice(64)
-    mesh = make_mesh((64, 128), devices=jax.devices()[:4],
-                     decomposition={"y": 4, "x": 1})
-    lat = Lattice(m, (64, 128), dtype=jnp.float32,
-                  settings={"nu": 0.05, "Velocity": 0.03}, mesh=mesh)
+    m, ref = _bgk_lattice()
+    mesh = make_mesh(ref.shape, devices=jax.devices()[:2],
+                     decomposition={"z": 2, "y": 1, "x": 1})
+    lat = Lattice(m, ref.shape, dtype=jnp.float32, mesh=mesh,
+                  settings={"omega": 1.0, "GravitationX": 1e-5})
     lat.set_flags(np.asarray(ref.state.flags))
     lat.init()
     return lat, "pallas_sharded"
 
 
-def _tail_with_a_series():
-    _, lat = _karman_lattice(64)
+def _tail_on_shards_of_odd_rows():
+    # 48 rows over 4 chips: shards of 12 rows, no multiple of 8 (no
+    # sharded fused engine either: the XLA engine runs every step)
+    return _karman_on_a_mesh(4, ny=48)[1], None
+
+
+def _tail_on_an_x_split():
+    # the kernels keep the lane plane whole
+    return _karman_on_a_mesh(2, axis="x")[1], None
+
+
+def _with_a_series(lat):
     lat.set_setting_series(
         "Velocity", 0.03 + 0.001 * np.sin(np.arange(16) * 0.3), zone=0)
-    return lat, "pallas_generic"
+    return lat
+
+
+def _tail_with_a_series():
+    return _with_a_series(_karman_lattice(64)[1]), "pallas_generic"
 
 
 def _tail_of_a_refused_dtype(monkeypatch):
@@ -127,29 +192,45 @@ def _tail_of_half_a_lane_tile():
     return _bgk_lattice(nx=64)[1], "pallas_d3q"
 
 
-@pytest.mark.parametrize("case", ["mesh", "series", "refused_dtype",
+def _tail_on_a_mesh_with_a_series():
+    # the sharded engines take no <Control> series: XLA runs every step
+    return _with_a_series(_karman_on_a_mesh(4)[1]), "pallas_sharded"
+
+
+@pytest.mark.parametrize("case", ["mesh_3d", "mesh_odd_rows", "mesh_x_split",
+                                  "mesh_series", "series", "refused_dtype",
                                   "no_kernel_globals"])
 def test_tail_engine_stays_off(monkeypatch, seen, case):
     """Where the generic engine's one-step flavour does not apply, the
-    trailing step stays the XLA step: on a mesh (the sharded engine's
-    own step), with a ``<Control>`` series (the series-aware generic
-    engine reduces the Globals itself: no trailing step at all), with a
-    storage dtype the generic engine refuses, and on a shape whose
-    generic kernel has no flavour that reduces Globals."""
+    trailing step stays the XLA step: on a mesh the sharded tail cannot
+    take (a 3D one split in z, shards of 12 rows, a split in x: the last
+    two have no sharded fused engine either and run every step on XLA),
+    with a ``<Control>`` series (the series-aware generic engine reduces
+    the Globals itself, and on a mesh the XLA engine runs the whole
+    call: no trailing step at all), with a storage dtype the generic
+    engine refuses, and on a shape whose generic kernel has no flavour
+    that reduces Globals."""
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     before = telemetry.counters().get("engine.tail_calls", 0)
     lat, family = (_tail_of_a_refused_dtype(monkeypatch)
                    if case == "refused_dtype"
-                   else {"mesh": _tail_on_a_mesh,
+                   else {"mesh_3d": _tail_on_a_3d_mesh,
+                         "mesh_odd_rows": _tail_on_shards_of_odd_rows,
+                         "mesh_x_split": _tail_on_an_x_split,
+                         "mesh_series": _tail_on_a_mesh_with_a_series,
                          "series": _tail_with_a_series,
                          "no_kernel_globals": _tail_of_half_a_lane_tile,
                          }[case]())
     lat.iterate(6)
-    assert lat._fast_name.startswith(family + "[")
+    if family is None:
+        assert lat._fast_name is None
+    else:
+        assert lat._fast_name.startswith(family + "[")
     assert lat._tail is None and lat._tail_name is None
     steps = _spans(seen, "iterate.globals_step")
-    assert [e["engine"] for e in steps] == ([] if case == "series"
-                                            else ["xla"])
+    assert [e["engine"] for e in steps] == (
+        ["xla"] if case in ("mesh_3d", "refused_dtype", "no_kernel_globals")
+        else [])
     assert telemetry.counters().get("engine.tail_calls", 0) == before
     assert all(e["engine"] == lat._fast_name
                for e in _spans(seen, "engine.probe"))
@@ -169,21 +250,35 @@ def _bgk_lattice(storage_dtype=None, nx=128):
     return m, lat
 
 
+_FALLBACK_CASES = {
+    # the lattice, the method of Lattice that names the tail's candidate,
+    # the fused family, the tail's tag
+    "chip": (lambda: _bgk_lattice(), "_generic_cand", "pallas_d3q",
+             "pallas_generic[d3q27_BGK,fuse=1]"),
+    "mesh": (lambda: _karman_on_a_mesh(4), "_sharded_tail_cand",
+             "pallas_sharded", _mesh_tags(4)[1]),
+}
+
+
 @pytest.mark.parametrize("fails", ["build", "first_call"])
-def test_tail_engine_falls_back_to_the_xla_step(monkeypatch, seen, fails):
+@pytest.mark.parametrize("where", list(_FALLBACK_CASES))
+def test_tail_engine_falls_back_to_the_xla_step(monkeypatch, seen, where,
+                                                fails):
     """A tail that cannot be built, or whose first call fails, hands the
     trailing step to XLA with one ``engine_fallback`` event (from its tag
     to ``xla``); the tail's one call does not donate, so the state is
-    intact and the run goes on to the XLA engine's result.  The failure
-    is this lattice's alone: the generic engine's process-wide verdict,
-    which the fused chain's rungs read, is not touched, and a later
-    lattice probes its own tail."""
+    intact and the run goes on to the XLA engine's result: on one chip,
+    and on a mesh, where the step that takes over is the sharded XLA
+    step.  The failure is this lattice's alone: the generic engine's
+    process-wide verdict, which the fused chain's rungs read, is not
+    touched, and a later lattice probes its own tail."""
+    lattice, names_cand, family, tail = _FALLBACK_CASES[where]
     monkeypatch.setattr(pallas_generic, "_mosaic_verdict", {})
     monkeypatch.setenv("TCLB_FASTPATH", "0")
-    m, lat_x = _bgk_lattice()
+    m, lat_x = lattice()
     lat_x.iterate(10)
     monkeypatch.setenv("TCLB_FASTPATH", "force")
-    real = Lattice._generic_cand
+    real = getattr(Lattice, names_cand)
 
     def broken(self, *a, **k):
         cand = real(self, *a, **k)
@@ -198,23 +293,22 @@ def test_tail_engine_falls_back_to_the_xla_step(monkeypatch, seen, fails):
                 raise RuntimeError("scoped vmem exceeded")
             return dataclasses.replace(it, run=iterate)
         return dataclasses.replace(cand, build=build)
-    monkeypatch.setattr(Lattice, "_generic_cand", broken)
-    _, lat = _bgk_lattice()
+    monkeypatch.setattr(Lattice, names_cand, broken)
+    _, lat = lattice()
     lat.iterate(5)
-    monkeypatch.setattr(Lattice, "_generic_cand", real)
+    monkeypatch.setattr(Lattice, names_cand, real)
     lat.iterate(5)
-    assert lat._fast_name.startswith("pallas_d3q[")
+    assert lat._fast_name.startswith(family + "[")
     assert lat._tail is None and lat._tail_name is None
     fell, = [e for e in seen if e["kind"] == "engine_fallback"]
-    assert (fell["from"], fell["to"]) == (
-        "pallas_generic[d3q27_BGK,fuse=1]", "xla")
+    assert (fell["from"], fell["to"]) == (tail, "xla")
     assert [e["engine"] for e in _spans(seen, "iterate.globals_step")] \
         == ["xla", "xla"]
-    assert pallas_generic.mosaic_ok(m, lat.shape)
+    assert all(pallas_generic._mosaic_verdict.values())
     # a later lattice of the model and shape tries its own tail
-    _, lat2 = _bgk_lattice()
+    _, lat2 = lattice()
     lat2.iterate(5)
-    assert lat2._tail_name == "pallas_generic[d3q27_BGK,fuse=1]"
+    assert lat2._tail_name == tail
     assert len([e for e in seen if e["kind"] == "engine_fallback"]) == 1
     np.testing.assert_allclose(np.asarray(lat.state.fields),
                                np.asarray(lat_x.state.fields),
@@ -222,5 +316,3 @@ def test_tail_engine_falls_back_to_the_xla_step(monkeypatch, seen, fails):
     for k, v in lat_x.get_globals().items():
         np.testing.assert_allclose(lat.get_globals()[k], v, rtol=1e-4,
                                    atol=1e-6, err_msg=f"global {k}")
-
-

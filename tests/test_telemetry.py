@@ -1054,7 +1054,11 @@ def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch, niter):
     aux stack once per call.  The span carries the engine's account
     beside the bytes: its kernel calls, those a two-call loop body
     issues (none of three trips, four of five), and the 8 halo rows a
-    side the kernel takes as operands of their own."""
+    side the kernel takes as operands of their own.  The trailing step
+    is the sharded tail (``make_sharded_pallas_tail``): its one exchange
+    of the fields' and the aux stack's 8 rows a side lies on
+    ``iterate.globals_step`` with its account (the probed first call's
+    on the probe's spans; the counters hold both calls)."""
     from tclb_tpu.parallel.mesh import make_mesh
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     ny, nx = 64, 128
@@ -1068,26 +1072,32 @@ def test_halo_bytes_equal_the_formula_on_the_mesh(seen, monkeypatch, niter):
     lat.set_flags(flags)
     lat.init()
     lat.iterate(niter)
+    lat.iterate(niter)
     assert lat._fast_name == "pallas_sharded[{'y': 4, 'x': 1},fuse=2]"
     assert telemetry.fuse_of(lat._fast_name) == 2
+    tail = "pallas_sharded[generic,{'y': 4, 'x': 1},fuse=1,globals]"
+    assert lat._tail_name == tail and telemetry.fuse_of(tail) == 1
 
     plane = 2 * 8 * nx * 4                  # 8 rows each way, f32
     nfast = niter - 1
     want = (nfast // 2 + nfast % 2) * m.n_storage * plane + 3 * plane
-    fused, = _spans(seen, "iterate.fused")
-    assert fused["iters"] == nfast and fused["halo_bytes"] == want
     calls, paired = {8: (4, 0), 12: (6, 4)}[niter]
-    assert (fused["kernel_calls"], fused["paired_calls"],
-            fused["halo_operand_rows"]) == (calls, paired, 8)
-    assert telemetry.counters()["engine.kernel_calls"] == calls
-    # as it counted: a fused step an exchange, the XLA step one a mesh axis
-    assert telemetry.counters()["halo.exchanges"] == nfast + 2
-    # the trailing step is the sharded XLA step: the planes that cross y
-    # (3 of d2q9's 9 each way), one row wide
-    step, = _spans(seen, "iterate.globals_step")
-    crossing = int(np.count_nonzero(m.ei[:, 1]))
-    assert step["halo_bytes"] == 2 * 1 * nx * crossing * 4
-    assert telemetry.counters()["halo.bytes"] == want + step["halo_bytes"]
+    for fused in _spans(seen, "iterate.fused"):
+        assert fused["iters"] == nfast and fused["halo_bytes"] == want
+        assert (fused["kernel_calls"], fused["paired_calls"],
+                fused["halo_operand_rows"]) == (calls, paired, 8)
+    assert telemetry.counters()["engine.kernel_calls"] == 2 * (calls + 1)
+    # as it counted: a fused step an exchange, the tail's step one
+    assert telemetry.counters()["halo.exchanges"] == 2 * (nfast + 1)
+    probed, step = _spans(seen, "iterate.globals_step")
+    assert probed["engine"] == step["engine"] == tail
+    assert step["halo_bytes"] == (m.n_storage + 3) * plane
+    assert (step["kernel_calls"], step["halo_operand_rows"],
+            step["aux_planes"]) == (1, 0, 3)
+    assert "halo_bytes" not in probed
+    assert telemetry.counters()["halo.bytes"] \
+        == 2 * (want + step["halo_bytes"])
+    assert telemetry.counters()["engine.tail_calls"] == 2
     assert not [e for e in _spans(seen) if e["name"].startswith("halo.")]
 
 

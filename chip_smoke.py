@@ -2,8 +2,9 @@
 """Prove on the chip that the forward solver starts and computes.
 
     python chip_smoke.py              one TPU chip, every phase below
-    python chip_smoke.py --chips 4    four chips: the sharded path and
-                                      its one-device comparison, only
+    python chip_smoke.py --chips 4    four chips: the sharded paths (2D
+                                      in y, 3D in z) and what each is
+                                      held against, only
     python chip_smoke.py --rehearse   control-flow rehearsal at tiny
                                       sizes on any backend; can never
                                       print ``"ok": true``
@@ -160,6 +161,7 @@ REHEARSAL = {
     "3d_channel.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
     "3d_channel_512.xml": (8, {"nx": 128, "ny": 16, "nz": 16}),
     "tgv_256.xml": (8, {"nx": 128, "ny": 16, "nz": 8}),
+    "tgv_384.xml": (8, {"nx": 128, "ny": 16, "nz": 32}),
     "drop_512.xml": (10, {"nx": 384, "ny": 384}),
     "karman_4096.xml": (12, {"nx": 128, "ny": 256}),
     # rows still wide enough for the raised scoped-VMEM limit
@@ -170,6 +172,10 @@ REHEARSAL = {
 #: are those of the whole case, the state 92 MB
 WIDE_ROWS = {"ny": 256}
 WIDE_STEPS = 20
+#: example/tgv_384.xml cut to a box four chips hold beside the XLA
+#: step's own copies (shards of 32 x 256 x 256, 0.29 GB each): the plane
+#: is still one no kernel holds whole, so each shard's windows are tiled
+ZSPLIT_BOX = {"nx": 256, "ny": 256, "nz": 128}
 
 
 def case_size(case: str) -> tuple:
@@ -329,19 +335,21 @@ class Smoke:
         return f, info, lat
 
     def agree(self, name: str, file: str, engine: tuple,
-              steps: int = AGREE_STEPS, geometry: dict | None = None
-              ) -> None:
+              steps: int = AGREE_STEPS, geometry: dict | None = None,
+              mesh: str | None = None) -> None:
         """``steps`` steps of example ``file`` (at the size ``geometry``
-        cuts it to) on the selected Pallas engine and on the XLA step,
-        same initial state; max |diff| <= TOL."""
+        cuts it to) on the selected Pallas engine and on the XLA step
+        (with ``mesh``: both on that device mesh), same initial state;
+        max |diff| <= TOL."""
         import numpy as np
         outdir = os.path.join(OUT, name)
         shutil.rmtree(outdir, ignore_errors=True)
         cut = self.case(file, steps, handlers=False, geometry=geometry)
-        fp, ip, lat = self.fields_after(cut, outdir, engine)
+        fp, ip, lat = self.fields_after(cut, outdir, engine, mesh=mesh)
         # the flow has to have moved, or agreement would be vacuous
         umax = float(np.max(np.abs(np.asarray(lat.get_quantity("U")))))
-        fx, _, _ = self.fields_after(cut, outdir, engine, xla=True)
+        fx, _, _ = self.fields_after(cut, outdir, engine, mesh=mesh,
+                                     xla=True)
         diff = float(np.max(np.abs(fp - fx)))
         print(json.dumps({"phase": name, "engine": ip["engine"],
                           "reference": "xla", "steps": steps,
@@ -537,6 +545,12 @@ def one_chip(s: Smoke) -> None:
 
 def four_chips(s: Smoke) -> None:
     s.phase("sharded_4x1", s.sharded, "karman_4096.xml", "4x1")
+    # a 3D box split in z, its 256 x 256 plane tiled in y on every
+    # shard: the fused kernel on the neighbours' exchanged slabs against
+    # the sharded XLA step
+    s.phase("agree_zsplit_4x1x1", s.agree, "tgv_384.xml",
+            ("pallas_sharded[{'z': 4, 'y': 1, 'x': 1},fuse=",),
+            AGREE_STEPS, ZSPLIT_BOX, "4x1x1")
     s.phase("failcheck_fires_4x1", s.failcheck_fires, "karman_4096.xml",
             ("pallas_sharded[",), mesh="4x1")
 

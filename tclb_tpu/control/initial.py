@@ -11,11 +11,13 @@ acCallPython``)::
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tclb_tpu import telemetry
 from tclb_tpu.ops import lbm
 
 
@@ -45,8 +47,12 @@ def taylor_green(solver) -> int:
     W = lbm.weights(E)
     nz, ny, nx = lat.shape
     dt = jnp.dtype(lat.dtype)
+    # on a mesh the field is made in the lattice's own shards: at 384^3
+    # the 27 planes are 6.1 GB, more than a chip holds beside its share
+    # of the lattice
+    on = None if lat.mesh is None else lat.state.fields.sharding
 
-    @jax.jit
+    @partial(jax.jit, out_shardings=on)
     def populations(U0):
         def angle(n, axis):
             a = jnp.arange(n, dtype=dt) * jnp.asarray(2.0 * math.pi / n, dt)
@@ -62,6 +68,9 @@ def taylor_green(solver) -> int:
 
     U0 = jnp.asarray(lat.params.settings[m.setting_index["Velocity"]], dt)
     f = populations(U0)
+    # on the element's own span: the set-up table reads its rows there
+    telemetry.annotate("startup.element", sharded=on is not None,
+                       bytes=int(f.nbytes))
     lat.set_density_planes({m.densities[i].name: f[k]
                             for k, i in enumerate(idx)})
     return 0

@@ -861,14 +861,18 @@ class EngineCandidate:
     #                          (fuse, by_cap) to remember once it has run
 
 
-@partial(jax.jit, static_argnames=("idx",))
-def _write_planes(fields, planes, idx):
+@partial(jax.jit, static_argnames=("idx", "out"))
+def _write_planes(fields, planes, idx, out=None):
     """``fields`` with ``planes`` written at storage indices ``idx``, as
     one program: a plane at a time, dispatched eagerly, a state of
-    gigabytes has one whole copy in flight for every plane written."""
+    gigabytes has one whole copy in flight for every plane written.
+    ``out`` (on a mesh: the fields' own sharding) is the result's, so
+    that the planes, whatever theirs, are cut to the shards and never
+    gathered."""
     for i, plane in zip(idx, planes):
         fields = fields.at[i].set(plane)
-    return fields
+    return (fields if out is None
+            else jax.lax.with_sharding_constraint(fields, out))
 
 
 class Lattice:
@@ -929,9 +933,18 @@ class Lattice:
                 np.broadcast_to(vec[:, None], (len(vec), model.zone_max)),
                 dtype=dtype),
         )
+        # on a mesh the arrays of the lattice's size are made in shards:
+        # no device ever holds the whole lattice
+        on_fields = on_flags = None
+        if mesh is not None:
+            from tclb_tpu.parallel.mesh import field_spec, flag_spec
+            from jax.sharding import NamedSharding
+            on_fields = NamedSharding(mesh, field_spec(mesh))
+            on_flags = NamedSharding(mesh, flag_spec(mesh))
         self.state = LatticeState(
-            fields=jnp.zeros((model.n_storage,) + self.shape, dtype=sdt),
-            flags=jnp.zeros(self.shape, dtype=FLAG_DTYPE),
+            fields=jnp.zeros((model.n_storage,) + self.shape, dtype=sdt,
+                             device=on_fields),
+            flags=jnp.zeros(self.shape, dtype=FLAG_DTYPE, device=on_flags),
             globals_=jnp.zeros((model.n_globals,), dtype=dtype),
             iteration=jnp.zeros((), dtype=jnp.int32),
         )
@@ -1003,8 +1016,11 @@ class Lattice:
         src/Lattice.cu.Rt:892-905)."""
         assert flags.shape == self.shape
         self._host_flags = np.asarray(flags, dtype=np.uint16).copy()
+        # on a mesh from the host straight to the shards
         self.state = dataclasses.replace(
-            self.state, flags=jnp.asarray(flags, dtype=FLAG_DTYPE))
+            self.state, flags=jnp.asarray(flags, dtype=FLAG_DTYPE)
+            if self.mesh is None else jax.device_put(
+                self._host_flags, self.state.flags.sharding))
         if self._place is not None:
             self.state, self.params = self._place()
         self._fast_tried = False   # present node types may have changed
@@ -1220,17 +1236,7 @@ class Lattice:
                 tag, lambda: make(model, shape, sdt, present=present, **kw),
                 probe, cap, verdict)
         if self.mesh is not None:
-            from tclb_tpu.parallel.halo import make_sharded_pallas_iterate
-            # building this engine IS asking whether it takes the case,
-            # so it alone is built here.  Its generic flavour is probed,
-            # with nothing under it
-            it = make_sharded_pallas_iterate(model, self.mesh, shape,
-                                             self.dtype, present=present)
-            if it is None:
-                return []
-            return [EngineCandidate(
-                f"pallas_sharded[{dict(self.mesh.shape)},fuse={it.fuse}]",
-                lambda: it, probe=it.unproven)]
+            return self._sharded_chain(present)
         if not has_series and pallas_d2q9.covers(model, shape, sdt):
             chain = self._band_chain(cand, sampled, points)
             if chain:
@@ -1340,6 +1346,47 @@ class Lattice:
             band(fz, cap, f"pallas_generic[{name},fuse={fz},by<={cap}]",
                  probe=True, cap=cap, verdict=verdict(fz, cap))
             for fz, cap in rungs]
+
+    def _sharded_chain(self, present: set) -> list:
+        """:meth:`_build_fast`'s chain on a mesh.  Building the sharded
+        engine IS asking whether it takes the case, so the preferred one
+        is built here; a refusal says why (``fused_rejected``) and the
+        sharded XLA step runs.  The 2D generic flavour is probed, with
+        nothing under it.  A 3D shard's fused kernel compiles against
+        the raised scoped-VMEM ceiling, which the planner cannot prove:
+        it is probed, and under a plan of K >= 2 stands the same
+        kernel's K = 1 plan; where that fails too the chain has run out
+        (:meth:`_probe_first_call`: an ``engine_fallback`` and the
+        sharded XLA step off the TPU, an error on it)."""
+        from tclb_tpu.parallel import halo
+        model, mesh, shape = self.model, self.mesh, self.shape
+
+        def make(**kw):
+            return halo.make_sharded_pallas_iterate(
+                model, mesh, shape, self.dtype, present=present, **kw)
+
+        def tag(fuse: int, plan: Optional[tuple]) -> str:
+            # a plane the engine tiles says so: the rows of its bands
+            by = f",by={plan[1]}" if plan and plan[1] < shape[1] else ""
+            return f"pallas_sharded[{dict(mesh.shape)},fuse={fuse}{by}]"
+
+        it = make()
+        if it is None:
+            telemetry.event(
+                "fused_rejected", engine="pallas_sharded", model=model.name,
+                shape=list(shape), mesh=dict(mesh.shape),
+                reason=halo.why_no_sharded_pallas(model, mesh, shape,
+                                                  self.dtype))
+            return []
+        chain = [EngineCandidate(tag(it.fuse, it.plan), lambda: it,
+                                 probe=it.unproven)]
+        if it.plan is not None and it.fuse >= 2:
+            from tclb_tpu.ops import pallas_d3q
+            local = halo.band_shards(model, mesh, shape)[2]
+            chain.append(EngineCandidate(
+                tag(1, pallas_d3q.tile_plan(model, local, fuse=1)),
+                lambda: make(fuse=1), probe=True))
+        return chain
 
     def _band_chain(self, cand: Callable, sampled: bool, points) -> list:
         """The tuned 2D family's part of :meth:`_build_fast`'s chain for a
@@ -1888,7 +1935,8 @@ class Lattice:
             idxs.append(idx)
             planes.append(plane)
         self.state = dataclasses.replace(self.state, fields=_write_planes(
-            self.state.fields, tuple(planes), tuple(idxs)))
+            self.state.fields, tuple(planes), tuple(idxs),
+            None if self.mesh is None else self.state.fields.sharding))
         if self._place is not None:
             self.state, self.params = self._place()
 

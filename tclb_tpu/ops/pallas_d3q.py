@@ -43,7 +43,7 @@ computes that set from the host flag field.
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Iterable, Optional
+from typing import Callable, Iterable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -356,11 +356,14 @@ def choose_fuse(model: Model, shape, itemsize: int = 4) -> int:
 def supports(model: Model, shape, dtype, ext_halo: bool = False) -> bool:
     """Whether the fused 3D kernel can run this configuration.
 
-    ``ext_halo=True`` asks about the sharded building block, which only
-    has the block kernel — ring-only shapes (whose block working set
-    exceeds VMEM) and planes only :func:`tile_plan` takes must answer
-    False there so parallel/halo.py falls back cleanly instead of
-    building a kernel Mosaic will reject."""
+    ``ext_halo=True`` asks about the sharded building block: ``shape``
+    is one device's z-block, which the fused kernel advances on whole
+    planes where a single-step block kernel would hold them
+    (:func:`_slab_depth`) and on y-tiled windows where :func:`tile_plan`
+    takes the block.  Ring-only shapes (the rolling window wraps z
+    inside the array it reads) answer False there so parallel/halo.py
+    falls back cleanly instead of building a kernel Mosaic will
+    reject."""
     if model.name not in _SUPPORTED:
         return False
     if len(shape) != 3 or jnp.dtype(dtype) not in (
@@ -374,13 +377,65 @@ def supports(model: Model, shape, dtype, ext_halo: bool = False) -> bool:
         return False  # (ny, nx) is the (sublane, lane) tile
     if _slab_depth(model, nz, ny, nx, itemsize) is not None:
         return True
-    if ext_halo:
-        return False
-    return (_ring_ok(model, nz, ny, nx, itemsize)
+    return ((not ext_halo and _ring_ok(model, nz, ny, nx, itemsize))
             or tile_plan(model, shape, itemsize) is not None)
 
 
 present_types = lbm.present_types   # shared helper (re-exported)
+
+
+def window_account(model: Model, shape, plan: tuple, itemsize: int = 4
+                   ) -> dict:
+    """The windows one call of the fused kernel at ``plan`` =
+    ``(bz, by, K)`` cuts ``shape`` into (on a mesh: one shard's), under
+    the names an engine's account reports them by."""
+    nz, ny, nx = (int(s) for s in shape)
+    bz, by, K = plan
+    return dict(
+        z_bands=nz // bz, band_slabs=bz, halo_slabs=K,
+        y_bands=ny // by, band_rows=by,
+        halo_rows=_HALO_Y if by < ny else 0,
+        aux_planes=1,      # the int32 flag plane rides each window
+        # what the planner's account admitted the window at
+        vmem_bytes=_fused_vmem(model, ny, nx, bz, K, itemsize,
+                               by if by < ny else None))
+
+
+class ShardKernels(NamedTuple):
+    """``make_pallas_iterate(ext_halo=True)``: the fused kernel on one
+    z-block of a lattice split over devices.  ``call`` advances the
+    block ``plan[2]`` = K steps, ``rest`` one step (the same kernel
+    where K is 1); both are ``(settings, zone table, block, lower
+    neighbour's slabs, upper neighbour's, int32 flags extended by K
+    slabs a side) -> block``, the neighbours' slabs K of them for
+    ``call`` and one for ``rest``.  ``zonal_si``: the settings whose
+    zone-table rows make the zone table, in order."""
+
+    plan: tuple             # (bz, by, K) of ``call``
+    call: Callable
+    rest: Callable
+    zonal_si: tuple
+    account: dict           # :func:`window_account` of ``plan``
+
+
+class _EitherCopy:
+    """One of two copies into the same window on the same semaphore:
+    ``copy(a)`` where ``first`` holds, else ``copy(b)``; waited for
+    through ``copy(done)``, a copy of their size whose source indices
+    are static.  Each is made where it is used (a descriptor never
+    started nor waited for is an error to Pallas)."""
+
+    def __init__(self, first, copy: Callable, a, b, done):
+        self.first, self.copy = first, copy
+        self.a, self.b, self.done = a, b, done
+
+    def start(self) -> None:
+        pl.when(self.first)(lambda: self.copy(*self.a).start())
+        pl.when(jnp.logical_not(self.first))(
+            lambda: self.copy(*self.b).start())
+
+    def wait(self) -> None:
+        self.copy(*self.done).wait()
 
 
 def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
@@ -403,11 +458,14 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     (tests use it to exercise nz % (bz*K) != 0 layouts).
 
     ``ext_halo=True`` builds the sharded building block: ``shape`` is one
-    device's z-block, the input stack carries ONE exchanged halo slab at
-    each end ((ns, nz+2, ny, nx)) and the kernel reads those instead of
-    wrapping; returns ``(call, bz)`` for parallel/halo.py to compose with
-    ``ppermute``."""
-    if not supports(model, shape, dtype):
+    device's z-block and the kernels are the fused kernel's ``ext``
+    flavour (:func:`fused_call`), which takes the block as it is, the
+    two neighbours' K exchanged slabs as operands of their own
+    ((ns, K, ny, nx) each) and the int32 flags extended by K slabs a
+    side, and reads those where the one-chip kernel wraps z; y and x
+    wrap inside the block.  Returns a :class:`ShardKernels` for
+    parallel/halo.py to compose with ``ppermute``."""
+    if not supports(model, shape, dtype, ext_halo):
         raise ValueError(f"pallas path unsupported for {model.name} {shape}")
     # storage dtype (what HBM holds) vs compute dtype (what the collision
     # arithmetic runs in).  At f32 storage the casts below are traced
@@ -419,8 +477,6 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     itemsize = jnp.dtype(dtype).itemsize
     nz, ny, nx = (int(s) for s in shape)
     bz = _slab_depth(model, nz, ny, nx, itemsize) or 1
-    if ext_halo:
-        fuse = 1
     # a plane no single-step engine holds whole: the fused kernel on
     # (bz, by) windows does every step, the remainder at its K = 1 plan
     tiled = tile_plan(model, shape, itemsize, fuse, vmem_budget)
@@ -445,6 +501,12 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             cfg = (bzf, fuse)
     if cfg is not None and len(cfg) == 2:
         cfg = (cfg[0], ny, cfg[1])       # a whole plane is one y band
+    if ext_halo and tiled is None:
+        # every step of a shard goes through the fused kernel: on whole
+        # planes at K = 1 the steps a fused call leaves over, and every
+        # step where no fused plan wins
+        rem_cfg = (_deepest_band(model, nz, ny, nx, 1, itemsize), ny, 1)
+        cfg = cfg or rem_cfg
     K = cfg[2] if cfg else 1
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -566,7 +628,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         return jnp.where(coll[None], fc, f), None
 
     naux = len(aux_idx)
-    ring_mode = (not ext_halo) and _ring_ok(model, nz, ny, nx, itemsize)
+    ring_mode = _ring_ok(model, nz, ny, nx, itemsize)
 
     def kernel_ring(sett, f_hbm, flags_ref, zonal_ref, out_ref, ring, scra,
                     sems, sems_a):
@@ -693,18 +755,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
         def band_dmas(slot, band):
             base = band * jnp.int32(bz)
-            if ext_halo:
-                # input slabs are [halo(1) | local nz | halo(1)]: the band
-                # lives at base+1, halos at base and base+1+bz — no wrap,
-                # the exchanged slabs ARE the neighbors
-                mid1 = base + jnp.int32(1)
-                zm = base
-                zp = base + jnp.int32(1 + bz)
-            else:
-                mid1 = base
-                zm = jax.lax.rem(base - jnp.int32(1) + jnp.int32(nz),
-                                 jnp.int32(nz))
-                zp = jax.lax.rem(base + jnp.int32(bz), jnp.int32(nz))
+            mid1 = base
+            zm = jax.lax.rem(base - jnp.int32(1) + jnp.int32(nz),
+                             jnp.int32(nz))
+            zp = jax.lax.rem(base + jnp.int32(bz), jnp.int32(nz))
             copies = [
                 pltpu.make_async_copy(f_hbm.at[pl.ds(0, q), pl.ds(mid1, bz)],
                                       scrf.at[slot, :, pl.ds(1, bz)],
@@ -833,15 +887,17 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             name="d3q_slab_fuse1",
         )
 
-    if ext_halo:
-        # zonal_names rides along so callers stack the zonal planes in
-        # exactly the order this kernel's zonal_ref expects
-        return call, bz, zonal_names
-
-    def fused_call(bzK: int, byK: int, K: int):
+    def fused_call(bzK: int, byK: int, K: int, ext: int = 0):
         """The multi-step fused band kernel at one plan: K lattice steps
         per HBM round trip on windows of ``bzK`` slabs x ``byK`` rows
-        (``byK == ny``: whole planes, one y band)."""
+        (``byK == ny``: whole planes, one y band).  ``ext`` (slabs, 0:
+        the lattice on one chip, z periodic inside the array) builds the
+        flavour of one z-block of a lattice split over devices: beside
+        the block as it is, the lower and the upper neighbour's K slabs
+        are operands of their own ((ns, K, ny, nx): what a window's
+        halo reads past either end of the block), and the flags come
+        extended by ``ext >= K`` slabs a side; y still wraps inside the
+        block."""
         hy = _HALO_Y if byK < ny else 0
         H = bzK + 2 * K       # buffer depth: band + K wrapped halo slabs/side
         R = byK + 2 * hy      # buffer rows: band + hy wrapped halo rows/side
@@ -870,8 +926,32 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             return base if not off else jax.lax.rem(
                 base + jnp.int32(off + n), jnp.int32(n))
 
-        def kernel_fused(sett, ztab, f_hbm, flags_hbm, out_ref, scrf, scrg,
-                         sems):
+        def field_copy(f_hbm, halos, z0, oz: int, sz, lz: int, rows, dst,
+                       sem):
+            """The copy of ``lz`` slabs, ``oz`` from the band's first
+            (slab ``sz`` of a lattice on one chip), into the window
+            ``dst``.  With ``halos`` (the neighbours' K slabs below and
+            above the block) a halo piece comes from the block where it
+            lies inside it, else from the neighbour's slabs: a piece is
+            a block no longer than the band, or one slab, and never
+            straddles the block's end."""
+            def window(ref, z):
+                return pltpu.make_async_copy(
+                    ref.at[:, pl.ds(z, lz), rows], dst, sem)
+            if not halos:
+                return window(f_hbm, sz)
+            if not oz:
+                return window(f_hbm, z0)
+            zs = z0 + jnp.int32(oz)
+            if oz < 0:
+                inside, halo, zh = zs >= 0, halos[0], zs + jnp.int32(K)
+            else:
+                inside, halo, zh = (zs + jnp.int32(lz) <= nz, halos[1],
+                                    zs - jnp.int32(nz))
+            return _EitherCopy(inside, window, (f_hbm, zs), (halo, zh),
+                               (halo, 0))
+
+        def kernel_fused(sett, ztab, f_hbm, *refs):
             """The DMA'd buffer carries K wrapped halo slabs per side
             (f + aux stack AND flags — boundary dispatch in the halo
             region needs true node types so the recomputed halo sites
@@ -886,7 +966,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             in-kernel (fusion.zone_plane) — the same aux diet the generic
             engine runs.  The 2-slot double-buffered band pipeline is
             kept: the next band's (wider) blocks prefetch under this
-            band's K-step compute."""
+            band's K-step compute.  ``refs``: the flags, the out block
+            and the scratch, after the two neighbours' slabs where the
+            flavour is ``ext``."""
+            *halos, flags_hbm, out_ref, scrf, scrg, sems = refs
             i = pl.program_id(0)
             j = pl.program_id(1) if nyb > 1 else jnp.int32(0)
             t = i * jnp.int32(nyb) + j       # bands run z-major, y-minor
@@ -897,13 +980,16 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 copies = []
                 for oz, dz, lz in z_pieces:
                     for oy, dy_, ly in y_pieces:
-                        sz, sy = wrap(z0, oz, nz), wrap(y0, oy, ny)
+                        # the extended flags hold slab z at z + ext
+                        sz = (z0 + jnp.int32(oz + ext) if ext
+                              else wrap(z0, oz, nz))
+                        sy = wrap(y0, oy, ny)
                         if hy:      # bands and halos are whole sublane tiles
                             sy = pl.multiple_of(sy, _HALO_Y)
                         s = len(copies)
                         copies += [
-                            pltpu.make_async_copy(
-                                f_hbm.at[:, pl.ds(sz, lz), pl.ds(sy, ly)],
+                            field_copy(
+                                f_hbm, halos, z0, oz, sz, lz, pl.ds(sy, ly),
                                 scrf.at[slot, :, pl.ds(dz, lz),
                                         pl.ds(dy_, ly)],
                                 sems.at[slot, s]),
@@ -1005,12 +1091,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         return pl.pallas_call(
             lbm.mosaic_body(kernel_fused, interpret),
             grid=(nzb,) if nyb == 1 else (nzb, nyb),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+            + [pl.BlockSpec(memory_space=pl.ANY)] * (4 if ext else 2),
             out_specs=pl.BlockSpec(
                 (ns, bzK, byK, nx),
                 (lambda i: (0, i, 0, 0)) if nyb == 1
@@ -1028,6 +1110,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                 vmem_limit_bytes=_FUSED_VMEM_LIMIT),
             name=f"d3q_slab_fuse{K}",
         )
+
+    if ext_halo:
+        return ShardKernels(
+            cfg, fused_call(*cfg, ext=K), fused_call(*rem_cfg, ext=K),
+            tuple(zonal_si), window_account(model, shape, cfg, itemsize))
 
     call_f = fused_call(*cfg) if cfg else None
     # the steps a fused call leaves over: the single-step block/ring
@@ -1086,15 +1173,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         did = dict(kernel_calls=fused + rest, remainder_steps=rest,
                    paired_calls=paired_calls(fused, rest))
         if fused:
-            bzp, byp, _ = cfg
-            did.update(
-                z_bands=nz // bzp, band_slabs=bzp, halo_slabs=K,
-                y_bands=ny // byp, band_rows=byp,
-                halo_rows=_HALO_Y if byp < ny else 0,
-                aux_planes=1,      # the int32 flag plane rides each window
-                # what the planner's account admitted the window at
-                vmem_bytes=_fused_vmem(model, ny, nx, bzp, K, itemsize,
-                                       byp if byp < ny else None))
+            did.update(window_account(model, shape, cfg, itemsize))
         return did
 
     def iterate(state: LatticeState, params: SimParams, niter: int

@@ -278,38 +278,61 @@ def _streams(model: Model) -> int:
                    for st in model.actions["Iteration"]))
 
 
+def why_no_sharded_pallas(model: Model, mesh: Mesh, shape, dtype) -> str:
+    """Why :func:`make_sharded_pallas_iterate` refuses the case, for the
+    ``fused_rejected`` event of dispatch."""
+    shards = band_shards(model, mesh, shape)
+    if shards is None:
+        return (f"mesh: {dict(mesh.shape)} is not the model's, splits an "
+                f"axis other than the band axis, or does not divide "
+                f"{tuple(shape)}")
+    return (f"kernels: no Pallas family takes a shard {shards[2]} of "
+            f"{model.name} in {jnp.dtype(dtype).name} with exchanged halos "
+            "(3D: neither whole planes nor a y-tiled window fits)")
+
+
 def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                                 dtype=jnp.float32,
                                 present: Optional[set] = None,
-                                interpret: Optional[bool] = None
+                                interpret: Optional[bool] = None,
+                                fuse: Optional[int] = None,
+                                vmem_budget: Optional[int] = None
                                 ) -> Optional[Callable]:
     """Fused Pallas fast path over the device mesh, or None if this
     configuration can't run it.
 
     The band axis of the kernels (y in 2D, z in 3D) is the sharded axis;
-    x (and y in 3D) must be unsplit.  Each kernel call exchanges an 8-row
-    (2D, Mosaic tile granularity) or 1-slab (3D) halo via ``ppermute``
+    x (and y in 3D) must be unsplit.  Each kernel call exchanges a halo
+    via ``ppermute`` (8 rows in 2D, Mosaic's tile granularity; in 3D the
+    K slabs a call of K fused steps reads past either end of the shard)
     and runs the per-shard band kernel — the TPU composition of the
     reference's RunBorder / MPIStream_A / RunInterior / MPIStream_B
     overlap pipeline (src/Lattice.cu.Rt:424-456), with XLA's
     latency-hiding scheduler providing the overlap.  The tuned 2D mode
-    (``pallas_d2q9``, two steps a call) hands the kernel the shard as it
-    is and the neighbours' two 8-row blocks as operands of their own
+    (``pallas_d2q9``, two steps a call) and the 3D mode (``pallas_d3q``'s
+    fused kernel, whole planes or y-tiled windows, K steps a call;
+    ``fuse`` pins K, ``vmem_budget`` is the planner's, for tests) hand
+    the kernel the shard as it is and the
+    neighbours' two blocks as operands of their own
     (:func:`_halo_blocks`), two calls a loop body: nothing of the
-    shard's size is written between two calls.  The generic 2D and the
-    3D mode still run their kernel on the extended block
+    shard's size is written between two calls.  The 3D mode runs the
+    steps ``niter % K`` leaves over through the same kernel at its K = 1
+    plan, one slab a side, in the same program; the int32 flags it
+    extends by K slabs a side once an ``iterate``.  The generic 2D mode
+    still runs its kernel on the extended block
     (:func:`_exchange_axis`'s padded copy), one call a body.
 
     Like the single-device fast path this is the "NoGlobals"
     specialization: ``globals_`` is zeroed; the Lattice hybrid's trailing
     step supplies them: :func:`make_sharded_pallas_tail` where it takes
     the case, else the sharded XLA step (both psum)."""
-    from tclb_tpu.ops import fusion, pallas_d2q9, pallas_d3q
+    from tclb_tpu.ops import pallas_d2q9, pallas_d3q
     from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
     shards = band_shards(model, mesh, shape)
     if shards is None:
         return None
     axis, n, local = shards
+    itemsize = jnp.dtype(dtype).itemsize
 
     mode = None
     if model.ndim == 2:
@@ -337,21 +360,33 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
     else:
         if not pallas_d3q.supports(model, local, dtype, ext_halo=True):
             return None
-        call3, bz, zonal_names = pallas_d3q.make_pallas_iterate(
-            model, local, dtype, interpret=interpret, present=present,
-            ext_halo=True)
-        si = model.setting_index
-        zonal_si = [si[nm] for nm in zonal_names]
-        width = 1
+        try:
+            k3 = pallas_d3q.make_pallas_iterate(
+                model, local, dtype, interpret=interpret, present=present,
+                ext_halo=True, fuse=fuse,
+                **({} if vmem_budget is None
+                   else dict(vmem_budget=vmem_budget)))
+        except ValueError:
+            return None         # no plan at the pinned ``fuse``
+        mode = "fused3d"
+        width = k3.plan[2]
     zshift = model.zone_shift
-    steps = 2 if mode == "tuned2d" else 1     # a kernel call of the loop
-    # bytes one chip sends per exchange of one plane and of the fields;
-    # the planes of the aux stack, which is exchanged once per call
-    plane_bytes = _exchange_bytes((1,) + local, 1, width, n, 1,
-                                  jnp.dtype(dtype).itemsize)
-    field_bytes = model.n_storage * plane_bytes
-    aux_planes = (3 if mode == "tuned2d"
-                  else 1 + len(gz_si) if mode == "generic2d" else 0)
+    # steps a kernel call of the loop advances
+    steps = 2 if mode == "tuned2d" else width if mode == "fused3d" else 1
+
+    def sent(w: int, itemsize: int) -> int:
+        """Bytes one chip sends in one exchange of ``w`` cells of one
+        plane along the band axis."""
+        return _exchange_bytes((1,) + local, 1, w, n, 1, itemsize)
+
+    # per exchange of the fields by a call of the loop and by a call
+    # left over (3D: one slab a side); the aux stack, which is exchanged
+    # once per call of ``iterate`` (3D: the int32 flags)
+    field_bytes = model.n_storage * sent(width, itemsize)
+    rest_bytes = model.n_storage * sent(
+        1 if mode == "fused3d" else width, itemsize)
+    aux_bytes = (sent(width, 4) if mode == "fused3d" else sent(
+        width, itemsize) * (3 if mode == "tuned2d" else 1 + len(gz_si)))
 
     def exch(arr):
         """Prepend/append ``width`` halo rows/slabs from the torus
@@ -364,15 +399,16 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
 
     def split(niter: int) -> tuple:
         """``niter`` steps as the trips of the loop (calls of two fused
-        steps in the tuned 2D mode, of one elsewhere) and the odd call
-        after it."""
-        return divmod(niter, 2) if mode == "tuned2d" else (niter, 0)
+        steps in the tuned 2D mode, of K in the 3D mode, of one in the
+        generic) and the steps left over, one kernel call each."""
+        return (niter, 0) if mode == "generic2d" else divmod(niter, steps)
 
     @lru_cache(maxsize=None)
     def _program(trips: int, odd: int):
-        """The jitted program of a loop of ``trips`` kernel calls or of
-        the ``odd`` call after it.  Two programs (:func:`iterate`), not
-        one: with the one-step kernel in the loop's program the compiler
+        """The jitted program of a loop of ``trips`` kernel calls and
+        the ``odd`` one-step calls after it.  In the tuned 2D mode two
+        programs (:func:`iterate`), not one: with the one-step kernel in
+        the loop's program the compiler
         keeps one of the loop's two state buffers, or both, out of its
         fast memory at 11 x 1024 x 1024, and the ``kernel2`` that waited
         for its input copies took 308 to 380 us a call on a state it read
@@ -385,9 +421,16 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             zones = flags_i32 >> zshift
             sett = params.settings.astype(dtype)
             fields = state.fields
-            # the generic 2D and the 3D loop are single, paired=False:
-            # their body builds the padded operand anew, which is the
-            # copy of the carry a single call a body needs (ROADMAP S9)
+            def halos(f, w=width):
+                """The neighbours' ``w`` rows or slabs, the kernels'
+                operands beside the shard as it is: no padded copy of
+                it."""
+                with jax.named_scope("halo_exchange"):
+                    return _halo_blocks(f, axis, 1, w, n)
+
+            # the generic 2D loop is single, paired=False: its body
+            # builds the padded operand anew, which is the copy of the
+            # carry a single call a body needs (ROADMAP S9)
             if mode == "generic2d":
                 aux_ext = exch(_generic_aux(params, flags_i32, zones,
                                             gz_si, dtype))
@@ -402,13 +445,6 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
             elif model.ndim == 2:
                 vel, den = pallas_d2q9.zonal_planes(
                     model, params, zones, dtype)
-
-                def halos(f):
-                    """The neighbours' 8 rows, the kernels' operands
-                    beside the shard as it is: no padded copy of it."""
-                    with jax.named_scope("halo_exchange"):
-                        return _halo_blocks(f, axis, 1, width, n)
-
                 if trips:
                     aux_ext = exch(jnp.stack(
                         [flags_i32.astype(dtype), vel, den]))
@@ -425,14 +461,20 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                     fields = call1(sett, fields, *halos(fields), flags_i32,
                                    vel, den)
             else:
-                zonal = jnp.stack([fusion.zone_plane(
-                    params.zone_table[j].astype(dtype), zones)
-                    for j in zonal_si])
+                ztab = jnp.concatenate(
+                    [params.zone_table[j].astype(dtype)
+                     for j in k3.zonal_si])
+                flags_ext = exch(flags_i32[None])[0]
 
-                def body3(f, _):
-                    return call3(sett, exch(f), flags_i32, zonal), None
+                def body3(call, w):
+                    return lambda f, _: (
+                        call(sett, ztab, f, *halos(f, w), flags_ext), None)
 
-                fields = scan_calls(body3, fields, trips, False)
+                # paired, as the one-chip engine's loops are: two calls
+                # a body, so that XLA copies no carry before a call
+                fields = scan_calls(body3(k3.call, width), fields, trips,
+                                    True)
+                fields = scan_calls(body3(k3.rest, 1), fields, odd, True)
             return LatticeState(
                 fields=fields,
                 flags=state.flags,
@@ -451,24 +493,36 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                 "pallas iterate does not support Control time series")
         trips, odd = split(int(niter))
         out = state
-        if trips or not odd:
-            out = _program(trips, 0)(out, params)
-        if odd:
-            out = _program(0, odd)(out, params)
+        if mode == "fused3d":
+            out = _program(trips, odd)(out, params)
+        else:
+            if trips or not odd:
+                out = _program(trips, 0)(out, params)
+            if odd:
+                out = _program(0, odd)(out, params)
         if telemetry.enabled():
             # counted host-side from the shapes: one exchange of the
             # fields per kernel call (a fused pair of steps in the tuned
-            # 2D mode) plus the aux stack once per call; the wall time is
-            # the enclosing span's business
-            _count_halo(int(niter), sum(split(int(niter))) * field_bytes
-                        + aux_planes * plane_bytes)
+            # 2D mode, K slabs a side for K fused steps in 3D and one
+            # for a step left over) plus the aux stack (3D: the int32
+            # flags, K slabs a side) once per call; the wall time is the
+            # enclosing span's business
+            _count_halo(int(niter), trips * field_bytes
+                        + odd * rest_bytes + aux_bytes)
         return out
 
     def account(niter: int, has_series: bool = False) -> dict:
-        """One call's kernel calls, those its two-call loop body issues,
-        and the halo rows a side the kernel takes as operands of their
-        own (0 where the mode pads the shard round them)."""
+        """One call's kernel calls, those its two-call loop bodies
+        issue, and the halo rows (2D) or slabs (3D) a side the kernel
+        takes as operands of their own (0 where the mode pads the shard
+        round them); in 3D the steps left over, the shards, and the
+        windows the fused kernel cuts ONE shard into."""
         trips, odd = split(niter)
+        if mode == "fused3d":
+            return dict(kernel_calls=trips + odd,
+                        paired_calls=paired_calls(trips, odd),
+                        remainder_steps=odd, shards=n, **k3.account,
+                        halo_operand_slabs=width)
         tuned = mode == "tuned2d"
         return dict(kernel_calls=trips + odd,
                     paired_calls=paired_calls(trips) if tuned else 0,
@@ -478,8 +532,10 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
     # dispatch probes its first call and falls back to the sharded XLA
     # engine on a Mosaic lowering failure.  fuse: steps per kernel call,
     # for the engine tag.  impl: the jitted programs, for the compile tests
-    return Engine(iterate, account, unproven=(mode == "generic2d"),
-                  fuse=steps,
+    # plan: the 3D mode's windows of one shard, (bz, by, K), for the tag
+    return Engine(iterate, account,
+                  unproven=mode in ("generic2d", "fused3d"), fuse=steps,
+                  plan=k3.plan if mode == "fused3d" else None,
                   impl=dict(program=_program))
 
 
@@ -489,8 +545,11 @@ def make_sharded_pallas_tail(model: Model, mesh: Mesh, shape,
                              interpret: Optional[bool] = None):
     """The one step a hybrid engine leaves for the Globals, on a y-split
     2D mesh: an :class:`Engine` of ``iterate(state, params, 1)``, or None
-    where this configuration can't run it (a 3D model, whose generic
-    engine has no ``ext_halo`` mode; a mesh split in x; shards of no
+    where this configuration can't run it (a 3D model: the generic
+    slab engine, the only 3D kernel that reduces Globals, has no
+    ``ext_halo`` mode, so on a 3D mesh the trailing step stays the
+    sharded XLA step, :func:`make_sharded_iterate`, whatever engine runs
+    the steps before it; a mesh split in x; shards of no
     multiple of 8 rows; storage other than f32; a model or local shape
     ``pallas_generic`` refuses; Globals its kernel does not reduce).
 

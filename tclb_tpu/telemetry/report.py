@@ -430,7 +430,8 @@ def _setup_summary(evts: list[dict]) -> dict:
         depth, up = 0, by_id.get(e.get("parent"))
         while up is not None and up["name"] == "startup.element":
             depth, up = depth + 1, by_id.get(up.get("parent"))
-        elements.append({**row(e, "element", "nodes", "zones"),
+        elements.append({**row(e, "element", "nodes", "zones", "sharded",
+                               "bytes"),
                          "depth": depth})
     if elements:
         out["elements"] = elements
@@ -461,12 +462,24 @@ def _setup_summary(evts: list[dict]) -> dict:
     return out
 
 
+# what an engine's account puts on ``iterate.fused``: counts of one
+# call, which add up over a run, and the plan of its kernel's windows (on
+# a mesh: of ONE shard), which does not change
+ACCOUNT_SUMS = ("kernel_calls", "paired_calls", "remainder_steps",
+                "resident_calls", "halo_bytes")
+ACCOUNT_PLAN = ("shards", "z_bands", "band_slabs", "halo_slabs", "y_bands",
+                "band_rows", "halo_rows", "aux_planes", "bands",
+                "halo_operand_rows", "halo_operand_slabs", "vmem_bytes",
+                "vmem_limit_bytes")
+
+
 def summarize(evts: list[dict]) -> dict:
     """Aggregate one trace into the report structure (all plain dicts,
     JSON-serializable as-is)."""
     spans: dict[str, dict] = {}
     engines: dict[str, dict] = {}
     tails: dict[str, dict] = {}
+    accounts: dict[str, dict] = {}
     selected: list[dict] = []
     fallbacks: list[dict] = []
     failchecks: list[dict] = []
@@ -509,6 +522,16 @@ def summarize(evts: list[dict]) -> dict:
                 g["node_updates"] += (float(e.get("nodes", 0.0))
                                       * float(e.get("iters", 0)))
                 g["total_s"] += dt
+            elif name == "iterate.fused" and "kernel_calls" in e:
+                # the engine's own account of its calls
+                # (Lattice._run_engine): the counts add up, the plan of
+                # its windows is the newest call's
+                g = accounts.setdefault(e.get("engine", "?"), {"calls": 0})
+                g["calls"] += 1
+                for k in ACCOUNT_SUMS:
+                    if k in e:
+                        g[k] = g.get(k, 0) + e[k]
+                g.update({k: e[k] for k in ACCOUNT_PLAN if k in e})
             elif name == "iterate.globals_step":
                 # the step a hybrid engine leaves for the Globals, by
                 # the engine that ran it (a trace from before the span
@@ -544,7 +567,8 @@ def summarize(evts: list[dict]) -> dict:
             g["mlups"] = None
         g["total_s"] = round(g["total_s"], 6)
         del g["node_updates"]
-    return {"engines": engines, "globals_steps": tails, "spans": spans,
+    return {"engines": engines, "globals_steps": tails,
+            "accounts": accounts, "spans": spans,
             "segments": _segments_summary(evts),
             "setup": _setup_summary(evts),
             "serving": _serving_summary(evts),
@@ -888,6 +912,8 @@ def _format_setup(su: dict) -> list:
         name = "  " * r["depth"] + str(r.get("element"))
         extra = (f"  nodes {r['nodes']} zones {r.get('zones')}"
                  if "nodes" in r else "")
+        if "bytes" in r:    # an initial field, and whether made in shards
+            extra += f"  sharded {r.get('sharded')} bytes {r['bytes']}"
         lines.append(f"  element  {name:<44} "
                      f"{_fmt(r['seconds'], 3):>9}{extra}")
     for r in su.get("engine_build", []):
@@ -943,6 +969,18 @@ def format_text(summary: dict) -> str:
         for eng, g in sorted(summary["globals_steps"].items()):
             lines.append(f"  {eng:<44} {g['steps']:>6} "
                          f"{_fmt(g['total_s'], 4):>10}")
+        lines.append("")
+    if summary.get("accounts"):
+        lines.append("fused calls by engine (the engine's account on "
+                     "iterate.fused; windows of one shard)")
+        for eng, g in sorted(summary["accounts"].items()):
+            lines.append(f"  {eng}")
+            lines.append("      " + "  ".join(
+                f"{k} {g[k]}" for k in ("calls",) + ACCOUNT_SUMS
+                if k in g))
+            plan = "  ".join(f"{k} {g[k]}" for k in ACCOUNT_PLAN if k in g)
+            if plan:
+                lines.append("      " + plan)
         lines.append("")
     if summary["spans"]:
         lines.append("spans")

@@ -230,12 +230,14 @@ def off_launch_thread(name: str) -> None:
     events._span_local.prefix = name + "/"
 
 
-def annotate(**fields: Any) -> None:
-    """Add fields to the innermost span open on this thread, so that a
-    callee can say what it did without a span of its own; nothing when
-    telemetry is disabled or no span is open."""
+def annotate(on: Optional[str] = None, **fields: Any) -> None:
+    """Add fields to the innermost span open on this thread (with ``on``:
+    the innermost of that name), so that a callee can say what it did
+    without a span of its own; nothing when telemetry is disabled or no
+    such span is open."""
     if not events.enabled():
         return
-    stack = events.span_stack()
-    if stack:
-        stack[-1].add(**fields)
+    for sp in reversed(events.span_stack()):
+        if on is None or sp.name == on:
+            sp.add(**fields)
+            return

@@ -1008,3 +1008,78 @@ def test_tail_engine_is_one_call_and_copies_no_state(one_chip, monkeypatch,
         assert len(_state_copies(donated.lower(
             state, params).compile().as_text().splitlines(),
             m, shape)) == 1
+
+
+def _tgv_384_on_4x1x1_mesh(topo) -> tuple:
+    """``example/tgv_384.xml`` on a z-split mesh of four of the described
+    chips, by shapes only (the lattice is 7.7 GB): the model, the mesh,
+    and the shapes of its state and parameters with their shardings."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tclb_tpu.core.lattice import LatticeState
+    from tclb_tpu.parallel import halo
+    shape = (384, 384, 384)
+    m = get_model("d3q27_cumulant")
+    small = Lattice(m, (8, 8, 128), dtype=jnp.float32,
+                    settings={"nu": 0.00191, "Velocity": 0.05})
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1, 1),
+                ("z", "y", "x"))
+
+    def on(x, spec, dims=None):
+        return jax.ShapeDtypeStruct(dims or x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    st, specs = small.state, halo._state_specs(mesh)
+    state = LatticeState(
+        fields=on(st.fields, specs.fields, (m.n_storage,) + shape),
+        flags=on(st.flags, specs.flags, shape),
+        globals_=on(st.globals_, P()), iteration=on(st.iteration, P()))
+    params = jax.tree.map(lambda x: on(x, P()), small.params)
+    return m, mesh, shape, state, params
+
+
+@pytest.mark.parametrize("fuse,niter", [(None, 249), (1, 5)],
+                         ids=["fuse3", "fuse1"])
+def test_sharded_d3q27_cumulant_384_on_4x1x1_mesh(topo, fuse, niter):
+    """The four-chip path of the cell ``tgv384.zsplit``: the engine's own
+    jitted program for the 249 engine steps of ``Lattice.iterate(250)``,
+    donating its state, compiled for a z-split mesh of the described
+    topology's devices at shards of 96 x 384 x 384.  No kernel holds a
+    384 x 384 plane whole: the shard's plan is y-tiled, (3, 24, 3), 83
+    calls of three steps and none left over, and under it in dispatch's
+    chain stands the K = 1 plan (3, 48, 1).  The loop's body holds two
+    calls of the fused kernel and four ``collective-permute`` of the
+    neighbours' (34, K, 384, 384) slabs, which the kernel takes as
+    operands of their own: nothing of the shard's size is padded,
+    concatenated or copied inside the ``while``."""
+    from tclb_tpu.parallel import halo
+    m, mesh, shape, state, params = _tgv_384_on_4x1x1_mesh(topo)
+    local = (96, 384, 384)
+    assert pallas_d3q._slab_depth(m, *local) is None
+    assert pallas_d3q.tile_plan(m, local) == (3, 24, 3)
+    assert pallas_d3q.tile_plan(m, local, fuse=1) == (3, 48, 1)
+    it = halo.make_sharded_pallas_iterate(m, mesh, shape, jnp.float32,
+                                          present={"MRT"}, interpret=False,
+                                          fuse=fuse)
+    K = fuse or 3
+    assert it is not None and it.fuse == K and it.unproven
+    assert it.plan == pallas_d3q.tile_plan(m, local, fuse=fuse)
+    trips, rest = divmod(niter, K)
+    did = it.account(niter)
+    assert (did["kernel_calls"], did["remainder_steps"], did["shards"],
+            did["halo_operand_slabs"]) == (trips + rest, rest, 4, K)
+    assert did["paired_calls"] == trips - trips % 2
+    lowered = it.impl["program"](trips, rest).lower(state, params)
+    assert lowered.args_info[0][0].fields.donated
+    text = lowered.compile().as_text()
+    assert "halo_exchange/" in text
+    body, calls = _kernel_loop_body(text, f"d3q_slab_fuse{K}")
+    assert calls == 2
+    permutes = [line for line in body
+                if re.search(r"= \(.*\) collective-permute-start\(", line)]
+    assert len(permutes) == 4
+    assert all(line.split("= (")[1].startswith(f"f32[34,{K},384,384]")
+               for line in permutes)
+    made = [line.strip() for line in body
+            if re.search(r"= f32\[34,(9\d|1\d\d),384,384\]\S* (?!custom-call|"
+                         r"get-tuple-element|parameter)", line)]
+    assert not made, made

@@ -489,14 +489,15 @@ def test_channel_plan_slab_by_slab_halo(name, bz):
     ((512, 48, 256), (4, 3), True),      # channel3d512: (2, 3) until PR 41
     ((48, 48, 256), (4, 3), True),       # 3d_channel.xml: (3, 2) until then
     ((256, 128, 128), (2, 3), False),    # a ring-only plane, whole: (2, 2)
-    ((256, 256, 256), (4, 32, 3), False),     # tgv256: tiled, as it was
-    ((128, 128, 256), (4, 32, 3), False)])
+    ((256, 256, 256), (4, 32, 3), True),      # tgv256: tiled, as it was
+    ((128, 128, 256), (4, 32, 3), True)])
 def test_planner_keeps_whole_planes_and_tiles_the_rest(shape, want, ext):
     """Where a whole plane fits a single-step kernel the plan is the one
     ``PERF.md`` records (section 6, PR 41: planned with the temporaries
     Mosaic holds), to the tuple; where none does the tiled plan is what
     it was and passes the planner's own VMEM account; the sharded
-    building block stays whole-plane."""
+    building block (a z-shard of this shape) takes whole planes and,
+    since PR 53, tiled windows, and only the ring-only plane not."""
     m = get_model("d3q27_cumulant")
     nz, ny, nx = shape
     assert pallas_d3q.supports(m, shape, jnp.float32)

@@ -546,8 +546,8 @@ def one_chip(s: Smoke) -> None:
 def four_chips(s: Smoke) -> None:
     s.phase("sharded_4x1", s.sharded, "karman_4096.xml", "4x1")
     # a 3D box split in z, its 256 x 256 plane tiled in y on every
-    # shard: the fused kernel on the neighbours' exchanged slabs against
-    # the sharded XLA step
+    # shard: the fused kernel on the neighbours' exchanged slabs, and the
+    # last step on the sharded Pallas tail, against the sharded XLA step
     s.phase("agree_zsplit_4x1x1", s.agree, "tgv_384.xml",
             ("pallas_sharded[{'z': 4, 'y': 1, 'x': 1},fuse=",),
             AGREE_STEPS, ZSPLIT_BOX, "4x1x1")

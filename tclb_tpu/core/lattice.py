@@ -1448,8 +1448,9 @@ class Lattice:
         between an XLA pad and a slice of the whole state, and beside a
         resident engine it is one more band call among the ``n % 8``
         steps that engine accounts for; and on a mesh the sharded tail
-        cannot take: a 3D one, one split in x, shards of no multiple of
-        8 rows.  The same for every model and dimension: it is one
+        cannot take: one split in x (in 3D: or in y), 2D shards of no
+        multiple of 8 rows.  The same for every model and dimension: it
+        is one
         algorithm, one step that reduces Globals.  Off a mesh a
         ``<Control>`` series never gets here (it keeps the tuned engines
         out of the chain); on one it keeps the XLA engine for every

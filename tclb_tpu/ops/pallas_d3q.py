@@ -56,7 +56,7 @@ from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.lattice import LatticeState, SimParams
 from tclb_tpu.core.registry import Model
 from tclb_tpu.models import family
-from tclb_tpu.ops import cumulant, fusion, lbm
+from tclb_tpu.ops import cumulant, fusion, lbm, slab_dma
 from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
 
 _SUPPORTED = ("d3q27_BGK", "d3q27_BGK_galcor", "d3q27_cumulant",
@@ -416,26 +416,6 @@ class ShardKernels(NamedTuple):
     rest: Callable
     zonal_si: tuple
     account: dict           # :func:`window_account` of ``plan``
-
-
-class _EitherCopy:
-    """One of two copies into the same window on the same semaphore:
-    ``copy(a)`` where ``first`` holds, else ``copy(b)``; waited for
-    through ``copy(done)``, a copy of their size whose source indices
-    are static.  Each is made where it is used (a descriptor never
-    started nor waited for is an error to Pallas)."""
-
-    def __init__(self, first, copy: Callable, a, b, done):
-        self.first, self.copy = first, copy
-        self.a, self.b, self.done = a, b, done
-
-    def start(self) -> None:
-        pl.when(self.first)(lambda: self.copy(*self.a).start())
-        pl.when(jnp.logical_not(self.first))(
-            lambda: self.copy(*self.b).start())
-
-    def wait(self) -> None:
-        self.copy(*self.done).wait()
 
 
 def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
@@ -903,53 +883,9 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         R = byK + 2 * hy      # buffer rows: band + hy wrapped halo rows/side
         nzb, nyb = nz // bzK, ny // byK
 
-        def pieces(band: int, halo: int):
-            """(offset from the band's first index, buffer index, length)
-            of a band and its wrapped halos along one axis.  A halo no
-            longer than the band (which divides the axis) never straddles
-            the periodic seam and goes as one block; a longer one index
-            by index."""
-            if not halo:
-                return [(0, 0, band)]
-            if band >= halo:
-                return [(0, halo, band), (-halo, 0, halo),
-                        (band, halo + band, halo)]
-            return [(0, halo, band)] + [
-                p for h in range(1, halo + 1)
-                for p in ((-h, halo - h, 1),
-                          (band - 1 + h, halo + band - 1 + h, 1))]
-
-        z_pieces, y_pieces = pieces(bzK, K), pieces(byK, hy)
-
-        def wrap(base, off: int, n: int):
-            """``base + off`` on a periodic axis of ``n``."""
-            return base if not off else jax.lax.rem(
-                base + jnp.int32(off + n), jnp.int32(n))
-
-        def field_copy(f_hbm, halos, z0, oz: int, sz, lz: int, rows, dst,
-                       sem):
-            """The copy of ``lz`` slabs, ``oz`` from the band's first
-            (slab ``sz`` of a lattice on one chip), into the window
-            ``dst``.  With ``halos`` (the neighbours' K slabs below and
-            above the block) a halo piece comes from the block where it
-            lies inside it, else from the neighbour's slabs: a piece is
-            a block no longer than the band, or one slab, and never
-            straddles the block's end."""
-            def window(ref, z):
-                return pltpu.make_async_copy(
-                    ref.at[:, pl.ds(z, lz), rows], dst, sem)
-            if not halos:
-                return window(f_hbm, sz)
-            if not oz:
-                return window(f_hbm, z0)
-            zs = z0 + jnp.int32(oz)
-            if oz < 0:
-                inside, halo, zh = zs >= 0, halos[0], zs + jnp.int32(K)
-            else:
-                inside, halo, zh = (zs + jnp.int32(lz) <= nz, halos[1],
-                                    zs - jnp.int32(nz))
-            return _EitherCopy(inside, window, (f_hbm, zs), (halo, zh),
-                               (halo, 0))
+        z_pieces = slab_dma.pieces(bzK, K)
+        y_pieces = slab_dma.pieces(byK, hy)
+        wrap = slab_dma.wrap
 
         def kernel_fused(sett, ztab, f_hbm, *refs):
             """The DMA'd buffer carries K wrapped halo slabs per side
@@ -987,12 +923,17 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
                         if hy:      # bands and halos are whole sublane tiles
                             sy = pl.multiple_of(sy, _HALO_Y)
                         s = len(copies)
+                        rows = pl.ds(sy, ly)
+                        dst = scrf.at[slot, :, pl.ds(dz, lz), pl.ds(dy_, ly)]
+
+                        def window(ref, z, lz=lz, rows=rows, dst=dst,
+                                   sem=sems.at[slot, s]):
+                            return pltpu.make_async_copy(
+                                ref.at[:, pl.ds(z, lz), rows], dst, sem)
+
                         copies += [
-                            field_copy(
-                                f_hbm, halos, z0, oz, sz, lz, pl.ds(sy, ly),
-                                scrf.at[slot, :, pl.ds(dz, lz),
-                                        pl.ds(dy_, ly)],
-                                sems.at[slot, s]),
+                            slab_dma.field_copy(window, f_hbm, halos, z0,
+                                                oz, sz, lz, nz, K),
                             pltpu.make_async_copy(
                                 flags_hbm.at[pl.ds(sz, lz), pl.ds(sy, ly)],
                                 scrg.at[slot, pl.ds(dz, lz), pl.ds(dy_, ly)],

@@ -51,7 +51,7 @@ from tclb_tpu.core import shift as ddf
 from tclb_tpu.core.lattice import (LatticeState, NodeCtx, SimParams,
                                    series_dt_overrides, series_overrides)
 from tclb_tpu.core.registry import Model
-from tclb_tpu.ops import fusion, lbm
+from tclb_tpu.ops import fusion, lbm, slab_dma
 from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls, tap
 from tclb_tpu.ops.lbm import present_types  # noqa: F401  (re-export)
 
@@ -101,6 +101,23 @@ def kernel_reduces_globals(model: Model, nx: int) -> bool:
     partial sums, a row a Global, over rows of whole lane tiles."""
     return (0 < model.n_globals <= 8 and nx % 128 == 0
             and all(g.op == "SUM" for g in model.globals_))
+
+
+def _shard_calls(model: Model, nx: int, fuse: int, mk_call: Callable
+                 ) -> tuple:
+    """``(call, call_g)`` of an ``ext_halo`` building block from its
+    kernel flavours ``mk_call(with_globals=)``: the NoGlobals call, and
+    (``fuse`` 1 and :func:`kernel_reduces_globals`, else None) one step
+    of the in-kernel-globals flavour, ``-> (fields, globals)`` with the
+    block's lanes summed."""
+    call_g = None
+    if fuse == 1 and kernel_reduces_globals(model, nx):
+        kernel_g = mk_call(with_globals=True)
+
+        def call_g(*operands):
+            fields, gpart = kernel_g(*operands)
+            return fields, _lane_sum(model, gpart)
+    return mk_call(), call_g
 
 
 def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
@@ -818,14 +835,16 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     in-kernel-globals flavour, ``-> (fields, globals)`` with the block's
     lanes summed.  The block has no ghost rows and the accumulated planes
     are its own ``by`` rows, never the halo rows: a shard's Globals are
-    the sums over its own nodes, which the composer ``psum``s."""
+    the sums over its own nodes, which the composer ``psum``s.  A 3D
+    model's block is one device's z-block, which comes as it is with the
+    neighbours' slabs beside it (:func:`make_pallas_iterate_3d`, which
+    says its operands; ``fuse`` 1 only)."""
     if model.ndim == 3:
-        if ext_halo:
-            raise ValueError("3d generic engine has no ext_halo mode")
         return make_pallas_iterate_3d(model, shape, dtype,
                                       interpret=interpret, present=present,
                                       fuse=fuse, by_cap=by_cap,
-                                      shift=shift, points=points)
+                                      shift=shift, points=points,
+                                      ext_halo=ext_halo)
     if not supports(model, shape, dtype, probe=False):
         raise ValueError(f"pallas_generic unsupported: {model.name} {shape}")
     cdtype = _COMPUTE_DTYPE
@@ -1063,14 +1082,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     if ext_halo:
         # the sharded building block keeps the full-aux convention: the
         # halo composer assembles + exchanges aux planes host-side
-        call_g = None
-        if fuse == 1 and kernel_reduces_globals(model, nx):
-            kernel_g = _mk_call(plan, with_globals=True)
-
-            def call_g(*operands):
-                fields, gpart = kernel_g(*operands)
-                return fields, _lane_sum(model, gpart)
-        return _mk_call(plan), call_g, by, zonal_names
+        return (*_shard_calls(model, nx, fuse, partial(_mk_call, plan)),
+                by, zonal_names)
 
     plan1 = plan if fuse == 1 \
         else action_plan(model, "Iteration", fuse=1)[0]
@@ -1412,9 +1425,8 @@ def _reach_y(model: Model, fuse: int) -> int:
                       for s in model.actions["Iteration"])
 
 
-def _window_fits(model: Model, nx: int, bz: int, rows: int, by: int,
-                 plan: list, reach: int, itemsize: int = 4,
-                 budget: int = _TILED3D_BUDGET) -> bool:
+def _window_vmem(model: Model, nx: int, bz: int, rows: int, by: int,
+                 plan: list, reach: int, itemsize: int = 4) -> int:
     """VMEM account of a tiled window of ``bz`` slabs x ``by`` rows
     (``rows`` with its halo rows) under an action ``plan`` of ``reach``
     halo slabs: the double-slotted state + aux scratch (the series
@@ -1426,7 +1438,15 @@ def _window_fits(model: Model, nx: int, bz: int, rows: int, by: int,
     out = 2 * ns * itemsize * bz * by * nx
     widest = bz + 2 * max(ext for _, ext in plan)
     temp = _TILE3D_TEMP_PLANES * ns * widest * rows * nx * 4
-    return scratch + out + temp <= budget
+    return scratch + out + temp
+
+
+def _window_fits(model: Model, nx: int, bz: int, rows: int, by: int,
+                 plan: list, reach: int, itemsize: int = 4,
+                 budget: int = _TILED3D_BUDGET) -> bool:
+    """Whether :func:`_window_vmem` of the window is within ``budget``."""
+    return _window_vmem(model, nx, bz, rows, by, plan, reach,
+                        itemsize) <= budget
 
 
 def _tile_cost_3d(model: Model, bz: int, rows: int, by: int,
@@ -1484,6 +1504,23 @@ def tile_plan_3d(model: Model, shape, itemsize: int = 4,
             if best_c is None or c < best_c:
                 best, best_c = (bz, by, K), c
     return best
+
+
+def window_account_3d(model: Model, shape, plan: tuple, itemsize: int = 4
+                      ) -> dict:
+    """The windows one call of the slab kernel at ``plan`` =
+    ``(bz, by, K)`` cuts ``shape`` into (on a mesh: one shard's), under
+    the names an engine's account reports them by, and what
+    :func:`_window_vmem` counts of one."""
+    nz, ny, nx = (int(s) for s in shape)
+    bz, by, K = plan
+    stages, reach = action_plan(model, "Iteration", fuse=K)
+    R, hy = max(reach, 1), _HALO if by < ny else 0
+    return dict(
+        z_bands=nz // bz, band_slabs=bz, halo_slabs=R,
+        y_bands=ny // by, band_rows=by, halo_rows=hy,
+        vmem_bytes=_window_vmem(model, nx, bz, by + 2 * hy, by, stages, R,
+                                itemsize))
 
 
 def choose_fuse_3d(model: Model, shape,
@@ -1569,24 +1606,6 @@ def supports_3d(model: Model, shape, dtype, probe: bool = True) -> bool:
     return _probe_cache[key]
 
 
-def _pieces(band: int, halo: int) -> list:
-    """(offset from the band's first index, buffer index, length) of a
-    band and its wrapped halos along one axis.  A halo no longer than the
-    band (which divides the axis) never straddles the periodic seam and
-    goes as one block; a longer one index by index (a block copy of R
-    slabs starting at (base - R) mod nz would read out of bounds, e.g.
-    bz=1, R=2, band 1)."""
-    if not halo:
-        return [(0, 0, band)]
-    if band >= halo:
-        return [(0, halo, band), (-halo, 0, halo),
-                (band, halo + band, halo)]
-    return [(0, halo, band)] + [
-        p for h in range(1, halo + 1)
-        for p in ((-h, halo - h, 1),
-                  (band - 1 + h, halo + band - 1 + h, 1))]
-
-
 def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                            interpret: Optional[bool] = None,
                            present: Optional[set] = None,
@@ -1594,7 +1613,8 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                            by_cap: Optional[int] = None,
                            shift: Optional[np.ndarray] = None,
                            window: Optional[tuple] = None,
-                           points: Optional[np.ndarray] = None):
+                           points: Optional[np.ndarray] = None,
+                           ext_halo: bool = False):
     """3D generic engine: the model's full Iteration action per z-slab
     band pass, with the same registry-driven machinery as the 2D builder
     (multi-stage extension plan, zonal aux planes, in-kernel SUM globals
@@ -1616,12 +1636,35 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     Control-series flavors at ``fuse=1`` inside the fused plan's
     ``(bz, by)``, whose account holds the series' aux stack.
     ``window=(bz, by)`` pins the window (tests and sweeps); ``points``
-    builds the sampled flavour (:func:`_scheduled_engine`)."""
+    builds the sampled flavour (:func:`_scheduled_engine`).
+
+    ``ext_halo=True`` builds the sharded building block instead, for
+    ``parallel/halo.make_sharded_pallas_tail`` (``fuse`` 1, f32):
+    ``shape`` is one device's z-block of a lattice split in z, planned
+    and cut into windows as a lattice of that shape is, and z no longer
+    wraps inside the array.  The kernel takes the block as it is and the
+    lower and the upper neighbour's ``R1`` slabs (the plan's reach, at
+    least 1) as operands of their own, ``(ns, R1, ny, nx)`` each: a z
+    halo piece of a window is copied from the block where it lies inside
+    it, else from the neighbour's slabs (``slab_dma.field_copy``, as
+    ``ops/pallas_d3q``'s ``ext`` flavour); no padded copy of the block is
+    made.  The aux stack comes extended by ``R1`` slabs a side; y still
+    wraps inside the block.  Returns ``(call, call_g, (bz, by, 1),
+    zonal_names)`` as the 2D builder's ``ext_halo`` does, the calls
+    ``(settings, iteration[None], [zone table,] block, lower slabs,
+    upper slabs, aux) ->`` the block a step on (``call_g``: and its
+    Globals, summed over the block's own nodes; None where
+    :func:`kernel_reduces_globals` is false).  The aux diet is the
+    one-chip engine's: with zonal settings the flavours are lean (the
+    flattened zone table in SMEM, the f32 flag plane the only aux
+    plane), without any the aux stack is the flag plane too."""
     if not supports_3d(model, shape, dtype, probe=False):
         raise ValueError(f"pallas_generic 3d unsupported: {model.name} "
                          f"{shape}")
     cdtype = _COMPUTE_DTYPE
     itemsize = jnp.dtype(dtype).itemsize
+    if ext_halo and (fuse != 1 or jnp.dtype(dtype) != jnp.dtype(cdtype)):
+        raise ValueError("ext_halo (sharded) blocks are f32, fuse=1 only")
     plan, reach = action_plan(model, "Iteration", fuse=fuse)
     R = max(reach, 1)
     plan1, r1 = (plan, reach) if fuse == 1 \
@@ -1683,26 +1726,24 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
     loads_density = {nm: model.stages[nm].load_densities
                      for nm in model.actions["Iteration"]}
     nt_present = set(model.node_types) if present is None else set(present)
-    y_pieces = _pieces(by, hy)
+    y_pieces = slab_dma.pieces(by, hy)
     own = slice(hy, hy + by) if hy else slice(None)   # a band's own rows
-
-    def _wrap(base, off: int, n: int):
-        """``base + off`` on a periodic axis of ``n``."""
-        return base if not off else jax.lax.rem(
-            base + jnp.int32(off + n), jnp.int32(n))
 
     def _mk_kernel(plan, R, with_dt=False, with_globals=False, lean=False):
         n_aux_k = 1 if lean \
             else 1 + (2 if with_dt else 1) * len(zonal_names)
-        z_pieces = _pieces(bz, R)
+        z_pieces = slab_dma.pieces(bz, R)
         n_sem = len(z_pieces) * len(y_pieces)
 
         def kern(sett, it_ref, *rest):
             if lean:
-                ztab, f_hbm, aux_hbm, *refs = rest
+                ztab, f_hbm, *refs = rest
             else:
                 ztab = None
-                f_hbm, aux_hbm, *refs = rest
+                f_hbm, *refs = rest
+            # ext_halo: the two neighbours' slabs stand before the aux
+            halos = [refs.pop(0) for _ in range(2 * ext_halo)]
+            aux_hbm = refs.pop(0)
             if with_globals:
                 out_ref, g_ref, buff, bufa, sems = refs
             else:
@@ -1715,25 +1756,35 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                 z0 = bi * jnp.int32(bz)
                 y0 = bj * jnp.int32(by)
                 out = []
-                for hbm, buf, nplanes in ((f_hbm, buff, ns),
-                                          (aux_hbm, bufa, n_aux_k)):
+                for hbm, buf, nplanes, sides in (
+                        (f_hbm, buff, ns, halos),
+                        (aux_hbm, bufa, n_aux_k, ())):
                     for oz, dz, lz in z_pieces:
-                        sz = _wrap(z0, oz, nz)
-                        if not hy:          # whole planes
-                            out.append(pltpu.make_async_copy(
-                                hbm.at[pl.ds(0, nplanes), pl.ds(sz, lz)],
-                                buf.at[slot, :, pl.ds(dz, lz)],
-                                sems.at[slot, len(out)]))
-                            continue
+                        # the extended aux stack holds slab z at z + R
+                        sz = (z0 + jnp.int32(oz + R) if ext_halo
+                              else slab_dma.wrap(z0, oz, nz))
                         for oy, dy_, ly in y_pieces:
-                            # bands and halos are whole sublane tiles
-                            sy = pl.multiple_of(_wrap(y0, oy, ny), _HALO)
-                            out.append(pltpu.make_async_copy(
-                                hbm.at[pl.ds(0, nplanes), pl.ds(sz, lz),
-                                       pl.ds(sy, ly)],
-                                buf.at[slot, :, pl.ds(dz, lz),
-                                       pl.ds(dy_, ly)],
-                                sems.at[slot, len(out)]))
+                            src_y = dst_y = ()      # whole planes
+                            if hy:
+                                # bands and halos are whole sublane tiles
+                                sy = pl.multiple_of(
+                                    slab_dma.wrap(y0, oy, ny), _HALO)
+                                src_y, dst_y = ((pl.ds(sy, ly),),
+                                                (pl.ds(dy_, ly),))
+
+                            dst = buf.at[(slot, slice(None),
+                                          pl.ds(dz, lz)) + dst_y]
+
+                            def window(ref, z, lz=lz, nplanes=nplanes,
+                                       src_y=src_y, dst=dst,
+                                       sem=sems.at[slot, len(out)]):
+                                return pltpu.make_async_copy(
+                                    ref.at[(pl.ds(0, nplanes),
+                                            pl.ds(z, lz)) + src_y],
+                                    dst, sem)
+
+                            out.append(slab_dma.field_copy(
+                                window, hbm, sides, z0, oz, sz, lz, nz, R))
                 return out
 
             slot = jax.lax.rem(t, jnp.int32(2))
@@ -1889,10 +1940,7 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
                 pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ] + ([pl.BlockSpec(memory_space=pltpu.SMEM)] if lean else [])
-            + [
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            + [pl.BlockSpec(memory_space=pl.ANY)] * (4 if ext_halo else 2),
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
@@ -1906,6 +1954,11 @@ def make_pallas_iterate_3d(model: Model, shape, dtype=jnp.float32,
             interpret=interpret,
             name=f"generic_slab_fuse{fuse if plan_k is plan else 1}",
         )
+
+    if ext_halo:
+        return (*_shard_calls(model, nx, fuse, partial(
+            _mk_call, plan, R, lean=bool(zonal_names))),
+            (bz, by, fuse), zonal_names)
 
     return _scheduled_engine(
         model, dtype, nx, fuse, partial(_mk_call, plan, R),

@@ -270,6 +270,13 @@ def _generic_aux(params: SimParams, flags_i32, zones, gz_si, dtype):
            for j in gz_si])
 
 
+def _zonal_table(params: SimParams, zonal_si, dtype) -> jnp.ndarray:
+    """The zone-table rows of the settings at ``zonal_si``, flattened: the
+    SMEM operand from which a lean kernel rebuilds its zonal planes."""
+    return jnp.concatenate([params.zone_table[j].astype(dtype)
+                            for j in zonal_si])
+
+
 def _streams(model: Model) -> int:
     """What one rep of the Iteration action adds to the iteration
     counter: 1 iff any stage streams — the rule the single-device generic
@@ -325,7 +332,9 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
     Like the single-device fast path this is the "NoGlobals"
     specialization: ``globals_`` is zeroed; the Lattice hybrid's trailing
     step supplies them: :func:`make_sharded_pallas_tail` where it takes
-    the case, else the sharded XLA step (both psum)."""
+    the case (every mesh this engine takes whose shards the generic
+    kernel takes with Globals it reduces: the y-split 2D mesh and the
+    z-split 3D one), else the sharded XLA step (both psum)."""
     from tclb_tpu.ops import pallas_d2q9, pallas_d3q
     from tclb_tpu.ops.engine import Engine, paired_calls, scan_calls
     shards = band_shards(model, mesh, shape)
@@ -461,9 +470,7 @@ def make_sharded_pallas_iterate(model: Model, mesh: Mesh, shape,
                     fields = call1(sett, fields, *halos(fields), flags_i32,
                                    vel, den)
             else:
-                ztab = jnp.concatenate(
-                    [params.zone_table[j].astype(dtype)
-                     for j in k3.zonal_si])
+                ztab = _zonal_table(params, k3.zonal_si, dtype)
                 flags_ext = exch(flags_i32[None])[0]
 
                 def body3(call, w):
@@ -543,24 +550,30 @@ def make_sharded_pallas_tail(model: Model, mesh: Mesh, shape,
                              dtype=jnp.float32,
                              present: Optional[set] = None,
                              interpret: Optional[bool] = None):
-    """The one step a hybrid engine leaves for the Globals, on a y-split
-    2D mesh: an :class:`Engine` of ``iterate(state, params, 1)``, or None
-    where this configuration can't run it (a 3D model: the generic
-    slab engine, the only 3D kernel that reduces Globals, has no
-    ``ext_halo`` mode, so on a 3D mesh the trailing step stays the
-    sharded XLA step, :func:`make_sharded_iterate`, whatever engine runs
-    the steps before it; a mesh split in x; shards of no
-    multiple of 8 rows; storage other than f32; a model or local shape
-    ``pallas_generic`` refuses; Globals its kernel does not reduce).
+    """The one step a hybrid engine leaves for the Globals, on a mesh
+    split along the kernels' band axis (y in 2D, z in 3D): an
+    :class:`Engine` of ``iterate(state, params, 1)``, or None where this
+    configuration can't run it (a mesh split in x, or a 3D one in y; 2D
+    shards of no multiple of 8 rows; storage other than f32; a model or
+    local shape ``pallas_generic`` refuses; Globals its kernel does not
+    reduce): the sharded XLA step, :func:`make_sharded_iterate`, runs
+    the step then, whatever engine runs the steps before it.
 
-    One ``shard_map`` program: the fields' 8 rows a side and the aux
-    stack are exchanged (:func:`_exchange_axis`'s padded operand, made
-    once an ``iterate``, not once a kernel call), each shard runs ONE
-    call of ``pallas_generic``'s one-step band kernel with in-kernel
-    globals on it, which sums over the shard's own rows, and the partial
-    sums are reduced across the mesh (:func:`_globals_allreduce`):
-    ``globals_`` comes back replicated, as the sharded XLA step
-    (:func:`make_sharded_iterate`) returns it.
+    One ``shard_map`` program: the neighbours' halos are exchanged once,
+    each shard runs ONE call of ``pallas_generic``'s one-step kernel with
+    in-kernel globals, which sums over the shard's own nodes, and the
+    partial sums are reduced across the mesh
+    (:func:`_globals_allreduce`): ``globals_`` comes back replicated, as
+    the sharded XLA step returns it.  In 2D the band kernel runs on the
+    fields and the aux stack padded by their 8 exchanged rows a side
+    (:func:`_exchange_axis`).  In 3D the slab kernel (whole planes or
+    y-tiled windows, as ``pallas_generic.tile_plan_3d`` cuts the shard)
+    takes the shard as it is and the neighbours' ``R1`` slabs (the
+    action's reach, 1 for ``d3q27_cumulant``) as operands of their own
+    (:func:`_halo_blocks`): no padded copy of a shard's fields, which at
+    96 x 384 x 384 would be 1.97 GB more of the peak; only the f32 flag
+    plane, the whole aux stack there, comes extended by ``R1`` slabs a
+    side.
 
     The program does not donate the state: it is one kernel call, which
     reads halos of what it writes (``pallas_generic.
@@ -570,34 +583,62 @@ def make_sharded_pallas_tail(model: Model, mesh: Mesh, shape,
     from tclb_tpu.ops import pallas_generic
     from tclb_tpu.ops.engine import Engine
     shards = band_shards(model, mesh, shape)
-    if shards is None or model.ndim != 2:
+    if shards is None:
         return None
     axis, n, local = shards
-    if (local[0] % 8 or jnp.dtype(dtype) != jnp.dtype(jnp.float32)
+    if ((model.ndim == 2 and local[0] % 8)
+            or jnp.dtype(dtype) != jnp.dtype(jnp.float32)
             or not pallas_generic.supports(model, local, dtype,
                                            probe=False)):
         return None
-    _, call_g, by, gz_names = pallas_generic.make_pallas_iterate(
+    # cut: how the kernel cuts the shard, 2D its band's rows, 3D its
+    # plan (bz, by, 1)
+    _, call_g, cut, gz_names = pallas_generic.make_pallas_iterate(
         model, local, dtype, interpret=interpret, fuse=1, present=present,
         ext_halo=True)
     if call_g is None:
         return None
     gz_si = [model.setting_index[nm] for nm in gz_names]
-    width, adv, names = 8, _streams(model), tuple(mesh.axis_names)
-    aux_planes = 1 + len(gz_si)
+    adv, names = _streams(model), tuple(mesh.axis_names)
+    did = dict(kernel_calls=1, paired_calls=0,
+               stages_per_step=len(model.actions["Iteration"]))
+    if model.ndim == 2:
+        width, aux_planes = 8, 1 + len(gz_si)
+        did.update(halo_operand_rows=0, bands=local[0] // cut,
+                   band_rows=cut, halo_rows=width, aux_planes=aux_planes)
+
+        def operands(state, params, flags_i32) -> tuple:
+            """The shard padded round its halo rows, and its aux stack."""
+            return (_exchange_axis(state.fields, axis, 1, width, n),
+                    _exchange_axis(
+                        _generic_aux(params, flags_i32,
+                                     flags_i32 >> model.zone_shift, gz_si,
+                                     dtype), axis, 1, width, n))
+    else:
+        windows = pallas_generic.window_account_3d(model, local, cut)
+        width, aux_planes = windows["halo_slabs"], 1
+        did.update(remainder_steps=0, shards=n, **windows,
+                   aux_planes=aux_planes, halo_operand_slabs=width)
+
+        def operands(state, params, flags_i32) -> tuple:
+            """The zone table where the kernel is lean (it rebuilds the
+            zonal planes from the flags), the shard as it is, the
+            neighbours' slabs, the extended flag plane."""
+            ztab = [_zonal_table(params, gz_si, dtype)] if gz_si else []
+            return (*ztab, state.fields,
+                    *_halo_blocks(state.fields, axis, 1, width, n),
+                    _exchange_axis(flags_i32.astype(dtype)[None], axis, 1,
+                                   width, n))
+
     halo_bytes = (model.n_storage + aux_planes) * _exchange_bytes(
         (1,) + local, 1, width, n, 1, jnp.dtype(dtype).itemsize)
 
     def local_step(state: LatticeState, params: SimParams) -> LatticeState:
         flags_i32 = state.flags.astype(jnp.int32)
         with jax.named_scope("halo_exchange"):
-            fields_ext = _exchange_axis(state.fields, axis, 1, width, n)
-            aux_ext = _exchange_axis(
-                _generic_aux(params, flags_i32,
-                             flags_i32 >> model.zone_shift, gz_si, dtype),
-                axis, 1, width, n)
+            ops = operands(state, params, flags_i32)
         fields, g = call_g(params.settings.astype(dtype),
-                           state.iteration[None], fields_ext, aux_ext)
+                           state.iteration[None], *ops)
         return LatticeState(
             fields=fields, flags=state.flags,
             globals_=_globals_allreduce(
@@ -619,12 +660,12 @@ def make_sharded_pallas_tail(model: Model, mesh: Mesh, shape,
         return out
 
     def account(niter: int, has_series: bool = False) -> dict:
-        """One kernel call on the shard padded round its halo rows, and
-        the kernel's bands of it, under the generic engine's names."""
-        return dict(kernel_calls=1, paired_calls=0, halo_operand_rows=0,
-                    stages_per_step=len(model.actions["Iteration"]),
-                    bands=local[0] // by, band_rows=by, halo_rows=width,
-                    aux_planes=aux_planes)
+        """One kernel call a shard and the kernel's windows of ONE
+        shard, under the generic engines' names: in 2D the bands of the
+        shard padded round its halo rows; in 3D the slab engine's
+        windows, the shards, and the slabs a side the kernel takes as
+        operands of their own."""
+        return dict(did)
 
     # full_globals: the call returns the last (its one) step's Globals
     return Engine(iterate, account, full_globals=True, unproven=True,

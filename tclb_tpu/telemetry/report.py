@@ -462,9 +462,10 @@ def _setup_summary(evts: list[dict]) -> dict:
     return out
 
 
-# what an engine's account puts on ``iterate.fused``: counts of one
-# call, which add up over a run, and the plan of its kernel's windows (on
-# a mesh: of ONE shard), which does not change
+# what an engine's account puts on ``iterate.fused`` (a tail engine's on
+# ``iterate.globals_step``): counts of one call, which add up over a run,
+# and the plan of its kernel's windows (on a mesh: of ONE shard), which
+# does not change
 ACCOUNT_SUMS = ("kernel_calls", "paired_calls", "remainder_steps",
                 "resident_calls", "halo_bytes")
 ACCOUNT_PLAN = ("shards", "z_bands", "band_slabs", "halo_slabs", "y_bands",
@@ -522,9 +523,11 @@ def summarize(evts: list[dict]) -> dict:
                 g["node_updates"] += (float(e.get("nodes", 0.0))
                                       * float(e.get("iters", 0)))
                 g["total_s"] += dt
-            elif name == "iterate.fused" and "kernel_calls" in e:
+            if (name in ("iterate.fused", "iterate.globals_step")
+                    and "kernel_calls" in e):
                 # the engine's own account of its calls
-                # (Lattice._run_engine): the counts add up, the plan of
+                # (Lattice._run_engine; the tail engine's lies on the
+                # trailing step's span): the counts add up, the plan of
                 # its windows is the newest call's
                 g = accounts.setdefault(e.get("engine", "?"), {"calls": 0})
                 g["calls"] += 1
@@ -532,7 +535,7 @@ def summarize(evts: list[dict]) -> dict:
                     if k in e:
                         g[k] = g.get(k, 0) + e[k]
                 g.update({k: e[k] for k in ACCOUNT_PLAN if k in e})
-            elif name == "iterate.globals_step":
+            if name == "iterate.globals_step":
                 # the step a hybrid engine leaves for the Globals, by
                 # the engine that ran it (a trace from before the span
                 # said so: "?")
@@ -972,7 +975,8 @@ def format_text(summary: dict) -> str:
         lines.append("")
     if summary.get("accounts"):
         lines.append("fused calls by engine (the engine's account on "
-                     "iterate.fused; windows of one shard)")
+                     "iterate.fused, a tail engine's on "
+                     "iterate.globals_step; windows of one shard)")
         for eng, g in sorted(summary["accounts"].items()):
             lines.append(f"  {eng}")
             lines.append("      " + "  ".join(

@@ -105,6 +105,22 @@ def _sharded_tail():
     return it, lat
 
 
+def _sharded_tail_3d():
+    # shards of 4 slabs: two z bands of whole planes a shard
+    shape = (8, 16, 128)
+    mesh = make_mesh(shape, devices=jax.devices()[:2],
+                     decomposition={"z": 2, "y": 1, "x": 1})
+    m, lat, present = _lattice("d3q19", shape, mesh=mesh)
+    it = halo.make_sharded_pallas_tail(m, mesh, shape, jnp.float32,
+                                       present=present, interpret=True)
+    assert it.full_globals and it.unproven and it.fuse == 1
+    # the windows of ONE shard, the neighbour's slab an operand
+    did = it.account(1)
+    assert (did["shards"], did["z_bands"] * did["band_slabs"],
+            did["halo_operand_slabs"], did["aux_planes"]) == (2, 4, 1, 1)
+    return it, lat
+
+
 # builder, whether it reports (an account), the lengths to trace: empty
 # loops, loops of one trip, odd and even loops of either kernel
 BUILDERS = {
@@ -125,6 +141,7 @@ BUILDERS = {
     "sharded_tuned": (lambda: _sharded("d2q9"), True, (1, 5, 8, 11)),
     "sharded_generic": (lambda: _sharded("d2q9_kuper"), True, (3,)),
     "sharded_tail": (_sharded_tail, True, (1,)),
+    "sharded_tail_3d": (_sharded_tail_3d, True, (1,)),
 }
 
 

@@ -1083,3 +1083,58 @@ def test_sharded_d3q27_cumulant_384_on_4x1x1_mesh(topo, fuse, niter):
             if re.search(r"= f32\[34,(9\d|1\d\d),384,384\]\S* (?!custom-call|"
                          r"get-tuple-element|parameter)", line)]
     assert not made, made
+
+
+def test_sharded_tail_384_on_4x1x1_mesh(topo):
+    """The step under ``iterate.globals_step`` in the cell
+    ``tgv384.zsplit`` (``parallel/halo.make_sharded_pallas_tail`` on a 3D
+    mesh), compiled for the described 4 x 1 x 1 mesh at the cell's shard,
+    96 x 384 x 384, which the generic planner cuts as it cuts ``tgv256``:
+    windows of 4 slabs x 32 rows.  One program of ONE
+    ``generic_slab_fuse1`` call with in-kernel globals on the shard as it
+    is; the neighbours' one slab a side of the fields and of the f32 flag
+    plane by ``collective-permute``; one ``all-reduce`` of ``Flux``'s
+    partial sums; **not donating** (a failed probe leaves the state
+    whole); and nothing of the shard's size (34 x 96 x 384 x 384, 1.93
+    GB) made beside the kernel's result: no padded copy of the fields."""
+    from tclb_tpu.parallel import halo
+    m, mesh, shape, state, params = _tgv_384_on_4x1x1_mesh(topo)
+    local = (96, 384, 384)
+    assert pallas_generic.tile_plan_3d(m, local, fuse=1) == (4, 32, 1)
+    tail = halo.make_sharded_pallas_tail(m, mesh, shape, jnp.float32,
+                                         present={"MRT"}, interpret=False)
+    assert tail.full_globals and tail.unproven and tail.fuse == 1
+    did = tail.account(1)
+    assert did.pop("vmem_bytes") <= pallas_generic._TILED3D_BUDGET
+    assert did == dict(
+        kernel_calls=1, paired_calls=0, remainder_steps=0, shards=4,
+        stages_per_step=1, z_bands=24, band_slabs=4, halo_slabs=1,
+        y_bands=12, band_rows=32, halo_rows=8, aux_planes=1,
+        halo_operand_slabs=1)
+    lowered = tail.impl["program"].lower(state, params)
+    assert not lowered.args_info[0][0].fields.donated
+    compiled = lowered.compile()
+    assert compiled.output_shardings.globals_.is_fully_replicated
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "generic_slab_fuse1/pallas_call" in text
+    assert "halo_exchange/" in text
+    permutes = re.findall(r"= \((f32\[\d+,1,384,384\])\S*, .*\) "
+                          r"collective-permute-start\(", text)
+    assert sorted(permutes) == (["f32[1,1,384,384]"] * 2
+                                + ["f32[34,1,384,384]"] * 2)
+    # the Globals: one all-reduce of one sum, no max beside it
+    assert len(re.findall(r"= f32\[1\]\S* all-reduce(-start)?\(",
+                          text)) == 1
+    entry, = [lines for name, lines in _computations(text).items()
+              if name.startswith("main")]
+    assert not _state_copies(entry, m, local)
+    made = [line.strip() for line in entry
+            if re.search(r"= f32\[34,(9\d|1\d\d),384,384\]\S* (?!custom-call|"
+                         r"get-tuple-element|parameter|bitcast)", line)]
+    assert not made, made
+    # the shard and the kernel's result: nothing else of that size is
+    # on a chip while the program runs
+    mem = compiled.memory_analysis()
+    fields = 34 * 96 * 384 * 384 * 4
+    assert mem.temp_size_in_bytes < 0.1 * fields

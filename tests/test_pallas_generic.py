@@ -9,6 +9,8 @@ pin it over all eligible 2D models, multi-stage actions, Field stencils,
 zonal settings and the ghost-row padded path.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -379,6 +381,64 @@ def test_generic3d_halo_straddle():
     a block copy starting at (base - R) mod nz would read out of bounds
     (the bug that NaN'd d3q19_kuper at 48x48x256 on TPU)."""
     _parity_3d("d3q19_kuper", shape=(12, 16, 128), niter=4)
+
+
+@pytest.mark.parametrize("window", [(2, 16), (1, 8)],
+                         ids=["whole_planes", "y_bands"])
+def test_generic3d_ext_halo_block_is_the_one_chip_kernel(window):
+    """The ``ext_halo`` flavour of the slab kernel (one z-block of a
+    lattice split over devices: the block as it is, the neighbours'
+    ``R1`` slabs as operands of their own, the flag plane extended by
+    them) on a whole lattice with its own wrapped slabs handed in as the
+    neighbours' is the one-chip kernel bit for bit, fields and Globals:
+    at two z bands of whole planes (band 0 reads its lower halo from the
+    lower neighbour's slab and its upper one from the block, band 1 the
+    other way round) and at bands of one slab cut into two y bands of 8
+    rows with 8 wrapped halo rows a side (every window reads both
+    neighbours' slabs or neither)."""
+    m = get_model("d3q19")
+    shape = (4, 16, 128)
+    lat = Lattice(m, shape, dtype=jnp.float32,
+                  settings={"nu": 0.05, "Velocity": 0.02})
+    flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
+    flags[:, 0, :] = flags[:, -1, :] = m.flag_for("Wall")
+    flags[:, 1:-1, 0] = m.flag_for("WVelocity", "MRT")
+    flags[:, 1:-1, -1] = m.flag_for("EPressure", "MRT")
+    flags[:, 1:-1, 2] = m.flag_for("MRT", "Inlet")
+    flags[:, 1:-1, -3] = m.flag_for("MRT", "Outlet")
+    lat.set_flags(flags)
+    lat.init()
+    # every slab its own, so that a slab read from the wrong place shows
+    noise = np.random.default_rng(0).standard_normal(lat.state.fields.shape)
+    state = lat.state.replace(fields=lat.state.fields * jnp.asarray(
+        1 + 0.01 * noise, jnp.float32))
+    params = lat.params
+    build = partial(pallas_generic.make_pallas_iterate_3d, m, shape,
+                    interpret=True, window=window)
+    want = build()(state, params, 1)
+    call, call_g, plan, zonal = build(ext_halo=True)
+    assert plan == (*window, 1) and zonal == list(m.zonal_settings)
+    f = state.fields
+    aux = state.flags.astype(jnp.int32).astype(jnp.float32)[None]
+    operands = (
+        params.settings, state.iteration[None],
+        jnp.concatenate([params.zone_table[m.setting_index[nm]]
+                         for nm in zonal]),
+        f, f[:, -1:], f[:, :1],
+        jnp.concatenate([aux[:, -1:], aux, aux[:, :1]], axis=1))
+    fields, g = call_g(*operands)
+    np.testing.assert_array_equal(np.asarray(fields),
+                                  np.asarray(want.fields))
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(want.globals_))
+    assert np.all(np.asarray(g)[1:] > 1.0)      # the two fluxes
+    if window[1] < shape[1]:
+        # the NoGlobals flavour: the same windows, one output less
+        np.testing.assert_array_equal(np.asarray(call(*operands)),
+                                      np.asarray(want.fields))
+    # the flavour is one step of an f32 block
+    for refused in (dict(fuse=2), dict(dtype=jnp.bfloat16)):
+        with pytest.raises(ValueError, match="ext_halo"):
+            build(ext_halo=True, **refused)
 
 
 def test_sharded_generic_matches_single(monkeypatch):

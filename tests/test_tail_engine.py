@@ -4,9 +4,9 @@ A hybrid engine (``pallas_d3q``, ``pallas_2d``, ``pallas_resident``,
 ``pallas_sharded``) advances ``niter - 1`` steps and leaves the last one,
 which reduces the Globals, to another engine: the generic Pallas engine's
 one-step flavour with in-kernel globals wherever it takes the case
-(``Lattice._build_tail``; on a y-split 2D mesh on each shard, the partial
-sums reduced across it: ``parallel/halo.make_sharded_pallas_tail``), else
-the XLA step.  These tests force the
+(``Lattice._build_tail``; on a y-split 2D mesh and on a z-split 3D one on
+each shard, the partial sums reduced across it:
+``parallel/halo.make_sharded_pallas_tail``), else the XLA step.  These tests force the
 dispatch on CPU (interpret mode) and pin ``Lattice.iterate`` against the
 XLA engine, and what the run says of itself.  (The 2D composition,
 resident engine and tail: ``test_fastpath.py::
@@ -29,15 +29,31 @@ from test_fastpath import (  # noqa: F401 (seen is a fixture)
     _karman_lattice, _says_tail, _spans, seen)
 
 
-def _cumulant_lattice(shape, storage_dtype=None):
+def _two_chip_mesh(shape, split):
+    """``shape`` split along the mesh axis ``split`` over two of the
+    CPU's devices; None for no ``split``."""
+    from tclb_tpu.parallel.mesh import make_mesh
+    if not split:
+        return None
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices")
+    return make_mesh(shape, devices=jax.devices()[:2],
+                     decomposition={"z": 1, "y": 1, "x": 1, split: 2})
+
+
+def _cumulant_lattice(shape, storage_dtype=None, split=None):
     """d3q27_cumulant between walls in y with a turbulent inlet and a
     pressure outlet in x: the synthetic-turbulence coupling planes
     (``SynthT*``, which the ``<SyntheticTurbulence>`` handler fills in a
-    run) and the averages (``avg*``) all move, and ``Flux`` is reduced."""
+    run) and the averages (``avg*``) all move, and ``Flux`` is reduced.
+    ``split``: the mesh axis the lattice is split along, over two of the
+    CPU's devices (the planes differ from slab to slab, so a shard that
+    read its own slabs for a neighbour's would show)."""
     m = get_model("d3q27_cumulant")
     lat = Lattice(m, shape, dtype=jnp.float32, storage_dtype=storage_dtype,
                   settings={"nu": 0.05, "Velocity": 0.03,
-                            "Turbulence": 0.01})
+                            "Turbulence": 0.01},
+                  mesh=_two_chip_mesh(shape, split))
     flags = np.full(shape, m.flag_for("MRT"), dtype=np.uint16)
     flags[:, 0, :] = flags[:, -1, :] = m.flag_for("Wall")
     flags[:, 1:-1, 0] = m.flag_for("WVelocityTurbulent", "MRT")
@@ -85,10 +101,26 @@ def _mesh_tags(chips):
             f"pallas_sharded[generic,{split},fuse=1,globals]")
 
 
+_ZSPLIT = {"z": 2, "y": 1, "x": 1}
+_MESH_3D_TAIL = f"pallas_sharded[generic,{_ZSPLIT},fuse=1,globals]"
+_MESH_3D_CASES = {
+    # a z-split 3D mesh of two chips, shards of whole planes and of
+    # planes tiled in y: the tail is the slab kernel on each shard as it
+    # is, the neighbour's slab a side an operand of its own
+    "mesh_3d": ((16, 16, 128), f"pallas_sharded[{_ZSPLIT},fuse=4]"),
+    # shards of two slabs: the fused engine's plan is K = 1 on bands of
+    # 128 rows, the tail's bands of 32 rows as on one chip
+    "mesh_3d_y_tiled": ((4, 256, 256),
+                        f"pallas_sharded[{_ZSPLIT},fuse=1,by=128]"),
+}
+
 _TAIL_LATTICES = {
     **{case: (lambda shape=shape: _cumulant_lattice(shape), fused, tail,
               ("Flux",))
        for case, (shape, fused, tail) in _TAIL_CASES.items()},
+    **{case: (lambda shape=shape: _cumulant_lattice(shape, split="z"),
+              fused, _MESH_3D_TAIL, ("Flux",))
+       for case, (shape, fused) in _MESH_3D_CASES.items()},
     # a y-split 2D mesh: the tail is the same kernel on each shard under
     # shard_map, its partial sums reduced across the mesh
     **{f"mesh{chips}x1": (lambda chips=chips: _karman_on_a_mesh(chips),
@@ -106,8 +138,9 @@ def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
     ``SynthT*`` among them), the Globals, the iteration; and what the run
     says of itself: the engine on ``iterate.globals_step``, one
     ``engine.tail_calls`` a call, no fallback.  On a mesh (4 and 2 of the
-    CPU's devices) both sides are sharded: the XLA side is the sharded
-    XLA step this tail replaces.  (The one-chip 2D case is
+    CPU's devices in 2D, 2 in 3D: shards of whole planes and y-tiled
+    ones) both sides are sharded: the XLA side is the sharded XLA step
+    this tail replaces.  (The one-chip 2D case is
     ``test_engine_dispatch_matches_xla``.)"""
     lattice, fused, tail, reduced = _TAIL_LATTICES[case]
     niter, calls = 5, 2
@@ -135,8 +168,9 @@ def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
         if plane.startswith(("avg", "SynthT")):
             assert np.abs(fx[m.storage_index[plane]]).max() > 0, plane
     assert int(lat_f.state.iteration) == niter * calls
+    # the sharded tuned 2D engine is the one fused engine not probed
     _says_tail(seen, lat_f, fused, tail, calls,
-               fused_probed=lat_f.mesh is None)
+               fused_probed=lat_f.mesh is None or m.ndim == 3)
     assert telemetry.counters()["engine.tail_calls"] - before == calls
     if lat_f.mesh is not None:
         # replicated, as the sharded XLA step returns them
@@ -146,17 +180,10 @@ def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
         assert build["tail"] == tail
 
 
-def _tail_on_a_3d_mesh():
-    # a z-split 3D mesh: the generic 3D engine has no ext_halo mode
-    from tclb_tpu.parallel.mesh import make_mesh
-    m, ref = _bgk_lattice()
-    mesh = make_mesh(ref.shape, devices=jax.devices()[:2],
-                     decomposition={"z": 2, "y": 1, "x": 1})
-    lat = Lattice(m, ref.shape, dtype=jnp.float32, mesh=mesh,
-                  settings={"omega": 1.0, "GravitationX": 1e-5})
-    lat.set_flags(np.asarray(ref.state.flags))
-    lat.init()
-    return lat, "pallas_sharded"
+def _tail_on_a_y_split_3d_mesh():
+    # the slab kernels keep the (ny, nx) plane whole (no sharded fused
+    # engine either)
+    return _cumulant_lattice((8, 16, 128), split="y")[1], None
 
 
 def _tail_on_shards_of_odd_rows():
@@ -197,14 +224,15 @@ def _tail_on_a_mesh_with_a_series():
     return _with_a_series(_karman_on_a_mesh(4)[1]), "pallas_sharded"
 
 
-@pytest.mark.parametrize("case", ["mesh_3d", "mesh_odd_rows", "mesh_x_split",
+@pytest.mark.parametrize("case", ["mesh_3d_y_split", "mesh_odd_rows",
+                                  "mesh_x_split",
                                   "mesh_series", "series", "refused_dtype",
                                   "no_kernel_globals"])
 def test_tail_engine_stays_off(monkeypatch, seen, case):
     """Where the generic engine's one-step flavour does not apply, the
     trailing step stays the XLA step: on a mesh the sharded tail cannot
-    take (a 3D one split in z, shards of 12 rows, a split in x: the last
-    two have no sharded fused engine either and run every step on XLA),
+    take (a 3D one split in y, shards of 12 rows, a split in x: none of
+    them has a sharded fused engine either, and XLA runs every step),
     with a ``<Control>`` series (the series-aware generic engine reduces
     the Globals itself, and on a mesh the XLA engine runs the whole
     call: no trailing step at all), with a storage dtype the generic
@@ -214,7 +242,7 @@ def test_tail_engine_stays_off(monkeypatch, seen, case):
     before = telemetry.counters().get("engine.tail_calls", 0)
     lat, family = (_tail_of_a_refused_dtype(monkeypatch)
                    if case == "refused_dtype"
-                   else {"mesh_3d": _tail_on_a_3d_mesh,
+                   else {"mesh_3d_y_split": _tail_on_a_y_split_3d_mesh,
                          "mesh_odd_rows": _tail_on_shards_of_odd_rows,
                          "mesh_x_split": _tail_on_an_x_split,
                          "mesh_series": _tail_on_a_mesh_with_a_series,
@@ -229,7 +257,7 @@ def test_tail_engine_stays_off(monkeypatch, seen, case):
     assert lat._tail is None and lat._tail_name is None
     steps = _spans(seen, "iterate.globals_step")
     assert [e["engine"] for e in steps] == (
-        ["xla"] if case in ("mesh_3d", "refused_dtype", "no_kernel_globals")
+        ["xla"] if case in ("refused_dtype", "no_kernel_globals")
         else [])
     assert telemetry.counters().get("engine.tail_calls", 0) == before
     assert all(e["engine"] == lat._fast_name
@@ -238,11 +266,12 @@ def test_tail_engine_stays_off(monkeypatch, seen, case):
     assert np.isfinite(np.asarray(lat.state.fields, np.float32)).all()
 
 
-def _bgk_lattice(storage_dtype=None, nx=128):
+def _bgk_lattice(storage_dtype=None, nx=128, split=None):
     m = get_model("d3q27_BGK")
     shape = (8, 16, nx)
     lat = Lattice(m, shape, dtype=jnp.float32, storage_dtype=storage_dtype,
-                  settings={"omega": 1.0, "GravitationX": 1e-5})
+                  settings={"omega": 1.0, "GravitationX": 1e-5},
+                  mesh=_two_chip_mesh(shape, split))
     flags = np.full(shape, m.flag_for("BGK"), dtype=np.uint16)
     flags[:, 0, :] = flags[:, -1, :] = m.flag_for("Wall")
     lat.set_flags(flags)
@@ -257,19 +286,25 @@ _FALLBACK_CASES = {
              "pallas_generic[d3q27_BGK,fuse=1]"),
     "mesh": (lambda: _karman_on_a_mesh(4), "_sharded_tail_cand",
              "pallas_sharded", _mesh_tags(4)[1]),
+    "mesh_3d": (lambda: _bgk_lattice(split="z"), "_sharded_tail_cand",
+                "pallas_sharded", _MESH_3D_TAIL),
 }
 
 
-@pytest.mark.parametrize("fails", ["build", "first_call"])
-@pytest.mark.parametrize("where", list(_FALLBACK_CASES))
+@pytest.mark.parametrize("where,fails", [
+    (where, fails) for where in _FALLBACK_CASES
+    for fails in ("first_call", "build")
+    # on a 3D mesh the candidate is the 2D mesh's, built alike: the
+    # first call alone, which runs the 3D program on the live state
+    if (where, fails) != ("mesh_3d", "build")])
 def test_tail_engine_falls_back_to_the_xla_step(monkeypatch, seen, where,
                                                 fails):
     """A tail that cannot be built, or whose first call fails, hands the
     trailing step to XLA with one ``engine_fallback`` event (from its tag
     to ``xla``); the tail's one call does not donate, so the state is
     intact and the run goes on to the XLA engine's result: on one chip,
-    and on a mesh, where the step that takes over is the sharded XLA
-    step.  The failure is this lattice's alone: the generic engine's
+    and on a 2D and a 3D mesh, where the step that takes over is the
+    sharded XLA step.  The failure is this lattice's alone: the generic engine's
     process-wide verdict, which the fused chain's rungs read, is not
     touched, and a later lattice probes its own tail."""
     lattice, names_cand, family, tail = _FALLBACK_CASES[where]

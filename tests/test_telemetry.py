@@ -489,16 +489,31 @@ def test_summarize_engine_table(tmp_path):
     assert "per-engine iterate summary" in txt and _ENG in txt
 
 
+_TAIL_3D = "pallas_sharded[generic,{'z': 4, 'y': 1, 'x': 1},fuse=1,globals]"
+# what parallel/halo.make_sharded_pallas_tail says of a call at the
+# shards of tgv384 (one shard's windows)
+_TAIL_3D_ACCOUNT = dict(
+    kernel_calls=1, paired_calls=0, remainder_steps=0, shards=4,
+    stages_per_step=1, z_bands=24, band_slabs=4, halo_slabs=1, y_bands=12,
+    band_rows=32, halo_rows=8, aux_planes=1, halo_operand_slabs=1,
+    vmem_bytes=79724544, halo_bytes=41287680)
+
+
 @pytest.mark.parametrize("engines", [
     ["pallas_generic[d3q27_cumulant,fuse=1]"] * 3,
     ["xla", "pallas_generic[d2q9,fuse=1]", "pallas_generic[d2q9,fuse=1]"],
-    [None, None]], ids=["tail", "fell_back_once", "older_trace"])
+    [None, None], [_TAIL_3D] * 2],
+    ids=["tail", "fell_back_once", "older_trace", "mesh_3d"])
 def test_report_lists_the_trailing_steps_by_engine(engines):
     """``iterate.globals_step`` spans by the ``engine`` they say (a trace
-    from before they said one reads ``?``), with the counter beside."""
+    from before they said one reads ``?``), with the counter beside; a
+    tail engine's account, which lies on that span, is listed with the
+    fused engines' (``shards`` and the windows of one shard on a mesh)."""
     evts = [dict({"kind": "span", "ts": 1.0, "name": "iterate.globals_step",
                   "dur_s": 0.005, "iters": 1},
-                 **({"engine": eng} if eng else {})) for eng in engines]
+                 **({"engine": eng} if eng else {}),
+                 **(_TAIL_3D_ACCOUNT if eng == _TAIL_3D else {}))
+            for eng in engines]
     tail_calls = sum(bool(e) and e != "xla" for e in engines)
     evts.append({"kind": "counters", "ts": 2.0,
                  "counters": {"engine.tail_calls": tail_calls}})
@@ -511,6 +526,18 @@ def test_report_lists_the_trailing_steps_by_engine(engines):
     assert "trailing globals steps of the hybrid engines" in txt
     assert all((eng or "?") in txt for eng in engines)
     assert f"engine.tail_calls{'':<23} {tail_calls}" in txt
+    if _TAIL_3D in engines:
+        n = len(engines)
+        assert s["accounts"] == {_TAIL_3D: dict(
+            {k: v for k, v in _TAIL_3D_ACCOUNT.items()
+             if k in report.ACCOUNT_PLAN},
+            calls=n, kernel_calls=n, paired_calls=0, remainder_steps=0,
+            halo_bytes=n * _TAIL_3D_ACCOUNT["halo_bytes"])}
+        assert "fused calls by engine" in txt
+        assert ("shards 4  z_bands 24  band_slabs 4  halo_slabs 1  "
+                "y_bands 12  band_rows 32  halo_rows 8") in txt
+    else:
+        assert not s["accounts"]
 
 
 def test_compare_detects_injected_slowdown(tmp_path):

@@ -346,6 +346,12 @@ class conControl(Handler):
                 self._params(child, context)
             else:
                 raise ValueError(f"unknown element <{child.tag}> in Control")
+        # on the element's own span: the table the lattice now holds
+        table = s.lattice.params.time_series
+        if table is not None:
+            telemetry.annotate("startup.element", series=table.shape[0],
+                               horizon=table.shape[1],
+                               bytes=int(table.nbytes))
         return 0
 
     def _eval(self, context: dict[str, np.ndarray], expr: str) -> np.ndarray:

@@ -1208,9 +1208,13 @@ class Lattice:
         model, shape, name = self.model, self.shape, self.model.name
         present = self._present_types()
         shift = self._shift_vec
-        # a Control time series needs per-iteration zonal planes, which
-        # only the generic engine implements — skip the tuned kernels
-        # (set_setting_series invalidates the engine so this re-runs)
+        # a Control time series gives a step the zonal values of its own
+        # iteration.  The tuned 2D band takes them as scalars beside its
+        # per-call planes (pallas_d2q9.series_flavour) and the generic
+        # engine as per-iteration planes, one step a call; the tuned 3D
+        # family and the two VMEM-resident engines take none and are
+        # left out (set_setting_series invalidates the engine so this
+        # re-runs)
         has_series = self.params.time_series is not None
         # a sampler (<Sample>) needs what every step left at its points:
         # the engines that can hand that out run one step a kernel call
@@ -1237,8 +1241,8 @@ class Lattice:
                 probe, cap, verdict)
         if self.mesh is not None:
             return self._sharded_chain(present)
-        if not has_series and pallas_d2q9.covers(model, shape, sdt):
-            chain = self._band_chain(cand, sampled, points)
+        if pallas_d2q9.covers(model, shape, sdt):
+            chain = self._band_chain(cand, sampled, points, has_series)
             if chain:
                 return chain
         if not has_series and pallas_d3q.supports(model, shape, sdt):
@@ -1388,12 +1392,18 @@ class Lattice:
                 lambda: make(fuse=1), probe=True))
         return chain
 
-    def _band_chain(self, cand: Callable, sampled: bool, points) -> list:
+    def _band_chain(self, cand: Callable, sampled: bool, points,
+                    has_series: bool) -> list:
         """The tuned 2D family's part of :meth:`_build_fast`'s chain for a
         lattice its kernels cover: the band engine as
         ``pallas_d2q9.band_plan`` cuts it, at two steps a kernel call or
         (under a sampler) one, and over it the VMEM-resident engine where
-        the lattice fits.  A plan over Mosaic's own scoped-VMEM limit
+        the lattice fits and no ``<Control>`` series is attached (its
+        call is eight steps on-chip and takes none; the band engine takes
+        a series at either depth, each step its own iteration's values).
+        A series on a setting the band kernels read from no plane
+        (``pallas_d2q9.series_rows``) leaves the family out like a shape
+        without a plan.  A plan over Mosaic's own scoped-VMEM limit
         (rows of 2048 nodes and more) has not been shown to compile: its
         first call is probed, and under it stands the plan of the next
         band height down; a plan at the default limit is the proven
@@ -1409,6 +1419,14 @@ class Lattice:
                 shape=list(shape),
                 reason=pallas_d2q9.why_no_plan(model, shape))
             return []
+        if has_series and pallas_d2q9.series_rows(
+                model, self.params.series_map) is None:
+            telemetry.event(
+                "fused_rejected", engine="pallas_2d", model=name,
+                shape=list(shape),
+                reason="a <Control> series on a setting the band kernels "
+                       f"read from no plane: {self.params.series_map}")
+            return []
         fuse = 1 if sampled else 2
         how = dict(fuse=fuse, points=points)
         make = pallas_d2q9.make_pallas_iterate
@@ -1423,7 +1441,7 @@ class Lattice:
             chain.append(cand(
                 f"pallas_2d[{name},fuse={fuse},by<={rows}]", make,
                 probe=True, cap=rows, rows_cap=rows, **how))
-        if not sampled and pallas_d2q9.supports_resident(
+        if not (sampled or has_series) and pallas_d2q9.supports_resident(
                 model, shape, self.storage_dtype):
             # small domains: whole lattice VMEM-resident, 8 steps per
             # kernel call — (1R+1W)/8 HBM traffic per step.  First call
@@ -1451,9 +1469,10 @@ class Lattice:
         cannot take: one split in x (in 3D: or in y), 2D shards of no
         multiple of 8 rows.  The same for every model and dimension: it
         is one
-        algorithm, one step that reduces Globals.  Off a mesh a
-        ``<Control>`` series never gets here (it keeps the tuned engines
-        out of the chain); on one it keeps the XLA engine for every
+        algorithm, one step that reduces Globals.  Under a ``<Control>``
+        series (the tuned 2D band's, off a mesh) the same engine runs its
+        series flavour, ``call_sg``, which assembles that one step's
+        zonal planes; on a mesh a series keeps the XLA engine for every
         step.  Its first call is probed (:meth:`_probe_tail`): nothing
         has shown yet that it compiles."""
         from tclb_tpu import analysis
@@ -1597,8 +1616,11 @@ class Lattice:
         # (_build_tail: the generic Pallas kernel's in-kernel-globals
         # flavour where it takes the case, on a y-split 2D mesh on each
         # shard under shard_map with a psum, else the XLA step) instead.
-        # Engines advertising supports_series gather Control time series
-        # per iteration themselves; others fall back to XLA for those.
+        # Engines advertising supports_series give every step the Control
+        # series' values of its own iteration themselves (the tuned 2D
+        # band as scalars, the generic engines as planes): _build_fast
+        # lists no other under a series, and one that did not say so
+        # would leave every step to XLA here.
         full = fast is not None and fast.full_globals
         nfast = niter if full else niter - 1
         use_fast = (fast is not None and nfast >= 1
@@ -1702,7 +1724,10 @@ class Lattice:
         telemetry on, its account of the call (``Engine.account``) as
         the counters ``engine.kernel_calls``, ``engine.resident_calls``
         and ``engine.paired_calls`` and as fields ``say`` puts on a
-        span, once the call has returned: the innermost open one
+        span, once the call has returned (under a ``<Control>`` series
+        with ``series_rows``, ``series_horizon`` and
+        ``series_bytes_per_step``, and the counter
+        ``engine.series_steps``): the innermost open one
         (``iterate.fused`` or ``iterate.globals_step``), or the
         ``engine.probe`` whose candidate this is (its
         ``engine.probe.candidate`` is the innermost then, and times the
@@ -1714,7 +1739,18 @@ class Lattice:
             out, taps = out
             self._left_samples("taps", taps, taps, say)
         if telemetry.enabled() and engine.account is not None:
-            did = engine.account(niter, self.params.time_series is not None)
+            series = self.params.time_series
+            did = engine.account(niter, series is not None)
+            if series is not None:
+                # the series' account: the table, and the bytes of the
+                # planes the engine makes and its kernel reads a step
+                # because of it
+                telemetry.counter("engine.series_steps", niter)
+                did.update(
+                    series_rows=series.shape[0],
+                    series_horizon=series.shape[1],
+                    series_bytes_per_step=did.pop("series_planes", 0)
+                    * int(np.prod(self.shape)) * series.dtype.itemsize)
             telemetry.counter("engine.kernel_calls", did["kernel_calls"])
             if "resident_calls" in did:
                 telemetry.counter("engine.resident_calls",

@@ -24,9 +24,18 @@ Design (TPU-first, not a CUDA translation):
   128x128 systolic pass on a 9-vector);
 * scalar Settings ride in SMEM; zonal Settings (Velocity/Density) are
   built into per-node planes outside the kernel, once a call, by selects
-  over the zones (``fusion.zone_plane``; they are constant
-  across an ``Iterate`` call — the reference reads them per node from const
-  memory through the zone bits, src/LatticeContainer.h.Rt:89-108).
+  over the zones (``fusion.zone_plane`` — the reference reads them per
+  node from const memory through the zone bits,
+  src/LatticeContainer.h.Rt:89-108).  Without a ``<Control>`` series
+  they are constant across an ``Iterate`` call.  Under one (the
+  reference's zonal time tables, src/ZoneSettings.h:9-120) the band
+  kernels' series flavour takes, beside those planes, the value each
+  series row has at the iteration of EACH step of the call, as scalars
+  in SMEM sliced from the ``(n_series, T)`` table in HBM before the call
+  (``series_flavour``), and selects them over their zones in the kernel
+  (``_with_series``): no plane is made or read for a step, whatever
+  ``T`` is.  The sharded (``ext_halo``), sampled and VMEM-resident
+  flavours take no series; dispatch keeps such runs off them.
 
 This path is the reference's "NoGlobals" kernel specialization
 (src/cuda.cu.Rt Globals-mode template parameter): per-iteration Globals are
@@ -273,6 +282,27 @@ def zonal_planes(model: Model, params, zones, dtype):
     return vel, den
 
 
+def series_rows(model: Model, series_map: tuple) -> Optional[tuple]:
+    """The rows of a ``<Control>`` table (``SimParams.series_map``) as
+    the band kernels apply them: ``(plane, zone)`` of each row in the
+    table's order, ``plane`` the one of :func:`zonal_planes` its setting
+    fills (``"vel"`` or ``"den"``).  None where a row is of a setting
+    the kernels read from no plane: the band engine cannot take that
+    series.  (No model of the family has such a zonal setting today, and
+    none reads a ``_DT`` plane: ``NodeCtx.setting_dt`` has no caller in
+    ``models/``.)"""
+    names = {i: n for n, i in model.setting_index.items()}
+    planes = {"Velocity": "vel",
+              "Density" if "Density" in model.setting_index
+              else "Pressure": "den"}
+    rows = [None] * len(series_map)
+    for si, z, r in series_map:
+        if names[si] not in planes:
+            return None
+        rows[r] = (planes[names[si]], int(z))
+    return tuple(rows)
+
+
 def resident_vmem_bytes(model: Model, ny: int, nx: int) -> int:
     """What a resident call holds on-chip: the input block, the out block
     (doubles as the second ping-pong buffer) and one scratch stack, and
@@ -323,7 +353,9 @@ def make_resident_iterate(model: Model, shape, dtype=jnp.float32,
     telemetry on, dispatch says what the call issued on the open span
     (``account``).
 
-    Same NoGlobals + no-Control contract as the band kernels."""
+    Same NoGlobals contract as the band kernels; unlike them it takes
+    no ``<Control>`` series (eight steps on-chip a call): dispatch leaves
+    it out of the chain under one (``Lattice._band_chain``)."""
     if not supports_resident(model, shape, dtype):
         raise ValueError(f"resident kernel unsupported: {model.name} "
                          f"{shape}")
@@ -554,6 +586,13 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
     HBM traffic per step); the call ends in the single-step kernel: the
     odd step, or an even length's last two (``split``).
 
+    Under a ``<Control>`` series (``params.time_series``) the same
+    program shape runs the kernels' series flavour (``series_flavour``):
+    each step reads the value of its own iteration, the two-step kernel
+    two values a call.  The flavour is chosen where the program is
+    traced, so one engine serves a lattice before and after a series is
+    attached, and without one the programs are what they were.
+
     ``present`` restricts which boundary node types are materialized
     (every case is full-band compute-then-select, so skipping absent
     types is pure win); parity holds whenever it is a superset of the
@@ -761,7 +800,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         return {"step": _lbm_step, "bc_idx": bc_idx}
 
     def kernel(sett, f_hbm, flags_ref, vel_ref, den_ref, out_ref,
-               buf2, sems, halos=None):
+               buf2, sems, halos=None, series=None):
         # One CONTIGUOUS scratch buffer of by+16 rows per slot: the band
         # lands at rows [8, 8+by), its 8-row halo blocks at [0, 8) and
         # [8+by, 16+by) — all three DMA destinations are (8, 128)-tile
@@ -774,6 +813,8 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         # same overlap from its border/interior kernel split + async
         # memcpy streams, src/Lattice.cu.Rt:424-456).  ``halos`` (the
         # sharded flavour, ``sharded``): the neighbours' 8-row blocks.
+        # ``series`` (the series flavour, ``band_calls``): the values the
+        # <Control> series have at this call's step, ``_with_series``.
         i = pl.program_id(0)
         n = pl.num_programs(0)
 
@@ -846,8 +887,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         f = jnp.stack(pulled)
         bc0 = mid(bc_idx[0]) if bc_idx else 0.0
         bc1 = mid(bc_idx[1]) if bc_idx else 0.0
-        fnew = _lbm_step(f, flags_ref[:], vel_ref[:], den_ref[:],
-                         bc0, bc1, sett)
+        flags, vel, den = flags_ref[:], vel_ref[:], den_ref[:]
+        if series is not None:
+            vel, den = _with_series(flags, vel, den, series, 0)
+        fnew = _lbm_step(f, flags, vel, den, bc0, bc1, sett)
         for k in range(9):
             out_ref[k] = fnew[k]
         if bc_idx:
@@ -855,7 +898,7 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             out_ref[bc_idx[1]] = bc1
 
     def kernel2(sett, f_hbm, aux_hbm, out_ref, buff, bufa, sems,
-                halos=None):
+                halos=None, series=None):
         """Temporally-fused kernel: TWO collide-stream steps per band pass
         (the esoteric-twist-style traffic saving flagged in SURVEY §7's
         hard parts — each density is read/written once per TWO steps).
@@ -868,7 +911,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         access is a single slice, not a concatenate, and like kernel it is
         double-slotted: band i+1's six copies are issued before band i's
         arithmetic.  ``halos`` (the sharded flavour,
-        :func:`kernel2_sharded`): the neighbours' two 8-row blocks."""
+        :func:`kernel2_sharded`): the neighbours' two 8-row blocks.
+        ``series`` (the series flavour, :func:`band_calls`): the values
+        the <Control> series have at the call's first step, then those
+        at its second (:func:`_with_series`)."""
         i = pl.program_id(0)
         n = pl.num_programs(0)
 
@@ -951,7 +997,10 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         den_e = ext(bufa, 2, -1, by2 + 1)
         bc0_e = ext(buff, bc_idx[0], -1, by2 + 1) if bc_idx else 0.0
         bc1_e = ext(buff, bc_idx[1], -1, by2 + 1) if bc_idx else 0.0
-        f1 = _lbm_step(f, flags_e, vel_e, den_e, bc0_e, bc1_e, sett)
+        vel_1, den_1 = vel_e, den_e
+        if series is not None:
+            vel_1, den_1 = _with_series(flags_e, vel_e, den_e, series, 0)
+        f1 = _lbm_step(f, flags_e, vel_1, den_1, bc0_e, bc1_e, sett)
 
         # ---- step 2 on rows [0, by) ------------------------------------- #
         pulled = []
@@ -960,8 +1009,13 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
             sl = f1[k, 1 - dy:1 - dy + by2, :]
             pulled.append(pltpu.roll(sl, dx % nx, axis=1) if dx else sl)
         f = jnp.stack(pulled)
-        f2 = _lbm_step(f, flags_e[1:by2 + 1], vel_e[1:by2 + 1],
-                       den_e[1:by2 + 1],
+        flags_2, vel_2, den_2 = (flags_e[1:by2 + 1], vel_e[1:by2 + 1],
+                                 den_e[1:by2 + 1])
+        if series is not None:
+            # the second step reads the values of ITS iteration, over
+            # the planes of the call, not the first step's
+            vel_2, den_2 = _with_series(flags_2, vel_2, den_2, series, 1)
+        f2 = _lbm_step(f, flags_2, vel_2, den_2,
                        bc0_e[1:by2 + 1] if bc_idx else 0.0,
                        bc1_e[1:by2 + 1] if bc_idx else 0.0,
                        sett)
@@ -987,59 +1041,99 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         kernel(sett, f_hbm, flags_ref, vel_ref, den_ref, out_ref, buf2,
                sems, halos=(lo_hbm, hi_hbm))
 
-    # the field stack and, in the sharded flavour, the neighbours' blocks
-    f_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (3 if ext_halo else 1)
+    zshift = model.zone_shift
 
-    # both kernels start band i + 1's copies at grid step i and wait for
-    # them at step i + 1: the grid is walked in order, on one core (the
-    # default, "arbitrary", of a grid dimension; a v5e has one core)
-    grid2 = (ny // by2,)
-    call2 = pl.pallas_call(
-        lbm.mosaic_body(kernel2_sharded if ext_halo else kernel2,
-                        interpret),
-        grid=grid2,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + f_specs
-        + [pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((n_storage, by2, nx), lambda i: (0, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
-        scratch_shapes=[
-            pltpu.VMEM((_BAND_SLOTS, n_storage, by2 + 16, nx), dtype),
-            pltpu.VMEM((_BAND_SLOTS, _AUX_PLANES, by2 + 16, nx), dtype),
-            pltpu.SemaphoreType.DMA((_BAND_SLOTS, 6)),
-        ],
-        interpret=interpret,
-        compiler_params=plan.compiler_params(2),
-        name="d2q9_band_fuse2",
-    )
+    def _with_series(flags, vel, den, series, k: int):
+        """``vel`` and ``den`` as step ``k`` of a series call reads them:
+        over the call's planes, by a select on the zone ids the flags
+        hold, the value each series row has at that step's iteration
+        (``series``: the SMEM operand, a call's steps row after row, and
+        :func:`series_rows`'s ``(plane, zone)`` of each row).  What
+        ``core.lattice.series_overrides`` does to the XLA step's
+        setting."""
+        sv, rows = series
+        zones = flags >> jnp.int32(zshift)
+        for r, (plane, z) in enumerate(rows):
+            hit = zones == jnp.int32(z)
+            v = sv[k * len(rows) + r]
+            if plane == "vel":
+                vel = jnp.where(hit, v, vel)
+            else:
+                den = jnp.where(hit, v, den)
+        return vel, den
 
-    call = pl.pallas_call(
-        lbm.mosaic_body(kernel_sharded if ext_halo else kernel, interpret),
-        grid=(ny // by,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + f_specs + [
-            pl.BlockSpec((by, nx), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((by, nx), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((by, nx), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((n_storage, by, nx), lambda i: (0, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
-        scratch_shapes=[
-            pltpu.VMEM((_BAND_SLOTS, n_storage, by + 16, nx), dtype),
-            pltpu.SemaphoreType.DMA((_BAND_SLOTS, 3)),
-        ],
-        interpret=interpret,
-        compiler_params=plan.compiler_params(1),
-        name="d2q9_band_fuse1",
-    )
+    def band_calls(rows: Optional[tuple] = None) -> tuple:
+        """The one-step and the two-step ``pallas_call``.  ``rows``
+        (:func:`series_rows`): the series flavour, which takes the
+        series' values at the call's steps as a second SMEM operand
+        behind ``sett``, ``(steps * len(rows),)``; without, the kernels
+        and operands a lattice with no series has always had."""
+        if rows:
+            def one(sett, sv, *refs):
+                kernel(sett, *refs, series=(sv, rows))
+
+            def two(sett, sv, *refs):
+                kernel2(sett, *refs, series=(sv, rows))
+        else:
+            one, two = ((kernel_sharded, kernel2_sharded) if ext_halo
+                        else (kernel, kernel2))
+        smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] * (2 if rows else 1)
+        flavour = "_series" if rows else ""
+        # the field stack and, in the sharded flavour, the neighbours'
+        # blocks
+        f_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (
+            3 if ext_halo else 1)
+
+        # both kernels start band i + 1's copies at grid step i and wait
+        # for them at step i + 1: the grid is walked in order, on one
+        # core (the default, "arbitrary", of a grid dimension; a v5e has
+        # one core)
+        call2 = pl.pallas_call(
+            lbm.mosaic_body(two, interpret),
+            grid=(ny // by2,),
+            in_specs=smem + f_specs + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((n_storage, by2, nx),
+                                   lambda i: (0, i, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
+            scratch_shapes=[
+                pltpu.VMEM((_BAND_SLOTS, n_storage, by2 + 16, nx), dtype),
+                pltpu.VMEM((_BAND_SLOTS, _AUX_PLANES, by2 + 16, nx), dtype),
+                pltpu.SemaphoreType.DMA((_BAND_SLOTS, 6)),
+            ],
+            interpret=interpret,
+            compiler_params=plan.compiler_params(2),
+            name="d2q9_band_fuse2" + flavour,
+        )
+
+        call = pl.pallas_call(
+            lbm.mosaic_body(one, interpret),
+            grid=(ny // by,),
+            in_specs=smem + f_specs + [
+                pl.BlockSpec((by, nx), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((by, nx), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((by, nx), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((n_storage, by, nx), lambda i: (0, i, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((n_storage, ny, nx), dtype),
+            scratch_shapes=[
+                pltpu.VMEM((_BAND_SLOTS, n_storage, by + 16, nx), dtype),
+                pltpu.SemaphoreType.DMA((_BAND_SLOTS, 3)),
+            ],
+            interpret=interpret,
+            compiler_params=plan.compiler_params(1),
+            name="d2q9_band_fuse1" + flavour,
+        )
+        return call, call2
+
+    call, call2 = band_calls()
 
     if ext_halo:
         return call, call2, by, by2
-
-    zshift = model.zone_shift
 
     def split(niter: int) -> tuple:
         """``niter`` steps as the calls of the two-step kernel and the
@@ -1052,6 +1146,40 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         chip, PR 48)."""
         twos = max(niter - 1, 0) // 2 if fuse == 2 else 0
         return twos, niter - 2 * twos
+
+    series_built: dict = {}
+
+    def series_flavour(params) -> tuple:
+        """``(call, call2, values)`` of a call under a ``<Control>``
+        series: the series flavour of the kernels (:func:`band_calls`)
+        and ``values(it, steps)``, the SMEM operand of a call of
+        ``steps`` steps that starts at iteration ``it``: its steps'
+        columns of the device's ``(n_series, T)`` table, each sliced at
+        its own iteration mod ``T`` (a call's two steps may lie on
+        either side of the wrap).  The values go in as scalars; ``vel``
+        and ``den`` stay the planes of the whole call and no plane is
+        made for a step."""
+        rows = series_rows(model, params.series_map)
+        if rows not in series_built:
+            series_built[rows] = band_calls(rows)
+        table = params.time_series.astype(dtype)
+        horizon = table.shape[1]
+        # zonal_planes' own rule where the model has no Density: the
+        # density plane is 1 + 3 p
+        of_pressure = np.array([plane == "den" for plane, _ in rows]) \
+            & ("Density" not in model.setting_index)
+
+        def values(it, steps):
+            v = jnp.concatenate([
+                jax.lax.dynamic_slice_in_dim(
+                    table, jnp.mod(it + k, horizon), 1, axis=1)[:, 0]
+                for k in range(steps)])
+            if of_pressure.any():
+                v = jnp.where(np.tile(of_pressure, steps),
+                              1.0 + 3.0 * v, v)
+            return v
+
+        return (*series_built[rows], values)
 
     # the sampled flavour does not donate its state: donated, the loop's
     # carry is the caller's HBM buffer and every trip of two calls ends
@@ -1109,16 +1237,33 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         else:
             # two calls a loop body, so no copy of the carry before a
             # call (ops/engine.py): 10.6 us a call of 215.7 at 1024 x
-            # 1024, 61.4 where the carry lay in HBM (chip, PR 48)
+            # 1024, 61.4 where the carry lay in HBM (chip, PR 48).
+            # Under a <Control> series the same loops run the kernels'
+            # series flavour and carry the iteration beside the state:
+            # before a call its steps' values are sliced from the table
+            values = None
+            c1, c2, carry = call, call2, fields
+            if params.time_series is not None:
+                c1, c2, values = series_flavour(params)
+                carry = (fields, jnp.asarray(state.iteration, jnp.int32))
+
+            def calls_of(c, steps, *operands):
+                def one_call(carry, _):
+                    if values is None:
+                        return c(sett, refresh(carry), *operands), None
+                    fields, it = carry
+                    return (c(sett, values(it, steps), refresh(fields),
+                              *operands), it + steps), None
+                return one_call
+
             twos, ones = split(niter)
             if twos:
                 aux = jnp.stack([flags_i32.astype(dtype), vel, den])
-
-                def body2(fields, _):
-                    return call2(sett, refresh(fields), aux), None
-
-                fields = scan_calls(body2, fields, twos, paired)
-            fields = scan_calls(body, fields, ones, paired)
+                carry = scan_calls(calls_of(c2, 2, aux), carry, twos,
+                                   paired)
+            carry = scan_calls(calls_of(c1, 1, flags_i32, vel, den), carry,
+                               ones, paired)
+            fields = carry if values is None else carry[0]
         if pad:
             fields = fields[:, :ny_phys, :]
         out = LatticeState(
@@ -1131,14 +1276,11 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
 
     def iterate(state: LatticeState, params: SimParams, niter: int
                 ) -> LatticeState:
-        # the kernel freezes zonal Velocity/Density planes for the whole
-        # call; a <Control> time series changes them per iteration, which
-        # only the XLA path implements (NodeCtx.setting) — reject rather
-        # than silently diverge
-        if params.time_series is not None:
-            raise ValueError(
-                "pallas iterate does not support Control time series; "
-                "use the XLA path for time-dependent zonal settings")
+        if params.time_series is not None and points is not None:
+            # dispatch keeps such a run on the XLA scan
+            # (Lattice._samples_on_engine)
+            raise NotImplementedError(
+                "the sampled flavour does not take a <Control> series")
         return _iterate_jit(state, params, niter)
 
     # the bands of the kernel the engine loops; band_shape: the
@@ -1156,14 +1298,17 @@ def make_pallas_iterate(model: Model, shape, dtype=jnp.float32,
         two-call loop body issues, and the looped kernel's bands and
         the scratch slots it holds of one."""
         twos, ones = split(niter)
+        # series_planes: the planes made for a step because of a series
+        # (none: its values go in as scalars, ``series_flavour``)
         return dict(kernel_calls=twos + ones, remainder_steps=0,
                     paired_calls=paired_calls(twos, ones) if paired else 0,
-                    aux_planes=_AUX_PLANES, band_slots=_BAND_SLOTS, **looped)
+                    aux_planes=_AUX_PLANES, band_slots=_BAND_SLOTS, **looped,
+                    **(dict(series_planes=0) if has_series else {}))
 
     # vmem: the looped kernel's part of the plan, beside the account on
     # the span; impl: the jitted program, for the compile tests
     return Engine(iterate, account, samples=points is not None,
-                  pad_rows=pad,
+                  supports_series=points is None, pad_rows=pad,
                   vmem=dict(vmem_bytes=plan.vmem_bytes[fuse - 1],
                             vmem_limit_bytes=plan.vmem_limit_bytes[fuse - 1]),
                   impl=dict(band_shape=band_shape, program=_iterate_jit,

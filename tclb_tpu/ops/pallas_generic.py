@@ -293,7 +293,11 @@ def _scheduled_engine(model: Model, dtype, nx: int, fuse: int,
             **window(fused),
             # the f32 flag plane (and what rides beside it) of each window
             aux_planes=(1 + 2 * len(zonal_names) if has_series
-                        else 1 if lean_aux else 1 + len(zonal_names)))
+                        else 1 if lean_aux else 1 + len(zonal_names)),
+            # the planes ``assemble_aux`` makes for every step of a
+            # series and the kernel reads beside the flags: value and _DT
+            **(dict(series_planes=2 * len(zonal_names)) if has_series
+               else {}))
 
     program = _donating_unless_one_call(_schedule)
 

@@ -431,7 +431,7 @@ def _setup_summary(evts: list[dict]) -> dict:
         while up is not None and up["name"] == "startup.element":
             depth, up = depth + 1, by_id.get(up.get("parent"))
         elements.append({**row(e, "element", "nodes", "zones", "sharded",
-                               "bytes"),
+                               "bytes", "series", "horizon"),
                          "depth": depth})
     if elements:
         out["elements"] = elements
@@ -471,7 +471,8 @@ ACCOUNT_SUMS = ("kernel_calls", "paired_calls", "remainder_steps",
 ACCOUNT_PLAN = ("shards", "z_bands", "band_slabs", "halo_slabs", "y_bands",
                 "band_rows", "halo_rows", "aux_planes", "bands",
                 "halo_operand_rows", "halo_operand_slabs", "vmem_bytes",
-                "vmem_limit_bytes")
+                "vmem_limit_bytes", "series_rows", "series_horizon",
+                "series_bytes_per_step")
 
 
 def summarize(evts: list[dict]) -> dict:
@@ -915,7 +916,10 @@ def _format_setup(su: dict) -> list:
         name = "  " * r["depth"] + str(r.get("element"))
         extra = (f"  nodes {r['nodes']} zones {r.get('zones')}"
                  if "nodes" in r else "")
-        if "bytes" in r:    # an initial field, and whether made in shards
+        if "series" in r:   # a <Control>: its table
+            extra += (f"  series {r['series']} horizon {r.get('horizon')}"
+                      f" bytes {r.get('bytes')}")
+        elif "bytes" in r:  # an initial field, and whether made in shards
             extra += f"  sharded {r.get('sharded')} bytes {r['bytes']}"
         lines.append(f"  element  {name:<44} "
                      f"{_fmt(r['seconds'], 3):>9}{extra}")

@@ -209,8 +209,11 @@ def test_engine_declares_what_dispatch_reads(name):
     if name.startswith("generic_band"):
         assert it.pad_rows == it.account(1)["pad_rows"]
         assert (it.pad_rows > 0) == (name == "generic_band_ghost_rows")
+    # the engines that give a step the Control series' values of its
+    # own iteration: the generic ones and (PR 55) the tuned 2D band
     assert it.supports_series == name.startswith(("generic_band",
-                                                  "generic_3d"))
+                                                  "generic_3d",
+                                                  "tuned_band"))
     assert (it.plan is not None) == name.startswith("generic_3d")
     with pytest.raises(AttributeError):
         it.uses_generic

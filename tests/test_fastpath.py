@@ -257,15 +257,21 @@ def test_generic_resident_dispatch_matches_xla(monkeypatch):
 
 
 def test_fallbacks(monkeypatch):
-    """Unsupported configurations transparently run the XLA path: a
-    Control time series (per-iteration zonal settings) and an unsupported
-    model both fall back, producing correct results."""
+    """A Control time series (per-iteration zonal settings) no longer
+    falls back: since PR 55 dispatch keeps it on the tuned band engine
+    (the VMEM-resident engine, which takes none, leaves the chain) with
+    the generic band's series flavour as its tail; the run itself is
+    held to the XLA step in ``test_tail_engine_matches_the_xla_step
+    [series]`` and ``tests/test_control_band.py``.  What the fused
+    families do not cover still runs the XLA path transparently."""
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     m, lat = _karman_lattice()
     series = 0.03 + 0.001 * np.sin(np.arange(16) * 0.3)
     lat.set_setting_series("Velocity", series, zone=0)
-    lat.iterate(8)   # must not raise: dispatch sees time_series, uses XLA
-    assert np.isfinite(np.asarray(lat.state.fields)).all()
+    assert lat._fast_path().supports_series
+    assert [c.tag for c in lat._fast_chain] == ["pallas_2d[d2q9,fuse=2]"]
+    assert lat._tail_name == "pallas_generic[d2q9,fuse=1]"
+    assert lat._tail.supports_series
 
     # d2q9_heat used to be the fallback example; since round 4 the
     # registry-driven generic engine covers it — assert it dispatches

@@ -458,6 +458,43 @@ def test_d2q9_band_1024_pairs_the_calls(one_chip, fuse, niter, twos, ones):
     assert _in_fast_memory(body) == [True, True]
 
 
+def test_d2q9_band_1024_series_pairs_the_calls(one_chip):
+    """The same loop under a ``<Control>`` series, at the size of the
+    cell ``karman1024control.logonly``: Mosaic takes the series flavour
+    of both kernels (a second SMEM operand, a select a row on the zone
+    ids) under its default limit, the loop's body holds two
+    ``d2q9_band_fuse2_series`` calls, no copy or move of the whole
+    state, **both results in the compiler's fast memory**, as without a
+    series (the iteration carried beside the state tips nothing), and
+    beside them three fusions of a few scalars: one that slices the
+    body's four values from the table, each at its own iteration, and a
+    concatenation a call.  No plane of the lattice's size is made inside
+    the loop: a series costs scalars (57.1 ms an ``iterate(499)``
+    against 56.0 without one, chip, PR 55)."""
+    shape = (1024, 1024)
+    m, lat, present = _channel("d2q9", shape, nu=0.02)
+    lat.set_setting_series("Velocity", 0.01 + 1e-3 * (np.arange(4000) % 7),
+                           zone=1)
+    it = pallas_d2q9.make_pallas_iterate(m, shape, jnp.float32,
+                                         interpret=False, fuse=2,
+                                         present=present)
+    assert it.supports_series
+    lowered = it.impl["program"].lower(*_spec(lat, one_chip), niter=499)
+    assert lowered.args_info[0][0].fields.donated
+    text = lowered.compile().as_text()
+    body, calls = _kernel_loop_body(text, "d2q9_band_fuse2_series")
+    assert calls == 2
+    assert not _state_copies(body, m, shape)
+    assert not _state_moves(body, m, shape)
+    assert _in_fast_memory(body) == [True, True]
+    assert len(re.findall(
+        r"= \S+ custom-call\(.*d2q9_band_fuse1_series/", text)) == 1
+    assert "d2q9_band_fuse2/" not in text and "d2q9_band_fuse1/" not in text
+    plane = re.compile(r"= \w+\[(\d+,)*1024,1024\]\S* fusion\(")
+    assert not [line for line in body if plane.search(line)]
+    assert sum(" fusion(" in line for line in body) == 3
+
+
 # shapes the parent's band sizing could not compile, and the bands the
 # plan gives them (one-step, two-step)
 _PLANNED = [((800, 1024), (40, 40)), ((1280, 1024), (64, 40)),

@@ -191,7 +191,11 @@ def test_the_backlog_reaches_sinks_enabled_after_import_once(
             assert sum(e == kept for e in docs) == 1
         n = len(first)
         assert main(case) == 0              # a later main: its own boot
-        assert [e["kind"] for e in first[n:n + 1]] == ["boot"]
+        # (a snapshot of the counters rides on the first event that
+        # comes COUNTER_SNAPSHOT_S after the last snapshot: on a loaded
+        # host this one, and it goes out before the event it rides on)
+        assert [e["kind"] for e in first[n:]
+                if e["kind"] != "counters"][:1] == ["boot"]
         assert sum(e == kept for e in first) == 1
     finally:
         telemetry.unsubscribe(first.append)

@@ -127,6 +127,12 @@ _TAIL_LATTICES = {
                           *_mesh_tags(chips),
                           ("PressureLoss", "OutletFlux", "InletFlux"))
        for chips in (4, 2)},
+    # one chip under a <Control> series (PR 55): the tuned band takes
+    # the series' values as scalars, and the tail is the generic band's
+    # series flavour, which assembles that one step's zonal planes
+    "series": (lambda: _with_a_series(*_karman_lattice(64)),
+               "pallas_2d[d2q9,fuse=2]", "pallas_generic[d2q9,fuse=1]",
+               ("PressureLoss", "OutletFlux", "InletFlux")),
 }
 
 
@@ -140,8 +146,9 @@ def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
     ``engine.tail_calls`` a call, no fallback.  On a mesh (4 and 2 of the
     CPU's devices in 2D, 2 in 3D: shards of whole planes and y-tiled
     ones) both sides are sharded: the XLA side is the sharded XLA step
-    this tail replaces.  (The one-chip 2D case is
-    ``test_engine_dispatch_matches_xla``.)"""
+    this tail replaces.  (The one-chip 2D case without a series is
+    ``test_engine_dispatch_matches_xla``; under one, both calls of its
+    16 values, it is here.)"""
     lattice, fused, tail, reduced = _TAIL_LATTICES[case]
     niter, calls = 5, 2
     monkeypatch.setenv("TCLB_FASTPATH", "0")
@@ -155,6 +162,7 @@ def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
     monkeypatch.setenv("TCLB_FASTPATH", "force")
     _, lat_f = lattice()
     before = telemetry.counters().get("engine.tail_calls", 0)
+    under_a_series = telemetry.counters().get("engine.series_steps", 0)
     for want in wanted:
         lat_f.iterate(niter)
         got = lat_f.get_globals()
@@ -168,10 +176,22 @@ def test_tail_engine_matches_the_xla_step(monkeypatch, seen, case):
         if plane.startswith(("avg", "SynthT")):
             assert np.abs(fx[m.storage_index[plane]]).max() > 0, plane
     assert int(lat_f.state.iteration) == niter * calls
-    # the sharded tuned 2D engine is the one fused engine not probed
-    _says_tail(seen, lat_f, fused, tail, calls,
-               fused_probed=lat_f.mesh is None or m.ndim == 3)
+    # the tuned 2D engines, sharded or not, are the fused engines not
+    # probed (a plan at Mosaic's default limit)
+    _says_tail(seen, lat_f, fused, tail, calls, fused_probed=m.ndim == 3)
     assert telemetry.counters()["engine.tail_calls"] - before == calls
+    if case == "series":
+        # the series' account: every step of both engines counted, the
+        # table, and the planes made for a step because of it (none on
+        # the band; the tail's value and _DT planes of two zonal
+        # settings)
+        assert telemetry.counters()["engine.series_steps"] \
+            - under_a_series == niter * calls
+        for span, planes in ((_spans(seen, "iterate.fused")[-1], 0),
+                             (_spans(seen, "iterate.globals_step")[-1], 4)):
+            assert (span["series_rows"], span["series_horizon"],
+                    span["series_bytes_per_step"]) \
+                == (1, 16, planes * 64 * 128 * 4)
     if lat_f.mesh is not None:
         # replicated, as the sharded XLA step returns them
         assert lat_f.state.globals_.sharding.is_fully_replicated
@@ -197,14 +217,10 @@ def _tail_on_an_x_split():
     return _karman_on_a_mesh(2, axis="x")[1], None
 
 
-def _with_a_series(lat):
+def _with_a_series(m, lat):
     lat.set_setting_series(
         "Velocity", 0.03 + 0.001 * np.sin(np.arange(16) * 0.3), zone=0)
-    return lat
-
-
-def _tail_with_a_series():
-    return _with_a_series(_karman_lattice(64)[1]), "pallas_generic"
+    return m, lat
 
 
 def _tail_of_a_refused_dtype(monkeypatch):
@@ -221,21 +237,23 @@ def _tail_of_half_a_lane_tile():
 
 def _tail_on_a_mesh_with_a_series():
     # the sharded engines take no <Control> series: XLA runs every step
-    return _with_a_series(_karman_on_a_mesh(4)[1]), "pallas_sharded"
+    return _with_a_series(*_karman_on_a_mesh(4))[1], "pallas_sharded"
 
 
 @pytest.mark.parametrize("case", ["mesh_3d_y_split", "mesh_odd_rows",
                                   "mesh_x_split",
-                                  "mesh_series", "series", "refused_dtype",
+                                  "mesh_series", "refused_dtype",
                                   "no_kernel_globals"])
 def test_tail_engine_stays_off(monkeypatch, seen, case):
     """Where the generic engine's one-step flavour does not apply, the
     trailing step stays the XLA step: on a mesh the sharded tail cannot
     take (a 3D one split in y, shards of 12 rows, a split in x: none of
     them has a sharded fused engine either, and XLA runs every step),
-    with a ``<Control>`` series (the series-aware generic engine reduces
-    the Globals itself, and on a mesh the XLA engine runs the whole
-    call: no trailing step at all), with a storage dtype the generic
+    with a ``<Control>`` series on a mesh (the sharded engines take
+    none and the XLA engine runs the whole call: no trailing step at
+    all; on one chip the tail runs its series flavour,
+    ``test_tail_engine_matches_the_xla_step[series]``), with a storage
+    dtype the generic
     engine refuses, and on a shape whose generic kernel has no flavour
     that reduces Globals."""
     monkeypatch.setenv("TCLB_FASTPATH", "force")
@@ -246,7 +264,6 @@ def test_tail_engine_stays_off(monkeypatch, seen, case):
                          "mesh_odd_rows": _tail_on_shards_of_odd_rows,
                          "mesh_x_split": _tail_on_an_x_split,
                          "mesh_series": _tail_on_a_mesh_with_a_series,
-                         "series": _tail_with_a_series,
                          "no_kernel_globals": _tail_of_half_a_lane_tile,
                          }[case]())
     lat.iterate(6)
